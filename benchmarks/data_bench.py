@@ -35,8 +35,6 @@ import threading
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("RTPU_JAX_PLATFORM", "cpu")
-
 from ray_tpu.util.jaxenv import cpu_mesh_env  # noqa: E402
 
 cpu_mesh_env(8)

@@ -7,6 +7,8 @@ measurement is dominated by steady-state per-token latency — the
 memory-bandwidth-bound regime decoding lives in (each step reads every
 parameter once: ~0.7GB at 350M bf16, so the roofline is HBM, not MXU).
 
+Runs on the chip only: off it, the script fails instead of timing the host.
+
 Usage: python benchmarks/decode_bench.py [--batch 8 --prompt 128 --new 128]
 """
 from __future__ import annotations
@@ -39,9 +41,11 @@ def main():
     from ray_tpu.models import generate
     from ray_tpu.models import transformer as tfm
     from ray_tpu.models.configs import bench_350m
+    from ray_tpu.util.jaxenv import enable_compile_cache, require_tpu
 
+    enable_compile_cache()
+    dev = require_tpu()
     cfg = bench_350m(remat=False)
-    dev = jax.devices()[0]
     params = tfm.init_params(jax.random.key(0), cfg)
     if args.int8:
         from ray_tpu.models.quantize import quantize_params_int8
@@ -80,6 +84,7 @@ def main():
         "wall_s": round(best, 3),
         "int8": args.int8,
         "platform": dev.platform,
+        "device_kind": dev.device_kind,
     }), flush=True)
 
 

@@ -1,5 +1,5 @@
 """True MXU ceiling: K chained matmuls inside ONE jitted program (zero
-dispatch overhead, data-dependent so nothing is elided)."""
+dispatch overhead, data-dependent so nothing is elided). Chip only."""
 from __future__ import annotations
 
 import os
@@ -46,6 +46,10 @@ def probe(n, inner=20, reps=3):
 
 
 if __name__ == "__main__":
+    from ray_tpu.util.jaxenv import enable_compile_cache, require_tpu
+
+    enable_compile_cache()
+    print(json.dumps({"device_kind": require_tpu().device_kind}), flush=True)
     for n in (2048, 4096, 8192):
         try:
             print(json.dumps(probe(n)), flush=True)

@@ -30,6 +30,11 @@ Usage:
 --smoke shrinks request counts ~10x for the slow-tier CI check; the
 committed BENCH_r13.json comes from the full profile on the same 1-CPU
 host as PERF.json.
+
+A host benchmark of the serving control path with llama_tiny: the whole
+process tree is pinned to the cpu platform below (cpu_mesh_env exports
+JAX_PLATFORMS=cpu to every child), so none of its numbers is a device
+number. The on-chip serving path is exercised by chip_smoke.py.
 """
 import argparse
 import json
@@ -40,8 +45,6 @@ import time
 import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("RTPU_JAX_PLATFORM", "cpu")
-
 from ray_tpu.util.jaxenv import cpu_mesh_env  # noqa: E402
 
 cpu_mesh_env(8)

@@ -2,9 +2,11 @@
 classification through Ray-Data-style streaming into a device actor pool).
 
 Pipeline measured end-to-end: read_images (decode+resize) -> ImageNormalizer
--> map_batches(ViTPredictor actors). On a TPU host the predictor runs
-ViT-L/16 on the chip (bf16); the CPU fallback runs it scaled down so the
-benchmark always emits a line. Writes benchmarks/VIT_INFER.json.
+-> map_batches(ViTPredictor actors). On a TPU host the predictor actor
+reserves a chip and runs ViT-L/16 there (bf16); on a host without a chip it
+is a host benchmark of the same pipeline with a scaled-down model, and says
+so in its "device" and "model" fields. Writes benchmarks/VIT_INFER.json
+(the committed copy is a host run: "device": "cpu").
 
 Run from the repo root: python benchmarks/vit_infer.py
 """
@@ -37,10 +39,6 @@ class VitPredictor:
     (reference actor_pool_map_operator.py:289 GPU-actor UDFs)."""
 
     def __init__(self, use_tpu: bool):
-        if not use_tpu:
-            from ray_tpu.util.jaxenv import ensure_platform
-
-            ensure_platform("cpu")
         import functools
 
         import jax
@@ -62,7 +60,13 @@ class VitPredictor:
 
 
 def main():
-    use_tpu = not os.environ.get("JAX_PLATFORMS", "").startswith("cpu")
+    # The pool actor's worker decides the device, not this driver: with a
+    # chip the actor reserves it (num_tpus=1) and its worker can only run
+    # JAX on the tpu platform; without one it is a plain worker pinned to
+    # the cpu platform (core/worker_env.py). The driver never touches JAX.
+    from ray_tpu.util.accelerators import detect_tpu_chips
+
+    use_tpu = detect_tpu_chips() > 0
     n_images, batch = (512, 32) if use_tpu else (96, 16)
 
     import ray_tpu
@@ -85,7 +89,6 @@ def main():
         rows = ds.take_all()
         dt = time.perf_counter() - t0
     assert len(rows) == n_images
-    params_m = VitPredictor(False).cfg.num_params() / 1e6 if not use_tpu else 304
     out = {
         "metric": "vit_infer_images_per_s",
         "value": round(n_images / dt, 1),

@@ -306,7 +306,9 @@ def shutdown() -> None:
         _reset_direct_state(wc)
         if _owned_controller is not None and _controller_io is not None:
             try:
-                _controller_io.call(_owned_controller.shutdown(), timeout=5)
+                # 5s of teardown plus the controller's bounded wait (a
+                # minute) for chip-owning workers to exit.
+                _controller_io.call(_owned_controller.shutdown(), timeout=70)
             except Exception:
                 pass
         try:

@@ -20,6 +20,12 @@ def main() -> int:
         for p in reversed(extra_path.split(os.pathsep)):
             if p and p not in sys.path:
                 sys.path.insert(0, p)
+    if flags.get("RTPU_TPU_WORKER"):
+        # This process owns chips and will compile for them: join the
+        # shared persistent compile cache before anything imports jax.
+        from ray_tpu.util.jaxenv import enable_compile_cache
+
+        enable_compile_cache()
     from .worker import WorkerRuntime
 
     try:

@@ -16,10 +16,9 @@ import jax.numpy as jnp
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """Decided from the live platform; a backend that fails to initialize
+    raises here instead of quietly selecting the XLA path."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def reference_attention(
@@ -52,29 +51,49 @@ def reference_attention(
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
-def _seq_parallel_attention(q, k, v, mesh, rules, causal, scale):
-    """Embed context parallelism in the jitted program via shard_map when
-    the mesh has a nontrivial `seq` axis: pjit keeps global array semantics
-    outside; inside, each device works on its sequence shard. Two schemes
-    (SURVEY §5.7): ring (K/V rotation — any head count) and ulysses
-    (all-to-all head scattering — fewer collectives when the head counts
-    divide the axis). RTPU_SP_MODE selects: ring | ulysses | auto
-    (ulysses when divisible, else ring)."""
+def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash):
+    """Run attention per shard of a multi-device mesh via shard_map: pjit
+    keeps global array semantics outside; inside, each device works on its
+    batch/head/sequence shard.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned", a hard lowering error on a real 2x2 mesh —
+    chip run PR 21), and attention is independent per batch row and head, so
+    with the sequence whole on every device the flash kernel runs on the
+    local batch/head shard with no communication. A nontrivial `seq` axis
+    that the rules route the activation sequence dim onto adds context
+    parallelism, in two schemes (SURVEY §5.7): ring (K/V rotation — any head
+    count) and ulysses (all-to-all head scattering — fewer collectives when
+    the head counts divide the axis). RTPU_SP_MODE selects: ring | ulysses |
+    auto (ulysses when divisible, else ring).
+
+    Returns None when neither applies: dense attention under pjit
+    partitions itself."""
     from jax import shard_map
 
     from ray_tpu import flags
     from ray_tpu.parallel.sharding import logical_to_mesh_spec
+    from .flash_attention import flash_attention
     from .ring_attention import ring_attention
     from .ulysses_attention import ulysses_attention
 
     q_spec = logical_to_mesh_spec(("batch", "seq_act", "heads", None), rules, mesh)
     kv_spec = logical_to_mesh_spec(("batch", "seq_act", "kv_heads", None), rules, mesh)
+
+    def run(body):
+        return shard_map(body, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
+
     if q_spec[1] != "seq":
-        # Rules don't route the activation sequence dim onto the seq axis
-        # (e.g. RULES_DP on a mesh that happens to have seq>1): a ring over
+        # The sequence dim is whole on every device: no seq axis, or rules
+        # that don't route the activation sequence dim onto it (e.g.
+        # RULES_DP on a mesh that happens to have seq>1 — a ring over
         # replicated full-sequence "chunks" would silently double-count
-        # keys. Fall back to dense attention.
-        return None
+        # keys).
+        if not use_flash:
+            return None
+        return run(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, scale=scale))
     mode = flags.get("RTPU_SP_MODE")
     sp = mesh.shape["seq"]
 
@@ -101,14 +120,7 @@ def _seq_parallel_attention(q, k, v, mesh, rules, causal, scale):
         # divide falls back here rather than failing the whole step.
         body = lambda q, k, v: ring_attention(
             q, k, v, "seq", causal=causal, scale=scale)
-    fn = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(q_spec, kv_spec, kv_spec),
-        out_specs=q_spec,
-        check_vma=False,
-    )
-    return fn(q, k, v)
+    return run(body)
 
 
 def attention(
@@ -135,18 +147,6 @@ def attention(
                 "treating as 'auto'", stacklevel=2)
             _warned_bad_impl = True
         impl = "auto"
-    ctx = current_sharding_ctx()
-    # impl=xla promises a Pallas-free program; the seq-parallel schemes
-    # (ring/ulysses) run Mosaic flash kernels per-shard, so they are
-    # bypassed too — dense reference attention under pjit computes the
-    # same global result (XLA shards it by the operand shardings), just
-    # without the comm/compute overlap.
-    if ctx is not None and impl != "xla":
-        mesh, rules = ctx
-        if "seq" in mesh.axis_names and mesh.shape["seq"] > 1:
-            out = _seq_parallel_attention(q, k, v, mesh, rules, causal, scale)
-            if out is not None:
-                return out
     if use_flash is None:
         if impl == "flash":
             use_flash = True
@@ -154,25 +154,21 @@ def attention(
             use_flash = False
         else:
             use_flash = _on_tpu()
+    ctx = current_sharding_ctx()
+    # impl=xla promises a Pallas-free program; the seq-parallel schemes
+    # (ring/ulysses) run Mosaic flash kernels per-shard, so they are
+    # bypassed too — dense reference attention under pjit computes the
+    # same global result (XLA shards it by the operand shardings), just
+    # without the comm/compute overlap.
+    if ctx is not None and impl != "xla" and ctx[0].size > 1:
+        out = _shard_mapped_attention(q, k, v, *ctx, causal, scale, use_flash)
+        if out is not None:
+            return out
     if use_flash:
-        try:
-            from .flash_attention import flash_attention
+        from .flash_attention import flash_attention
 
-            return flash_attention(q, k, v, causal=causal, scale=scale)
-        except ImportError:
-            global _warned_no_flash
-            if not _warned_no_flash:
-                import warnings
-
-                warnings.warn(
-                    "flash_attention kernel unavailable; falling back to "
-                    "reference attention (materializes S^2 logits — expect "
-                    "HBM pressure at long sequence lengths)",
-                    stacklevel=2,
-                )
-                _warned_no_flash = True
+        return flash_attention(q, k, v, causal=causal, scale=scale)
     return reference_attention(q, k, v, causal=causal, scale=scale)
 
 
-_warned_no_flash = False
 _warned_bad_impl = False

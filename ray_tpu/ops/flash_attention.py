@@ -26,19 +26,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# 512-blocks win on v5e at bench shapes (benchmarks/probe_flash.py: fwd
-# 8.1ms @128 -> 5.3ms @512, grad 14.7 -> 7.2); VMEM for the [bq, bk] f32
-# score tile stays at 1MB. Module-level so benchmarks/mfu_sweep.py can
-# tune without threading kwargs through every model layer.
+# 512-blocks win on v5e at bench shapes (benchmarks/probe_flash.py at
+# [8,1024,16,64], chip run PR 21: fwd 3.79ms @128 -> 1.00ms @512, grad
+# 10.2 -> 2.91); VMEM for the [bq, bk] f32 score tile stays at 1MB.
+# Module-level so benchmarks/mfu_sweep.py can tune without threading
+# kwargs through every model layer.
 DEFAULT_BLOCK = 512
 _NEG_INF = -1e30
 
 
 def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
+    """Interpret mode is chosen because the platform is cpu, never because
+    the backend failed: a backend error propagates to the caller."""
+    return jax.devices()[0].platform == "cpu"
 
 
 # ---------------------------------------------------------------- forward

@@ -12,7 +12,7 @@ fragment sampler (env_runner.sample_fragment) consumes and three backends:
 - CnnRolloutBenchEnv: a pure-numpy Atari-shaped synthetic env whose whole
   batch steps in a few vector ops (SAME_STEP autoreset). It exists to
   measure the sampler+policy-inference ceiling without ALE in the image;
-  it is NOT a real game (RL_PERF.json labels it as overhead probe).
+  it is NOT a real game (benchmarks/rl_perf.py labels it as overhead probe).
 """
 from __future__ import annotations
 

@@ -104,25 +104,27 @@ def run(target: Application, *, name: str = "default",
             dep.name, cloudpickle.dumps(dep.func_or_class),
             args, kwargs, cfg, prefix))
 
-    # Wait for the ingress deployment to have live replicas; a deployment
-    # whose constructor keeps failing must raise with the real error, not
-    # hand back a handle that can never route.
+    # Wait until every replica of the ingress deployment has finished its
+    # constructor, so no request (and no request deadline) ever queues
+    # behind model load or program warm-up. A deployment whose constructor
+    # keeps failing raises with the real error, not a handle that can
+    # never route.
     from ray_tpu import flags
 
+    name = target.deployment.name
     ready_timeout = flags.get("RTPU_SERVE_READY_TIMEOUT_S")
-    deadline = time.time() + ready_timeout
+    deadline = time.monotonic() + ready_timeout
     while True:
-        _, reps = ray_tpu.get(
-            ctrl.get_replicas.remote(target.deployment.name))
-        if reps:
+        p = ray_tpu.get(ctrl.get_start_progress.remote(name))
+        if p["started"] >= p["target"]:
             break
-        if time.time() > deadline:
-            err = ray_tpu.get(
-                ctrl.get_last_error.remote(target.deployment.name))
+        if p["start_failures"] >= 3 or time.monotonic() > deadline:
             raise RuntimeError(
-                f"deployment {target.deployment.name!r} has no live "
-                f"replicas after {ready_timeout:g}s; last replica error: "
-                f"{err}")
+                f"deployment {name!r} has {p['started']} of {p['target']} "
+                f"replicas started after {p['start_failures']} failed "
+                f"constructions and "
+                f"{ready_timeout - (deadline - time.monotonic()):.0f}s; "
+                f"last replica error: {p['last_error']}")
         time.sleep(0.1)
     if _http:
         start(http_port=http_port)

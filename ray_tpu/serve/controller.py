@@ -73,6 +73,11 @@ class _DeploymentInfo:
         # the routed set (version bump) but alive until idle or the drain
         # deadline — in-flight streams finish across a resize.
         self.draining: List[Tuple[Any, float]] = []
+        # Replicas whose constructor is still running: actor id ->
+        # monotonic creation time.
+        self.starting: Dict[str, float] = {}
+        # Constructor failures in a row; serve.run() gives up at 3.
+        self.start_failures = 0
         self.version = 0
         self.last_error: Optional[str] = None
         # Latest aggregated serving signals from the stats poll.
@@ -118,6 +123,7 @@ class ServeController:
                 for r in info.replicas:
                     self._kill_replica(r)
                 info.replicas = []
+                info.starting = {}
                 info.version += 1
                 self._publish_update(name, info.version)
             if route_prefix:
@@ -209,6 +215,19 @@ class ServeController:
         info = self._deployments.get(name)
         return info.last_error if info else None
 
+    def get_start_progress(self, name: str) -> Dict[str, Any]:
+        """What serve.run() waits on: replicas whose constructor finished
+        vs the target, and constructor failures in a row."""
+        info = self._deployments.get(name)
+        if info is None:
+            raise KeyError(f"no deployment {name!r}")
+        with self._lock:
+            started = sum(r._actor_id not in info.starting
+                          for r in info.replicas)
+            return {"started": started, "target": info.target_replicas,
+                    "start_failures": info.start_failures,
+                    "last_error": info.last_error}
+
     # ---------------------------------------------------------- reconcile
 
     def _make_replica(self, info: _DeploymentInfo):
@@ -219,8 +238,47 @@ class ServeController:
         opts.setdefault("max_concurrency",
                         info.config.get("max_ongoing_requests", 16))
         cls = ray_tpu.remote(ReplicaActor).options(**opts)
-        return cls.remote(info.serialized_callable, info.init_args,
-                          info.init_kwargs, info.config.get("user_config"))
+        handle = cls.remote(info.serialized_callable, info.init_args,
+                            info.init_kwargs, info.config.get("user_config"))
+        info.starting[handle._actor_id] = time.monotonic()
+        return handle
+
+    def _poll_starting(self, info: _DeploymentInfo, r) -> bool:
+        """True while replica ``r`` is constructing or once it has started;
+        False (with info.last_error set) when its constructor failed or
+        outlived RTPU_SERVE_READY_TIMEOUT_S.
+
+        A starting replica is not held to the 30s health window: process
+        start, accelerator runtime start, model load and program warm-up
+        are start-up work, and a replica killed in the middle of them can
+        never become ready."""
+        from ray_tpu.core import context as ctx
+
+        born = info.starting.get(r._actor_id)
+        if born is None:  # a concurrent pass already saw it start
+            return True
+        state = ctx.get_worker_context().client.request(
+            {"kind": "resolve_actor", "actor_id": r._actor_id,
+             "wait": 0})["state"]
+        if state == "alive":  # constructor returned
+            info.starting.pop(r._actor_id, None)
+            info.start_failures = 0
+            return True
+        limit = flags.get("RTPU_SERVE_READY_TIMEOUT_S")
+        if state == "pending":
+            if time.monotonic() - born <= limit:
+                return True
+            info.last_error = (f"replica still constructing after "
+                               f"{limit:g}s (RTPU_SERVE_READY_TIMEOUT_S)")
+        else:  # the constructor raised or its worker died: ask it why
+            try:
+                ray_tpu.get(r.check_health.remote(), timeout=5.0)
+                info.last_error = f"replica actor is {state}"
+            except Exception as e:
+                info.last_error = repr(e)
+        info.starting.pop(r._actor_id, None)
+        info.start_failures += 1
+        return False
 
     def _kill_replica(self, handle) -> None:
         try:
@@ -256,7 +314,11 @@ class ServeController:
             probes = []
             for r in replicas:
                 try:
-                    probes.append((r, r.check_health.remote()))
+                    if r._actor_id in info.starting:
+                        (alive if self._poll_starting(info, r)
+                         else dead).append(r)
+                    else:
+                        probes.append((r, r.check_health.remote()))
                 except Exception as e:
                     info.last_error = repr(e)
                     dead.append(r)
@@ -296,6 +358,9 @@ class ServeController:
                     info.replicas = alive
                     info.version += 1
                     self._publish_update(info.name, info.version)
+                    live = {r._actor_id for r in alive}
+                    info.starting = {a: v for a, v in info.starting.items()
+                                     if a in live}
 
     def _reap_draining(self) -> None:
         """Kill draining replicas that went idle (or overstayed the drain

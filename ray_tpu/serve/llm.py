@@ -196,6 +196,7 @@ def build_streaming_llm_deployment(cfg, params_factory, *, name: str = "llm-stre
                     max_new_tokens=max_new_tokens,
                     seed=int.from_bytes(os.urandom(4), "little"),
                     model=name)
+                self._engine.warmup()
                 self._stop = threading.Event()
                 self._ticker = threading.Thread(
                     target=self._engine.run_forever, args=(self._stop,),
@@ -214,6 +215,13 @@ def build_streaming_llm_deployment(cfg, params_factory, *, name: str = "llm-stre
             if self._engine is None:
                 return {}
             return self._engine.stats()
+
+        def device_report(self) -> Dict[str, Any]:
+            """The engine's device_report(), from inside this replica."""
+            if self._engine is None:
+                raise RuntimeError(
+                    "device_report needs continuous_batching=True")
+            return self._engine.device_report()
 
         def __call__(self, request: Dict[str, Any]):
             import jax

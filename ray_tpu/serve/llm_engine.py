@@ -208,16 +208,13 @@ class ContinuousBatchingEngine:
         skew never touches the histogram. ``arrival_ts`` (epoch seconds)
         is the SAME-PROCESS alternative for embedders/tests; ignored when
         queue_wait_s is given. With neither, arrival is now."""
-        jnp = self._jnp
         mono0 = time.monotonic()
         ids = np.asarray(tokens, np.int32)
         if ids.ndim != 1 or ids.size == 0:
             raise ValueError("tokens must be a non-empty 1-D integer list")
         ids = ids[-self.max_prompt_len:]
         S = bucket_len(len(ids), self.max_prompt_len)
-        padded = np.zeros((1, S), np.int32)
-        padded[0, :len(ids)] = ids
-        # Prefill OUTSIDE the engine lock (seconds on first compile).
+        # Prefill OUTSIDE the engine lock.
         from . import trace as serve_trace
 
         hop = serve_trace.start_hop(
@@ -225,25 +222,94 @@ class ContinuousBatchingEngine:
             attributes={"model": self.model, "prompt_len": len(ids),
                         "bucket": S, "local": True})
         try:
-            logits1, k1, v1 = self._prefill_one(
-                self.params, jnp.asarray(padded),
-                jnp.asarray([len(ids)], jnp.int32))
+            logits1, k1, v1 = self._prefill_padded(ids, S)
         except BaseException as e:
             if hop is not None:
                 hop.end(error=type(e).__name__)
             raise
         if hop is not None:
             hop.end()
-        # Pad the slot K/V out to the engine max_len on the host once.
-        pad = self.max_len - S
-        if pad:
-            k1 = jnp.pad(k1, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v1 = jnp.pad(v1, ((0, 0), (0, pad), (0, 0), (0, 0)))
         return self._attach(k1, v1, len(ids), np.asarray(logits1),
                             max_new_tokens=max_new_tokens,
                             temperature=temperature, eos_id=eos_id,
                             timeout=timeout, arrival_ts=arrival_ts,
                             queue_wait_s=queue_wait_s, mono0=mono0)
+
+    def _prefill_padded(self, ids: np.ndarray, S: int):
+        """Bucketed prefill of one prompt, K/V padded out to the engine
+        max_len: (logits [V], k, v [L, max_len, KVH, hd])."""
+        jnp = self._jnp
+        padded = np.zeros((1, S), np.int32)
+        padded[0, :len(ids)] = ids
+        logits1, k1, v1 = self._prefill_one(
+            self.params, jnp.asarray(padded),
+            jnp.asarray([len(ids)], jnp.int32))
+        pad = self.max_len - S
+        if pad:
+            k1 = jnp.pad(k1, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            v1 = jnp.pad(v1, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return logits1, k1, v1
+
+    def warmup(self) -> None:
+        """Compile every program a request can reach — each prefill bucket,
+        the slot splice and the decode tick — by running each once on
+        throwaway inputs, concurrently (XLA compiles outside the GIL).
+
+        A serving replica calls this from its constructor, so compilation
+        is start-up time covered by the deployment's ready deadline: no
+        request deadline and no stream-stall window ever spans a compile.
+        Nothing here touches slot state (the programs are functional and
+        their results are dropped)."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        jnp = self._jnp
+        buckets = sorted({bucket_len(n, self.max_prompt_len)
+                          for n in range(1, self.max_prompt_len + 1)})
+
+        def bucket(S: int):
+            return self._prefill_padded(np.zeros(1, np.int32), S)
+
+        def tick():
+            key = self._jax.random.fold_in(self._rng, 0)
+            return self._tick(self.params, self.cache, self.cur_tok, key,
+                              jnp.asarray(self.temp))
+
+        with ThreadPoolExecutor(max_workers=len(buckets) + 1) as pool:
+            futs = [pool.submit(bucket, S) for S in buckets]
+            futs.append(pool.submit(tick))
+            done = [f.result() for f in futs]
+        _, k1, v1 = done[0]
+        done.append(self._splice(
+            self.cache.k, self.cache.v, self.cache.pos, self.cur_tok,
+            k1, v1, jnp.asarray(1, jnp.int32), jnp.asarray(0, jnp.int32), 0))
+        self._jax.block_until_ready(done)
+
+    def device_report(self) -> Dict[str, Any]:
+        """Where this engine computes, asked from inside its process: the
+        devices the process sees, the devices its params and KV cache live
+        on, and whether the compiled prefill carries the Pallas flash
+        kernel (custom_call_target "tpu_custom_call")."""
+        import os
+
+        from ray_tpu import flags
+
+        jax, jnp = self._jax, self._jnp
+        devs = jax.local_devices()
+
+        def homes(tree) -> List[str]:
+            return sorted({str(d) for x in jax.tree.leaves(tree)
+                           for d in x.devices()})
+
+        hlo = self._prefill_one.lower(
+            self.params, jnp.zeros((1, self.max_prompt_len), jnp.int32),
+            jnp.ones((1,), jnp.int32)).compile().as_text()
+        return {"pid": os.getpid(), "platform": devs[0].platform,
+                "device_kind": devs[0].device_kind,
+                "local_devices": [str(d) for d in devs],
+                "visible_chips": flags.get("TPU_VISIBLE_CHIPS"),
+                "params_on": homes(self.params),
+                "cache_on": homes((self.cache.k, self.cache.v)),
+                "prefill_has_tpu_custom_call": "tpu_custom_call" in hlo}
 
     def attach_prefilled(self, k, v, length: int, logits, *,
                          max_new_tokens: Optional[int] = None,

@@ -84,28 +84,25 @@ class JaxBackend(Backend):
     mesh_shape: Optional[Dict[str, int]] = None
 
     def on_start(self, worker_group: WorkerGroup) -> None:
-        coordinator = None
-        if self.distributed:
-            def pick_addr() -> str:
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.bind(("", 0))
-                port = s.getsockname()[1]
-                s.close()
-                return f"{socket.gethostbyname(socket.gethostname())}:{port}"
+        if not self.distributed:
+            return  # JAX reads its platform from the worker's environment
 
-            coordinator = worker_group.execute_single(0, pick_addr)
+        def pick_addr() -> str:
+            s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            s.bind(("", 0))
+            port = s.getsockname()[1]
+            s.close()
+            return f"{socket.gethostbyname(socket.gethostname())}:{port}"
+
+        coordinator = worker_group.execute_single(0, pick_addr)
         n = len(worker_group)
 
-        def setup(rank: int, coord: Optional[str]) -> None:
-            from ray_tpu.util.jaxenv import ensure_platform
+        def setup(rank: int, coord: str) -> None:
+            import jax
 
-            ensure_platform()
-            if coord is not None:
-                import jax
-
-                jax.distributed.initialize(
-                    coordinator_address=coord, num_processes=n, process_id=rank
-                )
+            jax.distributed.initialize(
+                coordinator_address=coord, num_processes=n, process_id=rank
+            )
 
         import ray_tpu as rt
 

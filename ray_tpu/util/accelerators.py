@@ -13,12 +13,23 @@ from ray_tpu import flags
 import glob
 from typing import Dict, Optional
 
-# Peak dense bf16 TFLOP/s per chip, used for MFU accounting (public specs).
+# Peak dense bf16 TFLOP/s per chip, used for MFU accounting (Google Cloud
+# TPU documentation, per-generation system architecture pages).
 TPU_PEAK_TFLOPS_BF16: Dict[str, float] = {
     "v4": 275.0,
     "v5e": 197.0,
     "v5p": 459.0,
     "v6e": 918.0,
+}
+
+# jax ``Device.device_kind`` -> generation. "TPU v5 lite" is what this repo's
+# v5e reports (chip run, PR 21); a kind that is not here is an error, never
+# a default.
+TPU_GENERATION_BY_DEVICE_KIND: Dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v6 lite": "v6e",
 }
 
 
@@ -60,9 +71,21 @@ def tpu_pod_resources(pod_name: str, pod_type: str, is_head: bool) -> Dict[str, 
     return res
 
 
-def peak_flops_per_chip(generation: Optional[str] = None, dtype: str = "bf16") -> float:
-    gen = generation or detect_tpu_generation() or "v5e"
-    tf = TPU_PEAK_TFLOPS_BF16.get(gen, 197.0)
+def peak_flops_per_chip(device_kind: Optional[str] = None,
+                        dtype: str = "bf16") -> float:
+    """Peak FLOP/s of one chip of ``device_kind``; by default the kind of the
+    live device, so call it in the process that owns the chip. Raises on a
+    kind with no entry: an MFU against a guessed peak is not a measurement."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    gen = TPU_GENERATION_BY_DEVICE_KIND.get(device_kind)
+    if gen is None:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device_kind!r}; known: "
+            f"{sorted(TPU_GENERATION_BY_DEVICE_KIND)}")
+    tf = TPU_PEAK_TFLOPS_BF16[gen]
     if dtype in ("f32", "float32"):
         tf = tf / 2
     return tf * 1e12

@@ -5,11 +5,7 @@ JAX is forced onto a virtual 8-device CPU platform before any test imports it,
 so sharding/collective tests run the real pjit/shard_map paths without TPU
 hardware (SURVEY.md §4.4 test-ring 2).
 """
-import os
-
-os.environ["RTPU_JAX_PLATFORM"] = "cpu"
-
-from ray_tpu.util.jaxenv import cpu_mesh_env  # noqa: E402
+from ray_tpu.util.jaxenv import cpu_mesh_env
 
 cpu_mesh_env(8)
 

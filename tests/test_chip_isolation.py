@@ -61,3 +61,58 @@ def test_chip_count_aware_worker_reuse(monkeypatch):
         assert chips4 == [] or len(chips4) == 4, chips4
     finally:
         ray_tpu.shutdown()
+
+
+def test_chips_return_only_when_their_process_is_gone(monkeypatch):
+    """libtpu holds a chip until its process has exited: a killed worker's
+    chips rejoin the pool then, not when the worker is declared dead, so a
+    successor is never spawned onto a chip that is still open."""
+    import os
+
+    import ray_tpu
+
+    monkeypatch.setenv("RTPU_NUM_TPUS", "2")
+    ray_tpu.init(num_cpus=2)
+    try:
+        @ray_tpu.remote(num_tpus=1)
+        class Holder:
+            def __init__(self, predecessor=None):
+                self.predecessor_alive = False
+                if predecessor is not None:
+                    try:
+                        os.kill(predecessor, 0)
+                        self.predecessor_alive = True
+                    except ProcessLookupError:
+                        pass
+
+            def linger_on_exit(self):
+                """Make this process slow to die, like a TPU runtime
+                tearing down its DMA mappings."""
+                import ctypes
+                import signal
+                import time
+
+                # Ignore SIGTERM (libc: this is not the main thread) and
+                # dawdle in the hard exit the shutdown message ends in.
+                ctypes.CDLL(None).signal(signal.SIGTERM, 1)  # SIG_IGN
+                hard_exit = os._exit
+                os._exit = lambda code: (time.sleep(1.5), hard_exit(code))
+                return os.getpid()
+
+            def facts(self):
+                ids = ray_tpu.get_runtime_context() \
+                    .get_accelerator_ids()["TPU"]
+                return ids, self.predecessor_alive
+
+        a, b = Holder.remote(), Holder.remote()
+        pid_a = ray_tpu.get(a.linger_on_exit.remote(), timeout=60)
+        chips_a, _ = ray_tpu.get(a.facts.remote(), timeout=60)
+        chips_b, _ = ray_tpu.get(b.facts.remote(), timeout=60)
+        ray_tpu.kill(a)
+        c = Holder.remote(pid_a)
+        chips_c, predecessor_alive = ray_tpu.get(c.facts.remote(), timeout=60)
+        assert chips_c == chips_a and chips_c != chips_b
+        assert not predecessor_alive, \
+            "successor started on a chip its predecessor still held"
+    finally:
+        ray_tpu.shutdown()

@@ -55,7 +55,6 @@ def test_graft_entry_dryrun():
     env = dict(os.environ)
     env.update(
         JAX_PLATFORMS="cpu",
-        RTPU_JAX_PLATFORM="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     out = subprocess.run(

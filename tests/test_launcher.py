@@ -55,7 +55,7 @@ def test_up_exec_pg_down(tmp_path):
         "provider": {"type": "local"},
         "head": {"num_cpus": 2},
         "workers": {"count": 2, "num_cpus": 2},
-        "env": {"RTPU_JAX_PLATFORM": "cpu"},
+        "env": {"JAX_PLATFORMS": "cpu"},
     })
     launcher = ClusterLauncher(cfg)
     state = launcher.up()

@@ -142,6 +142,42 @@ def test_autoscaling_up(serve_instance):
     assert grew, "autoscaler never scaled up under sustained load"
 
 
+def test_run_returns_once_constructors_finished(serve_instance):
+    """serve.run() waits for the replicas' constructors (model load,
+    program warm-up), so no request deadline queues behind one; and a
+    constructor that keeps raising surfaces its error instead of a handle
+    that can never route."""
+
+    @serve.deployment(num_replicas=2)
+    class SlowStart:
+        def __init__(self):
+            time.sleep(1.5)
+
+        def __call__(self, x):
+            return x
+
+    t0 = time.monotonic()
+    handle = serve.run(SlowStart.bind(), route_prefix="/slowstart")
+    assert time.monotonic() - t0 >= 1.5
+    ctrl = ray_tpu.get_actor("SERVE_CONTROLLER")
+    p = ray_tpu.get(ctrl.get_start_progress.remote("SlowStart"))
+    assert (p["started"], p["target"]) == (2, 2)
+    assert handle.remote(3).result(timeout=10) == 3
+    serve.delete("SlowStart")
+
+    @serve.deployment
+    class Broken:
+        def __init__(self):
+            raise ValueError("boom in constructor")
+
+        def __call__(self, x):
+            return x
+
+    with pytest.raises(RuntimeError, match="boom in constructor"):
+        serve.run(Broken.bind(), route_prefix="/broken")
+    serve.delete("Broken")
+
+
 def test_replica_recovery(serve_instance):
     @serve.deployment(num_replicas=1)
     def stable(x):
