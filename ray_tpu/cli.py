@@ -994,6 +994,25 @@ def cmd_serve(args) -> int:
             print()
             print(line)
             return 0
+        if args.serve_cmd == "profile":
+            import ray_tpu
+            from ray_tpu.serve.controller import CONTROLLER_NAME
+
+            ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
+            _, replicas = ray_tpu.get(ctrl.get_replicas.remote(
+                args.deployment))
+            logdir = os.path.abspath(args.logdir)
+            outs = ray_tpu.get(
+                [r.profile.remote(args.seconds, os.path.join(
+                    logdir, f"replica-{i}"))
+                 for i, r in enumerate(replicas)],
+                timeout=args.seconds + 120)
+            for i, o in enumerate(outs):
+                print(f"replica {i} pid {o['pid']}: {args.seconds:g}s -> "
+                      + (", ".join(o["xplane"]) or "no xplane file written"))
+            print(f"view: tensorboard --logdir {logdir} (the host phases "
+                  "are on the replica's threads beside the TPU planes)")
+            return 0
         raise SystemExit(f"unknown serve subcommand {args.serve_cmd!r}")
     finally:
         if args.serve_cmd != "run":
@@ -1370,6 +1389,14 @@ def main(argv=None) -> int:
     st_.add_argument("request_id")
     st_.add_argument("--address", default=None)
     st_.set_defaults(fn=cmd_serve)
+    spf = ssub.add_parser("profile",
+                          help="jax.profiler trace of every replica of a "
+                               "deployment, taken in the chip-owning process")
+    spf.add_argument("deployment")
+    spf.add_argument("--seconds", type=float, default=3.0)
+    spf.add_argument("--logdir", default="serve_profile")
+    spf.add_argument("--address", default=None)
+    spf.set_defaults(fn=cmd_serve)
 
     p = sub.add_parser("dashboard", help="serve the web dashboard")
     p.add_argument("--address", default=None)

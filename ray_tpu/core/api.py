@@ -602,9 +602,12 @@ class ObjectRefGenerator:
             # for the task would error instead of honoring the protocol.
             raise StopIteration
         wc = ctx.get_worker_context()
-        r = wc.client.request(
-            {"kind": "generator_next", "task_id": self._task_id, "index": self._index}
-        )
+        # Held until the producer reports the item: a wait, not a stall.
+        with tracing.phase("stream.next", slow=False):
+            r = wc.client.request(
+                {"kind": "generator_next", "task_id": self._task_id,
+                 "index": self._index}
+            )
         if r.get("done"):
             self._exhausted = True
             raise StopIteration

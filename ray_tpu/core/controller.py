@@ -37,6 +37,7 @@ import uuid
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from ..util import tracing
 from . import protocol, worker_env
 from .ids import ActorID, NodeID, ObjectID, PlacementGroupID, TaskID, WorkerID
 from .object_store import ObjectLocation, free_location
@@ -600,12 +601,32 @@ class Controller:
 
     # ------------------------------------------------------------------ setup
 
+    @staticmethod
+    def _periodic(name: str, coro) -> "asyncio.Task":
+        """A periodic loop as a task whose every stretch on the event loop
+        is observed as ctrl.periodic.<name> (its sleeps are not counted)."""
+        async def run():
+            return await tracing.steps("ctrl.periodic." + name, coro)
+
+        return asyncio.get_running_loop().create_task(run())
+
+    def _lag_probe(self) -> None:
+        """ctrl.loop_lag: how late a 50 ms timer fires on this loop. A probe
+        that fires late covers exactly the time the loop was held."""
+        now = time.monotonic_ns()
+        tracing.observe("ctrl.loop_lag", now - self._lag_due, self._lag_due)
+        self._lag_due = now + 50_000_000
+        self._lag_handle = asyncio.get_running_loop().call_later(
+            0.05, self._lag_probe)
+
     async def start(self) -> Tuple[str, int]:
         self.server = await asyncio.start_server(self._on_connection, self.host, self.port)
         self.port = self.server.sockets[0].getsockname()[1]
         loop = asyncio.get_running_loop()
-        self._sched_task = loop.create_task(self._scheduler_loop())
-        self._health_task = loop.create_task(self._health_check_loop())
+        self._lag_due = time.monotonic_ns() + 50_000_000
+        self._lag_handle = loop.call_later(0.05, self._lag_probe)
+        self._sched_task = self._periodic("scheduler", self._scheduler_loop())
+        self._health_task = self._periodic("health", self._health_check_loop())
         if getattr(self, "_restored_detached", None):
             # Restored detached actors re-create right after the adoption
             # grace window, independent of the health loop's cadence.
@@ -617,19 +638,19 @@ class Controller:
 
             loop.create_task(_resume_after_grace())
         if flags.get("RTPU_MEMORY_MONITOR"):
-            self._memory_task = loop.create_task(self._memory_monitor_loop())
+            self._memory_task = self._periodic("memory_monitor", self._memory_monitor_loop())
         if flags.get("RTPU_HANG_WATCHDOG") and flags.get("RTPU_EVENTS"):
             # Off => no task, no per-sweep work: the disabled-path perf
             # floor is literally zero controller cycles.
-            self._watchdog_task = loop.create_task(self._hang_watchdog_loop())
+            self._watchdog_task = self._periodic("hang_poll", self._hang_watchdog_loop())
         if flags.get("RTPU_LEAK_WATCHDOG") and flags.get("RTPU_EVENTS"):
             # Same off-switch contract as the hang watchdog: disabled means
             # no task and zero per-sweep work.
-            self._leak_task = loop.create_task(self._leak_watchdog_loop())
+            self._leak_task = self._periodic("leak_poll", self._leak_watchdog_loop())
         if self.tsdb is not None:
             # RTPU_TSDB=0 => no task, no per-step sampling work: the
             # disabled path is zero controller cycles (perf-floor test).
-            self._telemetry_task = loop.create_task(self._telemetry_loop())
+            self._telemetry_task = self._periodic("telemetry", self._telemetry_loop())
         # Resume drains interrupted by a controller bounce: restored
         # (non-agent) nodes become unschedulable immediately, but the
         # drain task itself waits out the reconnect grace — the node's
@@ -760,6 +781,8 @@ class Controller:
         from . import native_store
 
         native_store.close_arena(destroy=True)
+        if getattr(self, "_lag_handle", None) is not None:
+            self._lag_handle.cancel()
         if self._sched_task is not None:
             self._sched_task.cancel()
         if self._health_task is not None:
@@ -1334,7 +1357,9 @@ class Controller:
         # the ownership-protocol tests' proof that ref passing between
         # workers makes NO controller round-trips.
         self.rpc_counts[kind] = self.rpc_counts.get(kind, 0) + 1
-        return await fn(conn, msg)
+        # ctrl.rpc.<kind>: every stretch this handler holds the loop (its
+        # awaits are not counted), so a blocked loop names its blocker.
+        return await tracing.steps("ctrl.rpc." + kind, fn(conn, msg))
 
     # --------------------------------------------------------------- handlers
 
@@ -4355,7 +4380,20 @@ class Controller:
             for ev in msg.get("events", ()):
                 if isinstance(ev, dict) and ev.get("kind"):
                     self.events.append(dict(ev))
+                    if ev["kind"] == "SLOW_PHASE":
+                        tracing.ingest_slow_event(ev)
         return {"ok": True}
+
+    async def _h_phase_table(self, conn, msg):
+        """Host phases (util/tracing.py phase_table / slow_phases) of this
+        process and, with `workers`, of every live worker (the stack_dump
+        fan-out: partial results, never an error)."""
+        out = {"controller": {"table": tracing.phase_table(),
+                              "slow": tracing.slow_phases()}, "workers": {}}
+        if msg.get("workers"):
+            _, _, out["workers"] = await self._gather_from_workers(
+                "phase_dump", float(msg.get("timeout", 2.0)))
+        return out
 
     # ------------------------------------------------- hang/straggler watchdog
     # Reference failure mode (LlamaRL): at scale the dominant outage is a

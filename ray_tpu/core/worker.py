@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional
 
 import cloudpickle
 
+from ..util import tracing
 from . import context as ctx
 from . import task_events
 from .client import CoreClient
@@ -441,7 +442,9 @@ class WorkerRuntime:
         self.direct_port = self._start_direct_server()
         # Context must be live before registration: the controller may push a
         # task the instant the register request lands.
-        ctx.set_worker_context(ctx.WorkerContext(client=self.client, node_id=node_id, role="worker"))
+        ctx.set_worker_context(ctx.WorkerContext(
+            client=self.client, node_id=node_id, role="worker",
+            extra={"worker_id": self.worker_id}))
         # Apply the runtime env BEFORE registering: the controller may push
         # a task the moment registration lands, and the env (cwd, sys.path,
         # env_vars) must already be in place (the pip venv part was applied
@@ -1088,6 +1091,16 @@ class WorkerRuntime:
                     {"kind": "profile_result", "req_id": msg["req_id"],
                      "worker_id": self.worker_id, "text": text}),
                 daemon=True).start()
+        elif kind == "phase_dump":
+            # This process's host phases for state.phase_table(); same
+            # off-loop reply pattern as stack_dump.
+            text = {"table": tracing.phase_table(),
+                    "slow": tracing.slow_phases()}
+            threading.Thread(
+                target=lambda: self.client.request(
+                    {"kind": "profile_result", "req_id": msg["req_id"],
+                     "worker_id": self.worker_id, "text": text}),
+                daemon=True).start()
         elif kind == "profile":
             # Wall-clock sampling profiler (core/profiler.py): sample this
             # process's threads for the requested duration on a daemon
@@ -1636,10 +1649,12 @@ class WorkerRuntime:
             )
         for value in result:
             oid = ObjectID.generate()
-            loc = put_bytes(value, oid, self.node_id)
-            ack = self.client.request(
-                {"kind": "generator_item", "task_id": task_id, "loc": loc}
-            )
+            with tracing.phase("stream.put"):
+                loc = put_bytes(value, oid, self.node_id)
+            with tracing.phase("stream.report"):
+                ack = self.client.request(
+                    {"kind": "generator_item", "task_id": task_id, "loc": loc}
+                )
             if isinstance(ack, dict) and ack.get("closed"):
                 # Consumer dropped the generator: stop producing.
                 result.close()
@@ -1665,18 +1680,20 @@ class WorkerRuntime:
         loop = mailbox.ensure_aio_loop()
         task_id = spec["task_id"]
 
+        def report(loc):  # on an executor thread: a phase nests per thread
+            with tracing.phase("stream.report"):
+                return self.client.request(
+                    {"kind": "generator_item", "task_id": task_id,
+                     "loc": loc})
+
         async def drive():
             try:
                 async for value in agen:
                     oid = ObjectID.generate()
-                    loc = put_bytes(value, oid, self.node_id)
+                    with tracing.phase("stream.put"):
+                        loc = put_bytes(value, oid, self.node_id)
                     ack = await asyncio.get_running_loop().run_in_executor(
-                        None,
-                        lambda loc=loc: self.client.request(
-                            {"kind": "generator_item", "task_id": task_id,
-                             "loc": loc}
-                        ),
-                    )
+                        None, report, loc)
                     if isinstance(ack, dict) and ack.get("closed"):
                         await agen.aclose()
                         break

@@ -16,6 +16,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.util import tracing
+
 # A logical spec is a tuple of logical axis names (or None), one per dim.
 LogicalSpec = Tuple[Optional[str], ...]
 Rules = Dict[str, Union[str, Tuple[str, ...], None]]
@@ -111,7 +113,8 @@ def shard_batch(mesh: Mesh, batch: Any) -> Any:
         spec: LogicalSpec = ("batch",) + (None,) * (x.ndim - 1)
         return jax.device_put(x, named_sharding(mesh, spec))
 
-    return jax.tree.map(put, batch)
+    with tracing.phase("train.shard_batch"):
+        return jax.tree.map(put, batch)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -54,6 +54,7 @@ import numpy as np
 
 import ray_tpu
 from ray_tpu import flags
+from ray_tpu.util import tracing
 
 from .deployment import deployment
 from .llm import build_streaming_llm_deployment
@@ -348,7 +349,10 @@ def build_disagg_llm_deployment(cfg, params_factory, *, name: str = "llm",
                         status = "deadline"
                         raise DeadlineExceededError(
                             "request deadline passed mid-stream")
-                    toks = self._engine.peek(req)
+                    toks, stamp = self._engine.peek_stamped(req, sent)
+                    if stamp is not None:
+                        tracing.observe("stream.poll_lag", int(
+                            (time.monotonic() - stamp) * 1e9))
                     while sent < len(toks):
                         yield {"token": toks[sent]}
                         sent += 1

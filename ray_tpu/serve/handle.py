@@ -18,6 +18,7 @@ from typing import Any, Dict, List, Optional
 
 import ray_tpu
 from ray_tpu import flags
+from ray_tpu.util import tracing
 from ray_tpu.core.controller import (ActorDiedError, DeadlineExceededError,
                                      GetTimeoutError, TaskCancelledError,
                                      TaskError, WorkerCrashedError)
@@ -181,7 +182,8 @@ class DeploymentStreamingResponse:
             self._release()
             raise
         self._items += 1
-        return ray_tpu.get(ref)
+        with tracing.phase("stream.get"):
+            return ray_tpu.get(ref)
 
     def close(self) -> None:
         """Client walked away (HTTP disconnect / explicit abort): close the

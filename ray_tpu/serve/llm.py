@@ -16,6 +16,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.util import tracing
+
 from . import batching
 from .deployment import deployment
 
@@ -292,7 +294,13 @@ def build_streaming_llm_deployment(cfg, params_factory, *, name: str = "llm-stre
                             status = "deadline"
                             raise DeadlineExceededError(
                                 "request deadline passed mid-stream")
-                        toks = self._engine.peek(req)
+                        toks, stamp = self._engine.peek_stamped(req, sent)
+                        if stamp is not None:
+                            # engine stamp of the oldest token not yet
+                            # yielded -> now: the wait for the engine lock
+                            # and this loop's 5 ms poll.
+                            tracing.observe("stream.poll_lag", int(
+                                (_t.monotonic() - stamp) * 1e9))
                         while sent < len(toks):
                             yield {"token": toks[sent]}
                             sent += 1

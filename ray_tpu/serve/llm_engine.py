@@ -29,6 +29,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.util import tracing
 
 _serve_metrics_cache = None
 
@@ -246,8 +247,9 @@ class ContinuousBatchingEngine:
             jnp.asarray([len(ids)], jnp.int32))
         pad = self.max_len - S
         if pad:
-            k1 = jnp.pad(k1, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v1 = jnp.pad(v1, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            with tracing.phase("engine.prefill.pad", bucket=S):
+                k1 = jnp.pad(k1, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                v1 = jnp.pad(v1, ((0, 0), (0, pad), (0, 0), (0, 0)))
         return logits1, k1, v1
 
     def warmup(self) -> None:
@@ -388,88 +390,113 @@ class ContinuousBatchingEngine:
                        arrival_ts: Optional[float],
                        queue_wait_s: Optional[float] = None,
                        mono0: Optional[float] = None, hop=None) -> int:
-        jnp = self._jnp
         if mono0 is None:
             mono0 = time.monotonic()
-        with self._free_cv:
-            # One monotonic deadline for the whole wait: contended submits
-            # that wake repeatedly must not restart the clock each time.
-            deadline = (None if timeout is None
-                        else time.monotonic() + timeout)
-            self._waiting += 1
+        # engine.attach.wait: the engine lock (held for a whole tick and
+        # retaken at once) and then a free slot; .splice: the rest, under it.
+        with tracing.phase("engine.attach.wait"):
+            self._free_cv.acquire()
             try:
-                while not self._free:
-                    # A dead ticker thread recorded the failure and notified
-                    # this condition; blocking the full timeout (or forever)
-                    # on an engine that will never free a slot helps nobody.
-                    if self.failed is not None:
-                        raise RuntimeError(
-                            f"engine failed: {self.failed!r}")
-                    remaining = (None if deadline is None
-                                 else deadline - time.monotonic())
-                    if remaining is not None and remaining <= 0:
-                        raise TimeoutError("no free generation slot")
-                    self._free_cv.wait(timeout=remaining)
-            finally:
-                self._waiting -= 1
-            if self.failed is not None:
-                raise RuntimeError(f"engine failed: {self.failed!r}")
-            slot = self._free.pop()
-            self._req_seq += 1
-            req = self._req_seq
-            self.slot_req[slot] = req
-            self._req_slot[req] = slot
-            self._done_ev[req] = threading.Event()
-            # First token comes from the prefill logits, decided under the
-            # lock with the slot's sampling config.
-            first = self._pick_host(logits1, temperature)
-            m = _serve_metrics()
-            # Skew-free TTFT: upstream wait is a per-host monotonic
-            # accumulation, local wait (prefill + slot) is this host's
-            # monotonic delta. The epoch arrival_ts path is same-process
-            # only, where wall-clock deltas are safe.
-            local_wait = time.monotonic() - mono0
-            if queue_wait_s is not None:
-                ttft = max(0.0, float(queue_wait_s)) + local_wait
-            elif arrival_ts is not None:
-                ttft = max(0.0, time.time() - float(arrival_ts))
-            else:
-                ttft = local_wait
-            m["ttft"].observe(ttft, tags=self._mtags)
-            m["tokens"].inc(1.0, tags=self._mtags)
-            # Token timeline: stamp the first token on this host's
-            # monotonic clock; tick() appends one stamp per decode token.
-            # Gated on the trace flag so RTPU_SERVE_TRACE=0 keeps the
-            # timeline/ITL/stall plane to a single flag check.
-            from . import trace as serve_trace
+                self._wait_free_locked(timeout)
+            except BaseException:
+                self._free_cv.release()
+                raise
+        try:
+            with tracing.phase("engine.attach.splice"):
+                return self._splice_locked(
+                    k1, v1, length, logits1, max_new_tokens=max_new_tokens,
+                    temperature=temperature, eos_id=eos_id,
+                    arrival_ts=arrival_ts, queue_wait_s=queue_wait_s,
+                    mono0=mono0, hop=hop)
+        finally:
+            self._free_cv.release()
 
-            if serve_trace.enabled():
-                self._token_times[req] = collections.deque(
-                    [time.monotonic()], maxlen=_TOKEN_RING)
-                self._ttft_vals[req] = float(ttft)
-            if hop is not None:
-                hop.attributes.update(
-                    slot=slot, ttft_s=round(float(ttft), 6),
-                    slot_wait_s=round(local_wait, 6))
-            n = min(max_new_tokens or self.max_new, self.max_new)
-            self.active[slot] = True
-            self.budget[slot] = n - 1
-            self.eos[slot] = eos_id
-            self.temp[slot] = temperature
-            self.out[slot] = [int(first)]
-            ck, cv, pos, cur = self._splice(
-                self.cache.k, self.cache.v, self.cache.pos, self.cur_tok,
-                k1, v1, jnp.asarray(length, jnp.int32),
-                jnp.asarray(int(first), jnp.int32), slot)
-            from ray_tpu.models.generate import KVCache
+    def _wait_free_locked(self, timeout: Optional[float]) -> None:
+        # One monotonic deadline for the whole wait: contended submits
+        # that wake repeatedly must not restart the clock each time.
+        deadline = (None if timeout is None
+                    else time.monotonic() + timeout)
+        self._waiting += 1
+        try:
+            while not self._free:
+                # A dead ticker thread recorded the failure and notified
+                # this condition; blocking the full timeout (or forever)
+                # on an engine that will never free a slot helps nobody.
+                if self.failed is not None:
+                    raise RuntimeError(
+                        f"engine failed: {self.failed!r}")
+                remaining = (None if deadline is None
+                             else deadline - time.monotonic())
+                if remaining is not None and remaining <= 0:
+                    raise TimeoutError("no free generation slot")
+                self._free_cv.wait(timeout=remaining)
+        finally:
+            self._waiting -= 1
+        if self.failed is not None:
+            raise RuntimeError(f"engine failed: {self.failed!r}")
 
-            self.cache = KVCache(k=ck, v=cv, pos=pos)
-            self.cur_tok = cur
-            if self.budget[slot] <= 0 or (eos_id is not None
-                                          and int(first) == eos_id):
-                self._retire_locked(slot)
-            m["slots"].set(self.B - len(self._free), tags=self._mtags)
-            return req
+    def _splice_locked(self, k1, v1, length: int, logits1: np.ndarray, *,
+                       max_new_tokens: Optional[int], temperature: float,
+                       eos_id: Optional[int], arrival_ts: Optional[float],
+                       queue_wait_s: Optional[float], mono0: float,
+                       hop) -> int:
+        jnp = self._jnp
+        slot = self._free.pop()
+        self._req_seq += 1
+        req = self._req_seq
+        self.slot_req[slot] = req
+        self._req_slot[req] = slot
+        self._done_ev[req] = threading.Event()
+        # First token comes from the prefill logits, decided under the
+        # lock with the slot's sampling config.
+        first = self._pick_host(logits1, temperature)
+        m = _serve_metrics()
+        # Skew-free TTFT: upstream wait is a per-host monotonic
+        # accumulation, local wait (prefill + slot) is this host's
+        # monotonic delta. The epoch arrival_ts path is same-process
+        # only, where wall-clock deltas are safe.
+        local_wait = time.monotonic() - mono0
+        if queue_wait_s is not None:
+            ttft = max(0.0, float(queue_wait_s)) + local_wait
+        elif arrival_ts is not None:
+            ttft = max(0.0, time.time() - float(arrival_ts))
+        else:
+            ttft = local_wait
+        m["ttft"].observe(ttft, tags=self._mtags)
+        m["tokens"].inc(1.0, tags=self._mtags)
+        # Token timeline: stamp the first token on this host's
+        # monotonic clock; tick() appends one stamp per decode token.
+        # Gated on the trace flag so RTPU_SERVE_TRACE=0 keeps the
+        # timeline/ITL/stall plane to a single flag check.
+        from . import trace as serve_trace
+
+        if serve_trace.enabled():
+            self._token_times[req] = collections.deque(
+                [time.monotonic()], maxlen=_TOKEN_RING)
+            self._ttft_vals[req] = float(ttft)
+        if hop is not None:
+            hop.attributes.update(
+                slot=slot, ttft_s=round(float(ttft), 6),
+                slot_wait_s=round(local_wait, 6))
+        n = min(max_new_tokens or self.max_new, self.max_new)
+        self.active[slot] = True
+        self.budget[slot] = n - 1
+        self.eos[slot] = eos_id
+        self.temp[slot] = temperature
+        self.out[slot] = [int(first)]
+        ck, cv, pos, cur = self._splice(
+            self.cache.k, self.cache.v, self.cache.pos, self.cur_tok,
+            k1, v1, jnp.asarray(length, jnp.int32),
+            jnp.asarray(int(first), jnp.int32), slot)
+        from ray_tpu.models.generate import KVCache
+
+        self.cache = KVCache(k=ck, v=cv, pos=pos)
+        self.cur_tok = cur
+        if self.budget[slot] <= 0 or (eos_id is not None
+                                      and int(first) == eos_id):
+            self._retire_locked(slot)
+        m["slots"].set(self.B - len(self._free), tags=self._mtags)
+        return req
 
     def prefill_only(self, tokens):
         """Run this engine's bucketed prefill WITHOUT taking a slot:
@@ -609,41 +636,59 @@ class ContinuousBatchingEngine:
         advancing; writes clamp harmlessly) — the price of one compiled
         program; a splice fully re-initializes a slot on attach."""
         jax, jnp = self._jax, self._jnp
+        if not any(self.active):  # unlocked look: an idle pass takes no lock
+            return 0
+        # Every phase is entered and folded INSIDE the lock, and the wait
+        # for it is observed after the fact: the loop gives the lock up and
+        # retakes it at once, and bookkeeping between the two would hand it
+        # to waiters more often than an untraced engine does (measured).
+        t_wait = time.monotonic_ns()
         with self.lock:
+            tracing.observe("engine.tick.lock",
+                            time.monotonic_ns() - t_wait, t_wait)
             if not any(self.active):
                 return 0
-            self._draws += 1
-            key = jax.random.fold_in(self._rng, self._draws)
-            temps = jnp.asarray(self.temp)
-            nxt, logits, cache = self._tick(
-                self.params, self.cache, self.cur_tok, key, temps)
-            nxt_host = np.asarray(nxt)
-            self.cache = cache
-            self.cur_tok = nxt
-            emitted = 0
-            now_m = time.monotonic()
-            m_itl = _serve_metrics()["itl"]
-            for s in range(self.B):
-                if not self.active[s]:
-                    continue
-                tok = int(nxt_host[s])
-                self.out[s].append(tok)
-                # Token timeline: one monotonic stamp per emitted token
-                # feeds the ITL histogram and the stream-stall detector.
-                dq = self._token_times.get(self.slot_req[s])
-                if dq is not None:
-                    m_itl.observe(now_m - dq[-1], tags=self._mtags)
-                    dq.append(now_m)
-                emitted += 1
-                self.budget[s] -= 1
-                if self.budget[s] <= 0 or (self.eos[s] is not None
-                                           and tok == self.eos[s]):
-                    self._retire_locked(s)
-            if emitted:
-                m = _serve_metrics()
-                m["tokens"].inc(float(emitted), tags=self._mtags)
-                m["slots"].set(self.B - len(self._free), tags=self._mtags)
+            with tracing.phase("engine.tick", live=sum(self.active),
+                               waiting=self._waiting):
+                with tracing.phase("engine.tick.dispatch"):
+                    self._draws += 1
+                    key = jax.random.fold_in(self._rng, self._draws)
+                    temps = jnp.asarray(self.temp)
+                    nxt, logits, cache = self._tick(
+                        self.params, self.cache, self.cur_tok, key, temps)
+                with tracing.phase("engine.tick.readback"):
+                    nxt_host = np.asarray(nxt)
+                with tracing.phase("engine.tick.emit"):
+                    self.cache = cache
+                    self.cur_tok = nxt
+                    self._emit_locked(nxt_host)
             return sum(self.active)
+
+    def _emit_locked(self, nxt_host: np.ndarray) -> None:
+        """Hand one tick's tokens to their streams: append, stamp, retire."""
+        emitted = 0
+        now_m = time.monotonic()
+        m_itl = _serve_metrics()["itl"]
+        for s in range(self.B):
+            if not self.active[s]:
+                continue
+            tok = int(nxt_host[s])
+            self.out[s].append(tok)
+            # Token timeline: one monotonic stamp per emitted token
+            # feeds the ITL histogram and the stream-stall detector.
+            dq = self._token_times.get(self.slot_req[s])
+            if dq is not None:
+                m_itl.observe(now_m - dq[-1], tags=self._mtags)
+                dq.append(now_m)
+            emitted += 1
+            self.budget[s] -= 1
+            if self.budget[s] <= 0 or (self.eos[s] is not None
+                                       and tok == self.eos[s]):
+                self._retire_locked(s)
+        if emitted:
+            m = _serve_metrics()
+            m["tokens"].inc(float(emitted), tags=self._mtags)
+            m["slots"].set(self.B - len(self._free), tags=self._mtags)
 
     # ------------------------------------------------------------- results
 
@@ -679,7 +724,14 @@ class ContinuousBatchingEngine:
         return self.failed
 
     def peek(self, req: int) -> List[int]:
-        """Tokens emitted so far (streaming consumers poll this).
+        return self.peek_stamped(req)[0]
+
+    def peek_stamped(self, req: int, sent: int = 0):
+        """Tokens emitted so far (streaming consumers poll this), and the
+        engine's stamp (CLOCK_MONOTONIC) of token number ``sent``, the
+        oldest one a consumer that has taken ``sent`` tokens has not seen
+        (None when there is none, the token timeline is off, or the request
+        has finished).
 
         The stream-stall detector lives here rather than in the ticker:
         a hung tick thread (the main way a stream stalls) can't run its
@@ -690,12 +742,15 @@ class ContinuousBatchingEngine:
         with self.lock:
             done = self._results.get(req)
             if done is not None:
-                return list(done)
+                return list(done), None
             slot = self._req_slot.get(req)
             if slot is None:
                 raise KeyError(f"unknown request {req}")
             out = list(self.out[slot])
             dq = self._token_times.get(req)
+            stamp = None
+            if dq and sent < len(out):  # the ring holds the last stamps
+                stamp = dq[max(0, len(dq) - (len(out) - sent))]
             if dq is not None and req not in self._stall_flagged:
                 from ray_tpu import flags
 
@@ -707,7 +762,7 @@ class ContinuousBatchingEngine:
                         stalled_age = age
         if stalled_age is not None:
             self._emit_stall(req, stalled_age)
-        return out
+        return out, stamp
 
     def _emit_stall(self, req: int, age_s: float) -> None:
         """Ship the STREAM_STALLED cluster event (outside the engine lock:
@@ -791,4 +846,5 @@ class ContinuousBatchingEngine:
                     self._free_cv.notify_all()
                 return
             if n == 0:
-                time.sleep(idle_sleep)
+                with tracing.phase("engine.idle"):
+                    time.sleep(idle_sleep)

@@ -12,10 +12,12 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Any, Dict, Optional
 
 import ray_tpu
 from ray_tpu import flags
+from ray_tpu.util import tracing
 from ray_tpu.core.controller import DeadlineExceededError
 
 from . import trace
@@ -217,7 +219,9 @@ class HTTPProxy:
                     data = item.encode()
                 else:
                     data = (json.dumps(item) + "\n").encode()
-                await resp.write(data)
+                t0 = time.monotonic_ns()  # across an await: observed,
+                await resp.write(data)    # not a per-thread phase
+                tracing.observe("proxy.write", time.monotonic_ns() - t0, t0)
                 items += 1
         finally:
             # Reached on normal end AND on client disconnect (aiohttp

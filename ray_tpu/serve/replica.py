@@ -164,6 +164,31 @@ class ReplicaActor:
                 pass
         return out
 
+    def profile(self, seconds: float, logdir: str) -> Dict[str, Any]:
+        """Run jax.profiler for ``seconds`` in this process, the one that
+        owns the replica's chips (`rtpu serve profile`; the train-worker
+        analog is session.profile). Requests keep being served meanwhile;
+        the trace holds the device ops beside the host phases of
+        util/tracing.py (engine.tick, stream.report, ...) and one
+        clock_anchor. Returns the xplane files written under ``logdir``."""
+        import glob
+        import os
+
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0   # the phases are the host's story
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(logdir, profiler_options=opts)
+        try:
+            time.sleep(max(0.0, float(seconds)))
+        finally:
+            jax.profiler.stop_trace()
+        return {"pid": os.getpid(), "logdir": logdir,
+                "seconds": float(seconds),
+                "xplane": sorted(glob.glob(os.path.join(
+                    logdir, "**", "*.xplane.pb"), recursive=True))}
+
     def check_health(self) -> bool:
         user_check = getattr(self._callable, "check_health", None)
         if callable(user_check):

@@ -33,6 +33,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from ray_tpu import flags
+from ray_tpu.util import tracing
 
 from . import context as serve_context
 
@@ -159,26 +160,6 @@ def flush_serve_trace(timeout: float = 30.0) -> bool:
 
 def _ship_span(d: Dict[str, Any]) -> None:
     _shipper.add(span=d)
-    # With the generic tracing plane on, serve hops also land in the
-    # per-process finished-span record so get_cluster_spans(trace_id)
-    # merges them with task spans sharing the same traceparent.
-    try:
-        from ray_tpu.util import tracing
-
-        if tracing.enabled():
-            sp = tracing.Span(
-                name=d["name"],
-                context=tracing.SpanContext(d["trace_id"], d["span_id"]),
-                parent_span_id=d.get("parent_span_id", ""),
-                kind=d.get("kind", "internal"),
-                attributes=dict(d.get("attributes") or {}),
-                start_time=d["start_ts"])
-            sp.end_time = d["start_ts"] + d.get("dwell_s", 0.0)
-            with tracing._finished_lock:
-                tracing._finished.append(sp)
-                del tracing._finished[:-4096]
-    except Exception:
-        pass
 
 
 # ------------------------------------------------------------------ metrics
@@ -257,13 +238,18 @@ class Hop:
             self._ctx["parent_span_id"] = self._prev_parent
         if attrs:
             self.attributes.update(attrs)
+        dwell = max(0.0, time.monotonic() - self._mono0)
+        # The same hop as a host phase (util/tracing.py): the per-process
+        # table, and in a profiler session an annotation beside the device.
+        tracing.observe(self.name, int(dwell * 1e9), int(self._mono0 * 1e9),
+                        slow=False, request_id=self.request_id)
         _ship_span({
             "name": self.name, "kind": self.kind,
             "trace_id": self.trace_id, "span_id": self.span_id,
             "parent_span_id": self.parent_span_id or "",
             "request_id": self.request_id, "deployment": self.deployment,
             "start_ts": self.start_ts,
-            "dwell_s": max(0.0, time.monotonic() - self._mono0),
+            "dwell_s": dwell,
             "attributes": self.attributes,
         })
 

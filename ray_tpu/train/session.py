@@ -12,6 +12,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
+from ray_tpu.util import tracing
+
 from .checkpoint import Checkpoint
 
 
@@ -95,13 +97,14 @@ def report(metrics: Dict[str, Any], *, checkpoint: Optional[Checkpoint] = None) 
     optionally a checkpoint) to the driver."""
     s = _get_session()
     s.iteration += 1
-    s.results.put({
-        "type": "report",
-        "metrics": dict(metrics),
-        "checkpoint": checkpoint,
-        "iteration": s.iteration,
-        "rank": s.context.world_rank,
-    })
+    with tracing.phase("train.report"):
+        s.results.put({
+            "type": "report",
+            "metrics": dict(metrics),
+            "checkpoint": checkpoint,
+            "iteration": s.iteration,
+            "rank": s.context.world_rank,
+        })
     if s.stop_requested:
         raise StopIteration("training stop requested by the driver")
 

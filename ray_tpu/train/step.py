@@ -20,6 +20,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel import sharding as shd
+from ray_tpu.util import tracing
 
 
 class ShardedTrainStep:
@@ -81,7 +82,8 @@ class ShardedTrainStep:
         return shd.shard_batch(self.mesh, batch)
 
     def step(self, params, opt_state, batch) -> Tuple[Any, Any, jax.Array]:
-        return self._jit_step(params, opt_state, batch)
+        with tracing.phase("train.step"):  # the host side: the enqueue
+            return self._jit_step(params, opt_state, batch)
 
     def eval_loss(self, params, batch) -> jax.Array:
         return self._jit_eval(params, batch)

@@ -94,6 +94,31 @@ def profile_workers(timeout: float = 2.0) -> Dict[str, Any]:
     return _req({"kind": "profile_workers", "timeout": timeout})
 
 
+def phase_table(workers: bool = True,
+                timeout: float = 2.0) -> Dict[str, Any]:
+    """Host phases (util/tracing.py): {"controller": {"table", "slow"},
+    "workers": {worker_id: {"table", "slow"}}}. ``table`` is
+    ``tracing.phase_table()`` of that process (name -> count, total_ns,
+    max_ns, log2 buckets), ``slow`` its ``tracing.slow_phases()`` (phases of
+    50 ms or more with their start on CLOCK_MONOTONIC). The controller's
+    slow list also holds what workers reported as SLOW_PHASE events."""
+    return _req({"kind": "phase_table", "workers": workers,
+                 "timeout": timeout})
+
+
+def slow_phases(timeout: float = 2.0) -> List[Dict[str, Any]]:
+    """Every process's slow phases, oldest first, each with its
+    ``process`` ("controller" or a worker id)."""
+    r = phase_table(True, timeout)
+    rows = [dict(p, process=wid) for wid, w in r["workers"].items()
+            for p in w.get("slow", ())]
+    seen = {(p["name"], p["start_monotonic_ns"]) for p in rows}
+    # the controller's ring also holds what workers reported: once is enough
+    rows += [dict(p, process="controller") for p in r["controller"]["slow"]
+             if (p["name"], p["start_monotonic_ns"]) not in seen]
+    return sorted(rows, key=lambda p: p["start_monotonic_ns"])
+
+
 def profile(duration: float = 2.0, *,
             task_id: Optional[str] = None,
             actor_id: Optional[str] = None,

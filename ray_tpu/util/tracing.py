@@ -16,15 +16,25 @@ recorder on (``RTPU_TASK_EVENTS``), cluster-wide via
 ``get_cluster_spans()``: workers ship their finished spans to the
 controller alongside task phase events.
 
-Everything is gated on ``RTPU_TRACING`` (set by ``setup_tracing``; worker
-processes inherit it through the spawn env): when off, submission pays one
-flag check and nothing else.
+Everything above is gated on ``RTPU_TRACING`` (set by ``setup_tracing``;
+worker processes inherit it through the spawn env): when off, submission
+pays one flag check and nothing else.
+
+Host phases (``phase`` / ``observe`` / ``steps``, always on) are the other
+half: named stretches of host work stamped on CLOCK_MONOTONIC, folded into a
+per-process table and, in a process that has imported jax, emitted as
+``jax.profiler.TraceAnnotation`` so a profiler session shows them beside the
+device ops. See the section at the end of this module.
 """
 from __future__ import annotations
 
+import collections
+import os
 import secrets
+import sys
 import threading
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -278,3 +288,271 @@ class task_span:
             except Exception:
                 pass
         return False
+
+
+# ---------------------------------------------------------------- host phases
+# One primitive for "what was the host doing": the engine loop, the token
+# relay, the controller's handlers and periodic bodies, the train step's host
+# side. No flag and no shipping: a phase costs two clock reads, a TraceMe
+# (a no-op outside a profiler session) and one table update.
+
+SLOW_NS = 50_000_000      # phases at least this long go to the slow ring
+SLOW_RING = 1024          # entries kept
+_N_BUCKETS = 40           # log2(ns) buckets: bucket b holds [2^(b-1), 2^b)
+_SLOW_EVENTS_PER_10S = 32  # cluster events a process may emit for slow phases
+
+_phase_lock = threading.Lock()   # guards the registry below, not the folding
+_phase_local = threading.local()  # .table: this thread's name -> row
+_tables: List[tuple] = []         # (weakref to thread, its table), live threads
+_retired: Dict[str, list] = {}    # rows of threads that have ended
+_slow: "collections.deque" = collections.deque(maxlen=SLOW_RING)
+_anchored = False         # a clock_anchor was emitted in the current session
+_slow_event_budget = [float(_SLOW_EVENTS_PER_10S), 0.0]  # tokens, refilled at
+
+
+def _annotation():
+    """jax.profiler.TraceAnnotation if this process has imported jax, else
+    None. Never imports it: a runner, controller or proxy process must stay
+    without a JAX backend."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation
+    except AttributeError:  # jax is mid-import on another thread
+        return None
+
+
+def _anchor(ann) -> bool:
+    """Whether a profiler session is on; once per session it gets an
+    annotation carrying this instant on CLOCK_MONOTONIC and on the wall
+    clock, so a reader can lay stamps taken by other processes of the
+    machine on the trace's clock."""
+    global _anchored
+    if not ann.is_enabled():
+        _anchored = False
+        return False
+    if not _anchored:
+        _anchored = True
+        with ann("clock_anchor", monotonic_ns=time.monotonic_ns(),
+                 time_ns=time.time_ns()):
+            pass
+    return True
+
+
+def _merge(into: Dict[str, list], table: Dict[str, list]) -> None:
+    for name, row in list(table.items()):
+        acc = into.get(name)
+        if acc is None:
+            acc = into[name] = [0, 0, 0, [0] * _N_BUCKETS]
+        acc[0] += row[0]
+        acc[1] += row[1]
+        acc[2] = max(acc[2], row[2])
+        acc[3] = [a + b for a, b in zip(acc[3], row[3])]
+
+
+def _thread_table() -> Dict[str, list]:
+    """This thread's own table, so folding takes no lock (seventeen threads
+    of a replica fold a few thousand phases a second). A new thread's first
+    phase registers it and retires the rows of threads that have ended."""
+    table: Dict[str, list] = {}
+    _phase_local.table = table
+    with _phase_lock:
+        live = []
+        for ref, t in _tables:
+            th = ref()
+            if th is not None and th.is_alive():
+                live.append((ref, t))
+            else:
+                _merge(_retired, t)
+        live.append((weakref.ref(threading.current_thread()), table))
+        _tables[:] = live
+    return table
+
+
+def _fold(name: str, start_ns: int, dur_ns: int,
+          attrs: Optional[Dict[str, Any]], slow: bool = True) -> None:
+    dur_ns = max(0, int(dur_ns))
+    try:
+        table = _phase_local.table
+    except AttributeError:
+        table = _thread_table()
+    row = table.get(name)
+    if row is None:
+        row = table[name] = [0, 0, 0, [0] * _N_BUCKETS]
+    row[0] += 1
+    row[1] += dur_ns
+    if dur_ns > row[2]:
+        row[2] = dur_ns
+    row[3][min(dur_ns.bit_length(), _N_BUCKETS - 1)] += 1
+    if dur_ns >= SLOW_NS and slow:
+        _slow.append((name, int(start_ns), dur_ns, dict(attrs or {})))
+        _slow_event(name, int(start_ns), dur_ns, attrs)
+
+
+def _slow_event(name: str, start_ns: int, dur_ns: int, attrs) -> None:
+    """A slow phase of a worker process also becomes a cluster event (rare by
+    construction, and rate-limited here), which is how `rtpu events` and the
+    controller's process see a worker's stalls. The controller's own process
+    already holds its phases in this table."""
+    if name.startswith("ctrl."):
+        return
+    try:
+        from ray_tpu.core import context as ctx
+        from ray_tpu.core import events
+
+        if not (ctx.is_initialized() and events.enabled()
+                and ctx.get_worker_context().role == "worker"):
+            return  # a driver's phases stay in its own table
+        now = time.monotonic()
+        with _phase_lock:
+            tokens, at = _slow_event_budget
+            tokens = min(float(_SLOW_EVENTS_PER_10S), tokens + (now - at)
+                         * _SLOW_EVENTS_PER_10S / 10.0)
+            if tokens < 1.0:
+                _slow_event_budget[:] = [tokens, now]
+                return
+            _slow_event_budget[:] = [tokens - 1.0, now]
+        events.emit(
+            "INFO", "SLOW_PHASE",
+            f"{name} took {dur_ns / 1e6:.1f} ms", source="tracing",
+            worker_id=ctx.get_worker_context().extra.get("worker_id"),
+            data={"name": name, "start_monotonic_ns": start_ns,
+                  "dur_ns": dur_ns, "pid": os.getpid(),
+                  "attrs": {k: v for k, v in (attrs or {}).items()
+                            if isinstance(v, (int, float, str, bool))}})
+    except Exception:
+        pass  # a phase must never break the work it times
+
+
+def ingest_slow_event(ev: Dict[str, Any]) -> None:
+    """Controller side: a SLOW_PHASE cluster event from another process is
+    copied into this process's slow ring (tagged with its pid), so a reader
+    in the controller's process sees the cluster's stalls after shutdown."""
+    d = ev.get("data") or {}
+    if d.get("pid") == os.getpid() or "name" not in d:
+        return
+    attrs = dict(d.get("attrs") or {}, pid=d.get("pid"),
+                 worker_id=ev.get("worker_id"))
+    _slow.append((d["name"], int(d.get("start_monotonic_ns", 0)),
+                  int(d.get("dur_ns", 0)), attrs))
+
+
+class phase:
+    """``with tracing.phase("engine.tick", live=3): ...`` — one named stretch
+    of host work on the calling thread. Must exit on the thread it entered
+    (a profiler annotation nests per thread); for time measured across
+    threads or awaits use ``observe``."""
+
+    __slots__ = ("name", "attrs", "slow", "_t0", "_ann")
+
+    def __init__(self, name: str, slow: bool = True, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+        self.slow = slow  # False: a wait by design, kept out of the slow ring
+
+    def __enter__(self) -> "phase":
+        ann = _annotation()
+        if ann is not None:
+            _anchor(ann)
+            self._ann = ann(self.name, **self.attrs)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        dur = time.monotonic_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        _fold(self.name, self._t0, dur, self.attrs, self.slow)
+        return False
+
+
+def observe(name: str, ns: int, start_ns: Optional[int] = None,
+            slow: bool = True, **attrs: Any) -> None:
+    """Record a duration measured elsewhere (``ns``, ending now unless
+    ``start_ns`` on CLOCK_MONOTONIC is given). In a profiler session it
+    appears as an instant annotation carrying ``dur_ns`` and
+    ``start_monotonic_ns``, from which a reader rebuilds the interval.
+    ``slow=False`` keeps it out of the slow ring (a request-long hop is
+    long by nature, not a stall)."""
+    ns = max(0, int(ns))
+    if start_ns is None:
+        start_ns = time.monotonic_ns() - ns
+    ann = _annotation()
+    if ann is not None and _anchor(ann):
+        with ann(name, dur_ns=ns, start_monotonic_ns=int(start_ns), **attrs):
+            pass
+    _fold(name, start_ns, ns, attrs, slow)
+
+
+class steps:
+    """``await tracing.steps(name, coro)``: run a coroutine on its event loop
+    as usual and observe every stretch it holds the loop (resumption to next
+    suspension) under ``name``. Awaited time is not counted: a long-poll
+    handler is many short steps, a handler that blocks the loop is one long
+    one."""
+
+    __slots__ = ("name", "coro")
+
+    def __init__(self, name: str, coro):
+        self.name = name
+        self.coro = coro
+
+    def __await__(self):
+        send, throw, name = self.coro.send, self.coro.throw, self.name
+        val: Any = None
+        exc: Optional[BaseException] = None
+        while True:
+            t0 = time.monotonic_ns()
+            try:
+                out = send(val) if exc is None else throw(exc)
+            except StopIteration as stop:
+                return stop.value
+            finally:  # a step that returns, raises or suspends: all counted
+                _fold(name, t0, time.monotonic_ns() - t0, None)
+            try:
+                val, exc = (yield out), None
+            except GeneratorExit:
+                self.coro.close()
+                raise
+            except BaseException as e:
+                val, exc = None, e
+
+
+def phase_table() -> Dict[str, Dict[str, Any]]:
+    """This process's phases since it started (``ray_tpu.shutdown()`` does
+    not clear them): name -> count, total_ns, max_ns and ``buckets``, where
+    bucket b counts durations in [2^(b-1), 2^b) ns."""
+    merged: Dict[str, list] = {}
+    with _phase_lock:
+        _merge(merged, _retired)
+        for _, table in _tables:
+            _merge(merged, table)
+    return {n: {"count": r[0], "total_ns": r[1], "max_ns": r[2],
+                "buckets": r[3]} for n, r in merged.items()}
+
+
+def slow_phases() -> List[Dict[str, Any]]:
+    """The last ``SLOW_RING`` phases of ``SLOW_NS`` or more, oldest first:
+    this process's, and in the controller's process also those other
+    processes reported (``attrs`` then carries their ``pid``)."""
+    rows = list(_slow)
+    return [{"name": n, "start_monotonic_ns": s, "dur_ns": d, "attrs": a}
+            for n, s, d, a in rows]
+
+
+def bucket_quantile(buckets: List[int], q: float) -> Optional[float]:
+    """Approximate quantile (ns) of a ``phase_table`` row's buckets: the
+    geometric middle of the bucket the q-th observation falls in."""
+    total = sum(buckets)
+    if not total:
+        return None
+    want, seen = q * total, 0
+    for b, c in enumerate(buckets):
+        seen += c
+        if c and seen >= want:
+            return 0.0 if b == 0 else 2.0 ** (b - 0.5)
+    return 2.0 ** (len(buckets) - 1.5)
