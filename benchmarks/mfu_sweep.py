@@ -84,11 +84,9 @@ if __name__ == "__main__":
     variants = [
         (True, "full", 8, 1024, True),
         (True, "dots", 8, 1024, True),        # the default policy
-        (True, "dots_attn", 8, 1024, True),   # + no flash-fwd recompute
         (True, "min", 8, 1024, True),
         (False, None, 8, 1024, True),         # no remat
         (True, "dots", 16, 1024, True),       # bigger matmul M
-        (True, "dots_attn", 16, 1024, True),
     ]
     for remat, policy, batch, seq, shift in variants:
         try:

@@ -50,22 +50,21 @@ class TransformerConfig:
     remat: bool = False
     # Remat granularity when remat=True:
     # - "full": recompute the whole layer body in the backward (max memory
-    #   saving, ~33% extra FLOPs).
-    # - "dots": save matmul outputs, recompute elementwise/norm work — BUT
-    #   also recomputes the flash-attention forward (a Pallas custom call is
-    #   not a dot), which dominates at long sequence lengths.
-    # - "dots_attn": "dots" plus the attention output (tagged "attn_out") —
-    #   the backward no longer re-runs the flash forward kernel (a Pallas
-    #   custom call is not a dot, so plain "dots" recomputes it).
-    #   One extra [B,S,H*hd] bf16 residual per layer.
+    #   saving, ~33% extra FLOPs, flash forward kernel included).
+    # - "dots": save matmul outputs and the flash kernel's own residuals
+    #   (o [B,H,S,hd] and lse [B,H,S], named inside its forward rule,
+    #   ops/flash_attention.py RESIDUAL_NAMES); recompute elementwise/norm
+    #   work. The forward kernel runs once a layer.
+    # - "half_dots" / "half_full": the first half of the stack under
+    #   "dots" / "full", the second half without remat.
     # - "min": save everything except the two fat fused-projection outputs
     #   (qkv and gate_up, tagged via checkpoint_name below) — flash
     #   residuals stay saved, recompute is one einsum + elementwise. The
     #   cheapest policy that still bounds activation memory.
-    # Default "dots": at the bench shape (bench_350m, batch 8 x 1024, one
-    # 16 GB v5e chip) it is the fastest policy that fits — 284 ms/step vs
-    # dots_attn 286 and full 307, while "min" and no remat exceed HBM at
-    # compile (benchmarks/mfu_sweep.py, chip run PR 21).
+    # Default "dots": keeping o and lse takes the second forward-kernel call
+    # out of every layer's backward (gpt2_124m, batch 16 x 1024, one v5e
+    # chip: step 184.2 -> 179.5 ms, step memory 13.96 -> 15.19 GB; chip
+    # runs of PR 26, PERF.md section 6).
     remat_policy: str = "dots"
     # Mixture-of-Experts MLP (ops/moe.py, GShard capacity-based top-k):
     # 0 = dense. The expert dim shards over the `expert` mesh axis.
@@ -331,7 +330,7 @@ def _layer_body(cfg: TransformerConfig, x: jax.Array, layer: Params,
     h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm)
     q, k, v = _qkv_proj(cfg, h, layer, positions)
     q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
-    o = checkpoint_name(attention(q, k, v, causal=True), "attn_out")
+    o = attention(q, k, v, causal=True)
     x = x + o.reshape(B, S, H * hd) @ _w(layer, "wo", cfg)
     x = maybe_constrain(x, ("batch", "seq_act", "embed"))
 
@@ -378,16 +377,14 @@ def layer_scan_body(cfg: TransformerConfig, positions: jax.Array):
     body = lambda carry, layer: _layer_body(cfg, carry, layer, positions)
     if cfg.remat:
         if cfg.remat_policy == "dots":
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            )
-        elif cfg.remat_policy == "dots_attn":
+            from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
             body = jax.checkpoint(
                 body,
                 policy=jax.checkpoint_policies.save_from_both_policies(
                     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                    jax.checkpoint_policies.save_only_these_names("attn_out"),
+                    jax.checkpoint_policies.save_only_these_names(
+                        *RESIDUAL_NAMES),
                 ),
             )
         elif cfg.remat_policy == "min":

@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -266,6 +267,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd(res, g, scale, causal, block_q, block_k):
     q, k, v, o, lse = res
+    lse = lse[..., None]  # [B, H, S, 1], the kernels' block shape
     do = g.astype(q.dtype)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)  # [B, H, S, 1]
@@ -363,8 +365,20 @@ def _flash(q, k, v, scale, causal, block_q, block_k):
     return o
 
 
+# The forward kernel's two outputs, named inside the forward rule so that a
+# remat policy can keep them (`save_only_these_names(*RESIDUAL_NAMES)`): the
+# backward rule reads exactly these arrays, and a pallas_call is not a dot,
+# so a dots-only policy would run the kernel again to get them back.
+RESIDUAL_NAMES = ("flash_o", "flash_lse")
+
+
 def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k):
     o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    # Saved as [B,H,S], S on the lanes: a float32 [...,S,1] tiled (8,128)
+    # as it stands stores 128 lanes for each one used (the v5e compiler
+    # re-lays a saved stack S-minor by itself; this does not rest on that).
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
