@@ -110,3 +110,42 @@ def moe_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def kimi_linear_tiny(**overrides) -> TransformerConfig:
+    """A mixed stack in the Kimi-Linear pattern at widths small enough for
+    CPU tests (docs/model_layers.md): layers 4, 8, ... are MLA and the rest
+    KDA (three to one), the first layer keeps a dense SwiGLU and the others
+    have 16 sigmoid-routed experts, 4 a token, one shared. Published sizes
+    live in chipbench/configs/ only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=5,
+        n_heads=4,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-5,
+        activation="swiglu",
+        positional="none",
+        tie_embeddings=False,
+        kda_layers=(1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21,
+                    22, 23, 25, 26),
+        mla_layers=(4, 8, 12, 16, 20, 24, 27),
+        kda_head_dim=16,
+        kda_chunk=16,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        moe_num_experts=16,
+        moe_experts_per_token=4,
+        moe_router="sigmoid",
+        moe_d_ff=32,
+        moe_shared_experts=1,
+        moe_routed_scale=2.446,
+        moe_first_dense=1,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

@@ -60,6 +60,11 @@ def prefill(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     BEFORE the attention einsum runs (the valid mask is slot <= pos[i],
     which includes the just-written slot — ordering of _write before
     attend in decode_step's body is load-bearing)."""
+    if cfg.mixed:
+        raise NotImplementedError(
+            "decode holds keys and values only: a stack with KDA / MLA "
+            "layers or held experts (cfg.mixed) trains but does not serve "
+            "yet (ROADMAP R7)")
     B, S = tokens.shape
     if S > max_len:
         raise ValueError(f"prompt length {S} exceeds cache max_len {max_len}")
@@ -135,7 +140,8 @@ def decode_step(params: Params, cache: KVCache, token: jax.Array,
 
     def body(x, xs):
         layer, ck, cv = xs  # ck/cv: [B, max_len, KVH, hd]
-        h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm)
+        h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
+                  cfg.norm_eps)
         q, k, v = _qkv_proj(cfg, h, layer, positions)
         ck = _write(ck, k)
         cv = _write(cv, v)
@@ -151,7 +157,8 @@ def decode_step(params: Params, cache: KVCache, token: jax.Array,
         o = o.reshape(B, 1, H * hd)
         x = x + o @ _w(layer, "wo", cfg)
 
-        h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm)
+        h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
+                  cfg.norm_eps)
         delta, _aux = _mlp_block(cfg, h, layer)
         x = x + delta
         return x, (ck, cv)

@@ -17,6 +17,7 @@ it hosts torch):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -42,7 +43,7 @@ class TransformerConfig:
     max_seq_len: int = 2048
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     activation: str = "swiglu"  # swiglu | gelu
-    positional: str = "rope"  # rope | learned
+    positional: str = "rope"  # rope | learned | none (KDA / NoPE-MLA stacks)
     rope_theta: float = 500000.0
     tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16
@@ -66,20 +67,146 @@ class TransformerConfig:
     # chip: step 184.2 -> 179.5 ms, step memory 13.96 -> 15.19 GB; chip
     # runs of PR 26, PERF.md section 6).
     remat_policy: str = "dots"
-    # Mixture-of-Experts MLP (ops/moe.py, GShard capacity-based top-k):
-    # 0 = dense. The expert dim shards over the `expert` mesh axis.
+    # RMSNorm / LayerNorm epsilon; None = 1e-6 / 1e-5 (what was hard-coded).
+    norm_eps: Optional[float] = None
+    # Mixture-of-Experts MLP (ops/moe.py): 0 = dense; else the number of
+    # experts the router scores. The expert dim shards over the `expert`
+    # mesh axis. Two routings (`moe_router`):
+    # - "softmax_capacity" (GShard): softmax gates, top-k renormalised, a
+    #   capacity a routing group (`moe_capacity_factor`) past which tokens
+    #   are DROPPED, every expert held, aux loss `moe_aux_coef`. Every layer
+    #   is an expert layer.
+    # - "sigmoid" (DeepSeek-V3 / Kimi): sigmoid scores, top-k of score +
+    #   correction bias, renormalised, times `moe_routed_scale`; NOTHING is
+    #   dropped, no aux loss; `moe_shared_experts` always-on experts beside
+    #   the routed ones; layers before `moe_first_dense` (0-based count) keep
+    #   the dense MLP of `d_ff`; experts are `moe_d_ff` wide. `moe_held` =
+    #   (first, count) is the contiguous range of experts THIS program holds
+    #   (None = all): it routes over all `moe_num_experts`, computes its own
+    #   experts' part and leaves the rest out (one expert-parallel rank).
+    #   Gathered assignments are worked in windows of a few times the held
+    #   experts' even share (ops/moe.py `HELD_WINDOW_FACTOR`), as many as the
+    #   routing needs; what falls past the first is counted: nothing is
+    #   dropped.
     moe_num_experts: int = 0
     moe_experts_per_token: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01
+    moe_router: str = "softmax_capacity"
+    moe_held: Optional[Tuple[int, int]] = None
+    moe_d_ff: Optional[int] = None
+    moe_shared_experts: int = 0
+    moe_routed_scale: float = 1.0
+    moe_first_dense: int = 0
+    # What each layer is (docs/model_layers.md). Layer numbers are 1-based,
+    # as published configs list them; numbers past n_layers are ignored, so
+    # a cut in depth keeps the published lists. A layer in neither list has
+    # the softmax attention above ("attn").
+    # - kda_layers: Kimi Delta Attention (ops/kda.py), `kda_heads` heads of
+    #   `kda_head_dim`, a causal depthwise convolution of `kda_conv`, gate
+    #   projections of rank `kda_gate_rank`, chunks of `kda_chunk` tokens.
+    # - mla_layers: multi-head latent attention without rotation
+    #   (`kv_lora_rank` latent + `qk_rope_head_dim` shared key part, keys
+    #   `qk_nope_head_dim` + `qk_rope_head_dim` wide, values `v_head_dim`).
+    kda_layers: Tuple[int, ...] = ()
+    mla_layers: Tuple[int, ...] = ()
+    kda_heads: Optional[int] = None       # None => n_heads
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_gate_rank: Optional[int] = None   # None => kda_head_dim
+    kda_chunk: int = 128
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
     # Chunked fused lm-head+CE (ops/fused_ce.py): never materializes the
     # [B*S, V] logits/dlogits tensors (~1GB each way at bench shapes) —
     # vocab chunks stream through online logsumexp fwd / recompute bwd.
     fused_ce: bool = False
 
+    def __post_init__(self):
+        for name in ("kda_layers", "mla_layers", "moe_held"):
+            v = getattr(self, name)
+            if isinstance(v, list):  # from a JSON file
+                object.__setattr__(self, name, tuple(v))
+        if self.moe_router not in ("softmax_capacity", "sigmoid"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if set(self.kda_layers) & set(self.mla_layers):
+            raise ValueError("a layer is listed as both kda and mla")
+        if self.mixed and self.moe_num_experts and self.moe_router != "sigmoid":
+            raise ValueError(
+                "kda / mla layers compose with moe_router='sigmoid' only")
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def mixed(self) -> bool:
+        """Whether the stack has layers of more than the one classic kind
+        (softmax attention + dense or GShard MLP): its parameters are then a
+        list of segments (`stack_plan`), not one stacked tree."""
+        L = self.n_layers
+        return (any(l <= L for l in self.kda_layers + self.mla_layers)
+                or (self.moe_num_experts > 0 and self.moe_router == "sigmoid"))
+
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, feed-forward) of every layer: mixer attn | mla | kda,
+        feed-forward dense | moe."""
+        sig = self.moe_num_experts > 0 and self.moe_router == "sigmoid"
+        out = []
+        for l in range(self.n_layers):
+            mixer = ("kda" if l + 1 in self.kda_layers else
+                     "mla" if l + 1 in self.mla_layers else "attn")
+            if sig:
+                ffn = "moe" if l >= self.moe_first_dense else "dense"
+            else:
+                ffn = "moe" if self.moe_num_experts else "dense"
+            out.append((mixer, ffn))
+        return tuple(out)
+
+    def stack_plan(self) -> Tuple[Tuple[Tuple[Tuple[str, str], ...], int], ...]:
+        """The stack as segments (pattern of layer kinds, repeats): greedily,
+        from each layer on, the period (up to 8) whose repeats cover the
+        most layers; a layer that starts no repeat is a segment of its own.
+        Each segment is one `lax.scan` over its repeats, so compile time
+        follows the number of segments and not the depth."""
+        kinds, plan, i = self.layer_kinds(), [], 0
+        while i < len(kinds):
+            best = (1, 1)
+            for p in range(1, 9):
+                r = 1
+                while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                    r += 1
+                if r >= 2 and p * r > best[0] * best[1]:
+                    best = (p, r)
+            p, r = best
+            plan.append((kinds[i:i + p], r))
+            i += p * r
+        return tuple(plan)
+
+    def layer_slot(self, l: int) -> Tuple[int, int, int]:
+        """Where layer l (0-based) lives in a mixed stack's parameters:
+        params["layers"][segment][position], row `repeat` of each leaf."""
+        i = 0
+        for seg, (pattern, r) in enumerate(self.stack_plan()):
+            n = len(pattern) * r
+            if l < i + n:
+                return seg, (l - i) % len(pattern), (l - i) // len(pattern)
+            i += n
+        raise IndexError(l)
+
+    @property
+    def kda_n_heads(self) -> int:
+        return self.kda_heads or self.n_heads
+
+    @property
+    def moe_ff_dim(self) -> int:
+        return self.moe_d_ff or self.ff_dim
+
+    @property
+    def moe_held_range(self) -> Tuple[int, int]:
+        return self.moe_held or (0, self.moe_num_experts)
 
     @property
     def head_dim(self) -> int:
@@ -95,39 +222,87 @@ class TransformerConfig:
             return (d + 127) // 128 * 128
         return 4 * self.d_model
 
+    def _mixer_params(self, mixer: str) -> int:
+        d, H = self.d_model, self.n_heads
+        if mixer == "attn":
+            h = self.head_dim
+            return d * H * h + 2 * d * self.kv_heads * h + H * h * d
+        if mixer == "mla":
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            lat = self.kv_lora_rank
+            return (d * (lat + self.qk_rope_head_dim) + lat
+                    + lat * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + d * H * qk + H * self.v_head_dim * d)
+        Hk, hd = self.kda_n_heads, self.kda_head_dim
+        rank = self.kda_gate_rank or hd
+        return (4 * d * Hk * hd + 3 * self.kda_conv * Hk * hd   # q k v o, convs
+                + 2 * (d * rank + rank * Hk * hd)                # decay, gate
+                + Hk + Hk * hd + d * Hk + hd)       # A_log dt_bias beta norm
+
+    def _ffn_params(self, ffn: str, active: bool = False) -> float:
+        d = self.d_model
+        if ffn == "dense":
+            return (3 if self.activation == "swiglu" else 2) * d * self.ff_dim
+        E, F = self.moe_num_experts, self.moe_ff_dim
+        if self.moe_router != "sigmoid":
+            n = self.moe_experts_per_token if active else E
+            return n * 3 * d * F + d * E
+        held = self.moe_held_range[1]
+        # Per token and under even routing, k * held / E of the held experts.
+        n = self.moe_experts_per_token * held / E if active else held
+        return (n + self.moe_shared_experts) * 3 * d * F + d * E + (
+            0 if active else E)  # the selection bias is no matmul
+
     def num_params(self) -> int:
         d, L, V = self.d_model, self.n_layers, self.vocab_size
-        h = self.head_dim
-        attn = d * (self.n_heads * h) + 2 * d * (self.kv_heads * h) + (self.n_heads * h) * d
-        if self.moe_num_experts:
-            mlp = self.moe_num_experts * 3 * d * self.ff_dim + d * self.moe_num_experts
-        elif self.activation == "swiglu":
-            mlp = 3 * d * self.ff_dim
-        else:
-            mlp = 2 * d * self.ff_dim
+        layers = sum(self._mixer_params(m) + self._ffn_params(f)
+                     for m, f in self.layer_kinds())
         norms = 2 * d * L + d
         if self.norm == "layernorm":
             norms *= 2  # biases alongside scales
         emb = V * d * (1 if self.tie_embeddings else 2)
-        pos = 0 if self.positional == "rope" else self.max_seq_len * d
-        return L * (attn + mlp) + norms + emb + pos
+        pos = self.max_seq_len * d if self.positional == "learned" else 0
+        return int(layers) + norms + emb + pos
 
     def num_active_params(self) -> int:
         """Params touched per token: for MoE, only experts_per_token of the
-        E experts execute, so compute-oriented uses (FLOPs/MFU) must not
-        count the full expert bank."""
+        E experts execute (of the held ones, under even routing, their share
+        of that), so compute-oriented uses (FLOPs/MFU) must not count the
+        full expert bank."""
         if not self.moe_num_experts:
             return self.num_params()
-        d, L, F = self.d_model, self.n_layers, self.ff_dim
-        full_mlp = self.moe_num_experts * 3 * d * F
-        active_mlp = self.moe_experts_per_token * 3 * d * F
-        return self.num_params() - L * (full_mlp - active_mlp)
+        full = sum(self._ffn_params(f) for _, f in self.layer_kinds())
+        active = sum(self._ffn_params(f, True) for _, f in self.layer_kinds())
+        return int(self.num_params() - full + active)
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Forward+backward FLOPs/token ≈ 6*N_active + 12*L*S*d (attn)."""
+        """Forward+backward FLOPs/token. Classic stacks: 6*N_active +
+        12*L*S*d (attn, not halved by causality, embedding counted: the
+        number the older benchmarks quote). Mixed stacks: 6 per matmul
+        parameter a token touches (no embedding lookup), causal attention
+        3*S*H*(d_qk + d_v) a softmax layer, and the chunked algorithm's
+        operations a KDA layer (see chipbench/reduce/kda_counts.py)."""
         S = seq_len or self.max_seq_len
-        return (6.0 * self.num_active_params()
-                + 12.0 * self.n_layers * S * self.d_model)
+        if not self.mixed:
+            return (6.0 * self.num_active_params()
+                    + 12.0 * self.n_layers * S * self.d_model)
+        d, H = self.d_model, self.n_heads
+        n = self.num_active_params() - self.vocab_size * d  # the lookup
+        if self.positional == "learned":
+            n -= self.max_seq_len * d
+        total = 6.0 * n
+        C, hd = self.kda_chunk, self.kda_head_dim
+        for mixer, _ in self.layer_kinds():
+            if mixer == "attn":
+                total += 3.0 * S * H * 2 * self.head_dim
+            elif mixer == "mla":
+                total += 3.0 * S * H * (self.qk_nope_head_dim
+                                        + self.qk_rope_head_dim
+                                        + self.v_head_dim)
+            else:
+                total += 3.0 * self.kda_n_heads * (
+                    6 * hd * hd + C * 5 * hd + C * C / 3)
+        return total
 
 
 def _dense_init(key, shape, param_dtype, scale: Optional[float] = None):
@@ -140,6 +315,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     d, L, V, F = cfg.d_model, cfg.n_layers, cfg.vocab_size, cfg.ff_dim
     H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     keys = jax.random.split(key, 12)
+    if cfg.mixed:
+        return _top_params(keys, cfg, _init_mixed_layers(keys[0], cfg))
 
     def stack(initializer, shape, k):
         ks = jax.random.split(k, L)
@@ -186,6 +363,11 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
         layers["attn_norm_b"] = jnp.zeros((L, d), cfg.param_dtype)
         layers["mlp_norm_b"] = jnp.zeros((L, d), cfg.param_dtype)
 
+    return _top_params(keys, cfg, layers)
+
+
+def _top_params(keys, cfg: TransformerConfig, layers) -> Params:
+    d, V = cfg.d_model, cfg.vocab_size
     params: Params = {
         "embed": (jax.random.normal(keys[7], (V, d)) * 0.02).astype(cfg.param_dtype),
         "final_norm": jnp.ones((d,), cfg.param_dtype),
@@ -231,6 +413,10 @@ def param_logical_specs(cfg: TransformerConfig) -> Params:
     if cfg.norm == "layernorm":
         layers["attn_norm_b"] = ("layers", None)
         layers["mlp_norm_b"] = ("layers", None)
+    if cfg.mixed:  # a list of segments, as init_params builds it
+        layers = [[{n: ("layers",) + axes for n, (_, axes, _) in
+                    _mixed_layer_shapes(cfg, kind).items()}
+                   for kind in pattern] for pattern, _ in cfg.stack_plan()]
     specs: Params = {
         "embed": ("vocab", "embed"),
         "final_norm": (None,),
@@ -245,15 +431,18 @@ def param_logical_specs(cfg: TransformerConfig) -> Params:
     return specs
 
 
-def _norm(x, w, b, kind: str):
+def _norm(x, w, b, kind: str, eps: Optional[float] = None):
+    """`eps` None: 1e-6 (rmsnorm) / 1e-5 (layernorm); callers pass cfg.norm_eps."""
     xf = x.astype(jnp.float32)
     if kind == "rmsnorm":
         x2 = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        out = xf * jax.lax.rsqrt(x2 + 1e-6) * w.astype(jnp.float32)
+        out = xf * jax.lax.rsqrt(x2 + (1e-6 if eps is None else eps)
+                                 ) * w.astype(jnp.float32)
     else:
         mu = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.var(xf, axis=-1, keepdims=True)
-        out = (xf - mu) * jax.lax.rsqrt(var + 1e-5) * w.astype(jnp.float32)
+        out = (xf - mu) * jax.lax.rsqrt(var + (1e-5 if eps is None else eps)
+                                        ) * w.astype(jnp.float32)
         if b is not None:
             out = out + b.astype(jnp.float32)
     return out.astype(x.dtype)
@@ -300,10 +489,12 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
     return q, k, v
 
 
-def _mlp_block(cfg: TransformerConfig, h: jax.Array, layer: Params):
-    """Post-attention FFN (moe / swiglu / gelu), shared with the decode
-    path; returns (delta, moe_aux)."""
-    if cfg.moe_num_experts:
+def _mlp_block(cfg: TransformerConfig, h: jax.Array, layer: Params,
+               dense: bool = False):
+    """Post-attention FFN (GShard moe / swiglu / gelu), shared with the
+    decode path; returns (delta, moe_aux). `dense`: this layer has the dense
+    MLP whatever cfg.moe_num_experts says (a mixed stack's lead layers)."""
+    if cfg.moe_num_experts and not dense:
         from ray_tpu.ops.moe import moe_ffn
 
         return moe_ffn(
@@ -327,14 +518,16 @@ def _layer_body(cfg: TransformerConfig, x: jax.Array, layer: Params,
     B, S, d = x.shape
     H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
 
-    h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm)
+    h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
+              cfg.norm_eps)
     q, k, v = _qkv_proj(cfg, h, layer, positions)
     q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
     o = attention(q, k, v, causal=True)
     x = x + o.reshape(B, S, H * hd) @ _w(layer, "wo", cfg)
     x = maybe_constrain(x, ("batch", "seq_act", "embed"))
 
-    h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm)
+    h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
+              cfg.norm_eps)
     delta, aux = _mlp_block(cfg, h, layer)
     x = x + delta
     x = maybe_constrain(x, ("batch", "seq_act", "embed"))
@@ -349,6 +542,252 @@ def _layer_body_kv(cfg: TransformerConfig, x: jax.Array, layer: Params,
     prefill path of models/generate.py primes its cache from these."""
     x, _aux, k, v = _layer_body(cfg, x, layer, positions, return_kv=True)
     return x, k, v
+
+
+# ------------------------------------------------------------ mixed stacks
+# A stack whose layers are of more than one kind (cfg.mixed): KDA or MLA
+# mixers, sigmoid-routed experts beside dense layers. params["layers"] is a
+# list of segments (cfg.stack_plan()), a segment a list over its pattern's
+# positions of one layer-kind's parameters, each leaf stacked over the
+# segment's repeats. docs/model_layers.md.
+
+
+def _mixed_layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
+    """{leaf: (shape, logical axes, init)} of one layer of `kind`. init:
+    "ones" | "zeros" | ("normal", std) | a callable key -> array."""
+    mixer, ffn = kind
+    d, L = cfg.d_model, cfg.n_layers
+    H = cfg.n_heads
+    fan = lambda n: ("normal", 1.0 / math.sqrt(n))
+    out_std = lambda n: ("normal", 1.0 / math.sqrt(2 * L * n))
+    sh: Dict[str, Any] = {
+        "attn_norm": ((d,), (None,), "ones"),
+        "mlp_norm": ((d,), (None,), "ones"),
+    }
+    if mixer == "attn":
+        hd, KVH = cfg.head_dim, cfg.kv_heads
+        sh["wo"] = ((H * hd, d), ("heads", "embed"), out_std(H * hd))
+        if KVH == H:
+            sh["wqkv"] = ((d, 3, H, hd), ("embed", None, "heads", None), fan(d))
+        else:
+            sh["wq"] = ((d, H, hd), ("embed", "heads", None), fan(d))
+            sh["wkv"] = ((d, 2, KVH, hd), ("embed", None, "kv_heads", None),
+                         fan(d))
+    elif mixer == "mla":
+        lat, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+        sh["mla_wq"] = ((d, H, nope + rope), ("embed", "heads", None), fan(d))
+        sh["mla_wkva"] = ((d, lat + rope), ("embed", None), fan(d))
+        sh["mla_kv_norm"] = ((lat,), (None,), "ones")
+        sh["mla_wkvb"] = ((lat, H, nope + dv), (None, "heads", None), fan(lat))
+        sh["mla_wo"] = ((H, dv, d), ("heads", None, "embed"), out_std(H * dv))
+    else:
+        Hk, hd = cfg.kda_n_heads, cfg.kda_head_dim
+        rank, K = cfg.kda_gate_rank or hd, cfg.kda_conv
+        for n in ("q", "k", "v"):
+            sh["kda_w" + n] = ((d, Hk, hd), ("embed", "heads", None), fan(d))
+            sh["kda_conv_" + n] = ((K, Hk, hd), (None, "heads", None),
+                                   fan(K))
+        for n in ("f", "g"):  # decay and output gate, low rank
+            sh[f"kda_w{n}1"] = ((d, rank), ("embed", None), fan(d))
+            sh[f"kda_w{n}2"] = ((rank, Hk, hd), (None, "heads", None),
+                                fan(rank))
+        # Decay a = exp(-exp(A_log) * softplus(. + dt_bias)): A in [1, 16],
+        # softplus(dt_bias) in [0.001, 0.1], log-uniform (the Mamba family's
+        # parametrisation, which the gated delta rules follow).
+        sh["kda_A_log"] = ((Hk,), ("heads",), lambda k: jnp.log(
+            jax.random.uniform(k, (Hk,), minval=1.0, maxval=16.0)))
+
+        def dt_bias(k):
+            dt = jnp.exp(jax.random.uniform(
+                k, (Hk, hd), minval=math.log(1e-3), maxval=math.log(1e-1)))
+            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+
+        sh["kda_dt_bias"] = ((Hk, hd), ("heads", None), dt_bias)
+        sh["kda_wb"] = ((d, Hk), ("embed", "heads"), fan(d))
+        sh["kda_o_norm"] = ((hd,), (None,), "ones")
+        sh["kda_wo"] = ((Hk, hd, d), ("heads", None, "embed"),
+                        out_std(Hk * hd))
+    if ffn == "dense":
+        F = cfg.ff_dim
+        sh["w_down"] = ((F, d), ("mlp", "embed"), out_std(F))
+        if cfg.activation == "swiglu":
+            sh["w_gate_up"] = ((d, 2, F), ("embed", None, "mlp"), fan(d))
+        else:
+            sh["w_up"] = ((d, F), ("embed", "mlp"), fan(d))
+    else:
+        E, F = cfg.moe_num_experts, cfg.moe_ff_dim
+        Eh, Fs = cfg.moe_held_range[1], cfg.moe_shared_experts * F
+        sh["router"] = ((d, E), ("embed", None), fan(d))
+        sh["router_bias"] = ((E,), (None,), "zeros")  # a buffer: no gradient
+        sh["moe_w_gate_up"] = ((Eh, d, 2, F), ("expert", "embed", None, "mlp"),
+                               fan(d))
+        sh["moe_w_down"] = ((Eh, F, d), ("expert", "mlp", "embed"), out_std(F))
+        if Fs:
+            sh["shared_w_gate_up"] = ((d, 2, Fs), ("embed", None, "mlp"),
+                                      fan(d))
+            sh["shared_w_down"] = ((Fs, d), ("mlp", "embed"), out_std(Fs))
+    if cfg.norm == "layernorm":
+        sh["attn_norm_b"] = ((d,), (None,), "zeros")
+        sh["mlp_norm_b"] = ((d,), (None,), "zeros")
+    return sh
+
+
+def _init_mixed_layers(key: jax.Array, cfg: TransformerConfig):
+    def leaf(k, r, shape, init):
+        if init == "ones":
+            return jnp.ones((r,) + shape, cfg.param_dtype)
+        if init == "zeros":
+            return jnp.zeros((r,) + shape, cfg.param_dtype)
+        if callable(init):
+            make = init
+        else:
+            make = lambda kk: jax.random.normal(kk, shape) * init[1]
+        return jnp.stack([make(kk) for kk in jax.random.split(k, r)]
+                         ).astype(cfg.param_dtype)
+
+    segments = []
+    for si, (pattern, r) in enumerate(cfg.stack_plan()):
+        seg = []
+        for pi, kind in enumerate(pattern):
+            sh = _mixed_layer_shapes(cfg, kind)
+            k = jax.random.fold_in(jax.random.fold_in(key, si), pi)
+            seg.append({n: leaf(jax.random.fold_in(k, i), r, shape, init)
+                        for i, (n, (shape, _, init)) in enumerate(sh.items())})
+        segments.append(seg)
+    return segments
+
+
+def layer_params(params: Params, cfg: TransformerConfig, l: int) -> Params:
+    """The parameters of layer l (0-based), whichever way they are stacked."""
+    if not cfg.mixed:
+        return jax.tree.map(lambda a: a[l], params["layers"])
+    seg, pos, rep = cfg.layer_slot(l)
+    return jax.tree.map(lambda a: a[rep], params["layers"][seg][pos])
+
+
+def _swiglu(h, w_gate_up, w_down):
+    gu = jnp.einsum("bsd,dcf->bscf", h, w_gate_up)
+    gu = checkpoint_name(gu, "gate_up")
+    return (jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]) @ w_down
+
+
+def _kda_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+    from ray_tpu.ops.kda import kda_chunked, l2_normalize, short_conv
+
+    f32 = jnp.float32
+
+    def proj(n):
+        y = jnp.einsum("bsd,dnh->bsnh", h, _w(layer, "kda_w" + n, cfg))
+        return jax.nn.silu(short_conv(y, layer["kda_conv_" + n]))
+
+    q, k, v = l2_normalize(proj("q")), l2_normalize(proj("k")), proj("v")
+
+    def low_rank(n):
+        return jnp.einsum("bsr,rnh->bsnh", h @ _w(layer, f"kda_w{n}1", cfg),
+                          _w(layer, f"kda_w{n}2", cfg))
+
+    g = -jnp.exp(layer["kda_A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        low_rank("f").astype(f32) + layer["kda_dt_bias"].astype(f32))
+    beta = jax.nn.sigmoid(jnp.einsum(
+        "bsd,dn->bsn", h, _w(layer, "kda_wb", cfg)).astype(f32))
+    with jax.named_scope("kda.core"):
+        o, _ = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+    o = _norm(o, layer["kda_o_norm"], None, "rmsnorm", cfg.norm_eps)
+    o = o * jax.nn.sigmoid(low_rank("g"))
+    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "kda_wo", cfg))
+
+
+def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+    lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = jnp.einsum("bsd,dnh->bsnh", h, _w(layer, "mla_wq", cfg))
+    ckr = h @ _w(layer, "mla_wkva", cfg)               # [B,S,lat+rope]
+    c = _norm(ckr[..., :lat], layer["mla_kv_norm"], None, "rmsnorm", cfg.norm_eps)
+    kv = jnp.einsum("bsl,lnh->bsnh", c, _w(layer, "mla_wkvb", cfg))
+    kr = jnp.broadcast_to(ckr[:, :, None, lat:],
+                          kv.shape[:3] + (cfg.qk_rope_head_dim,))
+    k = jnp.concatenate([kv[..., :nope], kr], axis=-1)  # no rotation (NoPE)
+    q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
+    o = attention(q, k, kv[..., nope:], causal=True)
+    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg))
+
+
+_COUNTER_NAMES = ("assigned", "load_max", "load_mean", "past_buffer",
+                  "dropped")
+
+
+def _mixed_layer_body(cfg: TransformerConfig, kind: Tuple[str, str],
+                      x: jax.Array, layer: Params, positions: jax.Array):
+    """One layer of `kind` -> (x, routing counters of this layer or None)."""
+    mixer, ffn = kind
+    B, S, d = x.shape
+    h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
+              cfg.norm_eps)
+    if mixer == "kda":
+        with jax.named_scope("kda"):
+            delta = _kda_mixer(cfg, h, layer)
+    elif mixer == "mla":
+        with jax.named_scope("mla"):
+            delta = _mla_mixer(cfg, h, layer)
+    else:
+        q, k, v = _qkv_proj(cfg, h, layer, positions)
+        q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
+        o = attention(q, k, v, causal=True)
+        delta = o.reshape(B, S, -1) @ _w(layer, "wo", cfg)
+    x = maybe_constrain(x + delta, ("batch", "seq_act", "embed"))
+    h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
+              cfg.norm_eps)
+    counters = None
+    if ffn == "dense":
+        delta = _mlp_block(cfg, h, layer, dense=True)[0]
+    else:
+        from ray_tpu.ops.moe import moe_ffn_held
+
+        delta, counters = moe_ffn_held(
+            h, layer["router"], layer["router_bias"],
+            layer["moe_w_gate_up"], layer["moe_w_down"],
+            held_first=cfg.moe_held_range[0],
+            experts_per_token=cfg.moe_experts_per_token,
+            routed_scale=cfg.moe_routed_scale, dtype=cfg.dtype)
+        if "shared_w_down" in layer:
+            delta = delta + _swiglu(h, _w(layer, "shared_w_gate_up", cfg),
+                                    _w(layer, "shared_w_down", cfg))
+    x = maybe_constrain(x + delta, ("batch", "seq_act", "embed"))
+    return x, counters
+
+
+def _mixed_backbone(params: Params, x: jax.Array, cfg: TransformerConfig,
+                    positions: jax.Array):
+    """The segments in turn, each a scan over its repeats, every layer under
+    the remat policy. -> (x, routing counters over the expert layers:
+    assigned, dropped, past_buffer (sums), load_max (max), load_mean
+    (mean); ops/moe.py `moe_ffn_held`)."""
+    if cfg.remat and cfg.remat_policy.startswith("half"):
+        raise ValueError("half_* remat policies are for classic stacks")
+    per_layer = []
+    for (pattern, _), seg in zip(cfg.stack_plan(), params["layers"]):
+        bodies = [_remat(cfg, functools.partial(_mixed_layer_body, cfg, kind))
+                  for kind in pattern]
+
+        def period(x, layers, bodies=bodies):
+            outs = []
+            for body, layer in zip(bodies, layers):
+                x, c = body(x, layer, positions)
+                if c is not None:
+                    outs.append(c)
+            return x, outs
+
+        x, outs = jax.lax.scan(period, x, seg)
+        per_layer.extend(outs)  # each leaf [repeats]
+    if not per_layer:
+        return x, {}
+    cat = {n: jnp.concatenate([c[n] for c in per_layer])
+           for n in _COUNTER_NAMES}
+    return x, {"moe_assigned": cat["assigned"].sum(),
+               "moe_dropped": cat["dropped"].sum(),
+               "moe_past_buffer": cat["past_buffer"].sum(),
+               "moe_load_max": cat["load_max"].max(),
+               "moe_load_mean": cat["load_mean"].mean()}
 
 
 def embed_tokens(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> jax.Array:
@@ -374,7 +813,12 @@ def layer_scan_body(cfg: TransformerConfig, positions: jax.Array):
     """The (remat-wrapped) per-layer scan body; shared by the plain forward
     and the pipeline-parallel stage apply (parallel/pipeline.py). The scan's
     per-layer output is the MoE aux loss (zeros for dense layers)."""
-    body = lambda carry, layer: _layer_body(cfg, carry, layer, positions)
+    return _remat(
+        cfg, lambda carry, layer: _layer_body(cfg, carry, layer, positions))
+
+
+def _remat(cfg: TransformerConfig, body):
+    """`body` under cfg's remat policy (one layer's granularity)."""
     if cfg.remat:
         if cfg.remat_policy == "dots":
             from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
@@ -426,9 +870,19 @@ def backbone_with_aux(
 ) -> Tuple[jax.Array, jax.Array]:
     """Everything before the lm head: tokens -> hidden [B,S,d] + MoE aux
     (the fused-CE loss path consumes the hidden states directly)."""
+    x, aux, _ = _backbone(params, tokens, cfg)
+    return x, aux
+
+
+def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
+    """-> (hidden, MoE aux loss, routing counters: {} unless the stack has
+    sigmoid-routed experts, see `_mixed_backbone`)."""
     B, S = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    if cfg.mixed:
+        x, counters = _mixed_backbone(params, x, cfg, positions)
+        return x, jnp.zeros((), jnp.float32), counters
     if cfg.remat and cfg.remat_policy.startswith("half"):
         # Mixed remat: the FIRST half of the stack checkpoints (its saved
         # activations would live longest — from forward until the very end
@@ -449,7 +903,7 @@ def backbone_with_aux(
         x, auxs = jax.lax.scan(
             layer_scan_body(cfg, positions), x, params["layers"])
         aux = auxs.sum()
-    return x, aux
+    return x, aux, {}
 
 
 def final_hidden_and_head(
@@ -458,7 +912,8 @@ def final_hidden_and_head(
     """THE head-weight convention (final norm + tied-or-separate head),
     shared by the unfused lm_head and the fused-CE loss path so the two
     can never drift."""
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm,
+              cfg.norm_eps)
     head = params.get("lm_head", None)
     if head is None:
         head = params["embed"].T
@@ -522,8 +977,12 @@ def next_token_loss(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
-            *, shift_inputs: bool = False) -> jax.Array:
-    """Next-token cross-entropy.
+            *, shift_inputs: bool = False, with_counters: bool = False):
+    """Next-token cross-entropy. `with_counters`: return (loss, routing
+    counters) for `ShardedTrainStep(has_aux=True)`: device scalars
+    moe_assigned, moe_dropped, moe_past_buffer, moe_load_max, moe_load_mean
+    of a stack with
+    sigmoid-routed experts (`_mixed_backbone`), {} for any other.
 
     Two token conventions:
     - in-place (default): batch tokens [B,S]; the forward runs on the FULL
@@ -545,7 +1004,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
         from ..ops.fused_ce import fused_next_token_loss
 
         tokens_in = tokens[:, :-1] if shift_inputs else tokens
-        x, aux = backbone_with_aux(params, tokens_in, cfg)
+        x, aux, counters = _backbone(params, tokens_in, cfg)
         x, head = final_hidden_and_head(params, x, cfg)
         if shift_inputs:
             targets, valid = shift_targets_valid(tokens, batch.get("mask"))
@@ -554,12 +1013,14 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
         loss = fused_next_token_loss(
             x.astype(cfg.dtype), head, targets, valid)
     elif shift_inputs:
-        logits, aux = forward_with_aux(params, tokens[:, :-1], cfg)
+        x, aux, counters = _backbone(params, tokens[:, :-1], cfg)
+        logits = lm_head(params, x, cfg)
         targets, valid = shift_targets_valid(tokens, batch.get("mask"))
         loss = token_cross_entropy(logits, targets, valid)
     else:
-        logits, aux = forward_with_aux(params, tokens, cfg)  # [B, S, V]
+        x, aux, counters = _backbone(params, tokens, cfg)
+        logits = lm_head(params, x, cfg)  # [B, S, V]
         loss = next_token_loss(logits, batch)
-    if cfg.moe_num_experts:
+    if cfg.moe_num_experts and not cfg.mixed:  # GShard's balancing loss
         loss = loss + cfg.moe_aux_coef * aux
-    return loss
+    return (loss, counters) if with_counters else loss

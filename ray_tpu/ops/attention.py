@@ -24,13 +24,13 @@ def _on_tpu() -> bool:
 def reference_attention(
     q: jax.Array,  # [B, S, H, D]
     k: jax.Array,  # [B, S, KVH, D]
-    v: jax.Array,  # [B, S, KVH, D]
+    v: jax.Array,  # [B, S, KVH, Dv]
     *,
     causal: bool = True,
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Plain XLA attention with GQA head-broadcast. Computes in f32 for
-    numerical stability, returns q.dtype."""
+    numerical stability, returns q.dtype, [B, S, H, Dv]."""
     B, S, H, D = q.shape
     KVH = k.shape[2]
     assert H % KVH == 0, f"heads {H} not divisible by kv_heads {KVH}"
@@ -48,7 +48,7 @@ def reference_attention(
         logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, vf)
-    return out.reshape(B, S, H, D).astype(q.dtype)
+    return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
 def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash):

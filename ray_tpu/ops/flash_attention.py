@@ -107,9 +107,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    """q: [B,H,S,D], k/v: [B,KVH,S,D] -> (o [B,H,S,D], lse [B,H,S] f32)."""
+    """q: [B,H,S,D], k: [B,KVH,S,D], v: [B,KVH,S,Dv] -> (o [B,H,S,Dv],
+    lse [B,H,S,1] f32). Dv may differ from D (latent attention: keys 192
+    wide, values 128)."""
     B, H, S, D = q.shape
-    KVH = k.shape[1]
+    KVH, Dv = k.shape[1], v.shape[-1]
     group = H // KVH
     bq = min(block_q, S)
     bk = min(block_k, S)
@@ -126,15 +128,15 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D),
                          lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, D),
+            pl.BlockSpec((1, 1, bk, Dv),
                          lambda b, h, i, j, g=group: (b, h // g, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
             # Trailing singleton keeps the (sublane, lane) block = (bq, 1),
             # which Mosaic accepts (lane == full array dim).
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
@@ -142,7 +144,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -285,7 +287,7 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     simple accumulation.
     """
     B, H, S, D = q.shape
-    KVH = k.shape[1]
+    KVH, Dv = k.shape[1], v.shape[-1]
     group = H // KVH
     bq = min(block_q, S)
     bk = min(block_k, S)
@@ -300,9 +302,9 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D),
                          lambda b, h, i, j, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bk, D),
+            pl.BlockSpec((1, 1, bk, Dv),
                          lambda b, h, i, j, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
@@ -324,23 +326,23 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
             pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D),
                          lambda b, h, j, i, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bk, D),
+            pl.BlockSpec((1, 1, bk, Dv),
                          lambda b, h, j, i, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -350,7 +352,7 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
 
     if group > 1:
         dk = dk_h.reshape(B, KVH, group, S, D).sum(axis=2).astype(k.dtype)
-        dv = dv_h.reshape(B, KVH, group, S, D).sum(axis=2).astype(v.dtype)
+        dv = dv_h.reshape(B, KVH, group, S, Dv).sum(axis=2).astype(v.dtype)
     else:
         dk, dv = dk_h, dv_h
     return dq, dk, dv
@@ -392,14 +394,15 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(
     q: jax.Array,  # [B, S, H, D]
     k: jax.Array,  # [B, S, KVH, D]
-    v: jax.Array,  # [B, S, KVH, D]
+    v: jax.Array,  # [B, S, KVH, Dv]
     *,
     causal: bool = True,
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
 ) -> jax.Array:
-    """Flash attention in model layout [B, S, H, D]; differentiable."""
+    """Flash attention in model layout [B, S, H, D] -> [B, S, H, Dv];
+    differentiable. The values' width Dv may differ from D."""
     block_q = block_q or DEFAULT_BLOCK
     block_k = block_k or DEFAULT_BLOCK
     D = q.shape[-1]

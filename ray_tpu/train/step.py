@@ -28,6 +28,10 @@ class ShardedTrainStep:
 
     loss_fn(params, batch) -> scalar loss. `logical_specs` is the pytree of
     logical axis names matching params (models expose param_logical_specs).
+    `has_aux`: loss_fn returns (loss, aux), aux a pytree of device values
+    (counters); `step` then returns (params, opt_state, loss, aux). Nothing
+    is called back to the host from inside the program: the caller reads
+    aux when it waits for the loss (`observe_counters`).
     """
 
     def __init__(
@@ -40,6 +44,7 @@ class ShardedTrainStep:
         rules: Optional[shd.Rules] = None,
         optimizer: Optional[optax.GradientTransformation] = None,
         donate: bool = True,
+        has_aux: bool = False,
     ):
         self.mesh = mesh
         self.rules = rules or shd.DEFAULT_RULES
@@ -62,12 +67,17 @@ class ShardedTrainStep:
 
         def _step(params, opt_state, batch):
             with shd.sharding_ctx(self.mesh, self.rules):
-                loss, grads = jax.value_and_grad(self._loss_fn)(params, batch)
+                loss, grads = jax.value_and_grad(
+                    self._loss_fn, has_aux=has_aux)(params, batch)
                 updates, opt_state = self.optimizer.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
+            if has_aux:
+                loss, aux = loss
+                return params, opt_state, loss, aux
             return params, opt_state, loss
 
         self._jit_step = jax.jit(_step, donate_argnums=(0, 1) if donate else ())
+        self._compiled_step = None  # see compile_step
 
         def _eval(params, batch):
             with shd.sharding_ctx(self.mesh, self.rules):
@@ -81,9 +91,30 @@ class ShardedTrainStep:
     def shard_batch(self, batch: Any) -> Any:
         return shd.shard_batch(self.mesh, batch)
 
-    def step(self, params, opt_state, batch) -> Tuple[Any, Any, jax.Array]:
+    def step(self, params, opt_state, batch) -> Tuple[Any, ...]:
         with tracing.phase("train.step"):  # the host side: the enqueue
-            return self._jit_step(params, opt_state, batch)
+            return (self._compiled_step or self._jit_step)(
+                params, opt_state, batch)
+
+    def compile_step(self, params, opt_state, batch):
+        """Compile the step ahead of time for these shapes and shardings;
+        `step` then runs that executable (it accepts no other shapes). One
+        compile serves the loop and `.memory_analysis()`, where
+        `lower_step(...).compile()` beside a jitted call compiles twice."""
+        self._compiled_step = self.lower_step(
+            params, opt_state, batch).compile()
+        return self._compiled_step
+
+    @staticmethod
+    def observe_counters(aux: Dict[str, Any]) -> Dict[str, float]:
+        """A step's counters (has_aux) as floats, folded into the phase table
+        as `train.<name>` (`util/tracing.observe`, the count in place of
+        nanoseconds; no entry in the slow ring). Call it where the loop waits for the loss anyway:
+        reading them blocks until the step is done."""
+        out = {k: float(v) for k, v in aux.items()}
+        for k, v in out.items():
+            tracing.observe("train." + k, round(v), slow=False)
+        return out
 
     def eval_loss(self, params, batch) -> jax.Array:
         return self._jit_eval(params, batch)
@@ -101,6 +132,7 @@ def transformer_train_step(
     optimizer: Optional[optax.GradientTransformation] = None,
     pipeline_microbatches: Optional[int] = None,
     shift_inputs: bool = False,
+    with_counters: bool = False,
 ) -> ShardedTrainStep:
     """Convenience: wire a models.transformer config into a ShardedTrainStep.
 
@@ -108,7 +140,9 @@ def transformer_train_step(
     (parallel/pipeline.py) with `pipeline_microbatches` microbatches
     (default: 2x the stage count, a reasonable bubble/memory tradeoff).
     ``shift_inputs`` selects the [B,S+1]-tokens convention (models.
-    transformer.loss_fn docstring) — the high-throughput path."""
+    transformer.loss_fn docstring) — the high-throughput path.
+    ``with_counters``: the step also returns the model's routing counters
+    (ShardedTrainStep `has_aux`)."""
     from ray_tpu.models import transformer as tfm
 
     if "pipe" in mesh.axis_names and mesh.shape["pipe"] > 1:
@@ -120,6 +154,10 @@ def transformer_train_step(
             raise NotImplementedError(
                 "fused_ce is not supported under pipeline parallelism "
                 "yet — unset cfg.fused_ce for pipe>1 meshes")
+        if with_counters or cfg.mixed:
+            raise NotImplementedError(
+                "pipeline parallelism runs classic stacks only (one stacked "
+                "tree of identical layers), without counters")
         from ray_tpu.parallel.pipeline import pipeline_loss_fn
 
         M = pipeline_microbatches or 2 * mesh.shape["pipe"]
@@ -128,7 +166,8 @@ def transformer_train_step(
             shift_inputs=shift_inputs)
     else:
         loss = lambda params, batch: tfm.loss_fn(
-            params, batch, cfg, shift_inputs=shift_inputs)
+            params, batch, cfg, shift_inputs=shift_inputs,
+            with_counters=with_counters)
 
     return ShardedTrainStep(
         init_params_fn=lambda rng: tfm.init_params(rng, cfg),
@@ -137,4 +176,5 @@ def transformer_train_step(
         mesh=mesh,
         rules=rules,
         optimizer=optimizer,
+        has_aux=with_counters,
     )
