@@ -52,10 +52,10 @@ class TransformerConfig:
     # Remat granularity when remat=True:
     # - "full": recompute the whole layer body in the backward (max memory
     #   saving, ~33% extra FLOPs, flash forward kernel included).
-    # - "dots": save matmul outputs and the flash kernel's own residuals
-    #   (o [B,H,S,hd] and lse [B,H,S], named inside its forward rule,
-    #   ops/flash_attention.py RESIDUAL_NAMES); recompute elementwise/norm
-    #   work. The forward kernel runs once a layer.
+    # - "dots": save matmul outputs and the kernels' own residuals (flash: o
+    #   [B,H,S,hd] and lse [B,H,S]; KDA: o, chunk states, inverses; named in
+    #   their forward rules, RESIDUAL_NAMES of ops/flash_attention.py and
+    #   ops/kda.py); recompute the rest. A forward kernel runs once a layer.
     # - "half_dots" / "half_full": the first half of the stack under
     #   "dots" / "full", the second half without remat.
     # - "min": save everything except the two fat fused-projection outputs
@@ -821,14 +821,14 @@ def _remat(cfg: TransformerConfig, body):
     """`body` under cfg's remat policy (one layer's granularity)."""
     if cfg.remat:
         if cfg.remat_policy == "dots":
-            from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
-
+            from ray_tpu.ops import flash_attention as fa, kda
+            names = fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES  # no dot makes them
             body = jax.checkpoint(
                 body,
                 policy=jax.checkpoint_policies.save_from_both_policies(
                     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
                     jax.checkpoint_policies.save_only_these_names(
-                        *RESIDUAL_NAMES),
+                        *names),
                 ),
             )
         elif cfg.remat_policy == "min":

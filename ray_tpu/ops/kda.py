@@ -6,12 +6,12 @@ Per head, with a state S [d_k, d_v] in place of keys and values:
     o_t = S_t^T q_t * scale,        a_t = exp(g_t), g_t <= 0 per key channel
 
 `kda_recurrent` is that recurrence, one token at a time (tests, and the shape
-a decode step will take). `kda_chunked` is the form the training path runs:
-the sequence is cut into chunks of `chunk` tokens; inside a chunk the
-rank-one updates are folded into one triangular system (the WY / UT
-transform of the delta rule), solved for all chunks at once; between chunks
-a `lax.scan` carries S. With G_t the decay summed in log space from the
-chunk's start (float32), u_t = beta_t (v_t - (Diag(a_t) S_{t-1})^T k_t):
+a decode step will take). The training path runs the chunked form: the
+sequence is cut into chunks of `chunk` tokens; inside a chunk the rank-one
+updates are folded into one triangular system (the WY / UT transform of the
+delta rule); between chunks the state S is carried. With G_t the decay summed
+in log space from the chunk's start (float32), u_t = beta_t (v_t - (Diag(a_t)
+S_{t-1})^T k_t):
 
     (I + A) U = beta (V - (K e^G) S_0),   A_ts = beta_t sum_c k_tc k_sc e^(G_tc - G_sc), s < t
     O = (Q e^G) S_0 + tril(Q K^T e^(G_t - G_s)) U
@@ -25,17 +25,54 @@ sub-block's start), so that both factors are at most 1 for every earlier
 sub-block and at most e^(sub * max|g|) inside the sub-block itself (capped
 at e^80: exact while a channel decays by less than that in `sub` steps).
 
-Everything is differentiable by plain autodiff; the matmuls take operands in
-the inputs' dtype (bfloat16 on the chip) and accumulate in float32; the
-triangular inverse, the decay and the state are float32. XLA only: a Pallas
-kernel for the chunk body is the obvious next step (PERF.md section 7).
+Precision, the same in both implementations: the matmuls take operands in
+the inputs' dtype (bfloat16 on the chip) and accumulate in float32; g, G, the
+decay factors, A, the triangular inverse and the state are float32, and u0
+goes into the state update in float32.
+
+`kda_chunked` is the entry point and dispatches on what it observes
+(`use_kernels`, no knob):
+
+- **`kda_chunked_pallas`**: two Pallas (Mosaic) kernels under a
+  `jax.custom_vjp`, on a TPU when d_k, d_v and the chunk are multiples of
+  128 and no multi-device mesh is active. Grid (batch, head pair, chunk),
+  the chunk axis sequential. q, k, v, g are read where they lie: [B, S, H, d] is
+  [B, S, H*d] and a head is a d-lane column block of it (no [B,H,S,d]
+  copies). A grid step holds one chunk of two neighbouring heads (one if the
+  head count is odd), each worked on its own, in VMEM: G (a product
+  with a triangle of ones), the sub-blocks' references and bounded factors,
+  A and P, T = (I + A)^-1 (`_inv_unit_lower`'s algorithm on one tile: forward
+  substitution on the diagonal 16-blocks, all eight at once on the vector
+  unit, then block merges as float32 MXU products), W, U, the output rows;
+  the state [d_k, d_v] float32 lives in VMEM scratch across the chunk axis.
+  The forward writes o, the final state and, for the backward, the
+  chunk-start states [B,H,N,d_k,d_v] float32 and T [B,H,N,C,C] in the
+  compute type. The backward kernel walks the chunks in reverse with the
+  state's cotangent in scratch, recomputes a chunk's factors from q, k, v,
+  g, beta, reads the saved state and T, and writes dq, dk, dv, dg, dbeta:
+  every [C, C] and [C, d] product is on the MXU, and the inverse is not
+  differentiated through its construction: dA = -(T^T dW) W^T - (T^T dU0)
+  U0^T, strictly lower. `RESIDUAL_NAMES` names o, the states and T
+  (`checkpoint_name` in the forward rule) so that a remat policy keeps them
+  and the forward kernel runs once (`models/transformer.py` `_remat`).
+- **`kda_chunked_xla`**: the same algorithm in plain XLA, differentiable by
+  autodiff: the fallback on the CPU, for narrow heads or chunks, and under a
+  mesh (a Mosaic call there needs `shard_map`; no configuration trains a KDA
+  stack on a mesh yet), and the kernels' oracle in the tests beside
+  `kda_recurrent`.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _interpret
 
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -113,25 +150,33 @@ def _inv_unit_lower(a: jax.Array, base: int = 16) -> jax.Array:
     return t[..., 0, :, :]
 
 
-def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
-                scale: Optional[float] = None,
-                initial_state: Optional[jax.Array] = None
-                ) -> Tuple[jax.Array, jax.Array]:
-    """Chunked KDA, same arguments and results as `kda_recurrent`. `chunk`
-    is 16 * 2^j (128 fills the MXU's tile); a sequence that is not a
-    multiple of it is padded at its end with tokens that leave the state as
-    it is (k = v = beta = g = 0)."""
+def _pad_to_chunks(q, k, v, g, beta, C):
+    """The sequence padded at its end to a multiple of C with tokens that
+    leave the state as it is (k = v = beta = g = 0)."""
+    pad = (-q.shape[1]) % C
+    if not pad:
+        return q, k, v, g, beta
+    q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                  for a in (q, k, v, g))
+    return q, k, v, g, jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+
+
+def kda_chunked_xla(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
+                    scale: Optional[float] = None,
+                    initial_state: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """Chunked KDA in plain XLA, differentiable by autodiff: the fallback of
+    `kda_chunked` and the kernels' oracle. Same arguments and results as
+    `kda_recurrent`. `chunk` is 16 * 2^j; a sequence that is not a multiple
+    of it is padded at its end with tokens that leave the state as it is
+    (k = v = beta = g = 0)."""
     B, S, H, dk = q.shape
     dv = v.shape[-1]
     scale = dk ** -0.5 if scale is None else scale
     C = chunk
     sub = min(sub, C)
-    pad = (-S) % C
-    if pad:
-        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for a in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    N, n = (S + pad) // C, C // sub
+    q, k, v, g, beta = _pad_to_chunks(q, k, v, g, beta, C)
+    N, n = q.shape[1] // C, C // sub
     mm = q.dtype
 
     def chunks(a):  # [B,S,H,x] -> [B,H,N,C,x]
@@ -197,3 +242,433 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
                              preferred_element_type=_F32)
     o = jnp.moveaxis(o.reshape(B, H, N * C, dv), 1, 2)[:, :S]
     return (o * scale).astype(v.dtype), s
+
+
+# ------------------------------------------------------------ Pallas kernels
+#
+# One grid step is one chunk of one head: everything between the chunk's
+# inputs and its outputs lives in VMEM ([C, C] and [C, d] tiles of 64 KB).
+
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+# What `_vjp_fwd` keeps for the backward kernel besides its own inputs, named
+# so that a remat policy can keep them (`models/transformer.py` `_remat`): o
+# because the layer's recomputation needs it, the chunk-start states and the
+# chunks' inverses because the backward kernel reads them. A pallas_call is
+# not a dot: under a dots-only policy the forward kernel would run twice.
+RESIDUAL_NAMES = ("kda_o", "kda_states", "kda_tinv")
+
+
+def _dot(a, b, dims=_NN, exact=False):
+    """MXU product with float32 accumulation; `exact` asks for float32
+    operands at full precision (the cumulative sums and the inverse)."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               precision=_HI if exact else None,
+                               preferred_element_type=_F32)
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _head_col(blk, h):
+    """Column h of a [C, H] block as [C, 1] (beta lies [B, S, H])."""
+    return jnp.sum(jnp.where(_iota(blk.shape, 1) == h, blk, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _flip(x):
+    """[1, n] -> [n, 1] or [n, 1] -> [1, n]: broadcast to a square tile and
+    transposed on the XLU."""
+    n = max(x.shape)
+    t = jnp.transpose(jnp.broadcast_to(x, (n, n)))
+    return t[:, :1] if x.shape[0] == 1 else t[:1]
+
+
+def _tree_sum(xs):
+    while len(xs) > 1:
+        xs = [sum(xs[i:i + 2]) for i in range(0, len(xs), 2)]
+    return xs[0]
+
+
+def _inv_unit_lower_vmem(a, scr, base: int = 16):
+    """(I + a)^-1 for strictly lower-triangular a [C, C] float32, the
+    algorithm of `_inv_unit_lower` on one tile: forward substitution on the
+    `base`-wide diagonal blocks, all blocks at once, then block merges
+    [[T1, 0], [-T2 a21 T1, T2]] up to C as MXU products at full float32
+    precision. `scr` is a [C, C] float32 VMEM scratch.
+
+    Substitution: each block's rows are rotated so that its diagonal block
+    lies in lanes 0..base-1; row i of every block is then one strided load
+    [C/base, C], and row i of the inverse is e_i - sum_j a_ij row_j with
+    a_ij a lane of that load, broadcast."""
+    C = a.shape[0]
+    base = min(base, C)
+    nb = C // base
+    for b in range(nb):
+        blk = a[b * base:(b + 1) * base]
+        scr[b * base:(b + 1) * base, :] = (
+            pltpu.roll(blk, C - b * base, 1) if b else blk)
+    lane = _iota((nb, C), 1)
+    rows = []
+    for i in range(base):
+        x = jnp.where(lane == i, 1.0, 0.0)
+        if i:
+            d = scr[pl.ds(i, nb, stride=base), :]
+            x = x - _tree_sum([d[:, j:j + 1] * rows[j] for j in range(i)])
+        rows.append(x)
+    for i in range(base):
+        scr[pl.ds(i, nb, stride=base), :] = rows[i]
+    blocks = [scr[b * base:(b + 1) * base, :] for b in range(nb)]
+    blocks = [pltpu.roll(x, b * base, 1) if b else x
+              for b, x in enumerate(blocks)]       # base rows each, in place
+    t, s = _iota((C, C), 0), _iota((C, C), 1)
+    m = base
+    while m < C:
+        # Pairs of m-blocks: only the odd blocks' rows change.
+        x = jnp.concatenate(blocks, 0)
+        off = jnp.where(((t ^ s) < 2 * m) & ((t & m) != 0) & ((s & m) == 0),
+                        a, 0.0)
+        odd = jnp.concatenate(blocks[1::2], 0)     # [C/2, C]
+        odd = odd - _dot(_dot(odd, off, exact=True), x, exact=True)
+        h = odd.shape[0] // (len(blocks) // 2)
+        blocks = [jnp.concatenate(
+            [blocks[2 * p], odd[p * h:(p + 1) * h]], 0)
+            for p in range(len(blocks) // 2)]
+        m *= 2
+    return blocks[0]
+
+
+def _chunk_terms(q, k, v, g, beta, sub, with_a):
+    """A chunk's quantities that need no state, as the module docstring and
+    `kda_chunked_xla` define them: q, k [C, dk], v [C, dv] in the compute
+    type, g [C, dk] and beta [C, 1] float32."""
+    C, dk = q.shape
+    mm = q.dtype
+    qf, kf, vf = q.astype(_F32), k.astype(_F32), v.astype(_F32)
+    t, s = _iota((C, C), 0), _iota((C, C), 1)
+    G = _dot((s <= t).astype(_F32), g, exact=True)       # running log-decay
+    # R_t: the decay at the start of t's sub-block, the reference of row t.
+    E = G - g
+    refs = [E[i * sub:i * sub + 1] for i in range(C // sub)]
+    R = jnp.concatenate([jnp.broadcast_to(r, (sub, dk)) for r in refs], 0)
+    row = jnp.exp(G - R)                                 # <= 1
+    lhs_a, lhs_p = kf * beta * row, qf * row
+    rows = _iota((C, 1), 0)
+    kh, cols, live, a, p = [], [], [], [], []
+    for i, r in enumerate(refs):
+        d = r - G                                        # r_i - G_s
+        keep = rows < (i + 1) * sub
+        cols.append(jnp.where(keep, jnp.exp(jnp.minimum(d, _CAP)), 0.0))
+        kh.append((kf * cols[i]).astype(mm))
+        live.append(keep & (d < _CAP))
+        sl = slice(i * sub, (i + 1) * sub)
+        if with_a:
+            a.append(_dot(lhs_a[sl].astype(mm), kh[i], _NT))
+        p.append(_dot(lhs_p[sl].astype(mm), kh[i], _NT))
+    eG = jnp.exp(G)
+    Gl = jnp.sum(jnp.where(rows == C - 1, G, 0.0), axis=0, keepdims=True)
+    ekb = jnp.exp(Gl - G)
+    x = dict(qf=qf, kf=kf, vf=vf, beta=beta, row=row, eG=eG, ekb=ekb,
+             lhs_a=lhs_a, lhs_p=lhs_p, kh=kh, cols=cols, live=live,
+             p=jnp.where(t >= s, jnp.concatenate(p, 0), 0.0).astype(mm),
+             rhs_w=(kf * beta * eG).astype(mm), rhs_u=(vf * beta).astype(mm),
+             qt=(qf * eG).astype(mm), kbar=(kf * ekb).astype(mm),
+             decay=jnp.exp(Gl))                          # [1, dk]
+    if with_a:
+        x["a"] = jnp.where(t > s, jnp.concatenate(a, 0), 0.0)
+    return x
+
+
+def _chunk_solve(x, tb, s):
+    """W, U0 and U of a chunk from T = (I + A)^-1 (compute type) and the
+    chunk-start state s (float32): u0 stays float32 into the state update."""
+    mm = tb.dtype
+    w = _dot(tb, x["rhs_w"]).astype(mm)
+    u0 = _dot(tb, x["rhs_u"])
+    sm = s.astype(mm)
+    u = u0 - _dot(w, sm)
+    return w, u0, u.astype(mm), sm
+
+
+def _fwd_chunk(q, k, v, g, beta, s, scale, sub, inv_scr):
+    """One chunk of one head -> (o, T, the next state)."""
+    x = _chunk_terms(q, k, v, g, beta, sub, with_a=True)
+    tb = _inv_unit_lower_vmem(x["a"], inv_scr).astype(q.dtype)
+    w, u0, ub, sm = _chunk_solve(x, tb, s)
+    o = (_dot(x["qt"], sm) + _dot(x["p"], ub)) * scale
+    return o, tb, s * _flip(x["decay"]) + _dot(x["kbar"], ub, _TN)
+
+
+def _bwd_chunk(q, k, v, g, beta, s, tb, do, ds1, scale, sub):
+    """One chunk of one head in the reverse sweep: s the chunk-start state,
+    tb its inverse, ds1 the cotangent of its end state -> dq, dk, dv, dg,
+    dbeta [C, 1] and the cotangent of the start state. The casts to the
+    compute type pass cotangents through as autodiff's `astype` does."""
+    mm = q.dtype
+    x = _chunk_terms(q, k, v, g, beta, sub, with_a=False)
+    C = q.shape[0]
+    qf, kf, vf = x["qf"], x["kf"], x["vf"]
+    w, u0, ub, sm = _chunk_solve(x, tb, s)
+    ds1b = ds1.astype(mm)
+    do = (do.astype(_F32) * scale).astype(mm)
+    t, s_i = _iota((C, C), 0), _iota((C, C), 1)
+    # o = qt S + P U;  S' = decay S + kbar^T U;  U = U0 - W S.
+    dqt = _dot(do, sm, _NT)
+    dp = jnp.where(t >= s_i, _dot(do, ub, _NT), 0.0).astype(mm)
+    dub = (_dot(x["p"], do, _TN) + _dot(x["kbar"], ds1b)).astype(mm)
+    dkbar = _dot(ub, ds1b, _NT)
+    dw = (-_dot(dub, sm, _NT)).astype(mm)
+    ds0 = (ds1 * _flip(x["decay"]) + _dot(x["qt"], do, _TN)
+           - _dot(w, dub, _TN))
+    ones = jnp.ones((8, s.shape[1]), _F32)
+    ddecay = _dot(ones, ds1 * s, _NT, exact=True)[:1]    # [1, dk]
+    # [W, U0] = T [rhs_w, rhs_u], and dA = -T^T dT T^T with dT = dW rhs_w^T +
+    # dU0 rhs_u^T is -(drhs_w W^T + drhs_u U0^T): T's own construction is
+    # not differentiated through.
+    drhs_w, drhs_u = _dot(tb, dw, _TN), _dot(tb, dub, _TN)
+    da = jnp.where(t > s_i, -(_dot(drhs_w.astype(mm), w, _NT)
+                              + _dot(drhs_u.astype(mm), u0.astype(mm), _NT)),
+                   0.0).astype(mm)
+    # A and P, a sub-block of rows at a time against its reference.
+    dlhs_a, dlhs_p, dk, dG, dref = [], [], 0.0, 0.0, []
+    for i, kh in enumerate(x["kh"]):
+        sl = slice(i * sub, (i + 1) * sub)
+        dlhs_a.append(_dot(da[sl], kh))
+        dlhs_p.append(_dot(dp[sl], kh))
+        dkh = (_dot(da[sl], x["lhs_a"][sl].astype(mm), _TN)
+               + _dot(dp[sl], x["lhs_p"][sl].astype(mm), _TN))
+        dk = dk + dkh * x["cols"][i]
+        e = jnp.where(x["live"][i], dkh * kf * x["cols"][i], 0.0)
+        dG = dG - e
+        dref.append(jnp.sum(e, axis=0, keepdims=True))
+    dlhs_a, dlhs_p = jnp.concatenate(dlhs_a, 0), jnp.concatenate(dlhs_p, 0)
+    f = dlhs_a * x["lhs_a"] + dlhs_p * x["lhs_p"]        # d(G - R) of row
+    h = dkbar * kf * x["ekb"]
+    dG = (dG + f + drhs_w * (kf * x["beta"] * x["eG"])
+          + dqt * (qf * x["eG"]) - h)
+    # The reference of sub-block i is row i*sub of E = G - g.
+    rows = _iota((C, 1), 0)
+    dE = sum(jnp.where(rows == i * sub, r - jnp.sum(
+        f[i * sub:(i + 1) * sub], axis=0, keepdims=True), 0.0)
+             for i, r in enumerate(dref))
+    dGl = jnp.sum(h, axis=0, keepdims=True) + ddecay * x["decay"]
+    dq = dlhs_p * x["row"] + dqt * x["eG"]
+    dk = (dk + dlhs_a * (x["beta"] * x["row"])
+          + drhs_w * (x["beta"] * x["eG"]) + dkbar * x["ekb"])
+    dbeta = jnp.sum(dlhs_a * (kf * x["row"]) + drhs_w * (kf * x["eG"]),
+                    axis=1, keepdims=True) + jnp.sum(
+                        drhs_u * vf, axis=1, keepdims=True)
+    # G = cumsum(g), Gl the whole chunk's sum.
+    dg = (_dot((s_i >= t).astype(_F32), dG + dE, exact=True) - dE + dGl)
+    return dq, dk, drhs_u * x["beta"], dg, dbeta, ds0
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
+                o_ref, sf_ref, st_ref, t_ref, s_scr, inv_scr, *, scale, sub,
+                hb):
+    """A grid step: one chunk of `hb` neighbouring heads, each on its own
+    (independent chains of products, which the scheduler interleaves)."""
+    n = pl.program_id(2)
+    dk, dv = s_scr.shape[1:]
+
+    @pl.when(n == 0)
+    def _init():
+        s_scr[...] = s0_ref[0]
+
+    for j in range(hb):
+        kl, vl = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+        s = s_scr[j]
+        o, tb, s_next = _fwd_chunk(
+            q_ref[0, :, kl], k_ref[0, :, kl], v_ref[0, :, vl],
+            g_ref[0, :, kl],
+            _head_col(beta_ref[0], pl.program_id(1) * hb + j), s, scale, sub,
+            inv_scr)
+        o_ref[0, :, vl] = o.astype(o_ref.dtype)
+        st_ref[0, j, 0] = s
+        t_ref[0, j, 0] = tb
+        s_scr[j] = s_next
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _flush():
+        sf_ref[0] = s_scr[...]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, t_ref, do_ref,
+                dsf_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds0_ref,
+                ds_scr, *, scale, sub, hb):
+    """The reverse sweep's grid step: ds_scr holds the cotangent of the
+    chunk's end state on entry and of its start state on exit."""
+    n = pl.program_id(2)
+    dk, dv = ds_scr.shape[1:]
+
+    @pl.when(n == 0)
+    def _init():
+        ds_scr[...] = dsf_ref[0]
+
+    for j in range(hb):
+        kl, vl = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+        dq, dk_, dv_, dg, dbeta, ds0 = _bwd_chunk(
+            q_ref[0, :, kl], k_ref[0, :, kl], v_ref[0, :, vl],
+            g_ref[0, :, kl],
+            _head_col(beta_ref[0], pl.program_id(1) * hb + j),
+            st_ref[0, j, 0], t_ref[0, j, 0], do_ref[0, :, vl], ds_scr[j],
+            scale, sub)
+        dq_ref[0, :, kl] = dq.astype(dq_ref.dtype)
+        dk_ref[0, :, kl] = dk_.astype(dk_ref.dtype)
+        dv_ref[0, :, vl] = dv_.astype(dv_ref.dtype)
+        dg_ref[0, :, kl] = dg
+        db_ref[0, j] = _flip(dbeta)
+        ds_scr[j] = ds0
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _flush():
+        ds0_ref[0] = ds_scr[...]
+
+
+def _heads_per_step(H: int) -> int:
+    return 2 if H % 2 == 0 else 1
+
+
+def _specs(B, H, N, C, dk, dv, hb, rev):
+    """BlockSpecs over the grid (batch, head block, chunk). q, k, v, g lie
+    [B, S, H*d]: a head is a d-lane column block, read where it lies."""
+    nn = (lambda n: N - 1 - n) if rev else (lambda n: n)
+    tok = lambda d: pl.BlockSpec((1, C, hb * d),
+                                 lambda b, h, n: (b, nn(n), h))
+    return dict(
+        k=tok(dk), v=tok(dv),
+        beta=pl.BlockSpec((1, C, H), lambda b, h, n: (b, nn(n), 0)),
+        state=pl.BlockSpec((1, hb, dk, dv), lambda b, h, n: (b, h, 0, 0)),
+        states=pl.BlockSpec((1, hb, 1, dk, dv),
+                            lambda b, h, n: (b, h, nn(n), 0, 0)),
+        tinv=pl.BlockSpec((1, hb, 1, C, C),
+                          lambda b, h, n: (b, h, nn(n), 0, 0)),
+        brow=pl.BlockSpec((1, hb, 1, C), lambda b, h, n: (b, h, 0, nn(n))))
+
+
+def _call(kernel, rev, operands, ins, outs, C, scratch=(), **kw):
+    """The pallas_call both kernels make, under the `kda.core` scope
+    (chipbench/reduce/scopes.py finds the core's device time by it).
+    `operands` start with q, k, v, g, beta; `ins` are their `_specs` keys,
+    `outs` (key, dtype) of each result."""
+    q, v, beta = operands[0], operands[2], operands[4]
+    B, S, H = beta.shape
+    dk, dv, N = q.shape[-1] // H, v.shape[-1] // H, S // C
+    hb = _heads_per_step(H)
+    sp = _specs(B, H, N, C, dk, dv, hb, rev)
+    shape = dict(k=q.shape, v=v.shape, state=(B, H, dk, dv),
+                 states=(B, H, N, dk, dv), tinv=(B, H, N, C, C),
+                 brow=(B, H, 1, S))
+    with jax.named_scope("kda.core"):
+        return pl.pallas_call(
+            functools.partial(kernel, hb=hb, **kw),
+            grid=(B, H // hb, N),
+            in_specs=[sp[i] for i in ins],
+            out_specs=[sp[o] for o, _ in outs],
+            out_shape=[jax.ShapeDtypeStruct(shape[o], dt) for o, dt in outs],
+            scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32), *scratch],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+        )(*operands)
+
+
+def _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub):
+    """q, k, g [B, S, H*dk], v [B, S, H*dv], beta [B, S, H], s0 [B,H,dk,dv]
+    -> o [B, S, H*dv], the final state, the chunk-start states
+    [B,H,N,dk,dv] float32 and the chunks' inverses [B,H,N,C,C]."""
+    return _call(
+        _fwd_kernel, False, (q, k, v, g, beta, s0),
+        ins=("k", "k", "v", "k", "beta", "state"),
+        outs=(("v", v.dtype), ("state", _F32), ("states", _F32),
+              ("tinv", q.dtype)),
+        C=C, scratch=[pltpu.VMEM((C, C), _F32)], scale=scale, sub=sub)
+
+
+def _kda_bwd_call(q, k, v, g, beta, states, tinv, do, dsf, scale, C, sub):
+    dq, dk, dv, dg, db, ds0 = _call(
+        _bwd_kernel, True, (q, k, v, g, beta, states, tinv, do, dsf),
+        ins=("k", "k", "v", "k", "beta", "states", "tinv", "v", "state"),
+        outs=(("k", q.dtype), ("k", k.dtype), ("v", v.dtype), ("k", _F32),
+              ("brow", _F32), ("state", _F32)),
+        C=C, scale=scale, sub=sub)
+    return dq, dk, dv, dg, jnp.swapaxes(db[:, :, 0], 1, 2), ds0
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _kda_kernels(q, k, v, g, beta, s0, scale, C, sub):
+    o, sf, _, _ = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub)
+    return o, sf
+
+
+def _vjp_fwd(q, k, v, g, beta, s0, scale, C, sub):
+    o, sf, states, tinv = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub)
+    o, states, tinv = (checkpoint_name(a, n) for a, n in zip(
+        (o, states, tinv), RESIDUAL_NAMES))
+    return (o, sf), (q, k, v, g, beta, states, tinv)
+
+
+def _vjp_bwd(scale, C, sub, res, cts):
+    do, dsf = cts
+    return _kda_bwd_call(*res, do, dsf.astype(_F32), scale, C, sub)
+
+
+_kda_kernels.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def kda_chunked_pallas(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
+                       scale: Optional[float] = None,
+                       initial_state: Optional[jax.Array] = None
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """`kda_chunked_xla` as two Pallas kernels under a custom VJP (module
+    docstring). The dispatcher `kda_chunked` comes here on the TPU; tests
+    come here directly and run the kernels in interpret mode."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    scale = dk ** -0.5 if scale is None else scale
+    C = chunk
+    sub = min(sub, C)
+    assert C % sub == 0 and sub & (sub - 1) == 0, (C, sub)
+    q, k, v, g, beta = _pad_to_chunks(q, k, v, g, beta, C)
+    flat = lambda a: a.reshape(B, -1, H * a.shape[-1])
+    s0 = (jnp.zeros((B, H, dk, dv), _F32) if initial_state is None
+          else initial_state.astype(_F32))
+    o, s = _kda_kernels(flat(q), flat(k.astype(q.dtype)),
+                        flat(v.astype(q.dtype)), flat(g.astype(_F32)),
+                        beta.astype(_F32), s0, scale, C, sub)
+    return o.reshape(B, -1, H, dv)[:, :S].astype(v.dtype), s
+
+
+# ---------------------------------------------------------------- dispatch
+
+
+def use_kernels(platform: str, d_k: int, d_v: int, chunk: int,
+                on_mesh: bool) -> bool:
+    """The dispatch rule, a pure function of what the code observes: the
+    kernels on a TPU, with keys, values and chunk whole 128-lane tiles and
+    no multi-device mesh (a Mosaic call cannot be partitioned by GSPMD; it
+    would need `shard_map`, as ops/attention.py does for flash)."""
+    return (platform == "tpu" and not on_mesh
+            and d_k % 128 == 0 and d_v % 128 == 0 and chunk % 128 == 0)
+
+
+def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
+                scale: Optional[float] = None,
+                initial_state: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Chunked KDA, same arguments and results as `kda_recurrent`: the
+    Pallas kernels where `use_kernels` says so, else `kda_chunked_xla`.
+    Each traced call counts once in the phase table, as `kda.core.pallas`
+    or `kda.core.xla` (layers under one scan trace once)."""
+    from ray_tpu.parallel.sharding import current_sharding_ctx
+    from ray_tpu.util import tracing
+
+    ctx = current_sharding_ctx()
+    kernels = use_kernels(jax.devices()[0].platform, q.shape[-1], v.shape[-1],
+                          chunk, ctx is not None and ctx[0].size > 1)
+    tracing.observe("kda.core.pallas" if kernels else "kda.core.xla", 0,
+                    slow=False)
+    body = kda_chunked_pallas if kernels else kda_chunked_xla
+    return body(q, k, v, g, beta, chunk=chunk, sub=sub, scale=scale,
+                initial_state=initial_state)

@@ -1,0 +1,59 @@
+"""The KDA kernels compiled for a v5e at the cell's real shape, with no chip:
+the TPU compiler is installed here and compiles for a described device.
+Interpret mode (tests/test_kimi_linear.py) cannot see what Mosaic refuses
+(unaligned slices, VMEM over the limit, an op with no lowering). Nothing
+runs, so this says nothing about results or times. The topology is described
+inside a fixture, never at import (one process at a time may load libtpu)."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops import kda
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_not_interpreted(monkeypatch):
+    """The platform here is cpu, which `_interpret()` reads; and a compile
+    for a device that is not attached must not be read back from the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_kda_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
+                                     what):
+    """1 x 8192 tokens, 32 heads of 128, chunks of 128, bfloat16: the shape
+    of `kimi_linear_48b_a3b.train_share_8k`. The program holds the two
+    Mosaic calls and no [B,H,S,d] copy of an input."""
+    B, S, H, d = 1, 8192, 32, 128
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    q = sd((B, S, H, d), jnp.bfloat16)
+    g, beta = sd((B, S, H, d), jnp.float32), sd((B, S, H), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        o, _ = kda.kda_chunked_pallas(q, k, v, g, beta, chunk=128)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    fn = loss if what == "forward" else jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(fn).lower(q, q, q, g, beta).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if what == "forward" else 2)
