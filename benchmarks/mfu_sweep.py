@@ -84,7 +84,6 @@ if __name__ == "__main__":
     variants = [
         (True, "full", 8, 1024, True),
         (True, "dots", 8, 1024, True),        # the default policy
-        (True, "min", 8, 1024, True),
         (False, None, 8, 1024, True),         # no remat
         (True, "dots", 16, 1024, True),       # bigger matmul M
     ]

@@ -26,13 +26,14 @@ import jax.numpy as jnp
 
 from .transformer import (
     TransformerConfig,
-    _layer_body_kv,
+    _layer_body,
     _mlp_block,
     _norm,
     _qkv_proj,
     _w,
     embed_tokens,
     final_hidden_and_head,
+    one_kind_stack,
 )
 
 Params = Dict[str, jax.Array]
@@ -44,6 +45,20 @@ class KVCache(NamedTuple):
     # Tokens filled so far: [] int32 (uniform batch) or [B] int32 (ragged
     # batch — per-row prompt lengths; decode masks and writes per row).
     pos: jax.Array
+
+
+def _kv_stack(params: Params, cfg: TransformerConfig):
+    """(kind, leaves [L, ...]) of the one stacked tree the cache's [L, ...]
+    keys and values are scanned beside; what decode cannot serve is refused
+    (ROADMAP R7)."""
+    if any(mixer != "attn" for mixer, _ in cfg.layer_kinds()):
+        raise NotImplementedError(
+            "decode holds keys and values only: a stack with KDA / MLA "
+            "layers trains but does not serve yet")
+    if cfg.moe_held is not None:
+        raise NotImplementedError(
+            f"decode needs every expert held, not moe_held={cfg.moe_held}")
+    return one_kind_stack(params, cfg, "decoding")
 
 
 def prefill(params: Params, tokens: jax.Array, cfg: TransformerConfig,
@@ -60,11 +75,7 @@ def prefill(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     BEFORE the attention einsum runs (the valid mask is slot <= pos[i],
     which includes the just-written slot — ordering of _write before
     attend in decode_step's body is load-bearing)."""
-    if cfg.mixed:
-        raise NotImplementedError(
-            "decode holds keys and values only: a stack with KDA / MLA "
-            "layers or held experts (cfg.mixed) trains but does not serve "
-            "yet (ROADMAP R7)")
+    kind, layers = _kv_stack(params, cfg)
     B, S = tokens.shape
     if S > max_len:
         raise ValueError(f"prompt length {S} exceeds cache max_len {max_len}")
@@ -72,10 +83,11 @@ def prefill(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
     def body(carry, layer):
-        x, k, v = _layer_body_kv(cfg, carry, layer, positions)
+        x, _, k, v = _layer_body(cfg, kind, carry, layer, positions,
+                                 return_kv=True)
         return x, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
+    x, (ks, vs) = jax.lax.scan(body, x, layers)
     # Pad [L, B, S, KVH, hd] out to the static max_len.
     pad = [(0, 0), (0, 0), (0, max_len - S), (0, 0), (0, 0)]
     if lengths is None:
@@ -99,6 +111,7 @@ def decode_step(params: Params, cache: KVCache, token: jax.Array,
             "decode_step: learned positional embeddings index by absolute "
             "position, which embed_tokens applies only for full sequences; "
             "use rope (the flagship configs) for incremental decoding")
+    (_, ffn), layers = _kv_stack(params, cfg)
     B = token.shape[0]
     H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     max_len = cache.k.shape[2]
@@ -159,11 +172,10 @@ def decode_step(params: Params, cache: KVCache, token: jax.Array,
 
         h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
                   cfg.norm_eps)
-        delta, _aux = _mlp_block(cfg, h, layer)
-        x = x + delta
+        x = x + _mlp_block(cfg, ffn, h, layer)[0]
         return x, (ck, cv)
 
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
+    x, (nk, nv) = jax.lax.scan(body, x, (layers, cache.k, cache.v))
     x, head = final_hidden_and_head(params, x, cfg)
     logits = (x @ head).astype(jnp.float32)[:, 0]
     return logits, KVCache(k=nk, v=nv, pos=pos + 1)

@@ -6,8 +6,11 @@ Design choices driven by XLA/TPU, not by the reference (which has no models —
 it hosts torch):
 - Pure functional: params are a pytree of arrays; no module framework in the
   hot path, nothing to trace but array math.
-- Layers are stacked and iterated with lax.scan → one compiled layer body,
-  O(1) compile time in depth, and the natural seam for pipeline parallelism.
+- The stack is a plan of segments (`TransformerConfig.stack_plan`): runs of
+  layers whose kinds (mixer, feed-forward) repeat, each leaf stacked over the
+  repeats and iterated with one lax.scan → compile time follows the number
+  of segments and not the depth, and a stack of one kind (a dense decoder)
+  is one scan, the natural seam for pipeline parallelism.
 - Every array dim carries a logical axis name; `param_logical_specs` returns
   the matching pytree so any sharding strategy (DP/FSDP/TP/SP) is a rule
   table away (ray_tpu.parallel.sharding).
@@ -17,7 +20,6 @@ it hosts torch):
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -49,19 +51,13 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    # Remat granularity when remat=True:
+    # Remat granularity when remat=True, every layer alike:
     # - "full": recompute the whole layer body in the backward (max memory
     #   saving, ~33% extra FLOPs, flash forward kernel included).
     # - "dots": save matmul outputs and the kernels' own residuals (flash: o
     #   [B,H,S,hd] and lse [B,H,S]; KDA: o, chunk states, inverses; named in
     #   their forward rules, RESIDUAL_NAMES of ops/flash_attention.py and
     #   ops/kda.py); recompute the rest. A forward kernel runs once a layer.
-    # - "half_dots" / "half_full": the first half of the stack under
-    #   "dots" / "full", the second half without remat.
-    # - "min": save everything except the two fat fused-projection outputs
-    #   (qkv and gate_up, tagged via checkpoint_name below) — flash
-    #   residuals stay saved, recompute is one einsum + elementwise. The
-    #   cheapest policy that still bounds activation memory.
     # Default "dots": keeping o and lse takes the second forward-kernel call
     # out of every layer's backward (gpt2_124m, batch 16 x 1024, one v5e
     # chip: step 184.2 -> 179.5 ms, step memory 13.96 -> 15.19 GB; chip
@@ -129,26 +125,21 @@ class TransformerConfig:
             v = getattr(self, name)
             if isinstance(v, list):  # from a JSON file
                 object.__setattr__(self, name, tuple(v))
+        if self.remat_policy not in ("dots", "full"):
+            raise ValueError(f"unknown remat_policy {self.remat_policy!r}: "
+                             "'dots' or 'full'")
         if self.moe_router not in ("softmax_capacity", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if set(self.kda_layers) & set(self.mla_layers):
             raise ValueError("a layer is listed as both kda and mla")
-        if self.mixed and self.moe_num_experts and self.moe_router != "sigmoid":
+        if (self.moe_num_experts and self.moe_router != "sigmoid"
+                and any(m != "attn" for m, _ in self.layer_kinds())):
             raise ValueError(
                 "kda / mla layers compose with moe_router='sigmoid' only")
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
-
-    @property
-    def mixed(self) -> bool:
-        """Whether the stack has layers of more than the one classic kind
-        (softmax attention + dense or GShard MLP): its parameters are then a
-        list of segments (`stack_plan`), not one stacked tree."""
-        L = self.n_layers
-        return (any(l <= L for l in self.kda_layers + self.mla_layers)
-                or (self.moe_num_experts > 0 and self.moe_router == "sigmoid"))
 
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """(mixer, feed-forward) of every layer: mixer attn | mla | kda,
@@ -170,7 +161,8 @@ class TransformerConfig:
         from each layer on, the period (up to 8) whose repeats cover the
         most layers; a layer that starts no repeat is a segment of its own.
         Each segment is one `lax.scan` over its repeats, so compile time
-        follows the number of segments and not the depth."""
+        follows the number of segments and not the depth. A stack of one
+        kind is one segment: (((kind,), n_layers),)."""
         kinds, plan, i = self.layer_kinds(), [], 0
         while i < len(kinds):
             best = (1, 1)
@@ -186,8 +178,8 @@ class TransformerConfig:
         return tuple(plan)
 
     def layer_slot(self, l: int) -> Tuple[int, int, int]:
-        """Where layer l (0-based) lives in a mixed stack's parameters:
-        params["layers"][segment][position], row `repeat` of each leaf."""
+        """Where layer l (0-based) lives in `stack_segments(params, cfg)`:
+        [segment][position], row `repeat` of each leaf."""
         i = 0
         for seg, (pattern, r) in enumerate(self.stack_plan()):
             n = len(pattern) * r
@@ -276,16 +268,11 @@ class TransformerConfig:
         return int(self.num_params() - full + active)
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Forward+backward FLOPs/token. Classic stacks: 6*N_active +
-        12*L*S*d (attn, not halved by causality, embedding counted: the
-        number the older benchmarks quote). Mixed stacks: 6 per matmul
-        parameter a token touches (no embedding lookup), causal attention
-        3*S*H*(d_qk + d_v) a softmax layer, and the chunked algorithm's
-        operations a KDA layer (see chipbench/reduce/kda_counts.py)."""
+        """Forward+backward FLOPs/token: 6 per matmul parameter a token
+        touches (no embedding lookup), causal attention 3*S*H*(d_qk + d_v) a
+        softmax layer, and the chunked algorithm's operations a KDA layer
+        (see chipbench/reduce/kda_counts.py)."""
         S = seq_len or self.max_seq_len
-        if not self.mixed:
-            return (6.0 * self.num_active_params()
-                    + 12.0 * self.n_layers * S * self.d_model)
         d, H = self.d_model, self.n_heads
         n = self.num_active_params() - self.vocab_size * d  # the lookup
         if self.positional == "learned":
@@ -305,78 +292,177 @@ class TransformerConfig:
         return total
 
 
-def _dense_init(key, shape, param_dtype, scale: Optional[float] = None):
-    fan_in = shape[0]
-    std = scale if scale is not None else (1.0 / math.sqrt(fan_in))
-    return (jax.random.normal(key, shape) * std).astype(param_dtype)
+def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
+    """{leaf: (shape, logical axes, init)} of one layer of `kind`: THE table
+    `init_params` and `param_logical_specs` are built from. init: "ones" |
+    "zeros" | ("normal", std) | a callable key -> array."""
+    mixer, ffn = kind
+    d, L = cfg.d_model, cfg.n_layers
+    H = cfg.n_heads
+    fan = lambda n: ("normal", 1.0 / math.sqrt(n))
+    out_std = lambda n: ("normal", 1.0 / math.sqrt(2 * L * n))
+    sh: Dict[str, Any] = {
+        "attn_norm": ((d,), (None,), "ones"),
+        "mlp_norm": ((d,), (None,), "ones"),
+    }
+    if mixer == "attn":
+        # Projections are FUSED into single matmuls (one MXU op instead of
+        # 2-3: q/k/v together for MHA, k/v together for GQA, gate/up together
+        # for swiglu). The fusion factor is its own array dim — NOT folded
+        # into the feature dim — so tensor-parallel sharding of heads/mlp
+        # stays aligned to shard boundaries (Megatron fused-qkv, done the
+        # GSPMD-friendly way).
+        hd, KVH = cfg.head_dim, cfg.kv_heads
+        sh["wo"] = ((H * hd, d), ("heads", "embed"), out_std(H * hd))
+        if KVH == H:
+            sh["wqkv"] = ((d, 3, H, hd), ("embed", None, "heads", None), fan(d))
+        else:
+            sh["wq"] = ((d, H, hd), ("embed", "heads", None), fan(d))
+            sh["wkv"] = ((d, 2, KVH, hd), ("embed", None, "kv_heads", None),
+                         fan(d))
+    elif mixer == "mla":
+        lat, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+        sh["mla_wq"] = ((d, H, nope + rope), ("embed", "heads", None), fan(d))
+        sh["mla_wkva"] = ((d, lat + rope), ("embed", None), fan(d))
+        sh["mla_kv_norm"] = ((lat,), (None,), "ones")
+        sh["mla_wkvb"] = ((lat, H, nope + dv), (None, "heads", None), fan(lat))
+        sh["mla_wo"] = ((H, dv, d), ("heads", None, "embed"), out_std(H * dv))
+    else:
+        Hk, hd = cfg.kda_n_heads, cfg.kda_head_dim
+        rank, K = cfg.kda_gate_rank or hd, cfg.kda_conv
+        for n in ("q", "k", "v"):
+            sh["kda_w" + n] = ((d, Hk, hd), ("embed", "heads", None), fan(d))
+            sh["kda_conv_" + n] = ((K, Hk, hd), (None, "heads", None),
+                                   fan(K))
+        for n in ("f", "g"):  # decay and output gate, low rank
+            sh[f"kda_w{n}1"] = ((d, rank), ("embed", None), fan(d))
+            sh[f"kda_w{n}2"] = ((rank, Hk, hd), (None, "heads", None),
+                                fan(rank))
+        # Decay a = exp(-exp(A_log) * softplus(. + dt_bias)): A in [1, 16],
+        # softplus(dt_bias) in [0.001, 0.1], log-uniform (the Mamba family's
+        # parametrisation, which the gated delta rules follow).
+        sh["kda_A_log"] = ((Hk,), ("heads",), lambda k: jnp.log(
+            jax.random.uniform(k, (Hk,), minval=1.0, maxval=16.0)))
+
+        def dt_bias(k):
+            dt = jnp.exp(jax.random.uniform(
+                k, (Hk, hd), minval=math.log(1e-3), maxval=math.log(1e-1)))
+            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+
+        sh["kda_dt_bias"] = ((Hk, hd), ("heads", None), dt_bias)
+        sh["kda_wb"] = ((d, Hk), ("embed", "heads"), fan(d))
+        sh["kda_o_norm"] = ((hd,), (None,), "ones")
+        sh["kda_wo"] = ((Hk, hd, d), ("heads", None, "embed"),
+                        out_std(Hk * hd))
+    if ffn == "dense":
+        F = cfg.ff_dim
+        sh["w_down"] = ((F, d), ("mlp", "embed"), out_std(F))
+        if cfg.activation == "swiglu":
+            sh["w_gate_up"] = ((d, 2, F), ("embed", None, "mlp"), fan(d))
+        else:
+            sh["w_up"] = ((d, F), ("embed", "mlp"), fan(d))
+    else:
+        E, F = cfg.moe_num_experts, cfg.moe_ff_dim
+        sigmoid = cfg.moe_router == "sigmoid"
+        Eh = cfg.moe_held_range[1] if sigmoid else E  # GShard holds them all
+        sh["router"] = ((d, E), ("embed", None), fan(d))
+        if sigmoid:
+            sh["router_bias"] = ((E,), (None,), "zeros")  # a buffer: no gradient
+        # The scales are the matmuls' fan-ins d and F, not the leading dim E.
+        sh["moe_w_gate_up"] = ((Eh, d, 2, F), ("expert", "embed", None, "mlp"),
+                               fan(d))
+        sh["moe_w_down"] = ((Eh, F, d), ("expert", "mlp", "embed"), out_std(F))
+        Fs = cfg.moe_shared_experts * F if sigmoid else 0
+        if Fs:
+            sh["shared_w_gate_up"] = ((d, 2, Fs), ("embed", None, "mlp"),
+                                      fan(d))
+            sh["shared_w_down"] = ((Fs, d), ("mlp", "embed"), out_std(Fs))
+    if cfg.norm == "layernorm":
+        sh["attn_norm_b"] = ((d,), (None,), "zeros")
+        sh["mlp_norm_b"] = ((d,), (None,), "zeros")
+    return sh
+
+
+# params["layers"] is stored in one of two formats, both part of what callers
+# outside this file build and read (chipbench/weights*.py, generate, quantize,
+# pipeline): a stack of softmax-attention layers with dense or GShard
+# feed-forwards is ONE dict of leaves [L, ...]; every other stack is a list of
+# segments (cfg.stack_plan()), a segment a list over its pattern's positions
+# of one layer kind's leaves, each stacked over the segment's repeats. The
+# three functions below are the only code that knows which.
+
+
+def _one_tree(cfg: TransformerConfig) -> bool:
+    return (all(m == "attn" for m, _ in cfg.layer_kinds())
+            and not (cfg.moe_num_experts > 0 and cfg.moe_router == "sigmoid"))
+
+
+def stack_segments(params: Params, cfg: TransformerConfig):
+    """params["layers"] as the list of segments `cfg.stack_plan()` describes,
+    whichever way it is stored (`params` may be any tree of that structure:
+    gradients, `param_logical_specs`)."""
+    return [[params["layers"]]] if _one_tree(cfg) else params["layers"]
+
+
+def _stored(segments, cfg: TransformerConfig):
+    """The inverse: segments as params["layers"] stores them."""
+    return segments[0][0] if _one_tree(cfg) else segments
+
+
+def one_kind_stack(params: Params, cfg: TransformerConfig, what: str):
+    """(kind, leaves [L, ...]) of a stack that is one segment of layers of
+    one kind: the only stack `what` (decoding, pipeline parallelism) takes,
+    because it scans or cuts ONE stacked tree."""
+    plan = cfg.stack_plan()
+    if len(plan) != 1 or len(plan[0][0]) != 1:
+        raise NotImplementedError(
+            f"{what} takes one segment of layers of one kind; this stack's "
+            f"plan is {plan}")
+    return plan[0][0][0], stack_segments(params, cfg)[0][0]
+
+
+def layer_params(params: Params, cfg: TransformerConfig, l: int) -> Params:
+    """The parameters of layer l (0-based), whichever way they are stacked."""
+    seg, pos, rep = cfg.layer_slot(l)
+    return jax.tree.map(lambda a: a[rep], stack_segments(params, cfg)[seg][pos])
 
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
-    d, L, V, F = cfg.d_model, cfg.n_layers, cfg.vocab_size, cfg.ff_dim
-    H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    keys = jax.random.split(key, 12)
-    if cfg.mixed:
-        return _top_params(keys, cfg, _init_mixed_layers(keys[0], cfg))
-
-    def stack(initializer, shape, k):
-        ks = jax.random.split(k, L)
-        return jnp.stack([initializer(ks[i], shape, cfg.param_dtype) for i in range(L)])
-
-    # Projections are FUSED into single matmuls (one MXU op instead of 2-3:
-    # q/k/v together for MHA, k/v together for GQA, gate/up together for
-    # swiglu). The fusion factor is its own array dim — NOT folded into the
-    # feature dim — so tensor-parallel sharding of heads/mlp stays aligned
-    # to shard boundaries (Megatron fused-qkv, done the GSPMD-friendly way).
-    layers = {
-        "attn_norm": jnp.ones((L, d), cfg.param_dtype),
-        "wo": stack(lambda k, s, pd: _dense_init(k, s, pd, scale=1.0 / math.sqrt(2 * L * s[0])),
-                    (H * hd, d), keys[3]),
-        "mlp_norm": jnp.ones((L, d), cfg.param_dtype),
-    }
-    if not cfg.moe_num_experts:
-        layers["w_down"] = stack(
-            lambda k, s, pd: _dense_init(k, s, pd,
-                                         scale=1.0 / math.sqrt(2 * L * s[0])),
-            (F, d), keys[5])
-    if KVH == H:
-        layers["wqkv"] = stack(_dense_init, (d, 3, H, hd), keys[0])
-    else:
-        layers["wq"] = stack(_dense_init, (d, H, hd), keys[0])
-        layers["wkv"] = stack(_dense_init, (d, 2, KVH, hd), keys[1])
-    if cfg.moe_num_experts:
-        E = cfg.moe_num_experts
-        layers["router"] = stack(_dense_init, (d, E), keys[6])
-        # Explicit scales: _dense_init's shape[0] fan-in heuristic would read
-        # E (the expert dim) instead of the real matmul fan-ins d and F.
-        layers["moe_w_gate_up"] = stack(
-            lambda k, s, pd: _dense_init(k, s, pd, scale=1.0 / math.sqrt(d)),
-            (E, d, 2, F), keys[4])
-        layers["moe_w_down"] = stack(
-            lambda k, s, pd: _dense_init(k, s, pd,
-                                         scale=1.0 / math.sqrt(2 * L * F)),
-            (E, F, d), keys[5])
-    elif cfg.activation == "swiglu":
-        layers["w_gate_up"] = stack(_dense_init, (d, 2, F), keys[4])
-    else:
-        layers["w_up"] = stack(_dense_init, (d, F), keys[4])
-    if cfg.norm == "layernorm":
-        layers["attn_norm_b"] = jnp.zeros((L, d), cfg.param_dtype)
-        layers["mlp_norm_b"] = jnp.zeros((L, d), cfg.param_dtype)
-
-    return _top_params(keys, cfg, layers)
-
-
-def _top_params(keys, cfg: TransformerConfig, layers) -> Params:
     d, V = cfg.d_model, cfg.vocab_size
+    keys = jax.random.split(key, 12)
+
+    def leaf(k, r, shape, init):
+        if init == "ones":
+            return jnp.ones((r,) + shape, cfg.param_dtype)
+        if init == "zeros":
+            return jnp.zeros((r,) + shape, cfg.param_dtype)
+        if callable(init):
+            make = init
+        else:
+            make = lambda kk: jax.random.normal(kk, shape) * init[1]
+        return jnp.stack([make(kk) for kk in jax.random.split(k, r)]
+                         ).astype(cfg.param_dtype)
+
+    segments = []
+    for si, (pattern, r) in enumerate(cfg.stack_plan()):
+        seg = []
+        for pi, kind in enumerate(pattern):
+            k = jax.random.fold_in(jax.random.fold_in(keys[0], si), pi)
+            seg.append({n: leaf(jax.random.fold_in(k, i), r, shape, init)
+                        for i, (n, (shape, _, init)) in enumerate(
+                            _layer_shapes(cfg, kind).items())})
+        segments.append(seg)
     params: Params = {
         "embed": (jax.random.normal(keys[7], (V, d)) * 0.02).astype(cfg.param_dtype),
         "final_norm": jnp.ones((d,), cfg.param_dtype),
-        "layers": layers,
+        "layers": _stored(segments, cfg),
     }
     if cfg.norm == "layernorm":
         params["final_norm_b"] = jnp.zeros((d,), cfg.param_dtype)
     if not cfg.tie_embeddings:
-        params["lm_head"] = _dense_init(keys[8], (d, V), cfg.param_dtype, scale=0.02)
+        params["lm_head"] = (jax.random.normal(keys[8], (d, V)) * 0.02
+                             ).astype(cfg.param_dtype)
     if cfg.positional == "learned":
         params["pos_embed"] = (
             jax.random.normal(keys[9], (cfg.max_seq_len, d)) * 0.02
@@ -387,40 +473,16 @@ def _top_params(keys, cfg: TransformerConfig, layers) -> Params:
 def param_logical_specs(cfg: TransformerConfig) -> Params:
     """Pytree of logical axis names matching init_params' structure
     (consumed by parallel.sharding.tree_shardings)."""
-    # The leading dim is the layer stack: logical axis "layers" maps onto
-    # the `pipe` mesh axis so each pipeline stage holds a contiguous range
-    # of layers (parallel/pipeline.py).
-    layers = {
-        "attn_norm": ("layers", None),
-        "wo": ("layers", "heads", "embed"),
-        "mlp_norm": ("layers", None),
-    }
-    if not cfg.moe_num_experts:
-        layers["w_down"] = ("layers", "mlp", "embed")
-    if cfg.kv_heads == cfg.n_heads:
-        layers["wqkv"] = ("layers", "embed", None, "heads", None)
-    else:
-        layers["wq"] = ("layers", "embed", "heads", None)
-        layers["wkv"] = ("layers", "embed", None, "kv_heads", None)
-    if cfg.moe_num_experts:
-        layers["router"] = ("layers", "embed", None)
-        layers["moe_w_gate_up"] = ("layers", "expert", "embed", None, "mlp")
-        layers["moe_w_down"] = ("layers", "expert", "mlp", "embed")
-    elif cfg.activation == "swiglu":
-        layers["w_gate_up"] = ("layers", "embed", None, "mlp")
-    else:
-        layers["w_up"] = ("layers", "embed", "mlp")
-    if cfg.norm == "layernorm":
-        layers["attn_norm_b"] = ("layers", None)
-        layers["mlp_norm_b"] = ("layers", None)
-    if cfg.mixed:  # a list of segments, as init_params builds it
-        layers = [[{n: ("layers",) + axes for n, (_, axes, _) in
-                    _mixed_layer_shapes(cfg, kind).items()}
-                   for kind in pattern] for pattern, _ in cfg.stack_plan()]
+    # A leaf's leading dim is the layer stack: logical axis "layers" maps
+    # onto the `pipe` mesh axis so each pipeline stage holds a contiguous
+    # range of layers (parallel/pipeline.py).
+    segments = [[{n: ("layers",) + axes for n, (_, axes, _) in
+                  _layer_shapes(cfg, kind).items()}
+                 for kind in pattern] for pattern, _ in cfg.stack_plan()]
     specs: Params = {
         "embed": ("vocab", "embed"),
         "final_norm": (None,),
-        "layers": layers,
+        "layers": _stored(segments, cfg),
     }
     if cfg.norm == "layernorm":
         specs["final_norm_b"] = (None,)
@@ -489,187 +551,44 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
     return q, k, v
 
 
-def _mlp_block(cfg: TransformerConfig, h: jax.Array, layer: Params,
-               dense: bool = False):
-    """Post-attention FFN (GShard moe / swiglu / gelu), shared with the
-    decode path; returns (delta, moe_aux). `dense`: this layer has the dense
-    MLP whatever cfg.moe_num_experts says (a mixed stack's lead layers)."""
-    if cfg.moe_num_experts and not dense:
+def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
+               layer: Params):
+    """Post-mixer feed-forward of kind `ffn`, shared with the decode path ->
+    (delta, extras): {} for the dense MLP (SwiGLU where the layer has a
+    fused gate/up leaf, else GELU), {"aux": balancing loss} for GShard
+    experts, the routing counters of ops/moe.py `moe_ffn_held` for
+    sigmoid-routed ones."""
+    if ffn == "dense":
+        if "w_gate_up" in layer:
+            gu = jnp.einsum("bsd,dcf->bscf", h, _w(layer, "w_gate_up", cfg))
+            gu = checkpoint_name(gu, "gate_up")
+            act = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
+        else:
+            act = checkpoint_name(h @ _w(layer, "w_up", cfg), "gate_up")
+            act = jax.nn.gelu(act)
+        return act @ _w(layer, "w_down", cfg), {}
+    if cfg.moe_router != "sigmoid":
         from ray_tpu.ops.moe import moe_ffn
 
-        return moe_ffn(
+        delta, aux = moe_ffn(
             h, layer["router"], layer["moe_w_gate_up"], layer["moe_w_down"],
             experts_per_token=cfg.moe_experts_per_token,
             capacity_factor=cfg.moe_capacity_factor,
             dtype=cfg.dtype)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.activation == "swiglu":
-        gu = jnp.einsum("bsd,dcf->bscf", h, _w(layer, "w_gate_up", cfg))
-        gu = checkpoint_name(gu, "gate_up")
-        act = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
-        return act @ _w(layer, "w_down", cfg), aux
-    act = checkpoint_name(h @ _w(layer, "w_up", cfg), "gate_up")
-    act = jax.nn.gelu(act)
-    return act @ _w(layer, "w_down", cfg), aux
+        return delta, {"aux": aux}
+    from ray_tpu.ops.moe import moe_ffn_held
 
-
-def _layer_body(cfg: TransformerConfig, x: jax.Array, layer: Params,
-                positions: jax.Array, return_kv: bool = False):
-    B, S, d = x.shape
-    H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-
-    h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
-              cfg.norm_eps)
-    q, k, v = _qkv_proj(cfg, h, layer, positions)
-    q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
-    o = attention(q, k, v, causal=True)
-    x = x + o.reshape(B, S, H * hd) @ _w(layer, "wo", cfg)
-    x = maybe_constrain(x, ("batch", "seq_act", "embed"))
-
-    h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
-              cfg.norm_eps)
-    delta, aux = _mlp_block(cfg, h, layer)
-    x = x + delta
-    x = maybe_constrain(x, ("batch", "seq_act", "embed"))
-    if return_kv:
-        return x, aux, k, v
-    return x, aux
-
-
-def _layer_body_kv(cfg: TransformerConfig, x: jax.Array, layer: Params,
-                   positions: jax.Array):
-    """Layer forward that also surfaces this layer's (roped) K/V — the
-    prefill path of models/generate.py primes its cache from these."""
-    x, _aux, k, v = _layer_body(cfg, x, layer, positions, return_kv=True)
-    return x, k, v
-
-
-# ------------------------------------------------------------ mixed stacks
-# A stack whose layers are of more than one kind (cfg.mixed): KDA or MLA
-# mixers, sigmoid-routed experts beside dense layers. params["layers"] is a
-# list of segments (cfg.stack_plan()), a segment a list over its pattern's
-# positions of one layer-kind's parameters, each leaf stacked over the
-# segment's repeats. docs/model_layers.md.
-
-
-def _mixed_layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
-    """{leaf: (shape, logical axes, init)} of one layer of `kind`. init:
-    "ones" | "zeros" | ("normal", std) | a callable key -> array."""
-    mixer, ffn = kind
-    d, L = cfg.d_model, cfg.n_layers
-    H = cfg.n_heads
-    fan = lambda n: ("normal", 1.0 / math.sqrt(n))
-    out_std = lambda n: ("normal", 1.0 / math.sqrt(2 * L * n))
-    sh: Dict[str, Any] = {
-        "attn_norm": ((d,), (None,), "ones"),
-        "mlp_norm": ((d,), (None,), "ones"),
-    }
-    if mixer == "attn":
-        hd, KVH = cfg.head_dim, cfg.kv_heads
-        sh["wo"] = ((H * hd, d), ("heads", "embed"), out_std(H * hd))
-        if KVH == H:
-            sh["wqkv"] = ((d, 3, H, hd), ("embed", None, "heads", None), fan(d))
-        else:
-            sh["wq"] = ((d, H, hd), ("embed", "heads", None), fan(d))
-            sh["wkv"] = ((d, 2, KVH, hd), ("embed", None, "kv_heads", None),
-                         fan(d))
-    elif mixer == "mla":
-        lat, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-        nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-        sh["mla_wq"] = ((d, H, nope + rope), ("embed", "heads", None), fan(d))
-        sh["mla_wkva"] = ((d, lat + rope), ("embed", None), fan(d))
-        sh["mla_kv_norm"] = ((lat,), (None,), "ones")
-        sh["mla_wkvb"] = ((lat, H, nope + dv), (None, "heads", None), fan(lat))
-        sh["mla_wo"] = ((H, dv, d), ("heads", None, "embed"), out_std(H * dv))
-    else:
-        Hk, hd = cfg.kda_n_heads, cfg.kda_head_dim
-        rank, K = cfg.kda_gate_rank or hd, cfg.kda_conv
-        for n in ("q", "k", "v"):
-            sh["kda_w" + n] = ((d, Hk, hd), ("embed", "heads", None), fan(d))
-            sh["kda_conv_" + n] = ((K, Hk, hd), (None, "heads", None),
-                                   fan(K))
-        for n in ("f", "g"):  # decay and output gate, low rank
-            sh[f"kda_w{n}1"] = ((d, rank), ("embed", None), fan(d))
-            sh[f"kda_w{n}2"] = ((rank, Hk, hd), (None, "heads", None),
-                                fan(rank))
-        # Decay a = exp(-exp(A_log) * softplus(. + dt_bias)): A in [1, 16],
-        # softplus(dt_bias) in [0.001, 0.1], log-uniform (the Mamba family's
-        # parametrisation, which the gated delta rules follow).
-        sh["kda_A_log"] = ((Hk,), ("heads",), lambda k: jnp.log(
-            jax.random.uniform(k, (Hk,), minval=1.0, maxval=16.0)))
-
-        def dt_bias(k):
-            dt = jnp.exp(jax.random.uniform(
-                k, (Hk, hd), minval=math.log(1e-3), maxval=math.log(1e-1)))
-            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-
-        sh["kda_dt_bias"] = ((Hk, hd), ("heads", None), dt_bias)
-        sh["kda_wb"] = ((d, Hk), ("embed", "heads"), fan(d))
-        sh["kda_o_norm"] = ((hd,), (None,), "ones")
-        sh["kda_wo"] = ((Hk, hd, d), ("heads", None, "embed"),
-                        out_std(Hk * hd))
-    if ffn == "dense":
-        F = cfg.ff_dim
-        sh["w_down"] = ((F, d), ("mlp", "embed"), out_std(F))
-        if cfg.activation == "swiglu":
-            sh["w_gate_up"] = ((d, 2, F), ("embed", None, "mlp"), fan(d))
-        else:
-            sh["w_up"] = ((d, F), ("embed", "mlp"), fan(d))
-    else:
-        E, F = cfg.moe_num_experts, cfg.moe_ff_dim
-        Eh, Fs = cfg.moe_held_range[1], cfg.moe_shared_experts * F
-        sh["router"] = ((d, E), ("embed", None), fan(d))
-        sh["router_bias"] = ((E,), (None,), "zeros")  # a buffer: no gradient
-        sh["moe_w_gate_up"] = ((Eh, d, 2, F), ("expert", "embed", None, "mlp"),
-                               fan(d))
-        sh["moe_w_down"] = ((Eh, F, d), ("expert", "mlp", "embed"), out_std(F))
-        if Fs:
-            sh["shared_w_gate_up"] = ((d, 2, Fs), ("embed", None, "mlp"),
-                                      fan(d))
-            sh["shared_w_down"] = ((Fs, d), ("mlp", "embed"), out_std(Fs))
-    if cfg.norm == "layernorm":
-        sh["attn_norm_b"] = ((d,), (None,), "zeros")
-        sh["mlp_norm_b"] = ((d,), (None,), "zeros")
-    return sh
-
-
-def _init_mixed_layers(key: jax.Array, cfg: TransformerConfig):
-    def leaf(k, r, shape, init):
-        if init == "ones":
-            return jnp.ones((r,) + shape, cfg.param_dtype)
-        if init == "zeros":
-            return jnp.zeros((r,) + shape, cfg.param_dtype)
-        if callable(init):
-            make = init
-        else:
-            make = lambda kk: jax.random.normal(kk, shape) * init[1]
-        return jnp.stack([make(kk) for kk in jax.random.split(k, r)]
-                         ).astype(cfg.param_dtype)
-
-    segments = []
-    for si, (pattern, r) in enumerate(cfg.stack_plan()):
-        seg = []
-        for pi, kind in enumerate(pattern):
-            sh = _mixed_layer_shapes(cfg, kind)
-            k = jax.random.fold_in(jax.random.fold_in(key, si), pi)
-            seg.append({n: leaf(jax.random.fold_in(k, i), r, shape, init)
-                        for i, (n, (shape, _, init)) in enumerate(sh.items())})
-        segments.append(seg)
-    return segments
-
-
-def layer_params(params: Params, cfg: TransformerConfig, l: int) -> Params:
-    """The parameters of layer l (0-based), whichever way they are stacked."""
-    if not cfg.mixed:
-        return jax.tree.map(lambda a: a[l], params["layers"])
-    seg, pos, rep = cfg.layer_slot(l)
-    return jax.tree.map(lambda a: a[rep], params["layers"][seg][pos])
-
-
-def _swiglu(h, w_gate_up, w_down):
-    gu = jnp.einsum("bsd,dcf->bscf", h, w_gate_up)
-    gu = checkpoint_name(gu, "gate_up")
-    return (jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]) @ w_down
+    delta, counters = moe_ffn_held(
+        h, layer["router"], layer["router_bias"],
+        layer["moe_w_gate_up"], layer["moe_w_down"],
+        held_first=cfg.moe_held_range[0],
+        experts_per_token=cfg.moe_experts_per_token,
+        routed_scale=cfg.moe_routed_scale, dtype=cfg.dtype)
+    shared = {n[len("shared_"):]: a for n, a in layer.items()
+              if n.startswith("shared_")}
+    if shared:  # the always-on experts: one dense SwiGLU of their joint width
+        delta = delta + _mlp_block(cfg, "dense", h, shared)[0]
+    return delta, counters
 
 
 def _kda_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
@@ -712,17 +631,17 @@ def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg))
 
 
-_COUNTER_NAMES = ("assigned", "load_max", "load_mean", "past_buffer",
-                  "dropped")
-
-
-def _mixed_layer_body(cfg: TransformerConfig, kind: Tuple[str, str],
-                      x: jax.Array, layer: Params, positions: jax.Array):
-    """One layer of `kind` -> (x, routing counters of this layer or None)."""
+def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
+                layer: Params, positions: jax.Array, return_kv: bool = False):
+    """One layer of `kind` -> (x, extras), extras as `_mlp_block` gives them.
+    `return_kv` (an "attn" mixer only): -> (x, extras, k, v), this layer's
+    (roped) keys and values, from which the prefill of models/generate.py
+    primes its cache."""
     mixer, ffn = kind
     B, S, d = x.shape
     h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
               cfg.norm_eps)
+    k = v = None
     if mixer == "kda":
         with jax.named_scope("kda"):
             delta = _kda_mixer(cfg, h, layer)
@@ -737,57 +656,11 @@ def _mixed_layer_body(cfg: TransformerConfig, kind: Tuple[str, str],
     x = maybe_constrain(x + delta, ("batch", "seq_act", "embed"))
     h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
               cfg.norm_eps)
-    counters = None
-    if ffn == "dense":
-        delta = _mlp_block(cfg, h, layer, dense=True)[0]
-    else:
-        from ray_tpu.ops.moe import moe_ffn_held
-
-        delta, counters = moe_ffn_held(
-            h, layer["router"], layer["router_bias"],
-            layer["moe_w_gate_up"], layer["moe_w_down"],
-            held_first=cfg.moe_held_range[0],
-            experts_per_token=cfg.moe_experts_per_token,
-            routed_scale=cfg.moe_routed_scale, dtype=cfg.dtype)
-        if "shared_w_down" in layer:
-            delta = delta + _swiglu(h, _w(layer, "shared_w_gate_up", cfg),
-                                    _w(layer, "shared_w_down", cfg))
+    delta, extras = _mlp_block(cfg, ffn, h, layer)
     x = maybe_constrain(x + delta, ("batch", "seq_act", "embed"))
-    return x, counters
-
-
-def _mixed_backbone(params: Params, x: jax.Array, cfg: TransformerConfig,
-                    positions: jax.Array):
-    """The segments in turn, each a scan over its repeats, every layer under
-    the remat policy. -> (x, routing counters over the expert layers:
-    assigned, dropped, past_buffer (sums), load_max (max), load_mean
-    (mean); ops/moe.py `moe_ffn_held`)."""
-    if cfg.remat and cfg.remat_policy.startswith("half"):
-        raise ValueError("half_* remat policies are for classic stacks")
-    per_layer = []
-    for (pattern, _), seg in zip(cfg.stack_plan(), params["layers"]):
-        bodies = [_remat(cfg, functools.partial(_mixed_layer_body, cfg, kind))
-                  for kind in pattern]
-
-        def period(x, layers, bodies=bodies):
-            outs = []
-            for body, layer in zip(bodies, layers):
-                x, c = body(x, layer, positions)
-                if c is not None:
-                    outs.append(c)
-            return x, outs
-
-        x, outs = jax.lax.scan(period, x, seg)
-        per_layer.extend(outs)  # each leaf [repeats]
-    if not per_layer:
-        return x, {}
-    cat = {n: jnp.concatenate([c[n] for c in per_layer])
-           for n in _COUNTER_NAMES}
-    return x, {"moe_assigned": cat["assigned"].sum(),
-               "moe_dropped": cat["dropped"].sum(),
-               "moe_past_buffer": cat["past_buffer"].sum(),
-               "moe_load_max": cat["load_max"].max(),
-               "moe_load_mean": cat["load_mean"].mean()}
+    if return_kv:
+        return x, extras, k, v
+    return x, extras
 
 
 def embed_tokens(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> jax.Array:
@@ -809,47 +682,24 @@ def embed_tokens(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> j
     return x
 
 
-def layer_scan_body(cfg: TransformerConfig, positions: jax.Array):
-    """The (remat-wrapped) per-layer scan body; shared by the plain forward
-    and the pipeline-parallel stage apply (parallel/pipeline.py). The scan's
-    per-layer output is the MoE aux loss (zeros for dense layers)."""
-    return _remat(
-        cfg, lambda carry, layer: _layer_body(cfg, carry, layer, positions))
+def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
+                    positions: jax.Array):
+    """(x, layer) -> (x, extras): one layer of `kind` under cfg's remat
+    policy, the body `_backbone` scans and the pipeline-parallel stage apply
+    (parallel/pipeline.py) shares."""
+    body = lambda x, layer: _layer_body(cfg, kind, x, layer, positions)
+    if not cfg.remat:
+        return body
+    if cfg.remat_policy == "full":
+        return jax.checkpoint(body)
+    from ray_tpu.ops import flash_attention as fa, kda
 
-
-def _remat(cfg: TransformerConfig, body):
-    """`body` under cfg's remat policy (one layer's granularity)."""
-    if cfg.remat:
-        if cfg.remat_policy == "dots":
-            from ray_tpu.ops import flash_attention as fa, kda
-            names = fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES  # no dot makes them
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.save_from_both_policies(
-                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                    jax.checkpoint_policies.save_only_these_names(
-                        *names),
-                ),
-            )
-        elif cfg.remat_policy == "min":
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.save_anything_except_these_names(
-                    "qkv_proj", "gate_up"
-                ),
-            )
-        elif cfg.remat_policy == "full":
-            body = jax.checkpoint(body)
-        else:
-            # "half_*" is resolved by forward_with_aux (it splits the stack
-            # and re-enters here with full/dots/remat=False); any other name
-            # reaching this point is a config error — a silent full-remat
-            # fallback would mis-measure the policy being asked for.
-            raise ValueError(
-                f"unhandled remat_policy {cfg.remat_policy!r} at the scan "
-                f"level (half_* composes only through the plain forward, "
-                f"not the pipeline path)")
-    return body
+    names = fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES  # no dot makes them
+    return jax.checkpoint(
+        body,
+        policy=jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(*names)))
 
 
 def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> jax.Array:
@@ -860,50 +710,44 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> jax.Ar
 def forward_with_aux(
     params: Params, tokens: jax.Array, cfg: TransformerConfig
 ) -> Tuple[jax.Array, jax.Array]:
-    """forward + summed MoE load-balancing aux loss (0 for dense stacks)."""
-    x, aux = backbone_with_aux(params, tokens, cfg)
-    return lm_head(params, x, cfg), aux
-
-
-def backbone_with_aux(
-    params: Params, tokens: jax.Array, cfg: TransformerConfig
-) -> Tuple[jax.Array, jax.Array]:
-    """Everything before the lm head: tokens -> hidden [B,S,d] + MoE aux
-    (the fused-CE loss path consumes the hidden states directly)."""
-    x, aux, _ = _backbone(params, tokens, cfg)
-    return x, aux
+    """forward + summed GShard load-balancing aux loss (0 for other stacks)."""
+    x, extras = _backbone(params, tokens, cfg)
+    return lm_head(params, x, cfg), extras.get("aux", jnp.zeros((), jnp.float32))
 
 
 def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
-    """-> (hidden, MoE aux loss, routing counters: {} unless the stack has
-    sigmoid-routed experts, see `_mixed_backbone`)."""
+    """Everything before the lm head: the segments in turn, each a scan over
+    its repeats, every layer under the remat policy. -> (hidden [B,S,d],
+    the stack's extras from its layers' (`_mlp_block`): {} for dense
+    feed-forwards, {"aux": sum} for GShard, and for sigmoid-routed experts
+    moe_assigned, moe_dropped, moe_past_buffer (sums), moe_load_max (max),
+    moe_load_mean (mean) over the expert layers)."""
     B, S = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    if cfg.mixed:
-        x, counters = _mixed_backbone(params, x, cfg, positions)
-        return x, jnp.zeros((), jnp.float32), counters
-    if cfg.remat and cfg.remat_policy.startswith("half"):
-        # Mixed remat: the FIRST half of the stack checkpoints (its saved
-        # activations would live longest — from forward until the very end
-        # of the backward), the second half keeps activations. Halves the
-        # backward recompute at roughly half of full-remat's memory saving,
-        # using only standard policies the AOT helper accepts.
-        inner = dataclasses.replace(
-            cfg, remat_policy="dots" if cfg.remat_policy == "half_dots"
-            else "full")
-        plain = dataclasses.replace(cfg, remat=False)
-        half = cfg.n_layers // 2
-        first = jax.tree.map(lambda a: a[:half], params["layers"])
-        second = jax.tree.map(lambda a: a[half:], params["layers"])
-        x, aux1 = jax.lax.scan(layer_scan_body(inner, positions), x, first)
-        x, aux2 = jax.lax.scan(layer_scan_body(plain, positions), x, second)
-        aux = aux1.sum() + aux2.sum()
-    else:
-        x, auxs = jax.lax.scan(
-            layer_scan_body(cfg, positions), x, params["layers"])
-        aux = auxs.sum()
-    return x, aux, {}
+    per_layer = []  # each leaf [repeats]
+    for (pattern, _), seg in zip(cfg.stack_plan(), stack_segments(params, cfg)):
+        bodies = [layer_scan_body(cfg, kind, positions) for kind in pattern]
+
+        def period(x, layers, bodies=bodies):
+            outs = []
+            for body, layer in zip(bodies, layers):
+                x, extras = body(x, layer)
+                outs.append(extras)
+            return x, outs
+
+        x, outs = jax.lax.scan(period, x, seg)
+        per_layer.extend(e for e in outs if e)
+    if not per_layer:
+        return x, {}
+    cat = {n: jnp.concatenate([e[n] for e in per_layer]) for n in per_layer[0]}
+    if "aux" in cat:
+        return x, {"aux": cat["aux"].sum()}
+    return x, {"moe_assigned": cat["assigned"].sum(),
+               "moe_dropped": cat["dropped"].sum(),
+               "moe_past_buffer": cat["past_buffer"].sum(),
+               "moe_load_max": cat["load_max"].max(),
+               "moe_load_mean": cat["load_mean"].mean()}
 
 
 def final_hidden_and_head(
@@ -981,8 +825,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
     """Next-token cross-entropy. `with_counters`: return (loss, routing
     counters) for `ShardedTrainStep(has_aux=True)`: device scalars
     moe_assigned, moe_dropped, moe_past_buffer, moe_load_max, moe_load_mean
-    of a stack with
-    sigmoid-routed experts (`_mixed_backbone`), {} for any other.
+    of a stack with sigmoid-routed experts (`_backbone`), {} for any other.
 
     Two token conventions:
     - in-place (default): batch tokens [B,S]; the forward runs on the FULL
@@ -1000,27 +843,20 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
       parallelism composes too.
     """
     tokens = batch["tokens"]
+    x, extras = _backbone(params, tokens[:, :-1] if shift_inputs else tokens,
+                          cfg)
+    if shift_inputs:
+        targets, valid = shift_targets_valid(tokens, batch.get("mask"))
+    else:
+        targets, valid = inplace_targets_valid(batch)
     if cfg.fused_ce:
         from ..ops.fused_ce import fused_next_token_loss
 
-        tokens_in = tokens[:, :-1] if shift_inputs else tokens
-        x, aux, counters = _backbone(params, tokens_in, cfg)
         x, head = final_hidden_and_head(params, x, cfg)
-        if shift_inputs:
-            targets, valid = shift_targets_valid(tokens, batch.get("mask"))
-        else:
-            targets, valid = inplace_targets_valid(batch)
         loss = fused_next_token_loss(
             x.astype(cfg.dtype), head, targets, valid)
-    elif shift_inputs:
-        x, aux, counters = _backbone(params, tokens[:, :-1], cfg)
-        logits = lm_head(params, x, cfg)
-        targets, valid = shift_targets_valid(tokens, batch.get("mask"))
-        loss = token_cross_entropy(logits, targets, valid)
     else:
-        x, aux, counters = _backbone(params, tokens, cfg)
-        logits = lm_head(params, x, cfg)  # [B, S, V]
-        loss = next_token_loss(logits, batch)
-    if cfg.moe_num_experts and not cfg.mixed:  # GShard's balancing loss
-        loss = loss + cfg.moe_aux_coef * aux
-    return (loss, counters) if with_counters else loss
+        loss = token_cross_entropy(lm_head(params, x, cfg), targets, valid)
+    if "aux" in extras:  # GShard's balancing loss; what is left are counters
+        loss = loss + cfg.moe_aux_coef * extras.pop("aux")
+    return (loss, extras) if with_counters else loss

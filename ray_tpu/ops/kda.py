@@ -54,7 +54,7 @@ goes into the state update in float32.
   differentiated through its construction: dA = -(T^T dW) W^T - (T^T dU0)
   U0^T, strictly lower. `RESIDUAL_NAMES` names o, the states and T
   (`checkpoint_name` in the forward rule) so that a remat policy keeps them
-  and the forward kernel runs once (`models/transformer.py` `_remat`).
+  and the forward kernel runs once (`models/transformer.py` `layer_scan_body`).
 - **`kda_chunked_xla`**: the same algorithm in plain XLA, differentiable by
   autodiff: the fallback on the CPU, for narrow heads or chunks, and under a
   mesh (a Mosaic call there needs `shard_map`; no configuration trains a KDA
@@ -252,7 +252,7 @@ def kda_chunked_xla(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 # What `_vjp_fwd` keeps for the backward kernel besides its own inputs, named
-# so that a remat policy can keep them (`models/transformer.py` `_remat`): o
+# so that a remat policy can keep them (`models/transformer.py` `layer_scan_body`): o
 # because the layer's recomputation needs it, the chunk-start states and the
 # chunks' inverses because the backward kernel reads them. A pallas_call is
 # not a dot: under a dots-only policy the forward kernel would run twice.

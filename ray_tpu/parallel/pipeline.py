@@ -59,7 +59,8 @@ def pipeline_apply(
 
     Returns (activations [M, mb, S, d], summed MoE aux loss — zero for
     dense stacks)."""
-    from ray_tpu.models.transformer import layer_scan_body
+    from ray_tpu.models.transformer import (layer_scan_body, one_kind_stack,
+                                            param_logical_specs)
 
     rules = rules or shd.DEFAULT_RULES
     num_stages = mesh.shape["pipe"]
@@ -68,9 +69,8 @@ def pipeline_apply(
 
     # [L, ...] -> [P, L/P, ...], stage dim pinned to `pipe`; remaining dims
     # keep their logical sharding (fsdp/tensor/expert) from the rule table.
-    from ray_tpu.models.transformer import param_logical_specs
-
-    lspecs = param_logical_specs(cfg)["layers"]
+    kind, lspecs = one_kind_stack(param_logical_specs(cfg), cfg,
+                                  "pipeline parallelism")
 
     def stage_fold(a, spec):
         L = a.shape[0]
@@ -94,7 +94,7 @@ def pipeline_apply(
 
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
                                  (mb, S))
-    scan_body = layer_scan_body(cfg, positions)
+    scan_body = layer_scan_body(cfg, kind, positions)
     # Ring attention is a shard_map over `seq` and cannot nest inside the
     # vmapped stage body; dropping the seq_act routing makes attention()
     # use the dense per-stage kernel (context parallelism composes with
@@ -103,8 +103,8 @@ def pipeline_apply(
 
     def stage_apply(stage_layers, h):
         with shd.sharding_ctx(mesh, inner_rules):
-            out, auxs = lax.scan(scan_body, h, stage_layers)
-        return out, auxs.sum()
+            out, extras = lax.scan(scan_body, h, stage_layers)
+        return out, extras["aux"].sum() if "aux" in extras else jnp.zeros(())
 
     vapply = jax.vmap(stage_apply)
 
@@ -162,6 +162,9 @@ def pipeline_loss_fn(cfg, mesh: Mesh, *, rules=None, num_microbatches: int = 4,
 
     rules = rules or shd.DEFAULT_RULES
     M = num_microbatches
+    # Refuse another stack here, not at trace time.
+    tfm.one_kind_stack(tfm.param_logical_specs(cfg), cfg,
+                       "pipeline parallelism")
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
@@ -172,7 +175,8 @@ def pipeline_loss_fn(cfg, mesh: Mesh, *, rules=None, num_microbatches: int = 4,
                 f"batch {B} not divisible by num_microbatches {M}")
         x = tfm.embed_tokens(params, inputs, cfg)  # [B, S, d]
         x = x.reshape(M, B // M, S, -1)
-        y, aux = pipeline_apply(cfg, params["layers"], x, mesh, rules)
+        _, layers = tfm.one_kind_stack(params, cfg, "pipeline parallelism")
+        y, aux = pipeline_apply(cfg, layers, x, mesh, rules)
         y = y.reshape(B, S, -1)
         y = shd.maybe_constrain(y, ("batch", "seq_act", "embed"))
         logits = tfm.lm_head(params, y, cfg)

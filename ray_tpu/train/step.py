@@ -154,10 +154,10 @@ def transformer_train_step(
             raise NotImplementedError(
                 "fused_ce is not supported under pipeline parallelism "
                 "yet — unset cfg.fused_ce for pipe>1 meshes")
-        if with_counters or cfg.mixed:
+        if with_counters:
             raise NotImplementedError(
-                "pipeline parallelism runs classic stacks only (one stacked "
-                "tree of identical layers), without counters")
+                "the pipelined loss threads GShard's aux loss through its "
+                "schedule and no routing counters")
         from ray_tpu.parallel.pipeline import pipeline_loss_fn
 
         M = pipeline_microbatches or 2 * mesh.shape["pipe"]

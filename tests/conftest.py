@@ -40,3 +40,13 @@ def ray_start_cluster():
     cluster = Cluster(head_resources={"CPU": 2})
     yield cluster
     cluster.shutdown()
+
+
+@pytest.fixture()
+def exact_matmuls():
+    """float32 matmuls as float32 (the CPU's default rounds their inputs):
+    for the files that hold a program to a reference within 1e-5."""
+    import jax
+
+    with jax.default_matmul_precision("highest"):
+        yield

@@ -1,0 +1,164 @@
+"""Kimi Delta Attention (ops/kda.py) on the CPU at small sizes: the chunked
+algorithm against the recurrence, the Pallas kernels (interpret mode) against
+both, the dispatch rule, and what a remat policy keeps of the kernels in a
+traced KDA stack. tests/test_kda_kernel_compile.py compiles the kernels for
+the chip."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import transformer as tfm
+from ray_tpu.models.configs import kimi_linear_tiny
+from ray_tpu.ops import kda
+
+pytestmark = pytest.mark.usefixtures("exact_matmuls")
+
+
+def _qkvgb(B, S, H, dk, dv, seed=0, decay=1.0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q = kda.l2_normalize(jax.random.normal(ks[0], (B, S, H, dk)))
+    k = kda.l2_normalize(jax.random.normal(ks[1], (B, S, H, dk)))
+    v = jax.random.normal(ks[2], (B, S, H, dv))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (B, S, H, dk)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("S,chunk,sub", [(64, 16, 16), (100, 32, 16),
+                                         (96, 64, 32), (256, 128, 32),
+                                         (130, 128, 32)])
+def test_kda_chunked_matches_recurrence(S, chunk, sub):
+    """Outputs, final state and every input's gradient, across chunk sizes
+    and lengths that are not a multiple of the chunk."""
+    args = _qkvgb(2, S, 2, 16, 24, seed=S)
+    chunked = jax.jit(lambda *a: kda.kda_chunked(*a, chunk=chunk, sub=sub))
+    o1, s1 = jax.jit(kda.kda_recurrent)(*args)
+    o2, s2 = chunked(*args)
+    np.testing.assert_allclose(o2, o1, atol=2e-5 * float(jnp.abs(o1).max()))
+    np.testing.assert_allclose(s2, s1, atol=2e-5 * float(jnp.abs(s1).max()))
+    loss = lambda f: lambda *a: jnp.sum(f(*a)[0] ** 2)
+    g1 = jax.jit(jax.grad(loss(kda.kda_recurrent),
+                          argnums=(0, 1, 2, 3, 4)))(*args)
+    g2 = jax.jit(jax.grad(loss(chunked), argnums=(0, 1, 2, 3, 4)))(*args)
+    for a, b in zip(g2, g1):
+        np.testing.assert_allclose(a, b, atol=5e-5 * float(jnp.abs(b).max()))
+
+
+def test_kda_chunked_carries_state_and_strong_decay():
+    """A sequence in two calls equals one call; a decay of e^-60 inside one
+    chunk (past float32's range for e^G * e^-G from the chunk's start)
+    stays exact thanks to the sub-block references."""
+    q, k, v, g, beta = _qkvgb(1, 128, 2, 16, 16, seed=3, decay=0.5)
+    g = g.at[:, 40:60].set(-3.0)  # 20 tokens of e^-3 each in a 128-chunk
+    o, s = kda.kda_recurrent(q, k, v, g, beta)
+    a = [x[:, :64] for x in (q, k, v, g, beta)]
+    b = [x[:, 64:] for x in (q, k, v, g, beta)]
+    o_a, s_a = kda.kda_chunked(*a, chunk=32)
+    o_b, s_b = kda.kda_chunked(*b, chunk=32, initial_state=s_a)
+    np.testing.assert_allclose(jnp.concatenate([o_a, o_b], 1), o, atol=1e-5)
+    np.testing.assert_allclose(s_b, s, atol=1e-5)
+    o128, _ = kda.kda_chunked(q, k, v, g, beta, chunk=128, sub=32)
+    np.testing.assert_allclose(o128, o, atol=1e-5)
+
+
+@pytest.mark.parametrize("S,chunk,sub,dk,dv,init,strong", [
+    (256, 128, 32, 128, 128, False, False),   # the chip's tile sizes
+    (256, 128, 32, 128, 128, True, True),     # e^-60 inside a chunk
+    (300, 128, 32, 16, 24, True, False),      # S not a multiple of the chunk
+    (96, 32, 16, 16, 16, False, True),
+    (64, 16, 16, 16, 24, True, False),        # one sub-block, no merge
+])
+def test_kda_kernels_match_recurrence_and_xla(S, chunk, sub, dk, dv, init,
+                                              strong):
+    """The Pallas kernels (interpret mode here) against the recurrence and
+    the XLA body: outputs, final state, all five gradients and the initial
+    state's, with a cotangent on the final state too."""
+    q, k, v, g, beta = _qkvgb(1, S, 2, dk, dv, seed=S + dk,
+                              decay=0.5 if strong else 1.0)
+    if strong:  # 20 tokens of e^-3 each inside one chunk
+        g = g.at[:, 40:60].set(-3.0)
+    s0 = (jax.random.normal(jax.random.key(9), (1, 2, dk, dv)) if init
+          else jnp.zeros((1, 2, dk, dv)))
+
+    def run(f, **kw):
+        def loss(q, k, v, g, beta, s0):
+            o, s = f(q, k, v, g, beta, initial_state=s0, **kw)
+            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(s)), (o, s)
+        (_, (o, s)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4, 5), has_aux=True))(
+                q, k, v, g, beta, s0)
+        return (o, s) + grads
+
+    want = run(kda.kda_recurrent)
+    xla = run(kda.kda_chunked_xla, chunk=chunk, sub=sub)
+    got = run(kda.kda_chunked_pallas, chunk=chunk, sub=sub)
+    for i, (a, b, c) in enumerate(zip(got, want, xla)):
+        atol = (2e-5 if i < 2 else 5e-5) * float(jnp.abs(b).max())
+        np.testing.assert_allclose(a, b, atol=atol, err_msg=f"output {i}")
+        np.testing.assert_allclose(a, c, atol=atol, err_msg=f"output {i}")
+
+
+def test_kda_dispatch_rule():
+    """`use_kernels` is a pure function of platform and shapes; on the CPU
+    `kda_chunked` is the XLA body, and says so in the phase table."""
+    from ray_tpu.util import tracing
+
+    assert kda.use_kernels("tpu", 128, 128, 128, on_mesh=False)
+    assert kda.use_kernels("tpu", 256, 128, 256, on_mesh=False)
+    assert not kda.use_kernels("cpu", 128, 128, 128, on_mesh=False)
+    assert not kda.use_kernels("tpu", 16, 128, 128, on_mesh=False)
+    assert not kda.use_kernels("tpu", 128, 16, 128, on_mesh=False)
+    assert not kda.use_kernels("tpu", 128, 128, 32, on_mesh=False)
+    assert not kda.use_kernels("tpu", 128, 128, 128, on_mesh=True)
+    count = lambda n: tracing.phase_table().get(n, {"count": 0})["count"]
+    before = count("kda.core.xla"), count("kda.core.pallas")
+    args = _qkvgb(1, 128, 1, 128, 128)
+    np.testing.assert_array_equal(
+        kda.kda_chunked(*args)[0], kda.kda_chunked_xla(*args)[0])
+    assert (count("kda.core.xla"), count("kda.core.pallas")) == (
+        before[0] + 1, before[1])
+
+
+def _kernel_calls(jaxpr, times=1, out=None):
+    """pallas_calls of a jaxpr by operand signature, a call inside a scan
+    counted once per iteration (tests/test_models.py does it for flash)."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            sig = f"{len(eqn.invars)}in_{len(eqn.outvars)}out"
+            out[sig] = out.get(sig, 0) + times
+        inner = times * (eqn.params["length"]
+                         if eqn.primitive.name == "scan" else 1)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, inner, out)
+    return out
+
+
+@pytest.mark.parametrize("policy,fwd_calls_per_layer",
+                         [("dots", 1), ("full", 2)])
+def test_remat_dots_keeps_the_kda_kernel_residuals(monkeypatch, policy,
+                                                   fwd_calls_per_layer):
+    """The traced gradient of a KDA stack through the kernels: under "dots"
+    the forward kernel (6 in / 4 out) runs once a layer, its o, states and
+    inverses being named residuals; under "full" twice. The backward kernel
+    (9 in / 6 out) once. Neither has a flash kernel's signature
+    (chipbench/reduce/xplane.py names kernels by it). Gradients are those
+    of the XLA body."""
+    cfg = kimi_linear_tiny(n_layers=3, moe_held=(0, 16), remat=True,
+                           remat_policy=policy, dtype=jnp.float32)
+    params = tfm.init_params(jax.random.key(0), cfg)
+    toks = jax.random.randint(jax.random.key(1), (2, 33), 0, cfg.vocab_size)
+    # A new function each time: jax caches a trace by the function's identity.
+    grad = lambda: jax.grad(lambda p: tfm.loss_fn(
+        p, {"tokens": toks}, cfg, shift_inputs=True))
+    assert _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr) == {}
+    g_xla = jax.jit(grad())(params)
+    monkeypatch.setattr(kda, "use_kernels", lambda *a, **kw: True)
+    calls = _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr)
+    assert calls == {"6in_4out": 3 * fwd_calls_per_layer, "9in_6out": 3}
+    if policy == "dots":
+        for a, b in zip(jax.tree.leaves(jax.jit(grad())(params)),
+                        jax.tree.leaves(g_xla)):
+            np.testing.assert_allclose(a, b, atol=1e-5 + 1e-4 * float(
+                jnp.abs(b).max()))

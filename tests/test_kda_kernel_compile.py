@@ -1,6 +1,6 @@
 """The KDA kernels compiled for a v5e at the cell's real shape, with no chip:
 the TPU compiler is installed here and compiles for a described device.
-Interpret mode (tests/test_kimi_linear.py) cannot see what Mosaic refuses
+Interpret mode (tests/test_kda.py) cannot see what Mosaic refuses
 (unaligned slices, VMEM over the limit, an op with no lowering). Nothing
 runs, so this says nothing about results or times. The topology is described
 inside a fixture, never at import (one process at a time may load libtpu)."""

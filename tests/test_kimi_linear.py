@@ -1,10 +1,9 @@
-"""The mixed stack (KDA and MLA mixers, a dense lead layer, sigmoid-routed
-experts of which a program holds a share) on the CPU at small sizes: the
-chunked KDA against the recurrence, the program against the plain reference
-(chipbench/reference/kimi_linear.py) for each kind of layer and for the
-27-layer pattern, the shares of an expert layer against the uncut layer,
-the counters, the counts and the configuration file."""
-import dataclasses
+"""The layer stack (models/transformer.py) where its layers are of several
+kinds, on the CPU at small sizes: a plan of several segments scanned against
+the same layers applied one by one, the shares of an expert layer against
+the uncut layer, the counters, the counts, the configuration file and the
+two stored formats. The KDA core is tests/test_kda.py, the program against
+the plain reference tests/test_kimi_linear_reference.py."""
 import json
 import os
 
@@ -15,230 +14,11 @@ import pytest
 
 from ray_tpu.models import transformer as tfm
 from ray_tpu.models.configs import kimi_linear_tiny
-from ray_tpu.ops import kda, moe
+from ray_tpu.ops import moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-@pytest.fixture(autouse=True)
-def _exact_matmuls():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def _qkvgb(B, S, H, dk, dv, seed=0, decay=1.0):
-    ks = jax.random.split(jax.random.key(seed), 5)
-    q = kda.l2_normalize(jax.random.normal(ks[0], (B, S, H, dk)))
-    k = kda.l2_normalize(jax.random.normal(ks[1], (B, S, H, dk)))
-    v = jax.random.normal(ks[2], (B, S, H, dv))
-    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (B, S, H, dk)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
-    return q, k, v, g, beta
-
-
-@pytest.mark.parametrize("S,chunk,sub", [(64, 16, 16), (100, 32, 16),
-                                         (96, 64, 32), (256, 128, 32),
-                                         (130, 128, 32)])
-def test_kda_chunked_matches_recurrence(S, chunk, sub):
-    """Outputs, final state and every input's gradient, across chunk sizes
-    and lengths that are not a multiple of the chunk."""
-    args = _qkvgb(2, S, 2, 16, 24, seed=S)
-    chunked = jax.jit(lambda *a: kda.kda_chunked(*a, chunk=chunk, sub=sub))
-    o1, s1 = jax.jit(kda.kda_recurrent)(*args)
-    o2, s2 = chunked(*args)
-    np.testing.assert_allclose(o2, o1, atol=2e-5 * float(jnp.abs(o1).max()))
-    np.testing.assert_allclose(s2, s1, atol=2e-5 * float(jnp.abs(s1).max()))
-    loss = lambda f: lambda *a: jnp.sum(f(*a)[0] ** 2)
-    g1 = jax.jit(jax.grad(loss(kda.kda_recurrent),
-                          argnums=(0, 1, 2, 3, 4)))(*args)
-    g2 = jax.jit(jax.grad(loss(chunked), argnums=(0, 1, 2, 3, 4)))(*args)
-    for a, b in zip(g2, g1):
-        np.testing.assert_allclose(a, b, atol=5e-5 * float(jnp.abs(b).max()))
-
-
-def test_kda_chunked_carries_state_and_strong_decay():
-    """A sequence in two calls equals one call; a decay of e^-60 inside one
-    chunk (past float32's range for e^G * e^-G from the chunk's start)
-    stays exact thanks to the sub-block references."""
-    q, k, v, g, beta = _qkvgb(1, 128, 2, 16, 16, seed=3, decay=0.5)
-    g = g.at[:, 40:60].set(-3.0)  # 20 tokens of e^-3 each in a 128-chunk
-    o, s = kda.kda_recurrent(q, k, v, g, beta)
-    a = [x[:, :64] for x in (q, k, v, g, beta)]
-    b = [x[:, 64:] for x in (q, k, v, g, beta)]
-    o_a, s_a = kda.kda_chunked(*a, chunk=32)
-    o_b, s_b = kda.kda_chunked(*b, chunk=32, initial_state=s_a)
-    np.testing.assert_allclose(jnp.concatenate([o_a, o_b], 1), o, atol=1e-5)
-    np.testing.assert_allclose(s_b, s, atol=1e-5)
-    o128, _ = kda.kda_chunked(q, k, v, g, beta, chunk=128, sub=32)
-    np.testing.assert_allclose(o128, o, atol=1e-5)
-
-
-@pytest.mark.parametrize("S,chunk,sub,dk,dv,init,strong", [
-    (256, 128, 32, 128, 128, False, False),   # the chip's tile sizes
-    (256, 128, 32, 128, 128, True, True),     # e^-60 inside a chunk
-    (300, 128, 32, 16, 24, True, False),      # S not a multiple of the chunk
-    (96, 32, 16, 16, 16, False, True),
-    (64, 16, 16, 16, 24, True, False),        # one sub-block, no merge
-])
-def test_kda_kernels_match_recurrence_and_xla(S, chunk, sub, dk, dv, init,
-                                              strong):
-    """The Pallas kernels (interpret mode here) against the recurrence and
-    the XLA body: outputs, final state, all five gradients and the initial
-    state's, with a cotangent on the final state too."""
-    q, k, v, g, beta = _qkvgb(1, S, 2, dk, dv, seed=S + dk,
-                              decay=0.5 if strong else 1.0)
-    if strong:  # 20 tokens of e^-3 each inside one chunk
-        g = g.at[:, 40:60].set(-3.0)
-    s0 = (jax.random.normal(jax.random.key(9), (1, 2, dk, dv)) if init
-          else jnp.zeros((1, 2, dk, dv)))
-
-    def run(f, **kw):
-        def loss(q, k, v, g, beta, s0):
-            o, s = f(q, k, v, g, beta, initial_state=s0, **kw)
-            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(s)), (o, s)
-        (_, (o, s)), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2, 3, 4, 5), has_aux=True))(
-                q, k, v, g, beta, s0)
-        return (o, s) + grads
-
-    want = run(kda.kda_recurrent)
-    xla = run(kda.kda_chunked_xla, chunk=chunk, sub=sub)
-    got = run(kda.kda_chunked_pallas, chunk=chunk, sub=sub)
-    for i, (a, b, c) in enumerate(zip(got, want, xla)):
-        atol = (2e-5 if i < 2 else 5e-5) * float(jnp.abs(b).max())
-        np.testing.assert_allclose(a, b, atol=atol, err_msg=f"output {i}")
-        np.testing.assert_allclose(a, c, atol=atol, err_msg=f"output {i}")
-
-
-def test_kda_dispatch_rule():
-    """`use_kernels` is a pure function of platform and shapes; on the CPU
-    `kda_chunked` is the XLA body, and says so in the phase table."""
-    from ray_tpu.util import tracing
-
-    assert kda.use_kernels("tpu", 128, 128, 128, on_mesh=False)
-    assert kda.use_kernels("tpu", 256, 128, 256, on_mesh=False)
-    assert not kda.use_kernels("cpu", 128, 128, 128, on_mesh=False)
-    assert not kda.use_kernels("tpu", 16, 128, 128, on_mesh=False)
-    assert not kda.use_kernels("tpu", 128, 16, 128, on_mesh=False)
-    assert not kda.use_kernels("tpu", 128, 128, 32, on_mesh=False)
-    assert not kda.use_kernels("tpu", 128, 128, 128, on_mesh=True)
-    count = lambda n: tracing.phase_table().get(n, {"count": 0})["count"]
-    before = count("kda.core.xla"), count("kda.core.pallas")
-    args = _qkvgb(1, 128, 1, 128, 128)
-    np.testing.assert_array_equal(
-        kda.kda_chunked(*args)[0], kda.kda_chunked_xla(*args)[0])
-    assert (count("kda.core.xla"), count("kda.core.pallas")) == (
-        before[0] + 1, before[1])
-
-
-def _kernel_calls(jaxpr, times=1, out=None):
-    """pallas_calls of a jaxpr by operand signature, a call inside a scan
-    counted once per iteration (tests/test_models.py does it for flash)."""
-    out = {} if out is None else out
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            sig = f"{len(eqn.invars)}in_{len(eqn.outvars)}out"
-            out[sig] = out.get(sig, 0) + times
-        inner = times * (eqn.params["length"]
-                         if eqn.primitive.name == "scan" else 1)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _kernel_calls(sub, inner, out)
-    return out
-
-
-@pytest.mark.parametrize("policy,fwd_calls_per_layer",
-                         [("dots", 1), ("full", 2)])
-def test_remat_dots_keeps_the_kda_kernel_residuals(monkeypatch, policy,
-                                                   fwd_calls_per_layer):
-    """The traced gradient of a KDA stack through the kernels: under "dots"
-    the forward kernel (6 in / 4 out) runs once a layer, its o, states and
-    inverses being named residuals; under "full" twice. The backward kernel
-    (9 in / 6 out) once. Neither has a flash kernel's signature
-    (chipbench/reduce/xplane.py names kernels by it). Gradients are those
-    of the XLA body."""
-    cfg = kimi_linear_tiny(n_layers=3, moe_held=(0, 16), remat=True,
-                           remat_policy=policy, dtype=jnp.float32)
-    params = tfm.init_params(jax.random.key(0), cfg)
-    toks = jax.random.randint(jax.random.key(1), (2, 33), 0, cfg.vocab_size)
-    # A new function each time: jax caches a trace by the function's identity.
-    grad = lambda: jax.grad(lambda p: tfm.loss_fn(
-        p, {"tokens": toks}, cfg, shift_inputs=True))
-    assert _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr) == {}
-    g_xla = jax.jit(grad())(params)
-    monkeypatch.setattr(kda, "use_kernels", lambda *a, **kw: True)
-    calls = _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr)
-    assert calls == {"6in_4out": 3 * fwd_calls_per_layer, "9in_6out": 3}
-    if policy == "dots":
-        for a, b in zip(jax.tree.leaves(jax.jit(grad())(params)),
-                        jax.tree.leaves(g_xla)):
-            np.testing.assert_allclose(a, b, atol=1e-5 + 1e-4 * float(
-                jnp.abs(b).max()))
-
-
-def _reference_parts(cfg, seed=5):
-    from chipbench import weights_kimi_linear as WK
-
-    tc = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
-    sz = WK.HybridSizes(tc, cfg.norm_eps)
-    key = jax.random.key(seed)
-    params = jax.jit(lambda k: WK.program_params(k, sz, cfg))(key)
-    return sz, key, params
-
-
-@pytest.mark.parametrize("name,over", [
-    ("kda_dense", dict(n_layers=1)),
-    ("kda_moe", dict(n_layers=1, moe_first_dense=0)),
-    ("mla_moe", dict(n_layers=1, moe_first_dense=0, kda_layers=(),
-                     mla_layers=(1,))),
-    ("stack5_remat", dict(remat=True, remat_policy="dots")),
-    ("pattern27", dict(n_layers=27)),
-])
-def test_program_matches_reference(name, over):
-    """Logits, loss and the compared gradient leaves, program against the
-    plain reference, weights from one seed: each layer kind alone and the
-    published 27-layer pattern (irregular tail included) at tiny widths."""
-    from chipbench.drivers import train_hybrid as drv
-    from chipbench.reference import kimi_linear as ref
-
-    cfg = kimi_linear_tiny(dtype=jnp.float32, moe_held=(4, 4), **over)
-    sz, key, params = _reference_parts(cfg)
-    if cfg.n_layers == 27:
-        kinds = [p for p, _ in cfg.stack_plan()]
-        assert [len(p) for p in kinds] == [1, 4, 1, 1], cfg.stack_plan()
-        assert cfg.stack_plan()[1][1] == 6
-    deep = cfg.n_layers == 27  # the reference unrolls: keep its compile short
-    toks = jax.random.randint(jax.random.key(1), (1, 17) if deep else (2, 41),
-                              0, cfg.vocab_size)
-    if not deep:
-        np.testing.assert_allclose(
-            jax.jit(lambda p: tfm.forward(p, toks[:, :-1], cfg))(params),
-            ref.forward(key, toks[:, :-1], sz), atol=2e-4)
-    loss_p, g = jax.jit(jax.value_and_grad(lambda p: tfm.loss_fn(
-        p, {"tokens": toks}, cfg, shift_inputs=True)))(params)
-    # Eagerly when deep: 27 unrolled layers compile as one program for
-    # minutes, op by op the layers share their compiled pieces.
-    ref_grads = lambda k, t: ref.loss_and_grads(k, t, sz)
-    loss_r, g_r = (ref_grads if deep else jax.jit(ref_grads))(key, toks)
-    assert abs(float(loss_p) - float(loss_r)) < 1e-5 * float(loss_r)
-    # Leaves of layer kinds this stack lacks are absent from the program.
-    has = {m for m, _ in sz.kinds} | {f for _, f in sz.kinds}
-    want = {"final_norm": True, "kda_wo": "kda" in has,
-            "mla_wkvb": "mla" in has, "expert_down": "moe" in has,
-            "router": "moe" in has}
-    lay = lambda l: tfm.layer_params(g, cfg, l)
-    got = {"final_norm": g["final_norm"]}
-    if want["kda_wo"]:
-        got["kda_wo"] = lay(sz.l_kda)["kda_wo"].reshape(-1, sz.d)
-    if want["mla_wkvb"]:
-        got["mla_wkvb"] = lay(sz.l_mla)["mla_wkvb"].reshape(sz.lat, -1)
-    if want["expert_down"]:
-        got["expert_down"] = lay(sz.l_moe)["moe_w_down"][sz.e_pick]
-        got["router"] = lay(sz.l_moe)["router"]
-    for n, a in got.items():
-        err = float(jnp.linalg.norm(a - g_r[n]) / jnp.linalg.norm(g_r[n]))
-        assert err < 2e-4, (name, n, err)
-    if cfg.n_layers == 27:
-        assert set(drv.program_leaves(cfg, sz, g)) == set(g_r)
+pytestmark = pytest.mark.usefixtures("exact_matmuls")
 
 
 def _layer_case(seed=0, B=2, S=32, d=32, E=16, F=24, k=4):
@@ -251,6 +31,13 @@ def _layer_case(seed=0, B=2, S=32, d=32, E=16, F=24, k=4):
         wd=jax.random.normal(ks[4], (E, F, d)) * 0.2,
         sgu=jax.random.normal(ks[5], (d, 2, F)) * 0.2,
         sd=jax.random.normal(ks[6], (F, d)) * 0.2, k=k, E=E)
+
+
+def _shared_expert(c):
+    """The always-on expert as the program computes it: the dense SwiGLU."""
+    return tfm._mlp_block(
+        kimi_linear_tiny(dtype=jnp.float32), "dense", c["x"],
+        {"w_gate_up": c["sgu"], "w_down": c["sd"]})[0]
 
 
 def _uncut_layer(c, scale=2.446):
@@ -288,7 +75,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(shares, factor, monkeypatch):
         assert float(cnt["dropped"]) == 0.0
         total, assigned = total + y, assigned + float(cnt["assigned"])
         past += float(cnt["past_buffer"])
-    shared = tfm._swiglu(c["x"], c["sgu"], c["sd"])
+    shared = _shared_expert(c)
     np.testing.assert_allclose(total + shared, _uncut_layer(c), atol=2e-5)
     assert assigned == c["x"].shape[0] * c["x"].shape[1] * c["k"]
     assert (past > 0) == (factor < 1)
@@ -306,7 +93,7 @@ def test_no_assignment_dropped_under_a_skewed_router(monkeypatch):
     kw = dict(experts_per_token=c["k"], routed_scale=2.446,
               dtype=jnp.float32)
     T = c["x"].shape[0] * c["x"].shape[1]
-    shared = tfm._swiglu(c["x"], c["sgu"], c["sd"])
+    shared = _shared_expert(c)
     y, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["b"], c["wgu"], c["wd"],
                               **kw)
     assert float(cnt["dropped"]) == 0.0 == float(cnt["past_buffer"])
@@ -397,18 +184,91 @@ def test_counts_match_the_cut():
     assert "32 chips" in conf["deployment"] and conf["assumed"]
 
 
+def _layer_by_layer(params, toks, cfg):
+    """The stack as a Python loop over its layers, each taken out of the
+    stored tree by `layer_params`: no plan, no scan, no remat."""
+    x = tfm.embed_tokens(params, toks, cfg)
+    positions = jnp.broadcast_to(jnp.arange(toks.shape[1], dtype=jnp.int32),
+                                 toks.shape)
+    for l, kind in enumerate(cfg.layer_kinds()):
+        x, _ = tfm._layer_body(cfg, kind, x, tfm.layer_params(params, cfg, l),
+                               positions)
+    return tfm.lm_head(params, x, cfg)
+
+
+@pytest.mark.parametrize("name,over,plan", [
+    # kda | mla | attn: one layer of each mixer, the last in neither list
+    ("attn_tail", dict(n_layers=3, kda_layers=(1,), mla_layers=(2,)),
+     [(1, 1), (1, 1), (1, 1)]),
+    # (kda, attn) twice, then kda: a pattern of two positions with repeats,
+    # under the remat policy
+    ("kda_attn_pairs", dict(n_layers=5, kda_layers=(1, 3, 5), mla_layers=(),
+                            moe_first_dense=0, remat=True,
+                            remat_policy="dots"),
+     [(2, 2), (1, 1)]),
+    # grouped-query attention between mla layers, every layer with experts
+    ("gqa_among_mla", dict(n_layers=4, kda_layers=(), mla_layers=(1, 2, 4),
+                           n_kv_heads=2, moe_first_dense=0),
+     [(1, 2), (1, 1), (1, 1)]),
+])
+def test_scanned_segments_match_layer_by_layer(name, over, plan):
+    """A softmax-attention layer among kda / mla ones (rope on it alone), in
+    a plan of several segments: `_backbone`'s scans against the same layers
+    applied one by one. Logits, and the gradient of every leaf of every
+    layer, found through `stack_segments` / `layer_slot` on both sides."""
+    cfg = kimi_linear_tiny(dtype=jnp.float32, positional="rope",
+                           moe_held=(4, 8), **over)
+    assert [(len(p), r) for p, r in cfg.stack_plan()] == plan
+    mixers = {m for m, _ in cfg.layer_kinds()}
+    assert "attn" in mixers and len(mixers) >= 2
+    params = tfm.init_params(jax.random.key(2), cfg)
+    toks = jax.random.randint(jax.random.key(3), (2, 24), 0, cfg.vocab_size)
+
+    def logits_and_grads(forward):  # one compile a side
+        def loss(p):
+            logits = forward(p, toks, cfg)
+            return jnp.mean(jnp.sin(logits)), logits
+        (_, logits), g = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+        return logits, g
+
+    logits, g_scan = logits_and_grads(tfm.forward)
+    want_logits, g_loop = logits_and_grads(_layer_by_layer)
+    np.testing.assert_allclose(logits, want_logits, atol=1e-5)
+    for l, (mixer, ffn) in enumerate(cfg.layer_kinds()):
+        got = tfm.layer_params(g_scan, cfg, l)
+        want = tfm.layer_params(g_loop, cfg, l)
+        assert ({"attn": "wo", "mla": "mla_wo", "kda": "kda_wo"}[mixer] in got
+                and {"dense": "w_down", "moe": "moe_w_down"}[ffn] in got)
+        for n in want:
+            if n == "router_bias":
+                continue  # a buffer
+            np.testing.assert_allclose(
+                got[n], want[n], err_msg=f"{name} layer {l} {n}",
+                atol=1e-6 + 1e-4 * float(jnp.abs(want[n]).max()))
+
+
 def test_classic_stacks_are_what_they_were():
     """A configuration with no kda / mla layer and the GShard router keeps
-    its one stacked tree, its counts and `stack_plan` of one segment."""
+    its one stacked tree (a dict of leaves [L, ...], also where
+    `param_logical_specs` describes it), its counts and `stack_plan` of one
+    segment; any other stack is a list of segments, a one-layer one too."""
     from ray_tpu.models.configs import gpt2_125m, llama_tiny
 
     for cfg in (llama_tiny(), llama_tiny(moe_num_experts=4), gpt2_125m()):
-        assert not cfg.mixed
+        specs = tfm.param_logical_specs(cfg)
+        assert isinstance(specs["layers"], dict)
+        assert tfm.stack_segments(specs, cfg) == [[specs["layers"]]]
         assert cfg.stack_plan() == (((cfg.layer_kinds()[0],), cfg.n_layers),)
     assert gpt2_125m().num_params() == 124_439_808 - 82_944  # no lin. biases
     p = tfm.init_params(jax.random.key(0), llama_tiny())
     assert isinstance(p["layers"], dict)
+    assert p["layers"]["wo"].shape == (2, 128, 128)
     assert tfm.layer_params(p, llama_tiny(), 1)["wo"].shape == (128, 128)
+    one = kimi_linear_tiny(n_layers=1)  # one KDA layer, a dense feed-forward
+    assert one.stack_plan() == (((("kda", "dense"),), 1),)
+    specs = tfm.param_logical_specs(one)
+    assert isinstance(specs["layers"], list)
+    assert tfm.stack_segments(specs, one) is specs["layers"]
     with pytest.raises(ValueError):
         kimi_linear_tiny(moe_router="softmax_capacity")
 

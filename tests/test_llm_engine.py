@@ -75,13 +75,15 @@ def test_slot_reuse_after_retirement(engine_setup):
 def test_eos_retires_early(engine_setup):
     cfg, params = engine_setup
     probe = _naive(params, cfg, [5, 9, 2], 4)
-    eos = probe[1]  # force an early stop at the second token
+    eos = probe[1]  # an early stop: at the second token, or where it came first
+    want = _naive(params, cfg, [5, 9, 2], 4, eos=eos)
+    assert want[-1] == eos and len(want) <= 2
     eng = ContinuousBatchingEngine(cfg, params, num_slots=2,
                                    max_prompt_len=16, max_new_tokens=4)
     s = eng.submit([5, 9, 2], eos_id=eos)
     while eng.tick():
         pass
-    assert eng.result(s, timeout=60) == probe[:2]
+    assert eng.result(s, timeout=60) == want
 
 
 def test_background_thread_and_blocking_submit(engine_setup):

@@ -132,13 +132,11 @@ def _tiny_batch(cfg, shape=(2, 32)):
     ("xla", dict(remat=True, remat_policy="dots"), 0),
     ("flash", dict(remat=False), 1),
     ("flash", dict(remat=True, remat_policy="dots"), 1),
-    ("flash", dict(remat=True, remat_policy="half_dots"), 1),
-    ("flash", dict(remat=True, remat_policy="min"), 1),
     ("flash", dict(remat=True, remat_policy="full"), 2),
 ])
 def test_remat_matches_no_remat(monkeypatch, impl, remat_kw,
                                 fwd_calls_per_layer):
-    """Every remat policy gives the gradients of no remat, and only "full"
+    """Both remat policies give the gradients of no remat, and only "full"
     runs the flash forward kernel a second time in the backward: "dots"
     keeps the kernel's own residuals (o, lse), which no dot produces."""
     monkeypatch.setenv("RTPU_ATTN_IMPL", impl)
@@ -197,9 +195,9 @@ def test_remat_dots_saved_residuals(monkeypatch):
     cfg = llama_tiny(remat=True, remat_policy="dots")
     B, S, H, hd = 2, 32, cfg.n_heads, cfg.head_dim
     params = tfm.init_params(jax.random.key(0), cfg)
-    layer = jax.tree.map(lambda a: a[0], params["layers"])
+    layer = tfm.layer_params(params, cfg, 0)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    body = tfm.layer_scan_body(cfg, positions)
+    body = tfm.layer_scan_body(cfg, cfg.layer_kinds()[0], positions)
     saved = [
         (aval.shape, str(aval.dtype), why) for aval, why in saved_residuals(
             lambda x, l: body(x, l)[0].astype(jnp.float32).sum(),
@@ -217,8 +215,10 @@ def test_remat_dots_saved_residuals(monkeypatch):
                 if sh == (B, S, H, hd) and "_qkv_proj" not in why]
 
 
-def test_remat_policy_dots_attn_is_gone():
-    cfg = llama_tiny(remat=True, remat_policy="dots_attn")
-    params = tfm.init_params(jax.random.key(0), cfg)
-    with pytest.raises(ValueError, match="unhandled remat_policy"):
-        tfm.loss_fn(params, _tiny_batch(cfg), cfg)
+@pytest.mark.parametrize("policy", ["dots_attn", "min", "half_dots",
+                                    "half_full"])
+def test_remat_policy_that_is_gone_raises_at_construction(policy):
+    """`remat_policy` is "dots" or "full"; a name that once meant something
+    is refused where the configuration is made, not at trace time."""
+    with pytest.raises(ValueError, match=f"unknown remat_policy '{policy}'"):
+        llama_tiny(remat=True, remat_policy=policy)
