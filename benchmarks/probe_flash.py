@@ -1,12 +1,18 @@
 """Flash kernel probe on the chip: does every shape the system gives the
 kernel compile under this Mosaic, does it agree with the XLA reference, and
-how long does it take.
+how long does each kernel take at each tile size.
 
-Shapes: the serving prefill buckets ([1, S, H, D] for S = 8 .. 256, forward
-only) and the bench_350m training shape ([8, 1024, 16, 64], forward and
-gradient) at each block size. One JSON line per case; a case that fails to
-compile or disagrees is reported with its error and makes the exit status 1.
-Off the chip the script fails at once.
+Shapes: the serving prefill buckets ([1, S, 16, 128] for S = 8 .. 2048,
+forward only, the table's tiles) and the three training cells' shapes
+(`CELLS`: forward and gradient against the reference at the table's tiles,
+then forward, dQ and dK/dV timed one by one for every (block, strip) of
+`SWEEP`). The table `_TILES` of ops/flash_attention.py is filled from the
+sweep's lines. One JSON line per case; a case that fails to compile or
+disagrees is reported with its error and makes the exit status 1. Off the
+chip the script fails at once.
+
+    python3 benchmarks/probe_flash.py            # check + sweep
+    python3 benchmarks/probe_flash.py check      # check only
 """
 from __future__ import annotations
 
@@ -21,30 +27,44 @@ import time
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops.attention import reference_attention
-from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.util.jaxenv import enable_compile_cache, require_tpu
 
-H, D = 16, 64  # bench_350m heads / head_dim
+# (name, B, S, H, KVH, D, Dv): what `attention()` hands the kernels in
+# gpt2_124m.train_1chip, internlm2_1_8b.train_mesh4 (a device's shard) and
+# kimi_linear_48b_a3b.train_share_8k (MLA).
+CELLS = [("gpt2", 16, 1024, 12, 12, 64, 64),
+         ("internlm2_shard", 4, 2048, 8, 4, 128, 128),
+         ("kimi_mla", 1, 8192, 32, 32, 192, 128)]
+SWEEP = [(b, s) for b in (512, 1024, 2048, 4096) for s in (128, 256, 512)]
+SWEEP += [(b, b) for b in (512, 1024)]  # no strips: the split of tiles alone
 # Flash and reference see the same bf16 inputs; flash rounds P to bf16 before
 # the PV matmul and the output to bf16 (ulp 2^-8), so errors relative to the
 # largest reference value are a few 2^-8. Computing in a lower precision than
 # bf16-in/f32-accumulate would exceed this.
 TOL = 2e-2
+CHECK_HEADS = 4  # the reference holds [B, H, S, S] in float32
 
 
-def _qkv(B, S):
-    ks = jax.random.split(jax.random.key(S), 3)
-    return tuple(jax.random.normal(k, (B, S, H, D), jnp.bfloat16) for k in ks)
+def _qkv(B, S, H, KVH, D, Dv):
+    ks = jax.random.split(jax.random.key(S + D), 3)
+    return (jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16),
+            jax.random.normal(ks[1], (B, S, KVH, D), jnp.bfloat16),
+            jax.random.normal(ks[2], (B, S, KVH, Dv), jnp.bfloat16))
 
 
-def _time(fn, args, steps=10):
+def _time(fn, args, steps=30, repeats=3):
+    """ms a call: the least of `repeats` means over `steps` calls in flight."""
     jax.block_until_ready(fn(*args))
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / steps * 1e3
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / steps * 1e3)
+    return best
 
 
 def _max_err(a, b):
@@ -60,12 +80,13 @@ def _loss(fn):
     return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
 
 
-def probe(B, S, block, grad):
-    q, k, v = _qkv(B, S)
-    flash = lambda q, k, v: flash_attention(
-        q, k, v, causal=True, block_q=block, block_k=block)
+def check(B, S, H, KVH, D, Dv, grad):
+    """The table's tiles against the reference, forward and gradient."""
+    q, k, v = _qkv(B, S, H, KVH, D, Dv)
+    flash = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
     ref = lambda q, k, v: reference_attention(q, k, v, causal=True)
-    row = {"B": B, "S": S, "block": block}
+    row = {"check": [B, S, H, KVH, D, Dv],
+           "tiles": fa.tile_sizes(S, D, Dv, q.dtype)}
     t0 = time.perf_counter()
     fwd = jax.jit(flash).lower(q, k, v).compile()
     row["compile_s"] = round(time.perf_counter() - t0, 2)
@@ -75,7 +96,6 @@ def probe(B, S, block, grad):
         want = jax.jit(ref)(q, k, v)
     row["fwd_rel_err"] = round(
         _max_err(fwd(q, k, v), want) / _max_abs([want]), 5)
-    row["fwd_ms"] = round(_time(fwd, (q, k, v)), 3)
     if grad:
         t0 = time.perf_counter()
         g = jax.jit(jax.grad(_loss(flash), (0, 1, 2))).lower(q, k, v).compile()
@@ -85,30 +105,63 @@ def probe(B, S, block, grad):
         row["grad_rel_err"] = round(
             max(_max_err(a, b) for a, b in zip(g(q, k, v), gw))
             / _max_abs(gw), 5)
-        row["grad_ms"] = round(_time(g, (q, k, v)), 3)
     ok = row["fwd_rel_err"] < TOL and row.get("grad_rel_err", 0.0) < TOL
     return row, ok
 
 
-def main() -> int:
+def kernel_ms(B, S, H, KVH, D, Dv, block, sub):
+    """Forward, dQ and dK/dV alone (XLA drops the kernel whose results a
+    program does not return), in model layout's transposed form."""
+    q, k, v = (jnp.swapaxes(x, 1, 2) for x in _qkv(B, S, H, KVH, D, Dv))
+    scale = D ** -0.5
+    tiles = dict(block_q=block, block_k=block, sub=sub)
+    fwd = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, scale, True, **tiles))
+    o, lse = fwd(q, k, v)
+    do = jnp.ones_like(o)
+    delta = jnp.sum(o.astype(jnp.float32), axis=-1)
+    bwd = lambda pick: jax.jit(lambda *a: pick(fa.flash_bwd_core(
+        *a, scale=scale, causal=True, **tiles)))
+    args = (q, k, v, do, lse, delta)
+    return {"fwd_ms": round(_time(fwd, (q, k, v)), 4),
+            "dq_ms": round(_time(bwd(lambda g: g[0]), args), 4),
+            "dkv_ms": round(_time(bwd(lambda g: g[1:]), args), 4)}
+
+
+def main(argv) -> int:
     dev = require_tpu()
     enable_compile_cache()
     print(json.dumps({"device_kind": dev.device_kind,
                       "devices": len(jax.devices())}), flush=True)
-    cases = [(1, S, 512, False) for S in (8, 16, 32, 64, 128, 256)]
-    cases += [(8, 1024, b, True) for b in (128, 256, 512)]
     failed = 0
-    for B, S, block, grad in cases:
+
+    def report(row, fn, *args):
+        nonlocal failed
         try:
-            row, ok = probe(B, S, block, grad)
-        except Exception as e:  # report every shape, fail at the end
-            row, ok = {"B": B, "S": S, "block": block,
-                       "error": f"{type(e).__name__}: {e}"[:1500]}, False
+            got, ok = fn(*args)
+            row.update(got)
+        except Exception as e:  # report every case, fail at the end
+            row["error"], ok = f"{type(e).__name__}: {e}"[:600], False
         row["ok"] = ok
         failed += not ok
         print(json.dumps(row), flush=True)
+
+    for S in (8, 16, 32, 64, 128, 256, 512, 1024, 2048):
+        report({}, check, 1, S, 16, 8, 128, 128, False)
+    for name, B, S, H, KVH, D, Dv in CELLS:
+        h = min(H, CHECK_HEADS)
+        report({"cell": name}, check, B if S < 8192 else 1, S, h,
+               max(1, h * KVH // H), D, Dv, True)
+    if argv[1:] == ["check"]:
+        return 1 if failed else 0
+    for name, B, S, H, KVH, D, Dv in CELLS:
+        for block, sub in SWEEP:
+            if block > S:
+                continue
+            report({"cell": name, "block": block, "sub": sub},
+                   lambda *a: (kernel_ms(*a), True),
+                   B, S, H, KVH, D, Dv, block, sub)
     return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    raise SystemExit(main(sys.argv))

@@ -3,23 +3,35 @@
 The reference framework ships no attention kernels (it delegates the model
 math to torch; SURVEY.md §5.7 — long-context is a first-class gap to fill).
 Here the flash kernel is the MFU-critical op: online-softmax tiling keeps the
-S×S logits out of HBM, blocks are 128×128 to land on the MXU, and the
-backward pass recomputes P from saved per-row logsumexp instead of storing
-probabilities.
+S×S logits out of HBM, and the backward pass recomputes P from saved per-row
+logsumexp instead of storing probabilities.
 
 Layout: the public entry takes [B, S, H, D] (model layout) and transposes to
 [B, H, S, D] so the trailing two block dims are (block_s, head_dim) — full
-(sublane, lane) tiles. XLA fuses the transposes into neighbouring ops.
+(sublane, lane) tiles. XLA fuses the transposes into neighbouring ops. The row
+statistics (`lse`, `delta`) are [B, H, S] float32 and cross the kernels as
+(1, block) blocks, the sequence on the lanes.
 
 Grid convention: the innermost grid dimension is the contraction over KV (or
 Q, in the dk/dv kernel) blocks; TPU grids execute sequentially so VMEM
 scratch accumulators carry across it ("arbitrary" dimension semantics), and
 outputs are flushed on the last inner step.
+
+A tile's work, in all three kernels alike (`_tile_class`, `tile_plan`): a grid
+tile wholly above the diagonal is *skipped* (no compute, and its index maps
+stay on the last needed block, so nothing is fetched for it); one wholly
+below it and inside the sequence is *interior* and runs with no mask at all;
+only an *edge* tile (cut by the diagonal or by a ragged last block) builds
+one. Inside a grid step the accumulating side goes in strips of `sub`
+positions, and on a diagonal tile each strip meets only the other side's
+positions on its side of the diagonal: `sub` x `sub` cells on the diagonal
+under a fixed triangle, the rest unmasked, the cells above it never computed.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,12 +39,28 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# 512-blocks win on v5e at bench shapes (benchmarks/probe_flash.py at
-# [8,1024,16,64], chip run PR 21: fwd 3.79ms @128 -> 1.00ms @512, grad
-# 10.2 -> 2.91); VMEM for the [bq, bk] f32 score tile stays at 1MB.
-# Module-level so benchmarks/mfu_sweep.py can tune without threading
-# kwargs through every model layer.
+# The fallback for shapes `_TILES` does not know, and the grid block of a
+# sequence the table's block does not divide.
 DEFAULT_BLOCK = 512
+# (D, Dv) of a 2-byte dtype -> (grid block, strip), from the sweep of
+# benchmarks/probe_flash.py on a v5e at the three training cells' shapes
+# (chip run PR 30, `sweep2`; ms a call forward + dQ + dK/dV, causal; the
+# parent's 512 x 512 with the mask on every tile first):
+#   [16,1024,12,64]        6.07 | 512/256 4.37 | 1024/128 3.49 | 1024/256 3.74
+#   [4,2048,8|4,128]       2.08 | 1024/256 1.39 | 2048/256 1.19 | 2048/512 1.27
+#   [1,8192,32,192|128]   42.0  | 512/256 33.0 | 1024/256 27.4 | 2048/256 35.5
+# A larger block wins until VMEM pushes back (one grid step a head at 1,024
+# and 2,048: K and V are fetched once); a strip under 128 rows cannot be cut
+# along the lanes, and a taller one feeds the MXU more rows for each block of
+# keys it loads but computes more of a diagonal cell's upper half. Head 64
+# takes 256 where 128 is 7% faster: every strip is unrolled in the trace,
+# and at 128 a cached start of the GPT-2 cell was 2.8 s longer than the
+# parent's (10% of it, the bound) for 0.25 ms a layer (`PERF.md` section 6).
+_TILES = {
+    (64, 64): (1024, 256),
+    (128, 128): (2048, 256),
+    (192, 128): (1024, 256),
+}
 _NEG_INF = -1e30
 
 
@@ -42,13 +70,177 @@ def _interpret() -> bool:
     return jax.devices()[0].platform == "cpu"
 
 
+# ---------------------------------------------------------------- tiles
+
+
+def tile_sizes(S, D, Dv, dtype, block_q=None, block_k=None, sub=None):
+    """(bq, bk, sub) for one call, from the shapes alone: `_TILES`' row for
+    the head widths where the caller names no block, `DEFAULT_BLOCK` where
+    the table has no row or its block does not divide S. A strip that does
+    not divide both blocks is the whole block (no strips)."""
+    row = _TILES.get((D, Dv)) if jnp.dtype(dtype).itemsize == 2 else None
+    t_block, t_sub = row or (DEFAULT_BLOCK, DEFAULT_BLOCK)
+    if S > t_block and S % t_block:
+        t_block = DEFAULT_BLOCK
+    bq = min(block_q or t_block, S)
+    bk = min(block_k or t_block, S)
+    sub = min(sub or t_sub, bq, bk)
+    if bq % sub or bk % sub:
+        sub = max(bq, bk)
+    return bq, bk, sub
+
+
+def _decomposed(causal, bq, bk, sub, seq_len) -> bool:
+    """Whether a tile on the diagonal is taken cell by cell: its place
+    relative to the diagonal has to be static, which square blocks that
+    divide the sequence give (every edge tile then has iq == ik)."""
+    return bool(causal and bq == bk and seq_len % bq == 0 and sub < bq)
+
+
+def _tile_class(iq, ik, *, bq, bk, seq_len, causal, ragged):
+    """(skipped, interior, edge) of grid tile (iq, ik), on Python ints or on
+    traced program ids. `ragged` names the side whose padded last block
+    needs a mask: "k" in the forward and dQ kernels (padded keys would enter
+    every row's softmax), "q" in the dK/dV kernel (padded queries would add
+    to every key's gradient)."""
+    row0, col0 = iq * bq, ik * bk
+    end = col0 + bk if ragged == "k" else row0 + bq
+    inside, outside = end <= seq_len, end > seq_len
+    if not causal:
+        return False, inside, outside
+    last_row = row0 + (bq - 1)
+    last_col = col0 + (bk - 1)
+    return (col0 > last_row, (last_col <= row0) & inside,
+            (col0 <= last_row) & ((last_col > row0) | outside))
+
+
+def _tile_bodies(iq, ik, **tile):
+    """`pl.when` for the interior body and for the edge body of grid step
+    (iq, ik). A class no tile of the whole grid has (one block a sequence
+    has no interior tile; a full, even grid no edge) gets a decorator that
+    drops its body: a kernel traces and compiles no body it cannot enter."""
+    grid = [_tile_class(i, j, **tile)
+            for i in range(pl.cdiv(tile["seq_len"], tile["bq"]))
+            for j in range(pl.cdiv(tile["seq_len"], tile["bk"]))]
+    here = _tile_class(iq, ik, **tile)
+    return [pl.when(here[c]) if any(t[c] for t in grid) else (lambda f: None)
+            for c in (1, 2)]
+
+
+class TilePlan(NamedTuple):
+    """What the forward kernel does for one (batch, head), counted in cells
+    of `sub` x `sub` where diagonal tiles are decomposed and in grid tiles
+    where they are not. Interior cells run unmasked, edge cells build a
+    mask, skipped cells are neither computed nor fetched."""
+    bq: int
+    bk: int
+    sub: int
+    tiles_interior: int
+    tiles_edge: int
+    tiles_skipped: int
+
+
+def tile_plan(seq_len, bq, bk, sub, causal) -> TilePlan:
+    dec = _decomposed(causal, bq, bk, sub, seq_len)
+    n = bq // sub if dec else 1
+    interior = edge = skipped = 0
+    for iq in range(pl.cdiv(seq_len, bq)):
+        for ik in range(pl.cdiv(seq_len, bk)):
+            s, i, _ = _tile_class(iq, ik, bq=bq, bk=bk, seq_len=seq_len,
+                                  causal=causal, ragged="k")
+            if i:
+                interior += n * n
+            elif s:
+                skipped += n * n
+            else:  # a diagonal tile by cells, or a whole masked tile
+                interior += n * (n - 1) // 2
+                edge += n
+                skipped += n * (n - 1) // 2
+    return TilePlan(bq, bk, sub if dec else max(bq, bk), interior, edge,
+                    skipped)
+
+
+def _compiler_params(bq, bk, sub, D, Dv, itemsize):
+    """Grid semantics, and room in VMEM for blocks past the compiler's 16 MiB
+    of scoped stack: the double-buffered blocks of the widest kernel
+    (dK/dV), its accumulators, and half a dozen [sub, block] float32
+    temporaries. A v5e core has 128 MiB."""
+    blocks = 2 * itemsize * (2 * bq * (D + Dv) + 2 * bk * (D + Dv))
+    need = blocks + 4 * bk * (D + Dv) + 6 * 4 * sub * max(bq, bk)
+    limit = min(2 * need, 100 << 20) if need > (12 << 20) else None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=limit)
+
+
+def _strips(n, sub):
+    """Index of each strip of `sub` among `n` positions of a block."""
+    if sub >= n or n % sub:
+        return [slice(None)]
+    return [pl.ds(a * sub, sub) for a in range(n // sub)]
+
+
+def _dot_nt(a, b):
+    """[m, d] x [n, d] -> [m, n]. Dots take the native bf16 operands (MXU
+    full rate) and accumulate in f32 via preferred_element_type; casting
+    inputs to f32 would drop the MXU to a quarter of its bf16 rate."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_nn(a, b):
+    """[m, n] x [n, d] -> [m, d], f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _folds(scale) -> bool:
+    """A power-of-two scale (D = 64: 0.125) multiplies `q` exactly in any
+    float type, so it leaves the [bq, bk] score tile for the [bq, D] block:
+    the same bits, a `bk / D`-th of the multiplies."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _scaled(x, scale):
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _triangle(sub, lower_rows):
+    """The mask of a cell on the diagonal: [sub, sub], query >= key, with
+    queries on the rows (`lower_rows`) or on the columns."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    return r >= c if lower_rows else c >= r
+
+
+def _edge_mask(iq, ik, bq, bk, seq_len, causal, queries_on_rows):
+    """The mask of a whole edge tile from its place in the sequence:
+    [bq, bk] (forward, dQ: keys inside the sequence) or [bk, bq] (dK/dV:
+    queries inside it), and under the diagonal if causal."""
+    shape, q_dim = ((bq, bk), 0) if queries_on_rows else ((bk, bq), 1)
+    rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    valid = (cols if queries_on_rows else rows) < seq_len
+    return valid & (rows >= cols) if causal else valid
+
+
+def _zero_padded(block0, n, seq_len, *arrays):
+    """Rows of a ragged last block beyond the sequence hold uninitialized
+    memory (possibly NaN/inf); a masked p of exactly 0 still yields
+    0*NaN=NaN in a dot, so they are zeroed."""
+    valid = (block0 + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)) < seq_len
+    return [jnp.where(valid, a, jnp.zeros_like(a)) for a in arrays]
+
+
 # ---------------------------------------------------------------- forward
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, bq, bk, nk, seq_len):
-    ik = pl.program_id(3)
-    iq = pl.program_id(2)
+                *, scale, causal, bq, bk, seq_len, sub):
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    fold = _folds(scale)
+    when_interior, when_edge = _tile_bodies(
+        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k")
 
     @pl.when(ik == 0)
     def _init():
@@ -56,232 +248,299 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal: skip blocks entirely in the future (first row of the q block
-    # is above the last col of the k block).
-    needed = True
-    if causal:
-        needed = (iq * bq + bq - 1) >= (ik * bk)
+    def queries(rows):
+        q = q_ref[rows]
+        return _scaled(q, scale) if fold else q
 
-    @pl.when(needed)
-    def _block():
-        # Dots take the native bf16 operands (MXU full rate) and accumulate
-        # in f32 via preferred_element_type; only the softmax statistics are
-        # carried in f32. Casting inputs to f32 would drop the MXU to a
-        # quarter of its bf16 rate.
-        q = q_ref[0, 0]                       # [bq, D] bf16
-        k = k_ref[0, 0]                       # [bk, D] bf16
-        v = v_ref[0, 0]                       # [bk, D] bf16
-        if seq_len % bk:
-            # Padded kv rows hold uninitialized garbage (possibly NaN/inf);
-            # a masked p of exactly 0 still yields 0*NaN=NaN in the dot.
-            kv_valid = (ik * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bk, 1), 0)) < seq_len
-            k = jnp.where(kv_valid, k, jnp.zeros_like(k))
-            v = jnp.where(kv_valid, v, jnp.zeros_like(v))
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk] f32
-        if causal or seq_len % bk:
-            rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            valid = cols < seq_len
-            if causal:
-                valid &= rows >= cols
-            s = jnp.where(valid, s, _NEG_INF)
-        m_prev = m_scr[:]                     # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                # [bq, bk] f32
-        alpha = jnp.exp(m_prev - m_new)       # [bq, 1]
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def score(q, k):
+        s = _dot_nt(q, k)                     # [rows, keys] f32
+        return s if fold else s * scale
 
-    @pl.when(ik == nk - 1)
+    def step(rows, scores, values):
+        """One online-softmax update of `rows` from the score pieces of one
+        tile and their value rows; only the statistics are carried in f32."""
+        m_prev = m_scr[rows]                  # [rows, 1]
+        m_new = m_prev
+        for s in scores:
+            m_new = jnp.maximum(m_new, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        l = l_scr[rows] * alpha
+        acc = acc_scr[rows] * alpha
+        for s, v in zip(scores, values):
+            p = jnp.exp(s - m_new)
+            l = l + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc + _dot_nn(p.astype(v.dtype), v)
+        m_scr[rows] = m_new
+        l_scr[rows] = l
+        acc_scr[rows] = acc
+
+    @when_interior
+    def _interior():
+        k, v = k_ref[...], v_ref[...]
+        for rows in _strips(bq, sub):
+            step(rows, [score(queries(rows), k)], [v])
+
+    if _decomposed(causal, bq, bk, sub, seq_len):
+        @when_edge
+        def _diagonal():
+            tri = _triangle(sub, lower_rows=True)
+            for a, rows in enumerate(_strips(bq, sub)):
+                q = queries(rows)
+                scores = [jnp.where(tri, score(q, k_ref[rows]),
+                                    _NEG_INF)]
+                values = [v_ref[rows]]
+                if a:
+                    past = pl.ds(0, a * sub)
+                    scores.append(score(q, k_ref[past]))
+                    values.append(v_ref[past])
+                step(rows, scores, values)
+    else:
+        @when_edge
+        def _edge():
+            k, v = k_ref[...], v_ref[...]
+            if seq_len % bk:
+                k, v = _zero_padded(ik * bk, bk, seq_len, k, v)
+            valid = _edge_mask(iq, ik, bq, bk, seq_len, causal, True)
+            s = jnp.where(valid, score(queries(slice(None)), k), _NEG_INF)
+            step(slice(None), [s], [v])
+
+    @pl.when(ik == pl.cdiv(seq_len, bk) - 1)
     def _flush():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:] + jnp.log(l_safe)
+        o_ref[...] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        lse_ref[...] = (m_scr[:] + jnp.log(l_safe)).T
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
+def _stat_spec(bq, index_map):
+    """A (1, bq) block of a [B, H, 1, S'] statistic, the sequence on the
+    lanes: a float32 [..., S, 1] block stores 128 lanes for each one used."""
+    return pl.BlockSpec((None, None, 1, bq), index_map)
+
+
+def _kv_block(causal, bq, bk):
+    """The K/V block of grid step (i, j) of the forward and dQ kernels. A
+    skipped step (j beyond the diagonal of q block i) keeps the last needed
+    block: the pipeline copies nothing when the index does not move."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+
+
+def _q_block(causal, bq, bk):
+    """The same for the Q-side blocks of the dK/dV kernel at (j, i): skipped
+    steps come first there and wait on the first needed block."""
+    if not causal:
+        return lambda j, i: i
+    return lambda j, i: jnp.maximum(i, (j * bk) // bq)
+
+
+def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None):
     """q: [B,H,S,D], k: [B,KVH,S,D], v: [B,KVH,S,Dv] -> (o [B,H,S,Dv],
-    lse [B,H,S,1] f32). Dv may differ from D (latent attention: keys 192
-    wide, values 128)."""
+    lse [B,H,S] f32). Dv may differ from D (latent attention: keys 192
+    wide, values 128). Each traced call publishes its `TilePlan` as one
+    `flash.plan` observation (layers under one scan trace once)."""
+    from ray_tpu.util import tracing
+
     B, H, S, D = q.shape
     KVH, Dv = k.shape[1], v.shape[-1]
     group = H // KVH
-    bq = min(block_q, S)
-    bk = min(block_k, S)
+    bq, bk, sub = tile_sizes(S, D, Dv, q.dtype, block_q, block_k, sub)
     nq = pl.cdiv(S, bq)
     nk = pl.cdiv(S, bk)
+    tracing.observe("flash.plan", 0, slow=False,
+                    **tile_plan(S, bq, bk, sub, causal)._asdict())
+    kv = _kv_block(causal, bq, bk)
+    params = _compiler_params(bq, bk, sub, D, Dv, q.dtype.itemsize)
 
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk,
-        seq_len=S)
     o, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
+                          bk=bk, seq_len=S, sub=sub),
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, D),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, Dv),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
+            pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, i, j, g=group: (b, h // g, kv(i, j), 0)),
+            pl.BlockSpec((None, None, bk, Dv),
+                         lambda b, h, i, j, g=group: (b, h // g, kv(i, j), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
+            _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
-            # Trailing singleton keeps the (sublane, lane) block = (bq, 1),
-            # which Mosaic accepts (lane == full array dim).
-            jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
+            # Whole blocks, so that no block is ragged along the lanes.
+            jax.ShapeDtypeStruct((B, H, 1, nq * bq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=params,
         interpret=_interpret(),
     )(q, k, v)
-    return o, lse
+    return o, lse[:, :, 0, :S]
 
 
 # ---------------------------------------------------------------- backward
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, bq, bk, nk, seq_len):
-    ik = pl.program_id(3)
-    iq = pl.program_id(2)
+               dq_scr, *, scale, causal, bq, bk, seq_len, sub):
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    fold = _folds(scale)
+    when_interior, when_edge = _tile_bodies(
+        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k")
 
     @pl.when(ik == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    needed = True
-    if causal:
-        needed = (iq * bq + bq - 1) >= (ik * bk)
+    def add(rows, pieces):
+        """dq of `rows` from (k, v, mask) pieces of one tile. p comes from
+        the saved lse, so the pieces are independent of each other."""
+        q, do = q_ref[rows], do_ref[rows]
+        if fold:
+            q = _scaled(q, scale)
+        lse = lse_ref[:, rows].T        # [rows, 1] f32
+        delta = delta_ref[:, rows].T
+        dq = None
+        for k, v, mask in pieces:
+            s = _dot_nt(q, k)
+            if not fold:
+                s = s * scale
+            if mask is not None:
+                s = jnp.where(mask, s, _NEG_INF)
+            ds = jnp.exp(s - lse) * (_dot_nt(do, v) - delta)
+            if not fold:
+                ds = ds * scale
+            part = _dot_nn(ds.astype(k.dtype), k)
+            dq = part if dq is None else dq + part
+        dq_scr[rows] += dq
 
-    @pl.when(needed)
-    def _block():
-        q = q_ref[0, 0]                       # bf16
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                   # [bq, 1] f32
-        delta = delta_ref[0, 0]               # [bq, 1] f32
-        if seq_len % bk:
-            kv_valid = (ik * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bk, 1), 0)) < seq_len
-            k = jnp.where(kv_valid, k, jnp.zeros_like(k))
-            v = jnp.where(kv_valid, v, jnp.zeros_like(v))
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal or seq_len % bk:
-            rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            valid = cols < seq_len
-            if causal:
-                valid &= rows >= cols
-            s = jnp.where(valid, s, _NEG_INF)
-        p = jnp.exp(s - lse)                  # [bq, bk] f32
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    @when_interior
+    def _interior():
+        k, v = k_ref[...], v_ref[...]
+        for rows in _strips(bq, sub):
+            add(rows, [(k, v, None)])
 
-    @pl.when(ik == nk - 1)
+    if _decomposed(causal, bq, bk, sub, seq_len):
+        @when_edge
+        def _diagonal():
+            tri = _triangle(sub, lower_rows=True)
+            for a, rows in enumerate(_strips(bq, sub)):
+                pieces = [(k_ref[rows], v_ref[rows], tri)]
+                if a:
+                    past = pl.ds(0, a * sub)
+                    pieces.append((k_ref[past], v_ref[past],
+                                   None))
+                add(rows, pieces)
+    else:
+        @when_edge
+        def _edge():
+            k, v = k_ref[...], v_ref[...]
+            if seq_len % bk:
+                k, v = _zero_padded(ik * bk, bk, seq_len, k, v)
+            add(slice(None), [(k, v, _edge_mask(iq, ik, bq, bk, seq_len,
+                                                causal, True))])
+
+    @pl.when(ik == pl.cdiv(seq_len, bk) - 1)
     def _flush():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        dq = dq_scr[:]
+        dq_ref[...] = (dq * scale if fold else dq).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, bq, bk, nq, seq_len):
-    iq = pl.program_id(3)
-    ik = pl.program_id(2)
+                *, scale, causal, bq, bk, seq_len, sub):
+    ik, iq = pl.program_id(2), pl.program_id(3)
+    fold = _folds(scale)
+    when_interior, when_edge = _tile_bodies(
+        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="q")
 
     @pl.when(iq == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    needed = True
-    if causal:
-        needed = (iq * bq + bq - 1) >= (ik * bk)
+    def add(keys, pieces):
+        """dk, dv of `keys` from (q, do, lse, delta, mask) pieces of one
+        tile, transposed ([keys, queries]): the statistics lie along the
+        lanes as they arrive. With a folded scale `q` is `q * scale`, which
+        is also what dk's product wants."""
+        k, v = k_ref[keys], v_ref[keys]
+        dk = dv = None
+        for q, do, lse, delta, mask in pieces:
+            st = _dot_nt(k, q)                # [keys, queries] f32
+            if not fold:
+                st = st * scale
+            if mask is not None:
+                st = jnp.where(mask, st, _NEG_INF)
+            pt = jnp.exp(st - lse)
+            dst = pt * (_dot_nt(v, do) - delta)
+            if not fold:
+                dst = dst * scale
+            dv_p = _dot_nn(pt.astype(do.dtype), do)
+            dk_p = _dot_nn(dst.astype(q.dtype), q)
+            dv = dv_p if dv is None else dv + dv_p
+            dk = dk_p if dk is None else dk + dk_p
+        dk_scr[keys] += dk
+        dv_scr[keys] += dv
 
-    @pl.when(needed)
-    def _block():
-        q = q_ref[0, 0]                       # bf16
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                   # [bq, 1] f32
-        delta = delta_ref[0, 0]               # [bq, 1] f32
-        if seq_len % bq:
-            q_valid = (iq * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, 1), 0)) < seq_len
-            q = jnp.where(q_valid, q, jnp.zeros_like(q))
-            do = jnp.where(q_valid, do, jnp.zeros_like(do))
-            delta = jnp.where(q_valid, delta, 0.0)
-        # s^T directly: [bk, bq]
-        st = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        # Padded q rows carry garbage lse/delta — always mask rows >= S so
-        # they cannot contribute to dk/dv of in-range kv rows.
-        cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
-        rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
-        valid = rows < seq_len
-        if causal:
-            valid &= rows >= cols
-        st = jnp.where(valid, st, _NEG_INF)
-        pt = jnp.exp(st - lse.T)              # [bk, bq] f32
-        pt = jnp.where(valid, pt, 0.0)
-        dv_scr[:] += jax.lax.dot_general(
-            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bk, bq]
-        dst = pt * (dpt - delta.T) * scale
-        dk_scr[:] += jax.lax.dot_general(
-            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def queries(qs, mask=None):
+        q = q_ref[qs]
+        return (_scaled(q, scale) if fold else q, do_ref[qs],
+                lse_ref[:, qs], delta_ref[:, qs], mask)
 
-    @pl.when(iq == nq - 1)
+    @when_interior
+    def _interior():
+        piece = queries(slice(None))
+        for keys in _strips(bk, sub):
+            add(keys, [piece])
+
+    if _decomposed(causal, bq, bk, sub, seq_len):
+        @when_edge
+        def _diagonal():
+            tri = _triangle(sub, lower_rows=False)
+            n = bk // sub
+            for b, keys in enumerate(_strips(bk, sub)):
+                pieces = [queries(keys, tri)]
+                if b < n - 1:
+                    pieces.append(queries(pl.ds((b + 1) * sub,
+                                                (n - 1 - b) * sub)))
+                add(keys, pieces)
+    else:
+        @when_edge
+        def _edge():
+            q, do, lse, delta, _ = queries(slice(None))
+            if seq_len % bq:
+                # The statistics' padding is zeros (`flash_bwd_core`).
+                q, do = _zero_padded(iq * bq, bq, seq_len, q, do)
+            add(slice(None), [(q, do, lse, delta, _edge_mask(
+                iq, ik, bq, bk, seq_len, causal, False))])
+
+    @pl.when(iq == pl.cdiv(seq_len, bq) - 1)
     def _flush():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k):
+def _flash_bwd(res, g, scale, causal, block_q, block_k, sub):
     q, k, v, o, lse = res
-    lse = lse[..., None]  # [B, H, S, 1], the kernels' block shape
     do = g.astype(q.dtype)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # [B, H, S, 1]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     return flash_bwd_core(q, k, v, do, lse, delta, scale=scale,
-                          causal=causal, block_q=block_q, block_k=block_k)
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          sub=sub)
 
 
 def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
-                   block_q=DEFAULT_BLOCK, block_k=DEFAULT_BLOCK):
+                   block_q=None, block_k=None, sub=None):
     """Backward kernels given externally supplied row stats.
 
-    lse/delta are [B,H,S,1] and may come from a *global* softmax (ring
+    lse/delta are [B,H,S] and may come from a *global* softmax (ring
     attention merges chunk statistics before calling this per chunk) — p is
     recomputed as exp(s - lse), so partial-chunk gradients compose by
     simple accumulation.
@@ -289,52 +548,58 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     B, H, S, D = q.shape
     KVH, Dv = k.shape[1], v.shape[-1]
     group = H // KVH
-    bq = min(block_q, S)
-    bk = min(block_k, S)
+    bq, bk, sub = tile_sizes(S, D, Dv, q.dtype, block_q, block_k, sub)
     nq = pl.cdiv(S, bq)
     nk = pl.cdiv(S, bk)
+    tiles = dict(scale=scale, causal=causal, bq=bq, bk=bk, seq_len=S, sub=sub)
+    # Whole (1, bq) blocks with zeros behind the sequence: nothing ragged
+    # along the lanes, and a padded query's statistics are numbers.
+    pad = [(0, 0), (0, 0), (0, 0), (0, nq * bq - S)]
+    lse = jnp.pad(lse[:, :, None, :], pad)
+    delta = jnp.pad(delta[:, :, None, :], pad)
+    kv = _kv_block(causal, bq, bk)
+    params = _compiler_params(bq, bk, sub, D, Dv, q.dtype.itemsize)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=nk, seq_len=S),
+        functools.partial(_dq_kernel, **tiles),
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, D),
-                         lambda b, h, i, j, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bk, Dv),
-                         lambda b, h, i, j, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bk, D), lambda b, h, i, j, g_=group:
+                         (b, h // g_, kv(i, j), 0)),
+            pl.BlockSpec((None, None, bk, Dv), lambda b, h, i, j, g_=group:
+                         (b, h // g_, kv(i, j), 0)),
+            pl.BlockSpec((None, None, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
+            _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
+            _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+        out_specs=pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=params,
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
     # dk/dv per *query* head, then segment-sum over the GQA group in XLA.
+    qb = _q_block(causal, bq, bk)
     dk_h, dv_h = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nq=nq, seq_len=S),
+        functools.partial(_dkv_kernel, **tiles),
         grid=(B, H, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, D),
+            pl.BlockSpec((None, None, bq, D),
+                         lambda b, h, j, i: (b, h, qb(j, i), 0)),
+            pl.BlockSpec((None, None, bk, D),
                          lambda b, h, j, i, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bk, Dv),
+            pl.BlockSpec((None, None, bk, Dv),
                          lambda b, h, j, i, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, j, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bq, Dv),
+                         lambda b, h, j, i: (b, h, qb(j, i), 0)),
+            _stat_spec(bq, lambda b, h, j, i: (b, h, 0, qb(j, i))),
+            _stat_spec(bq, lambda b, h, j, i: (b, h, 0, qb(j, i))),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((None, None, bk, D), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((None, None, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
@@ -344,9 +609,7 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, Dv), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=params,
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
@@ -361,9 +624,9 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
 # ---------------------------------------------------------------- public
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, block_q, block_k):
-    o, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, block_q, block_k, sub):
+    o, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, sub)
     return o
 
 
@@ -374,18 +637,15 @@ def _flash(q, k, v, scale, causal, block_q, block_k):
 RESIDUAL_NAMES = ("flash_o", "flash_lse")
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, sub):
+    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, sub)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
-    # Saved as [B,H,S], S on the lanes: a float32 [...,S,1] tiled (8,128)
-    # as it stands stores 128 lanes for each one used (the v5e compiler
-    # re-lays a saved stack S-minor by itself; this does not rest on that).
-    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])  # [B,H,S], S on the lanes
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k)
+def _flash_vjp_bwd(scale, causal, block_q, block_k, sub, res, g):
+    return _flash_bwd(res, g, scale, causal, block_q, block_k, sub)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -400,15 +660,16 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    sub: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention in model layout [B, S, H, D] -> [B, S, H, Dv];
-    differentiable. The values' width Dv may differ from D."""
-    block_q = block_q or DEFAULT_BLOCK
-    block_k = block_k or DEFAULT_BLOCK
+    differentiable. The values' width Dv may differ from D. Blocks and strip
+    left unnamed come from `tile_sizes` (what the sweep measures by naming
+    them, `benchmarks/probe_flash.py`)."""
     D = q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     qt = jnp.swapaxes(q, 1, 2)  # [B, H, S, D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    ot = _flash(qt, kt, vt, scale, causal, block_q, block_k)
+    ot = _flash(qt, kt, vt, scale, causal, block_q, block_k, sub)
     return jnp.swapaxes(ot, 1, 2)

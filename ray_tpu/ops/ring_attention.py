@@ -37,12 +37,13 @@ _NEG_INF = -1e30
 
 
 def _merge(o, lse, o_c, lse_c):
-    """Fold chunk (o_c, lse_c) into running (o, lse); all f32, lse [B,H,S,1]."""
+    """Fold chunk (o_c, lse_c) into running (o, lse); all f32, o [B,H,S,D],
+    lse [B,H,S] (the flash kernels' shape for the statistics)."""
     lse_new = jnp.logaddexp(lse, lse_c)
     # Rows with no valid keys yet have lse == lse_c == -inf; keep them zero.
     w_old = jnp.where(lse == _NEG_INF * 1.0, 0.0, jnp.exp(lse - lse_new))
     w_new = jnp.where(lse_c == _NEG_INF * 1.0, 0.0, jnp.exp(lse_c - lse_new))
-    return o * w_old + o_c * w_new, lse_new
+    return o * w_old[..., None] + o_c * w_new[..., None], lse_new
 
 
 def _ring_perm(sp: int):
@@ -50,7 +51,7 @@ def _ring_perm(sp: int):
 
 
 def _ring_fwd_impl(q, k, v, axis_name, causal, scale, block):
-    """q [B,H,S,D], k/v [B,KVH,S,D] shards -> (o f32, lse [B,H,S,1] f32)."""
+    """q [B,H,S,D], k/v [B,KVH,S,D] shards -> (o f32, lse [B,H,S] f32)."""
     sp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     B, H, S, D = q.shape
@@ -65,10 +66,10 @@ def _ring_fwd_impl(q, k, v, axis_name, causal, scale, block):
 
     def skip_chunk(q, kc, vc):
         return (jnp.zeros((B, H, S, D), jnp.float32),
-                jnp.full((B, H, S, 1), _NEG_INF, jnp.float32))
+                jnp.full((B, H, S), _NEG_INF, jnp.float32))
 
     o = jnp.zeros((B, H, S, D), jnp.float32)
-    lse = jnp.full((B, H, S, 1), _NEG_INF, jnp.float32)
+    lse = jnp.full((B, H, S), _NEG_INF, jnp.float32)
     kc, vc = k, v
     for step in range(sp):
         j = (my - step) % sp
@@ -144,8 +145,7 @@ def _ring_vjp_fwd(q, k, v, axis_name, causal, scale, block):
 def _ring_vjp_bwd(axis_name, causal, scale, block, res, g):
     q, k, v, o, lse = res
     do = g.astype(q.dtype)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     return _ring_bwd_impl(q, k, v, do, lse, delta, axis_name, causal, scale,
                           block)
 

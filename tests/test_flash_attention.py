@@ -3,20 +3,23 @@
 Runs the Pallas kernels in interpret mode on CPU (same code path the TPU
 compiles), checking forward values and gradients, causal + GQA variants.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops.attention import reference_attention
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import flash_attention, tile_plan, tile_sizes
 
 
-def _rand_qkv(key, B, S, H, KVH, D, dtype=jnp.float32):
+def _rand_qkv(key, B, S, H, KVH, D, dtype=jnp.float32, Dv=None):
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, S, H, D), dtype)
     k = jax.random.normal(kk, (B, S, KVH, D), dtype)
-    v = jax.random.normal(kv, (B, S, KVH, D), dtype)
+    v = jax.random.normal(kv, (B, S, KVH, Dv or D), dtype)
     return q, k, v
 
 
@@ -167,3 +170,141 @@ def test_flash_runs_per_shard_on_a_multi_device_mesh():
                                    atol=5e-4, rtol=5e-4)
     # The sharded program really went through shard_map.
     assert "shard_map" in str(jax.make_jaxpr(sharded)(q, k, v))
+
+
+# (S, D, Dv, H, KVH, causal, dtype, tiles). bfloat16 with no tiles named
+# lands on `_TILES`' rows at real sizes (S under a block, a block, ragged,
+# several blocks); float32 with small named tiles checks every body tightly:
+# strips on a diagonal tile, interior strips, the masked whole tile of a
+# ragged or oblong grid, a strip that divides nothing.
+_BF16, _F32 = jnp.bfloat16, jnp.float32
+_CASES = [
+    (384, 64, 64, 4, 4, True, _BF16, {}),
+    (384, 128, 128, 4, 2, False, _BF16, {}),
+    (1024, 64, 64, 4, 4, True, _BF16, {}),
+    (1024, 64, 64, 4, 2, False, _BF16, {}),
+    (1024, 192, 128, 4, 4, True, _BF16, {}),
+    (1280, 64, 64, 4, 2, True, _BF16, {}),
+    (1280, 128, 128, 4, 4, True, _BF16, {}),
+    (1280, 192, 128, 4, 4, False, _BF16, {}),
+    (2048, 64, 64, 4, 4, True, _BF16, {}),
+    (2048, 128, 128, 4, 2, True, _BF16, {}),
+    (2048, 128, 128, 4, 4, False, _BF16, {}),
+    (2048, 192, 128, 4, 2, True, _BF16, {}),
+    (256, 64, 64, 4, 2, True, _F32, dict(block_q=128, block_k=128, sub=32)),
+    (256, 192, 128, 2, 2, True, _F32, dict(block_q=256, block_k=256, sub=64)),
+    (256, 64, 64, 2, 1, False, _F32, dict(block_q=128, block_k=128, sub=32)),
+    (256, 32, 32, 2, 2, True, _F32, dict(block_q=64, block_k=128, sub=32)),
+    (320, 64, 64, 2, 1, True, _F32, dict(block_q=128, block_k=128, sub=64)),
+    (192, 64, 64, 2, 2, True, _F32, dict(block_q=96, block_k=96, sub=64)),
+]
+
+
+@pytest.mark.parametrize(
+    "S,D,Dv,H,KVH,causal,dtype,tiles", _CASES,
+    ids=[f"S{c[0]}-D{c[1]}_{c[2]}-H{c[3]}_{c[4]}-{'causal' if c[5] else 'full'}"
+         f"-{'bf16' if c[6] == _BF16 else 'f32'}" for c in _CASES])
+def test_forward_and_gradients_match_reference(S, D, Dv, H, KVH, causal,
+                                               dtype, tiles):
+    q, k, v = _rand_qkv(jax.random.key(S + D), 1, S, H, KVH, D, dtype, Dv)
+    f32 = lambda xs: [x.astype(jnp.float32) for x in xs]
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
+
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal, **tiles)
+    ref = lambda q, k, v: reference_attention(q, k, v, causal=causal)
+    out, grads = flash(q, k, v), jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = ref(*f32((q, k, v)))
+    wgrads = jax.grad(loss(ref), (0, 1, 2))(*f32((q, k, v)))
+    assert out.shape == (1, S, H, Dv) and out.dtype == dtype
+    # bfloat16: P and the outputs are rounded to 2^-8, so a few 2^-8 of the
+    # largest value (benchmarks/probe_flash.py TOL); a wrong mask is O(1).
+    tol = 2e-2 if dtype == _BF16 else 1e-4
+    for name, a, b in zip(("o", "dq", "dk", "dv"), [out, *grads],
+                          [want, *wgrads]):
+        b = np.asarray(b)
+        err = np.max(np.abs(np.asarray(a.astype(jnp.float32)) - b))
+        assert err <= tol * np.max(np.abs(b)), (name, err, np.max(np.abs(b)))
+
+
+def _painted(S, plan, causal):
+    """The S x S positions the plan's interior and edge cells cover, and the
+    counts of each class, from the cells alone (no `_tile_class`)."""
+    bq, bk, sub = plan.bq, plan.bk, plan.sub
+    cq, ck = (sub, sub) if sub < bq else (bq, bk)
+    g = math.gcd(S, cq, ck)  # painted in squares of g positions
+    covered = np.zeros((S // g, S // g), bool)
+    counts = dict(interior=0, edge=0, skipped=0)
+    for r0 in range(0, S, cq):
+        for c0 in range(0, S, ck):
+            r1, c1 = r0 + cq - 1, c0 + ck - 1  # r1, c1 may pass S (ragged)
+            if causal and c0 > r1:
+                counts["skipped"] += 1
+                continue
+            whole = (not causal or c1 <= r0) and c0 + ck <= S
+            counts["interior" if whole else "edge"] += 1
+            covered[r0 // g:(r0 + cq) // g, c0 // g:(c0 + ck) // g] = True
+    return covered, counts
+
+
+@pytest.mark.parametrize("S,bq,bk,sub,causal", [
+    (1024, 1024, 1024, 256, True), (1024, 512, 512, 128, True),
+    (2048, 1024, 1024, 128, True), (8192, 1024, 1024, 256, True),
+    (8192, 512, 512, 512, True), (1280, 512, 512, 512, True),
+    (1024, 512, 512, 128, False), (1280, 512, 512, 256, False),
+    (1024, 256, 512, 256, True)])
+def test_tile_plan_counts_and_cover(S, bq, bk, sub, causal):
+    plan = tile_plan(S, bq, bk, sub, causal)
+    assert (plan.bq, plan.bk) == (bq, bk)
+    covered, counts = _painted(S, plan, causal)
+    assert (plan.tiles_interior, plan.tiles_edge, plan.tiles_skipped) == (
+        counts["interior"], counts["edge"], counts["skipped"])
+    # Interior + edge cells cover the causal triangle (or the square), and
+    # no computed cell lies wholly outside it.
+    r, c = np.indices(covered.shape)
+    need = (r >= c) if causal else np.ones(covered.shape, bool)
+    assert not (need & ~covered).any()
+    if causal and bq == bk and S % bq == 0:
+        # The closed form in cells of `sub` (n a side): the diagonal is edge,
+        # the strict lower triangle interior, the strict upper one skipped.
+        n = S // plan.sub
+        assert (plan.tiles_interior, plan.tiles_edge, plan.tiles_skipped) == (
+            n * (n - 1) // 2, n, n * (n - 1) // 2)
+    if not causal:
+        n_q, n_k = -(-S // bq), -(-S // bk)
+        ragged = n_q if S % bk else 0
+        assert (plan.tiles_interior, plan.tiles_edge, plan.tiles_skipped) == (
+            n_q * n_k - ragged, ragged, 0)
+
+
+def test_tile_sizes_follow_the_shapes():
+    """The table's row where the widths and dtype are known and its block
+    divides S, `DEFAULT_BLOCK` elsewhere; a named block wins; a strip that
+    divides nothing is the whole block."""
+    block, sub = fa._TILES[(64, 64)]
+    assert tile_sizes(4 * block, 64, 64, jnp.bfloat16) == (block, block, sub)
+    assert tile_sizes(block // 2, 64, 64, jnp.bfloat16)[:2] == (
+        block // 2, block // 2)
+    d = fa.DEFAULT_BLOCK
+    assert tile_sizes(4 * d, 80, 80, jnp.bfloat16) == (d, d, d)
+    assert tile_sizes(4 * d, 64, 64, jnp.float32) == (d, d, d)
+    assert tile_sizes(2 * block + 128, 64, 64, jnp.bfloat16)[:2] == (d, d)
+    assert tile_sizes(1024, 64, 64, jnp.bfloat16, 128, 128) == (
+        128, 128, min(sub, 128))
+    assert tile_sizes(192, 64, 64, jnp.float32, 96, 96, 64) == (96, 96, 96)
+
+
+def test_flash_plan_in_the_phase_table():
+    """A traced call leaves one `flash.plan` observation with the forward's
+    tile counts (layers under one scan trace once)."""
+    from ray_tpu.util import tracing
+
+    before = tracing.phase_table().get("flash.plan", {}).get("count", 0)
+    q, k, v = _rand_qkv(jax.random.key(7), 1, 256, 2, 2, 32)
+    tiles = dict(block_q=128, block_k=128, sub=64)
+    jax.jit(lambda q, k, v: flash_attention(q, k, v, **tiles)).lower(q, k, v)
+    row = tracing.phase_table()["flash.plan"]
+    assert row["count"] == before + 1
+    plan = tile_plan(256, 128, 128, 64, True)
+    assert plan == (128, 128, 64, 6, 4, 6)

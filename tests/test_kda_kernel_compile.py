@@ -1,4 +1,4 @@
-"""The KDA kernels compiled for a v5e at the cell's real shape, with no chip:
+"""The KDA and flash kernels compiled for a v5e at the cells' real shapes, with no chip:
 the TPU compiler is installed here and compiles for a described device.
 Interpret mode (tests/test_kda.py) cannot see what Mosaic refuses
 (unaligned slices, VMEM over the limit, an op with no lowering). Nothing
@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import kda
+from ray_tpu.ops import flash_attention as fa, kda
 
 
 @pytest.fixture(scope="module")
@@ -30,6 +30,7 @@ def compiled_not_interpreted(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(kda, "_interpret", lambda: False)
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -57,3 +58,25 @@ def test_kda_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     text = jax.jit(fn).lower(q, q, q, g, beta).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == (
         1 if what == "forward" else 2)
+
+
+@pytest.mark.parametrize("B,S,H,KVH,D,Dv", [
+    (16, 1024, 12, 12, 64, 64),      # gpt2_124m.train_1chip
+    (4, 2048, 8, 4, 128, 128),       # internlm2_1_8b.train_mesh4, a device
+    (1, 8192, 32, 32, 192, 128),     # kimi_linear_48b_a3b.train_share_8k, MLA
+], ids=["gpt2", "internlm2_shard", "kimi_mla"])
+def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
+                                       B, S, H, KVH, D, Dv):
+    """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape,
+    bfloat16, causal: three Mosaic calls in the gradient's program, and the
+    statistics cross them with the sequence on the lanes ([B,H,1,S])."""
+    sd = lambda sh: jax.ShapeDtypeStruct(sh, jnp.bfloat16, sharding=one_chip)
+    q, k, v = sd((B, S, H, D)), sd((B, S, KVH, D)), sd((B, S, KVH, Dv))
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert f"f32[{B},{H},1,{S}]" in text and f"f32[{B},{H},{S},1]" not in text
