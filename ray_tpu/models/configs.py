@@ -149,3 +149,39 @@ def kimi_linear_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def granite_hybrid_tiny(**overrides) -> TransformerConfig:
+    """A Mamba-2 / NoPE-attention stack in the Granite-4.0-H pattern at
+    widths small enough for CPU tests (docs/model_layers.md): of every ten
+    layers the sixth is GQA attention without positions and the rest are
+    Mamba-2, every feed-forward a dense SwiGLU, the head tied, and the
+    family's four multipliers. Published sizes live in chipbench/configs/
+    only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=10,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-5,
+        activation="swiglu",
+        positional="none",
+        tie_embeddings=True,
+        mamba_layers=tuple(l for l in range(1, 41) if l % 10 != 6),
+        mamba_heads=8,
+        mamba_head_dim=16,
+        mamba_d_state=32,
+        mamba_groups=1,
+        mamba_conv=4,
+        mamba_chunk=16,
+        embed_scale=12.0,
+        residual_scale=0.22,
+        attn_scale=1.0 / 16,  # the family's 1 / head_dim, not its root
+        logit_scale=8.0,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

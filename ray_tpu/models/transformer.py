@@ -45,7 +45,10 @@ class TransformerConfig:
     max_seq_len: int = 2048
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     activation: str = "swiglu"  # swiglu | gelu
-    positional: str = "rope"  # rope | learned | none (KDA / NoPE-MLA stacks)
+    # rope | learned | none: no position signal at all. KDA, MLA-NoPE and
+    # Mamba-2 mixers never take one; with "none" an "attn" layer runs
+    # unrotated too (NoPE attention: the stack's recurrent layers carry order).
+    positional: str = "rope"
     rope_theta: float = 500000.0
     tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16
@@ -104,8 +107,13 @@ class TransformerConfig:
     # - mla_layers: multi-head latent attention without rotation
     #   (`kv_lora_rank` latent + `qk_rope_head_dim` shared key part, keys
     #   `qk_nope_head_dim` + `qk_rope_head_dim` wide, values `v_head_dim`).
+    # - mamba_layers: Mamba-2 (ops/ssd.py), `mamba_heads` heads of
+    #   `mamba_head_dim` with a state `mamba_d_state` wide, B and C shared by
+    #   the heads of each of `mamba_groups` groups, a biased causal depthwise
+    #   convolution of `mamba_conv` over x, B and C, chunks of `mamba_chunk`.
     kda_layers: Tuple[int, ...] = ()
     mla_layers: Tuple[int, ...] = ()
+    mamba_layers: Tuple[int, ...] = ()
     kda_heads: Optional[int] = None       # None => n_heads
     kda_head_dim: int = 128
     kda_conv: int = 4
@@ -115,13 +123,28 @@ class TransformerConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    mamba_heads: int = 64
+    mamba_head_dim: int = 64
+    mamba_d_state: int = 128
+    mamba_groups: int = 1
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
+    # Four scalars some families multiply by (docs/model_layers.md); at
+    # these defaults no operation is traced for them. Embeddings times
+    # `embed_scale`; both residual branches of every layer times
+    # `residual_scale`; `attn_scale` in place of head_dim ** -0.5 in "attn"
+    # layers (None: that default); logits divided by `logit_scale`.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: Optional[float] = None
+    logit_scale: float = 1.0
     # Chunked fused lm-head+CE (ops/fused_ce.py): never materializes the
     # [B*S, V] logits/dlogits tensors (~1GB each way at bench shapes) —
     # vocab chunks stream through online logsumexp fwd / recompute bwd.
     fused_ce: bool = False
 
     def __post_init__(self):
-        for name in ("kda_layers", "mla_layers", "moe_held"):
+        for name in ("kda_layers", "mla_layers", "mamba_layers", "moe_held"):
             v = getattr(self, name)
             if isinstance(v, list):  # from a JSON file
                 object.__setattr__(self, name, tuple(v))
@@ -130,25 +153,28 @@ class TransformerConfig:
                              "'dots' or 'full'")
         if self.moe_router not in ("softmax_capacity", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
-        if set(self.kda_layers) & set(self.mla_layers):
-            raise ValueError("a layer is listed as both kda and mla")
+        lists = self.kda_layers + self.mla_layers + self.mamba_layers
+        if len(set(lists)) != len(lists):
+            raise ValueError("a layer is listed as two of kda, mla, mamba")
         if (self.moe_num_experts and self.moe_router != "sigmoid"
                 and any(m != "attn" for m, _ in self.layer_kinds())):
             raise ValueError(
-                "kda / mla layers compose with moe_router='sigmoid' only")
+                "kda / mla / mamba layers compose with moe_router='sigmoid' "
+                "only")
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
-        """(mixer, feed-forward) of every layer: mixer attn | mla | kda,
-        feed-forward dense | moe."""
+        """(mixer, feed-forward) of every layer: mixer attn | mla | kda |
+        mamba2, feed-forward dense | moe."""
         sig = self.moe_num_experts > 0 and self.moe_router == "sigmoid"
         out = []
         for l in range(self.n_layers):
             mixer = ("kda" if l + 1 in self.kda_layers else
-                     "mla" if l + 1 in self.mla_layers else "attn")
+                     "mla" if l + 1 in self.mla_layers else
+                     "mamba2" if l + 1 in self.mamba_layers else "attn")
             if sig:
                 ffn = "moe" if l >= self.moe_first_dense else "dense"
             else:
@@ -158,7 +184,7 @@ class TransformerConfig:
 
     def stack_plan(self) -> Tuple[Tuple[Tuple[Tuple[str, str], ...], int], ...]:
         """The stack as segments (pattern of layer kinds, repeats): greedily,
-        from each layer on, the period (up to 8) whose repeats cover the
+        from each layer on, the period (up to 10) whose repeats cover the
         most layers; a layer that starts no repeat is a segment of its own.
         Each segment is one `lax.scan` over its repeats, so compile time
         follows the number of segments and not the depth. A stack of one
@@ -166,7 +192,7 @@ class TransformerConfig:
         kinds, plan, i = self.layer_kinds(), [], 0
         while i < len(kinds):
             best = (1, 1)
-            for p in range(1, 9):
+            for p in range(1, 11):
                 r = 1
                 while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
                     r += 1
@@ -191,6 +217,10 @@ class TransformerConfig:
     @property
     def kda_n_heads(self) -> int:
         return self.kda_heads or self.n_heads
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
 
     @property
     def moe_ff_dim(self) -> int:
@@ -225,6 +255,11 @@ class TransformerConfig:
             return (d * (lat + self.qk_rope_head_dim) + lat
                     + lat * H * (self.qk_nope_head_dim + self.v_head_dim)
                     + d * H * qk + H * self.v_head_dim * d)
+        if mixer == "mamba2":
+            di, Hm = self.mamba_inner, self.mamba_heads
+            conv = di + 2 * self.mamba_groups * self.mamba_d_state
+            return (d * (di + conv + Hm) + conv * (self.mamba_conv + 1)
+                    + 3 * Hm + di + di * d)  # dt_bias A_log D, norm, out
         Hk, hd = self.kda_n_heads, self.kda_head_dim
         rank = self.kda_gate_rank or hd
         return (4 * d * Hk * hd + 3 * self.kda_conv * Hk * hd   # q k v o, convs
@@ -270,11 +305,13 @@ class TransformerConfig:
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Forward+backward FLOPs/token: 6 per matmul parameter a token
         touches (no embedding lookup), causal attention 3*S*H*(d_qk + d_v) a
-        softmax layer, and the chunked algorithm's operations a KDA layer
-        (see chipbench/reduce/kda_counts.py)."""
+        softmax layer, and the chunked algorithm's operations a KDA or
+        Mamba-2 layer (see chipbench/reduce/kda_counts.py, ssd_counts.py)."""
         S = seq_len or self.max_seq_len
         d, H = self.d_model, self.n_heads
-        n = self.num_active_params() - self.vocab_size * d  # the lookup
+        n = self.num_active_params()
+        if not self.tie_embeddings:  # the lookup; a tied table is the head
+            n -= self.vocab_size * d
         if self.positional == "learned":
             n -= self.max_seq_len * d
         total = 6.0 * n
@@ -286,10 +323,32 @@ class TransformerConfig:
                 total += 3.0 * S * H * (self.qk_nope_head_dim
                                         + self.qk_rope_head_dim
                                         + self.v_head_dim)
+            elif mixer == "mamba2":
+                Cm, N = self.mamba_chunk, self.mamba_d_state
+                total += 3.0 * (self.mamba_groups * Cm * N  # C B^T, lower
+                                + self.mamba_inner * (Cm + 4 * N))
             else:
                 total += 3.0 * self.kda_n_heads * (
                     6 * hd * hd + C * 5 * hd + C * C / 3)
         return total
+
+
+# The decay a = exp(-exp(A_log) * softplus(. + dt_bias)) of the `mamba2` and
+# `kda` mixers: A in [1, 16], softplus(dt_bias) in [0.001, 0.1] log-uniform
+# (the Mamba family's parametrisation, which the gated delta rules follow).
+
+
+def _a_log_init(shape):
+    return lambda k: jnp.log(jax.random.uniform(k, shape, minval=1.0,
+                                                maxval=16.0))
+
+
+def _dt_bias_init(shape):
+    def init(k):
+        dt = jnp.exp(jax.random.uniform(
+            k, shape, minval=math.log(1e-3), maxval=math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+    return init
 
 
 def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
@@ -328,6 +387,26 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
         sh["mla_kv_norm"] = ((lat,), (None,), "ones")
         sh["mla_wkvb"] = ((lat, H, nope + dv), (None, "heads", None), fan(lat))
         sh["mla_wo"] = ((H, dv, d), ("heads", None, "embed"), out_std(H * dv))
+    elif mixer == "mamba2":
+        # in_proj's three parts are leaves of their own (gate z and x fused
+        # over an array dim of their own, as wkv is): heads stay a dim that
+        # a tensor-parallel rule can cut, B / C and dt are narrow.
+        Hm, P = cfg.mamba_heads, cfg.mamba_head_dim
+        G, N, K = cfg.mamba_groups, cfg.mamba_d_state, cfg.mamba_conv
+        sh["mamba_wzx"] = ((d, 2, Hm, P), ("embed", None, "heads", None),
+                           fan(d))
+        sh["mamba_wbc"] = ((d, 2, G, N), ("embed", None, None, None), fan(d))
+        sh["mamba_wdt"] = ((d, Hm), ("embed", "heads"), fan(d))
+        sh["mamba_conv_x"] = ((K, Hm, P), (None, "heads", None), fan(K))
+        sh["mamba_conv_x_b"] = ((Hm, P), ("heads", None), "zeros")
+        sh["mamba_conv_bc"] = ((K, 2, G, N), (None, None, None, None), fan(K))
+        sh["mamba_conv_bc_b"] = ((2, G, N), (None, None, None), "zeros")
+        sh["mamba_A_log"] = ((Hm,), ("heads",), _a_log_init((Hm,)))
+        sh["mamba_dt_bias"] = ((Hm,), ("heads",), _dt_bias_init((Hm,)))
+        sh["mamba_D"] = ((Hm,), ("heads",), "ones")
+        sh["mamba_norm"] = ((Hm, P), ("heads", None), "ones")
+        sh["mamba_wo"] = ((Hm, P, d), ("heads", None, "embed"),
+                          out_std(Hm * P))
     else:
         Hk, hd = cfg.kda_n_heads, cfg.kda_head_dim
         rank, K = cfg.kda_gate_rank or hd, cfg.kda_conv
@@ -339,18 +418,9 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
             sh[f"kda_w{n}1"] = ((d, rank), ("embed", None), fan(d))
             sh[f"kda_w{n}2"] = ((rank, Hk, hd), (None, "heads", None),
                                 fan(rank))
-        # Decay a = exp(-exp(A_log) * softplus(. + dt_bias)): A in [1, 16],
-        # softplus(dt_bias) in [0.001, 0.1], log-uniform (the Mamba family's
-        # parametrisation, which the gated delta rules follow).
-        sh["kda_A_log"] = ((Hk,), ("heads",), lambda k: jnp.log(
-            jax.random.uniform(k, (Hk,), minval=1.0, maxval=16.0)))
-
-        def dt_bias(k):
-            dt = jnp.exp(jax.random.uniform(
-                k, (Hk, hd), minval=math.log(1e-3), maxval=math.log(1e-1)))
-            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-
-        sh["kda_dt_bias"] = ((Hk, hd), ("heads", None), dt_bias)
+        sh["kda_A_log"] = ((Hk,), ("heads",), _a_log_init((Hk,)))
+        sh["kda_dt_bias"] = ((Hk, hd), ("heads", None),
+                             _dt_bias_init((Hk, hd)))
         sh["kda_wb"] = ((d, Hk), ("embed", "heads"), fan(d))
         sh["kda_o_norm"] = ((hd,), (None,), "ones")
         sh["kda_wo"] = ((Hk, hd, d), ("heads", None, "embed"),
@@ -617,6 +687,35 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "kda_wo", cfg))
 
 
+def _mamba_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+    from ray_tpu.ops.kda import short_conv
+    from ray_tpu.ops.ssd import ssd_chunked
+
+    f32 = jnp.float32
+    B, S, _ = h.shape
+    zx = jnp.einsum("bsd,dcnp->bscnp", h, _w(layer, "mamba_wzx", cfg))
+    bc = jnp.einsum("bsd,dcgn->bscgn", h, _w(layer, "mamba_wbc", cfg))
+    dt = jnp.einsum("bsd,dn->bsn", h, _w(layer, "mamba_wdt", cfg))
+
+    def conv(y, n):
+        return jax.nn.silu(short_conv(y, layer["mamba_conv_" + n])
+                           + layer[f"mamba_conv_{n}_b"].astype(y.dtype))
+
+    z, x, bc = zx[:, :, 0], conv(zx[:, :, 1], "x"), conv(bc, "bc")
+    dt = jax.nn.softplus(dt.astype(f32) + layer["mamba_dt_bias"].astype(f32))
+    with jax.named_scope("ssd.core"):
+        y, _ = ssd_chunked(x, dt, -jnp.exp(layer["mamba_A_log"].astype(f32)),
+                           bc[:, :, 0], bc[:, :, 1], layer["mamba_D"],
+                           chunk=cfg.mamba_chunk)
+    # The gated norm: times silu(z) first, then RMSNorm over a group's
+    # channels (all of them where there is one group), float32.
+    G = cfg.mamba_groups
+    y = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(B, S, G, -1)
+    y = _norm(y, layer["mamba_norm"].reshape(G, -1), None, "rmsnorm",
+              cfg.norm_eps).reshape(z.shape).astype(h.dtype)
+    return jnp.einsum("bsnp,npd->bsd", y, _w(layer, "mamba_wo", cfg))
+
+
 def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     q = jnp.einsum("bsd,dnh->bsnh", h, _w(layer, "mla_wq", cfg))
@@ -629,6 +728,12 @@ def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
     o = attention(q, k, kv[..., nope:], causal=True)
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg))
+
+
+def _scaled(x: jax.Array, scale: float) -> jax.Array:
+    """x * scale in x's dtype; at 1.0 x itself, so that nothing is traced
+    for a configuration without the scalar."""
+    return x if scale == 1.0 else x * jnp.asarray(scale, x.dtype)
 
 
 def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
@@ -648,16 +753,21 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     elif mixer == "mla":
         with jax.named_scope("mla"):
             delta = _mla_mixer(cfg, h, layer)
+    elif mixer == "mamba2":
+        with jax.named_scope("mamba"):
+            delta = _mamba_mixer(cfg, h, layer)
     else:
         q, k, v = _qkv_proj(cfg, h, layer, positions)
         q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
-        o = attention(q, k, v, causal=True)
+        o = attention(q, k, v, causal=True, scale=cfg.attn_scale)
         delta = o.reshape(B, S, -1) @ _w(layer, "wo", cfg)
-    x = maybe_constrain(x + delta, ("batch", "seq_act", "embed"))
+    x = maybe_constrain(x + _scaled(delta, cfg.residual_scale),
+                        ("batch", "seq_act", "embed"))
     h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
               cfg.norm_eps)
     delta, extras = _mlp_block(cfg, ffn, h, layer)
-    x = maybe_constrain(x + delta, ("batch", "seq_act", "embed"))
+    x = maybe_constrain(x + _scaled(delta, cfg.residual_scale),
+                        ("batch", "seq_act", "embed"))
     if return_kv:
         return x, extras, k, v
     return x, extras
@@ -675,7 +785,7 @@ def embed_tokens(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> j
     # below, and the backward scatter-add reduce-scatters back into the
     # sharded param layout.
     tbl = maybe_constrain(params["embed"].astype(cfg.dtype), (None, None))
-    x = tbl[tokens]
+    x = _scaled(tbl[tokens], cfg.embed_scale)
     x = maybe_constrain(x, ("batch", "seq_act", "embed"))
     if cfg.positional == "learned":
         x = x + params["pos_embed"].astype(cfg.dtype)[:S][None]
@@ -767,7 +877,7 @@ def final_hidden_and_head(
 def lm_head(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
     """Final norm + (tied) output projection: hidden [B,S,d] -> logits f32."""
     x, head = final_hidden_and_head(params, x, cfg)
-    return (x @ head).astype(jnp.float32)
+    return _scaled((x @ head).astype(jnp.float32), 1.0 / cfg.logit_scale)
 
 
 def token_cross_entropy(logits: jax.Array, targets: jax.Array,
@@ -854,7 +964,8 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
 
         x, head = final_hidden_and_head(params, x, cfg)
         loss = fused_next_token_loss(
-            x.astype(cfg.dtype), head, targets, valid)
+            _scaled(x.astype(cfg.dtype), 1.0 / cfg.logit_scale), head,
+            targets, valid)
     else:
         loss = token_cross_entropy(lm_head(params, x, cfg), targets, valid)
     if "aux" in extras:  # GShard's balancing loss; what is left are counters

@@ -64,7 +64,8 @@ def test_kda_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     (16, 1024, 12, 12, 64, 64),      # gpt2_124m.train_1chip
     (4, 2048, 8, 4, 128, 128),       # internlm2_1_8b.train_mesh4, a device
     (1, 8192, 32, 32, 192, 128),     # kimi_linear_48b_a3b.train_share_8k, MLA
-], ids=["gpt2", "internlm2_shard", "kimi_mla"])
+    (1, 4096, 32, 8, 64, 64),        # granite_4_0_h_micro.train_stage_4k
+], ids=["gpt2", "internlm2_shard", "kimi_mla", "granite_gqa64"])
 def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
                                        B, S, H, KVH, D, Dv):
     """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape,
