@@ -58,9 +58,10 @@ class TransformerConfig:
     # - "full": recompute the whole layer body in the backward (max memory
     #   saving, ~33% extra FLOPs, flash forward kernel included).
     # - "dots": save matmul outputs and the kernels' own residuals (flash: o
-    #   [B,H,S,hd] and lse [B,H,S]; KDA: o, chunk states, inverses; named in
-    #   their forward rules, RESIDUAL_NAMES of ops/flash_attention.py and
-    #   ops/kda.py); recompute the rest. A forward kernel runs once a layer.
+    #   [B,H,S,hd] and lse [B,H,S]; KDA: o, chunk states, inverses; SSD: y,
+    #   chunk states; named in their forward rules, RESIDUAL_NAMES of
+    #   ops/flash_attention.py, ops/kda.py and ops/ssd.py); recompute the
+    #   rest. A forward kernel runs once a layer.
     # Default "dots": keeping o and lse takes the second forward-kernel call
     # out of every layer's backward (gpt2_124m, batch 16 x 1024, one v5e
     # chip: step 184.2 -> 179.5 ms, step memory 13.96 -> 15.19 GB; chip
@@ -802,9 +803,10 @@ def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
         return body
     if cfg.remat_policy == "full":
         return jax.checkpoint(body)
-    from ray_tpu.ops import flash_attention as fa, kda
+    from ray_tpu.ops import flash_attention as fa, kda, ssd
 
-    names = fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES  # no dot makes them
+    # What no dot makes: the kernels' own residuals.
+    names = fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
     return jax.checkpoint(
         body,
         policy=jax.checkpoint_policies.save_from_both_policies(

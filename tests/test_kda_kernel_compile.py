@@ -1,6 +1,6 @@
-"""The KDA and flash kernels compiled for a v5e at the cells' real shapes, with no chip:
+"""The KDA, SSD and flash kernels compiled for a v5e at the cells' real shapes, with no chip:
 the TPU compiler is installed here and compiles for a described device.
-Interpret mode (tests/test_kda.py) cannot see what Mosaic refuses
+Interpret mode (tests/test_kda.py, tests/test_ssd.py) cannot see what Mosaic refuses
 (unaligned slices, VMEM over the limit, an op with no lowering). Nothing
 runs, so this says nothing about results or times. The topology is described
 inside a fixture, never at import (one process at a time may load libtpu)."""
@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import flash_attention as fa, kda
+from ray_tpu.ops import flash_attention as fa, kda, ssd
 
 
 @pytest.fixture(scope="module")
@@ -31,12 +31,18 @@ def compiled_not_interpreted(monkeypatch):
 
     monkeypatch.setattr(kda, "_interpret", lambda: False)
     monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(ssd, "_interpret", lambda: False)
+    # The SSD calls are jitted on their own: no trace made in the other mode.
+    forget = lambda: [f.clear_cache() for f in (ssd._ssd_fwd_call,
+                                                ssd._ssd_bwd_call)]
+    forget()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+    forget()
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
@@ -58,6 +64,30 @@ def test_kda_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     text = jax.jit(fn).lower(q, q, q, g, beta).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == (
         1 if what == "forward" else 2)
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
+                                     what):
+    """1 x 4096 tokens, 64 heads of 64, state 128, one group, chunks of 256,
+    bfloat16: the shape of `granite_4_0_h_micro.train_stage_4k`. The program
+    holds the two Mosaic calls, no [B,H,S,P] copy of x and nothing
+    [chunk, chunk] a head."""
+    B, S, H, P, N, chunk = 1, 4096, 64, 64, 128, 256
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    x, bc = sd((B, S, H, P), jnp.bfloat16), sd((B, S, 1, N), jnp.bfloat16)
+    dt, a = sd((B, S, H), jnp.float32), sd((H,), jnp.float32)
+
+    def loss(x, dt, A, Bm, Cm, D):
+        y, _ = ssd.ssd_chunked_pallas(x, dt, A, Bm, Cm, D, chunk=chunk)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    fn = loss if what == "forward" else jax.grad(loss, argnums=range(6))
+    text = jax.jit(fn).lower(x, dt, a, bc, bc, a).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if what == "forward" else 2)
+    assert f"[{B},{H},{S},{P}]" not in text
+    assert f",{chunk},{chunk}]" not in text.replace(f"f32[{chunk},{chunk}]", "")
 
 
 @pytest.mark.parametrize("B,S,H,KVH,D,Dv", [
