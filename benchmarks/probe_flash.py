@@ -13,6 +13,11 @@ chip the script fails at once.
 
     python3 benchmarks/probe_flash.py            # check + sweep
     python3 benchmarks/probe_flash.py check      # check only
+    python3 benchmarks/probe_flash.py band       # the windowed calls only:
+        # `BAND` checked against the reference at a length its [H, S, S]
+        # fits, at the table's tiles and at every (block, strip) of
+        # `BAND_SWEEP`, then timed at the cell's length beside the same
+        # shape's full causal call (the sweep beside `_TILES`)
 """
 from __future__ import annotations
 
@@ -37,6 +42,12 @@ from ray_tpu.util.jaxenv import enable_compile_cache, require_tpu
 CELLS = [("gpt2", 16, 1024, 12, 12, 64, 64),
          ("internlm2_shard", 4, 2048, 8, 4, 128, 128),
          ("kimi_mla", 1, 8192, 32, 32, 192, 128)]
+# (name, B, S, H, KVH, D, Dv, window): mellum2_12b_a2_5b.train_share_16k's
+# sliding layers; checked at BAND_CHECK_S positions (three windows).
+BAND = ("mellum2_swa", 1, 16384, 32, 4, 128, 128, 1024)
+BAND_CHECK_S = 4096
+BAND_SWEEP = [(512, 128), (512, 256), (1024, 128), (1024, 256), (1024, 512),
+              (2048, 256), (2048, 512)]
 SWEEP = [(b, s) for b in (512, 1024, 2048, 4096) for s in (128, 256, 512)]
 SWEEP += [(b, b) for b in (512, 1024)]  # no strips: the split of tiles alone
 # Flash and reference see the same bf16 inputs; flash rounds P to bf16 before
@@ -80,13 +91,19 @@ def _loss(fn):
     return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
 
 
-def check(B, S, H, KVH, D, Dv, grad):
-    """The table's tiles against the reference, forward and gradient."""
+def check(B, S, H, KVH, D, Dv, grad, window=None, block=None, sub=None):
+    """The table's tiles (or the named ones) against the reference, forward
+    and gradient."""
     q, k, v = _qkv(B, S, H, KVH, D, Dv)
-    flash = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
-    ref = lambda q, k, v: reference_attention(q, k, v, causal=True)
+    flash = lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, window=window, block_q=block, block_k=block,
+        sub=sub)
+    ref = lambda q, k, v: reference_attention(q, k, v, causal=True,
+                                              window=window)
     row = {"check": [B, S, H, KVH, D, Dv],
-           "tiles": fa.tile_sizes(S, D, Dv, q.dtype)}
+           "tiles": fa.tile_sizes(S, D, Dv, q.dtype, block, block, sub)}
+    if window is not None:
+        row["window"] = window
     t0 = time.perf_counter()
     fwd = jax.jit(flash).lower(q, k, v).compile()
     row["compile_s"] = round(time.perf_counter() - t0, 2)
@@ -109,12 +126,12 @@ def check(B, S, H, KVH, D, Dv, grad):
     return row, ok
 
 
-def kernel_ms(B, S, H, KVH, D, Dv, block, sub):
+def kernel_ms(B, S, H, KVH, D, Dv, block, sub, window=None):
     """Forward, dQ and dK/dV alone (XLA drops the kernel whose results a
     program does not return), in model layout's transposed form."""
     q, k, v = (jnp.swapaxes(x, 1, 2) for x in _qkv(B, S, H, KVH, D, Dv))
     scale = D ** -0.5
-    tiles = dict(block_q=block, block_k=block, sub=sub)
+    tiles = dict(block_q=block, block_k=block, sub=sub, window=window)
     fwd = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, scale, True, **tiles))
     o, lse = fwd(q, k, v)
     do = jnp.ones_like(o)
@@ -145,6 +162,22 @@ def main(argv) -> int:
         failed += not ok
         print(json.dumps(row), flush=True)
 
+    if argv[1:] == ["band"]:
+        name, B, S, H, KVH, D, Dv, window = BAND
+        h = 2 * H // KVH  # two key/value heads with all their query heads
+        for block, sub in [(None, None)] + BAND_SWEEP:
+            report({"cell": name, "block": block, "sub": sub}, check,
+                   B, BAND_CHECK_S, h, 2, D, Dv, True, window, block, sub)
+        for block, sub in BAND_SWEEP:
+            report({"cell": name, "block": block, "sub": sub,
+                    "window": window},
+                   lambda *a: (kernel_ms(*a), True),
+                   B, S, H, KVH, D, Dv, block, sub, window)
+        for block, sub in ((1024, 256), (2048, 256)):  # the full layer
+            report({"cell": name, "block": block, "sub": sub, "window": None},
+                   lambda *a: (kernel_ms(*a), True),
+                   B, S, H, KVH, D, Dv, block, sub)
+        return 1 if failed else 0
     for S in (8, 16, 32, 64, 128, 256, 512, 1024, 2048):
         report({}, check, 1, S, 16, 8, 128, 128, False)
     for name, B, S, H, KVH, D, Dv in CELLS:

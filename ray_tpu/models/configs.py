@@ -185,3 +185,43 @@ def granite_hybrid_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def mellum2_tiny(**overrides) -> TransformerConfig:
+    """A sliding-window / full-attention stack in the Mellum2 pattern at
+    widths small enough for CPU tests (docs/model_layers.md): of every four
+    layers three see a window of 8 keys under plain RoPE and the fourth the
+    whole sequence under YaRN-scaled RoPE; heads of 32 (4 x 32 is not
+    d_model); every feed-forward 8 softmax-routed experts, 2 a token, none
+    shared, experts 4-7 held here; an untied head. Published sizes live in
+    chipbench/configs/ only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=1,
+        attn_head_dim=32,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-6,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=500000.0,
+        yarn_factor=16.0,
+        yarn_original_len=32,
+        yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0,
+        yarn_attn_factor=1.2772588722239782,
+        tie_embeddings=False,
+        swa_layers=tuple(l for l in range(1, 29) if l % 4),
+        sliding_window=8,
+        moe_num_experts=8,
+        moe_experts_per_token=2,
+        moe_router="softmax",
+        moe_held=(4, 4),
+        moe_d_ff=32,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

@@ -53,8 +53,9 @@ def _kv_stack(params: Params, cfg: TransformerConfig):
     (ROADMAP R7)."""
     if any(mixer != "attn" for mixer, _ in cfg.layer_kinds()):
         raise NotImplementedError(
-            "decode holds keys and values only: a stack with KDA / MLA / "
-            "Mamba-2 layers trains but does not serve yet")
+            "decode holds keys and values of one length a layer only: a "
+            "stack with KDA / MLA / Mamba-2 / windowed layers trains but "
+            "does not serve yet")
     if (cfg.embed_scale, cfg.residual_scale, cfg.attn_scale,
             cfg.logit_scale) != (1.0, 1.0, None, 1.0):
         raise NotImplementedError(

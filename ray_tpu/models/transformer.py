@@ -20,6 +20,7 @@ it hosts torch):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -32,6 +33,10 @@ from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.sharding import maybe_constrain
 
 Params = Dict[str, Any]
+
+
+# `moe_router`s whose layer holds a range of the experts and drops nothing.
+_HELD_ROUTERS = ("sigmoid", "softmax")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +55,19 @@ class TransformerConfig:
     # unrotated too (NoPE attention: the stack's recurrent layers carry order).
     positional: str = "rope"
     rope_theta: float = 500000.0
+    # YaRN on the "attn" layers' rotation (None: plain RoPE, and nothing is
+    # traced for it): inverse frequencies blended between theta's and theirs
+    # over `yarn_factor` across the pairs whose turns in
+    # `yarn_original_len` positions lie between `yarn_beta_slow` and
+    # `yarn_beta_fast`, cos and sin times `yarn_attn_factor`
+    # (docs/model_layers.md). Windowed ("swa") layers keep plain RoPE.
+    yarn_factor: Optional[float] = None
+    yarn_original_len: int = 8192
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attn_factor: float = 1.0
+    # Width of an attention head; None => d_model // n_heads.
+    attn_head_dim: Optional[int] = None
     tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -80,7 +98,10 @@ class TransformerConfig:
     #   correction bias, renormalised, times `moe_routed_scale`; NOTHING is
     #   dropped, no aux loss; `moe_shared_experts` always-on experts beside
     #   the routed ones; layers before `moe_first_dense` (0-based count) keep
-    #   the dense MLP of `d_ff`; experts are `moe_d_ff` wide. `moe_held` =
+    #   the dense MLP of `d_ff`; experts are `moe_d_ff` wide.
+    # - "softmax": the same held-range layer routed by softmax over all the
+    #   experts' scores, top-k of the probabilities, renormalised; no bias
+    #   leaf, no scale. `moe_held` =
     #   (first, count) is the contiguous range of experts THIS program holds
     #   (None = all): it routes over all `moe_num_experts`, computes its own
     #   experts' part and leaves the rest out (one expert-parallel rank).
@@ -102,6 +123,8 @@ class TransformerConfig:
     # as published configs list them; numbers past n_layers are ignored, so
     # a cut in depth keeps the published lists. A layer in neither list has
     # the softmax attention above ("attn").
+    # - swa_layers: the same attention (the same leaves) where query i sees
+    #   keys j with 0 <= i - j < `sliding_window` ("swa"), plain RoPE.
     # - kda_layers: Kimi Delta Attention (ops/kda.py), `kda_heads` heads of
     #   `kda_head_dim`, a causal depthwise convolution of `kda_conv`, gate
     #   projections of rank `kda_gate_rank`, chunks of `kda_chunk` tokens.
@@ -115,6 +138,8 @@ class TransformerConfig:
     kda_layers: Tuple[int, ...] = ()
     mla_layers: Tuple[int, ...] = ()
     mamba_layers: Tuple[int, ...] = ()
+    swa_layers: Tuple[int, ...] = ()
+    sliding_window: Optional[int] = None
     kda_heads: Optional[int] = None       # None => n_heads
     kda_head_dim: int = 128
     kda_conv: int = 4
@@ -145,38 +170,59 @@ class TransformerConfig:
     fused_ce: bool = False
 
     def __post_init__(self):
-        for name in ("kda_layers", "mla_layers", "mamba_layers", "moe_held"):
+        for name in ("kda_layers", "mla_layers", "mamba_layers", "swa_layers",
+                     "moe_held"):
             v = getattr(self, name)
             if isinstance(v, list):  # from a JSON file
                 object.__setattr__(self, name, tuple(v))
         if self.remat_policy not in ("dots", "full"):
             raise ValueError(f"unknown remat_policy {self.remat_policy!r}: "
                              "'dots' or 'full'")
-        if self.moe_router not in ("softmax_capacity", "sigmoid"):
+        if self.moe_router not in ("softmax_capacity",) + _HELD_ROUTERS:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
-        lists = self.kda_layers + self.mla_layers + self.mamba_layers
+        lists = (self.kda_layers + self.mla_layers + self.mamba_layers
+                 + self.swa_layers)
         if len(set(lists)) != len(lists):
-            raise ValueError("a layer is listed as two of kda, mla, mamba")
-        if (self.moe_num_experts and self.moe_router != "sigmoid"
+            raise ValueError(
+                "a layer is listed as two of kda, mla, mamba, swa")
+        if self.swa_layers and not (self.sliding_window or 0) >= 1:
+            raise ValueError("swa_layers need a sliding_window of >= 1")
+        if (self.moe_num_experts and not self.moe_holds_range
                 and any(m != "attn" for m, _ in self.layer_kinds())):
             raise ValueError(
-                "kda / mla / mamba layers compose with moe_router='sigmoid' "
-                "only")
+                "kda / mla / mamba / swa layers compose with "
+                "moe_router='sigmoid' or 'softmax' only")
+        if self.moe_router == "softmax" and self.moe_routed_scale != 1.0:
+            raise ValueError("moe_router='softmax' takes no moe_routed_scale")
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
+    @property
+    def moe_holds_range(self) -> bool:
+        """Experts routed with nothing dropped, a range of them held here
+        (ops/moe.py `moe_ffn_held`), not GShard's capacity dispatch."""
+        return self.moe_num_experts > 0 and self.moe_router in _HELD_ROUTERS
+
+    @property
+    def rope_yarn(self) -> Optional[Tuple[float, int, float, float, float]]:
+        if self.yarn_factor is None:
+            return None
+        return (self.yarn_factor, self.yarn_original_len, self.yarn_beta_fast,
+                self.yarn_beta_slow, self.yarn_attn_factor)
+
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
-        """(mixer, feed-forward) of every layer: mixer attn | mla | kda |
-        mamba2, feed-forward dense | moe."""
-        sig = self.moe_num_experts > 0 and self.moe_router == "sigmoid"
+        """(mixer, feed-forward) of every layer: mixer attn | swa | mla |
+        kda | mamba2, feed-forward dense | moe."""
+        held = self.moe_holds_range
         out = []
         for l in range(self.n_layers):
             mixer = ("kda" if l + 1 in self.kda_layers else
                      "mla" if l + 1 in self.mla_layers else
-                     "mamba2" if l + 1 in self.mamba_layers else "attn")
-            if sig:
+                     "mamba2" if l + 1 in self.mamba_layers else
+                     "swa" if l + 1 in self.swa_layers else "attn")
+            if held:
                 ffn = "moe" if l >= self.moe_first_dense else "dense"
             else:
                 ffn = "moe" if self.moe_num_experts else "dense"
@@ -233,7 +279,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
 
     @property
     def ff_dim(self) -> int:
@@ -247,7 +293,7 @@ class TransformerConfig:
 
     def _mixer_params(self, mixer: str) -> int:
         d, H = self.d_model, self.n_heads
-        if mixer == "attn":
+        if mixer in ("attn", "swa"):
             h = self.head_dim
             return d * H * h + 2 * d * self.kv_heads * h + H * h * d
         if mixer == "mla":
@@ -272,14 +318,15 @@ class TransformerConfig:
         if ffn == "dense":
             return (3 if self.activation == "swiglu" else 2) * d * self.ff_dim
         E, F = self.moe_num_experts, self.moe_ff_dim
-        if self.moe_router != "sigmoid":
+        if not self.moe_holds_range:
             n = self.moe_experts_per_token if active else E
             return n * 3 * d * F + d * E
         held = self.moe_held_range[1]
         # Per token and under even routing, k * held / E of the held experts.
         n = self.moe_experts_per_token * held / E if active else held
+        bias = E if self.moe_router == "sigmoid" and not active else 0
         return (n + self.moe_shared_experts) * 3 * d * F + d * E + (
-            0 if active else E)  # the selection bias is no matmul
+            bias)  # the selection bias is no matmul
 
     def num_params(self) -> int:
         d, L, V = self.d_model, self.n_layers, self.vocab_size
@@ -306,8 +353,10 @@ class TransformerConfig:
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Forward+backward FLOPs/token: 6 per matmul parameter a token
         touches (no embedding lookup), causal attention 3*S*H*(d_qk + d_v) a
-        softmax layer, and the chunked algorithm's operations a KDA or
-        Mamba-2 layer (see chipbench/reduce/kda_counts.py, ssd_counts.py)."""
+        softmax layer (a windowed one its band's pairs, S W - W (W - 1) / 2,
+        in place of the triangle's), and the chunked algorithm's operations
+        a KDA or Mamba-2 layer (see chipbench/reduce/kda_counts.py,
+        ssd_counts.py)."""
         S = seq_len or self.max_seq_len
         d, H = self.d_model, self.n_heads
         n = self.num_active_params()
@@ -320,6 +369,9 @@ class TransformerConfig:
         for mixer, _ in self.layer_kinds():
             if mixer == "attn":
                 total += 3.0 * S * H * 2 * self.head_dim
+            elif mixer == "swa":
+                W = min(self.sliding_window, S)
+                total += 6.0 * (2 * W - W * (W - 1) / S) * H * self.head_dim
             elif mixer == "mla":
                 total += 3.0 * S * H * (self.qk_nope_head_dim
                                         + self.qk_rope_head_dim
@@ -365,7 +417,7 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
         "attn_norm": ((d,), (None,), "ones"),
         "mlp_norm": ((d,), (None,), "ones"),
     }
-    if mixer == "attn":
+    if mixer in ("attn", "swa"):
         # Projections are FUSED into single matmuls (one MXU op instead of
         # 2-3: q/k/v together for MHA, k/v together for GQA, gate/up together
         # for swiglu). The fusion factor is its own array dim — NOT folded
@@ -435,16 +487,16 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
             sh["w_up"] = ((d, F), ("embed", "mlp"), fan(d))
     else:
         E, F = cfg.moe_num_experts, cfg.moe_ff_dim
-        sigmoid = cfg.moe_router == "sigmoid"
-        Eh = cfg.moe_held_range[1] if sigmoid else E  # GShard holds them all
+        held = cfg.moe_holds_range
+        Eh = cfg.moe_held_range[1] if held else E  # GShard holds them all
         sh["router"] = ((d, E), ("embed", None), fan(d))
-        if sigmoid:
+        if cfg.moe_router == "sigmoid":
             sh["router_bias"] = ((E,), (None,), "zeros")  # a buffer: no gradient
         # The scales are the matmuls' fan-ins d and F, not the leading dim E.
         sh["moe_w_gate_up"] = ((Eh, d, 2, F), ("expert", "embed", None, "mlp"),
                                fan(d))
         sh["moe_w_down"] = ((Eh, F, d), ("expert", "mlp", "embed"), out_std(F))
-        Fs = cfg.moe_shared_experts * F if sigmoid else 0
+        Fs = cfg.moe_shared_experts * F if held else 0
         if Fs:
             sh["shared_w_gate_up"] = ((d, 2, Fs), ("embed", None, "mlp"),
                                       fan(d))
@@ -458,7 +510,8 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
 # params["layers"] is stored in one of two formats, both part of what callers
 # outside this file build and read (chipbench/weights*.py, generate, quantize,
 # pipeline): a stack of softmax-attention layers with dense or GShard
-# feed-forwards is ONE dict of leaves [L, ...]; every other stack is a list of
+# feed-forwards is ONE dict of leaves [L, ...]; every other stack (another
+# mixer, windowed attention among them, or a held range of experts) is a list of
 # segments (cfg.stack_plan()), a segment a list over its pattern's positions
 # of one layer kind's leaves, each stacked over the segment's repeats. The
 # three functions below are the only code that knows which.
@@ -466,7 +519,7 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
 
 def _one_tree(cfg: TransformerConfig) -> bool:
     return (all(m == "attn" for m, _ in cfg.layer_kinds())
-            and not (cfg.moe_num_experts > 0 and cfg.moe_router == "sigmoid"))
+            and not cfg.moe_holds_range)
 
 
 def stack_segments(params: Params, cfg: TransformerConfig):
@@ -581,14 +634,39 @@ def _norm(x, w, b, kind: str, eps: Optional[float] = None):
     return out.astype(x.dtype)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over the last dim of [B, S, H, D]."""
+def yarn_ramp(half: int, theta: float, original_len: int, beta_fast: float,
+              beta_slow: float) -> np.ndarray:
+    """YaRN's blend r_i of pair i of `half`: 0 where the pair turns more
+    than `beta_fast` times in `original_len` positions (its frequency is
+    kept), 1 where it turns less than `beta_slow` times (its frequency is
+    divided by the factor), linear between; the two bounds truncated to
+    whole pairs."""
+    at = lambda turns: (2 * half * math.log(original_len / (turns * 2 * math.pi))
+                        / (2 * math.log(theta)))
+    low = max(math.floor(at(beta_fast)), 0)
+    high = min(math.ceil(at(beta_slow)), 2 * half - 1)
+    return np.clip((np.arange(half, dtype=np.float32) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+
+
+def _rope(x: jax.Array, positions: jax.Array, theta: float,
+          yarn=None) -> jax.Array:
+    """Rotary embedding over the last dim of [B, S, H, D]. `yarn`
+    (`TransformerConfig.rope_yarn`): blended inverse frequencies, and cos
+    and sin times the attention factor. Frequencies, angles and the factor
+    are float32: at position 16,383 an angle in bfloat16 is radians off."""
     D = x.shape[-1]
     half = D // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is not None:
+        factor, original_len, beta_fast, beta_slow, attn_factor = yarn
+        r = yarn_ramp(half, theta, original_len, beta_fast, beta_slow)
+        freqs = freqs * (1.0 - r) + freqs / factor * r
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,S,half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * attn_factor, sin * attn_factor
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -604,9 +682,11 @@ def _w(layer: Params, name: str, cfg: TransformerConfig) -> jax.Array:
 
 
 def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
-              positions: jax.Array):
+              positions: jax.Array, mixer: str = "attn"):
     """Projection + rope shared by training forward and KV-cache decode
-    (models/generate.py) — ONE home for the layer's q/k/v convention."""
+    (models/generate.py) — ONE home for the layer's q/k/v convention. An
+    "attn" layer's rotation takes cfg's YaRN scaling, a "swa" layer's never
+    does."""
     if "wqkv" in layer:
         qkv = jnp.einsum("bsd,dcnh->bscnh", h, _w(layer, "wqkv", cfg))
         qkv = checkpoint_name(qkv, "qkv_proj")
@@ -617,8 +697,9 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
         kv = checkpoint_name(kv, "qkv_proj")
         k, v = kv[:, :, 0], kv[:, :, 1]
     if cfg.positional == "rope":
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        yarn = cfg.rope_yarn if mixer == "attn" else None
+        q = _rope(q, positions, cfg.rope_theta, yarn)
+        k = _rope(k, positions, cfg.rope_theta, yarn)
     return q, k, v
 
 
@@ -627,8 +708,8 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
     """Post-mixer feed-forward of kind `ffn`, shared with the decode path ->
     (delta, extras): {} for the dense MLP (SwiGLU where the layer has a
     fused gate/up leaf, else GELU), {"aux": balancing loss} for GShard
-    experts, the routing counters of ops/moe.py `moe_ffn_held` for
-    sigmoid-routed ones."""
+    experts, the routing counters of ops/moe.py `moe_ffn_held` for a held
+    range of them (sigmoid- or softmax-routed)."""
     if ffn == "dense":
         if "w_gate_up" in layer:
             gu = jnp.einsum("bsd,dcf->bscf", h, _w(layer, "w_gate_up", cfg))
@@ -638,7 +719,7 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
             act = checkpoint_name(h @ _w(layer, "w_up", cfg), "gate_up")
             act = jax.nn.gelu(act)
         return act @ _w(layer, "w_down", cfg), {}
-    if cfg.moe_router != "sigmoid":
+    if not cfg.moe_holds_range:
         from ray_tpu.ops.moe import moe_ffn
 
         delta, aux = moe_ffn(
@@ -647,14 +728,19 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
             capacity_factor=cfg.moe_capacity_factor,
             dtype=cfg.dtype)
         return delta, {"aux": aux}
-    from ray_tpu.ops.moe import moe_ffn_held
+    from ray_tpu.ops import moe
 
-    delta, counters = moe_ffn_held(
-        h, layer["router"], layer["router_bias"],
-        layer["moe_w_gate_up"], layer["moe_w_down"],
-        held_first=cfg.moe_held_range[0],
-        experts_per_token=cfg.moe_experts_per_token,
-        routed_scale=cfg.moe_routed_scale, dtype=cfg.dtype)
+    if cfg.moe_router == "sigmoid":
+        route = functools.partial(
+            moe.sigmoid_route, bias=layer["router_bias"],
+            experts_per_token=cfg.moe_experts_per_token,
+            routed_scale=cfg.moe_routed_scale)
+    else:
+        route = functools.partial(
+            moe.softmax_route, experts_per_token=cfg.moe_experts_per_token)
+    delta, counters = moe.moe_ffn_held(
+        h, layer["router"], layer["moe_w_gate_up"], layer["moe_w_down"],
+        route=route, held_first=cfg.moe_held_range[0], dtype=cfg.dtype)
     shared = {n[len("shared_"):]: a for n, a in layer.items()
               if n.startswith("shared_")}
     if shared:  # the always-on experts: one dense SwiGLU of their joint width
@@ -740,7 +826,7 @@ def _scaled(x: jax.Array, scale: float) -> jax.Array:
 def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
                 layer: Params, positions: jax.Array, return_kv: bool = False):
     """One layer of `kind` -> (x, extras), extras as `_mlp_block` gives them.
-    `return_kv` (an "attn" mixer only): -> (x, extras, k, v), this layer's
+    `return_kv` (an "attn" / "swa" mixer only): -> (x, extras, k, v), this layer's
     (roped) keys and values, from which the prefill of models/generate.py
     primes its cache."""
     mixer, ffn = kind
@@ -758,9 +844,15 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
         with jax.named_scope("mamba"):
             delta = _mamba_mixer(cfg, h, layer)
     else:
-        q, k, v = _qkv_proj(cfg, h, layer, positions)
+        q, k, v = _qkv_proj(cfg, h, layer, positions, mixer)
         q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
-        o = attention(q, k, v, causal=True, scale=cfg.attn_scale)
+        attend = functools.partial(attention, q, k, v, causal=True,
+                                   scale=cfg.attn_scale)
+        if mixer == "swa":  # the scope tells its kernels from a full layer's
+            with jax.named_scope("swa"):
+                o = attend(window=cfg.sliding_window)
+        else:
+            o = attend()
         delta = o.reshape(B, S, -1) @ _w(layer, "wo", cfg)
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale),
                         ("batch", "seq_act", "embed"))
@@ -831,7 +923,7 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
     """Everything before the lm head: the segments in turn, each a scan over
     its repeats, every layer under the remat policy. -> (hidden [B,S,d],
     the stack's extras from its layers' (`_mlp_block`): {} for dense
-    feed-forwards, {"aux": sum} for GShard, and for sigmoid-routed experts
+    feed-forwards, {"aux": sum} for GShard, and for a held range of experts
     moe_assigned, moe_dropped, moe_past_buffer (sums), moe_load_max (max),
     moe_load_mean (mean) over the expert layers)."""
     B, S = tokens.shape
@@ -937,7 +1029,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
     """Next-token cross-entropy. `with_counters`: return (loss, routing
     counters) for `ShardedTrainStep(has_aux=True)`: device scalars
     moe_assigned, moe_dropped, moe_past_buffer, moe_load_max, moe_load_mean
-    of a stack with sigmoid-routed experts (`_backbone`), {} for any other.
+    of a stack with a held range of experts (`_backbone`), {} for any other.
 
     Two token conventions:
     - in-place (default): batch tokens [B,S]; the forward runs on the FULL

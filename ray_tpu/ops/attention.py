@@ -28,9 +28,11 @@ def reference_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain XLA attention with GQA head-broadcast. Computes in f32 for
-    numerical stability, returns q.dtype, [B, S, H, Dv]."""
+    numerical stability, returns q.dtype, [B, S, H, Dv]. `window` (with
+    `causal`): query i sees keys j with 0 <= i - j < window."""
     B, S, H, D = q.shape
     KVH = k.shape[2]
     assert H % KVH == 0, f"heads {H} not divisible by kv_heads {KVH}"
@@ -45,13 +47,16 @@ def reference_attention(
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, kf)
     if causal:
         mask = jnp.tril(jnp.ones((S, S), dtype=bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(mask, -window)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, vf)
     return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
-def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash):
+def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash,
+                            window=None):
     """Run attention per shard of a multi-device mesh via shard_map: pjit
     keeps global array semantics outside; inside, each device works on its
     batch/head/sequence shard.
@@ -68,7 +73,9 @@ def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash):
     auto (ulysses when divisible, else ring).
 
     Returns None when neither applies: dense attention under pjit
-    partitions itself."""
+    partitions itself. A `window` goes to the flash kernel of each shard;
+    the two sequence-parallel schemes refuse it (their chunks would have to
+    know where the band lies in the whole sequence)."""
     from jax import shard_map
 
     from ray_tpu import flags
@@ -93,7 +100,11 @@ def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash):
         if not use_flash:
             return None
         return run(lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, scale=scale))
+            q, k, v, causal=causal, scale=scale, window=window))
+    if window is not None:
+        raise NotImplementedError(
+            "windowed attention over a sharded sequence: ring_attention and "
+            "ulysses_attention take no window")
     mode = flags.get("RTPU_SP_MODE")
     sp = mesh.shape["seq"]
 
@@ -131,8 +142,10 @@ def attention(
     causal: bool = True,
     scale: Optional[float] = None,
     use_flash: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Dispatching attention entry point used by all models."""
+    """Dispatching attention entry point used by all models. `window`
+    (with `causal`): query i sees keys j with 0 <= i - j < window."""
     from ray_tpu import flags
     from ray_tpu.parallel.sharding import current_sharding_ctx
 
@@ -161,14 +174,17 @@ def attention(
     # same global result (XLA shards it by the operand shardings), just
     # without the comm/compute overlap.
     if ctx is not None and impl != "xla" and ctx[0].size > 1:
-        out = _shard_mapped_attention(q, k, v, *ctx, causal, scale, use_flash)
+        out = _shard_mapped_attention(q, k, v, *ctx, causal, scale, use_flash,
+                                      window)
         if out is not None:
             return out
     if use_flash:
         from .flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    return reference_attention(q, k, v, causal=causal, scale=scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window)
+    return reference_attention(q, k, v, causal=causal, scale=scale,
+                               window=window)
 
 
 _warned_bad_impl = False

@@ -26,6 +26,15 @@ one. Inside a grid step the accumulating side goes in strips of `sub`
 positions, and on a diagonal tile each strip meets only the other side's
 positions on its side of the diagonal: `sub` x `sub` cells on the diagonal
 under a fixed triangle, the rest unmasked, the cells above it never computed.
+
+A window (`window=W`: query i sees keys j with 0 <= i - j < W) is a second
+boundary below the diagonal. Tiles wholly below the band are skipped like
+those above the diagonal, and the grid's inner dimension is no longer every
+block of the other side but the few the band of one block can reach
+(`_band_steps`: two where W <= block), the index maps starting at the band's
+first block: a layer's grid steps, fetches and work follow S x W. A tile the
+lower boundary cuts is an edge tile too and is taken by the same cells, each
+skipped, unmasked or masked from its static place (`_cells`).
 """
 from __future__ import annotations
 
@@ -56,6 +65,15 @@ DEFAULT_BLOCK = 512
 # takes 256 where 128 is 7% faster: every strip is unrolled in the trace,
 # and at 128 a cached start of the GPT-2 cell was 2.8 s longer than the
 # parent's (10% of it, the bound) for 0.25 ms a layer (`PERF.md` section 6).
+# A windowed call (`window` set) takes the same row: what bounds its work is
+# the cells inside a block, not the block. Sweep of `probe_flash.py band` on a
+# v5e at [1,16384,32|4,128], window 1,024 (chip run PR 33; ms a call forward
+# + dQ + dK/dV; the same shape's full causal call 58.88 at 2048/256):
+#   512/128 15.59 | 512/256 15.37 | 1024/128 11.77 | 1024/256 11.95
+#   1024/512 12.62 | 2048/256 10.91 | 2048/512 11.87
+# A block of twice the window wins over one of the window: a block's first
+# step fetches and starts once for twice the rows, and the cells of the
+# second key block it then skips cost nothing.
 _TILES = {
     (64, 64): (1024, 256),
     (128, 128): (2048, 256),
@@ -91,18 +109,20 @@ def tile_sizes(S, D, Dv, dtype, block_q=None, block_k=None, sub=None):
 
 
 def _decomposed(causal, bq, bk, sub, seq_len) -> bool:
-    """Whether a tile on the diagonal is taken cell by cell: its place
+    """Whether a tile a boundary cuts is taken cell by cell: its place
     relative to the diagonal has to be static, which square blocks that
-    divide the sequence give (every edge tile then has iq == ik)."""
+    divide the sequence give (a tile's class then follows from iq - ik)."""
     return bool(causal and bq == bk and seq_len % bq == 0 and sub < bq)
 
 
-def _tile_class(iq, ik, *, bq, bk, seq_len, causal, ragged):
+def _tile_class(iq, ik, *, bq, bk, seq_len, causal, ragged, window=None):
     """(skipped, interior, edge) of grid tile (iq, ik), on Python ints or on
     traced program ids. `ragged` names the side whose padded last block
     needs a mask: "k" in the forward and dQ kernels (padded keys would enter
     every row's softmax), "q" in the dK/dV kernel (padded queries would add
-    to every key's gradient)."""
+    to every key's gradient). With a `window`, a tile wholly below the band
+    (or, on a banded grid, past the last block) is skipped too, and one the
+    band's lower boundary cuts is an edge."""
     row0, col0 = iq * bq, ik * bk
     end = col0 + bk if ragged == "k" else row0 + bq
     inside, outside = end <= seq_len, end > seq_len
@@ -110,8 +130,15 @@ def _tile_class(iq, ik, *, bq, bk, seq_len, causal, ragged):
         return False, inside, outside
     last_row = row0 + (bq - 1)
     last_col = col0 + (bk - 1)
-    return (col0 > last_row, (last_col <= row0) & inside,
-            (col0 <= last_row) & ((last_col > row0) | outside))
+    if window is None:
+        return (col0 > last_row, (last_col <= row0) & inside,
+                (col0 <= last_row) & ((last_col > row0) | outside))
+    live = (col0 <= last_row) & (row0 - last_col < window) & (row0 < seq_len)
+    cut = (last_col > row0) | (last_row - col0 >= window) | outside
+    return ((col0 > last_row) | (row0 - last_col >= window)
+            | (row0 >= seq_len),
+            (last_col <= row0) & (last_row - col0 < window) & inside,
+            live & cut)
 
 
 def _tile_bodies(iq, ik, **tile):
@@ -127,9 +154,88 @@ def _tile_bodies(iq, ik, **tile):
             for c in (1, 2)]
 
 
+def _edge_tiles(iq, ik, when_edge, b, window, seq_len):
+    """[(d, decorator)] of the decomposed edge bodies: the tiles a boundary
+    cuts, each known by d = iq - ik. The diagonal alone without a window
+    (the edge class is that tile); with one, also the one or two distances
+    at which the band's lower boundary crosses a tile (a banded grid's
+    steps past the last q block are at such a distance and are no tile)."""
+    if window is None:
+        return [(0, when_edge)]
+    return [(d, pl.when((iq - ik == d) & (iq * b < seq_len)))
+            for d in range((window + b - 2) // b + 1)
+            if d == 0 or (d + 1) * b > window]
+
+
+def _cells(d, b, sub, window, fixed, fixed_is_query):
+    """Strip `fixed` of one side of edge tile d (blocks of `b`, strips of
+    `sub`) against the other side's strips -> (masked, runs): `masked` the
+    [(strip, off)] a boundary cuts, query - key = off + (row - column)
+    inside the cell, `runs` the [(first strip, strips)] wholly inside the
+    band, neighbours joined. Strips outside the band are in neither."""
+    masked, runs = [], []
+    for o in range(b // sub):
+        qs, ks = (fixed, o) if fixed_is_query else (o, fixed)
+        off = d * b + (qs - ks) * sub
+        lo, hi = off - (sub - 1), off + (sub - 1)
+        if hi < 0 or (window is not None and lo >= window):
+            continue
+        if lo >= 0 and (window is None or hi < window):
+            if runs and sum(runs[-1]) == o:
+                runs[-1] = (runs[-1][0], runs[-1][1] + 1)
+            else:
+                runs.append((o, 1))
+        else:
+            masked.append((o, off))
+    return masked, runs
+
+
+def _cell_masks(cells, sub, window, lower_rows):
+    """{off: mask [sub, sub]} of every masked cell of `cells` (a list of
+    `_cells` results): query >= key and, with a window, query - key <
+    window, queries on the rows (`lower_rows`) or on the columns. Only the
+    comparisons a boundary inside the cell needs are built."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    q, k = (r, c) if lower_rows else (c, r)
+    out = {}
+    for off in sorted({off for masked, _ in cells for _, off in masked}):
+        m = None
+        if off - (sub - 1) < 0:
+            m = q >= k if off == 0 else q + off >= k
+        if window is not None and off + (sub - 1) >= window:
+            w = q + (off - window) < k
+            m = w if m is None else m & w
+        out[off] = m
+    return out
+
+
+def _band_k(i, bq, bk, window, nk, lo=jnp.maximum, hi=jnp.minimum):
+    """(first, last) key block the band of q block i reaches; on traced
+    ids, or on ints with `lo=max, hi=min`."""
+    return (lo(i * bq - (window - 1), 0) // bk,
+            hi(((i + 1) * bq - 1) // bk, nk - 1))
+
+
+def _band_q(j, bq, bk, window, nq, hi=jnp.minimum):
+    """(first, last) q block that sees key block j."""
+    return (j * bk) // bq, hi(((j + 1) * bk + window - 2) // bq, nq - 1)
+
+
+def _band_steps(seq_len, bq, bk, window):
+    """(key blocks a q block's band reaches at most, q blocks a key block
+    is seen by at most): the inner grid dimensions of a windowed call."""
+    nq, nk = pl.cdiv(seq_len, bq), pl.cdiv(seq_len, bk)
+    span = lambda first, last: last - first + 1
+    return (max(span(*_band_k(i, bq, bk, window, nk, max, min))
+                for i in range(nq)),
+            max(span(*_band_q(j, bq, bk, window, nq, min))
+                for j in range(nk)))
+
+
 class TilePlan(NamedTuple):
     """What the forward kernel does for one (batch, head), counted in cells
-    of `sub` x `sub` where diagonal tiles are decomposed and in grid tiles
+    of `sub` x `sub` where edge tiles are decomposed and in grid tiles
     where they are not. Interior cells run unmasked, edge cells build a
     mask, skipped cells are neither computed nor fetched."""
     bq: int
@@ -140,22 +246,26 @@ class TilePlan(NamedTuple):
     tiles_skipped: int
 
 
-def tile_plan(seq_len, bq, bk, sub, causal) -> TilePlan:
+def tile_plan(seq_len, bq, bk, sub, causal, window=None) -> TilePlan:
     dec = _decomposed(causal, bq, bk, sub, seq_len)
     n = bq // sub if dec else 1
     interior = edge = skipped = 0
     for iq in range(pl.cdiv(seq_len, bq)):
         for ik in range(pl.cdiv(seq_len, bk)):
             s, i, _ = _tile_class(iq, ik, bq=bq, bk=bk, seq_len=seq_len,
-                                  causal=causal, ragged="k")
+                                  causal=causal, ragged="k", window=window)
             if i:
                 interior += n * n
             elif s:
                 skipped += n * n
-            else:  # a diagonal tile by cells, or a whole masked tile
-                interior += n * (n - 1) // 2
-                edge += n
-                skipped += n * (n - 1) // 2
+            elif not dec:  # a whole masked tile
+                edge += 1
+            else:  # a tile a boundary cuts, by cells
+                for a in range(n):
+                    masked, runs = _cells(iq - ik, bq, sub, window, a, True)
+                    full = sum(c for _, c in runs)
+                    interior, edge = interior + full, edge + len(masked)
+                    skipped += n - full - len(masked)
     return TilePlan(bq, bk, sub if dec else max(bq, bk), interior, edge,
                     skipped)
 
@@ -205,22 +315,18 @@ def _scaled(x, scale):
     return (x.astype(jnp.float32) * scale).astype(x.dtype)
 
 
-def _triangle(sub, lower_rows):
-    """The mask of a cell on the diagonal: [sub, sub], query >= key, with
-    queries on the rows (`lower_rows`) or on the columns."""
-    r = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
-    return r >= c if lower_rows else c >= r
-
-
-def _edge_mask(iq, ik, bq, bk, seq_len, causal, queries_on_rows):
+def _edge_mask(iq, ik, bq, bk, seq_len, causal, queries_on_rows,
+               window=None):
     """The mask of a whole edge tile from its place in the sequence:
     [bq, bk] (forward, dQ: keys inside the sequence) or [bk, bq] (dK/dV:
-    queries inside it), and under the diagonal if causal."""
+    queries inside it), under the diagonal if causal, inside the band if
+    windowed."""
     shape, q_dim = ((bq, bk), 0) if queries_on_rows else ((bk, bq), 1)
     rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
     cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     valid = (cols if queries_on_rows else rows) < seq_len
+    if window is not None:
+        valid = valid & (rows - cols < window)
     return valid & (rows >= cols) if causal else valid
 
 
@@ -235,14 +341,33 @@ def _zero_padded(block0, n, seq_len, *arrays):
 # ---------------------------------------------------------------- forward
 
 
+def _grid_place(bq, bk, seq_len, window, keys_inner):
+    """(iq, ik, inner step, inner steps) of this grid step: the inner
+    dimension runs over every block of the other side, or with a window
+    over those the band reaches, from its first one."""
+    outer, inner = pl.program_id(2), pl.program_id(3)
+    nq, nk = pl.cdiv(seq_len, bq), pl.cdiv(seq_len, bk)
+    if window is None:
+        n = nk if keys_inner else nq
+        pair = (outer, inner) if keys_inner else (inner, outer)
+    elif keys_inner:
+        n = _band_steps(seq_len, bq, bk, window)[0]
+        pair = (outer, _band_k(outer, bq, bk, window, nk)[0] + inner)
+    else:
+        n = _band_steps(seq_len, bq, bk, window)[1]
+        pair = (_band_q(outer, bq, bk, window, nq)[0] + inner, outer)
+    return pair + (inner, n)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, bq, bk, seq_len, sub):
-    iq, ik = pl.program_id(2), pl.program_id(3)
+                *, scale, causal, bq, bk, seq_len, sub, window):
+    iq, ik, step_i, steps = _grid_place(bq, bk, seq_len, window, True)
     fold = _folds(scale)
     when_interior, when_edge = _tile_bodies(
-        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k")
+        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k",
+        window=window)
 
-    @pl.when(ik == 0)
+    @pl.when(step_i == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -280,31 +405,43 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         for rows in _strips(bq, sub):
             step(rows, [score(queries(rows), k)], [v])
 
+    def cut(d):
+        """An edge tile at distance d, by cells: each strip of rows meets
+        the masked cells, then the runs of keys wholly in the band."""
+        cells = [_cells(d, bq, sub, window, a, True)
+                 for a in range(bq // sub)]
+        masks = _cell_masks(cells, sub, window, lower_rows=True)
+        for rows, (masked, runs) in zip(_strips(bq, sub), cells):
+            if not masked and not runs:
+                continue
+            q = queries(rows)
+            scores, values = [], []
+            for b, off in masked:
+                keys = pl.ds(b * sub, sub)
+                scores.append(jnp.where(masks[off], score(q, k_ref[keys]),
+                                        _NEG_INF))
+                values.append(v_ref[keys])
+            for b, n in runs:
+                keys = pl.ds(b * sub, n * sub)
+                scores.append(score(q, k_ref[keys]))
+                values.append(v_ref[keys])
+            step(rows, scores, values)
+
     if _decomposed(causal, bq, bk, sub, seq_len):
-        @when_edge
-        def _diagonal():
-            tri = _triangle(sub, lower_rows=True)
-            for a, rows in enumerate(_strips(bq, sub)):
-                q = queries(rows)
-                scores = [jnp.where(tri, score(q, k_ref[rows]),
-                                    _NEG_INF)]
-                values = [v_ref[rows]]
-                if a:
-                    past = pl.ds(0, a * sub)
-                    scores.append(score(q, k_ref[past]))
-                    values.append(v_ref[past])
-                step(rows, scores, values)
+        for d, when in _edge_tiles(iq, ik, when_edge, bq, window,
+                                   seq_len):
+            when(functools.partial(cut, d))
     else:
         @when_edge
         def _edge():
             k, v = k_ref[...], v_ref[...]
             if seq_len % bk:
                 k, v = _zero_padded(ik * bk, bk, seq_len, k, v)
-            valid = _edge_mask(iq, ik, bq, bk, seq_len, causal, True)
+            valid = _edge_mask(iq, ik, bq, bk, seq_len, causal, True, window)
             s = jnp.where(valid, score(queries(slice(None)), k), _NEG_INF)
             step(slice(None), [s], [v])
 
-    @pl.when(ik == pl.cdiv(seq_len, bk) - 1)
+    @pl.when(step_i == steps - 1)
     def _flush():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -318,24 +455,38 @@ def _stat_spec(bq, index_map):
     return pl.BlockSpec((None, None, 1, bq), index_map)
 
 
-def _kv_block(causal, bq, bk):
+def _kv_block(causal, bq, bk, window=None, nk=None):
     """The K/V block of grid step (i, j) of the forward and dQ kernels. A
     skipped step (j beyond the diagonal of q block i) keeps the last needed
-    block: the pipeline copies nothing when the index does not move."""
+    block: the pipeline copies nothing when the index does not move. With a
+    window, step j of q block i is the j-th block of its band."""
     if not causal:
         return lambda i, j: j
-    return lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+    if window is None:
+        return lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+    def block(i, j):
+        first, last = _band_k(i, bq, bk, window, nk)
+        return jnp.minimum(first + j, last)
+    return block
 
 
-def _q_block(causal, bq, bk):
+def _q_block(causal, bq, bk, window=None, nq=None):
     """The same for the Q-side blocks of the dK/dV kernel at (j, i): skipped
-    steps come first there and wait on the first needed block."""
+    steps come first there and wait on the first needed block. With a
+    window, step i of key block j is the i-th q block that sees it, and the
+    skipped steps come last."""
     if not causal:
         return lambda j, i: i
-    return lambda j, i: jnp.maximum(i, (j * bk) // bq)
+    if window is None:
+        return lambda j, i: jnp.maximum(i, (j * bk) // bq)
+    def block(j, i):
+        first, last = _band_q(j, bq, bk, window, nq)
+        return jnp.minimum(first + i, last)
+    return block
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None):
+def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None,
+               window=None):
     """q: [B,H,S,D], k: [B,KVH,S,D], v: [B,KVH,S,Dv] -> (o [B,H,S,Dv],
     lse [B,H,S] f32). Dv may differ from D (latent attention: keys 192
     wide, values 128). Each traced call publishes its `TilePlan` as one
@@ -348,15 +499,16 @@ def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None):
     bq, bk, sub = tile_sizes(S, D, Dv, q.dtype, block_q, block_k, sub)
     nq = pl.cdiv(S, bq)
     nk = pl.cdiv(S, bk)
-    tracing.observe("flash.plan", 0, slow=False,
-                    **tile_plan(S, bq, bk, sub, causal)._asdict())
-    kv = _kv_block(causal, bq, bk)
+    tracing.observe("flash.plan", 0, slow=False, window=window or 0,
+                    **tile_plan(S, bq, bk, sub, causal, window)._asdict())
+    kv = _kv_block(causal, bq, bk, window, nk)
     params = _compiler_params(bq, bk, sub, D, Dv, q.dtype.itemsize)
+    steps = nk if window is None else _band_steps(S, bq, bk, window)[0]
 
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, seq_len=S, sub=sub),
-        grid=(B, H, nq, nk),
+                          bk=bk, seq_len=S, sub=sub, window=window),
+        grid=(B, H, nq, steps),
         in_specs=[
             pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((None, None, bk, D),
@@ -388,13 +540,14 @@ def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, bq, bk, seq_len, sub):
-    iq, ik = pl.program_id(2), pl.program_id(3)
+               dq_scr, *, scale, causal, bq, bk, seq_len, sub, window):
+    iq, ik, step_i, steps = _grid_place(bq, bk, seq_len, window, True)
     fold = _folds(scale)
     when_interior, when_edge = _tile_bodies(
-        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k")
+        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k",
+        window=window)
 
-    @pl.when(ik == 0)
+    @pl.when(step_i == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -426,17 +579,20 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         for rows in _strips(bq, sub):
             add(rows, [(k, v, None)])
 
+    def cut(d):
+        cells = [_cells(d, bq, sub, window, a, True)
+                 for a in range(bq // sub)]
+        masks = _cell_masks(cells, sub, window, lower_rows=True)
+        for rows, (masked, runs) in zip(_strips(bq, sub), cells):
+            keys = [(pl.ds(b * sub, sub), masks[off]) for b, off in masked]
+            keys += [(pl.ds(b * sub, n * sub), None) for b, n in runs]
+            if keys:
+                add(rows, [(k_ref[ks], v_ref[ks], m) for ks, m in keys])
+
     if _decomposed(causal, bq, bk, sub, seq_len):
-        @when_edge
-        def _diagonal():
-            tri = _triangle(sub, lower_rows=True)
-            for a, rows in enumerate(_strips(bq, sub)):
-                pieces = [(k_ref[rows], v_ref[rows], tri)]
-                if a:
-                    past = pl.ds(0, a * sub)
-                    pieces.append((k_ref[past], v_ref[past],
-                                   None))
-                add(rows, pieces)
+        for d, when in _edge_tiles(iq, ik, when_edge, bq, window,
+                                   seq_len):
+            when(functools.partial(cut, d))
     else:
         @when_edge
         def _edge():
@@ -444,9 +600,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             if seq_len % bk:
                 k, v = _zero_padded(ik * bk, bk, seq_len, k, v)
             add(slice(None), [(k, v, _edge_mask(iq, ik, bq, bk, seq_len,
-                                                causal, True))])
+                                                causal, True, window))])
 
-    @pl.when(ik == pl.cdiv(seq_len, bk) - 1)
+    @pl.when(step_i == steps - 1)
     def _flush():
         dq = dq_scr[:]
         dq_ref[...] = (dq * scale if fold else dq).astype(dq_ref.dtype)
@@ -454,13 +610,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, bq, bk, seq_len, sub):
-    ik, iq = pl.program_id(2), pl.program_id(3)
+                *, scale, causal, bq, bk, seq_len, sub, window):
+    iq, ik, step_i, steps = _grid_place(bq, bk, seq_len, window, False)
     fold = _folds(scale)
     when_interior, when_edge = _tile_bodies(
-        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="q")
+        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="q",
+        window=window)
 
-    @pl.when(iq == 0)
+    @pl.when(step_i == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -500,17 +657,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for keys in _strips(bk, sub):
             add(keys, [piece])
 
+    def cut(d):
+        cells = [_cells(d, bk, sub, window, b, False)
+                 for b in range(bk // sub)]
+        masks = _cell_masks(cells, sub, window, lower_rows=False)
+        for keys, (masked, runs) in zip(_strips(bk, sub), cells):
+            qs = [(pl.ds(a * sub, sub), masks[off]) for a, off in masked]
+            qs += [(pl.ds(a * sub, n * sub), None) for a, n in runs]
+            if qs:
+                add(keys, [queries(rows, m) for rows, m in qs])
+
     if _decomposed(causal, bq, bk, sub, seq_len):
-        @when_edge
-        def _diagonal():
-            tri = _triangle(sub, lower_rows=False)
-            n = bk // sub
-            for b, keys in enumerate(_strips(bk, sub)):
-                pieces = [queries(keys, tri)]
-                if b < n - 1:
-                    pieces.append(queries(pl.ds((b + 1) * sub,
-                                                (n - 1 - b) * sub)))
-                add(keys, pieces)
+        for d, when in _edge_tiles(iq, ik, when_edge, bk, window,
+                                   seq_len):
+            when(functools.partial(cut, d))
     else:
         @when_edge
         def _edge():
@@ -519,25 +679,25 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 # The statistics' padding is zeros (`flash_bwd_core`).
                 q, do = _zero_padded(iq * bq, bq, seq_len, q, do)
             add(slice(None), [(q, do, lse, delta, _edge_mask(
-                iq, ik, bq, bk, seq_len, causal, False))])
+                iq, ik, bq, bk, seq_len, causal, False, window))])
 
-    @pl.when(iq == pl.cdiv(seq_len, bq) - 1)
+    @pl.when(step_i == steps - 1)
     def _flush():
         dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k, sub):
+def _flash_bwd(res, g, scale, causal, block_q, block_k, sub, window):
     q, k, v, o, lse = res
     do = g.astype(q.dtype)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     return flash_bwd_core(q, k, v, do, lse, delta, scale=scale,
                           causal=causal, block_q=block_q, block_k=block_k,
-                          sub=sub)
+                          sub=sub, window=window)
 
 
 def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
-                   block_q=None, block_k=None, sub=None):
+                   block_q=None, block_k=None, sub=None, window=None):
     """Backward kernels given externally supplied row stats.
 
     lse/delta are [B,H,S] and may come from a *global* softmax (ring
@@ -551,18 +711,21 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     bq, bk, sub = tile_sizes(S, D, Dv, q.dtype, block_q, block_k, sub)
     nq = pl.cdiv(S, bq)
     nk = pl.cdiv(S, bk)
-    tiles = dict(scale=scale, causal=causal, bq=bq, bk=bk, seq_len=S, sub=sub)
+    tiles = dict(scale=scale, causal=causal, bq=bq, bk=bk, seq_len=S, sub=sub,
+                 window=window)
+    k_steps, q_steps = (nk, nq) if window is None else _band_steps(
+        S, bq, bk, window)
     # Whole (1, bq) blocks with zeros behind the sequence: nothing ragged
     # along the lanes, and a padded query's statistics are numbers.
     pad = [(0, 0), (0, 0), (0, 0), (0, nq * bq - S)]
     lse = jnp.pad(lse[:, :, None, :], pad)
     delta = jnp.pad(delta[:, :, None, :], pad)
-    kv = _kv_block(causal, bq, bk)
+    kv = _kv_block(causal, bq, bk, window, nk)
     params = _compiler_params(bq, bk, sub, D, Dv, q.dtype.itemsize)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **tiles),
-        grid=(B, H, nq, nk),
+        grid=(B, H, nq, k_steps),
         in_specs=[
             pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((None, None, bk, D), lambda b, h, i, j, g_=group:
@@ -581,10 +744,10 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     )(q, k, v, do, lse, delta)
 
     # dk/dv per *query* head, then segment-sum over the GQA group in XLA.
-    qb = _q_block(causal, bq, bk)
+    qb = _q_block(causal, bq, bk, window, nq)
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_dkv_kernel, **tiles),
-        grid=(B, H, nk, nq),
+        grid=(B, H, nk, q_steps),
         in_specs=[
             pl.BlockSpec((None, None, bq, D),
                          lambda b, h, j, i: (b, h, qb(j, i), 0)),
@@ -624,9 +787,9 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
 # ---------------------------------------------------------------- public
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, sub):
-    o, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, sub)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, block_q, block_k, sub, window):
+    o, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, sub, window)
     return o
 
 
@@ -637,15 +800,16 @@ def _flash(q, k, v, scale, causal, block_q, block_k, sub):
 RESIDUAL_NAMES = ("flash_o", "flash_lse")
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, sub):
-    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, sub)
+def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, sub, window):
+    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, sub,
+                        window)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])  # [B,H,S], S on the lanes
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, sub, res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k, sub)
+def _flash_vjp_bwd(scale, causal, block_q, block_k, sub, window, res, g):
+    return _flash_bwd(res, g, scale, causal, block_q, block_k, sub, window)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -661,15 +825,23 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     sub: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention in model layout [B, S, H, D] -> [B, S, H, Dv];
-    differentiable. The values' width Dv may differ from D. Blocks and strip
-    left unnamed come from `tile_sizes` (what the sweep measures by naming
-    them, `benchmarks/probe_flash.py`)."""
+    differentiable. The values' width Dv may differ from D. `window` (with
+    `causal`): query i sees keys j with 0 <= i - j < window; one that
+    reaches the whole sequence is no window. Blocks and strip left unnamed
+    come from `tile_sizes` (what the sweep measures by naming them,
+    `benchmarks/probe_flash.py`)."""
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is a causal band of at least one key")
+        if window >= q.shape[1]:
+            window = None
     D = q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     qt = jnp.swapaxes(q, 1, 2)  # [B, H, S, D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    ot = _flash(qt, kt, vt, scale, causal, block_q, block_k, sub)
+    ot = _flash(qt, kt, vt, scale, causal, block_q, block_k, sub, window)
     return jnp.swapaxes(ot, 1, 2)

@@ -4,15 +4,17 @@
   top-k renormalised, a one-hot [T, E, C] dispatch with a capacity a group;
   tokens over capacity are DROPPED; every expert is held; a load-balancing
   aux loss. `TransformerConfig.moe_router = "softmax_capacity"`.
-- `moe_ffn_held` (the DeepSeek-V3 / Kimi family): sigmoid scores, top-k of
-  score + correction bias (selection only), weights renormalised and scaled,
-  NO token dropped, no aux loss. The layer is told which contiguous range of
+- `moe_ffn_held`: NO token dropped, no aux loss, the routing an argument:
+  `sigmoid_route` (the DeepSeek-V3 / Kimi family: sigmoid scores, top-k of
+  score + correction bias (selection only), weights renormalised and scaled)
+  or `softmax_route` (softmax over every expert's score, top-k of the
+  probabilities, renormalised). The layer is told which contiguous range of
   the experts it holds (`held`): it routes over all of them, sorts the
   assignments by expert with its own first, runs those as grouped matrix
   products (`lax.ragged_dot`, no one-hot) a window of rows at a time, and
   returns its own experts' part. With every expert held and the expert dim
   sharded over an `expert` mesh axis this is expert parallelism.
-  `TransformerConfig.moe_router = "sigmoid"`.
+  `TransformerConfig.moe_router = "sigmoid"` or `"softmax"`.
 
 GShard-style capacity-based top-k dispatch:
 
@@ -31,7 +33,7 @@ capacity-factor semantics.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -152,6 +154,19 @@ def sigmoid_route(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
     return idx.astype(jnp.int32), w
 
 
+def softmax_route(x: jax.Array, router_w: jax.Array, *,
+                  experts_per_token: int):
+    """Scores over ALL experts for tokens x [T, d]: p = softmax(x W_r); the
+    top k of p are selected; weights p_sel / sum(p_sel). float32 at full
+    precision, as `sigmoid_route`.
+    -> (expert ids [T, k] int32, weights [T, k] f32)."""
+    p = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(p, experts_per_token)
+    return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
 # A window of gathered assignments is this many times the held experts'
 # even share of them (tokens x k x held / experts). An even routing fits one
 # window with room for a few times the mean load on one expert; a skewed one
@@ -179,19 +194,18 @@ def _trips(held, rows: int, windows: int):
 def moe_ffn_held(
     x: jax.Array,          # [B, S, d] (cfg.dtype)
     router_w: jax.Array,   # [d, E]      E = every expert of the layer
-    bias: jax.Array,       # [E]         selection bias, no gradient
     w_gate_up: jax.Array,  # [Eh, d, 2, F]  the experts held here
     w_down: jax.Array,     # [Eh, F, d]
     *,
+    route: Callable,       # (x [T, d], router_w) -> (ids [T, k], weights)
     held_first: int = 0,
-    experts_per_token: int = 8,
-    routed_scale: float = 1.0,
     dtype=jnp.bfloat16,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """-> (the held experts' part of the layer's output [B, S, d], counters).
 
     Experts `held_first .. held_first + Eh` are the ones whose weights are
-    given. What the other experts would add is left out.
+    given. What the other experts would add is left out. `route` is
+    `sigmoid_route` or `softmax_route` with its keywords bound.
 
     The assignments, sorted by expert with the held ones first, are worked
     through in windows of `held_window_rows` rows by one loop of as many
@@ -205,14 +219,14 @@ def moe_ffn_held(
     assignments), `past_buffer` (assignments beyond the first window),
     `dropped` (assigned less the rows the loop's trips counted as worked)."""
     B, S, d = x.shape
-    T, k = B * S, experts_per_token
+    T = B * S
     E, Eh, F = router_w.shape[-1], w_gate_up.shape[0], w_down.shape[1]
     xf = x.reshape(T, d)
-    W = held_window_rows(T, k, E, Eh)
-    windows = -(-T * k // W)
     with jax.named_scope("moe.route"):
-        idx, wts = sigmoid_route(xf, router_w, bias, experts_per_token=k,
-                                 routed_scale=routed_scale)
+        idx, wts = route(xf, router_w)
+        k = idx.shape[-1]
+        W = held_window_rows(T, k, E, Eh)
+        windows = -(-T * k // W)
         local = idx.reshape(T * k) - held_first
         local = jnp.where((local >= 0) & (local < Eh), local, Eh)
         counts = jnp.sum(local[:, None] == jnp.arange(Eh)[None, :], axis=0,

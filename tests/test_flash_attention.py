@@ -308,3 +308,106 @@ def test_flash_plan_in_the_phase_table():
     assert row["count"] == before + 1
     plan = tile_plan(256, 128, 128, 64, True)
     assert plan == (128, 128, 64, 6, 4, 6)
+
+
+# ------------------------------------------------------------ the window
+
+# (S, window, tiles): blocks of 128 with strips of 32 unless named.
+_BAND = dict(block_q=128, block_k=128, sub=32)
+_WINDOWS = [
+    (512, 16, _BAND),     # smaller than a strip
+    (512, 100, _BAND),    # between a strip and a block
+    (512, 128, _BAND),    # a block: two key blocks a query block
+    (512, 129, _BAND),    # one key past a block: a third block's corner
+    (512, 300, _BAND),    # several blocks: interior tiles inside the band
+    (384, 1, _BAND),      # every query sees itself alone
+    (512, 4096, _BAND),   # larger than the sequence: plain causal
+    (500, 100, _BAND),    # a ragged length: whole masked tiles
+    (500, 130, dict(block_q=128, block_k=64, sub=32)),  # and unequal blocks
+    (512, 100, dict(block_q=128, block_k=128, sub=128)),  # no strips
+]
+
+
+@pytest.mark.parametrize("S,window,tiles", _WINDOWS,
+                         ids=[f"S{s}-W{w}-{t['block_q']}_{t['block_k']}_"
+                              f"{t['sub']}" for s, w, t in _WINDOWS])
+def test_windowed_forward_and_gradients_match_reference(S, window, tiles):
+    """Query i sees keys j with 0 <= i - j < window, in all three kernels:
+    output and the gradients of q, k and v against
+    `reference_attention(window=)`, float32, 2:1 grouped heads. A band off
+    by one key is an error of order one."""
+    q, k, v = _rand_qkv(jax.random.key(S + window), 1, S, 2, 1, 32)
+    w = jax.random.normal(jax.random.key(3), q.shape)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    flash = lambda q, k, v: flash_attention(q, k, v, window=window, **tiles)
+    ref = lambda q, k, v: reference_attention(q, k, v, window=window)
+    got = [flash(q, k, v), *jax.grad(loss(flash), (0, 1, 2))(q, k, v)]
+    want = [ref(q, k, v), *jax.grad(loss(ref), (0, 1, 2))(q, k, v)]
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        err = float(jnp.max(jnp.abs(a - b)))  # dq, dk are 0 at one key
+        assert err <= 1e-4 * max(float(jnp.max(jnp.abs(b))), 1.0), (name, err)
+    # The reference's own band: one key fewer or more is another answer.
+    off = reference_attention(q, k, v, window=window + 1)
+    if window < S:
+        assert float(jnp.max(jnp.abs(off - want[0]))) > 1e-3
+
+
+@pytest.mark.parametrize("S,bq,bk,sub,window", [
+    (2048, 512, 512, 128, 512), (2048, 512, 512, 128, 100),
+    (2048, 512, 512, 128, 513), (2048, 512, 512, 128, 1300),
+    (2048, 512, 512, 512, 512), (2048, 256, 512, 256, 300),
+    (1280, 512, 512, 128, 200), (16384, 2048, 2048, 256, 1024)])
+def test_tile_plan_with_a_window_against_a_brute_count(S, bq, bk, sub,
+                                                       window):
+    """Every cell of the plan classified from its (i, j) pairs alone:
+    interior where all of them are in the band and the sequence, skipped
+    where none is in the band, edge otherwise; and what the banded grid
+    visits (`_band_steps`) covers every cell that is not skipped."""
+    plan = tile_plan(S, bq, bk, sub, True, window)
+    cq, ck = (plan.sub, plan.sub) if plan.sub < bq else (bq, bk)
+    counts = dict(interior=0, edge=0, skipped=0)
+    for r0 in range(0, -(-S // bq) * bq, cq):
+        for c0 in range(0, -(-S // bk) * bk, ck):
+            i = np.arange(r0, r0 + cq)[:, None]
+            j = np.arange(c0, c0 + ck)[None, :]
+            band = (i >= j) & (i - j < window)
+            if not band.any():
+                counts["skipped"] += 1
+            elif band.all() and c0 + ck <= S:
+                counts["interior"] += 1
+            else:
+                counts["edge"] += 1
+    assert (plan.tiles_interior, plan.tiles_edge, plan.tiles_skipped) == (
+        counts["interior"], counts["edge"], counts["skipped"])
+    k_steps, q_steps = fa._band_steps(S, bq, bk, window)
+    for iq in range(-(-S // bq)):
+        first = max(iq * bq - (window - 1), 0) // bk
+        for ik in range(-(-S // bk)):
+            skipped = fa._tile_class(iq, ik, bq=bq, bk=bk, seq_len=S,
+                                     causal=True, ragged="k",
+                                     window=window)[0]
+            assert skipped or first <= ik < first + k_steps, (iq, ik)
+            assert skipped or (ik * bk) // bq <= iq < (
+                ik * bk) // bq + q_steps, (iq, ik)
+    if S == 16384:  # the cell's windowed layer: most of the grid is skipped
+        assert plan.tiles_skipped > 10 * (plan.tiles_interior
+                                          + plan.tiles_edge)
+        assert (k_steps, q_steps) == (2, 2) and (bq, bk, sub) == tile_sizes(
+            S, 128, 128, jnp.bfloat16)
+
+
+def test_window_that_is_no_window():
+    """A window that reaches the whole sequence traces the causal program;
+    one without `causal`, or of no key, is refused."""
+    q, k, v = _rand_qkv(jax.random.key(1), 1, 256, 2, 2, 32)
+    tiles = dict(block_q=128, block_k=128, sub=64)
+    text = lambda **kw: jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, **tiles, **kw)).lower(q, k, v).as_text()
+    assert text(window=256) == text() != text(window=255)
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, causal=False, window=8)
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, window=0)

@@ -90,22 +90,26 @@ def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     assert f",{chunk},{chunk}]" not in text.replace(f"f32[{chunk},{chunk}]", "")
 
 
-@pytest.mark.parametrize("B,S,H,KVH,D,Dv", [
-    (16, 1024, 12, 12, 64, 64),      # gpt2_124m.train_1chip
-    (4, 2048, 8, 4, 128, 128),       # internlm2_1_8b.train_mesh4, a device
-    (1, 8192, 32, 32, 192, 128),     # kimi_linear_48b_a3b.train_share_8k, MLA
-    (1, 4096, 32, 8, 64, 64),        # granite_4_0_h_micro.train_stage_4k
-], ids=["gpt2", "internlm2_shard", "kimi_mla", "granite_gqa64"])
+@pytest.mark.parametrize("B,S,H,KVH,D,Dv,window", [
+    (16, 1024, 12, 12, 64, 64, None),    # gpt2_124m.train_1chip
+    (4, 2048, 8, 4, 128, 128, None),     # internlm2_1_8b.train_mesh4, a device
+    (1, 8192, 32, 32, 192, 128, None),   # kimi_linear_48b_a3b.train_share_8k
+    (1, 4096, 32, 8, 64, 64, None),      # granite_4_0_h_micro.train_stage_4k
+    (1, 16384, 32, 4, 128, 128, None),   # mellum2_12b_a2_5b.train_share_16k,
+    (1, 16384, 32, 4, 128, 128, 1024),   # its full layer and a windowed one
+], ids=["gpt2", "internlm2_shard", "kimi_mla", "granite_gqa64",
+        "mellum2_full", "mellum2_swa"])
 def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
-                                       B, S, H, KVH, D, Dv):
-    """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape,
-    bfloat16, causal: three Mosaic calls in the gradient's program, and the
-    statistics cross them with the sequence on the lanes ([B,H,1,S])."""
+                                       B, S, H, KVH, D, Dv, window):
+    """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape, bfloat16, causal: three Mosaic calls in the gradient's
+    program, and the statistics cross them with the sequence on the lanes
+    ([B,H,1,S])."""
     sd = lambda sh: jax.ShapeDtypeStruct(sh, jnp.bfloat16, sharding=one_chip)
     q, k, v = sd((B, S, H, D)), sd((B, S, KVH, D)), sd((B, S, KVH, Dv))
 
     def loss(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v).astype(jnp.float32) ** 2)
+        return jnp.sum(fa.flash_attention(q, k, v, window=window).astype(
+            jnp.float32) ** 2)
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()

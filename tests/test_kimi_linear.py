@@ -40,6 +40,14 @@ def _shared_expert(c):
         {"w_gate_up": c["sgu"], "w_down": c["sd"]})[0]
 
 
+def _sigmoid(c, scale=2.446):
+    """`moe_ffn_held`'s `route` for the case: sigmoid scores, its bias."""
+    import functools
+
+    return functools.partial(moe.sigmoid_route, bias=c["b"],
+                             experts_per_token=c["k"], routed_scale=scale)
+
+
 def _uncut_layer(c, scale=2.446):
     """The whole layer by the published equations, a loop over all experts."""
     x = c["x"].reshape(-1, c["x"].shape[-1])
@@ -69,9 +77,9 @@ def test_expert_shares_add_up_to_the_uncut_layer(shares, factor, monkeypatch):
     total, assigned, past = 0.0, 0.0, 0.0
     for r in range(shares):
         y, cnt = moe.moe_ffn_held(
-            c["x"], c["rw"], c["b"], c["wgu"][r * per:(r + 1) * per],
-            c["wd"][r * per:(r + 1) * per], held_first=r * per,
-            experts_per_token=c["k"], routed_scale=2.446, dtype=jnp.float32)
+            c["x"], c["rw"], c["wgu"][r * per:(r + 1) * per],
+            c["wd"][r * per:(r + 1) * per], route=_sigmoid(c),
+            held_first=r * per, dtype=jnp.float32)
         assert float(cnt["dropped"]) == 0.0
         total, assigned = total + y, assigned + float(cnt["assigned"])
         past += float(cnt["past_buffer"])
@@ -90,19 +98,17 @@ def test_no_assignment_dropped_under_a_skewed_router(monkeypatch):
     window that holds everything."""
     c = _layer_case(seed=1, S=128)
     c["b"] = c["b"].at[:4].add(10.0)  # experts 0-3 win every selection
-    kw = dict(experts_per_token=c["k"], routed_scale=2.446,
-              dtype=jnp.float32)
+    kw = dict(route=_sigmoid(c), dtype=jnp.float32)
     T = c["x"].shape[0] * c["x"].shape[1]
     shared = _shared_expert(c)
-    y, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["b"], c["wgu"], c["wd"],
-                              **kw)
+    y, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["wgu"], c["wd"], **kw)
     assert float(cnt["dropped"]) == 0.0 == float(cnt["past_buffer"])
     assert float(cnt["load_max"]) == T and float(cnt["assigned"]) == T * 4
     np.testing.assert_allclose(y + shared, _uncut_layer(c), atol=2e-5)
 
     def share(x, wgu, factor):
         monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", factor)
-        return moe.moe_ffn_held(x, c["rw"], c["b"], wgu, c["wd"][:4], **kw)
+        return moe.moe_ffn_held(x, c["rw"], wgu, c["wd"][:4], **kw)
 
     monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", 0.5)
     rows = moe.held_window_rows(T, 4, 16, 4)
@@ -126,9 +132,8 @@ def test_dropped_counts_what_the_loop_did_not_work(monkeypatch):
     c["b"] = c["b"].at[:4].add(10.0)
     monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", 0.5)
     monkeypatch.setattr(moe, "_trips", lambda held, rows, windows: 7)
-    _, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["b"], c["wgu"][:4],
-                              c["wd"][:4], experts_per_token=c["k"],
-                              dtype=jnp.float32)
+    _, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["wgu"][:4], c["wd"][:4],
+                              route=_sigmoid(c, 1.0), dtype=jnp.float32)
     assert float(cnt["assigned"]) == 1024 and float(cnt["dropped"]) == 128
 
 

@@ -65,3 +65,55 @@ def test_expert_parallel_matches_single_device():
         ep = float(jax.jit(
             lambda p, b: tfm.loss_fn(p, b, cfg))(params, batch))
     assert abs(ep - ref) < 2e-3, (ep, ref)
+
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_softmax_route_is_top_k_of_the_softmax(k):
+    """`softmax_route`: softmax over every expert's score, the top k of the
+    probabilities, renormalised: weights sum to 1, ids and weights are
+    `jax.lax.top_k`'s of the softmax, and no bias or scale takes part."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    x = jax.random.normal(jax.random.key(0), (96, 32))
+    w = jax.random.normal(jax.random.key(1), (32, 16)) * 0.3
+    idx, wts = moe.softmax_route(x, w, experts_per_token=k)
+    assert idx.shape == wts.shape == (96, k) and idx.dtype == jnp.int32
+    np.testing.assert_allclose(wts.sum(-1), 1.0, atol=1e-6)
+    p = jax.nn.softmax(jnp.dot(x, w, precision="highest"), -1)
+    top, want = jax.lax.top_k(p, k)
+    np.testing.assert_array_equal(idx, want)
+    np.testing.assert_allclose(wts, top / top.sum(-1, keepdims=True),
+                               atol=1e-6)
+
+
+def test_held_layer_takes_the_routing_it_is_given():
+    """`moe_ffn_held` with `softmax_route` bound: every expert held, the
+    layer is the plain sum over each token's top k; the GShard path's
+    capacity plays no part (nothing dropped at any skew)."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    ks = jax.random.split(jax.random.key(2), 4)
+    x = jax.random.normal(ks[0], (2, 24, 16))
+    rw = jax.random.normal(ks[1], (16, 8))
+    wgu = jax.random.normal(ks[2], (8, 16, 2, 12)) * 0.2
+    wd = jax.random.normal(ks[3], (8, 12, 16)) * 0.2
+    route = functools.partial(moe.softmax_route, experts_per_token=2)
+    with jax.default_matmul_precision("highest"):
+        y, cnt = moe.moe_ffn_held(x, rw, wgu, wd, route=route,
+                                  dtype=jnp.float32)
+        xf = x.reshape(-1, 16)
+        idx, wts = route(xf, rw)
+        want = jnp.zeros_like(xf)
+        for e in range(8):
+            we = jnp.sum(jnp.where(idx == e, wts, 0.0), -1)
+            h = jax.nn.silu(xf @ wgu[e, :, 0]) * (xf @ wgu[e, :, 1])
+            want = want + we[:, None] * (h @ wd[e])
+    np.testing.assert_allclose(y.reshape(-1, 16), want, atol=2e-5)
+    assert float(cnt["dropped"]) == 0.0
+    assert float(cnt["assigned"]) == 48 * 2
