@@ -1,0 +1,231 @@
+"""Held-experts probe on the chip: one expert layer alone (`ops/moe.py`
+`moe_ffn_held` under the model's `"dots"` remat policy), forward + backward,
+at the shapes of the two cells that run it, at the module's own window and
+for each rule of `RULES` (a factor on the even share, alone): ms a call, the window's rows, the trips the loop took and the rows it
+worked. `HELD_WINDOW_FACTOR` of ops/moe.py is filled from these lines (the
+sweep beside the constant).
+
+The routing is what a random router gives random tokens: close to even
+(`assigned` over `even` in each line), so a margin of 1.125 and more takes
+one trip and the half- and quarter-share windows more. `skew` adds a
+selection bias towards the held range (`sigmoid_route`'s, in both cells:
+only that route has one), for the cost of what falls past the module's first
+window at each share of it that a further window may have (`SHARES`).
+
+`parts` times the layer's passes one by one at a window of W rows: the row
+gather, the scatter-add back (as it is, and with the rows sorted by token
+first), the combine written as a gather over every token's k positions, and
+the gate/up grouped product with its two transposes through both paths of
+`moe.grouped_products` (`lax.ragged_dot`; the Pallas grouped matmul at
+`moe._tiles`) and, at the module's window, at each tiling of `GMM_TILES`
+(the sweep beside `_tiles`).
+
+One JSON line a case. Off the chip the script fails at once.
+
+    python3 benchmarks/probe_moe.py            # the sweep, both cells
+    python3 benchmarks/probe_moe.py skew       # the module's window, skewed
+    python3 benchmarks/probe_moe.py parts      # the passes alone
+"""
+from __future__ import annotations
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import functools
+import json
+import time
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops import moe
+from ray_tpu.util.jaxenv import enable_compile_cache, require_tpu
+
+# (name, tokens, d, experts, held first, held count, expert width, k, route):
+# mellum2_12b_a2_5b.train_share_16k and kimi_linear_48b_a3b.train_share_8k.
+CELLS = [("mellum2", 16384, 2304, 64, 16, 16, 896, 8, "softmax"),
+         ("kimi_linear", 8192, 2304, 256, 104, 8, 1024, 8, "sigmoid")]
+# Window rules: the factor on the held experts' even share. 4.0 is what PRs
+# 27-33 ran (with a quarter of the experts held: every assignment).
+RULES = (4.0, 1.5, 1.25, 1.125, 0.5, 0.25)
+SKEW = (0.0, 0.03, 0.06, 0.12)  # added to the held experts' scores in the selection
+# (rows, contracted, columns) tiles of the Pallas grouped matmul, for `parts`.
+GMM_TILES = ((512, 768, 896), (512, 1152, 896), (1024, 768, 896),
+             (256, 1152, 896), (512, 1152, 1792), (512, 2304, 896))
+FACTOR = moe.HELD_WINDOW_FACTOR  # the module's own, which the rules replace
+MIN_TOKENS = getattr(moe, "HELD_WINDOW_MIN_TOKENS", 0.0)  # (a rule: factor alone)
+SHARE = getattr(moe, "FURTHER_WINDOW_SHARE", 1.0)
+SHARES = (1.0, 0.5, 0.25, 0.125)  # of the first window, a further one
+
+
+def _case(T, d, E, first, Eh, F, k, kind, skew=0.0):
+    ks = jax.random.split(jax.random.key(T + E), 5)
+    if kind == "sigmoid" or skew:  # only this route has a selection bias
+        route = functools.partial(
+            moe.sigmoid_route, experts_per_token=k, routed_scale=2.446,
+            bias=jnp.zeros((E,)).at[first:first + Eh].add(skew))
+    else:
+        route = functools.partial(moe.softmax_route, experts_per_token=k)
+    x = jax.random.normal(ks[0], (1, T, d), jnp.bfloat16)
+    params = (jax.random.normal(ks[1], (d, E)) * d ** -0.5,
+              jax.random.normal(ks[2], (Eh, d, 2, F)) * 0.02,
+              jax.random.normal(ks[3], (Eh, F, d)) * 0.02)
+    wy = jax.random.normal(ks[4], (1, T, d), jnp.bfloat16)
+    return x, params, wy, route
+
+
+def _layer(route, first):
+    """loss and gradients (x, router, both weight stacks) of the layer as a
+    stack's layer body runs it: under `jax.checkpoint` with what `"dots"`
+    keeps."""
+    policy = jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        jax.checkpoint_policies.save_only_these_names(
+            *getattr(moe, "RESIDUAL_NAMES", ())))
+
+    @functools.partial(jax.checkpoint, policy=policy)
+    def body(x, rw, wgu, wd):
+        return moe.moe_ffn_held(x, rw, wgu, wd, route=route,
+                                held_first=first)
+
+    def loss(x, rw, wgu, wd, wy):
+        y, cnt = body(x, rw, wgu, wd)
+        return jnp.sum(y.astype(jnp.float32) * wy), cnt
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                      has_aux=True))
+
+
+def _ms(fn, args, steps=10, repeats=3):
+    """ms a call: the least of `repeats` means over `steps` calls in flight."""
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / steps * 1e3)
+    return round(best, 3)
+
+
+def layer_ms(cell, factor, skew=0.0, share=None):
+    name, T, d, E, first, Eh, F, k, kind = cell
+    moe.HELD_WINDOW_FACTOR = factor or FACTOR  # read when the layer is traced
+    moe.HELD_WINDOW_MIN_TOKENS = 0.0 if factor else MIN_TOKENS
+    moe.FURTHER_WINDOW_SHARE = share or SHARE
+    x, params, wy, route = _case(T, d, E, first, Eh, F, k, kind, skew)
+    fn = _layer(route, first)
+    t0 = time.perf_counter()
+    (_, cnt), grads = jax.block_until_ready(fn(x, *params, wy))
+    row = {"cell": name, "factor": moe.HELD_WINDOW_FACTOR,
+           "min_tokens": moe.HELD_WINDOW_MIN_TOKENS, "skew": skew,
+           "further_share": moe.FURTHER_WINDOW_SHARE,
+           "compile_s": round(time.perf_counter() - t0, 2),
+           "window_rows": moe.held_window_rows(T, k, E, Eh),
+           "even": T * k * Eh // E, "assigned": float(cnt["assigned"]),
+           "dropped": float(cnt["dropped"]),
+           "finite": all(bool(jnp.isfinite(g.astype(jnp.float32)).all())
+                         for g in grads)}
+    if "trips" in cnt:
+        row["trips"] = float(cnt["trips"])
+        row["rows_worked"] = row["window_rows"] + (row["trips"] - 1) * (
+            moe.further_window_rows(row["window_rows"]))
+    row["ms"] = _ms(fn, (x, *params, wy))
+    return row, row["dropped"] == 0.0 and row["finite"]
+
+
+def parts_ms(cell, factor):
+    """The passes of one window alone, at the rule's rows, groups even."""
+    name, T, d, E, first, Eh, F, k, kind = cell
+    moe.HELD_WINDOW_FACTOR, moe.HELD_WINDOW_MIN_TOKENS = factor, 0.0
+    W = moe.held_window_rows(T, k, E, Eh)
+    ks = jax.random.split(jax.random.key(0), 6)
+    bf = jnp.bfloat16
+    xf = jax.random.normal(ks[0], (T, d), bf)
+    yb = jax.random.normal(ks[1], (W, d), bf)
+    gu = jax.random.normal(ks[2], (W, 2 * F), bf)
+    w1 = jax.random.normal(ks[3], (Eh, d, 2 * F), bf)
+    tok = jax.random.randint(ks[4], (W,), 0, T)
+    pos = jax.random.randint(ks[5], (T, k), 0, 4 * W)  # a quarter in reach
+    sizes = jnp.full((Eh,), W // Eh, jnp.int32)
+    row = {"cell": name, "factor": factor, "window_rows": W}
+
+    def sorted_scatter(yb, tok):
+        by_token = jnp.argsort(tok)
+        return jnp.zeros((T, d), bf).at[tok[by_token]].add(
+            yb[by_token], mode="drop", indices_are_sorted=True)
+
+    cases = {
+        "gather_ms": (lambda xf, tok: xf.at[tok].get(
+            mode="fill", fill_value=0), (xf, tok)),
+        "scatter_add_ms": (lambda yb, tok: jnp.zeros((T, d), bf).at[tok].add(
+            yb, mode="drop"), (yb, tok)),
+        "scatter_add_sorted_ms": (sorted_scatter, (yb, tok)),
+        "combine_as_gather_ms": (lambda yb, pos: jnp.sum(yb.at[pos].get(
+            mode="fill", fill_value=0), axis=1), (yb, pos)),
+    }
+    for key, (fn, args) in cases.items():
+        row[key] = _ms(jax.jit(fn), args, steps=20)
+
+    def products(label):
+        """[W, d] x [Eh, d, 2F] and its two transposes, as the layer's
+        gate/up product makes them."""
+        for key, fn, args in zip(
+                ("product", "product_t_rows", "product_t_weights"),
+                moe.grouped_products(label != "ragged_dot", bf),
+                ((yb, w1, sizes), (gu, w1, sizes), (yb, gu, sizes))):
+            try:
+                row[f"{key}_{label}_ms"] = _ms(jax.jit(fn), args, steps=20)
+            except Exception as e:  # a tiling the kernel refuses
+                row[f"{key}_{label}_error"] = f"{type(e).__name__}: {e}"[:160]
+
+    products("ragged_dot")
+    products("kernel")
+    if factor == FACTOR:
+        tiles_fn = moe._tiles
+        try:
+            for tiles in GMM_TILES:
+                moe._tiles = lambda m, k, n, tiles=tiles: tiles
+                products("kernel_%dx%dx%d" % tiles)
+        finally:
+            moe._tiles = tiles_fn
+    return row, True
+
+
+def main(argv) -> int:
+    dev = require_tpu()
+    enable_compile_cache()
+    print(json.dumps({"device_kind": dev.device_kind,
+                      "devices": len(jax.devices())}), flush=True)
+    failed = 0
+
+    def report(fn, *args):
+        nonlocal failed
+        try:
+            row, ok = fn(*args)
+        except Exception as e:  # report every case, fail at the end
+            row, ok = {"args": repr(args)[:200],
+                       "error": f"{type(e).__name__}: {e}"[:600]}, False
+        row["ok"] = ok
+        failed += not ok
+        print(json.dumps(row), flush=True)
+
+    for cell in CELLS:
+        if argv[1:] == ["skew"]:
+            for skew in SKEW:
+                for share in SHARES if skew else SHARES[:1]:
+                    report(layer_ms, cell, None, skew, share)
+        elif argv[1:] == ["parts"]:
+            for factor in RULES[:4]:
+                report(parts_ms, cell, factor)
+        else:
+            for factor in (None,) + RULES:  # the module's own rule first
+                report(layer_ms, cell, factor)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main(sys.argv))

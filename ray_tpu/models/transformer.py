@@ -77,9 +77,10 @@ class TransformerConfig:
     #   saving, ~33% extra FLOPs, flash forward kernel included).
     # - "dots": save matmul outputs and the kernels' own residuals (flash: o
     #   [B,H,S,hd] and lse [B,H,S]; KDA: o, chunk states, inverses; SSD: y,
-    #   chunk states; named in their forward rules, RESIDUAL_NAMES of
-    #   ops/flash_attention.py, ops/kda.py and ops/ssd.py); recompute the
-    #   rest. A forward kernel runs once a layer.
+    #   chunk states; a held range of experts: the first window's two grouped
+    #   products; named in their forward rules, RESIDUAL_NAMES of
+    #   ops/flash_attention.py, ops/kda.py, ops/ssd.py and ops/moe.py);
+    #   recompute the rest. A forward kernel runs once a layer.
     # Default "dots": keeping o and lse takes the second forward-kernel call
     # out of every layer's backward (gpt2_124m, batch 16 x 1024, one v5e
     # chip: step 184.2 -> 179.5 ms, step memory 13.96 -> 15.19 GB; chip
@@ -105,9 +106,12 @@ class TransformerConfig:
     #   (first, count) is the contiguous range of experts THIS program holds
     #   (None = all): it routes over all `moe_num_experts`, computes its own
     #   experts' part and leaves the rest out (one expert-parallel rank).
-    #   Gathered assignments are worked in windows of a few times the held
-    #   experts' even share (ops/moe.py `HELD_WINDOW_FACTOR`), as many as the
-    #   routing needs; what falls past the first is counted: nothing is
+    #   Gathered assignments are worked in windows of a margin over the held
+    #   experts' even share (ops/moe.py `HELD_WINDOW_FACTOR`, no fewer than a
+    #   row a token; every assignment where all are held): the layer's time
+    #   follows the window's rows, so a routing within the margin takes one
+    #   trip and only one past it more, as many as it needs; what falls past
+    #   the first is counted (`moe_past_buffer`, `moe_trips`): nothing is
     #   dropped.
     moe_num_experts: int = 0
     moe_experts_per_token: int = 2
@@ -895,10 +899,12 @@ def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
         return body
     if cfg.remat_policy == "full":
         return jax.checkpoint(body)
-    from ray_tpu.ops import flash_attention as fa, kda, ssd
+    from ray_tpu.ops import flash_attention as fa, kda, moe, ssd
 
-    # What no dot makes: the kernels' own residuals.
-    names = fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
+    # What no dot makes: the kernels' own residuals and the held experts'
+    # grouped products (`ragged_dot` is no `dot_general`).
+    names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
+             + moe.RESIDUAL_NAMES)
     return jax.checkpoint(
         body,
         policy=jax.checkpoint_policies.save_from_both_policies(
@@ -924,8 +930,9 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
     its repeats, every layer under the remat policy. -> (hidden [B,S,d],
     the stack's extras from its layers' (`_mlp_block`): {} for dense
     feed-forwards, {"aux": sum} for GShard, and for a held range of experts
-    moe_assigned, moe_dropped, moe_past_buffer (sums), moe_load_max (max),
-    moe_load_mean (mean) over the expert layers)."""
+    moe_assigned, moe_dropped, moe_past_buffer, moe_trips (sums),
+    moe_load_max, moe_window_rows (max), moe_load_mean (mean) over the
+    expert layers)."""
     B, S = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -951,7 +958,9 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
                "moe_dropped": cat["dropped"].sum(),
                "moe_past_buffer": cat["past_buffer"].sum(),
                "moe_load_max": cat["load_max"].max(),
-               "moe_load_mean": cat["load_mean"].mean()}
+               "moe_load_mean": cat["load_mean"].mean(),
+               "moe_trips": cat["trips"].sum(),
+               "moe_window_rows": cat["window_rows"].max()}
 
 
 def final_hidden_and_head(
@@ -1028,8 +1037,9 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
             *, shift_inputs: bool = False, with_counters: bool = False):
     """Next-token cross-entropy. `with_counters`: return (loss, routing
     counters) for `ShardedTrainStep(has_aux=True)`: device scalars
-    moe_assigned, moe_dropped, moe_past_buffer, moe_load_max, moe_load_mean
-    of a stack with a held range of experts (`_backbone`), {} for any other.
+    moe_assigned, moe_dropped, moe_past_buffer, moe_load_max, moe_load_mean,
+    moe_trips, moe_window_rows of a stack with a held range of experts
+    (`_backbone`), {} for any other.
 
     Two token conventions:
     - in-place (default): batch tokens [B,S]; the forward runs on the FULL

@@ -11,9 +11,11 @@
   probabilities, renormalised). The layer is told which contiguous range of
   the experts it holds (`held`): it routes over all of them, sorts the
   assignments by expert with its own first, runs those as grouped matrix
-  products (`lax.ragged_dot`, no one-hot) a window of rows at a time, and
-  returns its own experts' part. With every expert held and the expert dim
-  sharded over an `expert` mesh axis this is expert parallelism.
+  products (`lax.ragged_dot`, no one-hot) in one window of a margin over the held
+  experts' even share of the rows and no fewer than a row a token (smaller
+  ones for what a skewed routing puts past it), and returns its own experts' part. With every expert held
+  and the expert dim sharded over an `expert` mesh axis this is expert
+  parallelism.
   `TransformerConfig.moe_router = "sigmoid"` or `"softmax"`.
 
 GShard-style capacity-based top-k dispatch:
@@ -38,6 +40,9 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from . import flash_attention
 
 
 def moe_ffn(
@@ -167,28 +172,147 @@ def softmax_route(x: jax.Array, router_w: jax.Array, *,
     return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
 
 
-# A window of gathered assignments is this many times the held experts'
-# even share of them (tokens x k x held / experts). An even routing fits one
-# window with room for a few times the mean load on one expert; a skewed one
-# takes further trips of the same loop.
-HELD_WINDOW_FACTOR = 4.0
+# The first window of gathered assignments is the held experts' even share of
+# them (tokens x k x held / experts) times this margin. A window spans the
+# sorted rows of ALL held experts, so one expert's skew never matters: only
+# the held total does; the layer's time follows the window's rows, not the
+# held ones, and every further trip of the backward loop writes and adds a
+# whole dW1, dW2 and dx, so ONE trip is the rule. Sweep on the chip
+# (`benchmarks/probe_moe.py`, PR 34; one layer alone, forward + backward, ms
+# at mellum2's [16384, 2304] with 16 of 64 experts of 896 held / at the
+# hybrid's [8192, 2304] with 8 of 256 of 1024; 32,819 / 2,166 held rows, an
+# even routing: 0.99-1.08 of the even share on the chip at a run's start):
+#   4.0 (every assignment, PRs 27-33)   64.92 / 11.56
+#   1.5                                 32.82 /  9.91
+#   1.25                                30.36 /  9.82
+#   1.125                               29.03 /  9.32
+#   0.5, then 3 x half of it            56.49 / 13.48
+#   0.25, then 7 x half of it           80.87 / 17.17
+# The margin is what the held total reaches in the steps a program runs, not
+# what an even routing needs: in mellum2's cell AdamW pulls the router to the
+# held experts (131 k -> 189-236 k assignments a step in 66-74 steps, two of
+# the four layers far ahead of the others), a trip past the window costs a
+# layer 10-22 ms, and how many a run takes is the seed's to decide. The cell,
+# tokens/s/chip over 5-8 seeds, the middle half of them, rows past the window
+# a run (parent 25,984; the driver's bound on the spread 260):
+#   1.25   39,833-40,332   273-309   415-658 k
+#   1.5    39,267-39,612   320       200-388 k
+#   2.5    35,806-35,840   25        0          (66 steps)
+#   3.0    does not fit the chip (the kept products: 187 MB over)
+# (In the traced step the held experts take 38.0 ms a layer at 2.5 and 24.2
+# at 1.25.) With a router that stays even (a balancing term in the cell's job
+# file, PERF.md section 7 (d)) 1.25 is the value.
+HELD_WINDOW_FACTOR = 2.5
+# And the window is never under this many rows a token. A token picks an
+# expert once, so the tokens are the rows ONE held expert can be given, and
+# where few experts of many are held (the hybrid: 8 of 256, k = 8) the even
+# share is a quarter of that: its held total read 0.55 to 1.63 of the even
+# share over a run's steps and seeds, one expert alone up to 1.6 of it
+# (`load_max` 3,291 for an even share of 2,048), so a window of 1.25 took a
+# further trip in most steps and a number of them that the seed decides:
+# 1.74 ms a layer saved, the cell's six runs 1.6% apart where the parent's
+# were 0.3% (PERF.md section 6, PR 34, second round). At a row a token the
+# hybrid's window is the 8,192 rows of PRs 27-33, which no step has passed
+# (seven seeds 25,717-25,784 tokens/s/chip, the parent 25,195-25,219);
+# mellum2's (81,920 for 16,384 tokens) is the margin's.
+HELD_WINDOW_MIN_TOKENS = 1.0
+# What falls past the first window is worked in windows of this share of it,
+# so that a step that overflows by a few rows (a router drifting towards the
+# held experts does, PERF.md section 6, PR 34) pays half a window and not a
+# whole one more, while a trip's own cost (6 ms at mellum2's shape, 1.5 at
+# the hybrid's) keeps the windows few. Same probe, `skew` (`lax.ragged_dot`
+# products), ms at a share of 1 / 0.5 / 0.25 / 0.125: mellum2 with 46,510
+# held rows 74.7 / 66.0 / 62.9 / 68.0, with 61,220 85.7 / 76.0 / 81.7 / 91.3;
+# the hybrid with 3,534 14.6 / 14.2 / 14.3 / 15.1, with 9,087 25.1 / 33.9 /
+# 32.8 / 38.1.
+FURTHER_WINDOW_SHARE = 0.5
+
+# The first window's grouped products' outputs (gate/up [W, 2F], down [W, d])
+# carry these names in the forward rule, so that a remat policy can keep them
+# (`save_only_these_names(*RESIDUAL_NAMES)`, models/transformer.py "dots"):
+# the backward then gathers no rows and runs no product a second time.
+RESIDUAL_NAMES = ("moe_gate_up", "moe_down")
+
+
+def use_kernels(platform: str, dtype, widths: Tuple[int, ...],
+                on_mesh: bool) -> bool:
+    """The grouped products' dispatch rule, a pure function of what the code
+    observes: the Pallas grouped matmul (`jax.experimental.pallas.ops.tpu.
+    megablox`) on a TPU with 2-byte operands, widths of whole 128-lane tiles
+    and no multi-device mesh (a Mosaic call cannot be partitioned by GSPMD),
+    else `lax.ragged_dot`, which XLA lowers to a Mosaic call of its own
+    tiling: at a window of 40,960 rows x 2,304 -> 1,792 that one takes 4.12
+    ms, 4.59 against the weights transposed and 5.10 for the weights'
+    cotangent, the kernel at `_tiles` 1.98, 2.01 and 2.00 (`probe_moe.py
+    parts`, PERF.md section 6, PR 34)."""
+    return (platform == "tpu" and not on_mesh
+            and jnp.dtype(dtype).itemsize == 2
+            and all(w % 128 == 0 for w in widths))
+
+
+def _tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """Tiles (rows, contracted, columns) of a grouped product of those
+    sizes: the largest of each list that divides its dimension (rows are
+    multiples of 128). Sweep on the chip, `probe_moe.py parts`, [40960,
+    2304] x [16, 2304, 1792], ms plain / against the weights transposed /
+    the weights' cotangent: 512 x 1152 x 896 1.98 / 2.86 / 2.00, 512 x 768 x
+    896 2.08 / 3.04 / 2.10, 1024 x 768 x 896 2.25 / 3.35 / 2.30, 256 x 1152
+    x 896 2.59 / 3.54 / 2.18; 2304 deep or 1792 wide passes the VMEM. (The
+    transposed product contracts 1,792 and takes 896 x 1152: 2.01.)"""
+    pick = lambda x, sizes: next((t for t in sizes if x % t == 0), 128)
+    return (pick(m, (512, 256)), pick(k, (1152, 1024, 896, 768, 512, 256)),
+            pick(n, (1152, 1024, 896, 768, 512, 256)))
+
+
+def grouped_products(kernels: bool, dtype):
+    """-> (rows [W, m] x weights [Eh, m, n] -> [W, n]; rows [W, n] x the
+    same weights, transposed -> [W, m]; rows [W, m], [W, n] -> the weights'
+    cotangent [Eh, m, n]), each over `sizes` rows an expert: a product and
+    its two transposes, by the Pallas kernels or by `lax.ragged_dot`. Rows
+    past the groups' end come back undefined from the first two."""
+    if not kernels:
+        dims = jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+        return (jax.lax.ragged_dot,
+                lambda g, w, sizes: jax.lax.ragged_dot(
+                    g, jnp.swapaxes(w, 1, 2), sizes),
+                lambda a, g, sizes: jax.lax.ragged_dot_general(
+                    a, g, sizes, dims))
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    kw = dict(preferred_element_type=dtype, tiling=_tiles,
+              interpret=flash_attention._interpret())
+    return (lambda a, w, sizes: gmm(a, w, sizes, **kw),
+            lambda g, w, sizes: gmm(g, w, sizes, transpose_rhs=True, **kw),
+            lambda a, g, sizes: tgmm(a.T, g, sizes, **kw))
 
 
 def held_window_rows(tokens: int, experts_per_token: int, num_experts: int,
                      held_count: int) -> int:
-    """Rows of one window: `HELD_WINDOW_FACTOR` times the even share of the
-    held experts, a multiple of 128, at most every assignment there is
-    (which is what `held` = all gets)."""
+    """Rows of the first window: `HELD_WINDOW_FACTOR` times the even share
+    of the held experts, no fewer than `HELD_WINDOW_MIN_TOKENS` a token,
+    rounded up to a multiple of 128, at most every assignment there is (which
+    is what `held` = all gets: one trip, always)."""
     total = tokens * experts_per_token
     if held_count >= num_experts:
         return total
-    rows = int(HELD_WINDOW_FACTOR * total * held_count / num_experts)
-    return min(total, -(-max(rows, 1) // 128) * 128)
+    rows = max(int(HELD_WINDOW_FACTOR * total * held_count / num_experts),
+               int(HELD_WINDOW_MIN_TOKENS * tokens), 1)
+    return min(total, -(-rows // 128) * 128)
 
 
-def _trips(held, rows: int, windows: int):
-    """Windows of `rows` rows that `held` sorted assignments reach into."""
-    return jnp.clip((held + rows - 1) // rows, 1, windows)
+def further_window_rows(rows: int) -> int:
+    """Rows of every window past a first one of `rows`:
+    `FURTHER_WINDOW_SHARE` of it, rounded up to a multiple of 128."""
+    return min(rows, -(-int(FURTHER_WINDOW_SHARE * rows) // 128) * 128)
+
+
+def _trips(held, rows: int, further_rows: int, further: int):
+    """Windows that `held` sorted assignments reach into: the first, of
+    `rows` rows, and as many of the `further` ones of `further_rows`."""
+    return 1 + jnp.clip((held - rows + further_rows - 1) // further_rows,
+                        0, further)
 
 
 def moe_ffn_held(
@@ -208,16 +332,22 @@ def moe_ffn_held(
     `sigmoid_route` or `softmax_route` with its keywords bound.
 
     The assignments, sorted by expert with the held ones first, are worked
-    through in windows of `held_window_rows` rows by one loop of as many
-    trips as the held assignments reach into (one, as a rule; up to all
-    tokens x k rows), each a grouped product over the rows the routing put
-    there: a routing however skewed loses nothing and an even one pays for
-    its own rows in one window.
+    through in windows: the first, of `held_window_rows` rows (a margin over
+    the held experts' even share, a row a token at least; every assignment
+    where all are held), always, then a loop of as many smaller ones
+    (`further_window_rows`) as the held assignments reach into. Each is a
+    gather of the window's token rows, two grouped products over the rows the
+    routing put there and a scatter-add back, so the device work follows the
+    window that the held share sizes: a routing within the margin takes the
+    one window and a routing however skewed loses nothing.
 
     Counters (device scalars, float32): `assigned` (assignments that fell
     on held experts), `load_max` / `load_mean` (of a held expert, in
     assignments), `past_buffer` (assignments beyond the first window),
-    `dropped` (assigned less the rows the loop's trips counted as worked)."""
+    `dropped` (assigned less the rows the windows counted as worked),
+    `trips` (windows worked), `window_rows` (rows of the first window)."""
+    from ray_tpu.parallel.sharding import current_sharding_ctx
+
     B, S, d = x.shape
     T = B * S
     E, Eh, F = router_w.shape[-1], w_gate_up.shape[0], w_down.shape[1]
@@ -226,7 +356,8 @@ def moe_ffn_held(
         idx, wts = route(xf, router_w)
         k = idx.shape[-1]
         W = held_window_rows(T, k, E, Eh)
-        windows = -(-T * k // W)
+        W2 = further_window_rows(W)
+        n_further = -(-(T * k - W) // W2)  # windows past the first, at most
         local = idx.reshape(T * k) - held_first
         local = jnp.where((local >= 0) & (local < Eh), local, Eh)
         counts = jnp.sum(local[:, None] == jnp.arange(Eh)[None, :], axis=0,
@@ -235,74 +366,130 @@ def moe_ffn_held(
         held = ends[-1]
         # Sorted by expert, held ones first; padded to whole windows.
         order = jnp.pad(jnp.argsort(local, stable=True),
-                        (0, windows * W - T * k))
+                        (0, W + n_further * W2 - T * k))
         wflat = wts.reshape(T * k)
-        trips = _trips(held, W, windows)
+        trips = _trips(held, W, W2, n_further)
     w1 = w_gate_up.reshape(Eh, d, 2 * F).astype(dtype)
     w2 = w_down.astype(dtype)
+    f32 = lambda a: a.astype(jnp.float32)
 
-    def window(xf, w1, w2, wflat, order, ends, i):
-        """Rows i W .. (i + 1) W of the sorted list -> (their part of the
-        output [T, d], how many of them were held assignments)."""
-        lo = i * W
-        rows = jax.lax.dynamic_slice(order, (lo,), (W,))
-        ends_w = jnp.clip(ends, lo, lo + W) - lo
-        sizes = jnp.diff(ends_w, prepend=0)
-        # Rows in no group are left undefined by a grouped product (zeros on
-        # the CPU, whatever the buffer held on the TPU: NaN seen, chip run of
-        # PR 27), forward and in every transposed product of the backward
-        # pass. The masks zero them on the way out and, transposed, on the
-        # way back.
-        valid = (jnp.arange(W) < ends_w[-1])[:, None]
-        tok = rows // k
-        xb = jnp.where(valid, xf[tok], 0).astype(dtype)        # [W, d]
-        gu = jnp.where(valid, jax.lax.ragged_dot(xb, w1, sizes), 0)
-        act = jax.nn.silu(gu[:, :F]) * gu[:, F:]
-        yb = jnp.where(valid, jax.lax.ragged_dot(act, w2, sizes), 0)
-        yb = yb * jnp.where(valid[:, 0], wflat[rows], 0.0)[:, None].astype(
-            yb.dtype)
-        return jnp.zeros((T, d), yb.dtype).at[tok].add(yb), ends_w[-1]
+    # A grouped product leaves rows in no group undefined (zeros on the CPU,
+    # whatever the buffer held on the TPU: NaN seen, chip run of PR 27),
+    # forward and in every transposed product of the backward pass. Such rows
+    # (past the window's held assignments) take the token index T, one past
+    # the tokens: gathered from there they are zeros, scattered to there they
+    # are skipped, so no pass over a [W, .] buffer is a mask alone. What a
+    # product wrote there is zeroed once, in the pass that applies the SiLU
+    # (and its transpose), since a NaN times a zero weight is a NaN.
+    def rows_of(wflat, order, ends, lo, n):
+        """The window of rows lo .. lo + n of the sorted list -> the token
+        of each row (T where the row holds no held assignment), its
+        assignment (T k there), its weight (0 there), the experts' group
+        sizes inside the window, which rows hold an assignment, how many."""
+        rows = jax.lax.dynamic_slice(order, (lo,), (n,))
+        ends_w = jnp.clip(ends, lo, lo + n) - lo
+        valid = jnp.arange(n) < ends_w[-1]
+        return (jnp.where(valid, rows // k, T), jnp.where(valid, rows, T * k),
+                jnp.where(valid, wflat[rows], 0.0),
+                jnp.diff(ends_w, prepend=0), valid[:, None], ends_w[-1])
+
+    take = lambda a, at: a.at[at].get(mode="fill", fill_value=0)
+    # The first window's products by the kernels where `use_kernels` says so;
+    # the further ones, which an even routing never reaches, by `ragged_dot`
+    # always: every Pallas call in a program is lowered in Python when the
+    # program is traced (0.4 s each on the chip's worker), and the loop's
+    # eight would put `setup_s` past its bound (PERF.md section 6, PR 34).
+    ctx = current_sharding_ctx()
+    first = (0, W, grouped_products(use_kernels(
+        jax.devices()[0].platform, dtype, (d, F),
+        ctx is not None and ctx[0].size > 1), dtype))
+    further = lambda i: (W + (i - 1) * W2, W2, grouped_products(False, dtype))
+
+    def swiglu(gu, valid):
+        gu = jnp.where(valid, gu, 0)  # on the way in: transposed, it zeroes
+        return jax.nn.silu(gu[:, :F]) * gu[:, F:]  # the cotangent going out
+
+    def window(xf, w1, w2, wflat, order, ends, lo, rows, products):
+        """-> (the window's part of the output [T, d], how many of its rows
+        were held assignments, the two products' outputs)."""
+        grouped = products[0]
+        tok, _, wrow, sizes, valid, n = rows_of(wflat, order, ends, lo, rows)
+        gu = grouped(take(xf, tok).astype(dtype), w1, sizes)   # [W, 2F]
+        yb = grouped(swiglu(gu, valid), w2, sizes)             # [W, d]
+        y = jnp.zeros((T, d), dtype).at[tok].add(
+            (f32(yb) * wrow[:, None]).astype(dtype), mode="drop")
+        return y, n, (gu, yb)
+
+    def window_t(xf, w1, w2, wflat, order, ends, lo, rows, products, gu, yb,
+                 dy):
+        """The window's transpose: the output's cotangent dy [T, d] -> those
+        of xf, w1, w2, wflat, given the products' outputs."""
+        _, grouped_t, grouped_tw = products
+        tok, at, wrow, sizes, valid, _ = rows_of(wflat, order, ends, lo, rows)
+        xb = take(xf, tok).astype(dtype)
+        act, swiglu_t = jax.vjp(lambda gu: swiglu(gu, valid), gu)
+        dyb = f32(take(dy, tok))                               # [W, d]
+        dwrow = jnp.where(valid[:, 0], jnp.sum(f32(yb) * dyb, axis=-1), 0.0)
+        dyb = (dyb * wrow[:, None]).astype(dtype)
+        dgu, = swiglu_t(grouped_t(dyb, w2, sizes))
+        return (jnp.zeros_like(xf).at[tok].add(
+                    grouped_t(dgu, w1, sizes).astype(xf.dtype), mode="drop"),
+                grouped_tw(xb, dgu, sizes), grouped_tw(act, dyb, sizes),
+                jnp.zeros_like(wflat).at[at].add(dwrow, mode="drop"))
 
     # A loop of dynamic length has no reverse rule, and a scan of
     # `lax.cond`s differentiated as it stands hands the scan its
     # loop-invariant operands (x, both weight stacks) as residuals of every
-    # iteration (+2 GB at 8 k tokens): so the loop has a backward rule of its
-    # own, the same trips over each window's transpose, summed as the
-    # forward sums, in the operands' own types.
-    @jax.custom_vjp
-    def worked_windows(xf, w1, w2, wflat, order, ends, trips):
-        def body(i, carry):
-            y, n = window(xf, w1, w2, wflat, order, ends, i)
-            return carry[0] + y, carry[1] + n
+    # iteration (+2 GB at 8 k tokens): so the windows have a backward rule of
+    # their own, the same trips over each window's transpose, summed as the
+    # forward sums, in the operands' own types. The first window, which is
+    # always worked and as a rule the only one, stands outside the loop: its
+    # products' outputs are residuals a remat policy can keep
+    # (`RESIDUAL_NAMES`) and its gradients start the sums, where a loop's
+    # would be added to zeros.
+    def worked(*args):
+        at, trips = args[:6], args[6]
+        y, n, res = window(*at, *first)
 
-        return jax.lax.fori_loop(
-            0, trips, body, (jnp.zeros((T, d), dtype), jnp.int32(0)))
+        def body(i, carry):
+            yi, ni, _ = window(*at, *further(i))
+            return carry[0] + yi, carry[1] + ni
+
+        return jax.lax.fori_loop(1, trips, body, (y, n)), res
+
+    @jax.custom_vjp
+    def worked_windows(*args):  # xf, w1, w2, wflat, order, ends, trips
+        return worked(*args)[0]
 
     def fwd(*args):
-        return worked_windows(*args), args
+        out, res = worked(*args)
+        return out, (args, tuple(map(checkpoint_name, res, RESIDUAL_NAMES)))
 
     def bwd(res, ct):
-        diff, (order, ends, trips) = res[:4], res[4:]
+        args, first_res = res
+        at, trips = args[:6], args[6]
 
         def body(i, acc):
-            _, vjp = jax.vjp(lambda *a: window(*a, order, ends, i)[0], *diff)
-            return tuple(a + g for a, g in zip(acc, vjp(ct[0])))
+            win = at + further(i)
+            g = window_t(*win, *window(*win)[2], ct[0])
+            return tuple(a + b for a, b in zip(acc, g))
 
-        acc = jax.lax.fori_loop(0, trips, body,
-                                tuple(jnp.zeros_like(a) for a in diff))
+        acc = jax.lax.fori_loop(1, trips, body,
+                                window_t(*at, *first, *first_res, ct[0]))
         return acc + tuple(np.zeros(a.shape, jax.dtypes.float0)
-                           for a in res[4:])
+                           for a in args[4:])
 
     worked_windows.defvjp(fwd, bwd)
 
     with jax.named_scope("moe.experts"):
-        y, worked = worked_windows(xf, w1, w2, wflat, order, ends, trips)
-    f32 = lambda a: a.astype(jnp.float32)
+        y, n_worked = worked_windows(xf, w1, w2, wflat, order, ends, trips)
     counters = {
         "assigned": f32(held),
         "load_max": f32(jnp.max(counts)),
         "load_mean": f32(held) / Eh,
         "past_buffer": f32(jnp.maximum(held - W, 0)),
-        "dropped": f32(held - worked),
+        "dropped": f32(held - n_worked),
+        "trips": jnp.float32(trips),
+        "window_rows": jnp.float32(W),
     }
     return y.reshape(B, S, d), counters

@@ -4,6 +4,8 @@ Interpret mode (tests/test_kda.py, tests/test_ssd.py) cannot see what Mosaic ref
 (unaligned slices, VMEM over the limit, an op with no lowering). Nothing
 runs, so this says nothing about results or times. The topology is described
 inside a fixture, never at import (one process at a time may load libtpu)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -115,3 +117,42 @@ def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
         ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert f"f32[{B},{H},1,{S}]" in text and f"f32[{B},{H},{S},1]" not in text
+
+
+@pytest.mark.parametrize("T,E,first,Eh,F,kind", [
+    (16384, 64, 16, 16, 896, "softmax"), (8192, 256, 104, 8, 1024, "sigmoid")])
+def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
+                                      monkeypatch, T, E, first, Eh, F, kind):
+    """One expert layer, forward and gradient, at the shapes of
+    `mellum2_12b_a2_5b.train_share_16k` and
+    `kimi_linear_48b_a3b.train_share_8k` (d 2304, 8 experts a token,
+    bfloat16), with the grouped products dispatched as on the chip: the
+    first window's two products and their four transposes are the Pallas
+    grouped matmul at `_tiles` (`gmm` / `tgmm` in the program's text); the
+    loop of further windows keeps `ragged-dot`."""
+    import functools
+
+    from ray_tpu.ops import moe
+
+    rule = moe.use_kernels  # the platform here is cpu
+    monkeypatch.setattr(moe, "use_kernels", lambda _, *a: rule("tpu", *a))
+    d, k = 2304, 8
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    if kind == "sigmoid":
+        route = functools.partial(moe.sigmoid_route, bias=jnp.zeros((E,)),
+                                  experts_per_token=k, routed_scale=2.446)
+    else:
+        route = functools.partial(moe.softmax_route, experts_per_token=k)
+
+    def loss(x, rw, wgu, wd):
+        y, _ = moe.moe_ffn_held(x, rw, wgu, wd, route=route,
+                                held_first=first)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        sd((1, T, d), jnp.bfloat16), sd((d, E), jnp.float32),
+        sd((Eh, d, 2, F), jnp.float32), sd((Eh, F, d), jnp.float32)
+    ).compile().as_text()
+    assert text.count(" custom-call(") >= 6
+    assert len(re.findall(r"%t?gmm[.\d]* = ", text)) == 6, text.count("gmm")
+    assert "ragged-dot" in text

@@ -63,39 +63,60 @@ def _uncut_layer(c, scale=2.446):
     return y.reshape(c["x"].shape)
 
 
-@pytest.mark.parametrize("shares,factor", [(1, 4.0), (4, 4.0), (16, 4.0),
-                                           (4, 0.5)])
+def _window_factor(monkeypatch, factor):
+    """The first window as `factor` times the even share alone, without the
+    module's row a token under it."""
+    monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", factor)
+    monkeypatch.setattr(moe, "HELD_WINDOW_MIN_TOKENS", 0.0)
+
+
+@pytest.mark.parametrize("shares,factor", [(1, None), (4, None), (16, None),
+                                           (4, 0.5), (4, 4.0)])
 def test_expert_shares_add_up_to_the_uncut_layer(shares, factor, monkeypatch):
     """The parts all the shares give, the shared expert counted once, add
     up to the uncut layer's output; no assignment is dropped or counted
-    twice (the shares' `assigned` add up to tokens x k). At factor 0.5 a
-    share's window is half its even load: two or three trips of the loop,
-    with experts' runs that straddle the windows."""
-    monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", factor)
+    twice (the shares' `assigned` add up to tokens x k). A share's window
+    is its even load times the module's factor (None), 2.5, and a
+    row a token at least (the sixteenth shares: 256 rows for an even load of
+    64): what a share's routing puts past it takes further, smaller windows
+    and is counted. At factor 0.5 the window is half the even load: two or three
+    trips of the loop, with experts' runs that straddle the windows; at 4.0
+    (the rule of PRs 27-33) a quarter share's window is every assignment."""
+    if factor:
+        _window_factor(monkeypatch, factor)
     c = _layer_case(S=128)
-    per = c["E"] // shares
-    total, assigned, past = 0.0, 0.0, 0.0
+    per, every = c["E"] // shares, c["x"].shape[0] * c["x"].shape[1] * c["k"]
+    rows = moe.held_window_rows(every // c["k"], c["k"], c["E"], per)
+    assert rows == {(1, None): every, (4, None): 640, (16, None): 256,
+                    (4, 0.5): 128, (4, 4.0): every}[shares, factor]
+    total, assigned = 0.0, 0.0
     for r in range(shares):
         y, cnt = moe.moe_ffn_held(
             c["x"], c["rw"], c["wgu"][r * per:(r + 1) * per],
             c["wd"][r * per:(r + 1) * per], route=_sigmoid(c),
             held_first=r * per, dtype=jnp.float32)
         assert float(cnt["dropped"]) == 0.0
-        total, assigned = total + y, assigned + float(cnt["assigned"])
-        past += float(cnt["past_buffer"])
+        held = float(cnt["assigned"])
+        assert float(cnt["window_rows"]) == rows
+        assert float(cnt["trips"]) == 1 + max(
+            -(-(held - rows) // moe.further_window_rows(rows)), 0)
+        assert float(cnt["past_buffer"]) == max(held - rows, 0)
+        assert (factor != 0.5) or float(cnt["trips"]) > 1
+        total, assigned = total + y, assigned + held
     shared = _shared_expert(c)
     np.testing.assert_allclose(total + shared, _uncut_layer(c), atol=2e-5)
-    assert assigned == c["x"].shape[0] * c["x"].shape[1] * c["k"]
-    assert (past > 0) == (factor < 1)
+    assert assigned == every
 
 
 def test_no_assignment_dropped_under_a_skewed_router(monkeypatch):
     """A router that sends every token to the same four experts. With every
-    expert held one window holds all tokens x k assignments. A share that
-    holds those four with a window an eighth of their load works them in
-    eight trips of the loop: nothing is dropped, `past_buffer` counts what
-    went beyond the first window, and output and gradients are those of a
-    window that holds everything."""
+    expert held one window holds all tokens x k assignments: one trip. A
+    share that holds those four works them in two trips at the module's
+    windows (2.5 x its even quarter, 640 rows, then one of 384, half of it
+    to a multiple of 128) and in eight at a window an eighth of their load:
+    nothing is dropped, `past_buffer` counts what went beyond the first
+    window, and output and gradients are those of a window that holds
+    everything."""
     c = _layer_case(seed=1, S=128)
     c["b"] = c["b"].at[:4].add(10.0)  # experts 0-3 win every selection
     kw = dict(route=_sigmoid(c), dtype=jnp.float32)
@@ -103,20 +124,29 @@ def test_no_assignment_dropped_under_a_skewed_router(monkeypatch):
     shared = _shared_expert(c)
     y, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["wgu"], c["wd"], **kw)
     assert float(cnt["dropped"]) == 0.0 == float(cnt["past_buffer"])
+    assert float(cnt["trips"]) == 1.0 and float(cnt["window_rows"]) == T * 4
     assert float(cnt["load_max"]) == T and float(cnt["assigned"]) == T * 4
+    np.testing.assert_allclose(y + shared, _uncut_layer(c), atol=2e-5)
+    y, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["wgu"][:4], c["wd"][:4],
+                              **kw)
+    assert moe.held_window_rows(T, 4, 16, 4) == 640 == float(
+        cnt["window_rows"])
+    assert moe.further_window_rows(640) == 384
+    assert float(cnt["trips"]) == 2.0 and float(cnt["dropped"]) == 0.0
+    assert float(cnt["past_buffer"]) == T * 4 - 640
     np.testing.assert_allclose(y + shared, _uncut_layer(c), atol=2e-5)
 
     def share(x, wgu, factor):
-        monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", factor)
+        _window_factor(monkeypatch, factor)
         return moe.moe_ffn_held(x, c["rw"], wgu, c["wd"][:4], **kw)
 
-    monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", 0.5)
+    _window_factor(monkeypatch, 0.5)
     rows = moe.held_window_rows(T, 4, 16, 4)
     assert rows == 128 and T * 4 == 1024  # eight trips
     y, cnt = share(c["x"], c["wgu"][:4], 0.5)
     assert float(cnt["assigned"]) == T * 4
     assert float(cnt["past_buffer"]) == T * 4 - rows
-    assert float(cnt["dropped"]) == 0.0
+    assert float(cnt["dropped"]) == 0.0 and float(cnt["trips"]) == 8.0
     np.testing.assert_allclose(y + shared, _uncut_layer(c), atol=2e-5)
     loss = lambda f: lambda x, w: jnp.sum(jnp.sin(share(x, w, f)[0]))
     for got, want in zip(
@@ -130,8 +160,8 @@ def test_dropped_counts_what_the_loop_did_not_work(monkeypatch):
     not reckoned from the sizes: a loop that stops a trip short says so."""
     c = _layer_case(seed=1, S=128)
     c["b"] = c["b"].at[:4].add(10.0)
-    monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", 0.5)
-    monkeypatch.setattr(moe, "_trips", lambda held, rows, windows: 7)
+    _window_factor(monkeypatch, 0.5)
+    monkeypatch.setattr(moe, "_trips", lambda held, rows, more, further: 7)
     _, cnt = moe.moe_ffn_held(c["x"], c["rw"], c["wgu"][:4], c["wd"][:4],
                               route=_sigmoid(c, 1.0), dtype=jnp.float32)
     assert float(cnt["assigned"]) == 1024 and float(cnt["dropped"]) == 128
@@ -306,8 +336,11 @@ def test_train_step_returns_counters_and_folds_them():
     # One expert layer x 128 tokens x 4 a token, half the experts held.
     assert 0.3 * 512 < seen["moe_assigned"] < 0.7 * 512
     assert seen["moe_load_max"] >= seen["moe_load_mean"] > 0
-    row = tracing.phase_table()["train.moe_assigned"]
-    assert row["count"] == before["count"] + 3
+    assert seen["moe_window_rows"] == moe.held_window_rows(128, 4, 16, 8)
+    assert (seen["moe_trips"] > 1.0) == (seen["moe_past_buffer"] > 0)
+    table = tracing.phase_table()
+    assert table["train.moe_assigned"]["count"] == before["count"] + 3
+    assert {"train.moe_trips", "train.moe_window_rows"} <= set(table)
     # compile_step: one executable for the loop and for memory_analysis().
     batch = ts.shard_batch({"tokens": toks})
     exe = ts.compile_step(params, opt, batch)

@@ -366,7 +366,10 @@ def test_decoding_refuses_the_new_kinds(case):
 def test_train_step_returns_the_softmax_routing_counters():
     """transformer_train_step(with_counters=True) on the tiny preset: the
     counters of `moe_ffn_held` serve the softmax routing as the sigmoid
-    one, nothing is dropped, and the loss falls."""
+    one, nothing is dropped, a layer's window is just over the held half's
+    even share (a trip a layer unless the routing is skewed: only then does
+    anything fall past the first window), and the loss falls."""
+    from ray_tpu.ops import moe
     from ray_tpu.parallel import MeshSpec, make_mesh
     from ray_tpu.train.step import transformer_train_step
 
@@ -376,7 +379,7 @@ def test_train_step_returns_the_softmax_routing_counters():
                                 with_counters=True)
     params, opt = ts.init(jax.random.key(0))
     toks = np.random.RandomState(0).randint(
-        0, cfg.vocab_size, (2, 33)).astype(np.int32)
+        0, cfg.vocab_size, (4, 65)).astype(np.int32)
     losses = []
     for _ in range(3):
         params, opt, loss, aux = ts.step(params, opt,
@@ -384,6 +387,9 @@ def test_train_step_returns_the_softmax_routing_counters():
         losses.append(float(loss))
         seen = ts.observe_counters(aux)
     assert losses[-1] < losses[0] and np.isfinite(losses).all()
-    assert seen["moe_dropped"] == 0.0 == seen["moe_past_buffer"]
-    # Four expert layers x 64 tokens x 2 a token, half the experts held.
-    assert 0.3 * 512 < seen["moe_assigned"] < 0.7 * 512
+    assert seen["moe_dropped"] == 0.0
+    # Four expert layers x 256 tokens x 2 a token, half the experts held.
+    assert 0.3 * 2048 < seen["moe_assigned"] < 0.7 * 2048
+    # 512 assignments a layer: 2.5 of the held half's even share is them all.
+    assert seen["moe_window_rows"] == moe.held_window_rows(256, 2, 8, 4) == 512
+    assert seen["moe_trips"] == 4 and seen["moe_past_buffer"] == 0

@@ -117,3 +117,202 @@ def test_held_layer_takes_the_routing_it_is_given():
     np.testing.assert_allclose(y.reshape(-1, 16), want, atol=2e-5)
     assert float(cnt["dropped"]) == 0.0
     assert float(cnt["assigned"]) == 48 * 2
+
+
+def _held_case(share, routing, kind, T=256, d=16, E=64, F=8, k=2):
+    """A layer that holds E / share experts, and a routing: `even` (what a
+    random router gives), `all_held` (every assignment on the held range)
+    or `none_held`: every token carries a constant feature that the held
+    experts' router columns weigh by +-30."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    Eh = E // share
+    first = 0 if share == 1 else (E // 4 if share == 4 else 6)
+    ks = jax.random.split(jax.random.key(share), 5)
+    x = jax.random.normal(ks[0], (2, T // 2, d)).at[..., 0].set(1.0)
+    push = {"even": 0.0, "all_held": 30.0, "none_held": -30.0}[routing]
+    rw = (jax.random.normal(ks[1], (d, E)) * 0.3).at[
+        0, first:first + Eh].add(push)
+    wgu = jax.random.normal(ks[2], (Eh, d, 2, F)) * 0.3
+    wd = jax.random.normal(ks[3], (Eh, F, d)) * 0.3
+    wy = jax.random.normal(ks[4], x.shape)
+    if kind == "sigmoid":  # saturated scores tie: the selection bias decides
+        route = functools.partial(
+            moe.sigmoid_route, experts_per_token=k, routed_scale=2.446,
+            bias=jnp.linspace(-0.05, 0.05, E).at[first:first + Eh].add(
+                push / 10))
+    else:
+        route = functools.partial(moe.softmax_route, experts_per_token=k)
+    return (x, rw, wgu, wd), wy, route, first
+
+
+def _held_loop(x, rw, wgu, wd, *, route, first):
+    """The held experts' part as a plain masked loop over them."""
+    import jax.numpy as jnp
+
+    xf = x.reshape(-1, x.shape[-1])
+    idx, wts = route(xf, rw)
+    y = jnp.zeros_like(xf)
+    for e in range(wgu.shape[0]):
+        we = jnp.sum(jnp.where(idx == first + e, wts, 0.0), -1)
+        h = jax.nn.silu(xf @ wgu[e, :, 0]) * (xf @ wgu[e, :, 1])
+        y = y + we[:, None] * (h @ wd[e])
+    return y.reshape(x.shape)
+
+
+def _loss_and_grads(fn, args, wy):
+    import jax.numpy as jnp
+
+    def loss(*a):
+        y, cnt = fn(*a)
+        return jnp.sum(y * wy), (y, cnt)
+
+    (_, (y, cnt)), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+    return y, cnt, grads
+
+
+def _assert_close(got, want, what):
+    scale = max(1.0, float(np.max(np.abs(want))))
+    np.testing.assert_allclose(got, want, atol=3e-5 * scale, err_msg=what)
+
+
+@pytest.mark.parametrize("kind", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("routing", ["even", "all_held", "none_held"])
+@pytest.mark.parametrize("share", [1, 4, 32])
+def test_held_layer_equals_a_masked_loop(share, routing, kind):
+    """Output and the gradients of x, the router and both weight stacks of
+    `moe_ffn_held` are those of a plain masked loop over the held experts,
+    at every held share and however the routing falls on the held range:
+    the window follows the held share, the loop takes the trips the held
+    assignments need and not one more, nothing is dropped. With every
+    expert held the one window is every assignment."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    args, wy, route, first = _held_case(share, routing, kind)
+    T, k, E, Eh = 256, 2, 64, 64 // share
+    with jax.default_matmul_precision("highest"):
+        y, cnt, grads = _loss_and_grads(functools.partial(
+            moe.moe_ffn_held, route=route, held_first=first,
+            dtype=jnp.float32), args, wy)
+        want = _loss_and_grads(
+            lambda *a: (_held_loop(*a, route=route, first=first), {}),
+            args, wy)
+    _assert_close(y, want[0], "output")
+    for name, g, w in zip(("x", "router", "gate_up", "down"), grads, want[2]):
+        _assert_close(g, w, name)
+    rows = moe.held_window_rows(T, k, E, Eh)
+    more = moe.further_window_rows(rows)  # half of it, to 128 rows
+    held = int(cnt["assigned"])
+    assert float(cnt["window_rows"]) == rows
+    assert float(cnt["trips"]) == 1 + min(max(-(-(held - rows) // more), 0),
+                                          -(-(T * k - rows) // more))
+    assert float(cnt["dropped"]) == 0.0
+    assert float(cnt["past_buffer"]) == max(held - rows, 0)
+    if share == 1:
+        assert rows == T * k == held and float(cnt["trips"]) == 1.0
+    else:
+        assert rows < T * k  # the window follows the held share
+        if routing == "all_held":
+            assert held == T * k and float(cnt["trips"]) > 1.0
+        if routing == "none_held":
+            assert held == 0 and not np.any(np.asarray(y))
+
+
+@pytest.mark.parametrize("shape,rows", [
+    ((16384, 8, 64, 16), 81920),   # mellum2's cell: 2.5 of 32,768
+    ((8192, 8, 256, 8), 8192),     # the hybrid's: a row a token, not 5,120
+    ((8192, 8, 256, 256), 65536),  # every expert held: every assignment
+    ((256, 2, 64, 2), 256), ((300, 2, 64, 2), 384), ((64, 1, 8, 4), 64)])
+def test_first_window_rows(shape, rows):
+    """The first window: 2.5 of the held experts' even share, no fewer than
+    a row a token (what one held expert can be given), to 128 rows, at most
+    every assignment; the further ones half of it."""
+    from ray_tpu.ops import moe
+
+    assert moe.held_window_rows(*shape) == rows
+    assert moe.further_window_rows(rows) == min(rows, -(-rows // 256) * 128)
+
+
+@pytest.mark.parametrize("share,routing,factor,T", [
+    (4, "even", None, 256), (4, "all_held", 2.5, 224), (32, "even", None, 256),
+    (32, "none_held", None, 256)])
+def test_rows_in_no_group_may_hold_anything(share, routing, factor, T,
+                                            monkeypatch):
+    """A grouped product leaves the rows past its groups undefined on the
+    TPU (on the CPU they come back zero): with NaN there, in the forward
+    products and in the transposed ones of the backward pass, output and
+    gradients are what they are without. (At factor 2.5 the 448 held
+    assignments of 224 tokens take a window of 384 rows and a quarter of a
+    further one of 256.)"""
+    import functools
+
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    if factor:
+        monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", factor)
+    args, wy, route, first = _held_case(share, routing, "softmax", T=T)
+    fn = functools.partial(moe.moe_ffn_held, route=route, held_first=first,
+                           dtype=jnp.float32)
+    want = _loss_and_grads(fn, args, wy)
+    real, poisoned = jax.lax.ragged_dot, []
+
+    def ragged_dot(lhs, rhs, group_sizes, *a, **kw):
+        out = real(lhs, rhs, group_sizes, *a, **kw)
+        poisoned.append(out.shape)
+        in_a_group = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(in_a_group[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
+    y, cnt, grads = _loss_and_grads(fn, args, wy)
+    assert len(poisoned) >= 4  # both products and both transposed to rows
+    worked = float(cnt["window_rows"]) + (float(cnt["trips"]) - 1) * (
+        moe.further_window_rows(int(cnt["window_rows"])))
+    assert float(cnt["assigned"]) < worked and (
+        float(cnt["trips"]) == (2 if factor else 1))
+    for got, w in zip((y,) + grads, (want[0],) + want[2]):
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, w, atol=1e-6)
+
+
+@pytest.mark.parametrize("routing", ["even", "all_held"])
+def test_grouped_product_kernels_match_ragged_dot(routing, monkeypatch):
+    """The Pallas grouped matmul (the chip's path, here in interpret mode)
+    and `lax.ragged_dot` (the CPU's) give the held layer the same output
+    and gradients on bfloat16 operands: the first window through the
+    kernels and, with every assignment on the held quarter, two further ones
+    (which stay `ragged_dot`'s) behind it."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", 1.25)  # 256 + 2 x 128 rows
+    args, wy, route, first = _held_case(4, routing, "softmax", d=128, E=16,
+                                        F=128)
+    args = (args[0].astype(jnp.bfloat16),) + args[1:]
+    fn = functools.partial(moe.moe_ffn_held, route=route, held_first=first)
+    want = _loss_and_grads(fn, args, wy)
+    assert not moe.use_kernels("cpu", jnp.bfloat16, (128, 128), False)
+    assert not moe.use_kernels("tpu", jnp.bfloat16, (128, 128), True)
+    assert not moe.use_kernels("tpu", jnp.float32, (128, 128), False)
+    assert not moe.use_kernels("tpu", jnp.bfloat16, (128, 96), False)
+    assert moe.use_kernels("tpu", jnp.bfloat16, (128, 128), False)
+    monkeypatch.setattr(moe, "use_kernels", lambda *a: True)
+    y, cnt, grads = _loss_and_grads(fn, args, wy)
+    assert float(cnt["trips"]) == (1 if routing == "even" else 3)
+    for got, w in zip((y,) + grads, (want[0],) + want[2]):
+        got, w = np.asarray(got, np.float32), np.asarray(w, np.float32)
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, w, atol=2e-2 * np.max(np.abs(w)))
