@@ -1400,6 +1400,12 @@ class Controller:
             proc = self._spawned_procs.pop(token, None)
             if proc is not None:
                 w.proc = proc
+                # The controller's view of one start, beside the worker's
+                # boot.* phases on the same clock.
+                t0 = proc.spawned_ns  # _launch_worker's stamp
+                tracing.observe(
+                    "ctrl.worker_spawn", time.monotonic_ns() - t0, t0,
+                    pid=proc.pid, tpu=w.tpu_capable)
                 if w.tpu_capable:
                     self._chip_procs.add(proc)
             else:
@@ -6240,10 +6246,7 @@ class Controller:
                     runtime_env, [sys.executable, "-m",
                                   "ray_tpu.core.worker_main"])
                 try:
-                    proc = subprocess.Popen(
-                        cmd, env=env,
-                        stdout=self._worker_log_file(spawn_token),
-                        stderr=subprocess.STDOUT)
+                    proc = self._launch_worker(cmd, env, spawn_token)
                 except OSError as e:
                     node.spawning = max(0, node.spawning - 1)
                     self._release_env_spawn(node, spawn_token)
@@ -6283,10 +6286,9 @@ class Controller:
                     self._fail_env_tasks(runtime_env.get("hash", ""), e)
                     self._wake_scheduler()
                     return
-                proc = subprocess.Popen(
-                    [python, "-m", "ray_tpu.core.worker_main"], env=env,
-                    stdout=self._worker_log_file(spawn_token),
-                    stderr=subprocess.STDOUT)
+                proc = self._launch_worker(
+                    [python, "-m", "ray_tpu.core.worker_main"], env,
+                    spawn_token)
                 self._spawned_procs[spawn_token] = proc
                 asyncio.get_running_loop().create_task(
                     self._watch_spawn(node.node_id, spawn_token, proc))
@@ -6294,12 +6296,9 @@ class Controller:
             asyncio.get_running_loop().create_task(_spawn_with_venv())
             return True
         try:
-            proc = subprocess.Popen(
-                [sys.executable, "-m", "ray_tpu.core.worker_main"],
-                env=env,
-                stdout=self._worker_log_file(spawn_token),
-                stderr=subprocess.STDOUT,
-            )
+            proc = self._launch_worker(
+                [sys.executable, "-m", "ray_tpu.core.worker_main"], env,
+                spawn_token)
         except OSError:
             # Unwind: a failed launch must not leak the carved-out chips
             # or the spawning counters.
@@ -6315,6 +6314,17 @@ class Controller:
         # _h_register); this task only reaps processes that die pre-register.
         asyncio.get_running_loop().create_task(self._watch_spawn(node.node_id, spawn_token, proc))
         return True
+
+    def _launch_worker(self, cmd: List[str], env: Dict[str, str],
+                       spawn_token: str) -> subprocess.Popen:
+        """Start a worker process; `spawned_ns` on the handle is where
+        `ctrl.worker_spawn` (to that worker's registration) starts."""
+        t0 = time.monotonic_ns()  # before the fork: it holds the process's start
+        proc = subprocess.Popen(
+            cmd, env=env, stdout=self._worker_log_file(spawn_token),
+            stderr=subprocess.STDOUT)
+        proc.spawned_ns = t0
+        return proc
 
     def _free_spawn_chips(self, node: Optional[NodeInfo],
                           spawn_token: str) -> None:

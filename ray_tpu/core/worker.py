@@ -479,6 +479,7 @@ class WorkerRuntime:
             self._install_log_forwarder()
         self._env_hash = env_hash
         self.client.request(self._register_msg())
+        tracing.send_unsent()  # boot.*: slow phases from before the context
 
         # Controller-connection watch: a dropped connection first enters
         # the client's capped-backoff reconnect loop (the controller may
@@ -1786,9 +1787,11 @@ class WorkerRuntime:
                                 if mb.replay or c.startswith("__dag__")}
                     restored_epoch = mb.ckpt_epoch
                 else:
-                    cls = self._load_function(spec["func_id"])
-                    args, kwargs = self._resolve_args(spec)
-                    mb.instance = cls(*args, **kwargs)
+                    with tracing.phase("boot.actor_init") as boot:
+                        cls = self._load_function(spec["func_id"])
+                        boot.attrs["cls"] = getattr(cls, "__name__", "")
+                        args, kwargs = self._resolve_args(spec)
+                        mb.instance = cls(*args, **kwargs)
                 ready: Dict[str, Any] = {"kind": "actor_ready",
                                          "actor_id": actor_id}
                 if restored_epoch is not None:

@@ -294,9 +294,10 @@ class ContinuousBatchingEngine:
         import os
 
         from ray_tpu import flags
+        from ray_tpu.util import jaxenv
 
         jax, jnp = self._jax, self._jnp
-        devs = jax.local_devices()
+        devs = jaxenv.devices(local=True)
 
         def homes(tree) -> List[str]:
             return sorted({str(d) for x in jax.tree.leaves(tree)

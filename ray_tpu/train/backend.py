@@ -115,13 +115,14 @@ class JaxBackend(Backend):
         shape = self.mesh_shape
 
         def build_mesh() -> None:
-            import jax
+            from ray_tpu.util import jaxenv, tracing
 
+            devs = jaxenv.devices()  # runtime.import_jax, .backend_init
             from ray_tpu.parallel import MeshSpec, best_effort_spec, make_mesh
 
-            devs = jax.devices()
             spec = MeshSpec(**shape) if shape else best_effort_spec(len(devs))
-            mesh = make_mesh(spec, devices=devs)
+            with tracing.phase("runtime.mesh"):
+                mesh = make_mesh(spec, devices=devs)
             _get_session().mesh = mesh
 
         import ray_tpu as rt

@@ -307,7 +307,12 @@ _tables: List[tuple] = []         # (weakref to thread, its table), live threads
 _retired: Dict[str, list] = {}    # rows of threads that have ended
 _slow: "collections.deque" = collections.deque(maxlen=SLOW_RING)
 _anchored = False         # a clock_anchor was emitted in the current session
-_slow_event_budget = [float(_SLOW_EVENTS_PER_10S), 0.0]  # tokens, refilled at
+# tokens, refilled at, events the limit has dropped since the last one sent
+_slow_event_budget = [float(_SLOW_EVENTS_PER_10S), 0.0, 0]
+_UNSENT_MAX = 64
+# Slow phases from before this process had a worker context (a worker's
+# boot): held until send_unsent(), which sets this to None.
+_unsent: Optional[List[tuple]] = []
 
 
 def _annotation():
@@ -393,36 +398,57 @@ def _fold(name: str, start_ns: int, dur_ns: int,
 def _slow_event(name: str, start_ns: int, dur_ns: int, attrs) -> None:
     """A slow phase of a worker process also becomes a cluster event (rare by
     construction, and rate-limited here), which is how `rtpu events` and the
-    controller's process see a worker's stalls. The controller's own process
-    already holds its phases in this table."""
+    controller's process see a worker's stalls. What the limit drops is
+    counted, and the next event that passes carries the count
+    (``dropped_before``), so a reader knows whether a sum is whole. The
+    controller's own process already holds its phases in this table."""
     if name.startswith("ctrl."):
         return
     try:
         from ray_tpu.core import context as ctx
         from ray_tpu.core import events
 
-        if not (ctx.is_initialized() and events.enabled()
+        if not ctx.is_initialized():
+            # a worker that has not registered yet: kept for send_unsent()
+            if _unsent is not None and len(_unsent) < _UNSENT_MAX:
+                _unsent.append((name, start_ns, dur_ns, attrs))
+            return
+        if not (events.enabled()
                 and ctx.get_worker_context().role == "worker"):
             return  # a driver's phases stay in its own table
         now = time.monotonic()
         with _phase_lock:
-            tokens, at = _slow_event_budget
+            tokens, at, dropped = _slow_event_budget
             tokens = min(float(_SLOW_EVENTS_PER_10S), tokens + (now - at)
                          * _SLOW_EVENTS_PER_10S / 10.0)
             if tokens < 1.0:
-                _slow_event_budget[:] = [tokens, now]
+                _slow_event_budget[:] = [tokens, now, dropped + 1]
                 return
-            _slow_event_budget[:] = [tokens - 1.0, now]
+            _slow_event_budget[:] = [tokens - 1.0, now, 0]
+        data = {"name": name, "start_monotonic_ns": start_ns,
+                "dur_ns": dur_ns, "pid": os.getpid(),
+                "attrs": {k: v for k, v in (attrs or {}).items()
+                          if isinstance(v, (int, float, str, bool))}}
+        if dropped:
+            data["dropped_before"] = dropped
         events.emit(
             "INFO", "SLOW_PHASE",
             f"{name} took {dur_ns / 1e6:.1f} ms", source="tracing",
             worker_id=ctx.get_worker_context().extra.get("worker_id"),
-            data={"name": name, "start_monotonic_ns": start_ns,
-                  "dur_ns": dur_ns, "pid": os.getpid(),
-                  "attrs": {k: v for k, v in (attrs or {}).items()
-                            if isinstance(v, (int, float, str, bool))}})
+            data=data)
     except Exception:
         pass  # a phase must never break the work it times
+
+
+def send_unsent() -> None:
+    """Once, right after a worker has registered: the slow phases that ended
+    before the process had a worker context (``boot.interpreter``,
+    ``boot.imports``) go out as the cluster events they could not be then,
+    oldest first, through the same rate limit."""
+    global _unsent
+    held, _unsent = _unsent or [], None
+    for entry in held:
+        _slow_event(*entry)
 
 
 def ingest_slow_event(ev: Dict[str, Any]) -> None:
@@ -434,6 +460,8 @@ def ingest_slow_event(ev: Dict[str, Any]) -> None:
         return
     attrs = dict(d.get("attrs") or {}, pid=d.get("pid"),
                  worker_id=ev.get("worker_id"))
+    if d.get("dropped_before"):
+        attrs["dropped_before"] = int(d["dropped_before"])
     _slow.append((d["name"], int(d.get("start_monotonic_ns", 0)),
                   int(d.get("dur_ns", 0)), attrs))
 

@@ -368,3 +368,333 @@ def test_worker_slow_phase_reaches_the_controllers_ring(ray_start_regular):
     assert p["attrs"]["slot"] == 4 and p["dur_ns"] >= 55_000_000
     evs = state.list_events(kind="SLOW_PHASE")
     assert any(e["data"]["name"] == "t.worker.slow" for e in evs)
+
+
+# ------------------------------------- set-up: xla.*, runtime.*, boot.* (PR 35)
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _by_fun(fun):
+    """Slow-ring entries of the xla.* phases that name `fun`."""
+    return [p for p in tracing.slow_phases() if p["name"].startswith("xla.")
+            and p["attrs"].get("fun_name") in (fun, f"jit({fun})")]
+
+
+@pytest.fixture
+def watched(monkeypatch):
+    """The listeners on, and every phase long enough for the slow ring, so
+    that a test reads names and attributes there."""
+    from ray_tpu.util import jaxenv
+
+    jaxenv.watch_compiles()
+    monkeypatch.setattr(tracing, "SLOW_NS", 0)
+    return jaxenv
+
+
+def test_first_call_folds_into_trace_lower_and_compile_second_into_nothing(
+        watched):
+    import jax
+    import jax.numpy as jnp
+
+    def p35_first_call(x):
+        return jnp.tanh(x) * 3
+
+    f = jax.jit(p35_first_call)
+    before = time.monotonic_ns()
+    f(jnp.ones((4, 4))).block_until_ready()
+    after = time.monotonic_ns()
+    got = _by_fun("p35_first_call")
+    names = [p["name"] for p in got]
+    assert names[:2] == ["xla.trace", "xla.lower"], names
+    assert names[2:] in (["xla.compile"], ["xla.cache_read"]), names
+    assert got[0]["attrs"]["fun_name"] == "p35_first_call"
+    assert got[1]["attrs"]["fun_name"] == "jit(p35_first_call)"
+    assert got[2]["attrs"]["cache"] in ("hit", "miss", "off")
+    for p in got:   # where the work was, on the one clock
+        assert before - 5e6 <= p["start_monotonic_ns"]
+        assert p["start_monotonic_ns"] + p["dur_ns"] <= after + 5e6
+    starts = [p["start_monotonic_ns"] for p in got]
+    assert starts == sorted(starts)
+    rows = {n: _row(n)["count"] for n in ("xla.trace", "xla.lower",
+                                          "xla.compile", "xla.cache_read")}
+    f(jnp.ones((4, 4))).block_until_ready()   # compiled: nothing
+    assert len(_by_fun("p35_first_call")) == 3
+    assert rows == {n: _row(n)["count"] for n in rows}
+
+
+@pytest.mark.parametrize("how,want", [
+    ("hit", [("xla.cache_read", "hit")]),
+    ("miss", [("xla.compile", "miss")]),
+    ("off", [("xla.compile", "off")])])
+def test_cache_read_is_named_after_its_program_and_a_hit_compiles_nothing(
+        watched, how, want):
+    """The read carries no name: it arrives on the compiling thread just
+    before its program's compile span, which names it. A hit is the read
+    alone; a compile says whether a cache was asked at all."""
+    import jax.monitoring as mon
+
+    fun = f"jit(p35_cache_{how})"
+    t1 = time.time()
+    t0 = t1 - 0.5
+    if how != "off":
+        mon.record_event(_ASKED)
+    if how == "hit":
+        mon.record_event_duration_secs(_READ, 0.2)
+    now = time.monotonic_ns()
+    mon.record_event_time_span(_COMPILE, t0, t1, fun_name=fun)
+    got = _by_fun(f"p35_cache_{how}")
+    assert [(p["name"], p["attrs"]["cache"]) for p in got] == want
+    (p,) = got
+    if how == "hit":
+        assert p["dur_ns"] == 200_000_000
+        assert abs(p["start_monotonic_ns"] + p["dur_ns"] - now) < 50e6
+        assert abs(p["attrs"]["span_ns"] - 500_000_000) < 1000
+    else:
+        assert abs(p["dur_ns"] - 500_000_000) < 1000
+        assert abs(p["start_monotonic_ns"] + p["dur_ns"] - now) < 50e6
+    # the read belonged to that program only: the next compile is its own
+    mon.record_event_time_span(_COMPILE, t0, t1,
+                               fun_name=f"jit(p35_next_{how})")
+    (nxt,) = _by_fun(f"p35_next_{how}")
+    assert (nxt["name"], nxt["attrs"]["cache"]) == ("xla.compile", "off")
+
+
+@pytest.mark.parametrize("bad", [
+    lambda m: m.record_event_time_span(_TRACE, "then", None, fun_name=7),
+    lambda m: m.record_event_time_span(_COMPILE, None, 3.0),
+    lambda m: m.record_event_duration_secs(_READ, "long"),
+    lambda m: m.record_event(None, what=object()),
+    lambda m: m.record_event_time_span(_LOWER, 1.0, float("nan"))])
+def test_a_malformed_event_raises_nothing_into_jax(watched, bad):
+    import jax
+    import jax.monitoring as mon
+
+    bad(mon)   # jax.monitoring itself guards nothing: a raise lands in jit
+    assert int(jax.jit(lambda x: x + 2)(1)) == 3
+
+
+def test_watch_compiles_twice_registers_once(watched):
+    import jax.monitoring as mon
+
+    watched.watch_compiles()
+    watched.watch_compiles()
+    n = _row("xla.trace")["count"]
+    t = time.time()
+    mon.record_event_time_span(_TRACE, t - 0.001, t, fun_name="p35_once")
+    assert _row("xla.trace")["count"] == n + 1
+    assert len(_by_fun("p35_once")) == 1
+
+
+def test_nested_spans_are_counted_once_through_self_ns(monkeypatch):
+    """JAX reports a nested trace inside its parent's span, the child
+    first. A parent carries `self_ns`, its time less that of the spans
+    inside it that are long enough for the slow ring; shorter ones stay in
+    its own time, so sums of `self_ns` over the ring count an instant once."""
+    import jax.monitoring as mon
+
+    from ray_tpu.util import jaxenv
+
+    jaxenv.watch_compiles()
+    t = time.time()
+    span = lambda a, b, fun, ev=_TRACE: mon.record_event_time_span(  # noqa
+        ev, t - a, t - b, fun_name=fun)
+    span(1.2, 1.1, "p35n_before")       # ends before the parent starts
+    span(0.90, 0.80, "p35n_child_a")    # 100 ms, inside
+    span(0.70, 0.69, "p35n_short")      # 10 ms: not in the ring
+    span(0.55, 0.45, "p35n_grandchild")  # 100 ms, inside child_b
+    span(0.60, 0.40, "p35n_child_b")    # 200 ms, inside the parent
+    span(0.30, 0.20, "jit(p35n_inner)", _COMPILE)   # a compile a trace needed
+    span(1.00, 0.10, "p35n_parent")
+    by = {p["attrs"]["fun_name"]: p for p in tracing.slow_phases()
+          if "p35n_" in str(p["attrs"].get("fun_name", ""))}
+    assert "p35n_short" not in by
+    assert "self_ns" not in by["p35n_child_a"]["attrs"]
+    assert abs(by["p35n_child_b"]["attrs"]["self_ns"] - 100_000_000) < 3000
+    parent = by["p35n_parent"]
+    assert abs(parent["dur_ns"] - 900_000_000) < 1000
+    assert abs(parent["attrs"]["self_ns"] - 500_000_000) < 3000
+    own = sum(p["attrs"].get("self_ns", p["dur_ns"]) for n, p in by.items()
+              if n != "p35n_before")
+    assert abs(own - parent["dur_ns"]) < 5000   # the union, counted once
+    span(2.00, 0.05, "p35n_grand")   # later, around all of it
+    grand = [p for p in tracing.slow_phases()
+             if p["attrs"].get("fun_name") == "p35n_grand"][-1]
+    assert abs(grand["attrs"]["self_ns"] - 950_000_000) < 5000
+
+
+def test_a_real_nested_jit_is_reported_inside_its_parent(watched):
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def p35_in(x):
+        return jnp.sin(x) * 2
+
+    @jax.jit
+    def p35_out(x):
+        return p35_in(x) + p35_in(x * 3)
+
+    p35_out(jnp.ones((8,))).block_until_ready()
+    (outer,) = [p for p in _by_fun("p35_out") if p["name"] == "xla.trace"]
+    inner = [p for p in _by_fun("p35_in") if p["name"] == "xla.trace"]
+    assert inner and all(
+        outer["start_monotonic_ns"] <= p["start_monotonic_ns"]
+        and p["start_monotonic_ns"] + p["dur_ns"]
+        <= outer["start_monotonic_ns"] + outer["dur_ns"] + 1000
+        for p in inner)   # whole durations double-count ...
+    assert outer["attrs"]["self_ns"] <= outer["dur_ns"] - sum(
+        p["dur_ns"] for p in inner) + 1000   # ... self_ns does not
+
+
+def test_devices_tells_the_runtimes_start_once():
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import jaxenv, tracing\n"
+        "assert 'jax' not in sys.modules\n"
+        "devs = jaxenv.devices()\n"
+        "t = tracing.phase_table()\n"
+        "assert t['runtime.import_jax']['count'] == 1, t\n"
+        "assert t['runtime.backend_init']['count'] == 1, t\n"
+        "slow = {p['name']: p for p in tracing.slow_phases()}\n"
+        "assert slow['runtime.import_jax']['dur_ns'] >= tracing.SLOW_NS\n"
+        "assert len(jaxenv.devices(local=True)) == len(devs)\n"
+        "t = tracing.phase_table()\n"
+        "assert t['runtime.import_jax']['count'] == 1\n"
+        "assert t['runtime.backend_init']['count'] == 1\n"
+        "import jax\n"
+        "jax.jit(lambda x: x + 1)(1)\n"
+        "assert tracing.phase_table()['xla.trace']['count'] >= 1\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=180)
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
+def test_backend_init_carries_platform_and_count(monkeypatch):
+    import jax
+
+    from ray_tpu.util import jaxenv
+
+    monkeypatch.setattr(jaxenv, "_listed", False)
+    monkeypatch.setattr(tracing, "SLOW_NS", 0)
+    devs = jaxenv.devices()
+    assert devs == jax.devices()
+    p = _slow("runtime.backend_init")[-1]
+    assert p["attrs"] == {"platform": devs[0].platform, "count": len(devs)}
+
+
+class _Worker:
+    role = "worker"
+    extra = {"worker_id": "w35"}
+
+
+def _as_worker(monkeypatch, initialized=True):
+    """This process as a registered worker whose cluster events land in a
+    list."""
+    from ray_tpu.core import context as ctx
+    from ray_tpu.core import events
+
+    sent = []
+    monkeypatch.setattr(ctx, "is_initialized", lambda: initialized)
+    monkeypatch.setattr(ctx, "get_worker_context", lambda: _Worker)
+    monkeypatch.setattr(events, "enabled", lambda: True)
+    monkeypatch.setattr(events, "emit", lambda *a, **kw: sent.append(kw))
+    monkeypatch.setattr(tracing, "_slow_event_budget", [
+        float(tracing._SLOW_EVENTS_PER_10S), time.monotonic(), 0])
+    return sent
+
+
+def test_dropped_before_counts_what_the_rate_limit_dropped(monkeypatch):
+    sent = _as_worker(monkeypatch)
+    n = tracing._SLOW_EVENTS_PER_10S + 9
+    for i in range(n):
+        tracing.observe("t.flood", tracing.SLOW_NS, fun_name="f", i=i)
+    assert len(sent) == tracing._SLOW_EVENTS_PER_10S   # the bucket, then dry
+    assert all("dropped_before" not in e["data"] for e in sent)
+    assert sent[0]["data"]["attrs"] == {"fun_name": "f", "i": 0}
+    tracing._slow_event_budget[1] -= 1.0   # a second later: 3.2 tokens
+    tracing.observe("t.flood", tracing.SLOW_NS)
+    tracing.observe("t.flood", tracing.SLOW_NS)
+    assert sent[-2]["data"]["dropped_before"] == 9
+    assert "dropped_before" not in sent[-1]["data"]   # told once
+    # the controller's side keeps the count on the ring's entry
+    tracing.ingest_slow_event({"worker_id": "w35", "data": dict(
+        sent[-2]["data"], pid=1)})
+    assert tracing.slow_phases()[-1]["attrs"]["dropped_before"] == 9
+
+
+def test_phases_from_before_the_context_are_sent_once_after_it(monkeypatch):
+    from ray_tpu.core import context as ctx
+
+    sent = _as_worker(monkeypatch, initialized=False)
+    monkeypatch.setattr(tracing, "_unsent", [])
+    tracing.observe("boot.interpreter", tracing.SLOW_NS, start_ns=10)
+    tracing.observe("boot.imports", tracing.SLOW_NS + 1, start_ns=20)
+    tracing.observe("t.short", 5)
+    assert sent == []   # no worker context yet: held, not lost
+    monkeypatch.setattr(ctx, "is_initialized", lambda: True)
+    tracing.send_unsent()
+    assert [e["data"]["name"] for e in sent] == [
+        "boot.interpreter", "boot.imports"]
+    assert [e["data"]["start_monotonic_ns"] for e in sent] == [10, 20]
+    tracing.send_unsent()   # once
+    assert len(sent) == 2 and tracing._unsent is None
+    tracing.observe("boot.connect", tracing.SLOW_NS)   # now sent as it ends
+    assert sent[-1]["data"]["name"] == "boot.connect"
+
+
+def test_a_spawned_worker_tells_its_boot_in_order(ray_start_regular):
+    """boot.interpreter, boot.imports, boot.connect in a worker's own
+    table; those of 50 ms or more also in the controller's ring (sent after
+    registration), beside the controller's `ctrl.worker_spawn` of the same
+    pid; an actor's constructor under `boot.actor_init`. No wall-clock
+    limit: presence and the order of starts."""
+    import os
+
+    import ray_tpu
+    from ray_tpu.core import events
+    from ray_tpu.util import state
+
+    @ray_tpu.remote
+    class P35Slow:
+        def __init__(self):
+            time.sleep(0.06)
+
+        def pid(self):
+            events.flush_events()
+            return os.getpid()
+
+    a = P35Slow.remote()
+    pid = ray_tpu.get(a.pid.remote())
+    out = state.phase_table()
+    dump = next(w for w in out["workers"].values()
+                if "boot.actor_init" in w.get("table", {}))
+    for name in ("boot.interpreter", "boot.imports", "boot.connect"):
+        assert dump["table"][name]["count"] == 1, dump["table"].keys()
+    order = ["boot.interpreter", "boot.imports", "boot.connect",
+             "boot.actor_init"]
+    starts = {p["name"]: p["start_monotonic_ns"] for p in dump["slow"]
+              if p["name"] in order}
+    assert "boot.interpreter" in starts and "boot.actor_init" in starts
+    seen = [n for n in order if n in starts]
+    assert [starts[n] for n in seen] == sorted(starts[n] for n in seen)
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline and not [
+            p for p in _slow("boot.actor_init")
+            if p["attrs"].get("pid") == pid]:
+        time.sleep(0.05)
+    ring = [p for p in tracing.slow_phases() if p["attrs"].get("pid") == pid]
+    boot = {p["name"]: p for p in ring if not p["name"].startswith("ctrl.")}
+    assert boot["boot.actor_init"]["attrs"]["cls"] == "P35Slow"
+    assert boot["boot.interpreter"]["start_monotonic_ns"] \
+        == starts["boot.interpreter"]
+    (spawn,) = [p for p in ring if p["name"] == "ctrl.worker_spawn"]
+    # the controller's view of the same start: Popen .. registration holds
+    # the worker's interpreter start
+    assert spawn["start_monotonic_ns"] - 20e6 \
+        <= starts["boot.interpreter"] \
+        <= spawn["start_monotonic_ns"] + spawn["dur_ns"]
