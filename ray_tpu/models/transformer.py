@@ -30,6 +30,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention
+from ray_tpu.parallel import tensor_overlap as tp
 from ray_tpu.parallel.sharding import maybe_constrain
 
 Params = Dict[str, Any]
@@ -686,19 +687,29 @@ def _w(layer: Params, name: str, cfg: TransformerConfig) -> jax.Array:
 
 
 def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
-              positions: jax.Array, mixer: str = "attn"):
+              positions: jax.Array, mixer: str = "attn",
+              overlap: Optional[tp.Overlap] = None):
     """Projection + rope shared by training forward and KV-cache decode
     (models/generate.py) — ONE home for the layer's q/k/v convention. An
     "attn" layer's rotation takes cfg's YaRN scaling, a "swa" layer's never
-    does."""
+    does. `overlap` (a layer body whose residual's rows are cut over
+    `tensor`): h's rows are gathered behind the products."""
+    eqs = ({"wqkv": "bsd,dcnh->bscnh"} if "wqkv" in layer else
+           {"wq": "bsd,dnh->bsnh", "wkv": "bsd,dcnh->bscnh"})
+    outs = None
+    if overlap is not None:
+        axes = _layer_shapes(cfg, (mixer, "dense"))
+        outs = overlap.gather_matmul(
+            "qkv", h, [(eq, _w(layer, n, cfg), axes[n][1])
+                       for n, eq in eqs.items()])
+    if outs is None:
+        outs = [jnp.einsum(eq, h, _w(layer, n, cfg)) for n, eq in eqs.items()]
     if "wqkv" in layer:
-        qkv = jnp.einsum("bsd,dcnh->bscnh", h, _w(layer, "wqkv", cfg))
-        qkv = checkpoint_name(qkv, "qkv_proj")
+        qkv = checkpoint_name(outs[0], "qkv_proj")
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
     else:
-        q = jnp.einsum("bsd,dnh->bsnh", h, _w(layer, "wq", cfg))
-        kv = jnp.einsum("bsd,dcnh->bscnh", h, _w(layer, "wkv", cfg))
-        kv = checkpoint_name(kv, "qkv_proj")
+        q = outs[0]
+        kv = checkpoint_name(outs[1], "qkv_proj")
         k, v = kv[:, :, 0], kv[:, :, 1]
     if cfg.positional == "rope":
         yarn = cfg.rope_yarn if mixer == "attn" else None
@@ -708,21 +719,35 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
 
 
 def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
-               layer: Params):
+               layer: Params, overlap: Optional[tp.Overlap] = None):
     """Post-mixer feed-forward of kind `ffn`, shared with the decode path ->
     (delta, extras): {} for the dense MLP (SwiGLU where the layer has a
     fused gate/up leaf, else GELU), {"aux": balancing loss} for GShard
     experts, the routing counters of ops/moe.py `moe_ffn_held` for a held
-    range of them (sigmoid- or softmax-routed)."""
+    range of them (sigmoid- or softmax-routed). `overlap` (as `_qkv_proj`;
+    the dense MLP only): h and the delta have their rows cut over `tensor`,
+    gathered and scattered behind the two products."""
     if ffn == "dense":
         if "w_gate_up" in layer:
-            gu = jnp.einsum("bsd,dcf->bscf", h, _w(layer, "w_gate_up", cfg))
-            gu = checkpoint_name(gu, "gate_up")
-            act = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
+            w_in, eq = "w_gate_up", "bsd,dcf->bscf"
+
+            def act(gu):
+                gu = checkpoint_name(gu, "gate_up")
+                return jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
         else:
-            act = checkpoint_name(h @ _w(layer, "w_up", cfg), "gate_up")
-            act = jax.nn.gelu(act)
-        return act @ _w(layer, "w_down", cfg), {}
+            w_in, eq = "w_up", "bsd,df->bsf"
+            act = lambda u: jax.nn.gelu(checkpoint_name(u, "gate_up"))
+        delta = None
+        if overlap is not None:
+            axes = _layer_shapes(cfg, ("attn", "dense"))
+            delta = overlap.gathered_mlp(
+                ("gate_up", "w_down"), h, eq, _w(layer, w_in, cfg),
+                axes[w_in][1], act, _w(layer, "w_down", cfg),
+                axes["w_down"][1])
+        if delta is None:
+            delta = act(jnp.einsum(eq, h, _w(layer, w_in, cfg))
+                        ) @ _w(layer, "w_down", cfg)
+        return delta, {}
     if not cfg.moe_holds_range:
         from ray_tpu.ops.moe import moe_ffn
 
@@ -821,6 +846,11 @@ def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg))
 
 
+def _whole(x: jax.Array, overlap: Optional[tp.Overlap]) -> jax.Array:
+    """x with its rows gathered where a plan has cut them over `tensor`."""
+    return x if overlap is None else maybe_constrain(x, tp.ACTIVATION)
+
+
 def _scaled(x: jax.Array, scale: float) -> jax.Array:
     """x * scale in x's dtype; at 1.0 x itself, so that nothing is traced
     for a configuration without the scalar."""
@@ -835,20 +865,28 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     primes its cache."""
     mixer, ffn = kind
     B, S, d = x.shape
+    # Under a mesh with a `tensor` axis the residual's rows are cut over it
+    # (parallel/tensor_overlap.py): norms and adds work a rank's rows, the
+    # "attn" / "swa" projections and the dense MLP gather and scatter them
+    # behind their products, any other mixer or feed-forward gets them
+    # gathered (`whole`) and leaves its sum over `tensor` to the partitioner.
+    overlap = tp.plan(B, S)
+    residual = tp.RESIDUAL if overlap else tp.ACTIVATION
+    whole = functools.partial(_whole, overlap=overlap)
     h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
               cfg.norm_eps)
     k = v = None
     if mixer == "kda":
         with jax.named_scope("kda"):
-            delta = _kda_mixer(cfg, h, layer)
+            delta = _kda_mixer(cfg, whole(h), layer)
     elif mixer == "mla":
         with jax.named_scope("mla"):
-            delta = _mla_mixer(cfg, h, layer)
+            delta = _mla_mixer(cfg, whole(h), layer)
     elif mixer == "mamba2":
         with jax.named_scope("mamba"):
-            delta = _mamba_mixer(cfg, h, layer)
+            delta = _mamba_mixer(cfg, whole(h), layer)
     else:
-        q, k, v = _qkv_proj(cfg, h, layer, positions, mixer)
+        q, k, v = _qkv_proj(cfg, h, layer, positions, mixer, overlap)
         q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
         attend = functools.partial(attention, q, k, v, causal=True,
                                    scale=cfg.attn_scale)
@@ -857,14 +895,21 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
                 o = attend(window=cfg.sliding_window)
         else:
             o = attend()
-        delta = o.reshape(B, S, -1) @ _w(layer, "wo", cfg)
-    x = maybe_constrain(x + _scaled(delta, cfg.residual_scale),
-                        ("batch", "seq_act", "embed"))
+        o, wo, delta = o.reshape(B, S, -1), _w(layer, "wo", cfg), None
+        if overlap is not None:
+            delta = overlap.matmul_scatter(
+                "wo", o, "bsf,fd->bsd", wo,
+                _layer_shapes(cfg, kind)["wo"][1])
+        if delta is None:
+            delta = o @ wo
+    x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
     h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
               cfg.norm_eps)
-    delta, extras = _mlp_block(cfg, ffn, h, layer)
-    x = maybe_constrain(x + _scaled(delta, cfg.residual_scale),
-                        ("batch", "seq_act", "embed"))
+    delta, extras = _mlp_block(cfg, ffn, h if ffn == "dense" else whole(h),
+                               layer, overlap)
+    x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
+    if overlap is not None:
+        overlap.observe()
     if return_kv:
         return x, extras, k, v
     return x, extras
@@ -886,6 +931,13 @@ def embed_tokens(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> j
     x = maybe_constrain(x, ("batch", "seq_act", "embed"))
     if cfg.positional == "learned":
         x = x + params["pos_embed"].astype(cfg.dtype)[:S][None]
+    if tp.plan(B, S) is not None:
+        # The table is looked up for all of a rank's tokens and the rank's
+        # rows cut from the result: the way back is a gather of dx, where a
+        # lookup of a rank's rows alone would leave the table's gradient to
+        # be summed over `tensor`. (Two constraints: the first keeps the
+        # adds above on all the rows, and a position table's rows at home.)
+        x = maybe_constrain(maybe_constrain(x, tp.ACTIVATION), tp.RESIDUAL)
     return x
 
 
@@ -901,10 +953,11 @@ def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
         return jax.checkpoint(body)
     from ray_tpu.ops import flash_attention as fa, kda, moe, ssd
 
-    # What no dot makes: the kernels' own residuals and the held experts'
-    # grouped products (`ragged_dot` is no `dot_general`).
+    # What no dot makes: the kernels' own residuals, the held experts'
+    # grouped products (`ragged_dot` is no `dot_general`) and the products
+    # inside a ring over `tensor` (a `custom_vjp` hides its dots).
     names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
-             + moe.RESIDUAL_NAMES)
+             + moe.RESIDUAL_NAMES + tp.RESIDUAL_NAMES)
     return jax.checkpoint(
         body,
         policy=jax.checkpoint_policies.save_from_both_policies(
@@ -968,9 +1021,11 @@ def final_hidden_and_head(
 ) -> Tuple[jax.Array, jax.Array]:
     """THE head-weight convention (final norm + tied-or-separate head),
     shared by the unfused lm_head and the fused-CE loss path so the two
-    can never drift."""
+    can never drift. The norm works the rows a rank holds of the residual
+    (parallel/tensor_overlap.py); they are gathered before the head."""
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm,
               cfg.norm_eps)
+    x = _whole(x, tp.plan(*x.shape[:2]))
     head = params.get("lm_head", None)
     if head is None:
         head = params["embed"].T

@@ -98,8 +98,11 @@ def pipeline_apply(
     # Ring attention is a shard_map over `seq` and cannot nest inside the
     # vmapped stage body; dropping the seq_act routing makes attention()
     # use the dense per-stage kernel (context parallelism composes with
-    # pipe at the batch level instead).
-    inner_rules = {k: v for k, v in rules.items() if k != "seq_act"}
+    # pipe at the batch level instead). Without `seq_res` the stage's
+    # residual keeps its rows whole over `tensor` too (the decomposed
+    # products of parallel/tensor_overlap.py are `shard_map`s as well).
+    inner_rules = {k: v for k, v in rules.items()
+                   if k not in ("seq_act", "seq_res")}
 
     def stage_apply(stage_layers, h):
         with shd.sharding_ctx(mesh, inner_rules):
@@ -161,12 +164,19 @@ def pipeline_loss_fn(cfg, mesh: Mesh, *, rules=None, num_microbatches: int = 4,
     from ray_tpu.models import transformer as tfm
 
     rules = rules or shd.DEFAULT_RULES
+    # The embedding and the head on the stages' layout: rows whole over
+    # `tensor` (see `pipeline_apply`).
+    rules = {k: v for k, v in rules.items() if k != "seq_res"}
     M = num_microbatches
     # Refuse another stack here, not at trace time.
     tfm.one_kind_stack(tfm.param_logical_specs(cfg), cfg,
                        "pipeline parallelism")
 
     def loss_fn(params, batch):
+        with shd.sharding_ctx(mesh, rules):
+            return _loss(params, batch)
+
+    def _loss(params, batch):
         tokens = batch["tokens"]
         inputs = tokens[:, :-1] if shift_inputs else tokens
         B, S = inputs.shape

@@ -49,6 +49,7 @@ RULES_TP: Rules = {
     "kv_heads": "tensor",
     "embed": "fsdp",
     "seq_act": "seq",  # activation sequence dim under context parallelism
+    "seq_res": "tensor",
     "expert": "expert",
 }
 

@@ -5,9 +5,8 @@ This is the layer where the reference's per-step torch/NCCL machinery
 (DDP all-reduce inside the user train loop, SURVEY.md §3.4.4-6) collapses into
 compiler output: gradients reduce over `data`, parameters gather/scatter over
 `fsdp`, activations split over `tensor`/`seq` — all emitted by GSPMD from the
-shardings we pin on params and batch. Only params and inputs are constrained;
-optimizer state inherits shardings by propagation (zeros_like(param) inside
-the jitted init), which is the robust idiom for arbitrary optax trees.
+shardings we pin on params, optimizer state and batch: a moment is pinned
+to its parameter's sharding (`ShardedTrainStep._opt_shardings`).
 """
 from __future__ import annotations
 
@@ -53,17 +52,7 @@ class ShardedTrainStep:
         self._loss_fn = loss_fn
         self._init_params_fn = init_params_fn
 
-        def _init(rng):
-            with shd.sharding_ctx(self.mesh, self.rules):
-                params = init_params_fn(rng)
-                opt_state = self.optimizer.init(params)
-            return params, opt_state
-
-        # Pin param shardings; let GSPMD propagate into optimizer state
-        # (mu/nu are zeros_like(param) → inherit the param layout).
-        self._jit_init = jax.jit(
-            _init, out_shardings=(self.param_shardings, None)
-        )
+        self._jit_init = None  # built by the first `init`
 
         def _step(params, opt_state, batch):
             with shd.sharding_ctx(self.mesh, self.rules):
@@ -85,7 +74,28 @@ class ShardedTrainStep:
 
         self._jit_eval = jax.jit(_eval)
 
+    def _opt_shardings(self):
+        """The optimizer state's shardings: every copy of the parameters'
+        tree in it (AdamW's mu and nu) is sharded as the parameters are, the
+        rest (step counts) replicated. Left to propagation (`zeros_like`
+        carries no data dependence for the partitioner to follow) the
+        moments come out replicated: twice the model on every chip."""
+        params = jax.eval_shape(self._init_params_fn, jax.random.key(0))
+        replicated = shd.replicated(self.mesh)
+        return optax.tree_map_params(
+            self.optimizer, lambda _, sharding: sharding,
+            jax.eval_shape(self.optimizer.init, params), self.param_shardings,
+            transform_non_params=lambda _: replicated)
+
     def init(self, rng: jax.Array) -> Tuple[Any, Any]:
+        if self._jit_init is None:
+            def _init(rng):
+                with shd.sharding_ctx(self.mesh, self.rules):
+                    params = self._init_params_fn(rng)
+                    return params, self.optimizer.init(params)
+
+            self._jit_init = jax.jit(_init, out_shardings=(
+                self.param_shardings, self._opt_shardings()))
         return self._jit_init(rng)
 
     def shard_batch(self, batch: Any) -> Any:
