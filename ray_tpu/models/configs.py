@@ -151,6 +151,45 @@ def kimi_linear_tiny(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def kanana2_tiny(**overrides) -> TransformerConfig:
+    """An all-latent-attention stack in the Kanana-2 (DeepSeek-V3) pattern at
+    widths small enough for CPU tests (docs/model_layers.md): every layer is
+    MLA whose 8-wide decoupled part rotates (`positional="rope"`, theta 1e6)
+    beside 16 unrotated columns, values 16 wide; the first layer keeps a
+    dense SwiGLU and the others have 16 sigmoid-routed experts, 3 a token,
+    two shared, experts 4-7 held here; an untied head. Published sizes live
+    in chipbench/configs/ only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=4,
+        n_heads=4,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-6,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=1e6,
+        tie_embeddings=False,
+        mla_layers=tuple(range(1, 49)),
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        moe_num_experts=16,
+        moe_experts_per_token=3,
+        moe_router="sigmoid",
+        moe_held=(4, 4),
+        moe_d_ff=32,
+        moe_shared_experts=2,
+        moe_routed_scale=2.448,
+        moe_first_dense=1,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
 def granite_hybrid_tiny(**overrides) -> TransformerConfig:
     """A Mamba-2 / NoPE-attention stack in the Granite-4.0-H pattern at
     widths small enough for CPU tests (docs/model_layers.md): of every ten

@@ -51,9 +51,10 @@ class TransformerConfig:
     max_seq_len: int = 2048
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     activation: str = "swiglu"  # swiglu | gelu
-    # rope | learned | none: no position signal at all. KDA, MLA-NoPE and
-    # Mamba-2 mixers never take one; with "none" an "attn" layer runs
-    # unrotated too (NoPE attention: the stack's recurrent layers carry order).
+    # rope | learned | none: no position signal at all. KDA and Mamba-2
+    # mixers never take one; with "none" an "attn" layer runs unrotated too
+    # (NoPE attention: the stack's recurrent layers carry order) and so does
+    # an "mla" layer, which rotates its decoupled part under "rope" alone.
     positional: str = "rope"
     rope_theta: float = 500000.0
     # YaRN on the "attn" layers' rotation (None: plain RoPE, and nothing is
@@ -133,9 +134,12 @@ class TransformerConfig:
     # - kda_layers: Kimi Delta Attention (ops/kda.py), `kda_heads` heads of
     #   `kda_head_dim`, a causal depthwise convolution of `kda_conv`, gate
     #   projections of rank `kda_gate_rank`, chunks of `kda_chunk` tokens.
-    # - mla_layers: multi-head latent attention without rotation
-    #   (`kv_lora_rank` latent + `qk_rope_head_dim` shared key part, keys
-    #   `qk_nope_head_dim` + `qk_rope_head_dim` wide, values `v_head_dim`).
+    # - mla_layers: multi-head latent attention (`kv_lora_rank` latent +
+    #   `qk_rope_head_dim` shared key part, keys `qk_nope_head_dim` +
+    #   `qk_rope_head_dim` wide, values `v_head_dim`). With `positional`
+    #   "rope" the `qk_rope_head_dim` part of every query head and of the
+    #   shared key is rotated (plain RoPE at `rope_theta`, no YaRN); with
+    #   any other it is not (NoPE).
     # - mamba_layers: Mamba-2 (ops/ssd.py), `mamba_heads` heads of
     #   `mamba_head_dim` with a state `mamba_d_state` wide, B and C shared by
     #   the heads of each of `mamba_groups` groups, a biased causal depthwise
@@ -192,6 +196,10 @@ class TransformerConfig:
                 "a layer is listed as two of kda, mla, mamba, swa")
         if self.swa_layers and not (self.sliding_window or 0) >= 1:
             raise ValueError("swa_layers need a sliding_window of >= 1")
+        if (self.yarn_factor is not None and self.mla_rotates
+                and any(m == "mla" for m, _ in self.layer_kinds())):
+            raise ValueError("an mla layer's rotation takes no YaRN scaling "
+                             "(no mscale on its scores yet)")
         if (self.moe_num_experts and not self.moe_holds_range
                 and any(m != "attn" for m, _ in self.layer_kinds())):
             raise ValueError(
@@ -209,6 +217,13 @@ class TransformerConfig:
         """Experts routed with nothing dropped, a range of them held here
         (ops/moe.py `moe_ffn_held`), not GShard's capacity dispatch."""
         return self.moe_num_experts > 0 and self.moe_router in _HELD_ROUTERS
+
+    @property
+    def mla_rotates(self) -> bool:
+        """Whether an `mla` layer rotates its decoupled part (the last
+        `qk_rope_head_dim` columns of every query head and the shared key
+        part): under `positional="rope"`, never otherwise."""
+        return self.positional == "rope"
 
     @property
     def rope_yarn(self) -> Optional[Tuple[float, int, float, float, float]]:
@@ -832,17 +847,31 @@ def _mamba_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     return jnp.einsum("bsnp,npd->bsd", y, _w(layer, "mamba_wo", cfg))
 
 
-def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params,
+               positions: jax.Array):
+    """Latent attention. Where the stack's `positional` is "rope" the last
+    `qk_rope_head_dim` columns of every query head and the one key part the
+    heads share are rotated (decoupled RoPE, plain theta), the key part once,
+    before it is broadcast over the heads; what comes out of the latent is
+    not. The rotated columns are held as halves, as `_rope` rotates them: a
+    checkpoint that pairs neighbouring columns is turned where it is loaded
+    (docs/model_layers.md). Any other `positional`: no rotation (NoPE)."""
     lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     q = jnp.einsum("bsd,dnh->bsnh", h, _w(layer, "mla_wq", cfg))
     ckr = h @ _w(layer, "mla_wkva", cfg)               # [B,S,lat+rope]
     c = _norm(ckr[..., :lat], layer["mla_kv_norm"], None, "rmsnorm", cfg.norm_eps)
     kv = jnp.einsum("bsl,lnh->bsnh", c, _w(layer, "mla_wkvb", cfg))
-    kr = jnp.broadcast_to(ckr[:, :, None, lat:],
-                          kv.shape[:3] + (cfg.qk_rope_head_dim,))
-    k = jnp.concatenate([kv[..., :nope], kr], axis=-1)  # no rotation (NoPE)
+    kr = ckr[:, :, None, lat:]                         # [B,S,1,rope]
+    if cfg.mla_rotates:
+        with jax.named_scope("mla.rope"):
+            q = jnp.concatenate(
+                [q[..., :nope],
+                 _rope(q[..., nope:], positions, cfg.rope_theta)], axis=-1)
+            kr = _rope(kr, positions, cfg.rope_theta)
+    kr = jnp.broadcast_to(kr, kv.shape[:3] + (cfg.qk_rope_head_dim,))
+    k = jnp.concatenate([kv[..., :nope], kr], axis=-1)
     q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
-    o = attention(q, k, kv[..., nope:], causal=True)
+    o = attention(q, k, kv[..., nope:], causal=True)   # / sqrt(nope + rope)
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg))
 
 
@@ -881,7 +910,7 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
             delta = _kda_mixer(cfg, whole(h), layer)
     elif mixer == "mla":
         with jax.named_scope("mla"):
-            delta = _mla_mixer(cfg, whole(h), layer)
+            delta = _mla_mixer(cfg, whole(h), layer, positions)
     elif mixer == "mamba2":
         with jax.named_scope("mamba"):
             delta = _mamba_mixer(cfg, whole(h), layer)

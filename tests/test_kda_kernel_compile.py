@@ -99,8 +99,9 @@ def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     (1, 4096, 32, 8, 64, 64, None),      # granite_4_0_h_micro.train_stage_4k
     (1, 16384, 32, 4, 128, 128, None),   # mellum2_12b_a2_5b.train_share_16k,
     (1, 16384, 32, 4, 128, 128, 1024),   # its full layer and a windowed one
+    (1, 16384, 32, 32, 192, 128, None),  # kanana_2_30b_a3b.train_rank8_16k
 ], ids=["gpt2", "internlm2_shard", "kimi_mla", "granite_gqa64",
-        "mellum2_full", "mellum2_swa"])
+        "mellum2_full", "mellum2_swa", "kanana_mla"])
 def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
                                        B, S, H, KVH, D, Dv, window):
     """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape, bfloat16, causal: three Mosaic calls in the gradient's
@@ -119,14 +120,19 @@ def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     assert f"f32[{B},{H},1,{S}]" in text and f"f32[{B},{H},{S},1]" not in text
 
 
-@pytest.mark.parametrize("T,E,first,Eh,F,kind", [
-    (16384, 64, 16, 16, 896, "softmax"), (8192, 256, 104, 8, 1024, "sigmoid")])
+@pytest.mark.parametrize("T,E,first,Eh,F,kind,d,k", [
+    (16384, 64, 16, 16, 896, "softmax", 2304, 8),
+    (8192, 256, 104, 8, 1024, "sigmoid", 2304, 8),
+    (16384, 128, 48, 16, 768, "sigmoid", 2048, 6)])
 def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
-                                      monkeypatch, T, E, first, Eh, F, kind):
+                                      monkeypatch, T, E, first, Eh, F, kind,
+                                      d, k):
     """One expert layer, forward and gradient, at the shapes of
-    `mellum2_12b_a2_5b.train_share_16k` and
-    `kimi_linear_48b_a3b.train_share_8k` (d 2304, 8 experts a token,
-    bfloat16), with the grouped products dispatched as on the chip: the
+    `mellum2_12b_a2_5b.train_share_16k`,
+    `kimi_linear_48b_a3b.train_share_8k` (d 2304, 8 experts a token) and
+    `kanana_2_30b_a3b.train_rank8_16k` (d 2048, 6 a token, experts 768 wide:
+    `_tiles` takes 1024 x 768 and 768 x 1024), bfloat16, with the grouped
+    products dispatched as on the chip: the
     first window's two products and their four transposes are the Pallas
     grouped matmul at `_tiles` (`gmm` / `tgmm` in the program's text); the
     loop of further windows keeps `ragged-dot`."""
@@ -136,7 +142,6 @@ def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
 
     rule = moe.use_kernels  # the platform here is cpu
     monkeypatch.setattr(moe, "use_kernels", lambda _, *a: rule("tpu", *a))
-    d, k = 2304, 8
     sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
     if kind == "sigmoid":
         route = functools.partial(moe.sigmoid_route, bias=jnp.zeros((E,)),
