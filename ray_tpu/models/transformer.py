@@ -32,6 +32,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel import tensor_overlap as tp
 from ray_tpu.parallel.sharding import maybe_constrain
+from ray_tpu.util import tracing
 
 Params = Dict[str, Any]
 
@@ -74,9 +75,17 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    # Remat granularity when remat=True, every layer alike:
-    # - "full": recompute the whole layer body in the backward (max memory
-    #   saving, ~33% extra FLOPs, flash forward kernel included).
+    # Remat granularity when remat=True, every layer alike. The rule of
+    # "full": a forward kernel whose cost is quadratic in the sequence for an
+    # output linear in it is kept; the linear-time cores (KDA, SSD) and every
+    # product are recomputed.
+    # - "full": save the layer's input and the flash kernel's two outputs (o
+    #   and lse, RESIDUAL_NAMES of ops/flash_attention.py) and recompute the
+    #   rest of the layer body in the backward: projections, rotations,
+    #   norms, the KDA / SSD cores, the experts. What a sizing sweep falls
+    #   back to when "dots" does not fit (kanana_2_30b_a3b.train_rank8_16k,
+    #   one sequence of 16,384, one v5e chip: step 977.8 -> 857.9 ms, step
+    #   memory 14.34 -> 15.13 GB; chip runs of PR 40, PERF.md section 6).
     # - "dots": save matmul outputs and the kernels' own residuals (flash: o
     #   [B,H,S,hd] and lse [B,H,S]; KDA: o, chunk states, inverses; SSD: y,
     #   chunk states; a held range of experts: the first window's two grouped
@@ -978,20 +987,24 @@ def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
     body = lambda x, layer: _layer_body(cfg, kind, x, layer, positions)
     if not cfg.remat:
         return body
-    if cfg.remat_policy == "full":
-        return jax.checkpoint(body)
     from ray_tpu.ops import flash_attention as fa, kda, moe, ssd
 
-    # What no dot makes: the kernels' own residuals, the held experts'
-    # grouped products (`ragged_dot` is no `dot_general`) and the products
-    # inside a ring over `tensor` (a `custom_vjp` hides its dots).
-    names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
-             + moe.RESIDUAL_NAMES + tp.RESIDUAL_NAMES)
-    return jax.checkpoint(
-        body,
-        policy=jax.checkpoint_policies.save_from_both_policies(
+    # "full" keeps the one forward kernel that is quadratic in S for outputs
+    # linear in it. "dots" also keeps what no dot makes: the other kernels'
+    # residuals, the held experts' grouped products (`ragged_dot` is no
+    # `dot_general`) and the products inside a ring over `tensor` (a
+    # `custom_vjp` hides its dots).
+    names = fa.RESIDUAL_NAMES
+    policy = jax.checkpoint_policies.save_only_these_names(*names)
+    if cfg.remat_policy == "dots":
+        names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
+                 + moe.RESIDUAL_NAMES + tp.RESIDUAL_NAMES)
+        policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names(*names)))
+            jax.checkpoint_policies.save_only_these_names(*names))
+    tracing.observe("train.remat", 0, slow=False, policy=cfg.remat_policy,
+                    kept=",".join(names))
+    return jax.checkpoint(body, policy=policy)
 
 
 def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> jax.Array:

@@ -6,9 +6,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import transformer as tfm
-from ray_tpu.models.configs import gpt2_tiny, llama_tiny
+from ray_tpu.models import configs
+from ray_tpu.models.configs import gpt2_tiny, kanana2_tiny, llama_tiny
 from ray_tpu.parallel import MeshSpec, RULES_DP, RULES_TP, make_mesh
 from ray_tpu.train.step import transformer_train_step
+from ray_tpu.util import tracing
 
 
 @pytest.mark.parametrize("cfg_fn", [llama_tiny, gpt2_tiny])
@@ -127,20 +129,39 @@ def _tiny_batch(cfg, shape=(2, 32)):
     return {"tokens": tokens}
 
 
-@pytest.mark.parametrize("impl,remat_kw,fwd_calls_per_layer", [
-    ("xla", dict(remat=True, remat_policy="full"), 0),
-    ("xla", dict(remat=True, remat_policy="dots"), 0),
-    ("flash", dict(remat=False), 1),
-    ("flash", dict(remat=True, remat_policy="dots"), 1),
-    ("flash", dict(remat=True, remat_policy="full"), 2),
+def _layer_saves(cfg, index, B=2, S=32):
+    """[(shape, dtype, why)] of what layer `index`'s checkpointed body keeps
+    for the backward besides its arguments and closed-over constants (the
+    positions, a rotation's frequencies)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    params = tfm.init_params(jax.random.key(0), cfg)
+    layer = tfm.layer_params(params, cfg, index)
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    body = tfm.layer_scan_body(cfg, cfg.layer_kinds()[index], positions)
+    return [(aval.shape, str(aval.dtype), why) for aval, why in
+            saved_residuals(
+                lambda x, l: body(x, l)[0].astype(jnp.float32).sum(),
+                jnp.zeros((B, S, cfg.d_model), cfg.dtype), layer)
+            if "argument" not in why and "constant" not in why]
+
+
+@pytest.mark.parametrize("impl,preset,remat_kw,fwd_calls_per_layer", [
+    ("xla", llama_tiny, dict(remat=True, remat_policy="full"), 0),
+    ("xla", llama_tiny, dict(remat=True, remat_policy="dots"), 0),
+    ("flash", llama_tiny, dict(remat=False), 1),
+    ("flash", llama_tiny, dict(remat=True, remat_policy="dots"), 1),
+    ("flash", llama_tiny, dict(remat=True, remat_policy="full"), 1),
+    ("flash", kanana2_tiny, dict(remat=True, remat_policy="full"), 1),
 ])
-def test_remat_matches_no_remat(monkeypatch, impl, remat_kw,
+def test_remat_matches_no_remat(monkeypatch, impl, preset, remat_kw,
                                 fwd_calls_per_layer):
-    """Both remat policies give the gradients of no remat, and only "full"
-    runs the flash forward kernel a second time in the backward: "dots"
-    keeps the kernel's own residuals (o, lse), which no dot produces."""
+    """Both remat policies give the gradients of no remat, and neither runs
+    the flash forward kernel a second time in the backward: both keep the
+    kernel's own residuals (o, lse), which no dot produces. The latent
+    layers' keys (24 wide) and values (16) go through the same rule."""
     monkeypatch.setenv("RTPU_ATTN_IMPL", impl)
-    cfg = llama_tiny()
+    cfg = preset()
     params = tfm.init_params(jax.random.key(0), cfg)
     batch = _tiny_batch(cfg)
     g1 = jax.grad(lambda p: tfm.loss_fn(p, batch, cfg))(params)
@@ -151,7 +172,7 @@ def test_remat_matches_no_remat(monkeypatch, impl, remat_kw,
     # largest differences seen are 1 and 1.5 ulp of the largest gradients
     # (embed, 0.25: ulp 2^-10) under "dots" and "full", so two ulps there.
     atol = 1e-3 if impl == "xla" else 2e-3
-    cfg_r = llama_tiny(**remat_kw)
+    cfg_r = preset(**remat_kw)
     grad_r = jax.grad(lambda p: tfm.loss_fn(p, batch, cfg_r))
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(grad_r(params))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=atol)
@@ -161,14 +182,39 @@ def test_remat_matches_no_remat(monkeypatch, impl, remat_kw,
                           "6in_2out": 1} if fwd_calls_per_layer else {})
 
 
-def test_remat_dots_keeps_flash_residuals_through_shard_map(monkeypatch):
+def test_remat_full_is_the_work_of_saving_nothing(monkeypatch):
+    """The saved o and lse are the arrays the second forward call would have
+    written: the loss and every gradient element under "full" are bit-equal
+    to a body checkpointed with nothing saved (here, where XLA compiles the
+    two programs alike; on the chip they differ by roundings, PERF.md
+    section 6, PR 40)."""
+    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
+    cfg = llama_tiny(remat=True, remat_policy="full")
+    params = tfm.init_params(jax.random.key(0), cfg)
+    batch = _tiny_batch(cfg)
+    run = lambda: jax.jit(jax.value_and_grad(
+        lambda p: tfm.loss_fn(p, batch, cfg)))(params)
+    got = run()
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: jax.checkpoint_policies.nothing_saveable)
+    want = run()
+    calls = _flash_kernel_calls(jax.make_jaxpr(jax.grad(
+        lambda p: tfm.loss_fn(p, batch, cfg)))(params).jaxpr)
+    assert calls["3in_2out"] == 2 * cfg.n_layers  # the reference ran it twice
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("policy", ["dots", "full"])
+def test_remat_keeps_flash_residuals_through_shard_map(monkeypatch, policy):
     """The mesh path (ops/attention.py wraps the kernel in shard_map on a
-    multi-device mesh): still one forward call a layer under "dots"."""
+    multi-device mesh): still one forward call a layer under either
+    policy."""
     from ray_tpu.parallel.sharding import DEFAULT_RULES, sharding_ctx
 
     monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     mesh = make_mesh(MeshSpec(fsdp=2, tensor=2), devices=jax.devices()[:4])
-    cfg = llama_tiny(remat=True, remat_policy="dots")
+    cfg = llama_tiny(remat=True, remat_policy=policy)
     params = tfm.init_params(jax.random.key(0), cfg)
     batch = _tiny_batch(cfg, shape=(4, 32))
 
@@ -183,26 +229,109 @@ def test_remat_dots_keeps_flash_residuals_through_shard_map(monkeypatch):
         "6in_2out": cfg.n_layers}
 
 
+@pytest.mark.parametrize("preset", [
+    "llama_tiny", "moe_tiny", "kimi_linear_tiny", "kanana2_tiny",
+    "granite_hybrid_tiny", "mellum2_tiny"])
+def test_remat_full_saves_only_the_flash_outputs(monkeypatch, preset):
+    """What keeps "full" the small-memory policy: a layer of any kind keeps
+    its input and, where its mixer runs the flash kernel, o [B,H,S,hd] and
+    lse [B,H,S]; no dot output, and nothing the KDA and SSD cores or the held
+    experts name ("dots" keeps those)."""
+    from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
+    cfg = getattr(configs, preset)(remat=True, remat_policy="full")
+    kinds = cfg.layer_kinds()
+    B, S, H = 2, 32, cfg.n_heads
+    for index in sorted({kinds.index(k) for k in kinds}):
+        saved = _layer_saves(cfg, index, B, S)
+        if kinds[index][0] not in ("attn", "swa", "mla"):
+            assert saved == [], kinds[index]
+            continue
+        # jax puts a reduce_precision behind a residual that the forward
+        # pass also uses, which hides o's name: o is found by where it was
+        # made, as in test_remat_dots_saved_residuals.
+        assert all("flash_attention" in why for _, _, why in saved), saved
+        lse, o = sorted(saved, key=lambda r: len(r[0]))
+        assert f"'{RESIDUAL_NAMES[1]}'" in lse[2]
+        assert lse[:2] == ((B, H, S), "float32")
+        assert o[0][:3] == (B, H, S) and o[1] == "bfloat16"
+    dots = getattr(configs, preset)(remat=True, remat_policy="dots")
+    assert len(_layer_saves(dots, 0, B, S)) > 2  # the check can see a save
+
+
+def test_remat_full_saves_no_ring_product(monkeypatch):
+    """Under a `tensor` axis the decomposed products name their outputs
+    (tp.RESIDUAL_NAMES) for "dots"; "full" keeps none of them. (A save that
+    the forward pass also uses loses its name in the listing: the ring's are
+    found by where they were made.)"""
+    from ray_tpu.parallel.sharding import sharding_ctx
+
+    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
+    mesh = make_mesh(MeshSpec(fsdp=2, tensor=2), devices=jax.devices()[:4])
+    saves = {}
+    for policy in ("dots", "full"):
+        with sharding_ctx(mesh, RULES_TP):
+            saves[policy] = _layer_saves(
+                llama_tiny(remat=True, remat_policy=policy), 0, B=4)
+    ring = lambda rows: [w for _, _, w in rows if "tensor_overlap.py" in w]
+    assert ring(saves["dots"]) and not ring(saves["full"])
+    assert len(saves["full"]) == 2, saves["full"]
+
+
+@pytest.mark.parametrize("impl,digest", [
+    ("xla", "f57f8eb266c73546"), ("flash", "2f8f7c2106e0827d")])
+def test_remat_dots_lowers_to_the_recorded_program(monkeypatch, impl, digest):
+    """`layer_scan_body`'s "dots" branch is the program five of the
+    benchmark's six configurations run: llama_tiny's gradient lowers to the
+    StableHLO text it lowered to before "full" learned to keep the flash
+    outputs (its sha256, recorded at PR 39's commit with this JAX)."""
+    import hashlib
+
+    monkeypatch.setenv("RTPU_ATTN_IMPL", impl)
+    cfg = llama_tiny(remat=True, remat_policy="dots")
+    params = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 32), jnp.int32)}
+    text = jax.jit(jax.grad(lambda p, b: tfm.loss_fn(p, b, cfg))).lower(
+        params, batch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_remat_counter_is_one_a_checkpointed_body(monkeypatch):
+    """`train.remat`: one observation a checkpointed layer body built, with
+    the policy and the names it keeps; none without remat."""
+    from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    seen = []
+    real = tracing.observe
+    monkeypatch.setattr(tracing, "observe", lambda name, ns, **kw: (
+        seen.append((name, kw)), real(name, ns, **kw))[1])
+    positions = jnp.zeros((2, 32), jnp.int32)
+    count = lambda: tracing.phase_table().get("train.remat", {}).get(
+        "count", 0)
+    before = count()
+    for kw in (dict(remat=False), dict(remat=True, remat_policy="full"),
+               dict(remat=True, remat_policy="dots")):
+        cfg = llama_tiny(**kw)
+        tfm.layer_scan_body(cfg, cfg.layer_kinds()[0], positions)
+    rows = [kw for name, kw in seen if name == "train.remat"]
+    assert count() == before + 2
+    assert rows[0] == dict(slow=False, policy="full",
+                           kept=",".join(RESIDUAL_NAMES))
+    assert rows[1]["policy"] == "dots"
+    assert rows[1]["kept"].startswith(",".join(RESIDUAL_NAMES) + ",kda_o")
+
+
 def test_remat_dots_saved_residuals(monkeypatch):
     """What one checkpointed layer keeps for the backward under "dots": the
     kernel's o once, as [B,H,S,hd], and lse as lane-dense [B,H,S] float32;
     no second attention output in the model's [B,S,H,hd] layout."""
-    from jax._src.ad_checkpoint import saved_residuals
-
     from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 
     monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     cfg = llama_tiny(remat=True, remat_policy="dots")
     B, S, H, hd = 2, 32, cfg.n_heads, cfg.head_dim
-    params = tfm.init_params(jax.random.key(0), cfg)
-    layer = tfm.layer_params(params, cfg, 0)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    body = tfm.layer_scan_body(cfg, cfg.layer_kinds()[0], positions)
-    saved = [
-        (aval.shape, str(aval.dtype), why) for aval, why in saved_residuals(
-            lambda x, l: body(x, l)[0].astype(jnp.float32).sum(),
-            jnp.zeros((B, S, cfg.d_model), cfg.dtype), layer)
-        if "argument" not in why]
+    saved = _layer_saves(cfg, 0, B, S)
     lse_name = RESIDUAL_NAMES[1]
     assert [(sh, dt) for sh, dt, why in saved if f"'{lse_name}'" in why] == [
         ((B, H, S), "float32")]
