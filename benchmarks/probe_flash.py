@@ -18,6 +18,9 @@ chip the script fails at once.
         # fits, at the table's tiles and at every (block, strip) of
         # `BAND_SWEEP`, then timed at the cell's length beside the same
         # shape's full causal call (the sweep beside `_TILES`)
+    python3 benchmarks/probe_flash.py gated      # `GATED`, heads of 256 at
+        # 8:1: checked at GATED_CHECK_S positions at the table's tiles and
+        # at every (block, strip) of `GATED_SWEEP`, then timed at 16,384
 """
 from __future__ import annotations
 
@@ -48,6 +51,12 @@ BAND = ("mellum2_swa", 1, 16384, 32, 4, 128, 128, 1024)
 BAND_CHECK_S = 4096
 BAND_SWEEP = [(512, 128), (512, 256), (1024, 128), (1024, 256), (1024, 512),
               (2048, 256), (2048, 512)]
+# (name, B, S, H, KVH, D, Dv): qwen3_next_80b_a3b.train_rank16_16k's gated
+# attention layer (K and V of a block are twice the bytes of the 128 row).
+GATED = ("qwen3_next_gattn", 1, 16384, 16, 2, 256, 256)
+GATED_CHECK_S = 4096
+GATED_SWEEP = [(512, 128), (512, 256), (512, 512), (1024, 128), (1024, 256),
+               (1024, 512), (2048, 128), (2048, 256), (2048, 512)]
 SWEEP = [(b, s) for b in (512, 1024, 2048, 4096) for s in (128, 256, 512)]
 SWEEP += [(b, b) for b in (512, 1024)]  # no strips: the split of tiles alone
 # Flash and reference see the same bf16 inputs; flash rounds P to bf16 before
@@ -175,6 +184,17 @@ def main(argv) -> int:
                    B, S, H, KVH, D, Dv, block, sub, window)
         for block, sub in ((1024, 256), (2048, 256)):  # the full layer
             report({"cell": name, "block": block, "sub": sub, "window": None},
+                   lambda *a: (kernel_ms(*a), True),
+                   B, S, H, KVH, D, Dv, block, sub)
+        return 1 if failed else 0
+    if argv[1:] == ["gated"]:
+        name, B, S, H, KVH, D, Dv = GATED
+        for block, sub in [(None, None)] + GATED_SWEEP:
+            report({"cell": name, "block": block, "sub": sub}, check,
+                   B, GATED_CHECK_S, H // KVH, 1, D, Dv, True, None, block,
+                   sub)
+        for block, sub in GATED_SWEEP:
+            report({"cell": name, "block": block, "sub": sub},
                    lambda *a: (kernel_ms(*a), True),
                    B, S, H, KVH, D, Dv, block, sub)
         return 1 if failed else 0

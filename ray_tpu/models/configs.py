@@ -264,3 +264,50 @@ def mellum2_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def qwen3_next_tiny(**overrides) -> TransformerConfig:
+    """A Gated DeltaNet / gated-attention stack in the Qwen3-Next pattern at
+    widths small enough for CPU tests (docs/model_layers.md): of every four
+    layers three are `gdn` (2 key heads serving 4 value heads of 16, a
+    convolution of 4, chunks of 16) and the fourth softmax attention at 4 / 1
+    heads of 32 with q / k norms, a rotation of a quarter of a head and a
+    sigmoid gate on its output; zero-centred norm weights; every
+    feed-forward 32 softmax-routed experts, 4 a token, experts 8-15 held
+    here, beside one shared expert behind a sigmoid gate; an untied head.
+    Published sizes live in chipbench/configs/ only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=1,
+        attn_head_dim=32,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-6,
+        norm_offset=1.0,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=1e7,
+        rope_fraction=0.25,
+        attn_qk_norm=True,
+        attn_out_gate=True,
+        tie_embeddings=False,
+        gdn_layers=tuple(l for l in range(1, 49) if l % 4),
+        gdn_k_heads=2,
+        gdn_v_heads=4,
+        gdn_head_dim=16,
+        gdn_conv=4,
+        gdn_chunk=16,
+        moe_num_experts=32,
+        moe_experts_per_token=4,
+        moe_router="softmax",
+        moe_held=(8, 8),
+        moe_d_ff=32,
+        moe_shared_experts=1,
+        moe_shared_gate=True,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

@@ -51,11 +51,20 @@ def _kv_stack(params: Params, cfg: TransformerConfig):
     """(kind, leaves [L, ...]) of the one stacked tree the cache's [L, ...]
     keys and values are scanned beside; what decode cannot serve is refused
     (ROADMAP R7)."""
+    if any(mixer == "gdn" for mixer, _ in cfg.layer_kinds()):
+        raise NotImplementedError(
+            "decode cannot serve a Gated DeltaNet (gdn) layer: it would hold "
+            "a delta-rule state and the convolution's last tokens in place of "
+            "keys and values (ROADMAP R7 / R9); the stack trains but does not "
+            "serve yet")
     if any(mixer != "attn" for mixer, _ in cfg.layer_kinds()):
         raise NotImplementedError(
             "decode holds keys and values of one length a layer only: a "
             "stack with KDA / MLA / Mamba-2 / windowed layers trains but "
             "does not serve yet")
+    if cfg.attn_out_gate or cfg.norm_offset:
+        raise NotImplementedError(
+            "decode does not apply attn_out_gate / norm_offset yet")
     if (cfg.embed_scale, cfg.residual_scale, cfg.attn_scale,
             cfg.logit_scale) != (1.0, 1.0, None, 1.0):
         raise NotImplementedError(

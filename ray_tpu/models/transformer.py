@@ -19,6 +19,7 @@ it hosts torch):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -71,6 +72,25 @@ class TransformerConfig:
     yarn_attn_factor: float = 1.0
     # Width of an attention head; None => d_model // n_heads.
     attn_head_dim: Optional[int] = None
+    # Three properties of the "attn" / "swa" layers and one of the stack's
+    # norms (docs/model_layers.md); at these defaults nothing is traced for
+    # them and no leaf is made.
+    # - attn_qk_norm: an RMSNorm over every query head and every key head
+    #   (leaves `q_norm`, `k_norm` [head_dim]) before the rotation.
+    # - rope_fraction: the share of a head's columns the rotation turns, the
+    #   first head_dim * rope_fraction of them (pair i = columns i and
+    #   rot / 2 + i of those, f_i = theta^(-2i / rot)); the rest pass.
+    # - attn_out_gate: the attention's output times sigmoid(gate) before
+    #   `wo`, the gate a second query-sized projection (leaf `wq_gate`
+    #   [d, H, head_dim]; a checkpoint that doubles its query projection is
+    #   split where it is loaded).
+    # - norm_offset: RMSNorm weights are held zero-centred, x / rms(x) *
+    #   (norm_offset + w), 0.0 or 1.0: the layer norms, the final norm and
+    #   the q / k norms (not a `gdn` layer's gated norm).
+    attn_qk_norm: bool = False
+    rope_fraction: float = 1.0
+    attn_out_gate: bool = False
+    norm_offset: float = 0.0
     tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -134,6 +154,9 @@ class TransformerConfig:
     moe_shared_experts: int = 0
     moe_routed_scale: float = 1.0
     moe_first_dense: int = 0
+    # The shared experts' output times sigmoid(w . x), w one leaf [d]
+    # (`shared_gate`); a held range only. False: added unweighted.
+    moe_shared_gate: bool = False
     # What each layer is (docs/model_layers.md). Layer numbers are 1-based,
     # as published configs list them; numbers past n_layers are ignored, so
     # a cut in depth keeps the published lists. A layer in neither list has
@@ -153,10 +176,16 @@ class TransformerConfig:
     #   `mamba_head_dim` with a state `mamba_d_state` wide, B and C shared by
     #   the heads of each of `mamba_groups` groups, a biased causal depthwise
     #   convolution of `mamba_conv` over x, B and C, chunks of `mamba_chunk`.
+    # - gdn_layers: Gated DeltaNet (ops/kda.py at a decay a head):
+    #   `gdn_k_heads` query / key heads and `gdn_v_heads` value heads of
+    #   `gdn_head_dim` (key head i serves value heads r i .. r i + r - 1), a
+    #   causal depthwise convolution of `gdn_conv` over q, k and v, a
+    #   SiLU-gated norm a head, chunks of `gdn_chunk`.
     kda_layers: Tuple[int, ...] = ()
     mla_layers: Tuple[int, ...] = ()
     mamba_layers: Tuple[int, ...] = ()
     swa_layers: Tuple[int, ...] = ()
+    gdn_layers: Tuple[int, ...] = ()
     sliding_window: Optional[int] = None
     kda_heads: Optional[int] = None       # None => n_heads
     kda_head_dim: int = 128
@@ -173,6 +202,11 @@ class TransformerConfig:
     mamba_groups: int = 1
     mamba_conv: int = 4
     mamba_chunk: int = 256
+    gdn_k_heads: int = 16
+    gdn_v_heads: int = 32
+    gdn_head_dim: int = 128
+    gdn_conv: int = 4
+    gdn_chunk: int = 128
     # Four scalars some families multiply by (docs/model_layers.md); at
     # these defaults no operation is traced for them. Embeddings times
     # `embed_scale`; both residual branches of every layer times
@@ -189,7 +223,7 @@ class TransformerConfig:
 
     def __post_init__(self):
         for name in ("kda_layers", "mla_layers", "mamba_layers", "swa_layers",
-                     "moe_held"):
+                     "gdn_layers", "moe_held"):
             v = getattr(self, name)
             if isinstance(v, list):  # from a JSON file
                 object.__setattr__(self, name, tuple(v))
@@ -199,10 +233,22 @@ class TransformerConfig:
         if self.moe_router not in ("softmax_capacity",) + _HELD_ROUTERS:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         lists = (self.kda_layers + self.mla_layers + self.mamba_layers
-                 + self.swa_layers)
+                 + self.swa_layers + self.gdn_layers)
         if len(set(lists)) != len(lists):
             raise ValueError(
-                "a layer is listed as two of kda, mla, mamba, swa")
+                "a layer is listed as two of kda, mla, mamba, swa, gdn")
+        if self.gdn_layers and self.gdn_v_heads % self.gdn_k_heads:
+            raise ValueError("gdn_v_heads is a multiple of gdn_k_heads")
+        if self.norm_offset not in (0.0, 1.0) or (
+                self.norm_offset and self.norm != "rmsnorm"):
+            raise ValueError("norm_offset is 0.0, or 1.0 with norm='rmsnorm'")
+        if not 0.0 < self.rope_fraction <= 1.0 or self.rope_rotated % 2:
+            raise ValueError("rope_fraction rotates an even number of a "
+                             "head's columns, at most all of them")
+        if self.moe_shared_gate and not (self.moe_holds_range
+                                         and self.moe_shared_experts):
+            raise ValueError("moe_shared_gate gates the shared experts of a "
+                             "held range (moe_router 'sigmoid' / 'softmax')")
         if self.swa_layers and not (self.sliding_window or 0) >= 1:
             raise ValueError("swa_layers need a sliding_window of >= 1")
         if (self.yarn_factor is not None and self.mla_rotates
@@ -212,7 +258,7 @@ class TransformerConfig:
         if (self.moe_num_experts and not self.moe_holds_range
                 and any(m != "attn" for m, _ in self.layer_kinds())):
             raise ValueError(
-                "kda / mla / mamba / swa layers compose with "
+                "kda / mla / mamba / swa / gdn layers compose with "
                 "moe_router='sigmoid' or 'softmax' only")
         if self.moe_router == "softmax" and self.moe_routed_scale != 1.0:
             raise ValueError("moe_router='softmax' takes no moe_routed_scale")
@@ -243,14 +289,15 @@ class TransformerConfig:
 
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """(mixer, feed-forward) of every layer: mixer attn | swa | mla |
-        kda | mamba2, feed-forward dense | moe."""
+        kda | mamba2 | gdn, feed-forward dense | moe."""
         held = self.moe_holds_range
         out = []
         for l in range(self.n_layers):
             mixer = ("kda" if l + 1 in self.kda_layers else
                      "mla" if l + 1 in self.mla_layers else
                      "mamba2" if l + 1 in self.mamba_layers else
-                     "swa" if l + 1 in self.swa_layers else "attn")
+                     "swa" if l + 1 in self.swa_layers else
+                     "gdn" if l + 1 in self.gdn_layers else "attn")
             if held:
                 ffn = "moe" if l >= self.moe_first_dense else "dense"
             else:
@@ -291,6 +338,16 @@ class TransformerConfig:
         raise IndexError(l)
 
     @property
+    def rope_rotated(self) -> int:
+        """Columns of an "attn" / "swa" head the rotation turns."""
+        return int(self.head_dim * self.rope_fraction)
+
+    @property
+    def attn_gated(self) -> bool:
+        """Whether the "attn" kind carries what the `gattn` scope marks."""
+        return self.attn_qk_norm or self.attn_out_gate
+
+    @property
     def kda_n_heads(self) -> int:
         return self.kda_heads or self.n_heads
 
@@ -324,7 +381,14 @@ class TransformerConfig:
         d, H = self.d_model, self.n_heads
         if mixer in ("attn", "swa"):
             h = self.head_dim
-            return d * H * h + 2 * d * self.kv_heads * h + H * h * d
+            return (d * H * h * (2 if self.attn_out_gate else 1)
+                    + 2 * d * self.kv_heads * h + H * h * d
+                    + (2 * h if self.attn_qk_norm else 0))
+        if mixer == "gdn":
+            Hq, Hv, h = self.gdn_k_heads, self.gdn_v_heads, self.gdn_head_dim
+            return ((d + self.gdn_conv) * 2 * (Hq + Hv) * h   # q k v z, convs
+                    - self.gdn_conv * Hv * h                 # z has no conv
+                    + 2 * d * Hv + 2 * Hv + h + Hv * h * d)  # b a, A dt, norm, o
         if mixer == "mla":
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim
             lat = self.kv_lora_rank
@@ -354,7 +418,8 @@ class TransformerConfig:
         # Per token and under even routing, k * held / E of the held experts.
         n = self.moe_experts_per_token * held / E if active else held
         bias = E if self.moe_router == "sigmoid" and not active else 0
-        return (n + self.moe_shared_experts) * 3 * d * F + d * E + (
+        gate = d if self.moe_shared_gate else 0
+        return (n + self.moe_shared_experts) * 3 * d * F + d * E + gate + (
             bias)  # the selection bias is no matmul
 
     def num_params(self) -> int:
@@ -384,8 +449,9 @@ class TransformerConfig:
         touches (no embedding lookup), causal attention 3*S*H*(d_qk + d_v) a
         softmax layer (a windowed one its band's pairs, S W - W (W - 1) / 2,
         in place of the triangle's), and the chunked algorithm's operations
-        a KDA or Mamba-2 layer (see chipbench/reduce/kda_counts.py,
-        ssd_counts.py)."""
+        a KDA, Gated DeltaNet or Mamba-2 layer (see
+        chipbench/reduce/kda_counts.py, ssd_counts.py,
+        qwen3_next_counts.py)."""
         S = seq_len or self.max_seq_len
         d, H = self.d_model, self.n_heads
         n = self.num_active_params()
@@ -409,6 +475,13 @@ class TransformerConfig:
                 Cm, N = self.mamba_chunk, self.mamba_d_state
                 total += 3.0 * (self.mamba_groups * Cm * N  # C B^T, lower
                                 + self.mamba_inner * (Cm + 4 * N))
+            elif mixer == "gdn":
+                # The chunked rule at a scalar decay: K K^T and Q K^T are
+                # products a key head, the rest a value head.
+                Cg, hg = self.gdn_chunk, self.gdn_head_dim
+                total += 3.0 * (self.gdn_k_heads * 2 * Cg * hg
+                                + self.gdn_v_heads * (
+                                    6 * hg * hg + Cg * 3 * hg + Cg * Cg / 3))
             else:
                 total += 3.0 * self.kda_n_heads * (
                     6 * hd * hd + C * 5 * hd + C * C / 3)
@@ -433,6 +506,12 @@ def _dt_bias_init(shape):
     return init
 
 
+def _unit_norm_init(cfg: TransformerConfig) -> str:
+    """The init at which an RMSNorm multiplies by one: its weight is held
+    as w, or zero-centred as 1 + w (`norm_offset`)."""
+    return "zeros" if cfg.norm_offset else "ones"
+
+
 def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
     """{leaf: (shape, logical axes, init)} of one layer of `kind`: THE table
     `init_params` and `param_logical_specs` are built from. init: "ones" |
@@ -442,9 +521,10 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
     H = cfg.n_heads
     fan = lambda n: ("normal", 1.0 / math.sqrt(n))
     out_std = lambda n: ("normal", 1.0 / math.sqrt(2 * L * n))
+    unit = _unit_norm_init(cfg)
     sh: Dict[str, Any] = {
-        "attn_norm": ((d,), (None,), "ones"),
-        "mlp_norm": ((d,), (None,), "ones"),
+        "attn_norm": ((d,), (None,), unit),
+        "mlp_norm": ((d,), (None,), unit),
     }
     if mixer in ("attn", "swa"):
         # Projections are FUSED into single matmuls (one MXU op instead of
@@ -461,6 +541,31 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
             sh["wq"] = ((d, H, hd), ("embed", "heads", None), fan(d))
             sh["wkv"] = ((d, 2, KVH, hd), ("embed", None, "kv_heads", None),
                          fan(d))
+        if cfg.attn_out_gate:
+            sh["wq_gate"] = ((d, H, hd), ("embed", "heads", None), fan(d))
+        if cfg.attn_qk_norm:
+            sh["q_norm"] = ((hd,), (None,), unit)
+            sh["k_norm"] = ((hd,), (None,), unit)
+    elif mixer == "gdn":
+        # q and k (key heads) and v and the gate z (value heads) each fused
+        # over an array dim of their own, as `mamba_wzx` is; beta and the
+        # decay's input are narrow. The convolution is depthwise: one over
+        # [q; k; v] is one over each.
+        Hq, Hv, hg = cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_head_dim
+        K = cfg.gdn_conv
+        sh["gdn_wqk"] = ((d, 2, Hq, hg), ("embed", None, "heads", None),
+                         fan(d))
+        sh["gdn_wvz"] = ((d, 2, Hv, hg), ("embed", None, "heads", None),
+                         fan(d))
+        sh["gdn_wba"] = ((d, 2, Hv), ("embed", None, "heads"), fan(d))
+        sh["gdn_conv_qk"] = ((K, 2, Hq, hg), (None, None, "heads", None),
+                             fan(K))
+        sh["gdn_conv_v"] = ((K, Hv, hg), (None, "heads", None), fan(K))
+        sh["gdn_A_log"] = ((Hv,), ("heads",), _a_log_init((Hv,)))
+        sh["gdn_dt_bias"] = ((Hv,), ("heads",), _dt_bias_init((Hv,)))
+        sh["gdn_o_norm"] = ((hg,), (None,), "ones")
+        sh["gdn_wo"] = ((Hv, hg, d), ("heads", None, "embed"),
+                        out_std(Hv * hg))
     elif mixer == "mla":
         lat, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -530,6 +635,8 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
             sh["shared_w_gate_up"] = ((d, 2, Fs), ("embed", None, "mlp"),
                                       fan(d))
             sh["shared_w_down"] = ((Fs, d), ("mlp", "embed"), out_std(Fs))
+            if cfg.moe_shared_gate:
+                sh["shared_gate"] = ((d,), ("embed",), fan(d))
     if cfg.norm == "layernorm":
         sh["attn_norm_b"] = ((d,), (None,), "zeros")
         sh["mlp_norm_b"] = ((d,), (None,), "zeros")
@@ -608,7 +715,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
         segments.append(seg)
     params: Params = {
         "embed": (jax.random.normal(keys[7], (V, d)) * 0.02).astype(cfg.param_dtype),
-        "final_norm": jnp.ones((d,), cfg.param_dtype),
+        "final_norm": getattr(jnp, _unit_norm_init(cfg))((d,),
+                                                         cfg.param_dtype),
         "layers": _stored(segments, cfg),
     }
     if cfg.norm == "layernorm":
@@ -646,13 +754,18 @@ def param_logical_specs(cfg: TransformerConfig) -> Params:
     return specs
 
 
-def _norm(x, w, b, kind: str, eps: Optional[float] = None):
-    """`eps` None: 1e-6 (rmsnorm) / 1e-5 (layernorm); callers pass cfg.norm_eps."""
+def _norm(x, w, b, kind: str, eps: Optional[float] = None,
+          offset: float = 0.0):
+    """`eps` None: 1e-6 (rmsnorm) / 1e-5 (layernorm); callers pass
+    cfg.norm_eps. `offset` (rmsnorm; `TransformerConfig.norm_offset`): the
+    weight is held zero-centred and `offset + w` multiplies; at 0.0 nothing
+    is traced for it."""
     xf = x.astype(jnp.float32)
     if kind == "rmsnorm":
         x2 = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        wf = w.astype(jnp.float32)
         out = xf * jax.lax.rsqrt(x2 + (1e-6 if eps is None else eps)
-                                 ) * w.astype(jnp.float32)
+                                 ) * (wf + offset if offset else wf)
     else:
         mu = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.var(xf, axis=-1, keepdims=True)
@@ -701,6 +814,16 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
     return out.astype(x.dtype)
 
 
+def _rope_first(x: jax.Array, rot: int, positions: jax.Array, theta: float,
+                yarn=None) -> jax.Array:
+    """`_rope` on the first `rot` columns of every head, the rest passed
+    (`TransformerConfig.rope_fraction`); all of them: `_rope` itself."""
+    if rot == x.shape[-1]:
+        return _rope(x, positions, theta, yarn)
+    return jnp.concatenate(
+        [_rope(x[..., :rot], positions, theta, yarn), x[..., rot:]], axis=-1)
+
+
 def _w(layer: Params, name: str, cfg: TransformerConfig) -> jax.Array:
     """Weight access for the layer helpers: compute-dtype view,
     transparently dequantizing int8 weight-only params
@@ -735,10 +858,15 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
         q = outs[0]
         kv = checkpoint_name(outs[1], "qkv_proj")
         k, v = kv[:, :, 0], kv[:, :, 1]
+    if cfg.attn_qk_norm:
+        q, k = (_norm(x, layer[n], None, "rmsnorm", cfg.norm_eps,
+                      cfg.norm_offset)
+                for x, n in ((q, "q_norm"), (k, "k_norm")))
     if cfg.positional == "rope":
         yarn = cfg.rope_yarn if mixer == "attn" else None
-        q = _rope(q, positions, cfg.rope_theta, yarn)
-        k = _rope(k, positions, cfg.rope_theta, yarn)
+        rot = cfg.rope_rotated
+        q = _rope_first(q, rot, positions, cfg.rope_theta, yarn)
+        k = _rope_first(k, rot, positions, cfg.rope_theta, yarn)
     return q, k, v
 
 
@@ -795,8 +923,15 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
         h, layer["router"], layer["moe_w_gate_up"], layer["moe_w_down"],
         route=route, held_first=cfg.moe_held_range[0], dtype=cfg.dtype)
     shared = {n[len("shared_"):]: a for n, a in layer.items()
-              if n.startswith("shared_")}
-    if shared:  # the always-on experts: one dense SwiGLU of their joint width
+              if n.startswith("shared_w_")}
+    if shared and cfg.moe_shared_gate:  # times sigmoid(w . x), float32
+        with jax.named_scope("moe.shared"):
+            y = _mlp_block(cfg, "dense", h, shared)[0]
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsd,d->bs", h, _w(layer, "shared_gate", cfg),
+                preferred_element_type=jnp.float32))
+            delta = delta + y * gate[..., None].astype(y.dtype)
+    elif shared:  # the always-on experts: one dense SwiGLU of their joint width
         delta = delta + _mlp_block(cfg, "dense", h, shared)[0]
     return delta, counters
 
@@ -825,6 +960,31 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     o = _norm(o, layer["kda_o_norm"], None, "rmsnorm", cfg.norm_eps)
     o = o * jax.nn.sigmoid(low_rank("g"))
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "kda_wo", cfg))
+
+
+def _gdn_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+    """Gated DeltaNet: the delta rule of ops/kda.py at one decay a value
+    head, `gdn_k_heads` key heads serving `gdn_v_heads` value heads. The
+    output's norm is over a head's columns with a plain weight (never
+    zero-centred), then times SiLU(z)."""
+    from ray_tpu.ops.kda import kda_chunked, l2_normalize, short_conv
+
+    f32 = jnp.float32
+    qk = jnp.einsum("bsd,dcnh->bscnh", h, _w(layer, "gdn_wqk", cfg))
+    vz = jnp.einsum("bsd,dcnh->bscnh", h, _w(layer, "gdn_wvz", cfg))
+    ba = jnp.einsum("bsd,dcn->bscn", h, _w(layer, "gdn_wba", cfg))
+    qk = jax.nn.silu(short_conv(qk, layer["gdn_conv_qk"]))
+    v = jax.nn.silu(short_conv(vz[:, :, 0], layer["gdn_conv_v"]))
+    q, k = l2_normalize(qk[:, :, 0]), l2_normalize(qk[:, :, 1])
+    beta = jax.nn.sigmoid(ba[:, :, 0].astype(f32))
+    g = -jnp.exp(layer["gdn_A_log"].astype(f32)) * jax.nn.softplus(
+        ba[:, :, 1].astype(f32) + layer["gdn_dt_bias"].astype(f32))
+    with jax.named_scope("gdn.core"):
+        o, _ = kda_chunked(q, k, v, g, beta, chunk=cfg.gdn_chunk)
+    o = _norm(o.astype(f32), layer["gdn_o_norm"], None, "rmsnorm",
+              cfg.norm_eps)
+    o = (o * jax.nn.silu(vz[:, :, 1].astype(f32))).astype(h.dtype)
+    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "gdn_wo", cfg))
 
 
 def _mamba_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
@@ -884,6 +1044,42 @@ def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params,
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg))
 
 
+def _attn_mixer(cfg: TransformerConfig, kind: Tuple[str, str], h: jax.Array,
+                layer: Params, positions: jax.Array,
+                overlap: Optional[tp.Overlap]):
+    """Softmax attention ("attn", or "swa" under its window) -> (delta, k,
+    v). Where `cfg.attn_gated`, what a plain layer lacks (the q / k norms,
+    a rotation of part of a head, the output's gate) runs under the scope
+    `gattn.gate`; at the defaults nothing of it is traced."""
+    mixer = kind[0]
+    B, S, _ = h.shape
+    gated = lambda: (jax.named_scope("gattn.gate") if cfg.attn_gated
+                     else contextlib.nullcontext())
+    with gated():
+        q, k, v = _qkv_proj(cfg, h, layer, positions, mixer, overlap)
+    q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
+    attend = functools.partial(attention, q, k, v, causal=True,
+                               scale=cfg.attn_scale)
+    if mixer == "swa":  # the scope tells its kernels from a full layer's
+        with jax.named_scope("swa"):
+            o = attend(window=cfg.sliding_window)
+    else:
+        o = attend()
+    if cfg.attn_out_gate:
+        with gated():
+            gate = jnp.einsum("bsd,dnh->bsnh", _whole(h, overlap),
+                              _w(layer, "wq_gate", cfg))
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+    o, wo, delta = o.reshape(B, S, -1), _w(layer, "wo", cfg), None
+    if overlap is not None:
+        delta = overlap.matmul_scatter(
+            "wo", o, "bsf,fd->bsd", wo,
+            _layer_shapes(cfg, kind)["wo"][1])
+    if delta is None:
+        delta = o @ wo
+    return delta, k, v
+
+
 def _whole(x: jax.Array, overlap: Optional[tp.Overlap]) -> jax.Array:
     """x with its rows gathered where a plan has cut them over `tensor`."""
     return x if overlap is None else maybe_constrain(x, tp.ACTIVATION)
@@ -912,7 +1108,7 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     residual = tp.RESIDUAL if overlap else tp.ACTIVATION
     whole = functools.partial(_whole, overlap=overlap)
     h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
-              cfg.norm_eps)
+              cfg.norm_eps, cfg.norm_offset)
     k = v = None
     if mixer == "kda":
         with jax.named_scope("kda"):
@@ -923,26 +1119,16 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     elif mixer == "mamba2":
         with jax.named_scope("mamba"):
             delta = _mamba_mixer(cfg, whole(h), layer)
-    else:
-        q, k, v = _qkv_proj(cfg, h, layer, positions, mixer, overlap)
-        q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
-        attend = functools.partial(attention, q, k, v, causal=True,
-                                   scale=cfg.attn_scale)
-        if mixer == "swa":  # the scope tells its kernels from a full layer's
-            with jax.named_scope("swa"):
-                o = attend(window=cfg.sliding_window)
-        else:
-            o = attend()
-        o, wo, delta = o.reshape(B, S, -1), _w(layer, "wo", cfg), None
-        if overlap is not None:
-            delta = overlap.matmul_scatter(
-                "wo", o, "bsf,fd->bsd", wo,
-                _layer_shapes(cfg, kind)["wo"][1])
-        if delta is None:
-            delta = o @ wo
+    elif mixer == "gdn":
+        with jax.named_scope("gdn"):
+            delta = _gdn_mixer(cfg, whole(h), layer)
+    else:  # a gated layer runs under a scope that tells its kernels apart
+        with (jax.named_scope("gattn") if cfg.attn_gated
+              else contextlib.nullcontext()):
+            delta, k, v = _attn_mixer(cfg, kind, h, layer, positions, overlap)
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
     h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
-              cfg.norm_eps)
+              cfg.norm_eps, cfg.norm_offset)
     delta, extras = _mlp_block(cfg, ffn, h if ffn == "dense" else whole(h),
                                layer, overlap)
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
@@ -1066,7 +1252,7 @@ def final_hidden_and_head(
     can never drift. The norm works the rows a rank holds of the residual
     (parallel/tensor_overlap.py); they are gathered before the head."""
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm,
-              cfg.norm_eps)
+              cfg.norm_eps, cfg.norm_offset)
     x = _whole(x, tp.plan(*x.shape[:2]))
     head = params.get("lm_head", None)
     if head is None:

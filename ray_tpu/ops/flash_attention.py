@@ -74,10 +74,22 @@ DEFAULT_BLOCK = 512
 # A block of twice the window wins over one of the window: a block's first
 # step fetches and starts once for twice the rows, and the cells of the
 # second key block it then skips cost nothing.
+# Heads of 256 (`probe_flash.py gated` on a v5e at [1,16384,16|2,256], causal;
+# chip run PR 41, `p41a`; ms a call forward / dQ / dK/dV and their sum; the
+# fallback this shape took before, 512 x 512 with no strips, first):
+#   512/512  18.45 / 22.46 / 27.75 = 68.65 | 512/128 74.48 | 512/256 69.78
+#   1024/128 17.68 / 19.02 / 24.24 = 60.94 | 1024/256 15.44 / 19.10 / 24.41 = 58.94
+#   1024/512 14.62 / 19.35 / 24.76 = 58.73 | 2048/128 96.01 | 2048/256 95.47
+#   2048/512 13.52 / 38.56 / 46.83 = 98.91
+# K and V of a block are twice the bytes of the 128 row: at 2,048 the forward
+# still gains (13.5 ms) and both backward kernels lose a factor of 1.9 (VMEM
+# pushes back), so the block is 1,024; the strip of 512 is 0.2 ms ahead of
+# 256 and unrolls half as many strips in the trace.
 _TILES = {
     (64, 64): (1024, 256),
     (128, 128): (2048, 256),
     (192, 128): (1024, 256),
+    (256, 256): (1024, 512),
 }
 _NEG_INF = -1e30
 

@@ -1,9 +1,26 @@
-"""Kimi Delta Attention (KDA): a gated delta rule with a per-channel decay.
+"""The gated delta rule, both ways it is published: Kimi Delta Attention
+(KDA), whose decay is one number a key channel, and Gated DeltaNet, whose
+decay is one number a head and whose value heads share key heads.
 
-Per head, with a state S [d_k, d_v] in place of keys and values:
+Per value head, with a state S [d_k, d_v] in place of keys and values:
 
     S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T
-    o_t = S_t^T q_t * scale,        a_t = exp(g_t), g_t <= 0 per key channel
+    o_t = S_t^T q_t * scale,        a_t = exp(g_t), g_t <= 0
+
+One core serves both, told apart by shapes alone (no flag): `g` of rank 4
+[B,S,H,d_k] is a decay a channel, of rank 3 [B,S,H] the same number in every
+channel of a head; `q` and `k` with H_k heads where `v` has H_v = r H_k serve
+r value heads each (key head i the value heads r i .. r i + r - 1). Which
+body runs where: `kda_recurrent` takes both natively (the tests' oracle);
+the chunked bodies and the two Pallas kernels are the per-channel rule's, and
+a scalar-decay or shared-key call reaches them as a broadcast (`_per_channel`:
+g over the channels, q and k repeated over their value heads, done outside
+the kernels, so autodiff sums dg over the channels and dq, dk over the value
+heads). What the scalar case could skip and does not yet: e^(G_t - G_s) is a
+[C, C] matrix there and needs neither the sub-blocks' references nor the cap
+below, k could be fetched once for the value heads that share it, and g, dg
+are [B,S,H] (chipbench/reduce/qwen3_next_counts.py counts that lighter rule;
+PERF.md section 7). A per-channel call with H_k = H_v traces what it traced.
 
 `kda_recurrent` is that recurrence, one token at a time (tests, and the shape
 a decode step will take). The training path runs the chunked form: the
@@ -93,13 +110,36 @@ def l2_normalize(x: jax.Array, eps: float = 1e-6) -> jax.Array:
             ).astype(x.dtype)
 
 
+def _per_channel(q, k, v, g):
+    """(q, k, g) as the per-channel rule takes them: q and k repeated over
+    the value heads that share them (H_v = r H_k: key head i serves value
+    heads r i .. r i + r - 1), a decay a head [B,S,H] broadcast over the key
+    channels. A call that is per-channel already comes back as it is:
+    nothing is traced for it."""
+    r = v.shape[2] // q.shape[2]
+    if r > 1:
+        q, k = jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2)
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], g.shape + q.shape[-1:])
+    return q, k, g
+
+
 def kda_recurrent(q, k, v, g, beta, *, scale: Optional[float] = None,
                   initial_state: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Token-by-token KDA. q, k [B,S,H,dk]; v [B,S,H,dv]; g [B,S,H,dk] (log
-    decay); beta [B,S,H] -> (o [B,S,H,dv] in v's dtype, S [B,H,dk,dv] f32)."""
-    B, S, H, dk = q.shape
-    dv = v.shape[-1]
+    """Token by token. q, k [B,S,Hk,dk]; v [B,S,H,dv], H = r Hk; g the log
+    decay, [B,S,H,dk] (a channel) or [B,S,H] (a head); beta [B,S,H] ->
+    (o [B,S,H,dv] in v's dtype, S [B,H,dk,dv] f32). The scalar decay and
+    the shared key heads are taken as they come, not through
+    `_per_channel`: the chunked bodies' oracle owes them nothing."""
+    B, S, H = beta.shape
+    dk, dv = q.shape[-1], v.shape[-1]
+    r = H // q.shape[2]
+    if r > 1:  # value head j reads key head j // r
+        of = jnp.arange(H) // r
+        q, k = q[:, :, of], k[:, :, of]
+    if g.ndim == 3:
+        g = g[..., None]  # exp(g) meets every channel of the state's rows
     scale = dk ** -0.5 if scale is None else scale
     s0 = (jnp.zeros((B, H, dk, dv), _F32) if initial_state is None
           else initial_state.astype(_F32))
@@ -170,6 +210,7 @@ def kda_chunked_xla(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
     `kda_recurrent`. `chunk` is 16 * 2^j; a sequence that is not a multiple
     of it is padded at its end with tokens that leave the state as it is
     (k = v = beta = g = 0)."""
+    q, k, g = _per_channel(q, k, v, g)
     B, S, H, dk = q.shape
     dv = v.shape[-1]
     scale = dk ** -0.5 if scale is None else scale
@@ -547,9 +588,11 @@ def _specs(B, H, N, C, dk, dv, hb, rev):
         brow=pl.BlockSpec((1, hb, 1, C), lambda b, h, n: (b, h, 0, nn(n))))
 
 
-def _call(kernel, rev, operands, ins, outs, C, scratch=(), **kw):
-    """The pallas_call both kernels make, under the `kda.core` scope
-    (chipbench/reduce/scopes.py finds the core's device time by it).
+def _call(kernel, rev, operands, ins, outs, C, scope, scratch=(), **kw):
+    """The pallas_call both kernels make, under the scope `scope`:
+    `kda.core`, or `gdn.core` for a scalar-decay call
+    (chipbench/reduce/scopes.py finds the core's device time by it; the
+    backward rule is traced outside the mixer that opened its own).
     `operands` start with q, k, v, g, beta; `ins` are their `_specs` keys,
     `outs` (key, dtype) of each result."""
     q, v, beta = operands[0], operands[2], operands[4]
@@ -560,7 +603,7 @@ def _call(kernel, rev, operands, ins, outs, C, scratch=(), **kw):
     shape = dict(k=q.shape, v=v.shape, state=(B, H, dk, dv),
                  states=(B, H, N, dk, dv), tinv=(B, H, N, C, C),
                  brow=(B, H, 1, S))
-    with jax.named_scope("kda.core"):
+    with jax.named_scope(scope):
         return pl.pallas_call(
             functools.partial(kernel, hb=hb, **kw),
             grid=(B, H // hb, N),
@@ -574,7 +617,7 @@ def _call(kernel, rev, operands, ins, outs, C, scratch=(), **kw):
         )(*operands)
 
 
-def _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub):
+def _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub, scope):
     """q, k, g [B, S, H*dk], v [B, S, H*dv], beta [B, S, H], s0 [B,H,dk,dv]
     -> o [B, S, H*dv], the final state, the chunk-start states
     [B,H,N,dk,dv] float32 and the chunks' inverses [B,H,N,C,C]."""
@@ -583,35 +626,38 @@ def _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub):
         ins=("k", "k", "v", "k", "beta", "state"),
         outs=(("v", v.dtype), ("state", _F32), ("states", _F32),
               ("tinv", q.dtype)),
-        C=C, scratch=[pltpu.VMEM((C, C), _F32)], scale=scale, sub=sub)
+        C=C, scope=scope, scratch=[pltpu.VMEM((C, C), _F32)], scale=scale,
+        sub=sub)
 
 
-def _kda_bwd_call(q, k, v, g, beta, states, tinv, do, dsf, scale, C, sub):
+def _kda_bwd_call(q, k, v, g, beta, states, tinv, do, dsf, scale, C, sub,
+                  scope):
     dq, dk, dv, dg, db, ds0 = _call(
         _bwd_kernel, True, (q, k, v, g, beta, states, tinv, do, dsf),
         ins=("k", "k", "v", "k", "beta", "states", "tinv", "v", "state"),
         outs=(("k", q.dtype), ("k", k.dtype), ("v", v.dtype), ("k", _F32),
               ("brow", _F32), ("state", _F32)),
-        C=C, scale=scale, sub=sub)
+        C=C, scope=scope, scale=scale, sub=sub)
     return dq, dk, dv, dg, jnp.swapaxes(db[:, :, 0], 1, 2), ds0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def _kda_kernels(q, k, v, g, beta, s0, scale, C, sub):
-    o, sf, _, _ = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _kda_kernels(q, k, v, g, beta, s0, scale, C, sub, scope):
+    o, sf, _, _ = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub, scope)
     return o, sf
 
 
-def _vjp_fwd(q, k, v, g, beta, s0, scale, C, sub):
-    o, sf, states, tinv = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub)
+def _vjp_fwd(q, k, v, g, beta, s0, scale, C, sub, scope):
+    o, sf, states, tinv = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub,
+                                        scope)
     o, states, tinv = (checkpoint_name(a, n) for a, n in zip(
         (o, states, tinv), RESIDUAL_NAMES))
     return (o, sf), (q, k, v, g, beta, states, tinv)
 
 
-def _vjp_bwd(scale, C, sub, res, cts):
+def _vjp_bwd(scale, C, sub, scope, res, cts):
     do, dsf = cts
-    return _kda_bwd_call(*res, do, dsf.astype(_F32), scale, C, sub)
+    return _kda_bwd_call(*res, do, dsf.astype(_F32), scale, C, sub, scope)
 
 
 _kda_kernels.defvjp(_vjp_fwd, _vjp_bwd)
@@ -624,6 +670,8 @@ def kda_chunked_pallas(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
     """`kda_chunked_xla` as two Pallas kernels under a custom VJP (module
     docstring). The dispatcher `kda_chunked` comes here on the TPU; tests
     come here directly and run the kernels in interpret mode."""
+    scope = "gdn.core" if g.ndim == 3 else "kda.core"
+    q, k, g = _per_channel(q, k, v, g)
     B, S, H, dk = q.shape
     dv = v.shape[-1]
     scale = dk ** -0.5 if scale is None else scale
@@ -636,7 +684,7 @@ def kda_chunked_pallas(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
           else initial_state.astype(_F32))
     o, s = _kda_kernels(flat(q), flat(k.astype(q.dtype)),
                         flat(v.astype(q.dtype)), flat(g.astype(_F32)),
-                        beta.astype(_F32), s0, scale, C, sub)
+                        beta.astype(_F32), s0, scale, C, sub, scope)
     return o.reshape(B, -1, H, dv)[:, :S].astype(v.dtype), s
 
 
@@ -657,18 +705,26 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
                 scale: Optional[float] = None,
                 initial_state: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Chunked KDA, same arguments and results as `kda_recurrent`: the
-    Pallas kernels where `use_kernels` says so, else `kda_chunked_xla`.
-    Each traced call counts once in the phase table, as `kda.core.pallas`
-    or `kda.core.xla` (layers under one scan trace once)."""
+    """The chunked delta rule, same arguments and results as
+    `kda_recurrent`: the Pallas kernels where `use_kernels` says so, else
+    `kda_chunked_xla`. Each traced call counts once in the phase table
+    (layers under one scan trace once): a per-channel call as
+    `kda.core.pallas` or `kda.core.xla`, a scalar-decay one as
+    `gdn.core.pallas` or `gdn.core.xla` with what it observed (`chunk`,
+    `chunks`, `k_heads`, `v_heads`, `decay="head"`)."""
     from ray_tpu.parallel.sharding import current_sharding_ctx
     from ray_tpu.util import tracing
 
     ctx = current_sharding_ctx()
     kernels = use_kernels(jax.devices()[0].platform, q.shape[-1], v.shape[-1],
                           chunk, ctx is not None and ctx[0].size > 1)
-    tracing.observe("kda.core.pallas" if kernels else "kda.core.xla", 0,
-                    slow=False)
+    path = "pallas" if kernels else "xla"
+    if g.ndim == 3:
+        tracing.observe("gdn.core." + path, 0, slow=False, chunk=chunk,
+                        chunks=-(-q.shape[1] // chunk), k_heads=q.shape[2],
+                        v_heads=v.shape[2], decay="head")
+    else:
+        tracing.observe("kda.core." + path, 0, slow=False)
     body = kda_chunked_pallas if kernels else kda_chunked_xla
     return body(q, k, v, g, beta, chunk=chunk, sub=sub, scale=scale,
                 initial_state=initial_state)

@@ -69,6 +69,29 @@ def test_kda_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_gdn_core_compiles_for_v5e(one_chip, compiled_not_interpreted, what):
+    """1 x 16,384 tokens, 32 value heads over 16 key heads of 128, a decay a
+    head, chunks of 128, bfloat16: the shape of
+    `qwen3_next_80b_a3b.train_rank16_16k`. The scalar-decay, shared-key call
+    reaches the same two Mosaic calls (a broadcast outside them) and no
+    [B,H,S,d] copy of an input."""
+    B, S, Hk, Hv, d = 1, 16384, 16, 32, 128
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    q, v = sd((B, S, Hk, d), jnp.bfloat16), sd((B, S, Hv, d), jnp.bfloat16)
+    g = beta = sd((B, S, Hv), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        o, _ = kda.kda_chunked_pallas(q, k, v, g, beta, chunk=128)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    fn = loss if what == "forward" else jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(fn).lower(q, q, v, g, beta).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if what == "forward" else 2)
+    assert f"[{B},{Hv},{S},{d}]" not in text
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
 def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
                                      what):
     """1 x 4096 tokens, 64 heads of 64, state 128, one group, chunks of 256,
@@ -100,8 +123,9 @@ def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     (1, 16384, 32, 4, 128, 128, None),   # mellum2_12b_a2_5b.train_share_16k,
     (1, 16384, 32, 4, 128, 128, 1024),   # its full layer and a windowed one
     (1, 16384, 32, 32, 192, 128, None),  # kanana_2_30b_a3b.train_rank8_16k
+    (1, 16384, 16, 2, 256, 256, None),   # qwen3_next_80b_a3b.train_rank16_16k
 ], ids=["gpt2", "internlm2_shard", "kimi_mla", "granite_gqa64",
-        "mellum2_full", "mellum2_swa", "kanana_mla"])
+        "mellum2_full", "mellum2_swa", "kanana_mla", "qwen3_next_gattn"])
 def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
                                        B, S, H, KVH, D, Dv, window):
     """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape, bfloat16, causal: three Mosaic calls in the gradient's
@@ -123,7 +147,8 @@ def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
 @pytest.mark.parametrize("T,E,first,Eh,F,kind,d,k", [
     (16384, 64, 16, 16, 896, "softmax", 2304, 8),
     (8192, 256, 104, 8, 1024, "sigmoid", 2304, 8),
-    (16384, 128, 48, 16, 768, "sigmoid", 2048, 6)])
+    (16384, 128, 48, 16, 768, "sigmoid", 2048, 6),
+    (16384, 512, 224, 32, 512, "softmax", 2048, 10)])
 def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
                                       monkeypatch, T, E, first, Eh, F, kind,
                                       d, k):
@@ -131,7 +156,9 @@ def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
     `mellum2_12b_a2_5b.train_share_16k`,
     `kimi_linear_48b_a3b.train_share_8k` (d 2304, 8 experts a token) and
     `kanana_2_30b_a3b.train_rank8_16k` (d 2048, 6 a token, experts 768 wide:
-    `_tiles` takes 1024 x 768 and 768 x 1024), bfloat16, with the grouped
+    `_tiles` takes 1024 x 768 and 768 x 1024) and
+    `qwen3_next_80b_a3b.train_rank16_16k` (32 of 512 experts 512 wide, 10 a
+    token: a first window of 25,600 rows), bfloat16, with the grouped
     products dispatched as on the chip: the
     first window's two products and their four transposes are the Pallas
     grouped matmul at `_tiles` (`gmm` / `tgmm` in the program's text); the
