@@ -12,15 +12,25 @@ One core serves both, told apart by shapes alone (no flag): `g` of rank 4
 channel of a head; `q` and `k` with H_k heads where `v` has H_v = r H_k serve
 r value heads each (key head i the value heads r i .. r i + r - 1). Which
 body runs where: `kda_recurrent` takes both natively (the tests' oracle);
-the chunked bodies and the two Pallas kernels are the per-channel rule's, and
-a scalar-decay or shared-key call reaches them as a broadcast (`_per_channel`:
-g over the channels, q and k repeated over their value heads, done outside
-the kernels, so autodiff sums dg over the channels and dq, dk over the value
-heads). What the scalar case could skip and does not yet: e^(G_t - G_s) is a
-[C, C] matrix there and needs neither the sub-blocks' references nor the cap
-below, k could be fetched once for the value heads that share it, and g, dg
-are [B,S,H] (chipbench/reduce/qwen3_next_counts.py counts that lighter rule;
-PERF.md section 7). A per-channel call with H_k = H_v traces what it traced.
+`kda_chunked_xla` is the per-channel rule's and takes a scalar-decay or
+shared-key call as a broadcast (`_per_channel`: g over the channels, q and k
+repeated over their value heads, so autodiff sums dg over the channels and
+dq, dk over the value heads); the Pallas path has a forward and a backward
+kernel for each rule, picked by g's rank. What the scalar-decay kernels skip:
+e^(G_t - G_s) is ONE masked [C, C] float32 matrix D a value head, every entry
+at most 1, so there are no sub-blocks, no references and no cap; K K^T and
+Q K^T are one product each a KEY head (a grid step holds two key heads and
+the r value heads each serves, q and k fetched once), A = beta (K K^T . D) and
+P = Q K^T . D elementwise a value head; e^G and e^(G_C - G) are [C, 1]
+columns that scale rows; g is read and dg written [B,S,H] as beta and dbeta
+are, dq and dk are summed over a key head's value heads inside the kernel,
+and dG is the row sums less the column sums of one float32 matrix dA . A +
+dP . P, so a pair's term enters dg at its two tokens with the same rounding
+(the per-channel backward writes it through two bfloat16 products). The
+inverse, W, U and the state's recurrence are the same functions in both
+(chipbench/reduce/qwen3_next_counts.py counts the scalar rule). A per-channel
+call with H_k = H_v traces what it traced; one with shared key heads (no
+configuration has it) keeps `_per_channel`'s repeat.
 
 `kda_recurrent` is that recurrence, one token at a time (tests, and the shape
 a decode step will take). The training path runs the chunked form: the
@@ -52,7 +62,9 @@ goes into the state update in float32.
 
 - **`kda_chunked_pallas`**: two Pallas (Mosaic) kernels under a
   `jax.custom_vjp`, on a TPU when d_k, d_v and the chunk are multiples of
-  128 and no multi-device mesh is active. Grid (batch, head pair, chunk),
+  128 and no multi-device mesh is active. What follows is the per-channel
+  pair; the scalar-decay pair differs as said above and shares the grid's
+  shape, the residuals and their names. Grid (batch, head pair, chunk),
   the chunk axis sequential. q, k, v, g are read where they lie: [B, S, H, d] is
   [B, S, H*d] and a head is a d-lane column block of it (no [B,H,S,d]
   copies). A grid step holds one chunk of two neighbouring heads (one if the
@@ -196,9 +208,8 @@ def _pad_to_chunks(q, k, v, g, beta, C):
     pad = (-q.shape[1]) % C
     if not pad:
         return q, k, v, g, beta
-    q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                  for a in (q, k, v, g))
-    return q, k, v, g, jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    return tuple(jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                 for a in (q, k, v, g, beta))
 
 
 def kda_chunked_xla(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
@@ -324,6 +335,16 @@ def _flip(x):
     n = max(x.shape)
     t = jnp.transpose(jnp.broadcast_to(x, (n, n)))
     return t[:, :1] if x.shape[0] == 1 else t[:1]
+
+
+def _rows(x):
+    """Row sums [C, 1]."""
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _total(x):
+    """The sum of a tile [1, 1]: down the sublanes first."""
+    return jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1, keepdims=True)
 
 
 def _tree_sum(xs):
@@ -567,18 +588,180 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, t_ref, do_ref,
         ds0_ref[0] = ds_scr[...]
 
 
+# --------------------------------------------- one decay a head, shared keys
+#
+# A grid step is one chunk of two neighbouring KEY heads (one if their count
+# is odd) and the r value heads each serves: K K^T and Q K^T once a key head,
+# everything that holds a decay once a value head.
+
+
+def _scalar_terms(qf, kf, v, g, beta, kk, qk, mm):
+    """A chunk's quantities that need no state at ONE decay a head: qf, kf
+    [C, dk] float32 and kk = K K^T, qk = Q K^T [C, C] (the key head's, from
+    operands in the compute type `mm`), v [C, dv] in `mm`, g and beta [C, 1]
+    float32 (the value head's). e^(G_t - G_s) is the one masked [C, C]
+    matrix D, every entry at most 1: no sub-block, no reference, no cap."""
+    C = qf.shape[0]
+    vf = v.astype(_F32)
+    t, s = _iota((C, C), 0), _iota((C, C), 1)
+    # The running log-decay: G_s on the lanes, then G_t in every lane of row t.
+    Gr = jnp.sum(jnp.where(t <= s, g, 0.0), axis=0, keepdims=True)
+    Gb = jnp.transpose(jnp.broadcast_to(Gr, (C, C)))
+    G = Gb[:, :1]
+    D = jnp.exp(jnp.where(t >= s, Gb - Gr, -jnp.inf))   # 0 above the diagonal
+    Ds = jnp.where(t > s, D, 0.0)                       # strictly lower
+    pf = qk * D
+    eG, Gl = jnp.exp(G), Gr[:, C - 1:]
+    ekb, be = jnp.exp(Gl - G), beta * eG
+    return dict(kf=kf, qf=qf, vf=vf, beta=beta, kk=kk, D=D, Ds=Ds, pf=pf,
+                eG=eG, ekb=ekb, be=be, a=kk * Ds * beta, p=pf.astype(mm),
+                rhs_w=(kf * be).astype(mm), rhs_u=(vf * beta).astype(mm),
+                qt=(qf * eG).astype(mm), kbar=(kf * ekb).astype(mm),
+                decay=jnp.exp(Gl))                          # [1, 1]
+
+
+def _scalar_key_head(q_ref, k_ref, kl):
+    """(q, k, qf, kf, K K^T, Q K^T) of the key head in lanes `kl`."""
+    q, k = q_ref[0, :, kl], k_ref[0, :, kl]
+    return (q, k, q.astype(_F32), k.astype(_F32), _dot(k, k, _NT),
+            _dot(q, k, _NT))
+
+
+def _scalar_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
+                       o_ref, sf_ref, st_ref, t_ref, s_scr, inv_scr, *,
+                       scale, hb):
+    """`_fwd_kernel` at one decay a head: `hb` value heads, r of them to
+    each key head of the q, k block, g read [C, H] as beta is."""
+    n = pl.program_id(2)
+    dk, dv = s_scr.shape[1:]
+    r = hb * dk // q_ref.shape[2]
+
+    @pl.when(n == 0)
+    def _init():
+        s_scr[...] = s0_ref[0]
+
+    for i in range(hb // r):
+        q, k, qf, kf, kk, qk = _scalar_key_head(
+            q_ref, k_ref, slice(i * dk, (i + 1) * dk))
+        for j in range(i * r, (i + 1) * r):
+            vl, h = slice(j * dv, (j + 1) * dv), pl.program_id(1) * hb + j
+            s = s_scr[j]
+            x = _scalar_terms(qf, kf, v_ref[0, :, vl],
+                              _head_col(g_ref[0], h),
+                              _head_col(beta_ref[0], h), kk, qk, q.dtype)
+            tb = _inv_unit_lower_vmem(x["a"], inv_scr).astype(q.dtype)
+            w, u0, ub, sm = _chunk_solve(x, tb, s)
+            o = (_dot(x["qt"], sm) + _dot(x["p"], ub)) * scale
+            o_ref[0, :, vl] = o.astype(o_ref.dtype)
+            st_ref[0, j, 0] = s
+            t_ref[0, j, 0] = tb
+            s_scr[j] = s * x["decay"] + _dot(x["kbar"], ub, _TN)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _flush():
+        sf_ref[0] = s_scr[...]
+
+
+def _scalar_bwd_head(x, tb, s, do, ds1, scale):
+    """One chunk of one value head in the reverse sweep, as `_bwd_chunk`:
+    -> its terms of dq and dk [C, dk] float32, of dKK and dQK [C, C], dv,
+    dg and dbeta as rows [1, C], and the cotangent of the start state. dA
+    and dP stay float32: they meet D elementwise, and M = dA . A + dP . P is
+    one float32 matrix whose row sums less column sums are dG, so a pair's
+    term leaves dg between its two tokens exactly as it entered."""
+    mm = tb.dtype
+    C = tb.shape[0]
+    kf, qf, beta = x["kf"], x["qf"], x["beta"]
+    w, u0, ub, sm = _chunk_solve(x, tb, s)
+    ds1b = ds1.astype(mm)
+    do = (do.astype(_F32) * scale).astype(mm)
+    t, s_i = _iota((C, C), 0), _iota((C, C), 1)
+    # o = qt S + P U;  S' = decay S + kbar^T U;  U = U0 - W S.
+    dqt = _dot(do, sm, _NT)
+    dp = _dot(do, ub, _NT)
+    dub = (_dot(x["p"], do, _TN) + _dot(x["kbar"], ds1b)).astype(mm)
+    dkbar = _dot(ub, ds1b, _NT)
+    dw = (-_dot(dub, sm, _NT)).astype(mm)
+    ds0 = ds1 * x["decay"] + _dot(x["qt"], do, _TN) - _dot(w, dub, _TN)
+    ddecay = _total(ds1 * s)
+    # dA = -(drhs_w W^T + drhs_u U0^T), as in `_bwd_chunk`.
+    drhs_w, drhs_u = _dot(tb, dw, _TN), _dot(tb, dub, _TN)
+    da = -(_dot(drhs_w.astype(mm), w, _NT)
+           + _dot(drhs_u.astype(mm), u0.astype(mm), _NT))
+    # A = beta (K K^T . D) strictly lower, P = Q K^T . D lower.
+    dad = da * x["Ds"]
+    e = dad * x["kk"]
+    m = e * beta + dp * x["pf"]
+    dq, dkb = dqt * x["eG"], dkbar * x["ekb"]
+    c_w, hk = _rows(drhs_w * kf), dkb * kf
+    # G enters D (rows less columns of m), e^G (rhs_w, qt) and e^(G_C - G)
+    # (kbar); G_C enters kbar and the chunk's decay.
+    dG = _rows(m) + c_w * x["be"] + _rows(dq * qf - hk)
+    dGl = _total(hk) + ddecay * x["decay"]
+    dG = dG - _flip(jnp.sum(m, axis=0, keepdims=True))
+    # G = cumsum(g), G_C the whole chunk's sum: dg_t = sum_{s >= t} dG_s +
+    # dG_C, written as dbeta is: a row.
+    to_row = lambda col, keep: jnp.sum(jnp.where(keep, col, 0.0), axis=0,
+                                       keepdims=True)
+    dg = to_row(dG, t >= s_i) + dGl
+    dbeta = _rows(e) + c_w * x["eG"] + _rows(drhs_u * x["vf"])
+    return (dq, drhs_w * x["be"] + dkb, dad * beta, dp * x["D"],
+            drhs_u * beta, dg, to_row(dbeta, t == s_i), ds0)
+
+
+def _scalar_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, t_ref,
+                       do_ref, dsf_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                       db_ref, ds0_ref, ds_scr, *, scale, hb):
+    """`_bwd_kernel` at one decay a head: dq and dk summed over a key
+    head's r value heads here, dg written as dbeta is, [1, C] rows."""
+    n = pl.program_id(2)
+    dk, dv = ds_scr.shape[1:]
+    r = hb * dk // q_ref.shape[2]
+
+    @pl.when(n == 0)
+    def _init():
+        ds_scr[...] = dsf_ref[0]
+
+    for i in range(hb // r):
+        kl = slice(i * dk, (i + 1) * dk)
+        q, k, qf, kf, kk, qk = _scalar_key_head(q_ref, k_ref, kl)
+        shared = []  # (dq, dk, dKK, dQK) of each value head
+        for j in range(i * r, (i + 1) * r):
+            vl, h = slice(j * dv, (j + 1) * dv), pl.program_id(1) * hb + j
+            x = _scalar_terms(qf, kf, v_ref[0, :, vl],
+                              _head_col(g_ref[0], h),
+                              _head_col(beta_ref[0], h), kk, qk, q.dtype)
+            *terms, dv_j, dg, dbeta, ds0 = _scalar_bwd_head(
+                x, t_ref[0, j, 0], st_ref[0, j, 0], do_ref[0, :, vl],
+                ds_scr[j], scale)
+            shared.append(terms)
+            dv_ref[0, :, vl] = dv_j.astype(dv_ref.dtype)
+            dg_ref[0, j] = dg
+            db_ref[0, j] = dbeta
+            ds_scr[j] = ds0
+        dq, dk_, dkk, dqk = (_tree_sum(list(a)) for a in zip(*shared))
+        dkk, dqk = dkk.astype(q.dtype), dqk.astype(q.dtype)
+        dq_ref[0, :, kl] = (dq + _dot(dqk, k)).astype(dq_ref.dtype)
+        dk_ref[0, :, kl] = (dk_ + _dot(dqk, q, _TN) + _dot(dkk, k)
+                            + _dot(dkk, k, _TN)).astype(dk_ref.dtype)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _flush():
+        ds0_ref[0] = ds_scr[...]
+
+
 def _heads_per_step(H: int) -> int:
     return 2 if H % 2 == 0 else 1
 
 
-def _specs(B, H, N, C, dk, dv, hb, rev):
+def _specs(B, H, N, C, dk, dv, hb, kb, rev):
     """BlockSpecs over the grid (batch, head block, chunk). q, k, v, g lie
-    [B, S, H*d]: a head is a d-lane column block, read where it lies."""
+    [B, S, H*d]: a head is a d-lane column block, read where it lies; a grid
+    step holds `hb` value heads and the `kb` key heads that serve them."""
     nn = (lambda n: N - 1 - n) if rev else (lambda n: n)
-    tok = lambda d: pl.BlockSpec((1, C, hb * d),
-                                 lambda b, h, n: (b, nn(n), h))
+    tok = lambda w: pl.BlockSpec((1, C, w), lambda b, h, n: (b, nn(n), h))
     return dict(
-        k=tok(dk), v=tok(dv),
+        k=tok(kb * dk), v=tok(hb * dv),
         beta=pl.BlockSpec((1, C, H), lambda b, h, n: (b, nn(n), 0)),
         state=pl.BlockSpec((1, hb, dk, dv), lambda b, h, n: (b, h, 0, 0)),
         states=pl.BlockSpec((1, hb, 1, dk, dv),
@@ -589,17 +772,22 @@ def _specs(B, H, N, C, dk, dv, hb, rev):
 
 
 def _call(kernel, rev, operands, ins, outs, C, scope, scratch=(), **kw):
-    """The pallas_call both kernels make, under the scope `scope`:
+    """The pallas_call every kernel makes, under the scope `scope`:
     `kda.core`, or `gdn.core` for a scalar-decay call
     (chipbench/reduce/scopes.py finds the core's device time by it; the
     backward rule is traced outside the mixer that opened its own).
-    `operands` start with q, k, v, g, beta; `ins` are their `_specs` keys,
-    `outs` (key, dtype) of each result."""
+    `operands` start with q, k, v, g, beta and end with a state
+    [B, H, dk, dv]; `ins` are their `_specs` keys, `outs` (key, dtype) of
+    each result. A grid step holds two neighbouring key heads
+    (`_heads_per_step`: independent chains for the scheduler) and the value
+    heads each serves."""
     q, v, beta = operands[0], operands[2], operands[4]
     B, S, H = beta.shape
-    dk, dv, N = q.shape[-1] // H, v.shape[-1] // H, S // C
-    hb = _heads_per_step(H)
-    sp = _specs(B, H, N, C, dk, dv, hb, rev)
+    dk, dv, N = operands[-1].shape[2], v.shape[-1] // H, S // C
+    r = H * dk // q.shape[-1]           # value heads a key head
+    kb = _heads_per_step(H // r)
+    hb = kb * r
+    sp = _specs(B, H, N, C, dk, dv, hb, kb, rev)
     shape = dict(k=q.shape, v=v.shape, state=(B, H, dk, dv),
                  states=(B, H, N, dk, dv), tinv=(B, H, N, C, C),
                  brow=(B, H, 1, S))
@@ -641,15 +829,46 @@ def _kda_bwd_call(q, k, v, g, beta, states, tinv, do, dsf, scale, C, sub,
     return dq, dk, dv, dg, jnp.swapaxes(db[:, :, 0], 1, 2), ds0
 
 
+def _gdn_fwd_call(q, k, v, g, beta, s0, scale, C, sub, scope):
+    """`_kda_fwd_call` at one decay a head: q, k [B, S, H_k*dk], g [B, S, H]
+    as beta; `sub` has nothing to cut."""
+    return _call(
+        _scalar_fwd_kernel, False, (q, k, v, g, beta, s0),
+        ins=("k", "k", "v", "beta", "beta", "state"),
+        outs=(("v", v.dtype), ("state", _F32), ("states", _F32),
+              ("tinv", q.dtype)),
+        C=C, scope=scope, scratch=[pltpu.VMEM((C, C), _F32)], scale=scale)
+
+
+def _gdn_bwd_call(q, k, v, g, beta, states, tinv, do, dsf, scale, C, sub,
+                  scope):
+    dq, dk, dv, dg, db, ds0 = _call(
+        _scalar_bwd_kernel, True, (q, k, v, g, beta, states, tinv, do, dsf),
+        ins=("k", "k", "v", "beta", "beta", "states", "tinv", "v", "state"),
+        outs=(("k", q.dtype), ("k", k.dtype), ("v", v.dtype), ("brow", _F32),
+              ("brow", _F32), ("state", _F32)),
+        C=C, scope=scope, scale=scale)
+    rows = lambda a: jnp.swapaxes(a[:, :, 0], 1, 2)
+    return dq, dk, dv, rows(dg), rows(db), ds0
+
+
+def _calls(g, beta):
+    """(forward call, backward call) by what they observe: g [B, S, H], as
+    beta lies, is one decay a head; [B, S, H*dk] one a channel."""
+    return ((_gdn_fwd_call, _gdn_bwd_call) if g.shape == beta.shape
+            else (_kda_fwd_call, _kda_bwd_call))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def _kda_kernels(q, k, v, g, beta, s0, scale, C, sub, scope):
-    o, sf, _, _ = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub, scope)
+    o, sf, _, _ = _calls(g, beta)[0](q, k, v, g, beta, s0, scale, C, sub,
+                                     scope)
     return o, sf
 
 
 def _vjp_fwd(q, k, v, g, beta, s0, scale, C, sub, scope):
-    o, sf, states, tinv = _kda_fwd_call(q, k, v, g, beta, s0, scale, C, sub,
-                                        scope)
+    o, sf, states, tinv = _calls(g, beta)[0](q, k, v, g, beta, s0, scale, C,
+                                             sub, scope)
     o, states, tinv = (checkpoint_name(a, n) for a, n in zip(
         (o, states, tinv), RESIDUAL_NAMES))
     return (o, sf), (q, k, v, g, beta, states, tinv)
@@ -657,7 +876,8 @@ def _vjp_fwd(q, k, v, g, beta, s0, scale, C, sub, scope):
 
 def _vjp_bwd(scale, C, sub, scope, res, cts):
     do, dsf = cts
-    return _kda_bwd_call(*res, do, dsf.astype(_F32), scale, C, sub, scope)
+    return _calls(res[3], res[4])[1](*res, do, dsf.astype(_F32), scale, C,
+                                     sub, scope)
 
 
 _kda_kernels.defvjp(_vjp_fwd, _vjp_bwd)
@@ -671,15 +891,16 @@ def kda_chunked_pallas(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
     docstring). The dispatcher `kda_chunked` comes here on the TPU; tests
     come here directly and run the kernels in interpret mode."""
     scope = "gdn.core" if g.ndim == 3 else "kda.core"
-    q, k, g = _per_channel(q, k, v, g)
-    B, S, H, dk = q.shape
-    dv = v.shape[-1]
+    if g.ndim == 4:  # a decay a head takes its operands as they lie
+        q, k, g = _per_channel(q, k, v, g)
+    B, S, H, dv = v.shape
+    dk = q.shape[-1]
     scale = dk ** -0.5 if scale is None else scale
     C = chunk
     sub = min(sub, C)
     assert C % sub == 0 and sub & (sub - 1) == 0, (C, sub)
     q, k, v, g, beta = _pad_to_chunks(q, k, v, g, beta, C)
-    flat = lambda a: a.reshape(B, -1, H * a.shape[-1])
+    flat = lambda a: a.reshape(B, a.shape[1], -1) if a.ndim == 4 else a
     s0 = (jnp.zeros((B, H, dk, dv), _F32) if initial_state is None
           else initial_state.astype(_F32))
     o, s = _kda_kernels(flat(q), flat(k.astype(q.dtype)),
@@ -711,7 +932,9 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
     (layers under one scan trace once): a per-channel call as
     `kda.core.pallas` or `kda.core.xla`, a scalar-decay one as
     `gdn.core.pallas` or `gdn.core.xla` with what it observed (`chunk`,
-    `chunks`, `k_heads`, `v_heads`, `decay="head"`)."""
+    `chunks`, `k_heads`, `v_heads`, `decay="head"`) and the body that runs
+    it: `body="scalar"` (the kernels take the rule as it is) or
+    `"per_channel"` (the XLA body's broadcast)."""
     from ray_tpu.parallel.sharding import current_sharding_ctx
     from ray_tpu.util import tracing
 
@@ -722,7 +945,8 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
     if g.ndim == 3:
         tracing.observe("gdn.core." + path, 0, slow=False, chunk=chunk,
                         chunks=-(-q.shape[1] // chunk), k_heads=q.shape[2],
-                        v_heads=v.shape[2], decay="head")
+                        v_heads=v.shape[2], decay="head",
+                        body="scalar" if kernels else "per_channel")
     else:
         tracing.observe("kda.core." + path, 0, slow=False)
     body = kda_chunked_pallas if kernels else kda_chunked_xla

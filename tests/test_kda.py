@@ -3,6 +3,9 @@ algorithm against the recurrence, the Pallas kernels (interpret mode) against
 both, the dispatch rule, and what a remat policy keeps of the kernels in a
 traced KDA stack. tests/test_kda_kernel_compile.py compiles the kernels for
 the chip."""
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -97,6 +100,79 @@ def test_kda_kernels_match_recurrence_and_xla(S, chunk, sub, dk, dv, init,
         atol = (2e-5 if i < 2 else 5e-5) * float(jnp.abs(b).max())
         np.testing.assert_allclose(a, b, atol=atol, err_msg=f"output {i}")
         np.testing.assert_allclose(a, c, atol=atol, err_msg=f"output {i}")
+
+
+# sha256[:16] of the jaxpr a per-channel call (H_k = H_v, g rank 4) traced at
+# the parent commit (PR 41's tree, this container's JAX, this file's
+# `exact_matmuls`): `_per_channel_jaxpr` below, run on that tree.
+PARENT_JAXPR = "9abd7e0b4ccccd71"
+
+
+def _per_channel_jaxpr():
+    """Loss and all six gradients through the kernels at 300 tokens (padded
+    to three chunks), two heads of 128, bfloat16, an initial state: every
+    equation outside and inside the two `pallas_call`s, source locations
+    stripped."""
+    sd = jax.ShapeDtypeStruct
+    q = sd((1, 300, 2, 128), jnp.bfloat16)
+    g, beta = sd((1, 300, 2, 128), jnp.float32), sd((1, 300, 2), jnp.float32)
+
+    def loss(q, k, v, g, beta, s0):
+        o, s = kda.kda_chunked_pallas(q, k, v, g, beta, chunk=128,
+                                      initial_state=s0)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(s)
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(6)))(
+        q, q, q, g, beta, sd((1, 2, 128, 128), jnp.float32)))
+    return re.sub(r" at [^\s:]+:\d+", "", text)
+
+
+def test_a_per_channel_call_traces_what_it_traced():
+    """The scalar-decay bodies beside them leave the per-channel kernels as
+    they were, operand for operand and equation for equation: the hybrid
+    cell's call is the parent's."""
+    text = _per_channel_jaxpr()
+    assert text.count("pallas_call[") == 2
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_JAXPR
+
+
+def test_decay_gradient_of_a_head_that_forgets_fast():
+    """PR 41's `dg_noise` probe: bfloat16 q, k, v, four value heads on two
+    key heads at A 16 and dt about 0.1 (a decay of e^-1.6 a token), dg
+    summed over the tokens as it reaches `dt_bias`. The scalar body forms dG
+    as the row sums less the column sums of ONE float32 [C, C] matrix, so a
+    pair's term leaves dg between its two tokens exactly as it entered:
+    within 2% of the float32 recurrence's (0.2-0.7% by seed, what the
+    recurrence itself reads on the same bfloat16 inputs). The broadcast
+    into the per-channel body writes the pair at its row and at its column
+    through two bfloat16 products and reads 10-25% off."""
+    S, d, Hk, Hv = 512, 128, 2, 4
+    ks = jax.random.split(jax.random.key(7), 6)
+    q = kda.l2_normalize(jax.random.normal(ks[0], (1, S, Hk, d)))
+    k = kda.l2_normalize(jax.random.normal(ks[1], (1, S, Hk, d)))
+    v = jax.random.normal(ks[2], (1, S, Hv, d))
+    dt = jax.nn.softplus(jax.random.normal(ks[3], (1, S, Hv)) * 0.25 - 2.25)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, S, Hv)))
+    wo = jax.random.normal(ks[5], (1, S, Hv, d))
+    assert 0.08 < float(dt.mean()) < 0.13
+    half = lambda a: a.astype(jnp.bfloat16)
+
+    def d_dt_bias(body, *qkv):
+        def loss(g):
+            return jnp.sum(body(*qkv, g, beta)[0].astype(jnp.float32) * wo)
+        return jax.jit(jax.grad(loss))(-16.0 * dt).sum(1)[0]
+
+    def broadcast(q, k, v, g, beta):
+        q, k, g = kda._per_channel(q, k, v, g)
+        return kda.kda_chunked_pallas(q, k, v, g, beta)
+
+    want = d_dt_bias(kda.kda_recurrent, q, k, v)
+    rel = lambda got: float(jnp.linalg.norm(got - want)
+                            / jnp.linalg.norm(want))
+    scalar = rel(d_dt_bias(kda.kda_chunked_pallas, half(q), half(k), half(v)))
+    old = rel(d_dt_bias(broadcast, half(q), half(k), half(v)))
+    assert scalar < 0.02, (scalar, old)
+    assert old > 0.05, (scalar, old)
 
 
 def test_kda_dispatch_rule():

@@ -73,8 +73,10 @@ def test_gdn_core_compiles_for_v5e(one_chip, compiled_not_interpreted, what):
     """1 x 16,384 tokens, 32 value heads over 16 key heads of 128, a decay a
     head, chunks of 128, bfloat16: the shape of
     `qwen3_next_80b_a3b.train_rank16_16k`. The scalar-decay, shared-key call
-    reaches the same two Mosaic calls (a broadcast outside them) and no
-    [B,H,S,d] copy of an input."""
+    has its own two Mosaic calls (one forward, two in the gradient), which
+    read q, k a key head and g [B,S,H_v] where they lie: no [B,H,S,d] copy
+    of an input, and no float32 [B,S,H_v,d_k] (g over the channels, or its
+    gradient before the sum) anywhere in the compiled program."""
     B, S, Hk, Hv, d = 1, 16384, 16, 32, 128
     sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
     q, v = sd((B, S, Hk, d), jnp.bfloat16), sd((B, S, Hv, d), jnp.bfloat16)
@@ -89,6 +91,7 @@ def test_gdn_core_compiles_for_v5e(one_chip, compiled_not_interpreted, what):
     assert text.count('custom_call_target="tpu_custom_call"') == (
         1 if what == "forward" else 2)
     assert f"[{B},{Hv},{S},{d}]" not in text
+    assert f"f32[{B},{S},{Hv},{d}]" not in text
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])

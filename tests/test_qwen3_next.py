@@ -280,24 +280,39 @@ def _core_inputs(S, Hk, Hv, d, seed=0):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("body", ["xla", "pallas"])
+@pytest.mark.parametrize("body,S,init,strong", [
+    ("xla", 80, False, False),
+    ("pallas", 256, False, False),
+    ("pallas", 300, True, False),   # a state to start from, a ragged end
+    ("pallas", 256, True, True),    # a head that forgets inside a few tokens
+])
 @pytest.mark.parametrize("Hk,Hv", [(2, 2), (2, 4)])
-def test_the_scalar_decay_core_is_the_recurrence(body, Hk, Hv):
+def test_the_scalar_decay_core_is_the_recurrence(body, S, init, strong, Hk,
+                                                 Hv):
     """The chunked core at one decay a head, for H_v = H_k and H_v = 2 H_k
     (key head i serving value heads 2i and 2i + 1), is `kda_recurrent`:
-    outputs, final state and the gradients of all five inputs, through the
-    XLA body and through the kernels (interpret mode)."""
-    S, d = (80, 16) if body == "xla" else (256, 128)
-    chunk = 16 if body == "xla" else 128
+    outputs, final state and the gradients of all five inputs and of the
+    initial state, through the XLA body and through the scalar-decay kernels
+    (interpret mode), which are held to the XLA body too. `strong`: value
+    head 1 decays by e^-4 a token, e^-128 inside one sub-block of 32, past
+    the per-channel bodies' cap of e^80 (their pairs late in the sub-block
+    come out e^-7 for e^-4, so that case is held to the recurrence alone);
+    the scalar body's e^(G_t - G_s) has nothing to cap."""
+    d, chunk = (16, 16) if body == "xla" else (128, 128)
     fn = kda.kda_chunked_xla if body == "xla" else kda.kda_chunked_pallas
-    args = _core_inputs(S, Hk, Hv, d)
+    q, k, v, g, beta = _core_inputs(S, Hk, Hv, d)
+    if strong:
+        g = g.at[:, :, 1].set(-4.0)
+    s0 = (jax.random.normal(jax.random.key(9), (2, Hv, d, d)) if init
+          else jnp.zeros((2, Hv, d, d)))
+    args = (q, k, v, g, beta, s0)
 
     def run(f, **kw):
-        def loss(*a):
-            o, s = f(*a, **kw)
+        def loss(q, k, v, g, beta, s0):
+            o, s = f(q, k, v, g, beta, initial_state=s0, **kw)
             return jnp.sum(jnp.sin(o)) + jnp.sum(s * s), (o, s)
-        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
-                                  has_aux=True)(*args)
+        return jax.jit(jax.value_and_grad(loss, argnums=range(6),
+                                          has_aux=True))(*args)
 
     (_, (o, s)), grads = run(fn, chunk=chunk)
     (_, (o_r, s_r)), grads_r = run(kda.kda_recurrent)
@@ -306,13 +321,100 @@ def test_the_scalar_decay_core_is_the_recurrence(body, Hk, Hv):
     np.testing.assert_allclose(s, s_r, atol=2e-5)
     for got, want in zip(grads, grads_r):
         assert got.shape == want.shape
-        np.testing.assert_allclose(got, want, rtol=2e-5, atol=5e-5)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=5e-5 * max(
+            1.0, float(jnp.abs(want).max())))
+    if body == "pallas" and not strong:
+        (_, (o_x, s_x)), grads_x = run(kda.kda_chunked_xla, chunk=chunk)
+        for got, want in zip((o, s) + grads, (o_x, s_x) + grads_x):
+            np.testing.assert_allclose(got, want, rtol=2e-5, atol=5e-5 * max(
+                1.0, float(jnp.abs(want).max())))
     # Value heads 0 and 1 read key head 0: head i + H_k would differ.
     if Hv > Hk:
-        q, k, v, g, beta = args
-        alone, _ = kda.kda_recurrent(q[:, :, :1], k[:, :, :1], v[:, :, 1:2],
-                                     g[:, :, 1:2], beta[:, :, 1:2])
+        alone, _ = kda.kda_recurrent(
+            q[:, :, :1], k[:, :, :1], v[:, :, 1:2], g[:, :, 1:2],
+            beta[:, :, 1:2], initial_state=s0[:, 1:2])
         np.testing.assert_allclose(o[:, :, 1:2], alone, atol=2e-5)
+
+
+def _outer_avals(jaxpr, out=None):
+    """(shape, dtype) of every variable an equation outside the kernels
+    makes (a `pallas_call`'s own results counted, its body not), and the
+    `pallas_call` equations themselves."""
+    out = ([], []) if out is None else out
+    for eqn in jaxpr.eqns:
+        out[0].extend((tuple(v.aval.shape), str(v.aval.dtype))
+                      for v in eqn.outvars)
+        if eqn.primitive.name == "pallas_call":
+            out[1].append(eqn)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _outer_avals(sub, out)
+    return out
+
+
+def test_a_scalar_decay_call_takes_its_operands_as_they_lie():
+    """A rank-3 call's program, forward and gradient: nothing [B,S,H_v,d_k]
+    in float32 (g broadcast over the channels, or its gradient before the
+    sum), no q or k repeated over the value heads (nothing [B,S,H_v,d_k] or
+    [B,S,H_v d_k] at all: d_v differs here), and two `pallas_call`s that
+    read g as [B,S,H_v] and write dg as dbeta, [B,H_v,1,S]."""
+    B, S, Hk, Hv, dk, dv = 1, 256, 2, 4, 128, 256
+    sd = jax.ShapeDtypeStruct
+    q, v = sd((B, S, Hk, dk), jnp.bfloat16), sd((B, S, Hv, dv), jnp.bfloat16)
+    g = sd((B, S, Hv), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        o, _ = kda.kda_chunked_pallas(q, k, v, g, beta, chunk=128)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=range(5)))(q, q, v, g, g)
+    avals, calls = _outer_avals(jaxpr.jaxpr)
+    shapes = {sh for sh, _ in avals}
+    assert (B, S, Hv, dk) not in shapes and (B, S, Hv * dk) not in shapes
+    assert [len(c.invars) for c in calls] == [6, 9]
+    for call in calls:
+        ins = [tuple(x.aval.shape) for x in call.invars]
+        assert ins[:5] == [(B, S, Hk * dk)] * 2 + [(B, S, Hv * dv)] + [
+            (B, S, Hv)] * 2
+    outs = [tuple(x.aval.shape) for x in calls[1].outvars]
+    assert outs[:2] == [(B, S, Hk * dk)] * 2          # dq, dk a KEY head
+    assert outs[3:5] == [(B, Hv, 1, S)] * 2           # dg as dbeta: rows
+    # The broadcast it replaces, for scale: a per-channel call on the same
+    # rule has both.
+    def broadcast(q, k, v, g, beta):
+        q, k, g = kda._per_channel(q, k, v, g)
+        return loss(q, k, v, g, beta)
+
+    old = jax.make_jaxpr(jax.grad(broadcast, argnums=range(5)))(q, q, v, g, g)
+    assert ((B, S, Hv, dk), "float32") in _outer_avals(old.jaxpr)[0]
+
+
+def test_the_stack_takes_the_scalar_kernels_under_full_remat(monkeypatch):
+    """The tiny stack's gradient through the kernels (interpret mode) under
+    the cell's remat policy: the three DeltaNet layers run the scalar
+    forward kernel twice each (6 in / 4 out; `full` keeps no residual of
+    the core: ROADMAP D3) and the backward once (9 in / 6 out), every call
+    reading g as [B, S, H_v]; the gradients are the XLA body's."""
+    from test_kda import _kernel_calls
+
+    cfg = qwen3_next_tiny(remat=True, remat_policy="full", dtype=jnp.float32)
+    params = tfm.init_params(jax.random.key(0), cfg)
+    toks = jax.random.randint(jax.random.key(1), (2, 33), 0, cfg.vocab_size)
+    # A new function each time: jax caches a trace by the function's identity.
+    grad = lambda: jax.grad(lambda p: tfm.loss_fn(
+        p, {"tokens": toks}, cfg, shift_inputs=True))
+    g_xla = jax.jit(grad())(params)
+    monkeypatch.setattr(kda, "use_kernels", lambda *a, **kw: True)
+    jaxpr = jax.make_jaxpr(grad())(params).jaxpr
+    calls = _kernel_calls(jaxpr)
+    assert calls["6in_4out"] == 6 and calls["9in_6out"] == 3, calls
+    for call in _outer_avals(jaxpr)[1]:
+        if len(call.invars) in (6, 9):  # the core's, not flash's
+            assert call.invars[3].aval.shape == (2, 32, cfg.gdn_v_heads)
+    for a, b in zip(jax.tree.leaves(jax.jit(grad())(params)),
+                    jax.tree.leaves(g_xla)):
+        np.testing.assert_allclose(a, b, atol=1e-5 + 1e-4 * float(
+            jnp.abs(b).max()))
 
 
 def test_a_decay_constant_over_channels_is_the_scalar_decay():
@@ -374,15 +476,29 @@ def test_scopes_and_observations(case):
     """The program's text carries the device scopes the metrics read (`gdn`,
     `gdn.core`, `gattn`, `gattn.gate`, `moe.shared`), a stack without the
     gated kind none of `gattn`, and a traced scalar-decay core call leaves
-    one `gdn.core.xla` observation with what it saw."""
+    one `gdn.core.xla` observation with what it saw: `body` says whether
+    the rule ran as itself (the kernels: "scalar") or as a broadcast into
+    the per-channel body (the XLA fallback)."""
     from ray_tpu.util import tracing
 
     cfg = case["cfg"]
     before = tracing.phase_table().get("gdn.core.xla", {"count": 0})["count"]
     kda_before = tracing.phase_table().get("kda.core.xla",
                                            {"count": 0})["count"]
-    text = jax.jit(lambda p, t: tfm.forward(p, t, cfg)).lower(
-        case["params"], case["toks"][:, :-1]).as_text(debug_info=True)
+    with mock.patch.object(tracing, "observe", wraps=tracing.observe) as spy:
+        text = jax.jit(lambda p, t: tfm.forward(p, t, cfg)).lower(
+            case["params"], case["toks"][:, :-1]).as_text(debug_info=True)
+        seen = [c for c in spy.call_args_list if c.args[0] == "gdn.core.xla"]
+        assert [c.kwargs["body"] for c in seen] == ["per_channel"]
+        assert seen[0].kwargs == dict(
+            slow=False, chunk=cfg.gdn_chunk, chunks=3, k_heads=2, v_heads=4,
+            decay="head", body="per_channel")
+        with mock.patch.object(kda, "use_kernels", lambda *a, **k: True):
+            jax.eval_shape(lambda *a: kda.kda_chunked(*a, chunk=128),
+                           *_core_inputs(256, 2, 4, 128))
+        last = spy.call_args_list[-1]
+        assert last.args[0] == "gdn.core.pallas"
+        assert last.kwargs["body"] == "scalar"
     for scope in ("gdn", "gdn.core", "gattn", "gattn.gate", "moe.shared",
                   "moe.route", "moe.experts"):
         assert re.search(rf'["/]{re.escape(scope)}/', text), scope
