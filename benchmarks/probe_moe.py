@@ -20,11 +20,21 @@ the gate/up grouped product with its two transposes through both paths of
 `moe._tiles`) and, at the module's window, at each tiling of `GMM_TILES`
 (the sweep beside `_tiles`).
 
+`rows` times the layer, forward + backward, as the held total grows: the
+routing GIVEN (`RATIOS` of the held experts' even share put on the held range,
+the rest elsewhere; the weights still the router's, so every gradient is
+made), at the module's window, for each number of blocks a window's row
+passes may be cut into (`BLOCKS`; `moe.block_rows`): ms a call, the rows the
+passes worked, and a checksum of the loss's and every gradient's bits. Run
+in a tree without `moe.block_rows` (a parent's, with this file copied into
+it) it gives that tree's body at the same inputs: the line to set beside.
+
 One JSON line a case. Off the chip the script fails at once.
 
     python3 benchmarks/probe_moe.py            # the sweep, both cells
     python3 benchmarks/probe_moe.py skew       # the module's window, skewed
     python3 benchmarks/probe_moe.py parts      # the passes alone
+    python3 benchmarks/probe_moe.py rows       # ms against the held total
 """
 from __future__ import annotations
 
@@ -39,6 +49,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops import moe
 from ray_tpu.util.jaxenv import enable_compile_cache, require_tpu
@@ -47,6 +58,11 @@ from ray_tpu.util.jaxenv import enable_compile_cache, require_tpu
 # mellum2_12b_a2_5b.train_share_16k and kimi_linear_48b_a3b.train_share_8k.
 CELLS = [("mellum2", 16384, 2304, 64, 16, 16, 896, 8, "softmax"),
          ("kimi_linear", 8192, 2304, 256, 104, 8, 1024, 8, "sigmoid")]
+# `rows` adds kanana_2_30b_a3b.train_rank8_16k (the other modes' sweeps are
+# recorded beside the module's constants for the two above).
+KANANA = ("kanana", 16384, 2048, 128, 48, 16, 768, 6, "sigmoid")
+RATIOS = (0.5, 1.0, 1.5, 2.0, 2.5)  # held total over the even share, `rows`
+BLOCKS = (5, 10, 20, 40)            # blocks a window, `rows`
 # Window rules: the factor on the held experts' even share. 4.0 is what PRs
 # 27-33 ran (with a quarter of the experts held: every assignment).
 RULES = (4.0, 1.5, 1.25, 1.125, 0.5, 0.25)
@@ -58,6 +74,7 @@ FACTOR = moe.HELD_WINDOW_FACTOR  # the module's own, which the rules replace
 MIN_TOKENS = getattr(moe, "HELD_WINDOW_MIN_TOKENS", 0.0)  # (a rule: factor alone)
 SHARE = getattr(moe, "FURTHER_WINDOW_SHARE", 1.0)
 SHARES = (1.0, 0.5, 0.25, 0.125)  # of the first window, a further one
+BLOCK_ROWS = getattr(moe, "block_rows", None)  # (a parent's tree has none)
 
 
 def _case(T, d, E, first, Eh, F, k, kind, skew=0.0):
@@ -131,10 +148,73 @@ def layer_ms(cell, factor, skew=0.0, share=None):
                          for g in grads)}
     if "trips" in cnt:
         row["trips"] = float(cnt["trips"])
-        row["rows_worked"] = row["window_rows"] + (row["trips"] - 1) * (
-            moe.further_window_rows(row["window_rows"]))
+        row["rows_worked"] = float(cnt["rows_worked"]) if (  # since PR 43
+            "rows_worked" in cnt) else row["window_rows"] + (
+                row["trips"] - 1) * moe.further_window_rows(
+                    row["window_rows"])
     row["ms"] = _ms(fn, (x, *params, wy))
     return row, row["dropped"] == 0.0 and row["finite"]
+
+
+def _given_ids(T, k, E, first, Eh, held, seed=0):
+    """[T, k] expert ids with `held` assignments on the held range, spread
+    evenly over its experts, and the rest evenly over the others."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, E - Eh, T * k)
+    ids = np.where(ids >= first, ids + Eh, ids)
+    ids[rng.choice(T * k, held, replace=False)] = first + rng.integers(
+        0, Eh, held)
+    return jnp.asarray(ids.reshape(T, k), jnp.int32)
+
+
+def rows_ms(cell, blocks):
+    """The layer at the module's window, the routing given, at each of
+    `RATIOS`; `blocks` None: the tree's own body (`moe.block_rows` as it is,
+    or a tree that has none)."""
+    name, T, d, E, first, Eh, F, k, kind = cell
+    moe.HELD_WINDOW_FACTOR, moe.HELD_WINDOW_MIN_TOKENS = FACTOR, MIN_TOKENS
+    moe.FURTHER_WINDOW_SHARE = SHARE
+    if blocks:
+        moe.block_rows = functools.partial(BLOCK_ROWS, blocks=blocks)
+    x, params, wy, _ = _case(T, d, E, first, Eh, F, k, kind)
+
+    def given(ids, xf, rw):  # the routing given, the weights the router's
+        w = jnp.take_along_axis(jax.nn.sigmoid(
+            xf.astype(jnp.float32) @ rw.astype(jnp.float32)), ids, axis=-1)
+        return ids, w / jnp.sum(w, axis=-1, keepdims=True)
+
+    @jax.jit
+    def fn(ids, x, rw, wgu, wd, wy):
+        (loss, cnt), grads = _layer(functools.partial(given, ids), first)(
+            x, rw, wgu, wd, wy)
+        bits = lambda a: jnp.sum(jax.lax.bitcast_convert_type(
+            a, {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]).astype(
+                jnp.uint32))
+        finite = jnp.stack([jnp.isfinite(g.astype(jnp.float32)).all()
+                            for g in grads]).all()
+        return cnt, finite, jnp.stack([bits(g) for g in (loss,) + grads])
+
+    W = moe.held_window_rows(T, k, E, Eh)
+    even = T * k * Eh // E
+    row = {"cell": name, "window_rows": W, "even": even, "blocks": blocks,
+           "block_rows": BLOCK_ROWS and moe.block_rows(W), "cases": []}
+    ok = True
+    for i, ratio in enumerate(RATIOS):
+        args = (_given_ids(T, k, E, first, Eh, int(ratio * even)), x,
+                *params, wy)
+        t0 = time.perf_counter()
+        cnt, finite, sums = jax.block_until_ready(fn(*args))
+        if not i:
+            row["compile_s"] = round(time.perf_counter() - t0, 2)
+        case = {"ratio": ratio, "assigned": float(cnt["assigned"]),
+                "dropped": float(cnt["dropped"]), "finite": bool(finite),
+                "trips": float(cnt["trips"]),
+                "rows_worked": float(cnt.get("rows_worked", W)),
+                "ms": _ms(fn, args),
+                "sums": [int(v) for v in sums]}
+        ok = ok and case["finite"] and case["dropped"] == 0.0
+        row["cases"].append(case)
+    return row, ok
 
 
 def parts_ms(cell, factor):
@@ -213,8 +293,16 @@ def main(argv) -> int:
         failed += not ok
         print(json.dumps(row), flush=True)
 
-    for cell in CELLS:
-        if argv[1:] == ["skew"]:
+    for cell in CELLS + [KANANA] if argv[1:] == ["rows"] else CELLS:
+        if argv[1:] == ["rows"]:
+            seen = set()
+            W = moe.held_window_rows(cell[1], cell[7], cell[3], cell[5])
+            for blocks in BLOCKS if BLOCK_ROWS else (None,):
+                rows = blocks and BLOCK_ROWS(W, blocks)
+                if rows not in seen:  # (the hybrid's 5 and 10 are one block)
+                    seen.add(rows)
+                    report(rows_ms, cell, blocks)
+        elif argv[1:] == ["skew"]:
             for skew in SKEW:
                 for share in SHARES if skew else SHARES[:1]:
                     report(layer_ms, cell, None, skew, share)

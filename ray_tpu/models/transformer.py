@@ -140,9 +140,10 @@ class TransformerConfig:
     #   Gathered assignments are worked in windows of a margin over the held
     #   experts' even share (ops/moe.py `HELD_WINDOW_FACTOR`, no fewer than a
     #   row a token; every assignment where all are held): the layer's time
-    #   follows the window's rows, so a routing within the margin takes one
-    #   trip and only one past it more, as many as it needs; what falls past
-    #   the first is counted (`moe_past_buffer`, `moe_trips`): nothing is
+    #   follows the rows the routing filled, a block of the window at a time
+    #   (`moe_rows_worked`), so a routing within the margin takes one trip
+    #   and only one past it more, as many as it needs; what falls past the
+    #   first is counted (`moe_past_buffer`, `moe_trips`): nothing is
     #   dropped.
     moe_num_experts: int = 0
     moe_experts_per_token: int = 2
@@ -1211,9 +1212,9 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
     its repeats, every layer under the remat policy. -> (hidden [B,S,d],
     the stack's extras from its layers' (`_mlp_block`): {} for dense
     feed-forwards, {"aux": sum} for GShard, and for a held range of experts
-    moe_assigned, moe_dropped, moe_past_buffer, moe_trips (sums),
-    moe_load_max, moe_window_rows (max), moe_load_mean (mean) over the
-    expert layers)."""
+    moe_assigned, moe_dropped, moe_past_buffer, moe_trips, moe_rows_worked
+    (sums), moe_load_max, moe_window_rows (max), moe_load_mean (mean) over
+    the expert layers)."""
     B, S = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -1241,7 +1242,8 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
                "moe_load_max": cat["load_max"].max(),
                "moe_load_mean": cat["load_mean"].mean(),
                "moe_trips": cat["trips"].sum(),
-               "moe_window_rows": cat["window_rows"].max()}
+               "moe_window_rows": cat["window_rows"].max(),
+               "moe_rows_worked": cat["rows_worked"].sum()}
 
 
 def final_hidden_and_head(
@@ -1321,8 +1323,8 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
     """Next-token cross-entropy. `with_counters`: return (loss, routing
     counters) for `ShardedTrainStep(has_aux=True)`: device scalars
     moe_assigned, moe_dropped, moe_past_buffer, moe_load_max, moe_load_mean,
-    moe_trips, moe_window_rows of a stack with a held range of experts
-    (`_backbone`), {} for any other.
+    moe_trips, moe_window_rows, moe_rows_worked of a stack with a held range
+    of experts (`_backbone`), {} for any other.
 
     Two token conventions:
     - in-place (default): batch tokens [B,S]; the forward runs on the FULL

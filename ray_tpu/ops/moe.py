@@ -13,7 +13,8 @@
   assignments by expert with its own first, runs those as grouped matrix
   products (`lax.ragged_dot`, no one-hot) in one window of a margin over the held
   experts' even share of the rows and no fewer than a row a token (smaller
-  ones for what a skewed routing puts past it), and returns its own experts' part. With every expert held
+  ones for what a skewed routing puts past it), every pass round the products
+  over the row blocks the routing filled, and returns its own experts' part. With every expert held
   and the expert dim sharded over an `expert` mesh axis this is expert
   parallelism.
   `TransformerConfig.moe_router = "sigmoid"` or `"softmax"`.
@@ -175,9 +176,10 @@ def softmax_route(x: jax.Array, router_w: jax.Array, *,
 # The first window of gathered assignments is the held experts' even share of
 # them (tokens x k x held / experts) times this margin. A window spans the
 # sorted rows of ALL held experts, so one expert's skew never matters: only
-# the held total does; the layer's time follows the window's rows, not the
-# held ones, and every further trip of the backward loop writes and adds a
-# whole dW1, dW2 and dx, so ONE trip is the rule. Sweep on the chip
+# the held total does; until PR 43 the layer's time followed the window's
+# rows, not the held ones (the sweeps below are of that body), and every
+# further trip of the backward loop writes and adds a whole dW1, dW2 and dx,
+# so ONE trip is the rule. Sweep on the chip
 # (`benchmarks/probe_moe.py`, PR 34; one layer alone, forward + backward, ms
 # at mellum2's [16384, 2304] with 16 of 64 experts of 896 held / at the
 # hybrid's [8192, 2304] with 8 of 256 of 1024; 32,819 / 2,166 held rows, an
@@ -202,6 +204,16 @@ def softmax_route(x: jax.Array, router_w: jax.Array, *,
 # (In the traced step the held experts take 38.0 ms a layer at 2.5 and 24.2
 # at 1.25.) With a router that stays even (a balancing term in the cell's job
 # file, PERF.md section 7 (d)) 1.25 is the value.
+# What a margin costs since PR 43: memory (the [W, .] buffers), and the sort
+# and permutation of the unfilled rows a scatter-add is given (half the
+# window where the held rows end there). Every other pass works the blocks
+# the held rows reach into (`block_rows`), so the layer's time follows the
+# held total: same probe, `rows`, mellum2's shape at a held total of 0.5 /
+# 1.0 / 1.5 / 2.0 / 2.5 of the even share, ms: 27.71 / 33.40 / 45.05 / 50.72
+# / 56.41 where the whole-window passes took 47.89 / 51.24 / 54.58 / 57.91 /
+# 61.29; the cell at 2.5: 35,816-35,839 -> 39,670-39,945 tokens/s/chip over
+# six seeds (a faster run takes more steps, so its router drifts further
+# and its late steps take further trips that the slower run never reached).
 HELD_WINDOW_FACTOR = 2.5
 # And the window is never under this many rows a token. A token picks an
 # expert once, so the tokens are the rows ONE held expert can be given, and
@@ -308,6 +320,27 @@ def further_window_rows(rows: int) -> int:
     return min(rows, -(-int(FURTHER_WINDOW_SHARE * rows) // 128) * 128)
 
 
+def block_rows(rows: int, blocks: int = 20) -> int:
+    """Rows of a block of the row passes over a window of `rows`: a whole
+    number of the grouped products' row tiles (`_tiles`; of rows where the
+    window is not made of tiles) that divides the window, one of `blocks`
+    or the nearest below: 4,096 of 81,920, 1,536 of 30,720, 512 of 8,192.
+    Sweep on the chip (`benchmarks/probe_moe.py rows`, PR 43; one layer
+    alone, forward + backward, ms at a held total of the even share / of 2.5
+    of it; 5, 10, 20, 40 blocks a window): mellum2's [16384, 2304], 16 of 64
+    experts of 896, 34.60 / 59.03, 33.82 / 57.12, 33.40 / 56.41, 33.49 /
+    56.65 (the whole window in one pass, PR 42: 51.24 / 61.29); kanana's
+    [16384, 2048], 16 of 128 of 768, 16.17 / 23.14, 15.78 / 22.84, 15.43 /
+    22.26, 15.66 / 22.79 (21.04 / 24.16); the hybrid's [8192, 2304], 8 of
+    256 of 1024, a tenth 9.14 / 10.68, a twentieth 9.01 / 10.59 (10.16 /
+    10.90)."""
+    tile = _tiles(rows, 128, 128)[0] if rows % 128 == 0 else 1
+    tiles = max(1, round(rows / tile / blocks))
+    while rows // tile % tiles:
+        tiles -= 1
+    return tiles * tile
+
+
 def _trips(held, rows: int, further_rows: int, further: int):
     """Windows that `held` sorted assignments reach into: the first, of
     `rows` rows, and as many of the `further` ones of `further_rows`."""
@@ -337,15 +370,20 @@ def moe_ffn_held(
     where all are held), always, then a loop of as many smaller ones
     (`further_window_rows`) as the held assignments reach into. Each is a
     gather of the window's token rows, two grouped products over the rows the
-    routing put there and a scatter-add back, so the device work follows the
-    window that the held share sizes: a routing within the margin takes the
-    one window and a routing however skewed loses nothing.
+    routing put there and a scatter-add back; the products' grids stop at the
+    groups' end and every other pass runs over row blocks (`block_rows`), as
+    many as the window's held rows reach into, so the device work follows
+    the rows the routing filled, a block at a time, and the window's margin
+    costs memory: a routing within it takes the one window and a routing
+    however skewed loses nothing.
 
     Counters (device scalars, float32): `assigned` (assignments that fell
     on held experts), `load_max` / `load_mean` (of a held expert, in
     assignments), `past_buffer` (assignments beyond the first window),
     `dropped` (assigned less the rows the windows counted as worked),
-    `trips` (windows worked), `window_rows` (rows of the first window)."""
+    `trips` (windows worked), `window_rows` (rows of the first window),
+    `rows_worked` (rows of the blocks the row passes touched, over every
+    window worked: whole blocks, the held rows at least)."""
     from ray_tpu.parallel.sharding import current_sharding_ctx
 
     B, S, d = x.shape
@@ -375,12 +413,25 @@ def moe_ffn_held(
 
     # A grouped product leaves rows in no group undefined (zeros on the CPU,
     # whatever the buffer held on the TPU: NaN seen, chip run of PR 27),
-    # forward and in every transposed product of the backward pass. Such rows
-    # (past the window's held assignments) take the token index T, one past
-    # the tokens: gathered from there they are zeros, scattered to there they
-    # are skipped, so no pass over a [W, .] buffer is a mask alone. What a
-    # product wrote there is zeroed once, in the pass that applies the SiLU
-    # (and its transpose), since a NaN times a zero weight is a NaN.
+    # forward and in every transposed product of the backward pass, and reads
+    # none of them. Rows that hold no held assignment take the token index
+    # T, one past the tokens: gathered from there they are zeros, scattered
+    # to there they are skipped. So a window's passes follow the rows the
+    # routing filled, each in the way that costs least on the chip
+    # (`probe_moe.py rows`, PERF.md section 6, PR 43): the three scatter-adds
+    # stay one call over the window each (a call costs 1.4-1.6 ms whatever
+    # its rows and 0.08 ms per 1,000 rows it adds, 0.01 per 1,000 it skips;
+    # its sums are the whole window's in one order and one precision), and
+    # every other pass over a [W, .] buffer (gather, mask, SiLU, scale, row
+    # sums, and their transposes) runs over row blocks (`block_rows`), as
+    # many as the window's held rows reach into: a loop of dynamic length, a
+    # block's rows cut out of the buffer and put back. Rows past the last
+    # worked block hold whatever the buffer held (`lax.empty`, or the
+    # product's output that the pass overwrites in place): no pass reads
+    # them and the scatter-adds skip them. Inside the last worked block,
+    # what a product wrote past the held rows is zeroed once, in the pass
+    # that applies the SiLU (and its transpose), since a NaN times a zero
+    # weight is a NaN.
     def rows_of(wflat, order, ends, lo, n):
         """The window of rows lo .. lo + n of the sorted list -> the token
         of each row (T where the row holds no held assignment), its
@@ -393,7 +444,48 @@ def moe_ffn_held(
                 jnp.where(valid, wflat[rows], 0.0),
                 jnp.diff(ends_w, prepend=0), valid[:, None], ends_w[-1])
 
+    def row_passes(rows, held):
+        """A window's row passes, for `held` of its `rows` filled:
+        `each(body, init)` runs `body(at, carry)` for the first row `at` of
+        every block the held rows reach into, `cut(a, at)` is that block of
+        `a`, `put(buf, a, at)` writes it back; last, the rows of those
+        blocks."""
+        block = block_rows(rows)
+        blocks = (held + block - 1) // block
+        each = lambda body, init: jax.lax.fori_loop(
+            0, blocks, lambda b, carry: body(b * block, carry), init)
+        cut = lambda a, at: jax.lax.dynamic_slice_in_dim(a, at, block)
+        put = lambda buf, a, at: jax.lax.dynamic_update_slice_in_dim(
+            buf, a, at, 0)
+        return each, cut, put, blocks * block
+
     take = lambda a, at: a.at[at].get(mode="fill", fill_value=0)
+
+    def fresh(shape, dt, held):
+        """An uninitialised buffer (`lax.empty`: an AllocateBuffer on the
+        TPU, no fill), made inside a conditional on `held` (a count: never
+        negative) because XLA hoists a bare one out of the scan over layers
+        as loop-invariant and copies it back in every layer (0.92 + 0.36 ms
+        a layer at mellum2's shape; +0.9% of the cell's rate without)."""
+        make = lambda: jax.lax.empty(shape, dt)
+        return jax.lax.cond(held >= 0, make, make)
+
+    def add_rows(tok, vals, held):
+        """Zeros [T, d] with `vals` [W, d] added at the tokens `tok` (T:
+        skipped), as ONE scatter-add, over the window's first half where its
+        `held` filled rows end there: the rows past them are skipped either
+        way, so the sums are the whole window's, but XLA sorts and permutes
+        every row it is given (3.0 ms of a call's 7.8 at mellum2's shape).
+        (A quarter as a third choice made the step's program large enough
+        for XLA to recompute a product of 10 ms to fit it: PERF.md section
+        6, PR 43.)"""
+        half = -(-len(tok) // 2)
+        add = lambda tok, vals: jnp.zeros((T, d), vals.dtype).at[tok].add(
+            vals, mode="drop")
+        return jax.lax.cond(
+            held > half, add,
+            lambda tok, vals: add(tok[:half], vals[:half]), tok, vals)
+
     # The first window's products by the kernels where `use_kernels` says so;
     # the further ones, which an even routing never reaches, by `ragged_dot`
     # always: every Pallas call in a program is lowered in Python when the
@@ -411,31 +503,60 @@ def moe_ffn_held(
 
     def window(xf, w1, w2, wflat, order, ends, lo, rows, products):
         """-> (the window's part of the output [T, d], how many of its rows
-        were held assignments, the two products' outputs)."""
+        were held assignments, the rows its passes worked, the two products'
+        outputs)."""
         grouped = products[0]
         tok, _, wrow, sizes, valid, n = rows_of(wflat, order, ends, lo, rows)
-        gu = grouped(take(xf, tok).astype(dtype), w1, sizes)   # [W, 2F]
-        yb = grouped(swiglu(gu, valid), w2, sizes)             # [W, d]
-        y = jnp.zeros((T, d), dtype).at[tok].add(
-            (f32(yb) * wrow[:, None]).astype(dtype), mode="drop")
-        return y, n, (gu, yb)
+        each, cut, put, touched = row_passes(rows, n)
+        xb = each(lambda at, xb: put(
+            xb, take(xf, cut(tok, at)).astype(dtype), at),
+            fresh((rows, d), dtype, n))
+        gu = grouped(xb, w1, sizes)                            # [W, 2F]
+        act = each(lambda at, act: put(
+            act, swiglu(cut(gu, at), cut(valid, at)), at),
+            fresh((rows, F), dtype, n))
+        yb = grouped(act, w2, sizes)                           # [W, d]
+        # (Written over the gathered rows, which the first product has read.)
+        scaled = each(lambda at, xb: put(
+            xb, (f32(cut(yb, at)) * cut(wrow, at)[:, None]).astype(dtype),
+            at), xb)
+        return add_rows(tok, scaled, n), n, touched, (gu, yb)
 
     def window_t(xf, w1, w2, wflat, order, ends, lo, rows, products, gu, yb,
                  dy):
         """The window's transpose: the output's cotangent dy [T, d] -> those
-        of xf, w1, w2, wflat, given the products' outputs."""
+        of xf, w1, w2, wflat, given the products' outputs. A pass writes
+        over the buffer it has read where the shapes allow: `yb`'s blocks
+        become its cotangent's, `gu`'s its cotangent's, and the SiLU's
+        cotangent's the SiLU's output."""
         _, grouped_t, grouped_tw = products
-        tok, at, wrow, sizes, valid, _ = rows_of(wflat, order, ends, lo, rows)
-        xb = take(xf, tok).astype(dtype)
-        act, swiglu_t = jax.vjp(lambda gu: swiglu(gu, valid), gu)
-        dyb = f32(take(dy, tok))                               # [W, d]
-        dwrow = jnp.where(valid[:, 0], jnp.sum(f32(yb) * dyb, axis=-1), 0.0)
-        dyb = (dyb * wrow[:, None]).astype(dtype)
-        dgu, = swiglu_t(grouped_t(dyb, w2, sizes))
-        return (jnp.zeros_like(xf).at[tok].add(
-                    grouped_t(dgu, w1, sizes).astype(xf.dtype), mode="drop"),
+        tok, at_w, wrow, sizes, valid, n = rows_of(wflat, order, ends, lo,
+                                                   rows)
+        each, cut, put, _ = row_passes(rows, n)
+
+        def gathers(at, carry):
+            xb, yb, dwrow = carry
+            t = cut(tok, at)
+            dyb = f32(take(dy, t))                             # [block, d]
+            return (put(xb, take(xf, t).astype(dtype), at),
+                    put(yb, (dyb * cut(wrow, at)[:, None]).astype(dtype), at),
+                    put(dwrow, jnp.sum(f32(cut(yb, at)) * dyb, axis=-1), at))
+
+        xb, dyb, dwrow = each(gathers, (
+            fresh((rows, d), dtype, n), yb,
+            fresh((rows,), jnp.float32, n)))
+
+        def swiglu_t(at, carry):
+            dact, gu = carry
+            act, t = jax.vjp(lambda gu: swiglu(gu, cut(valid, at)),
+                             cut(gu, at))
+            return put(dact, act, at), put(gu, t(cut(dact, at))[0], at)
+
+        act, dgu = each(swiglu_t, (grouped_t(dyb, w2, sizes), gu))
+        dxb = grouped_t(dgu, w1, sizes)                        # [W, d]
+        return (add_rows(tok, dxb.astype(xf.dtype), n),
                 grouped_tw(xb, dgu, sizes), grouped_tw(act, dyb, sizes),
-                jnp.zeros_like(wflat).at[at].add(dwrow, mode="drop"))
+                jnp.zeros_like(wflat).at[at_w].add(dwrow, mode="drop"))
 
     # A loop of dynamic length has no reverse rule, and a scan of
     # `lax.cond`s differentiated as it stands hands the scan its
@@ -449,13 +570,13 @@ def moe_ffn_held(
     # would be added to zeros.
     def worked(*args):
         at, trips = args[:6], args[6]
-        y, n, res = window(*at, *first)
+        *out, res = window(*at, *first)
 
         def body(i, carry):
-            yi, ni, _ = window(*at, *further(i))
-            return carry[0] + yi, carry[1] + ni
+            return tuple(a + b for a, b in zip(
+                carry, window(*at, *further(i))[:3]))
 
-        return jax.lax.fori_loop(1, trips, body, (y, n)), res
+        return jax.lax.fori_loop(1, trips, body, tuple(out)), res
 
     @jax.custom_vjp
     def worked_windows(*args):  # xf, w1, w2, wflat, order, ends, trips
@@ -471,7 +592,7 @@ def moe_ffn_held(
 
         def body(i, acc):
             win = at + further(i)
-            g = window_t(*win, *window(*win)[2], ct[0])
+            g = window_t(*win, *window(*win)[3], ct[0])
             return tuple(a + b for a, b in zip(acc, g))
 
         acc = jax.lax.fori_loop(1, trips, body,
@@ -482,7 +603,8 @@ def moe_ffn_held(
     worked_windows.defvjp(fwd, bwd)
 
     with jax.named_scope("moe.experts"):
-        y, n_worked = worked_windows(xf, w1, w2, wflat, order, ends, trips)
+        y, n_worked, touched = worked_windows(xf, w1, w2, wflat, order, ends,
+                                              trips)
     counters = {
         "assigned": f32(held),
         "load_max": f32(jnp.max(counts)),
@@ -491,5 +613,6 @@ def moe_ffn_held(
         "dropped": f32(held - n_worked),
         "trips": jnp.float32(trips),
         "window_rows": jnp.float32(W),
+        "rows_worked": f32(touched),
     }
     return y.reshape(B, S, d), counters

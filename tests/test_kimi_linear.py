@@ -340,7 +340,13 @@ def test_train_step_returns_counters_and_folds_them():
     assert (seen["moe_trips"] > 1.0) == (seen["moe_past_buffer"] > 0)
     table = tracing.phase_table()
     assert table["train.moe_assigned"]["count"] == before["count"] + 3
-    assert {"train.moe_trips", "train.moe_window_rows"} <= set(table)
+    assert {"train.moe_trips", "train.moe_window_rows",
+            "train.moe_rows_worked"} <= set(table)
+    # The row passes work whole blocks, as far as the held rows reach.
+    block = moe.block_rows(int(seen["moe_window_rows"]))
+    assert seen["moe_rows_worked"] % block == 0 and (
+        seen["moe_assigned"] <= seen["moe_rows_worked"]
+        < seen["moe_assigned"] + seen["moe_trips"] * block)
     # compile_step: one executable for the loop and for memory_analysis().
     batch = ts.shard_batch({"tokens": toks})
     exe = ts.compile_step(params, opt, batch)

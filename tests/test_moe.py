@@ -227,6 +227,102 @@ def test_held_layer_equals_a_masked_loop(share, routing, kind):
             assert held == 0 and not np.any(np.asarray(y))
 
 
+def _given_route(kind, ids):
+    """The routing GIVEN (`ids` [T, k]), the weights the router's: the
+    scores of `sigmoid_route` / `softmax_route` at those experts,
+    renormalised (and scaled), so that a held total is exact."""
+    import jax.numpy as jnp
+
+    def route(x, rw):
+        z = jnp.dot(x.astype(jnp.float32), rw.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(z) if kind == "sigmoid" else jax.nn.softmax(z, -1)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return ids, w * 2.446 if kind == "sigmoid" else w
+
+    return route
+
+
+def _given_ids(held, T=256, k=2, E=64, first=16, Eh=16):
+    """[T, k] expert ids: `held` of the T k assignments on the held range."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(held)
+    ids = rng.randint(0, E - Eh, T * k)
+    ids = np.where(ids >= first, ids + Eh, ids)
+    ids[rng.permutation(T * k)[:held]] = first + rng.randint(0, Eh, held)
+    return jnp.asarray(ids.reshape(T, k), jnp.int32)
+
+
+# 256 tokens x 2, 16 of 64 experts held: a first window of 384 rows in blocks
+# of 128, then further ones of 256 rows (one block each: a tile of 256).
+HELD_TOTALS = [0, 1, 127, 128, 129, 383, 384, 385, 512]
+
+
+@pytest.mark.parametrize("kind", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("held", HELD_TOTALS)
+def test_row_passes_work_the_blocks_the_held_rows_reach(held, kind):
+    """The row passes of a window run over row blocks, as many as the held
+    rows reach into, and what they leave untouched changes nothing: output,
+    every gradient and every counter at a held total of zero, of one row, of
+    a block exactly and a row either side, of the whole window and a row
+    either side, and of every assignment there is, against the masked loop."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    T, k, E, first, Eh = 256, 2, 64, 16, 16
+    args, wy, _, _ = _held_case(4, "even", kind)
+    route = _given_route(kind, _given_ids(held))
+    with jax.default_matmul_precision("highest"):
+        y, cnt, grads = _loss_and_grads(functools.partial(
+            moe.moe_ffn_held, route=route, held_first=first,
+            dtype=jnp.float32), args, wy)
+        want = _loss_and_grads(
+            lambda *a: (_held_loop(*a, route=route, first=first), {}),
+            args, wy)
+    _assert_close(y, want[0], "output")
+    for name, g, w in zip(("x", "router", "gate_up", "down"), grads, want[2]):
+        _assert_close(g, w, name)
+    rows = moe.held_window_rows(T, k, E, Eh)
+    more = moe.further_window_rows(rows)
+    assert (rows, more) == (384, 256)
+    assert (moe.block_rows(rows), moe.block_rows(more)) == (128, 256)
+    trips = 1 + max(-(-(held - rows) // more), 0)
+    worked = -(-min(held, rows) // 128) * 128 + (trips - 1) * more
+    got = {n: float(v) for n, v in cnt.items()}
+    assert got == {
+        "assigned": held, "dropped": 0.0, "past_buffer": max(held - rows, 0),
+        "trips": trips, "window_rows": rows, "rows_worked": worked,
+        "load_max": got["load_max"], "load_mean": held / Eh}
+    # Whole blocks, the held rows at least, under a block a window more.
+    assert held <= worked < held + 128 + (trips - 1) * more
+    if not held:
+        assert not np.any(np.asarray(y)) and worked == 0.0
+
+
+@pytest.mark.parametrize("rows,block", [
+    (81920, 4096), (40960, 2048),  # mellum2's first window, a further one
+    (30720, 1536), (25600, 1024),  # kanana's, qwen3_next's (50 tiles: 2 each)
+    (8192, 512), (4096, 512),      # the hybrid's
+    (12800, 512),                  # 25 tiles
+    (384, 128), (256, 256), (640, 128), (64, 2), (96, 4)])
+def test_block_rows(rows, block):
+    """A block of the row passes: whole row tiles of the grouped products
+    (512, 256 or 128 rows as `_tiles` picks them; single rows where the
+    window is not made of tiles), a twentieth of the window or the nearest
+    below that divides it."""
+    from ray_tpu.ops import moe
+
+    assert moe.block_rows(rows) == block and rows % block == 0
+    if rows % 128 == 0:
+        assert block % moe._tiles(rows, 128, 128)[0] == 0
+    assert moe.block_rows(rows, 1) == rows
+
+
 @pytest.mark.parametrize("shape,rows", [
     ((16384, 8, 64, 16), 81920),   # mellum2's cell: 2.5 of 32,768
     ((8192, 8, 256, 8), 8192),     # the hybrid's: a row a token, not 5,120
@@ -244,15 +340,20 @@ def test_first_window_rows(shape, rows):
 
 @pytest.mark.parametrize("share,routing,factor,T", [
     (4, "even", None, 256), (4, "all_held", 2.5, 224), (32, "even", None, 256),
-    (32, "none_held", None, 256)])
+    (32, "none_held", None, 256), (4, "none_held", None, 256),
+    (4, "all_held", None, 256), (4, 1, None, 256), (4, 129, None, 256),
+    (4, 385, None, 256)])
 def test_rows_in_no_group_may_hold_anything(share, routing, factor, T,
                                             monkeypatch):
     """A grouped product leaves the rows past its groups undefined on the
-    TPU (on the CPU they come back zero): with NaN there, in the forward
-    products and in the transposed ones of the backward pass, output and
-    gradients are what they are without. (At factor 2.5 the 448 held
-    assignments of 224 tokens take a window of 384 rows and a quarter of a
-    further one of 256.)"""
+    TPU (on the CPU they come back zero), and the row passes' buffers start
+    uninitialised (`lax.empty`) and are worked as far as the held rows reach:
+    with NaN in every row of every [W, .] buffer that no pass has written
+    (past the groups' end in a product's output, forward and transposed;
+    everywhere in a fresh buffer), output and gradients are what they are
+    without. (At factor 2.5 the 448 held assignments of 224 tokens take a
+    window of 384 rows and a quarter of a further one of 256; a `routing`
+    that is a number is that held total, given.)"""
     import functools
 
     import jax.numpy as jnp
@@ -261,7 +362,11 @@ def test_rows_in_no_group_may_hold_anything(share, routing, factor, T,
 
     if factor:
         monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", factor)
-    args, wy, route, first = _held_case(share, routing, "softmax", T=T)
+    given = not isinstance(routing, str)
+    args, wy, route, first = _held_case(share, "even" if given else routing,
+                                        "softmax", T=T)
+    if given:
+        route = _given_route("softmax", _given_ids(routing))
     fn = functools.partial(moe.moe_ffn_held, route=route, held_first=first,
                            dtype=jnp.float32)
     want = _loss_and_grads(fn, args, wy)
@@ -273,13 +378,22 @@ def test_rows_in_no_group_may_hold_anything(share, routing, factor, T,
         in_a_group = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
         return jnp.where(in_a_group[:, None], out, jnp.nan)
 
+    def empty(shape, dtype):
+        poisoned.append(shape)
+        return jnp.full(shape, jnp.nan, dtype)
+
     monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
+    monkeypatch.setattr(jax.lax, "empty", empty)
     y, cnt, grads = _loss_and_grads(fn, args, wy)
-    assert len(poisoned) >= 4  # both products and both transposed to rows
-    worked = float(cnt["window_rows"]) + (float(cnt["trips"]) - 1) * (
-        moe.further_window_rows(int(cnt["window_rows"])))
-    assert float(cnt["assigned"]) < worked and (
-        float(cnt["trips"]) == (2 if factor else 1))
+    # Both products, both transposed to rows, and three fresh buffers.
+    assert len(poisoned) >= 7
+    rows = int(cnt["window_rows"])
+    more = moe.further_window_rows(rows)
+    held, trips = float(cnt["assigned"]), float(cnt["trips"])
+    assert held < rows + (trips - 1) * more  # some rows are in no group
+    assert trips == 1 + max(-(-(int(held) - rows) // more), 0)
+    assert float(cnt["rows_worked"]) < held + moe.block_rows(rows) + (
+        trips - 1) * moe.block_rows(more)
     for got, w in zip((y,) + grads, (want[0],) + want[2]):
         assert np.all(np.isfinite(got))
         np.testing.assert_allclose(got, w, atol=1e-6)
