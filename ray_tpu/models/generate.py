@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from .transformer import (
+    MIXERS,
     TransformerConfig,
     _layer_body,
     _mlp_block,
@@ -51,17 +52,9 @@ def _kv_stack(params: Params, cfg: TransformerConfig):
     """(kind, leaves [L, ...]) of the one stacked tree the cache's [L, ...]
     keys and values are scanned beside; what decode cannot serve is refused
     (ROADMAP R7)."""
-    if any(mixer == "gdn" for mixer, _ in cfg.layer_kinds()):
-        raise NotImplementedError(
-            "decode cannot serve a Gated DeltaNet (gdn) layer: it would hold "
-            "a delta-rule state and the convolution's last tokens in place of "
-            "keys and values (ROADMAP R7 / R9); the stack trains but does not "
-            "serve yet")
-    if any(mixer != "attn" for mixer, _ in cfg.layer_kinds()):
-        raise NotImplementedError(
-            "decode holds keys and values of one length a layer only: a "
-            "stack with KDA / MLA / Mamba-2 / windowed layers trains but "
-            "does not serve yet")
+    for mixer, _ in cfg.layer_kinds():
+        if MIXERS[mixer].no_decode:
+            raise NotImplementedError(MIXERS[mixer].no_decode)
     if cfg.attn_out_gate or cfg.norm_offset:
         raise NotImplementedError(
             "decode does not apply attn_out_gate / norm_offset yet")
@@ -98,8 +91,7 @@ def prefill(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
     def body(carry, layer):
-        x, _, k, v = _layer_body(cfg, kind, carry, layer, positions,
-                                 return_kv=True)
+        x, _, k, v = _layer_body(cfg, kind, carry, layer, positions)
         return x, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, layers)

@@ -23,7 +23,7 @@ import contextlib
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -223,8 +223,8 @@ class TransformerConfig:
     fused_ce: bool = False
 
     def __post_init__(self):
-        for name in ("kda_layers", "mla_layers", "mamba_layers", "swa_layers",
-                     "gdn_layers", "moe_held"):
+        fields = [m.layers_field for m in MIXERS.values() if m.layers_field]
+        for name in fields + ["moe_held"]:
             v = getattr(self, name)
             if isinstance(v, list):  # from a JSON file
                 object.__setattr__(self, name, tuple(v))
@@ -233,11 +233,9 @@ class TransformerConfig:
                              "'dots' or 'full'")
         if self.moe_router not in ("softmax_capacity",) + _HELD_ROUTERS:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
-        lists = (self.kda_layers + self.mla_layers + self.mamba_layers
-                 + self.swa_layers + self.gdn_layers)
+        lists = sum((getattr(self, f) for f in fields), ())
         if len(set(lists)) != len(lists):
-            raise ValueError(
-                "a layer is listed as two of kda, mla, mamba, swa, gdn")
+            raise ValueError("a layer is in two of " + ", ".join(fields))
         if self.gdn_layers and self.gdn_v_heads % self.gdn_k_heads:
             raise ValueError("gdn_v_heads is a multiple of gdn_k_heads")
         if self.norm_offset not in (0.0, 1.0) or (
@@ -253,14 +251,14 @@ class TransformerConfig:
         if self.swa_layers and not (self.sliding_window or 0) >= 1:
             raise ValueError("swa_layers need a sliding_window of >= 1")
         if (self.yarn_factor is not None and self.mla_rotates
-                and any(m == "mla" for m, _ in self.layer_kinds())):
+                and any(l <= self.n_layers for l in self.mla_layers)):
             raise ValueError("an mla layer's rotation takes no YaRN scaling "
                              "(no mscale on its scores yet)")
         if (self.moe_num_experts and not self.moe_holds_range
-                and any(m != "attn" for m, _ in self.layer_kinds())):
+                and any(m != _PLAIN for m, _ in self.layer_kinds())):
             raise ValueError(
-                "kda / mla / mamba / swa / gdn layers compose with "
-                "moe_router='sigmoid' or 'softmax' only")
+                " / ".join(fields) + " compose with moe_router='sigmoid' "
+                "or 'softmax' only")
         if self.moe_router == "softmax" and self.moe_routed_scale != 1.0:
             raise ValueError("moe_router='softmax' takes no moe_routed_scale")
 
@@ -289,21 +287,16 @@ class TransformerConfig:
                 self.yarn_beta_slow, self.yarn_attn_factor)
 
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
-        """(mixer, feed-forward) of every layer: mixer attn | swa | mla |
-        kda | mamba2 | gdn, feed-forward dense | moe."""
-        held = self.moe_holds_range
+        """(mixer, feed-forward) of every layer: mixer a name of `MIXERS`
+        (the kind whose list names the layer, else the one no list names),
+        feed-forward dense | moe."""
         out = []
         for l in range(self.n_layers):
-            mixer = ("kda" if l + 1 in self.kda_layers else
-                     "mla" if l + 1 in self.mla_layers else
-                     "mamba2" if l + 1 in self.mamba_layers else
-                     "swa" if l + 1 in self.swa_layers else
-                     "gdn" if l + 1 in self.gdn_layers else "attn")
-            if held:
-                ffn = "moe" if l >= self.moe_first_dense else "dense"
-            else:
-                ffn = "moe" if self.moe_num_experts else "dense"
-            out.append((mixer, ffn))
+            mixer = next((m.name for m in MIXERS.values() if m.layers_field
+                          and l + 1 in getattr(self, m.layers_field)), _PLAIN)
+            moe = self.moe_num_experts and (
+                not self.moe_holds_range or l >= self.moe_first_dense)
+            out.append((mixer, "moe" if moe else "dense"))
         return tuple(out)
 
     def stack_plan(self) -> Tuple[Tuple[Tuple[Tuple[str, str], ...], int], ...]:
@@ -378,115 +371,75 @@ class TransformerConfig:
             return (d + 127) // 128 * 128
         return 4 * self.d_model
 
-    def _mixer_params(self, mixer: str) -> int:
-        d, H = self.d_model, self.n_heads
-        if mixer in ("attn", "swa"):
-            h = self.head_dim
-            return (d * H * h * (2 if self.attn_out_gate else 1)
-                    + 2 * d * self.kv_heads * h + H * h * d
-                    + (2 * h if self.attn_qk_norm else 0))
-        if mixer == "gdn":
-            Hq, Hv, h = self.gdn_k_heads, self.gdn_v_heads, self.gdn_head_dim
-            return ((d + self.gdn_conv) * 2 * (Hq + Hv) * h   # q k v z, convs
-                    - self.gdn_conv * Hv * h                 # z has no conv
-                    + 2 * d * Hv + 2 * Hv + h + Hv * h * d)  # b a, A dt, norm, o
-        if mixer == "mla":
-            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
-            lat = self.kv_lora_rank
-            return (d * (lat + self.qk_rope_head_dim) + lat
-                    + lat * H * (self.qk_nope_head_dim + self.v_head_dim)
-                    + d * H * qk + H * self.v_head_dim * d)
-        if mixer == "mamba2":
-            di, Hm = self.mamba_inner, self.mamba_heads
-            conv = di + 2 * self.mamba_groups * self.mamba_d_state
-            return (d * (di + conv + Hm) + conv * (self.mamba_conv + 1)
-                    + 3 * Hm + di + di * d)  # dt_bias A_log D, norm, out
-        Hk, hd = self.kda_n_heads, self.kda_head_dim
-        rank = self.kda_gate_rank or hd
-        return (4 * d * Hk * hd + 3 * self.kda_conv * Hk * hd   # q k v o, convs
-                + 2 * (d * rank + rank * Hk * hd)                # decay, gate
-                + Hk + Hk * hd + d * Hk + hd)       # A_log dt_bias beta norm
-
-    def _ffn_params(self, ffn: str, active: bool = False) -> float:
-        d = self.d_model
-        if ffn == "dense":
-            return (3 if self.activation == "swiglu" else 2) * d * self.ff_dim
-        E, F = self.moe_num_experts, self.moe_ff_dim
-        if not self.moe_holds_range:
-            n = self.moe_experts_per_token if active else E
-            return n * 3 * d * F + d * E
-        held = self.moe_held_range[1]
-        # Per token and under even routing, k * held / E of the held experts.
-        n = self.moe_experts_per_token * held / E if active else held
-        bias = E if self.moe_router == "sigmoid" and not active else 0
-        gate = d if self.moe_shared_gate else 0
-        return (n + self.moe_shared_experts) * 3 * d * F + d * E + gate + (
-            bias)  # the selection bias is no matmul
-
     def num_params(self) -> int:
-        d, L, V = self.d_model, self.n_layers, self.vocab_size
-        layers = sum(self._mixer_params(m) + self._ffn_params(f)
-                     for m, f in self.layer_kinds())
-        norms = 2 * d * L + d
-        if self.norm == "layernorm":
-            norms *= 2  # biases alongside scales
-        emb = V * d * (1 if self.tie_embeddings else 2)
+        """The leaves `init_params` makes, counted from their shapes."""
+        d = self.d_model
+        layers = sum(_size(_layer_shapes(self, kind))
+                     for kind in self.layer_kinds())
+        final_norm = d * (2 if self.norm == "layernorm" else 1)
+        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         pos = self.max_seq_len * d if self.positional == "learned" else 0
-        return int(layers) + norms + emb + pos
+        return layers + final_norm + emb + pos
 
     def num_active_params(self) -> int:
         """Params touched per token: for MoE, only experts_per_token of the
         E experts execute (of the held ones, under even routing, their share
-        of that), so compute-oriented uses (FLOPs/MFU) must not count the
-        full expert bank."""
+        of that; no shape says how many that is), so compute-oriented uses
+        (FLOPs/MFU) must not count the full expert bank."""
         if not self.moe_num_experts:
             return self.num_params()
-        full = sum(self._ffn_params(f) for _, f in self.layer_kinds())
-        active = sum(self._ffn_params(f, True) for _, f in self.layer_kinds())
-        return int(self.num_params() - full + active)
+        d, E = self.d_model, self.moe_num_experts
+        k = self.moe_experts_per_token
+        if self.moe_holds_range:  # under even routing, k * held / E of them
+            k = k * self.moe_held_range[1] / E + self.moe_shared_experts
+        active = {  # the selection bias is no matmul
+            "dense": _size(_ffn_shapes(self, "dense")),
+            "moe": k * 3 * d * self.moe_ff_dim + d * E + (
+                d if self.moe_shared_gate else 0)}
+        ffns = [f for _, f in self.layer_kinds()]
+        full = sum(_size(_ffn_shapes(self, f)) for f in ffns)
+        return int(self.num_params() - full + sum(active[f] for f in ffns))
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Forward+backward FLOPs/token: 6 per matmul parameter a token
-        touches (no embedding lookup), causal attention 3*S*H*(d_qk + d_v) a
-        softmax layer (a windowed one its band's pairs, S W - W (W - 1) / 2,
-        in place of the triangle's), and the chunked algorithm's operations
-        a KDA, Gated DeltaNet or Mamba-2 layer (see
-        chipbench/reduce/kda_counts.py, ssd_counts.py,
-        qwen3_next_counts.py)."""
+        touches (no embedding lookup) and each layer's `Mixer.core_flops`:
+        causal attention 3*S*H*(d_qk + d_v) a softmax layer, the chunked
+        algorithm's operations a KDA, Gated DeltaNet or Mamba-2 layer
+        (chipbench/reduce/kda_counts.py, ssd_counts.py, qwen3_next_counts.py)."""
         S = seq_len or self.max_seq_len
-        d, H = self.d_model, self.n_heads
         n = self.num_active_params()
         if not self.tie_embeddings:  # the lookup; a tied table is the head
-            n -= self.vocab_size * d
+            n -= self.vocab_size * self.d_model
         if self.positional == "learned":
-            n -= self.max_seq_len * d
+            n -= self.max_seq_len * self.d_model
         total = 6.0 * n
-        C, hd = self.kda_chunk, self.kda_head_dim
         for mixer, _ in self.layer_kinds():
-            if mixer == "attn":
-                total += 3.0 * S * H * 2 * self.head_dim
-            elif mixer == "swa":
-                W = min(self.sliding_window, S)
-                total += 6.0 * (2 * W - W * (W - 1) / S) * H * self.head_dim
-            elif mixer == "mla":
-                total += 3.0 * S * H * (self.qk_nope_head_dim
-                                        + self.qk_rope_head_dim
-                                        + self.v_head_dim)
-            elif mixer == "mamba2":
-                Cm, N = self.mamba_chunk, self.mamba_d_state
-                total += 3.0 * (self.mamba_groups * Cm * N  # C B^T, lower
-                                + self.mamba_inner * (Cm + 4 * N))
-            elif mixer == "gdn":
-                # The chunked rule at a scalar decay: K K^T and Q K^T are
-                # products a key head, the rest a value head.
-                Cg, hg = self.gdn_chunk, self.gdn_head_dim
-                total += 3.0 * (self.gdn_k_heads * 2 * Cg * hg
-                                + self.gdn_v_heads * (
-                                    6 * hg * hg + Cg * 3 * hg + Cg * Cg / 3))
-            else:
-                total += 3.0 * self.kda_n_heads * (
-                    6 * hd * hd + C * 5 * hd + C * C / 3)
+            total += MIXERS[mixer].core_flops(self, S)
         return total
+
+
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    """One kind of token mixer, everything this file and decoding ask of it
+    (`MIXERS` is the table; docs/model_layers.md, "Adding a mixer"):
+    `layers_field`, the `TransformerConfig` field that lists its layers
+    (1-based; None: the kind of a layer no list names); `shapes(cfg)`, its
+    part of `_layer_shapes`; `apply(cfg, kind, h, layer, positions, overlap)
+    -> (delta, k, v)`, h [B,S,d] the normed residual, k and v its (roped)
+    keys and values or None; `core_flops(cfg, S)`, its term of
+    `flops_per_token`; `scope(cfg)`, the device scope its layers run under
+    (chipbench/metrics read it) or None; `cut_rows`, whether it works a
+    rank's rows of a residual cut over `tensor` (`overlap`) or is handed
+    them all; `no_decode`, why models/generate.py cannot serve it (None:
+    its keys and values are what the cache holds)."""
+    name: str
+    layers_field: Optional[str]
+    shapes: Callable[[TransformerConfig], Dict[str, Any]]
+    apply: Callable[..., Tuple[jax.Array, Any, Any]]
+    core_flops: Callable[[TransformerConfig, int], float]
+    scope: Callable[[TransformerConfig], Optional[str]] = lambda cfg: None
+    cut_rows: bool = False
+    no_decode: Optional[str] = None
 
 
 # The decay a = exp(-exp(A_log) * softplus(. + dt_bias)) of the `mamba2` and
@@ -513,131 +466,151 @@ def _unit_norm_init(cfg: TransformerConfig) -> str:
     return "zeros" if cfg.norm_offset else "ones"
 
 
-def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
-    """{leaf: (shape, logical axes, init)} of one layer of `kind`: THE table
-    `init_params` and `param_logical_specs` are built from. init: "ones" |
-    "zeros" | ("normal", std) | a callable key -> array."""
-    mixer, ffn = kind
-    d, L = cfg.d_model, cfg.n_layers
-    H = cfg.n_heads
-    fan = lambda n: ("normal", 1.0 / math.sqrt(n))
-    out_std = lambda n: ("normal", 1.0 / math.sqrt(2 * L * n))
-    unit = _unit_norm_init(cfg)
-    sh: Dict[str, Any] = {
-        "attn_norm": ((d,), (None,), unit),
-        "mlp_norm": ((d,), (None,), unit),
-    }
-    if mixer in ("attn", "swa"):
-        # Projections are FUSED into single matmuls (one MXU op instead of
-        # 2-3: q/k/v together for MHA, k/v together for GQA, gate/up together
-        # for swiglu). The fusion factor is its own array dim — NOT folded
-        # into the feature dim — so tensor-parallel sharding of heads/mlp
-        # stays aligned to shard boundaries (Megatron fused-qkv, done the
-        # GSPMD-friendly way).
-        hd, KVH = cfg.head_dim, cfg.kv_heads
-        sh["wo"] = ((H * hd, d), ("heads", "embed"), out_std(H * hd))
-        if KVH == H:
-            sh["wqkv"] = ((d, 3, H, hd), ("embed", None, "heads", None), fan(d))
-        else:
-            sh["wq"] = ((d, H, hd), ("embed", "heads", None), fan(d))
-            sh["wkv"] = ((d, 2, KVH, hd), ("embed", None, "kv_heads", None),
-                         fan(d))
-        if cfg.attn_out_gate:
-            sh["wq_gate"] = ((d, H, hd), ("embed", "heads", None), fan(d))
-        if cfg.attn_qk_norm:
-            sh["q_norm"] = ((hd,), (None,), unit)
-            sh["k_norm"] = ((hd,), (None,), unit)
-    elif mixer == "gdn":
-        # q and k (key heads) and v and the gate z (value heads) each fused
-        # over an array dim of their own, as `mamba_wzx` is; beta and the
-        # decay's input are narrow. The convolution is depthwise: one over
-        # [q; k; v] is one over each.
-        Hq, Hv, hg = cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_head_dim
-        K = cfg.gdn_conv
-        sh["gdn_wqk"] = ((d, 2, Hq, hg), ("embed", None, "heads", None),
-                         fan(d))
-        sh["gdn_wvz"] = ((d, 2, Hv, hg), ("embed", None, "heads", None),
-                         fan(d))
-        sh["gdn_wba"] = ((d, 2, Hv), ("embed", None, "heads"), fan(d))
-        sh["gdn_conv_qk"] = ((K, 2, Hq, hg), (None, None, "heads", None),
-                             fan(K))
-        sh["gdn_conv_v"] = ((K, Hv, hg), (None, "heads", None), fan(K))
-        sh["gdn_A_log"] = ((Hv,), ("heads",), _a_log_init((Hv,)))
-        sh["gdn_dt_bias"] = ((Hv,), ("heads",), _dt_bias_init((Hv,)))
-        sh["gdn_o_norm"] = ((hg,), (None,), "ones")
-        sh["gdn_wo"] = ((Hv, hg, d), ("heads", None, "embed"),
-                        out_std(Hv * hg))
-    elif mixer == "mla":
-        lat, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-        nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-        sh["mla_wq"] = ((d, H, nope + rope), ("embed", "heads", None), fan(d))
-        sh["mla_wkva"] = ((d, lat + rope), ("embed", None), fan(d))
-        sh["mla_kv_norm"] = ((lat,), (None,), "ones")
-        sh["mla_wkvb"] = ((lat, H, nope + dv), (None, "heads", None), fan(lat))
-        sh["mla_wo"] = ((H, dv, d), ("heads", None, "embed"), out_std(H * dv))
-    elif mixer == "mamba2":
-        # in_proj's three parts are leaves of their own (gate z and x fused
-        # over an array dim of their own, as wkv is): heads stay a dim that
-        # a tensor-parallel rule can cut, B / C and dt are narrow.
-        Hm, P = cfg.mamba_heads, cfg.mamba_head_dim
-        G, N, K = cfg.mamba_groups, cfg.mamba_d_state, cfg.mamba_conv
-        sh["mamba_wzx"] = ((d, 2, Hm, P), ("embed", None, "heads", None),
-                           fan(d))
-        sh["mamba_wbc"] = ((d, 2, G, N), ("embed", None, None, None), fan(d))
-        sh["mamba_wdt"] = ((d, Hm), ("embed", "heads"), fan(d))
-        sh["mamba_conv_x"] = ((K, Hm, P), (None, "heads", None), fan(K))
-        sh["mamba_conv_x_b"] = ((Hm, P), ("heads", None), "zeros")
-        sh["mamba_conv_bc"] = ((K, 2, G, N), (None, None, None, None), fan(K))
-        sh["mamba_conv_bc_b"] = ((2, G, N), (None, None, None), "zeros")
-        sh["mamba_A_log"] = ((Hm,), ("heads",), _a_log_init((Hm,)))
-        sh["mamba_dt_bias"] = ((Hm,), ("heads",), _dt_bias_init((Hm,)))
-        sh["mamba_D"] = ((Hm,), ("heads",), "ones")
-        sh["mamba_norm"] = ((Hm, P), ("heads", None), "ones")
-        sh["mamba_wo"] = ((Hm, P, d), ("heads", None, "embed"),
-                          out_std(Hm * P))
+# Leaves are {leaf: (shape, logical axes, init)}, init "ones" | "zeros" |
+# ("normal", std) | a callable key -> array: `_fan` for a product of fan-in
+# n, `_out_std` for one back into the residual. A `Mixer.shapes` each kind:
+_fan = lambda n: ("normal", 1.0 / math.sqrt(n))
+_out_std = lambda cfg, n: ("normal", 1.0 / math.sqrt(2 * cfg.n_layers * n))
+_size = lambda shapes: sum(math.prod(sh) for sh, _, _ in shapes.values())
+
+
+def _attn_shapes(cfg: TransformerConfig):
+    # Projections are FUSED into single matmuls (one MXU op instead of 2-3:
+    # q/k/v together for MHA, k/v together for GQA; gate/up for swiglu). The
+    # fusion factor is its own array dim — NOT folded into the feature dim —
+    # so tensor-parallel sharding of heads/mlp stays aligned to shard
+    # boundaries (Megatron fused-qkv, done the GSPMD-friendly way).
+    d, H, hd, KVH = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    fan, unit = _fan(d), _unit_norm_init(cfg)
+    sh = {"wo": ((H * hd, d), ("heads", "embed"), _out_std(cfg, H * hd))}
+    if KVH == H:
+        sh["wqkv"] = ((d, 3, H, hd), ("embed", None, "heads", None), fan)
     else:
-        Hk, hd = cfg.kda_n_heads, cfg.kda_head_dim
-        rank, K = cfg.kda_gate_rank or hd, cfg.kda_conv
-        for n in ("q", "k", "v"):
-            sh["kda_w" + n] = ((d, Hk, hd), ("embed", "heads", None), fan(d))
-            sh["kda_conv_" + n] = ((K, Hk, hd), (None, "heads", None),
-                                   fan(K))
-        for n in ("f", "g"):  # decay and output gate, low rank
-            sh[f"kda_w{n}1"] = ((d, rank), ("embed", None), fan(d))
-            sh[f"kda_w{n}2"] = ((rank, Hk, hd), (None, "heads", None),
-                                fan(rank))
-        sh["kda_A_log"] = ((Hk,), ("heads",), _a_log_init((Hk,)))
-        sh["kda_dt_bias"] = ((Hk, hd), ("heads", None),
-                             _dt_bias_init((Hk, hd)))
-        sh["kda_wb"] = ((d, Hk), ("embed", "heads"), fan(d))
-        sh["kda_o_norm"] = ((hd,), (None,), "ones")
-        sh["kda_wo"] = ((Hk, hd, d), ("heads", None, "embed"),
-                        out_std(Hk * hd))
+        sh["wq"] = ((d, H, hd), ("embed", "heads", None), fan)
+        sh["wkv"] = ((d, 2, KVH, hd), ("embed", None, "kv_heads", None), fan)
+    if cfg.attn_out_gate:
+        sh["wq_gate"] = ((d, H, hd), ("embed", "heads", None), fan)
+    if cfg.attn_qk_norm:
+        sh["q_norm"] = ((hd,), (None,), unit)
+        sh["k_norm"] = ((hd,), (None,), unit)
+    return sh
+
+
+def _gdn_shapes(cfg: TransformerConfig):
+    # q and k (key heads) and v and the gate z (value heads) each fused over
+    # an array dim of their own, as `mamba_wzx` is; beta and the decay's
+    # input are narrow. The convolution is depthwise: one over [q; k; v] is
+    # one over each.
+    d, Hq, Hv = cfg.d_model, cfg.gdn_k_heads, cfg.gdn_v_heads
+    hg, K, fan = cfg.gdn_head_dim, cfg.gdn_conv, _fan(cfg.d_model)
+    return {
+        "gdn_wqk": ((d, 2, Hq, hg), ("embed", None, "heads", None), fan),
+        "gdn_wvz": ((d, 2, Hv, hg), ("embed", None, "heads", None), fan),
+        "gdn_wba": ((d, 2, Hv), ("embed", None, "heads"), fan),
+        "gdn_conv_qk": ((K, 2, Hq, hg), (None, None, "heads", None), _fan(K)),
+        "gdn_conv_v": ((K, Hv, hg), (None, "heads", None), _fan(K)),
+        "gdn_A_log": ((Hv,), ("heads",), _a_log_init((Hv,))),
+        "gdn_dt_bias": ((Hv,), ("heads",), _dt_bias_init((Hv,))),
+        "gdn_o_norm": ((hg,), (None,), "ones"),
+        "gdn_wo": ((Hv, hg, d), ("heads", None, "embed"),
+                   _out_std(cfg, Hv * hg)),
+    }
+
+
+def _mla_shapes(cfg: TransformerConfig):
+    d, H, lat, fan = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank, _fan(cfg.d_model)
+    rope, nope, dv = cfg.qk_rope_head_dim, cfg.qk_nope_head_dim, cfg.v_head_dim
+    return {
+        "mla_wq": ((d, H, nope + rope), ("embed", "heads", None), fan),
+        "mla_wkva": ((d, lat + rope), ("embed", None), fan),
+        "mla_kv_norm": ((lat,), (None,), "ones"),
+        "mla_wkvb": ((lat, H, nope + dv), (None, "heads", None), _fan(lat)),
+        "mla_wo": ((H, dv, d), ("heads", None, "embed"),
+                   _out_std(cfg, H * dv)),
+    }
+
+
+def _mamba_shapes(cfg: TransformerConfig):
+    # in_proj's three parts are leaves of their own (gate z and x fused over
+    # an array dim of their own, as wkv is): heads stay a dim that a
+    # tensor-parallel rule can cut, B / C and dt are narrow.
+    d, Hm, P = cfg.d_model, cfg.mamba_heads, cfg.mamba_head_dim
+    G, N, K = cfg.mamba_groups, cfg.mamba_d_state, cfg.mamba_conv
+    fan = _fan(d)
+    return {
+        "mamba_wzx": ((d, 2, Hm, P), ("embed", None, "heads", None), fan),
+        "mamba_wbc": ((d, 2, G, N), ("embed", None, None, None), fan),
+        "mamba_wdt": ((d, Hm), ("embed", "heads"), fan),
+        "mamba_conv_x": ((K, Hm, P), (None, "heads", None), _fan(K)),
+        "mamba_conv_x_b": ((Hm, P), ("heads", None), "zeros"),
+        "mamba_conv_bc": ((K, 2, G, N), (None, None, None, None), _fan(K)),
+        "mamba_conv_bc_b": ((2, G, N), (None, None, None), "zeros"),
+        "mamba_A_log": ((Hm,), ("heads",), _a_log_init((Hm,))),
+        "mamba_dt_bias": ((Hm,), ("heads",), _dt_bias_init((Hm,))),
+        "mamba_D": ((Hm,), ("heads",), "ones"),
+        "mamba_norm": ((Hm, P), ("heads", None), "ones"),
+        "mamba_wo": ((Hm, P, d), ("heads", None, "embed"),
+                     _out_std(cfg, Hm * P)),
+    }
+
+
+def _kda_shapes(cfg: TransformerConfig):
+    d, Hk, hd = cfg.d_model, cfg.kda_n_heads, cfg.kda_head_dim
+    rank, K, fan = cfg.kda_gate_rank or hd, cfg.kda_conv, _fan(cfg.d_model)
+    sh = {}
+    for n in ("q", "k", "v"):
+        sh["kda_w" + n] = ((d, Hk, hd), ("embed", "heads", None), fan)
+        sh["kda_conv_" + n] = ((K, Hk, hd), (None, "heads", None), _fan(K))
+    for n in ("f", "g"):  # decay and output gate, low rank
+        sh[f"kda_w{n}1"] = ((d, rank), ("embed", None), fan)
+        sh[f"kda_w{n}2"] = ((rank, Hk, hd), (None, "heads", None), _fan(rank))
+    sh["kda_A_log"] = ((Hk,), ("heads",), _a_log_init((Hk,)))
+    sh["kda_dt_bias"] = ((Hk, hd), ("heads", None), _dt_bias_init((Hk, hd)))
+    sh["kda_wb"] = ((d, Hk), ("embed", "heads"), fan)
+    sh["kda_o_norm"] = ((hd,), (None,), "ones")
+    sh["kda_wo"] = ((Hk, hd, d), ("heads", None, "embed"),
+                    _out_std(cfg, Hk * hd))
+    return sh
+
+
+def _ffn_shapes(cfg: TransformerConfig, ffn: str):
+    """The feed-forward's leaves, as `Mixer.shapes` gives a mixer's."""
+    d, fan = cfg.d_model, _fan(cfg.d_model)
     if ffn == "dense":
         F = cfg.ff_dim
-        sh["w_down"] = ((F, d), ("mlp", "embed"), out_std(F))
+        sh = {"w_down": ((F, d), ("mlp", "embed"), _out_std(cfg, F))}
         if cfg.activation == "swiglu":
-            sh["w_gate_up"] = ((d, 2, F), ("embed", None, "mlp"), fan(d))
+            sh["w_gate_up"] = ((d, 2, F), ("embed", None, "mlp"), fan)
         else:
-            sh["w_up"] = ((d, F), ("embed", "mlp"), fan(d))
-    else:
-        E, F = cfg.moe_num_experts, cfg.moe_ff_dim
-        held = cfg.moe_holds_range
-        Eh = cfg.moe_held_range[1] if held else E  # GShard holds them all
-        sh["router"] = ((d, E), ("embed", None), fan(d))
-        if cfg.moe_router == "sigmoid":
-            sh["router_bias"] = ((E,), (None,), "zeros")  # a buffer: no gradient
-        # The scales are the matmuls' fan-ins d and F, not the leading dim E.
-        sh["moe_w_gate_up"] = ((Eh, d, 2, F), ("expert", "embed", None, "mlp"),
-                               fan(d))
-        sh["moe_w_down"] = ((Eh, F, d), ("expert", "mlp", "embed"), out_std(F))
-        Fs = cfg.moe_shared_experts * F if held else 0
-        if Fs:
-            sh["shared_w_gate_up"] = ((d, 2, Fs), ("embed", None, "mlp"),
-                                      fan(d))
-            sh["shared_w_down"] = ((Fs, d), ("mlp", "embed"), out_std(Fs))
-            if cfg.moe_shared_gate:
-                sh["shared_gate"] = ((d,), ("embed",), fan(d))
+            sh["w_up"] = ((d, F), ("embed", "mlp"), fan)
+        return sh
+    E, F = cfg.moe_num_experts, cfg.moe_ff_dim
+    held = cfg.moe_holds_range
+    Eh = cfg.moe_held_range[1] if held else E  # GShard holds them all
+    sh = {"router": ((d, E), ("embed", None), fan)}
+    if cfg.moe_router == "sigmoid":
+        sh["router_bias"] = ((E,), (None,), "zeros")  # a buffer: no gradient
+    # The scales are the matmuls' fan-ins d and F, not the leading dim E.
+    sh["moe_w_gate_up"] = ((Eh, d, 2, F), ("expert", "embed", None, "mlp"), fan)
+    sh["moe_w_down"] = ((Eh, F, d), ("expert", "mlp", "embed"),
+                        _out_std(cfg, F))
+    Fs = cfg.moe_shared_experts * F if held else 0
+    if Fs:
+        sh["shared_w_gate_up"] = ((d, 2, Fs), ("embed", None, "mlp"), fan)
+        sh["shared_w_down"] = ((Fs, d), ("mlp", "embed"), _out_std(cfg, Fs))
+        if cfg.moe_shared_gate:
+            sh["shared_gate"] = ((d,), ("embed",), fan)
+    return sh
+
+
+def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
+    """The leaves of one layer of `kind`: THE table `init_params`,
+    `param_logical_specs` and `num_params` are built from, in the order
+    `init_params` folds into a leaf's key."""
+    mixer, ffn = kind
+    d, unit = cfg.d_model, _unit_norm_init(cfg)
+    sh = {"attn_norm": ((d,), (None,), unit), "mlp_norm": ((d,), (None,), unit),
+          **MIXERS[mixer].shapes(cfg), **_ffn_shapes(cfg, ffn)}
     if cfg.norm == "layernorm":
         sh["attn_norm_b"] = ((d,), (None,), "zeros")
         sh["mlp_norm_b"] = ((d,), (None,), "zeros")
@@ -655,7 +628,7 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
 
 
 def _one_tree(cfg: TransformerConfig) -> bool:
-    return (all(m == "attn" for m, _ in cfg.layer_kinds())
+    return (all(m == _PLAIN for m, _ in cfg.layer_kinds())
             and not cfg.moe_holds_range)
 
 
@@ -846,7 +819,7 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
            {"wq": "bsd,dnh->bsnh", "wkv": "bsd,dcnh->bscnh"})
     outs = None
     if overlap is not None:
-        axes = _layer_shapes(cfg, (mixer, "dense"))
+        axes = _attn_shapes(cfg)
         outs = overlap.gather_matmul(
             "qkv", h, [(eq, _w(layer, n, cfg), axes[n][1])
                        for n, eq in eqs.items()])
@@ -892,7 +865,7 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
             act = lambda u: jax.nn.gelu(checkpoint_name(u, "gate_up"))
         delta = None
         if overlap is not None:
-            axes = _layer_shapes(cfg, ("attn", "dense"))
+            axes = _ffn_shapes(cfg, "dense")
             delta = overlap.gathered_mlp(
                 ("gate_up", "w_down"), h, eq, _w(layer, w_in, cfg),
                 axes[w_in][1], act, _w(layer, "w_down", cfg),
@@ -937,7 +910,10 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
     return delta, counters
 
 
-def _kda_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+# The mixers, each a `Mixer.apply`.
+
+
+def _kda_mixer(cfg, kind, h, layer, positions, overlap):
     from ray_tpu.ops.kda import kda_chunked, l2_normalize, short_conv
 
     f32 = jnp.float32
@@ -960,10 +936,10 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
         o, _ = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
     o = _norm(o, layer["kda_o_norm"], None, "rmsnorm", cfg.norm_eps)
     o = o * jax.nn.sigmoid(low_rank("g"))
-    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "kda_wo", cfg))
+    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "kda_wo", cfg)), None, None
 
 
-def _gdn_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+def _gdn_mixer(cfg, kind, h, layer, positions, overlap):
     """Gated DeltaNet: the delta rule of ops/kda.py at one decay a value
     head, `gdn_k_heads` key heads serving `gdn_v_heads` value heads. The
     output's norm is over a head's columns with a plain weight (never
@@ -985,10 +961,10 @@ def _gdn_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     o = _norm(o.astype(f32), layer["gdn_o_norm"], None, "rmsnorm",
               cfg.norm_eps)
     o = (o * jax.nn.silu(vz[:, :, 1].astype(f32))).astype(h.dtype)
-    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "gdn_wo", cfg))
+    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "gdn_wo", cfg)), None, None
 
 
-def _mamba_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
+def _mamba_mixer(cfg, kind, h, layer, positions, overlap):
     from ray_tpu.ops.kda import short_conv
     from ray_tpu.ops.ssd import ssd_chunked
 
@@ -1014,11 +990,11 @@ def _mamba_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params):
     y = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(B, S, G, -1)
     y = _norm(y, layer["mamba_norm"].reshape(G, -1), None, "rmsnorm",
               cfg.norm_eps).reshape(z.shape).astype(h.dtype)
-    return jnp.einsum("bsnp,npd->bsd", y, _w(layer, "mamba_wo", cfg))
+    return (jnp.einsum("bsnp,npd->bsd", y, _w(layer, "mamba_wo", cfg)),
+            None, None)
 
 
-def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params,
-               positions: jax.Array):
+def _mla_mixer(cfg, kind, h, layer, positions, overlap):
     """Latent attention. Where the stack's `positional` is "rope" the last
     `qk_rope_head_dim` columns of every query head and the one key part the
     heads share are rotated (decoupled RoPE, plain theta), the key part once,
@@ -1042,12 +1018,10 @@ def _mla_mixer(cfg: TransformerConfig, h: jax.Array, layer: Params,
     k = jnp.concatenate([kv[..., :nope], kr], axis=-1)
     q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
     o = attention(q, k, kv[..., nope:], causal=True)   # / sqrt(nope + rope)
-    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg))
+    return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "mla_wo", cfg)), None, None
 
 
-def _attn_mixer(cfg: TransformerConfig, kind: Tuple[str, str], h: jax.Array,
-                layer: Params, positions: jax.Array,
-                overlap: Optional[tp.Overlap]):
+def _attn_mixer(cfg, kind, h, layer, positions, overlap):
     """Softmax attention ("attn", or "swa" under its window) -> (delta, k,
     v). Where `cfg.attn_gated`, what a plain layer lacks (the q / k norms,
     a rotation of part of a head, the output's gate) runs under the scope
@@ -1074,11 +1048,60 @@ def _attn_mixer(cfg: TransformerConfig, kind: Tuple[str, str], h: jax.Array,
     o, wo, delta = o.reshape(B, S, -1), _w(layer, "wo", cfg), None
     if overlap is not None:
         delta = overlap.matmul_scatter(
-            "wo", o, "bsf,fd->bsd", wo,
-            _layer_shapes(cfg, kind)["wo"][1])
+            "wo", o, "bsf,fd->bsd", wo, _attn_shapes(cfg)["wo"][1])
     if delta is None:
         delta = o @ wo
     return delta, k, v
+
+
+def _swa_flops(cfg: TransformerConfig, S: int) -> float:
+    W = min(cfg.sliding_window, S)  # the band's pairs for the triangle's
+    return 6.0 * (2 * W - W * (W - 1) / S) * cfg.n_heads * cfg.head_dim
+
+
+def _mamba_flops(cfg: TransformerConfig, S: int) -> float:
+    C, N = cfg.mamba_chunk, cfg.mamba_d_state
+    return 3.0 * (cfg.mamba_groups * C * N  # C B^T, lower
+                  + cfg.mamba_inner * (C + 4 * N))
+
+
+# A head's operations a token of the chunked delta rule (ops/kda.py): n = 5
+# at a decay a channel; 3 a value head at a scalar's, K K^T, Q K^T a key head.
+_delta_flops = lambda C, hd, n: 6 * hd * hd + C * n * hd + C * C / 3
+# A gated layer runs under a scope that tells its kernels from a plain one's.
+_GATTN = lambda cfg: "gattn" if cfg.attn_gated else None
+_NO_CACHE = (
+    "decode holds keys and values of one length a layer only: a stack with "
+    "KDA / MLA / Mamba-2 / windowed layers trains but does not serve yet")
+
+MIXERS: Dict[str, Mixer] = {m.name: m for m in (
+    Mixer("attn", None, _attn_shapes, _attn_mixer,
+          lambda cfg, S: 3.0 * S * cfg.n_heads * 2 * cfg.head_dim,
+          scope=_GATTN, cut_rows=True),
+    Mixer("swa", "swa_layers", _attn_shapes, _attn_mixer, _swa_flops,
+          scope=_GATTN, cut_rows=True, no_decode=_NO_CACHE),
+    Mixer("mla", "mla_layers", _mla_shapes, _mla_mixer,
+          lambda cfg, S: 3.0 * S * cfg.n_heads * (
+              cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim),
+          scope=lambda cfg: "mla", no_decode=_NO_CACHE),
+    Mixer("kda", "kda_layers", _kda_shapes, _kda_mixer,
+          lambda cfg, S: 3.0 * cfg.kda_n_heads * _delta_flops(
+              cfg.kda_chunk, cfg.kda_head_dim, 5),
+          scope=lambda cfg: "kda", no_decode=_NO_CACHE),
+    Mixer("mamba2", "mamba_layers", _mamba_shapes, _mamba_mixer, _mamba_flops,
+          scope=lambda cfg: "mamba", no_decode=_NO_CACHE),
+    Mixer("gdn", "gdn_layers", _gdn_shapes, _gdn_mixer,
+          lambda cfg, S: 3.0 * (
+              cfg.gdn_k_heads * 2 * cfg.gdn_chunk * cfg.gdn_head_dim
+              + cfg.gdn_v_heads * _delta_flops(
+                  cfg.gdn_chunk, cfg.gdn_head_dim, 3)),
+          scope=lambda cfg: "gdn", no_decode=(
+              "decode cannot serve a Gated DeltaNet (gdn) layer: it would "
+              "hold a delta-rule state and the convolution's last tokens in "
+              "place of keys and values (ROADMAP R7 / R9); the stack trains "
+              "but does not serve yet")),
+)}
+_PLAIN = next(n for n, m in MIXERS.items() if m.layers_field is None)
 
 
 def _whole(x: jax.Array, overlap: Optional[tp.Overlap]) -> jax.Array:
@@ -1093,40 +1116,26 @@ def _scaled(x: jax.Array, scale: float) -> jax.Array:
 
 
 def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
-                layer: Params, positions: jax.Array, return_kv: bool = False):
-    """One layer of `kind` -> (x, extras), extras as `_mlp_block` gives them.
-    `return_kv` (an "attn" / "swa" mixer only): -> (x, extras, k, v), this layer's
-    (roped) keys and values, from which the prefill of models/generate.py
-    primes its cache."""
+                layer: Params, positions: jax.Array):
+    """One layer of `kind` -> (x, extras, k, v): extras as `_mlp_block` gives
+    them, k and v as the mixer does (`Mixer.apply`; the prefill of
+    models/generate.py primes its cache from them)."""
     mixer, ffn = kind
-    B, S, d = x.shape
+    B, S, _ = x.shape
     # Under a mesh with a `tensor` axis the residual's rows are cut over it
-    # (parallel/tensor_overlap.py): norms and adds work a rank's rows, the
-    # "attn" / "swa" projections and the dense MLP gather and scatter them
-    # behind their products, any other mixer or feed-forward gets them
-    # gathered (`whole`) and leaves its sum over `tensor` to the partitioner.
+    # (parallel/tensor_overlap.py): norms and adds work a rank's rows, as do
+    # a `Mixer.cut_rows` mixer and the dense MLP; any other gets them gathered
+    # (`whole`) and leaves its sum over `tensor` to the partitioner.
     overlap = tp.plan(B, S)
     residual = tp.RESIDUAL if overlap else tp.ACTIVATION
     whole = functools.partial(_whole, overlap=overlap)
     h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm,
               cfg.norm_eps, cfg.norm_offset)
-    k = v = None
-    if mixer == "kda":
-        with jax.named_scope("kda"):
-            delta = _kda_mixer(cfg, whole(h), layer)
-    elif mixer == "mla":
-        with jax.named_scope("mla"):
-            delta = _mla_mixer(cfg, whole(h), layer, positions)
-    elif mixer == "mamba2":
-        with jax.named_scope("mamba"):
-            delta = _mamba_mixer(cfg, whole(h), layer)
-    elif mixer == "gdn":
-        with jax.named_scope("gdn"):
-            delta = _gdn_mixer(cfg, whole(h), layer)
-    else:  # a gated layer runs under a scope that tells its kernels apart
-        with (jax.named_scope("gattn") if cfg.attn_gated
-              else contextlib.nullcontext()):
-            delta, k, v = _attn_mixer(cfg, kind, h, layer, positions, overlap)
+    row = MIXERS[mixer]
+    scope = row.scope(cfg)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        delta, k, v = row.apply(cfg, kind, h if row.cut_rows else whole(h),
+                                layer, positions, overlap)
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
     h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
               cfg.norm_eps, cfg.norm_offset)
@@ -1135,9 +1144,7 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
     if overlap is not None:
         overlap.observe()
-    if return_kv:
-        return x, extras, k, v
-    return x, extras
+    return x, extras, k, v
 
 
 def embed_tokens(params: Params, tokens: jax.Array, cfg: TransformerConfig) -> jax.Array:
@@ -1171,7 +1178,7 @@ def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
     """(x, layer) -> (x, extras): one layer of `kind` under cfg's remat
     policy, the body `_backbone` scans and the pipeline-parallel stage apply
     (parallel/pipeline.py) shares."""
-    body = lambda x, layer: _layer_body(cfg, kind, x, layer, positions)
+    body = lambda x, layer: _layer_body(cfg, kind, x, layer, positions)[:2]
     if not cfg.remat:
         return body
     from ray_tpu.ops import flash_attention as fa, kda, moe, ssd

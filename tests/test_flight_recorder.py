@@ -265,8 +265,23 @@ def test_phase_events_survive_controller_bounce(tmp_path):
                 return x
 
         a = Ping.remote()
-        # First call warms the direct route (worker-to-worker dispatch).
         assert ray_tpu.get(a.ping.remote(1), timeout=60) == 1
+        # The outage call below must ride the direct route (worker-to-
+        # worker dispatch). A first call submitted while the actor was
+        # still pending went through the controller and left the route
+        # unresolved (`_resolve_route`: state != "alive"); the call during
+        # the outage would then wait out two 20 s reconnect deadlines on a
+        # controller that is down by design. The actor is alive now, so the
+        # next call resolves it: wait for that, with a bound of its own.
+        from ray_tpu.core import api
+
+        def warm():
+            assert ray_tpu.get(a.ping.remote(1), timeout=60) == 1
+            return api._get_route(ctx.get_worker_context(),
+                                  a._actor_id).conn
+
+        assert _poll(warm, timeout=10), \
+            "the direct route to the actor never came up"
         tcr._wait_snapshot(state_path, lambda s: s.get("nodes"))
 
         killed.extend(tcr._worker_pids(client))

@@ -2,8 +2,10 @@
 the four multipliers, `positional="none"` with `attn` layers) on the CPU at
 the tiny preset: the program against the plain reference
 (chipbench/reference/granite_hybrid.py: nothing from ray_tpu, the recurrence
-token by token) on seeded weights, the plan, the counts, the configuration
-file, and what decoding refuses. The core itself is tests/test_ssd.py."""
+token by token) on seeded weights, the multipliers, the scanned period
+against its layers one by one, the counts and the configuration file. Its
+plan, lowering and what decoding refuses are tests/test_model_table.py; the
+core itself is tests/test_ssd.py."""
 import dataclasses
 import json
 import os
@@ -15,8 +17,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import transformer as tfm
-from ray_tpu.models.configs import (gpt2_125m, granite_hybrid_tiny,
-                                    kimi_linear_tiny, llama_tiny)
+from ray_tpu.models.configs import granite_hybrid_tiny
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -105,27 +106,6 @@ def test_each_multiplier_changes_the_output_and_its_default_does_not(
     np.testing.assert_allclose(f(spelled), f(base), atol=1e-6)
 
 
-def test_stack_plans():
-    """The published 40-layer list is ONE segment of ten kinds, four
-    repeats; the ten-layer cut is three segments; the accepted
-    configurations' plans are what they were."""
-    m, a = ("mamba2", "dense"), ("attn", "dense")
-    period = (m,) * 5 + (a,) + (m,) * 4
-    assert granite_hybrid_tiny(n_layers=40).stack_plan() == ((period, 4),)
-    assert granite_hybrid_tiny().stack_plan() == (((m,), 5), ((a,), 1),
-                                                  ((m,), 4))
-    assert granite_hybrid_tiny().layer_kinds() == period
-    assert [(len(p), r) for p, r in kimi_linear_tiny(
-        n_layers=27).stack_plan()] == [(1, 1), (4, 6), (1, 1), (1, 1)]
-    assert [(len(p), r) for p, r in kimi_linear_tiny().stack_plan()] == [
-        (1, 1), (1, 2), (1, 1), (1, 1)]
-    for cfg in (llama_tiny(), gpt2_125m(), llama_tiny(n_layers=24,
-                                                      n_kv_heads=2)):
-        assert cfg.stack_plan() == (((cfg.layer_kinds()[0],), cfg.n_layers),)
-    with pytest.raises(ValueError):
-        granite_hybrid_tiny(kda_layers=(1,))  # listed twice
-
-
 def test_scanned_period_matches_layer_by_layer(case):
     """The 20-layer stack (one segment of ten kinds, two repeats, under the
     remat policy) against the same layers applied one by one."""
@@ -140,7 +120,7 @@ def test_scanned_period_matches_layer_by_layer(case):
         pos = jnp.broadcast_to(jnp.arange(toks.shape[1], dtype=jnp.int32),
                                toks.shape)
         for l, kind in enumerate(cfg.layer_kinds()):
-            x, _ = tfm._layer_body(cfg, kind, x, tfm.layer_params(p, cfg, l),
+            x, *_ = tfm._layer_body(cfg, kind, x, tfm.layer_params(p, cfg, l),
                                    pos)
         return tfm.lm_head(p, x, cfg)
 
@@ -174,9 +154,9 @@ def test_counts_and_the_configuration_file():
     tc = dict(conf["transformer_config"])
     tc["dtype"], tc["param_dtype"] = jnp.bfloat16, jnp.float32
     cfg = tfm.TransformerConfig(**tc)
-    assert cfg._mixer_params("mamba2") == 25_847_232
-    assert cfg._mixer_params("attn") == 10_485_760
-    assert cfg._ffn_params("dense") == 50_331_648
+    assert tfm._size(tfm.MIXERS["mamba2"].shapes(cfg)) == 25_847_232
+    assert tfm._size(tfm.MIXERS["attn"].shapes(cfg)) == 10_485_760
+    assert tfm._size(tfm._ffn_shapes(cfg, "dense")) == 50_331_648
     assert cfg.num_params() == cfg.num_active_params() == 772_160_448
     shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
                             jax.random.key(0))
@@ -224,18 +204,6 @@ def test_counts_and_the_configuration_file():
     assert specs["mamba_wzx"] == ("layers", "embed", None, "heads", None)
     assert specs["mamba_wo"] == ("layers", "heads", None, "embed")
     assert specs["mamba_A_log"] == ("layers", "heads")
-
-
-def test_decoding_refuses_the_mixer_and_the_multipliers(case):
-    from ray_tpu.models.generate import prefill
-
-    toks = case["toks"][:, :8]
-    with pytest.raises(NotImplementedError, match="Mamba-2"):
-        prefill(case["params"], toks, case["cfg"], 16)
-    scaled = llama_tiny(logit_scale=8.0)
-    with pytest.raises(NotImplementedError, match="logit_scale"):
-        prefill(tfm.init_params(jax.random.key(0), scaled), toks,
-                         scaled, 16)
 
 
 def test_fused_ce_applies_the_logit_scale(case):

@@ -5,8 +5,10 @@ held range) on the CPU at the tiny preset: the program against the plain
 reference (chipbench/reference/kanana2.py: nothing from ray_tpu, the rotation
 on the published interleaved layout, full softmax rows, a loop over the held
 experts) on seeded weights, five ways to get the rotation wrong, the layout
-turn, the shares of the expert layer, the plan, the counts, the
-configuration file, and what decoding refuses."""
+turn, the counts and the configuration file. What it shares with the other
+families is tests/test_model_table.py (plan, lowering, decoding),
+test_preset_programs.py (the train step, the flash path) and
+test_expert_shares.py."""
 import contextlib
 import dataclasses
 import functools
@@ -21,7 +23,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import transformer as tfm
-from ray_tpu.models.configs import kanana2_tiny, kimi_linear_tiny
+from ray_tpu.models.configs import kanana2_tiny
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -101,17 +103,16 @@ def _sizes(cfg, **changes):
     return W.KananaSizes(dict(tc, **changes), cfg.norm_eps)
 
 
-def _numbers(cfg, sz, key, toks):
-    """(loss, compared gradient leaves) of the program and of the reference."""
+def _program(cfg, sz, key, toks):
+    """(weights, loss, compared gradient leaves) of the program. The
+    reference's are the module's `case`: made once, whatever the program is
+    made to get wrong."""
     from chipbench import weights_kanana2 as W
-    from chipbench.reference import kanana2 as ref
 
     params = W.program_params(key, sz, cfg)
-    loss_p, g = jax.jit(jax.value_and_grad(lambda p: tfm.loss_fn(
+    loss, g = jax.jit(jax.value_and_grad(lambda p: tfm.loss_fn(
         p, {"tokens": toks}, cfg, shift_inputs=True)))(params)
-    loss_r, g_r = jax.jit(lambda k, t: ref.loss_and_grads(k, t, sz))(key, toks)
-    return params, (float(loss_p), float(loss_r)), (
-        W.program_leaves(cfg, sz, g), g_r)
+    return params, float(loss), W.program_leaves(cfg, sz, g)
 
 
 @pytest.fixture(scope="module")
@@ -130,7 +131,10 @@ def case():
     sz, key = _sizes(cfg), jax.random.key(21)
     toks = jax.random.randint(jax.random.key(22), (2, 49), 0, cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
-        params, loss, grads = _numbers(cfg, sz, key, toks)
+        params, loss_p, got = _program(cfg, sz, key, toks)
+        loss_r, want = jax.jit(lambda k, t: ref.loss_and_grads(k, t, sz))(
+            key, toks)
+        loss, grads = (loss_p, float(loss_r)), (got, want)
         logits_p = jax.jit(lambda p, t: tfm.forward(p, t, cfg))(
             params, toks[:, :-1])
         logits_r = jax.jit(lambda k, t: ref.forward(k, t, sz))(
@@ -170,8 +174,9 @@ def test_a_wrong_rotation_fails_the_first_limit(case, conf, kind):
     over the cell's limit (the sound program reads 1e-6 here and about a
     percent in bfloat16 on the chip)."""
     with wrong_rotation(kind, case["cfg"].qk_nope_head_dim, shift=16384 - 48):
-        _, _, (got, want) = _numbers(case["cfg"], case["sz"], case["key"],
-                                     case["toks"])
+        _, _, got = _program(case["cfg"], case["sz"], case["key"],
+                             case["toks"])
+    want = case["grads"][1]
     first = conf["stack"]["groups"]["train_grad_rel_err"]
     worst = max(_rel(got[n], want[n]) for n in first)
     assert worst > conf["limits"]["train_grad_rel_err"], worst
@@ -213,126 +218,20 @@ def test_rotated_scores_depend_on_the_distance_alone(case):
     layer = tfm.layer_params(case["params"], cfg, 1)
     h = jax.random.normal(jax.random.key(5), (2, 48, cfg.d_model))
     pos = jnp.broadcast_to(jnp.arange(48, dtype=jnp.int32)[None], (2, 48))
-    out = tfm._mla_mixer(cfg, h, layer, pos)
-    np.testing.assert_allclose(tfm._mla_mixer(cfg, h, layer, pos + 1000), out,
-                               atol=2e-5)
+    mla = lambda cfg, h, pos: tfm.MIXERS["mla"].apply(
+        cfg, ("mla", "moe"), h, layer, pos, None)[0]
+    out = mla(cfg, h, pos)
+    np.testing.assert_allclose(mla(cfg, h, pos + 1000), out, atol=2e-5)
     nope_cfg = dataclasses.replace(cfg, positional="none")
-    assert float(jnp.max(jnp.abs(
-        tfm._mla_mixer(nope_cfg, h, layer, pos) - out))) > 1e-3
+    assert float(jnp.max(jnp.abs(mla(nope_cfg, h, pos) - out))) > 1e-3
     seen = []
     rope = tfm._rope
     with mock.patch.object(tfm, "_rope", lambda x, *a, **k: (
             seen.append(x.shape), rope(x, *a, **k))[1]):
-        text = jax.jit(lambda h: tfm._mla_mixer(cfg, h, layer, pos)).lower(
-            h).as_text(debug_info=True)
+        text = jax.jit(lambda h: mla(cfg, h, pos)).lower(h).as_text(
+            debug_info=True)
     assert sorted(seen) == [(2, 48, 1, 8), (2, 48, 4, 8)]
     assert "mla.rope" in text
-
-
-def test_an_unrotated_latent_layer_traces_what_it_did(case):
-    """`positional="none"` (the hybrid's latent layers): the mixer's trace
-    is the parent's, written out here, operation for operation; nothing of
-    the rotation is in it."""
-    cfg = kimi_linear_tiny(dtype=jnp.float32)
-    layer = tfm.layer_params(tfm.init_params(jax.random.key(0), cfg), cfg, 3)
-    assert "mla_wq" in layer
-    h = jax.random.normal(jax.random.key(5), (2, 32, cfg.d_model))
-    pos = jnp.broadcast_to(jnp.arange(32, dtype=jnp.int32)[None], (2, 32))
-
-    def parent(h):
-        lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-        q = jnp.einsum("bsd,dnh->bsnh", h, tfm._w(layer, "mla_wq", cfg))
-        ckr = h @ tfm._w(layer, "mla_wkva", cfg)
-        c = tfm._norm(ckr[..., :lat], layer["mla_kv_norm"], None, "rmsnorm",
-                      cfg.norm_eps)
-        kv = jnp.einsum("bsl,lnh->bsnh", c, tfm._w(layer, "mla_wkvb", cfg))
-        kr = jnp.broadcast_to(ckr[:, :, None, lat:],
-                              kv.shape[:3] + (cfg.qk_rope_head_dim,))
-        k = jnp.concatenate([kv[..., :nope], kr], axis=-1)
-        q = tfm.maybe_constrain(q, ("batch", "seq_act", "heads", None))
-        o = tfm.attention(q, k, kv[..., nope:], causal=True)
-        return jnp.einsum("bsnh,nhd->bsd", o, tfm._w(layer, "mla_wo", cfg))
-
-    got = str(jax.make_jaxpr(lambda h: tfm._mla_mixer(cfg, h, layer, pos))(h))
-    assert got == str(jax.make_jaxpr(parent)(h))
-    assert " cos " not in got and " sin " not in got
-    with pytest.raises(ValueError, match="YaRN"):
-        kanana2_tiny(yarn_factor=4.0)
-
-
-def test_the_flash_path_is_the_xla_path(case, monkeypatch):
-    """The model through the flash kernels (interpret mode here) at keys 24
-    wide and values 16, under both remat policies: the loss and the last
-    layer's query gradient are the XLA path's."""
-    from chipbench import weights_kanana2 as W
-
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
-    batch = {"tokens": case["toks"]}
-    for policy in ("dots", "full"):
-        cfg = dataclasses.replace(case["cfg"], remat=True,
-                                  remat_policy=policy)
-        loss, g = jax.value_and_grad(lambda p: tfm.loss_fn(
-            p, batch, cfg, shift_inputs=True))(case["params"])
-        assert abs(float(loss) - case["loss"][0]) < 1e-5, policy
-        got = W.program_leaves(cfg, case["sz"], g)
-        for leaf in ("mla_wq", "mla_wkva"):
-            assert _rel(got[leaf], case["grads"][1][leaf]) < 2e-5, leaf
-
-
-def test_the_shares_add_up():
-    """One expert layer of the tiny preset: the routed parts the eight held
-    ranges give (the program's `moe_ffn_held` under `sigmoid_route`, each
-    rank's weights made from the seed by the benchmark's maker) plus the
-    shared experts, counted once, sum to the uncut reference's layer, a
-    loop over all sixteen experts; no assignment is dropped or counted
-    twice."""
-    from chipbench import weights_kanana2 as W
-    from chipbench.reference import kanana2 as ref
-    from chipbench.weights import layer_key
-    from ray_tpu.ops import moe
-
-    cfg = kanana2_tiny(dtype=jnp.float32)
-    kind = ("mla", "moe")
-    key = layer_key(jax.random.key(31), 1)
-    x = jax.random.normal(jax.random.key(32), (2, 40, cfg.d_model))
-    whole = _sizes(cfg, moe_held=None)
-    w_all = W.layer(key, whole, kind)
-    want = ref._experts(x, w_all, whole, ref.mm_f32)
-    shared = ref._swiglu(x, w_all["s_gate"], w_all["s_up"], w_all["s_down"],
-                         ref.mm_f32)
-    total, assigned = shared, 0.0
-    for first in range(0, 16, 2):
-        sz = _sizes(cfg, moe_held=(first, 2))
-        w = W.to_program(W.layer(key, sz, kind), sz, kind)
-        np.testing.assert_array_equal(  # a rank's experts are the model's
-            w["moe_w_down"], w_all["e_down"][first:first + 2])
-        route = functools.partial(
-            moe.sigmoid_route, bias=w["router_bias"],
-            experts_per_token=cfg.moe_experts_per_token,
-            routed_scale=cfg.moe_routed_scale)
-        y, cnt = moe.moe_ffn_held(
-            x, w["router"], w["moe_w_gate_up"], w["moe_w_down"], route=route,
-            held_first=first, dtype=jnp.float32)
-        assert float(cnt["dropped"]) == 0.0
-        total, assigned = total + y, assigned + float(cnt["assigned"])
-        part = ref._experts(x, W.layer(key, sz, kind), sz, ref.mm_f32)
-        np.testing.assert_allclose(y + shared, part, atol=2e-5)
-    np.testing.assert_allclose(total, want, atol=3e-5)
-    assert assigned == 2 * 40 * cfg.moe_experts_per_token
-
-
-def test_stack_plans():
-    """The cut is two segments (the dense lead layer, then the expert
-    layers), the whole 48-layer stack the same two; the hybrid's plan is
-    what it was."""
-    d, m = ("mla", "dense"), ("mla", "moe")
-    assert kanana2_tiny().stack_plan() == (((d,), 1), ((m,), 3))
-    assert kanana2_tiny(n_layers=5).stack_plan() == (((d,), 1), ((m,), 4))
-    assert kanana2_tiny(n_layers=48).stack_plan() == (((d,), 1), ((m,), 47))
-    assert kanana2_tiny().layer_slot(2) == (1, 0, 1)
-    assert [(len(p), r) for p, r in kimi_linear_tiny(
-        n_layers=27).stack_plan()] == [(1, 1), (4, 6), (1, 1), (1, 1)]
-    assert isinstance(tfm.param_logical_specs(kanana2_tiny())["layers"], list)
 
 
 def test_counts_and_the_configuration_file(conf):
@@ -343,9 +242,9 @@ def test_counts_and_the_configuration_file(conf):
     tc = dict(conf["transformer_config"])
     tc["dtype"], tc["param_dtype"] = jnp.bfloat16, jnp.float32
     cfg = tfm.TransformerConfig(**tc)
-    assert cfg._mixer_params("mla") == 26_345_984
-    assert cfg._ffn_params("dense") == 37_748_736
-    assert cfg._ffn_params("moe") == 85_196_928
+    assert tfm._size(tfm.MIXERS["mla"].shapes(cfg)) == 26_345_984
+    assert tfm._size(tfm._ffn_shapes(cfg, "dense")) == 37_748_736
+    assert tfm._size(tfm._ffn_shapes(cfg, "moe")) == 85_196_928
     assert cfg.num_params() == 575_955_968
     shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
                             jax.random.key(0))
@@ -425,45 +324,3 @@ def test_hand_count_of_one_layers_operations(conf):
     # A worked row: gate, up, down = 3 x 2048 x 768 multiply-adds, x 6.
     e = c.experts(12288, 16, 2048, 768)
     assert e["flops"] == 12288 * 6 * 3 * 2048 * 768 == 347_892_350_976
-
-
-def test_decoding_refuses_a_latent_layer(case):
-    from ray_tpu.models.generate import prefill
-
-    with pytest.raises(NotImplementedError):
-        prefill(case["params"], case["toks"][:, :8], case["cfg"], 16)
-
-
-def test_train_step_returns_the_counters_and_folds_them():
-    """transformer_train_step(with_counters=True) on the tiny preset under
-    remat `full` (the cell's policy): the loss falls, nothing is dropped,
-    three expert layers' assignments are counted and folded into the phase
-    table."""
-    from ray_tpu.ops import moe
-    from ray_tpu.parallel import MeshSpec, make_mesh
-    from ray_tpu.train.step import transformer_train_step
-    from ray_tpu.util import tracing
-
-    cfg = kanana2_tiny(remat=True, remat_policy="full")
-    mesh = make_mesh(MeshSpec(), devices=jax.devices()[:1])
-    ts = transformer_train_step(cfg, mesh, shift_inputs=True,
-                                with_counters=True)
-    params, opt = ts.init(jax.random.key(0))
-    toks = np.random.RandomState(0).randint(
-        0, cfg.vocab_size, (4, 65)).astype(np.int32)
-    before = tracing.phase_table().get("train.moe_assigned", {"count": 0})
-    losses = []
-    for _ in range(3):
-        params, opt, loss, aux = ts.step(params, opt,
-                                         ts.shard_batch({"tokens": toks}))
-        losses.append(float(loss))
-        seen = ts.observe_counters(aux)
-    assert losses[-1] < losses[0] and np.isfinite(losses).all()
-    assert seen["moe_dropped"] == 0.0
-    # Three expert layers x 256 tokens x 3 a token, a quarter of the
-    # experts held.
-    assert 0.15 * 2304 < seen["moe_assigned"] < 0.35 * 2304
-    assert seen["moe_window_rows"] == moe.held_window_rows(256, 3, 16, 4)
-    assert (seen["moe_trips"] > 3.0) == (seen["moe_past_buffer"] > 0)
-    table = tracing.phase_table()
-    assert table["train.moe_assigned"]["count"] == before["count"] + 3

@@ -1,8 +1,9 @@
 """Kimi Delta Attention (ops/kda.py) on the CPU at small sizes: the chunked
 algorithm against the recurrence, the Pallas kernels (interpret mode) against
-both, the dispatch rule, and what a remat policy keeps of the kernels in a
-traced KDA stack. tests/test_kda_kernel_compile.py compiles the kernels for
-the chip."""
+both, and the dispatch rule. The rule at a decay a head is
+tests/test_kda_scalar.py, what a remat policy keeps of the kernels in a
+traced stack tests/test_kda_remat.py; tests/test_kda_kernel_compile.py
+compiles the kernels for the chip."""
 import hashlib
 import re
 
@@ -11,8 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import transformer as tfm
-from ray_tpu.models.configs import kimi_linear_tiny
 from ray_tpu.ops import kda
 
 pytestmark = pytest.mark.usefixtures("exact_matmuls")
@@ -194,47 +193,3 @@ def test_kda_dispatch_rule():
         kda.kda_chunked(*args)[0], kda.kda_chunked_xla(*args)[0])
     assert (count("kda.core.xla"), count("kda.core.pallas")) == (
         before[0] + 1, before[1])
-
-
-def _kernel_calls(jaxpr, times=1, out=None):
-    """pallas_calls of a jaxpr by operand signature, a call inside a scan
-    counted once per iteration (tests/test_models.py does it for flash)."""
-    out = {} if out is None else out
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            sig = f"{len(eqn.invars)}in_{len(eqn.outvars)}out"
-            out[sig] = out.get(sig, 0) + times
-        inner = times * (eqn.params["length"]
-                         if eqn.primitive.name == "scan" else 1)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _kernel_calls(sub, inner, out)
-    return out
-
-
-@pytest.mark.parametrize("policy,fwd_calls_per_layer",
-                         [("dots", 1), ("full", 2)])
-def test_remat_dots_keeps_the_kda_kernel_residuals(monkeypatch, policy,
-                                                   fwd_calls_per_layer):
-    """The traced gradient of a KDA stack through the kernels: under "dots"
-    the forward kernel (6 in / 4 out) runs once a layer, its o, states and
-    inverses being named residuals; under "full" twice. The backward kernel
-    (9 in / 6 out) once. Neither has a flash kernel's signature
-    (chipbench/reduce/xplane.py names kernels by it). Gradients are those
-    of the XLA body."""
-    cfg = kimi_linear_tiny(n_layers=3, moe_held=(0, 16), remat=True,
-                           remat_policy=policy, dtype=jnp.float32)
-    params = tfm.init_params(jax.random.key(0), cfg)
-    toks = jax.random.randint(jax.random.key(1), (2, 33), 0, cfg.vocab_size)
-    # A new function each time: jax caches a trace by the function's identity.
-    grad = lambda: jax.grad(lambda p: tfm.loss_fn(
-        p, {"tokens": toks}, cfg, shift_inputs=True))
-    assert _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr) == {}
-    g_xla = jax.jit(grad())(params)
-    monkeypatch.setattr(kda, "use_kernels", lambda *a, **kw: True)
-    calls = _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr)
-    assert calls == {"6in_4out": 3 * fwd_calls_per_layer, "9in_6out": 3}
-    if policy == "dots":
-        for a, b in zip(jax.tree.leaves(jax.jit(grad())(params)),
-                        jax.tree.leaves(g_xla)):
-            np.testing.assert_allclose(a, b, atol=1e-5 + 1e-4 * float(
-                jnp.abs(b).max()))

@@ -3,11 +3,13 @@
 `moe_router="softmax"` with a held range) on the CPU at the tiny preset: the
 program against the plain reference (chipbench/reference/mellum2.py: nothing
 from ray_tpu, full softmax rows with the band as a mask, a loop over the held
-experts) on seeded weights, the rotation's frequencies, the shares of the
-expert layer, the plan, the counts, the configuration file, and what decoding
-refuses. The windowed kernels themselves are tests/test_flash_attention.py."""
+experts) on seeded weights, a window off by one, the rotation's frequencies,
+the counts and the configuration file. What it shares with the other families
+is tests/test_model_table.py (plan, lowering, decoding),
+test_preset_programs.py (the train step, the flash path) and
+test_expert_shares.py; the windowed kernels themselves are
+tests/test_flash_attention.py."""
 import dataclasses
-import functools
 import json
 import math
 import os
@@ -19,9 +21,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import transformer as tfm
-from ray_tpu.models.configs import (gpt2_125m, granite_hybrid_tiny,
-                                    kimi_linear_tiny, llama_tiny,
-                                    mellum2_tiny)
+from ray_tpu.models.configs import mellum2_tiny
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -39,17 +39,16 @@ def _sizes(cfg, **changes):
     return W.MellumSizes(dict(tc, **changes), cfg.norm_eps)
 
 
-def _numbers(cfg, sz, key, toks):
-    """(loss, compared gradient leaves) of the program and of the reference."""
+def _program(cfg, sz, key, toks):
+    """(weights, loss, compared gradient leaves) of the program. The
+    reference's are the module's `case`: made once, whatever the program is
+    made to get wrong."""
     from chipbench import weights_mellum2 as W
-    from chipbench.reference import mellum2 as ref
 
     params = W.program_params(key, sz, cfg)
-    loss_p, g = jax.jit(jax.value_and_grad(lambda p: tfm.loss_fn(
+    loss, g = jax.jit(jax.value_and_grad(lambda p: tfm.loss_fn(
         p, {"tokens": toks}, cfg, shift_inputs=True)))(params)
-    loss_r, g_r = jax.jit(lambda k, t: ref.loss_and_grads(k, t, sz))(key, toks)
-    return params, (float(loss_p), float(loss_r)), (
-        W.program_leaves(cfg, sz, g), g_r)
+    return params, float(loss), W.program_leaves(cfg, sz, g)
 
 
 @pytest.fixture(scope="module")
@@ -62,7 +61,10 @@ def case():
     sz, key = _sizes(cfg), jax.random.key(21)
     toks = jax.random.randint(jax.random.key(22), (2, 49), 0, cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
-        params, loss, grads = _numbers(cfg, sz, key, toks)
+        params, loss_p, got = _program(cfg, sz, key, toks)
+        loss_r, want = jax.jit(lambda k, t: ref.loss_and_grads(k, t, sz))(
+            key, toks)
+        loss, grads = (loss_p, float(loss_r)), (got, want)
         logits_p = jax.jit(lambda p, t: tfm.forward(p, t, cfg))(
             params, toks[:, :-1])
         logits_r = jax.jit(lambda k, t: ref.forward(k, t, sz))(
@@ -102,31 +104,13 @@ def test_a_window_off_by_one_fails_the_first_limit(case, window):
     with open(CONFIG) as f:
         conf = json.load(f)
     cfg = dataclasses.replace(case["cfg"], sliding_window=window)
-    _, _, (got, want) = _numbers(cfg, case["sz"], case["key"], case["toks"])
+    _, _, got = _program(cfg, case["sz"], case["key"], case["toks"])
+    want = case["grads"][1]
     first = conf["stack"]["groups"]["train_grad_rel_err"]
     assert set(first) | set(ROUTED) == set(want)
     worst = max(_rel(got[n], want[n]) for n in first)
     assert worst > conf["limits"]["train_grad_rel_err"], worst
     assert _rel(got["swa_wkv"], want["swa_wkv"]) > 0.05
-
-
-def test_the_flash_path_is_the_xla_path(case, monkeypatch):
-    """The model through the flash kernels (interpret mode here), windowed
-    and full layers alike, under both remat policies: the loss and a
-    windowed layer's gradient are the XLA path's."""
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
-    batch = {"tokens": case["toks"]}
-    for policy in ("dots", "full"):
-        cfg = dataclasses.replace(case["cfg"], remat=True,
-                                  remat_policy=policy)
-        loss, g = jax.value_and_grad(lambda p: tfm.loss_fn(
-            p, batch, cfg, shift_inputs=True))(case["params"])
-        assert abs(float(loss) - case["loss"][0]) < 1e-5, policy
-        from chipbench import weights_mellum2 as W
-
-        got = W.program_leaves(cfg, case["sz"], g)
-        for leaf in ("swa_wkv", "full_wq"):
-            assert _rel(got[leaf], case["grads"][1][leaf]) < 2e-5, leaf
 
 
 def test_yarn_frequencies_against_a_hand_table():
@@ -193,68 +177,6 @@ def test_only_the_full_layers_take_yarn(case):
         atol=1e-6)
 
 
-def test_the_shares_add_up():
-    """One expert layer of the tiny preset: the parts the four held ranges
-    give (the program's `moe_ffn_held` under `softmax_route`, each rank's
-    weights made from the seed by the benchmark's maker) sum to the uncut
-    reference's layer, a loop over all eight experts; no assignment is
-    dropped or counted twice."""
-    from chipbench import weights_mellum2 as W
-    from chipbench.reference import mellum2 as ref
-    from chipbench.weights import layer_key
-    from ray_tpu.ops import moe
-
-    cfg = mellum2_tiny(dtype=jnp.float32)
-    key = layer_key(jax.random.key(31), 0)
-    x = jax.random.normal(jax.random.key(32), (2, 40, cfg.d_model))
-    whole = _sizes(cfg, moe_held=None)
-    want = ref._experts(x, W.layer(key, whole), whole, ref.mm_f32)
-    route = functools.partial(moe.softmax_route,
-                              experts_per_token=cfg.moe_experts_per_token)
-    total, assigned = 0.0, 0.0
-    for first in range(0, 8, 2):
-        sz = _sizes(cfg, moe_held=(first, 2))
-        w = W.to_program(W.layer(key, sz), sz)
-        np.testing.assert_array_equal(  # a rank's experts are the model's
-            w["moe_w_down"], W.layer(key, whole)["e_down"][first:first + 2])
-        y, cnt = moe.moe_ffn_held(
-            x, w["router"], w["moe_w_gate_up"], w["moe_w_down"], route=route,
-            held_first=first, dtype=jnp.float32)
-        assert float(cnt["dropped"]) == 0.0
-        total, assigned = total + y, assigned + float(cnt["assigned"])
-        part = ref._experts(x, W.layer(key, sz), sz, ref.mm_f32)
-        np.testing.assert_allclose(y, part, atol=2e-5)  # the rank's share
-    np.testing.assert_allclose(total, want, atol=2e-5)
-    assert assigned == 2 * 40 * cfg.moe_experts_per_token
-
-
-def test_stack_plans():
-    """The published 28-layer list is ONE segment of four kinds, seven
-    repeats; the four-layer cut is two segments; the accepted
-    configurations' plans are what they were."""
-    s, a = ("swa", "moe"), ("attn", "moe")
-    assert mellum2_tiny(n_layers=28).stack_plan() == (((s, s, s, a), 7),)
-    assert mellum2_tiny().stack_plan() == (((s,), 3), ((a,), 1))
-    assert mellum2_tiny().layer_slot(3) == (1, 0, 0)
-    m, d = ("mamba2", "dense"), ("attn", "dense")
-    assert granite_hybrid_tiny().stack_plan() == (((m,), 5), ((d,), 1),
-                                                  ((m,), 4))
-    assert [(len(p), r) for p, r in kimi_linear_tiny(
-        n_layers=27).stack_plan()] == [(1, 1), (4, 6), (1, 1), (1, 1)]
-    for cfg in (llama_tiny(), gpt2_125m(), llama_tiny(moe_num_experts=4)):
-        assert cfg.stack_plan() == (((cfg.layer_kinds()[0],), cfg.n_layers),)
-        assert isinstance(tfm.param_logical_specs(cfg)["layers"], dict)
-    assert isinstance(tfm.param_logical_specs(mellum2_tiny())["layers"], list)
-    with pytest.raises(ValueError):
-        mellum2_tiny(mla_layers=(1,))        # listed twice
-    with pytest.raises(ValueError):
-        mellum2_tiny(sliding_window=None)    # windowed layers, no window
-    with pytest.raises(ValueError):
-        mellum2_tiny(moe_router="softmax_capacity")  # GShard takes no swa
-    with pytest.raises(ValueError):
-        mellum2_tiny(moe_routed_scale=2.0)   # the softmax routing has none
-
-
 def test_counts_and_the_configuration_file():
     """num_params of the cut is 595,153,152 (ISSUE 33's table) and of the
     whole model 12.15 G; the file keeps every published width, the window
@@ -266,8 +188,9 @@ def test_counts_and_the_configuration_file():
     tc["dtype"], tc["param_dtype"] = jnp.bfloat16, jnp.float32
     cfg = tfm.TransformerConfig(**tc)
     assert cfg.head_dim == 128 != cfg.d_model // cfg.n_heads
-    assert cfg._mixer_params("attn") == cfg._mixer_params("swa") == 21_233_664
-    assert cfg._ffn_params("moe") == 99_090_432 + 147_456
+    assert tfm._size(tfm.MIXERS["attn"].shapes(cfg)) == tfm._size(
+        tfm.MIXERS["swa"].shapes(cfg)) == 21_233_664
+    assert tfm._size(tfm._ffn_shapes(cfg, "moe")) == 99_090_432 + 147_456
     assert cfg.num_params() == 595_153_152
     shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
                             jax.random.key(0))
@@ -349,47 +272,3 @@ def test_hand_count_of_the_kernels_operations():
     # A worked row: gate, up, down = 3 x 2304 x 896 multiply-adds, x 6.
     e = c.experts(32768, 16, 2304, 896)
     assert e["flops"] == 32768 * 6 * 3 * 2304 * 896 == 1_217_623_228_416
-
-
-def test_decoding_refuses_the_new_kinds(case):
-    from ray_tpu.models.generate import prefill
-
-    toks = case["toks"][:, :8]
-    with pytest.raises(NotImplementedError, match="windowed"):
-        prefill(case["params"], toks, case["cfg"], 16)
-    held = llama_tiny(moe_num_experts=4, moe_router="softmax",
-                      moe_held=(0, 2), tie_embeddings=False)
-    with pytest.raises(NotImplementedError, match="moe_held"):
-        prefill(tfm.init_params(jax.random.key(0), held), toks, held, 16)
-
-
-def test_train_step_returns_the_softmax_routing_counters():
-    """transformer_train_step(with_counters=True) on the tiny preset: the
-    counters of `moe_ffn_held` serve the softmax routing as the sigmoid
-    one, nothing is dropped, a layer's window is just over the held half's
-    even share (a trip a layer unless the routing is skewed: only then does
-    anything fall past the first window), and the loss falls."""
-    from ray_tpu.ops import moe
-    from ray_tpu.parallel import MeshSpec, make_mesh
-    from ray_tpu.train.step import transformer_train_step
-
-    cfg = mellum2_tiny(remat=True)
-    mesh = make_mesh(MeshSpec(), devices=jax.devices()[:1])
-    ts = transformer_train_step(cfg, mesh, shift_inputs=True,
-                                with_counters=True)
-    params, opt = ts.init(jax.random.key(0))
-    toks = np.random.RandomState(0).randint(
-        0, cfg.vocab_size, (4, 65)).astype(np.int32)
-    losses = []
-    for _ in range(3):
-        params, opt, loss, aux = ts.step(params, opt,
-                                         ts.shard_batch({"tokens": toks}))
-        losses.append(float(loss))
-        seen = ts.observe_counters(aux)
-    assert losses[-1] < losses[0] and np.isfinite(losses).all()
-    assert seen["moe_dropped"] == 0.0
-    # Four expert layers x 256 tokens x 2 a token, half the experts held.
-    assert 0.3 * 2048 < seen["moe_assigned"] < 0.7 * 2048
-    # 512 assignments a layer: 2.5 of the held half's even share is them all.
-    assert seen["moe_window_rows"] == moe.held_window_rows(256, 2, 8, 4) == 512
-    assert seen["moe_trips"] == 4 and seen["moe_past_buffer"] == 0

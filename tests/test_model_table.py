@@ -1,0 +1,379 @@
+"""The table of mixer kinds (models/transformer.py `MIXERS`) and what every
+preset's program is, read from it: each preset's gradient program against the
+text it lowered to at the parent commit (THE guard of a change that should
+change nothing), its counts against the parent's numbers, its plan, what
+decoding refuses of it, its device scopes, and docs/model_layers.md against
+the table. What only one family has (its reference, its wrong mechanisms,
+its configuration file) is in that family's file."""
+import collections
+import dataclasses
+import functools
+import glob
+import hashlib
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import configs, transformer as tfm
+from ray_tpu.models.generate import _kv_stack, prefill
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+pytestmark = pytest.mark.usefixtures("exact_matmuls")
+
+PRESETS = ("llama_tiny", "gpt2_tiny", "moe_tiny", "kimi_linear_tiny",
+           "granite_hybrid_tiny", "mellum2_tiny", "kanana2_tiny",
+           "qwen3_next_tiny")
+REMAT = ("off", "dots", "full")
+# "<sha256[:16] of the StableHLO>:<sha256[:16] of its operations' name
+# stacks>" of each preset's gradient program, remat off and under either
+# policy, as it lowered at the parent commit of PR 44 (154c181, before the
+# table; this container's JAX, the CPU, tokens [2, 33], `shift_inputs`,
+# float32 matmuls as float32): `_digests` below. The first is the text of
+# `jax.jit(value_and_grad(loss_fn)).lower(...).as_text()`; the second is
+# over `_scoped_ops` of the text with its locations, which the first drops:
+# the device scopes (`kda`, `mla`, `mamba`, `gdn`, `gattn`, `swa`, `*.core`,
+# `moe.*`) the per-kind metrics read, each with what runs under it. A change that means
+# to alter a program takes the new digests with `python
+# tests/test_model_table.py` and says why.
+PARENT = {
+    "llama_tiny": ("477b60d37afe307a:1204d8d39a7b453e",
+                   "fb0a0ec778730463:1204d8d39a7b453e",
+                   "24c710c0bd2bd4d3:1204d8d39a7b453e"),
+    "gpt2_tiny": ("4f2ae9e07027bc87:076347b6a6ef6975",
+                  "4a9578c67d387a25:076347b6a6ef6975",
+                  "3ba16a7e98c43668:076347b6a6ef6975"),
+    "moe_tiny": ("e7db6dd180684ee6:846b7814e5778ffa",
+                 "b1a0b14f2dbc5ccf:846b7814e5778ffa",
+                 "1a06e30aefde286e:846b7814e5778ffa"),
+    "kimi_linear_tiny": ("1351b6f8a51ed658:0dec5428f5393512",
+                         "595571e2024d4fe8:690c4963985676f7",
+                         "76ace0e896808a73:690c4963985676f7"),
+    "granite_hybrid_tiny": ("ff3c8acbca76f994:1aa0ff837d278860",
+                            "51b8226e0760232d:9ce01d30262a7b2d",
+                            "30f4d092b8abcfbd:9ce01d30262a7b2d"),
+    "mellum2_tiny": ("7c8f75ed566a2912:5277c5b9e65d3b63",
+                     "80fc62d0b1aaf755:5277c5b9e65d3b63",
+                     "8953f181cae1d2a6:5277c5b9e65d3b63"),
+    "kanana2_tiny": ("ad659bdff892a231:593d1eba54411321",
+                     "4ee529c508a5e1e7:593d1eba54411321",
+                     "a6c45060d21cec60:593d1eba54411321"),
+    "qwen3_next_tiny": ("6a18e99b94bd3ae4:4d7d9eca8c599952",
+                        "befaff51114e9531:79df8da7a94dd775",
+                        "52f1a824a7912a3e:79df8da7a94dd775"),
+    # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text
+    "llama_tiny-flash": ("b8a5e1398a317f9f:c7a534cb53ae9dba",
+                         "dc0235890402e707:c7a534cb53ae9dba",
+                         "c058535c5302c1aa:c7a534cb53ae9dba"),
+}
+# (num_params, num_active_params, flops_per_token(), flops_per_token(512)) of
+# every preset of models/configs.py and of every chipbench/configs/*.json's
+# `transformer_config`, as the parent's hand formulas (`_mixer_params`,
+# `_ffn_params`) gave them: the leaves' sizes summed give the same.
+PARENT_COUNTS = {
+    "gpt2_125m": (124356864, 124356864, 798045696.0, 769734144.0),
+    "llama3_8b": (8030261248, 8030261248, 51471998976.0, 45432201216.0),
+    "llama_tiny": (459392, 459392, 2952960.0, 3542784.0),
+    "gpt2_tiny": (476416, 476416, 2956800.0, 3546624.0),
+    "bench_350m": (341099520, 341099520, 2197592064.0, 2122094592.0),
+    "moe_tiny": (1377920, 788096, 4925184.0, 5515008.0),
+    "kimi_linear_tiny": (583280, 288304, 1801504.0, 2016544.0),
+    "kanana2_tiny": (239344, 179392, 1100928.0, 1961088.0),
+    "granite_hybrid_tiny": (548440, 548440, 3826704.0, 3998736.0),
+    "mellum2_tiny": (215616, 141888, 837024.0, 1182852.0),
+    "qwen3_next_tiny": (344008, 171976, 1077936.0, 1422000.0),
+    "gpt2_124m.json": (124356864, 124356864, 798045696.0, 769734144.0),
+    "granite_4_0_h_micro.json": (772160448, 772160448, 4769113728.0,
+                                 4725073536.0),
+    "internlm2_1_8b.json": (1889110016, 1889110016, 19861155840.0,
+                            10348474368.0),
+    "kanana_2_30b_a3b.json": (575955968, 288121344, 4048309248.0,
+                              1610370048.0),
+    "kimi_linear_48b_a3b.json": (602434432, 383018880, 2337959168.0,
+                                 2102029568.0),
+    "mellum2_12b_a2_5b.json": (595153152, 248336640, 1699215360.0,
+                               1200686592.0),
+    "qwen3_next_80b_a3b.json": (625667136, 230878272, 1603307904.0,
+                                1213237632.0),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered(preset, mode="off", impl=None):
+    """(sha256[:16] of the text, `_scoped_ops` of the text with locations) of
+    a preset's gradient program, remat off or under a policy; lowered once
+    a process (`impl`: what RTPU_ATTN_IMPL holds, the caller's to set)."""
+    cfg = getattr(configs, preset)(
+        remat=mode != "off", remat_policy="dots" if mode == "off" else mode)
+    p = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    toks = jax.ShapeDtypeStruct((2, 33), jnp.int32)
+    low = jax.jit(lambda p, t: jax.value_and_grad(
+        lambda p: tfm.loss_fn(p, {"tokens": t}, cfg, shift_inputs=True))(
+            p)).lower(p, toks)
+    return (hashlib.sha256(low.as_text().encode()).hexdigest()[:16],
+            _scoped_ops(low.as_text(debug_info=True)))
+
+
+def _scoped_ops(text):
+    """{(device scopes, operation)} over the locations of a text lowered with
+    `debug_info`: of every `jit(<lambda>)/jvp()/while/body/gdn/gdn.core/dot`
+    the scopes a mixer opened (`gdn`, `gdn.core`) and what ran under them,
+    the wrappers of transforms and loops dropped; a call of a jitted
+    function counts as one operation (its body, traced once and shared, is
+    located by whichever call came first in the process)."""
+    wrapper = lambda p: "(" in p or p in ("while", "body", "cond", "scan",
+                                          "closed_call", "checkpoint")
+    out = set()
+    for name in re.findall(r'loc\("(jit\(<lambda>\)/[^"]+)"', text):
+        parts = name.split("/")[1:]
+        calls = [i for i, p in enumerate(parts) if p.startswith("jit(")]
+        parts = parts[:calls[0] + 1] if calls else parts
+        out.add((tuple(p for p in parts[:-1] if not wrapper(p)), parts[-1]))
+    return out
+
+
+def _digests(preset, mode, impl=None):
+    text, ops = _lowered(preset, mode, impl)
+    return text + ":" + hashlib.sha256(
+        repr(sorted(ops)).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("mode", REMAT)
+@pytest.mark.parametrize("preset", sorted(PARENT))
+def test_lowers_to_the_parents_program(preset, mode, monkeypatch):
+    """Byte for byte, and every operation under the scope it was under."""
+    name, _, impl = preset.partition("-")
+    if impl:
+        monkeypatch.setenv("RTPU_ATTN_IMPL", impl)
+    assert _digests(name, mode, impl or None) == PARENT[preset][
+        REMAT.index(mode)]
+
+
+def _file_config(path):
+    with open(path) as f:
+        tc = dict(json.load(f)["transformer_config"])
+    tc["dtype"], tc["param_dtype"] = jnp.bfloat16, jnp.float32
+    return tfm.TransformerConfig(**tc)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_COUNTS))
+def test_counts_are_the_parents(name):
+    """The counts from the leaves' shapes are the hand formulas' numbers,
+    and what `init_params` makes has as many."""
+    cfg = (_file_config(os.path.join(ROOT, "chipbench", "configs", name))
+           if name.endswith(".json") else getattr(configs, name)())
+    assert (cfg.num_params(), cfg.num_active_params(), cfg.flops_per_token(),
+            cfg.flops_per_token(512)) == PARENT_COUNTS[name]
+    made = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    assert sum(a.size for a in jax.tree.leaves(made)) == cfg.num_params()
+
+
+def test_every_configuration_file_and_preset_is_counted():
+    files = {os.path.basename(p) for p in glob.glob(
+        os.path.join(ROOT, "chipbench", "configs", "*.json"))}
+    presets = {n for n, f in vars(configs).items()
+               if callable(f) and getattr(f, "__module__", "") ==
+               configs.__name__ and not n.startswith("_")}
+    assert files | presets == set(PARENT_COUNTS)
+    assert set(PRESETS) <= presets
+
+
+def test_the_table_is_what_the_configuration_lists():
+    """One row a kind: the rows' fields are the configuration's `*_layers`
+    fields, one row has none (the kind of a layer no list names), every
+    row's leaves are its own, and a row that cannot be decoded says why."""
+    rows = tfm.MIXERS
+    assert list(rows) == [r.name for r in rows.values()] == [
+        "attn", "swa", "mla", "kda", "mamba2", "gdn"]
+    fields = {f.name for f in dataclasses.fields(tfm.TransformerConfig)}
+    listed = [r.layers_field for r in rows.values() if r.layers_field]
+    assert sorted(listed) == sorted(f for f in fields if f.endswith("_layers")
+                                    and f != "n_layers")
+    assert [r.name for r in rows.values() if not r.layers_field] == ["attn"]
+    cfg = configs.llama_tiny(sliding_window=8)
+    leaves = collections.Counter(
+        n for r in rows.values() if r.name != "swa" for n in r.shapes(cfg))
+    assert max(leaves.values()) == 1  # swa's are attn's: the same function
+    assert rows["swa"].shapes is rows["attn"].shapes
+    assert [r.name for r in rows.values() if r.cut_rows] == ["attn", "swa"]
+    assert [r.name for r in rows.values() if not r.no_decode] == ["attn"]
+    with pytest.raises(ValueError, match="two of swa_layers, mla_layers, kda"):
+        configs.llama_tiny(kda_layers=(1,), mla_layers=(1,))
+    with pytest.raises(ValueError, match="swa_layers / mla_layers / kda"):
+        configs.moe_tiny(swa_layers=(1,), sliding_window=8)
+
+
+# What each preset's plan is: `plan` of the preset as it is, `deep` (layers,
+# plan) of the published depth, `slot` (layer, its `layer_slot`), whether
+# params["layers"] is the list of segments (else one dict of leaves [L, ..]),
+# and overrides the configuration refuses.
+_a, _s, _m = ("attn", "dense"), ("swa", "moe"), ("mamba2", "dense")
+_g, _ga = ("gdn", "moe"), ("attn", "moe")
+_ld, _lm = ("mla", "dense"), ("mla", "moe")
+_kd, _km = ("kda", "dense"), ("kda", "moe")
+PLANS = {
+    "llama_tiny": dict(plan=(((_a,), 2),), deep=(24, (((_a,), 24),)),
+                       slot=(1, (0, 0, 1)), segments=False,
+                       refused=[dict(remat_policy="none")]),
+    "gpt2_tiny": dict(plan=(((_a,), 2),), deep=(12, (((_a,), 12),)),
+                      slot=(0, (0, 0, 0)), segments=False,
+                      refused=[dict(norm_offset=1.0)]),
+    "moe_tiny": dict(plan=(((_ga,), 2),), deep=(4, (((_ga,), 4),)),
+                     slot=(1, (0, 0, 1)), segments=False,
+                     refused=[dict(moe_router="top1")]),
+    "kimi_linear_tiny": dict(
+        plan=(((_kd,), 1), ((_km,), 2), ((_lm,), 1), ((_km,), 1)),
+        deep=(27, (((_kd,), 1), ((_km, _km, _lm, _km), 6), ((_km,), 1),
+                   ((_lm,), 1))),
+        slot=(3, (2, 0, 0)), segments=True,
+        refused=[dict(moe_router="softmax_capacity")]),
+    "granite_hybrid_tiny": dict(
+        plan=(((_m,), 5), ((_a,), 1), ((_m,), 4)),
+        deep=(40, (((_m,) * 5 + (_a,) + (_m,) * 4, 4),)),
+        slot=(5, (1, 0, 0)), segments=True,
+        refused=[dict(kda_layers=(1,))]),
+    "mellum2_tiny": dict(
+        plan=(((_s,), 3), ((_ga,), 1)), deep=(28, (((_s, _s, _s, _ga), 7),)),
+        slot=(3, (1, 0, 0)), segments=True,
+        refused=[dict(mla_layers=(1,)), dict(sliding_window=None),
+                 dict(moe_router="softmax_capacity"),
+                 dict(moe_routed_scale=2.0)]),
+    "kanana2_tiny": dict(
+        plan=(((_ld,), 1), ((_lm,), 3)), deep=(48, (((_ld,), 1), ((_lm,), 47))),
+        slot=(2, (1, 0, 1)), segments=True,
+        refused=[dict(yarn_factor=4.0)]),
+    "qwen3_next_tiny": dict(
+        plan=(((_g,), 3), ((_ga,), 1)), deep=(48, (((_g, _g, _g, _ga), 12),)),
+        slot=(3, (1, 0, 0)), segments=True,
+        refused=[dict(kda_layers=(1,)), dict(gdn_k_heads=3)]),
+}
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+def test_stack_plan(preset):
+    """The cut's segments, the published depth's (a period scanned over its
+    repeats: compile time follows the segments, not the depth), where a
+    layer lives, how the layers are stored, and what is refused where the
+    configuration is made."""
+    want, make = PLANS[preset], getattr(configs, preset)
+    cfg = make()
+    assert cfg.stack_plan() == want["plan"]
+    n, deep = want["deep"]
+    assert make(n_layers=n).stack_plan() == deep
+    assert sum(len(p) * r for p, r in deep) == n
+    assert make(n_layers=n).layer_kinds()[:cfg.n_layers] == cfg.layer_kinds()
+    l, slot = want["slot"]
+    assert cfg.layer_slot(l) == slot
+    specs = tfm.param_logical_specs(cfg)
+    assert isinstance(specs["layers"], list if want["segments"] else dict)
+    segments = tfm.stack_segments(specs, cfg)
+    assert [len(seg) for seg in segments] == [len(p) for p, _ in want["plan"]]
+    kind = cfg.layer_kinds()[l]
+    assert list(segments[slot[0]][slot[1]]) == list(
+        tfm._layer_shapes(cfg, kind))
+    for override in want["refused"]:
+        with pytest.raises(ValueError):
+            make(**override)
+
+
+def test_a_one_layer_stack_of_another_kind_is_a_list_of_segments():
+    one = configs.kimi_linear_tiny(n_layers=1)  # one KDA layer, dense
+    assert one.stack_plan() == (((("kda", "dense"),), 1),)
+    specs = tfm.param_logical_specs(one)
+    assert tfm.stack_segments(specs, one) is specs["layers"]
+    p = tfm.init_params(jax.random.key(0), configs.llama_tiny())
+    assert p["layers"]["wo"].shape == (2, 128, 128)
+    assert tfm.layer_params(p, configs.llama_tiny(), 1)["wo"].shape == (
+        128, 128)
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+def test_decoding_serves_a_row_or_says_its_sentence(preset):
+    """`generate._kv_stack` is a loop over the table: a stack whose rows all
+    hold keys and values is served, any other is refused with the sentence
+    of its first layer's row that cannot be."""
+    cfg = getattr(configs, preset)()
+    params = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    toks = jnp.zeros((2, 8), jnp.int32)
+    why = [tfm.MIXERS[m].no_decode for m, _ in cfg.layer_kinds()
+           if tfm.MIXERS[m].no_decode]
+    if not why:
+        kind, layers = _kv_stack(params, cfg)
+        assert kind == cfg.layer_kinds()[0]
+        assert layers["wo"].shape[0] == cfg.n_layers
+        return
+    with pytest.raises(NotImplementedError, match=re.escape(why[0])):
+        prefill(params, toks, cfg, 16)
+    word = {"kimi_linear_tiny": "KDA / MLA", "granite_hybrid_tiny": "Mamba-2",
+            "mellum2_tiny": "windowed", "kanana2_tiny": "MLA",
+            "qwen3_next_tiny": "R7 / R9"}[preset]
+    assert word in why[0]
+
+
+UNAPPLIED = {
+    "attn_out_gate": dict(attn_out_gate=True),
+    "norm_offset": dict(norm_offset=1.0),
+    "logit_scale": dict(logit_scale=8.0),
+    "embed_scale": dict(embed_scale=12.0),
+    "moe_held": dict(moe_num_experts=4, moe_router="softmax",
+                     moe_held=(0, 2), tie_embeddings=False),
+}
+
+
+@pytest.mark.parametrize("field", sorted(UNAPPLIED))
+def test_decoding_refuses_what_it_does_not_apply(field):
+    cfg = configs.llama_tiny(**UNAPPLIED[field])
+    params = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    with pytest.raises(NotImplementedError, match=field):
+        prefill(params, jnp.zeros((2, 8), jnp.int32), cfg, 16)
+
+
+# The device scopes a preset's program must carry, from its rows and from
+# where the mixers open their own (`docs/model_layers.md`).
+INNER = {"kda": ("kda.core",), "gdn": ("gdn.core",), "mamba2": ("ssd.core",),
+         "swa": ("swa",), "mla": (), "attn": ()}
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+def test_a_presets_program_carries_its_rows_scopes(preset):
+    cfg = getattr(configs, preset)()
+    under = {s for scopes, _ in _lowered(preset)[1] for s in scopes}
+    mixers = {m for m, _ in cfg.layer_kinds()}
+    for row in tfm.MIXERS.values():
+        scope = row.scope(cfg)
+        if row.name in mixers:
+            assert scope is None or scope in under, scope
+            assert set(INNER[row.name]) <= under, row.name
+        elif scope is not None and scope not in {
+                tfm.MIXERS[m].scope(cfg) for m in mixers}:
+            assert scope not in under, scope
+    assert ("mla.rope" in under) == ("mla" in mixers and cfg.mla_rotates)
+    assert ("gattn.gate" in under) == cfg.attn_gated == ("gattn" in under)
+
+
+def test_the_docs_table_is_the_table():
+    """docs/model_layers.md's table of kinds names every row, its list's
+    field and its scope, in the table's order."""
+    with open(os.path.join(ROOT, "docs", "model_layers.md")) as f:
+        text = f.read()
+    rows = re.findall(r"^\| `(\w+)` \| (?:`(\w+)`|none[^|]*) \| (?:`([\w.]+)`"
+                      r"[^|]*|none[^|]*) \|", text, flags=re.M)
+    gated = configs.qwen3_next_tiny()
+    assert rows == [(r.name, r.layers_field or "", r.scope(gated) or "")
+                    for r in tfm.MIXERS.values()], rows
+    assert "_mixer_params" not in text
+
+
+if __name__ == "__main__":  # this tree's digests (JAX_PLATFORMS=cpu), for PARENT
+    with jax.default_matmul_precision("highest"):
+        for preset in PARENT:
+            name, _, impl = preset.partition("-")
+            os.environ.pop("RTPU_ATTN_IMPL", None)
+            if impl:
+                os.environ["RTPU_ATTN_IMPL"] = impl
+            print(repr(preset) + ":",
+                  tuple(_digests(name, m, impl or None) for m in REMAT), ",")

@@ -146,13 +146,19 @@ def _layer_saves(cfg, index, B=2, S=32):
             if "argument" not in why and "constant" not in why]
 
 
+def _kanana2_two_layers(**kw):
+    """The dense lead layer and one expert layer: as many latent layers as
+    "a layer" needs."""
+    return kanana2_tiny(n_layers=2, **kw)
+
+
 @pytest.mark.parametrize("impl,preset,remat_kw,fwd_calls_per_layer", [
     ("xla", llama_tiny, dict(remat=True, remat_policy="full"), 0),
     ("xla", llama_tiny, dict(remat=True, remat_policy="dots"), 0),
     ("flash", llama_tiny, dict(remat=False), 1),
     ("flash", llama_tiny, dict(remat=True, remat_policy="dots"), 1),
     ("flash", llama_tiny, dict(remat=True, remat_policy="full"), 1),
-    ("flash", kanana2_tiny, dict(remat=True, remat_policy="full"), 1),
+    ("flash", _kanana2_two_layers, dict(remat=True, remat_policy="full"), 1),
 ])
 def test_remat_matches_no_remat(monkeypatch, impl, preset, remat_kw,
                                 fwd_calls_per_layer):
@@ -277,24 +283,6 @@ def test_remat_full_saves_no_ring_product(monkeypatch):
     ring = lambda rows: [w for _, _, w in rows if "tensor_overlap.py" in w]
     assert ring(saves["dots"]) and not ring(saves["full"])
     assert len(saves["full"]) == 2, saves["full"]
-
-
-@pytest.mark.parametrize("impl,digest", [
-    ("xla", "f57f8eb266c73546"), ("flash", "2f8f7c2106e0827d")])
-def test_remat_dots_lowers_to_the_recorded_program(monkeypatch, impl, digest):
-    """`layer_scan_body`'s "dots" branch is the program five of the
-    benchmark's six configurations run: llama_tiny's gradient lowers to the
-    StableHLO text it lowered to before "full" learned to keep the flash
-    outputs (its sha256, recorded at PR 39's commit with this JAX)."""
-    import hashlib
-
-    monkeypatch.setenv("RTPU_ATTN_IMPL", impl)
-    cfg = llama_tiny(remat=True, remat_policy="dots")
-    params = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 32), jnp.int32)}
-    text = jax.jit(jax.grad(lambda p, b: tfm.loss_fn(p, b, cfg))).lower(
-        params, batch).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
 def test_remat_counter_is_one_a_checkpointed_body(monkeypatch):

@@ -1,5 +1,7 @@
 """Mixture-of-Experts layer + expert parallelism (SURVEY §5.7; ops/moe.py
-GShard capacity-based dispatch)."""
+GShard capacity-based dispatch), and a held range of experts: its windows,
+row blocks and grouped-product kernels. The held layer against a masked
+loop at every share and routing is tests/test_moe_held_loop.py."""
 import jax
 import numpy as np
 import pytest
@@ -179,52 +181,6 @@ def _loss_and_grads(fn, args, wy):
 def _assert_close(got, want, what):
     scale = max(1.0, float(np.max(np.abs(want))))
     np.testing.assert_allclose(got, want, atol=3e-5 * scale, err_msg=what)
-
-
-@pytest.mark.parametrize("kind", ["sigmoid", "softmax"])
-@pytest.mark.parametrize("routing", ["even", "all_held", "none_held"])
-@pytest.mark.parametrize("share", [1, 4, 32])
-def test_held_layer_equals_a_masked_loop(share, routing, kind):
-    """Output and the gradients of x, the router and both weight stacks of
-    `moe_ffn_held` are those of a plain masked loop over the held experts,
-    at every held share and however the routing falls on the held range:
-    the window follows the held share, the loop takes the trips the held
-    assignments need and not one more, nothing is dropped. With every
-    expert held the one window is every assignment."""
-    import functools
-
-    import jax.numpy as jnp
-
-    from ray_tpu.ops import moe
-
-    args, wy, route, first = _held_case(share, routing, kind)
-    T, k, E, Eh = 256, 2, 64, 64 // share
-    with jax.default_matmul_precision("highest"):
-        y, cnt, grads = _loss_and_grads(functools.partial(
-            moe.moe_ffn_held, route=route, held_first=first,
-            dtype=jnp.float32), args, wy)
-        want = _loss_and_grads(
-            lambda *a: (_held_loop(*a, route=route, first=first), {}),
-            args, wy)
-    _assert_close(y, want[0], "output")
-    for name, g, w in zip(("x", "router", "gate_up", "down"), grads, want[2]):
-        _assert_close(g, w, name)
-    rows = moe.held_window_rows(T, k, E, Eh)
-    more = moe.further_window_rows(rows)  # half of it, to 128 rows
-    held = int(cnt["assigned"])
-    assert float(cnt["window_rows"]) == rows
-    assert float(cnt["trips"]) == 1 + min(max(-(-(held - rows) // more), 0),
-                                          -(-(T * k - rows) // more))
-    assert float(cnt["dropped"]) == 0.0
-    assert float(cnt["past_buffer"]) == max(held - rows, 0)
-    if share == 1:
-        assert rows == T * k == held and float(cnt["trips"]) == 1.0
-    else:
-        assert rows < T * k  # the window follows the held share
-        if routing == "all_held":
-            assert held == T * k and float(cnt["trips"]) > 1.0
-        if routing == "none_held":
-            assert held == 0 and not np.any(np.asarray(y))
 
 
 def _given_route(kind, ids):

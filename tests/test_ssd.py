@@ -1,11 +1,11 @@
 """The Mamba-2 core (ops/ssd.py) on the CPU: the chunked form against the
 token-by-token recurrence, forward and gradients, at lengths that are no
 multiple of the chunk, with an initial state, several groups, and decays
-strong enough that an unmasked exp would overflow; the Pallas kernels
-(interpret mode) against both; the dispatch rule and the phase-table count;
-what a remat policy keeps of the kernels in a traced Mamba-2 stack; and
-reduce/ssd_counts.py against a hand count. tests/test_kda_kernel_compile.py
-compiles the kernels for the chip."""
+strong enough that an unmasked exp would overflow; the dispatch rule and
+the phase-table count; and reduce/ssd_counts.py against a hand count. The
+Pallas kernels (interpret mode) against both, and what a remat policy keeps
+of them in a traced Mamba-2 stack, are tests/test_ssd_kernels.py;
+tests/test_kda_kernel_compile.py compiles the kernels for the chip."""
 import functools
 import os
 import sys
@@ -15,8 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import transformer as tfm
-from ray_tpu.models.configs import granite_hybrid_tiny
 from ray_tpu.ops import ssd
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -110,55 +108,6 @@ def test_state_carries_across_calls():
     np.testing.assert_allclose(s2, s, atol=2e-4)
 
 
-_NAMES = ("x", "dt", "A", "B", "C", "D", "s0")
-
-
-@pytest.mark.parametrize("name,kw,chunk", [
-    # The cell's head shape cut in count: heads of 64, state 128, one
-    # group, chunks of 256 (two lane groups of two heads a grid step).
-    ("cell_heads", dict(B=1, S=512, H=4, P=64, G=1, N=128, state=False), 256),
-    ("two_groups", dict(B=2, S=256, H=4, P=64, G=2, N=128), 128),
-    ("ragged", dict(B=1, S=300, H=2, P=64, G=1, N=128, state=False), 128),
-    ("initial_state", dict(B=1, S=256, H=2, P=64, G=1, N=128), 128),
-    ("decay_past_e88", dict(B=1, S=256, H=2, P=64, G=1, N=128,
-                            dt_scale=12.0), 128),
-    ("heads_of_128", dict(B=1, S=256, H=2, P=128, G=1, N=128), 128),
-])
-def test_kernels_match_xla_and_recurrence(name, kw, chunk):
-    """The Pallas kernels (interpret mode here) against the XLA body and the
-    recurrence: y, the final state and every gradient (x, dt, A, B, C, D,
-    the initial state), with a cotangent on the final state too."""
-    c = _case(6, **kw)
-    if c["s0"] is None:
-        c["s0"] = jnp.zeros((kw["B"], kw["H"], kw["P"], kw["N"]))
-    if name == "decay_past_e88":
-        assert float(jnp.sum((c["dt"] * c["A"])[:, :chunk], axis=1).min()
-                     ) < -500.0
-
-    def run(fn):
-        def loss(*args):
-            y, s = fn(*args[:6], initial_state=args[6])
-            w = jnp.cos(jnp.arange(y.size, dtype=jnp.float32)).reshape(y.shape)
-            return jnp.sum(y * w) + jnp.sum(jnp.sin(s)), (y, s)
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=range(7), has_aux=True))(*(c[n] for n in _NAMES))
-        return out + grads
-
-    got = run(functools.partial(ssd.ssd_chunked_pallas, chunk=chunk))
-    xla = run(functools.partial(ssd.ssd_chunked_xla, chunk=chunk))
-    want = run(ssd.ssd_recurrent)
-    for n, a, b, r in zip(("y", "state") + _NAMES, got, xla, want):
-        assert np.isfinite(a).all(), n
-        # dA is a sum of terms that cancel, the more the stronger the decay:
-        # the two oracles differ by 4e-3 of it in the last case, by 2e-4 in
-        # the others.
-        tol = (3e-2 if name == "decay_past_e88" else 1e-3) if n == "A" \
-            else 1e-4
-        scale = float(jnp.abs(r).max())
-        np.testing.assert_allclose(a, r, err_msg=n, atol=tol * scale)
-        np.testing.assert_allclose(a, b, err_msg=n, atol=2 * tol * scale)
-
-
 def test_dispatch_rule():
     """`use_kernels` is a pure function of platform and shapes (the CPU, a
     mesh, a narrow state or chunk, heads that fill no lane group, states
@@ -182,57 +131,6 @@ def test_dispatch_rule():
     np.testing.assert_array_equal(
         _run(functools.partial(ssd.ssd_chunked, chunk=128), c)[0],
         _run(functools.partial(ssd.ssd_chunked_xla, chunk=128), c)[0])
-
-
-def _kernel_calls(jaxpr, times=1, out=None):
-    """pallas_calls of a jaxpr by operand signature, a call inside a scan
-    counted once per iteration (tests/test_kda.py does it for KDA)."""
-    out = {} if out is None else out
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            sig = f"{len(eqn.invars)}in_{len(eqn.outvars)}out"
-            out[sig] = out.get(sig, 0) + times
-        inner = times * (eqn.params["length"]
-                         if eqn.primitive.name == "scan" else 1)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _kernel_calls(sub, inner, out)
-    return out
-
-
-@pytest.mark.parametrize("policy,fwd_calls_per_layer",
-                         [("dots", 1), ("full", 2)])
-def test_remat_dots_keeps_the_ssd_kernel_residuals(monkeypatch, policy,
-                                                   fwd_calls_per_layer):
-    """The traced gradient of a two-layer `mamba2` stack through the kernels:
-    under "dots" the forward kernel (7 in / 3 out) runs once a layer, its y
-    and chunk-start states being named residuals; under "full" twice. The
-    backward kernel (9 in / 7 out) once. Neither has a flash or a KDA
-    kernel's signature (chipbench/reduce/xplane.py names kernels by it).
-    Gradients are those of the XLA body, and the traced calls count as
-    `ssd.core.pallas`."""
-    from ray_tpu.util import tracing
-
-    cfg = granite_hybrid_tiny(n_layers=2, mamba_layers=(1, 2), remat=True,
-                              remat_policy=policy, dtype=jnp.float32)
-    params = tfm.init_params(jax.random.key(0), cfg)
-    toks = jax.random.randint(jax.random.key(1), (2, 41), 0, cfg.vocab_size)
-    # A new function each time: jax caches a trace by the function's identity.
-    grad = lambda: jax.grad(lambda p: tfm.loss_fn(
-        p, {"tokens": toks}, cfg, shift_inputs=True))
-    assert _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr) == {}
-    g_xla = jax.jit(grad())(params)
-    monkeypatch.setattr(ssd, "use_kernels", lambda *a, **kw: True)
-    count = lambda: tracing.phase_table().get(
-        "ssd.core.pallas", {"count": 0})["count"]
-    before = count()
-    calls = _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr)
-    assert calls == {"7in_3out": 2 * fwd_calls_per_layer, "9in_7out": 2}
-    assert count() > before
-    if policy == "dots":
-        for a, b in zip(jax.tree.leaves(jax.jit(grad())(params)),
-                        jax.tree.leaves(g_xla)):
-            np.testing.assert_allclose(a, b, atol=1e-5 + 1e-4 * float(
-                jnp.abs(b).max()))
 
 
 def test_compute_dtype_and_phase_count():

@@ -3,7 +3,8 @@ collectives run in steps behind their matmuls (parallel/tensor_overlap.py):
 the sharded step gives one device's loss and gradients, its compiled text
 holds the ring's transfers and no all-reduce of an activation or collective
 of a weight that the step without the mechanism lacks, and where nothing
-engages the program is the one it was."""
+engages the program is the one it was. The sharded step's numbers, mesh by
+mesh, are tests/test_tensor_overlap_rows.py."""
 import collections
 import itertools
 import re
@@ -61,30 +62,8 @@ def _loss_and_grads(cfg, spec, rules, tokens):
     return float(loss), jax.tree.map(np.asarray, grads)
 
 
-@pytest.mark.parametrize("remat", [True, False], ids=["dots", "no_remat"])
-@pytest.mark.parametrize("model,mesh", [
-    ("llama", "fsdp2xtp2"), ("llama", "fsdp4xtp2"),
-    ("llama", "dp2xfsdp2xtp2"), ("gpt2", "fsdp2xtp2")])
-def test_sharded_rows_give_one_devices_loss_and_gradients(model, mesh, remat):
-    cfg = MODELS[model](remat=remat, remat_policy="dots")
-    tokens = _tokens(cfg)
-    before = _count()
-    loss, grads = _loss_and_grads(cfg, MESHES[mesh], RULES_TP, tokens)
-    assert _count() == before + 1  # one layer body traced, and it engaged
-    loss1, grads1 = _loss_and_grads(cfg, ONE, RULES_TP, tokens)
-    assert _count() == before + 1  # and not on a mesh of one device
-    assert abs(loss - loss1) < 1e-2, (loss, loss1)
-    for (path, a), b in zip(jax.tree.leaves_with_path(grads1),
-                            jax.tree.leaves(grads)):
-        # bfloat16 activations: sums in another order round apart.
-        scale = np.abs(a).max()
-        np.testing.assert_allclose(b, a, atol=4e-2 * scale,
-                                   err_msg=jax.tree_util.keystr(path))
-        assert np.linalg.norm(a - b) <= 2e-2 * np.linalg.norm(a), (
-            jax.tree_util.keystr(path))
-
-
 # ------------------------------------------------- the compiled step's text
+
 
 _COLLECTIVE = re.compile(
     r"= (\(?[^=]*?\)?) (all-reduce|all-gather|reduce-scatter|all-to-all|"
