@@ -18,6 +18,9 @@ chip the script fails at once.
         # fits, at the table's tiles and at every (block, strip) of
         # `BAND_SWEEP`, then timed at the cell's length beside the same
         # shape's full causal call (the sweep beside `_TILES`)
+    python3 benchmarks/probe_flash.py band512    # the same for `BAND512`
+        # (a window a quarter of the table's block, groups of 9), and the
+        # full layers' causal call beside it at every (block, strip)
     python3 benchmarks/probe_flash.py gated      # `GATED`, heads of 256 at
         # 8:1: checked at GATED_CHECK_S positions at the table's tiles and
         # at every (block, strip) of `GATED_SWEEP`, then timed at 16,384
@@ -51,6 +54,13 @@ BAND = ("mellum2_swa", 1, 16384, 32, 4, 128, 128, 1024)
 BAND_CHECK_S = 4096
 BAND_SWEEP = [(512, 128), (512, 256), (1024, 128), (1024, 256), (1024, 512),
               (2048, 256), (2048, 512)]
+# laguna_s_2_1.train_rank32_8k: the sliding layers (36 query heads over 4 key
+# heads, window 512) and, swept beside them, the full layers' causal call at
+# 24 query heads; checked at 2,048 positions (four windows).
+BAND512 = ("laguna_swa", 1, 8192, 36, 4, 128, 128, 512)
+BAND512_FULL_H = 24
+BAND512_CHECK_S = 2048
+BAND512_SWEEP = [(b, s) for b in (512, 1024, 2048) for s in (128, 256)]
 # (name, B, S, H, KVH, D, Dv): qwen3_next_80b_a3b.train_rank16_16k's gated
 # attention layer (K and V of a block are twice the bytes of the 128 row).
 GATED = ("qwen3_next_gattn", 1, 16384, 16, 2, 256, 256)
@@ -171,21 +181,28 @@ def main(argv) -> int:
         failed += not ok
         print(json.dumps(row), flush=True)
 
-    if argv[1:] == ["band"]:
-        name, B, S, H, KVH, D, Dv, window = BAND
+    if argv[1:] in (["band"], ["band512"]):
+        # (the band, its check length, its sweep, the full layer's heads and
+        # its (block, strip)s)
+        band, check_s, sweep, full_h, full = {
+            "band": (BAND, BAND_CHECK_S, BAND_SWEEP, BAND[3],
+                     ((1024, 256), (2048, 256))),
+            "band512": (BAND512, BAND512_CHECK_S, BAND512_SWEEP,
+                        BAND512_FULL_H, BAND512_SWEEP)}[argv[1]]
+        name, B, S, H, KVH, D, Dv, window = band
         h = 2 * H // KVH  # two key/value heads with all their query heads
-        for block, sub in [(None, None)] + BAND_SWEEP:
+        for block, sub in [(None, None)] + sweep:
             report({"cell": name, "block": block, "sub": sub}, check,
-                   B, BAND_CHECK_S, h, 2, D, Dv, True, window, block, sub)
-        for block, sub in BAND_SWEEP:
+                   B, check_s, h, 2, D, Dv, True, window, block, sub)
+        for block, sub in sweep:
             report({"cell": name, "block": block, "sub": sub,
                     "window": window},
                    lambda *a: (kernel_ms(*a), True),
                    B, S, H, KVH, D, Dv, block, sub, window)
-        for block, sub in ((1024, 256), (2048, 256)):  # the full layer
+        for block, sub in full:  # the full layer
             report({"cell": name, "block": block, "sub": sub, "window": None},
                    lambda *a: (kernel_ms(*a), True),
-                   B, S, H, KVH, D, Dv, block, sub)
+                   B, S, full_h, KVH, D, Dv, block, sub)
         return 1 if failed else 0
     if argv[1:] == ["gated"]:
         name, B, S, H, KVH, D, Dv = GATED

@@ -311,3 +311,54 @@ def qwen3_next_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def laguna_tiny(**overrides) -> TransformerConfig:
+    """A mixed-head window / full attention stack in the Laguna pattern at
+    widths small enough for CPU tests (docs/model_layers.md): of every four
+    layers the first sees the whole sequence at 4 query heads, half of each
+    head rotated under YaRN-scaled RoPE (theta 1e4), and the other three a
+    window of 8 keys at 6 query heads under plain RoPE of theta 100 over the
+    whole head, all over the same 2 key heads of 16 (groups of 2 and of 3); a
+    sigmoid gate a head on every attention output; the first layer keeps a
+    dense SwiGLU and the others have 16 sigmoid-routed experts, 3 a token,
+    one shared, experts 4-7 held here; an untied head. Published sizes live
+    in chipbench/configs/ only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=5,
+        n_heads=4,
+        n_kv_heads=2,
+        attn_head_dim=16,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-6,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=10000.0,
+        rope_fraction=0.5,
+        yarn_factor=16.0,
+        yarn_original_len=64,  # the ramp over 8 rotated columns is not
+        yarn_beta_fast=32.0,   # the one over the head's 16 at these
+        yarn_beta_slow=1.0,
+        yarn_attn_factor=1.2772588722239782,
+        attn_head_gate=True,
+        tie_embeddings=False,
+        swa_layers=tuple(l for l in range(1, 49) if l % 4 != 1),
+        sliding_window=8,
+        swa_heads=6,
+        swa_rope_theta=100.0,
+        swa_rope_fraction=1.0,
+        moe_num_experts=16,
+        moe_experts_per_token=3,
+        moe_router="sigmoid",
+        moe_held=(4, 4),
+        moe_d_ff=32,
+        moe_shared_experts=1,
+        moe_routed_scale=2.5,
+        moe_first_dense=1,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

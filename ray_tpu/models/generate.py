@@ -55,9 +55,11 @@ def _kv_stack(params: Params, cfg: TransformerConfig):
     for mixer, _ in cfg.layer_kinds():
         if MIXERS[mixer].no_decode:
             raise NotImplementedError(MIXERS[mixer].no_decode)
-    if cfg.attn_out_gate or cfg.norm_offset:
+    if cfg.attn_out_gate or cfg.attn_head_gate or cfg.norm_offset:
         raise NotImplementedError(
-            "decode does not apply attn_out_gate / norm_offset yet")
+            "decode does not apply attn_out_gate / attn_head_gate (the "
+            "attention output's sigmoid gate, a column or a head) / "
+            "norm_offset yet")
     if (cfg.embed_scale, cfg.residual_scale, cfg.attn_scale,
             cfg.logit_scale) != (1.0, 1.0, None, 1.0):
         raise NotImplementedError(

@@ -87,10 +87,21 @@ class TransformerConfig:
     # - norm_offset: RMSNorm weights are held zero-centred, x / rms(x) *
     #   (norm_offset + w), 0.0 or 1.0: the layer norms, the final norm and
     #   the q / k norms (not a `gdn` layer's gated norm).
+    # - attn_head_gate: the other published form of that gate, one scalar a
+    #   head: sigmoid(h @ `w_head_gate` [d, H]) in float32 times the head's
+    #   output before `wo`. One form or the other.
     attn_qk_norm: bool = False
     rope_fraction: float = 1.0
     attn_out_gate: bool = False
+    attn_head_gate: bool = False
     norm_offset: float = 0.0
+    # What a "swa" layer has of its own (None: the "attn" layers' value, and
+    # the two kinds then share their leaves' shapes): its number of query
+    # heads over the same `n_kv_heads`, and its rotation's theta and share
+    # of a head. YaRN is the "attn" layers' alone.
+    swa_heads: Optional[int] = None
+    swa_rope_theta: Optional[float] = None
+    swa_rope_fraction: Optional[float] = None
     tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -162,8 +173,9 @@ class TransformerConfig:
     # as published configs list them; numbers past n_layers are ignored, so
     # a cut in depth keeps the published lists. A layer in neither list has
     # the softmax attention above ("attn").
-    # - swa_layers: the same attention (the same leaves) where query i sees
-    #   keys j with 0 <= i - j < `sliding_window` ("swa"), plain RoPE.
+    # - swa_layers: the same attention where query i sees keys j with
+    #   0 <= i - j < `sliding_window` ("swa"), plain RoPE; the same leaves
+    #   unless `swa_heads` gives the kind a head count of its own.
     # - kda_layers: Kimi Delta Attention (ops/kda.py), `kda_heads` heads of
     #   `kda_head_dim`, a causal depthwise convolution of `kda_conv`, gate
     #   projections of rank `kda_gate_rank`, chunks of `kda_chunk` tokens.
@@ -241,9 +253,18 @@ class TransformerConfig:
         if self.norm_offset not in (0.0, 1.0) or (
                 self.norm_offset and self.norm != "rmsnorm"):
             raise ValueError("norm_offset is 0.0, or 1.0 with norm='rmsnorm'")
-        if not 0.0 < self.rope_fraction <= 1.0 or self.rope_rotated % 2:
-            raise ValueError("rope_fraction rotates an even number of a "
-                             "head's columns, at most all of them")
+        for mixer in ("attn", "swa"):
+            rot = self.attn_rope(mixer)[1]
+            if not 0 < rot <= self.head_dim or rot % 2:
+                raise ValueError(
+                    "rope_fraction / swa_rope_fraction rotate an even number "
+                    "of a head's columns, at most all of them")
+        if self.swa_heads and self.swa_heads % self.kv_heads:
+            raise ValueError("swa_heads is a multiple of the key / value "
+                             "heads")
+        if self.attn_out_gate and self.attn_head_gate:
+            raise ValueError("attn_out_gate (a gate a column) or "
+                             "attn_head_gate (a gate a head), not both")
         if self.moe_shared_gate and not (self.moe_holds_range
                                          and self.moe_shared_experts):
             raise ValueError("moe_shared_gate gates the shared experts of a "
@@ -331,15 +352,26 @@ class TransformerConfig:
             i += n
         raise IndexError(l)
 
-    @property
-    def rope_rotated(self) -> int:
-        """Columns of an "attn" / "swa" head the rotation turns."""
-        return int(self.head_dim * self.rope_fraction)
+    def attn_heads(self, mixer: str) -> int:
+        """Query heads of an "attn" or a "swa" layer."""
+        return (self.swa_heads if mixer == "swa" else None) or self.n_heads
+
+    def attn_rope(self, mixer: str):
+        """(theta, rotated columns of a head, YaRN or None) of the rotation
+        of an "attn" or a "swa" layer."""
+        if mixer != "swa":
+            return (self.rope_theta, int(self.head_dim * self.rope_fraction),
+                    self.rope_yarn)
+        fraction = (self.rope_fraction if self.swa_rope_fraction is None
+                    else self.swa_rope_fraction)
+        return (self.swa_rope_theta or self.rope_theta,
+                int(self.head_dim * fraction), None)
 
     @property
     def attn_gated(self) -> bool:
-        """Whether the "attn" kind carries what the `gattn` scope marks."""
-        return self.attn_qk_norm or self.attn_out_gate
+        """Whether the "attn" / "swa" kinds carry what the `gattn` scope
+        marks."""
+        return self.attn_qk_norm or self.attn_out_gate or self.attn_head_gate
 
     @property
     def kda_n_heads(self) -> int:
@@ -474,13 +506,16 @@ _out_std = lambda cfg, n: ("normal", 1.0 / math.sqrt(2 * cfg.n_layers * n))
 _size = lambda shapes: sum(math.prod(sh) for sh, _, _ in shapes.values())
 
 
-def _attn_shapes(cfg: TransformerConfig):
+def _attn_shapes(cfg: TransformerConfig, mixer: str = "attn"):
+    # The leaves of an "attn" layer or of a "swa" one (`mixer`: a "swa"
+    # layer may have its own number of query heads, `cfg.swa_heads`).
     # Projections are FUSED into single matmuls (one MXU op instead of 2-3:
     # q/k/v together for MHA, k/v together for GQA; gate/up for swiglu). The
     # fusion factor is its own array dim — NOT folded into the feature dim —
     # so tensor-parallel sharding of heads/mlp stays aligned to shard
     # boundaries (Megatron fused-qkv, done the GSPMD-friendly way).
-    d, H, hd, KVH = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    d, hd, KVH = cfg.d_model, cfg.head_dim, cfg.kv_heads
+    H = cfg.attn_heads(mixer)
     fan, unit = _fan(d), _unit_norm_init(cfg)
     sh = {"wo": ((H * hd, d), ("heads", "embed"), _out_std(cfg, H * hd))}
     if KVH == H:
@@ -490,6 +525,8 @@ def _attn_shapes(cfg: TransformerConfig):
         sh["wkv"] = ((d, 2, KVH, hd), ("embed", None, "kv_heads", None), fan)
     if cfg.attn_out_gate:
         sh["wq_gate"] = ((d, H, hd), ("embed", "heads", None), fan)
+    if cfg.attn_head_gate:
+        sh["w_head_gate"] = ((d, H), ("embed", "heads"), fan)
     if cfg.attn_qk_norm:
         sh["q_norm"] = ((hd,), (None,), unit)
         sh["k_norm"] = ((hd,), (None,), unit)
@@ -811,15 +848,17 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
               positions: jax.Array, mixer: str = "attn",
               overlap: Optional[tp.Overlap] = None):
     """Projection + rope shared by training forward and KV-cache decode
-    (models/generate.py) — ONE home for the layer's q/k/v convention. An
-    "attn" layer's rotation takes cfg's YaRN scaling, a "swa" layer's never
-    does. `overlap` (a layer body whose residual's rows are cut over
-    `tensor`): h's rows are gathered behind the products."""
+    (models/generate.py) — ONE home for the layer's q/k/v convention. The
+    rotation is the kind's (`cfg.attn_rope`): an "attn" layer's takes cfg's
+    YaRN scaling, a "swa" layer's never does and may have a theta and a
+    share of the head of its own. `overlap` (a layer body whose residual's
+    rows are cut over `tensor`): h's rows are gathered behind the
+    products."""
     eqs = ({"wqkv": "bsd,dcnh->bscnh"} if "wqkv" in layer else
            {"wq": "bsd,dnh->bsnh", "wkv": "bsd,dcnh->bscnh"})
     outs = None
     if overlap is not None:
-        axes = _attn_shapes(cfg)
+        axes = _attn_shapes(cfg, mixer)
         outs = overlap.gather_matmul(
             "qkv", h, [(eq, _w(layer, n, cfg), axes[n][1])
                        for n, eq in eqs.items()])
@@ -837,10 +876,9 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
                       cfg.norm_offset)
                 for x, n in ((q, "q_norm"), (k, "k_norm")))
     if cfg.positional == "rope":
-        yarn = cfg.rope_yarn if mixer == "attn" else None
-        rot = cfg.rope_rotated
-        q = _rope_first(q, rot, positions, cfg.rope_theta, yarn)
-        k = _rope_first(k, rot, positions, cfg.rope_theta, yarn)
+        theta, rot, yarn = cfg.attn_rope(mixer)
+        q = _rope_first(q, rot, positions, theta, yarn)
+        k = _rope_first(k, rot, positions, theta, yarn)
     return q, k, v
 
 
@@ -1022,12 +1060,23 @@ def _mla_mixer(cfg, kind, h, layer, positions, overlap):
 
 
 def _attn_mixer(cfg, kind, h, layer, positions, overlap):
-    """Softmax attention ("attn", or "swa" under its window) -> (delta, k,
-    v). Where `cfg.attn_gated`, what a plain layer lacks (the q / k norms,
-    a rotation of part of a head, the output's gate) runs under the scope
-    `gattn.gate`; at the defaults nothing of it is traced."""
+    """Softmax attention ("attn", or "swa" under its window and with the
+    heads and rotation `cfg` gives that kind) -> (delta, k, v). Where
+    `cfg.attn_gated`, what a plain layer lacks (the q / k norms, a rotation
+    of part of a head, the output's gate a column or a head) runs under the
+    scope `gattn.gate`; at the defaults nothing of it is traced. One
+    `attn.plan` observation a traced layer body says what the layer is."""
     mixer = kind[0]
     B, S, _ = h.shape
+    swa = mixer == "swa"
+    theta, rot, yarn = cfg.attn_rope(mixer)
+    tracing.observe(
+        "attn.plan", 0, slow=False, kind=mixer, heads=cfg.attn_heads(mixer),
+        kv_heads=cfg.kv_heads, window=cfg.sliding_window if swa else 0,
+        rotated=rot if cfg.positional == "rope" else 0, theta=theta,
+        yarn=yarn[0] if yarn else 0,
+        gate=("column" if cfg.attn_out_gate else
+              "head" if cfg.attn_head_gate else "none"))
     gated = lambda: (jax.named_scope("gattn.gate") if cfg.attn_gated
                      else contextlib.nullcontext())
     with gated():
@@ -1035,7 +1084,7 @@ def _attn_mixer(cfg, kind, h, layer, positions, overlap):
     q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
     attend = functools.partial(attention, q, k, v, causal=True,
                                scale=cfg.attn_scale)
-    if mixer == "swa":  # the scope tells its kernels from a full layer's
+    if swa:  # the scope tells its kernels from a full layer's
         with jax.named_scope("swa"):
             o = attend(window=cfg.sliding_window)
     else:
@@ -1045,10 +1094,16 @@ def _attn_mixer(cfg, kind, h, layer, positions, overlap):
             gate = jnp.einsum("bsd,dnh->bsnh", _whole(h, overlap),
                               _w(layer, "wq_gate", cfg))
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+    if cfg.attn_head_gate:
+        with gated():
+            gate = jnp.einsum("bsd,dn->bsn", _whole(h, overlap),
+                              _w(layer, "w_head_gate", cfg),
+                              preferred_element_type=jnp.float32)
+            o = o * jax.nn.sigmoid(gate)[..., None].astype(o.dtype)
     o, wo, delta = o.reshape(B, S, -1), _w(layer, "wo", cfg), None
     if overlap is not None:
         delta = overlap.matmul_scatter(
-            "wo", o, "bsf,fd->bsd", wo, _attn_shapes(cfg)["wo"][1])
+            "wo", o, "bsf,fd->bsd", wo, _attn_shapes(cfg, mixer)["wo"][1])
     if delta is None:
         delta = o @ wo
     return delta, k, v
@@ -1056,7 +1111,8 @@ def _attn_mixer(cfg, kind, h, layer, positions, overlap):
 
 def _swa_flops(cfg: TransformerConfig, S: int) -> float:
     W = min(cfg.sliding_window, S)  # the band's pairs for the triangle's
-    return 6.0 * (2 * W - W * (W - 1) / S) * cfg.n_heads * cfg.head_dim
+    return (6.0 * (2 * W - W * (W - 1) / S) * cfg.attn_heads("swa")
+            * cfg.head_dim)
 
 
 def _mamba_flops(cfg: TransformerConfig, S: int) -> float:
@@ -1078,8 +1134,9 @@ MIXERS: Dict[str, Mixer] = {m.name: m for m in (
     Mixer("attn", None, _attn_shapes, _attn_mixer,
           lambda cfg, S: 3.0 * S * cfg.n_heads * 2 * cfg.head_dim,
           scope=_GATTN, cut_rows=True),
-    Mixer("swa", "swa_layers", _attn_shapes, _attn_mixer, _swa_flops,
-          scope=_GATTN, cut_rows=True, no_decode=_NO_CACHE),
+    Mixer("swa", "swa_layers", functools.partial(_attn_shapes, mixer="swa"),
+          _attn_mixer, _swa_flops, scope=_GATTN, cut_rows=True,
+          no_decode=_NO_CACHE),
     Mixer("mla", "mla_layers", _mla_shapes, _mla_mixer,
           lambda cfg, S: 3.0 * S * cfg.n_heads * (
               cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim),

@@ -74,6 +74,22 @@ DEFAULT_BLOCK = 512
 # A block of twice the window wins over one of the window: a block's first
 # step fetches and starts once for twice the rows, and the cells of the
 # second key block it then skips cost nothing.
+# A window a quarter of the block (`probe_flash.py band512` on a v5e at
+# [1,8192,36|4,128], window 512: nine query heads a key head; chip run PR 45,
+# `p45a`; ms a call forward / dQ / dK/dV and their sum):
+#   512/128  2.19 / 1.75 / 1.81 = 5.76 | 512/256  2.40 / 1.58 / 1.85 = 5.84
+#   1024/128 1.79 / 1.38 / 1.60 = 4.78 | 1024/256 2.01 / 1.35 / 1.69 = 5.05
+#   2048/128 1.65 / 1.27 / 1.58 = 4.51 | 2048/256 1.88 / 1.27 / 1.68 = 4.84
+# and the full causal call beside it at [1,8192,24|4,128] (groups of six):
+#   512/128 18.91 | 512/256 17.85 | 1024/128 13.61 | 1024/256 12.83
+#   2048/128 11.89 | 2048/256 3.31 / 3.70 / 4.65 = 11.66
+# The block of 2,048 still wins at a window of 512 (four times the window:
+# 5% over 1,024, 20% over 512), so `tile_sizes` takes no window and the row
+# stands. Under the window a strip of 128 is 7% ahead of 256 (the forward
+# 0.22 ms, dK/dV 0.10: a strip then meets two cells of the band's 512, not
+# one and a half), where the full call loses 2% to it: 0.33 ms a layer, 1.0
+# ms of that cell's 255 ms step, left to a perf_opt issue (a strip from the
+# window would change what `flash.plan` and the driver's `flash_plans` say).
 # Heads of 256 (`probe_flash.py gated` on a v5e at [1,16384,16|2,256], causal;
 # chip run PR 41, `p41a`; ms a call forward / dQ / dK/dV and their sum; the
 # fallback this shape took before, 512 x 512 with no strips, first):

@@ -189,6 +189,9 @@ FAMILIES = {
                             sizes="QwenNextSizes", reference="qwen3_next",
                             kind=("gdn", "moe"), layer=1, experts=32,
                             atol=3e-5),
+    "laguna_tiny": dict(weights="weights_laguna", sizes="LagunaSizes",
+                        reference="laguna", kind=("swa", "moe"), layer=1,
+                        experts=16, atol=3e-5),
 }
 
 

@@ -127,8 +127,11 @@ def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     (1, 16384, 32, 4, 128, 128, 1024),   # its full layer and a windowed one
     (1, 16384, 32, 32, 192, 128, None),  # kanana_2_30b_a3b.train_rank8_16k
     (1, 16384, 16, 2, 256, 256, None),   # qwen3_next_80b_a3b.train_rank16_16k
+    (1, 8192, 24, 4, 128, 128, None),    # laguna_s_2_1.train_rank32_8k: a full
+    (1, 8192, 36, 4, 128, 128, 512),     # layer (groups of 6), a windowed (9)
 ], ids=["gpt2", "internlm2_shard", "kimi_mla", "granite_gqa64",
-        "mellum2_full", "mellum2_swa", "kanana_mla", "qwen3_next_gattn"])
+        "mellum2_full", "mellum2_swa", "kanana_mla", "qwen3_next_gattn",
+        "laguna_full", "laguna_swa"])
 def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
                                        B, S, H, KVH, D, Dv, window):
     """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape, bfloat16, causal: three Mosaic calls in the gradient's
@@ -151,7 +154,8 @@ def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     (16384, 64, 16, 16, 896, "softmax", 2304, 8),
     (8192, 256, 104, 8, 1024, "sigmoid", 2304, 8),
     (16384, 128, 48, 16, 768, "sigmoid", 2048, 6),
-    (16384, 512, 224, 32, 512, "softmax", 2048, 10)])
+    (16384, 512, 224, 32, 512, "softmax", 2048, 10),
+    (8192, 256, 120, 8, 1024, "sigmoid", 3072, 10)])
 def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
                                       monkeypatch, T, E, first, Eh, F, kind,
                                       d, k):
@@ -161,7 +165,9 @@ def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
     `kanana_2_30b_a3b.train_rank8_16k` (d 2048, 6 a token, experts 768 wide:
     `_tiles` takes 1024 x 768 and 768 x 1024) and
     `qwen3_next_80b_a3b.train_rank16_16k` (32 of 512 experts 512 wide, 10 a
-    token: a first window of 25,600 rows), bfloat16, with the grouped
+    token: a first window of 25,600 rows) and
+    `laguna_s_2_1.train_rank32_8k` (d 3072, 8 of 256 experts 1,024 wide, 10
+    a token: the row-a-token window of 8,192), bfloat16, with the grouped
     products dispatched as on the chip: the
     first window's two products and their four transposes are the Pallas
     grouped matmul at `_tiles` (`gmm` / `tgmm` in the program's text); the

@@ -27,7 +27,7 @@ pytestmark = pytest.mark.usefixtures("exact_matmuls")
 
 PRESETS = ("llama_tiny", "gpt2_tiny", "moe_tiny", "kimi_linear_tiny",
            "granite_hybrid_tiny", "mellum2_tiny", "kanana2_tiny",
-           "qwen3_next_tiny")
+           "qwen3_next_tiny", "laguna_tiny")
 REMAT = ("off", "dots", "full")
 # "<sha256[:16] of the StableHLO>:<sha256[:16] of its operations' name
 # stacks>" of each preset's gradient program, remat off and under either
@@ -65,6 +65,11 @@ PARENT = {
     "qwen3_next_tiny": ("6a18e99b94bd3ae4:4d7d9eca8c599952",
                         "befaff51114e9531:79df8da7a94dd775",
                         "52f1a824a7912a3e:79df8da7a94dd775"),
+    # new in PR 45 (its own tree's: the `swa` kind with its own heads, theta
+    # and rotated share, the gate a head); the rows above are the parent's
+    "laguna_tiny": ("f5f7f7b0405c9cb0:99e87d210fd8ce25",
+                    "a6b430c735907222:99e87d210fd8ce25",
+                    "0fe3499c4da11803:99e87d210fd8ce25"),
     # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text
     "llama_tiny-flash": ("b8a5e1398a317f9f:c7a534cb53ae9dba",
                          "dc0235890402e707:c7a534cb53ae9dba",
@@ -86,6 +91,7 @@ PARENT_COUNTS = {
     "granite_hybrid_tiny": (548440, 548440, 3826704.0, 3998736.0),
     "mellum2_tiny": (215616, 141888, 837024.0, 1182852.0),
     "qwen3_next_tiny": (344008, 171976, 1077936.0, 1422000.0),
+    "laguna_tiny": (260480, 180544, 1060248.0, 1405635.0),  # PR 45's own
     "gpt2_124m.json": (124356864, 124356864, 798045696.0, 769734144.0),
     "granite_4_0_h_micro.json": (772160448, 772160448, 4769113728.0,
                                  4725073536.0),
@@ -99,6 +105,8 @@ PARENT_COUNTS = {
                                1200686592.0),
     "qwen3_next_80b_a3b.json": (625667136, 230878272, 1603307904.0,
                                 1213237632.0),
+    "laguna_s_2_1.json": (672126976, 381932544, 2444659776.0,  # PR 45's own
+                          2121808896.0),
 }
 
 
@@ -197,8 +205,12 @@ def test_the_table_is_what_the_configuration_lists():
     cfg = configs.llama_tiny(sliding_window=8)
     leaves = collections.Counter(
         n for r in rows.values() if r.name != "swa" for n in r.shapes(cfg))
-    assert max(leaves.values()) == 1  # swa's are attn's: the same function
-    assert rows["swa"].shapes is rows["attn"].shapes
+    assert max(leaves.values()) == 1
+    # swa's leaves are attn's until it has a head count of its own
+    assert rows["swa"].shapes(cfg) == rows["attn"].shapes(cfg)
+    wider = configs.llama_tiny(sliding_window=8, swa_heads=8)
+    assert (rows["swa"].shapes(wider)["wo"][0], rows["attn"].shapes(wider)[
+        "wo"][0]) == ((8 * wider.head_dim, 128), (128, 128))
     assert [r.name for r in rows.values() if r.cut_rows] == ["attn", "swa"]
     assert [r.name for r in rows.values() if not r.no_decode] == ["attn"]
     with pytest.raises(ValueError, match="two of swa_layers, mla_layers, kda"):
@@ -215,6 +227,7 @@ _a, _s, _m = ("attn", "dense"), ("swa", "moe"), ("mamba2", "dense")
 _g, _ga = ("gdn", "moe"), ("attn", "moe")
 _ld, _lm = ("mla", "dense"), ("mla", "moe")
 _kd, _km = ("kda", "dense"), ("kda", "moe")
+_ad = ("attn", "dense")
 PLANS = {
     "llama_tiny": dict(plan=(((_a,), 2),), deep=(24, (((_a,), 24),)),
                        slot=(1, (0, 0, 1)), segments=False,
@@ -250,6 +263,12 @@ PLANS = {
         plan=(((_g,), 3), ((_ga,), 1)), deep=(48, (((_g, _g, _g, _ga), 12),)),
         slot=(3, (1, 0, 0)), segments=True,
         refused=[dict(kda_layers=(1,)), dict(gdn_k_heads=3)]),
+    "laguna_tiny": dict(
+        plan=(((_ad,), 1), ((_s,), 3), ((_ga,), 1)),
+        deep=(48, (((_ad,), 1), ((_s, _s, _s, _ga), 11), ((_s,), 3))),
+        slot=(4, (2, 0, 0)), segments=True,
+        refused=[dict(swa_heads=5), dict(attn_out_gate=True),
+                 dict(swa_rope_fraction=0.2), dict(sliding_window=None)]),
 }
 
 
@@ -310,12 +329,13 @@ def test_decoding_serves_a_row_or_says_its_sentence(preset):
         prefill(params, toks, cfg, 16)
     word = {"kimi_linear_tiny": "KDA / MLA", "granite_hybrid_tiny": "Mamba-2",
             "mellum2_tiny": "windowed", "kanana2_tiny": "MLA",
-            "qwen3_next_tiny": "R7 / R9"}[preset]
+            "qwen3_next_tiny": "R7 / R9", "laguna_tiny": "windowed"}[preset]
     assert word in why[0]
 
 
 UNAPPLIED = {
     "attn_out_gate": dict(attn_out_gate=True),
+    "attn_head_gate": dict(attn_head_gate=True),
     "norm_offset": dict(norm_offset=1.0),
     "logit_scale": dict(logit_scale=8.0),
     "embed_scale": dict(embed_scale=12.0),

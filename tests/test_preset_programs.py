@@ -29,6 +29,7 @@ STEPS = {
     ("kanana2_tiny", "full"): dict(toks=(4, 65)),
     ("qwen3_next_tiny", "dots"): dict(toks=(4, 65)),
     ("qwen3_next_tiny", "full"): dict(toks=(4, 65)),
+    ("laguna_tiny", "dots"): dict(toks=(4, 65)),
 }
 
 
@@ -96,7 +97,8 @@ def test_train_step_returns_the_counters_and_folds_them(preset, policy):
 # The leaves of the benchmark's comparison the flash path is held to: a
 # windowed layer's and a full one's; the latent layer's two projections.
 FLASH_LEAVES = {"mellum2_tiny": ("swa_wkv", "full_wq"),
-                "kanana2_tiny": ("mla_wq", "mla_wkva")}
+                "kanana2_tiny": ("mla_wq", "mla_wkva"),
+                "laguna_tiny": ("swa_wkv", "swa_gate", "full_wq")}
 
 
 def _rel(a, b):

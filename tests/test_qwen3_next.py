@@ -295,7 +295,7 @@ def test_partial_rotation_and_the_gated_norm(case):
         got[..., :8], tfm._rope(x[..., :8], pos, cfg.rope_theta))
     np.testing.assert_array_equal(tfm._rope_first(x, 32, pos, 1e4),
                                   tfm._rope(x, pos, 1e4))
-    assert cfg.rope_rotated == 8 and cfg.head_dim == 32
+    assert cfg.attn_rope("attn")[1] == 8 and cfg.head_dim == 32
     layer = tfm.layer_params(case["params"], cfg, 3)
     h = jax.random.normal(jax.random.key(5), (2, 48, cfg.d_model))
     attn = tfm.MIXERS["attn"].apply
