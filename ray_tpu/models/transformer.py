@@ -107,26 +107,34 @@ class TransformerConfig:
     param_dtype: Any = jnp.float32
     remat: bool = False
     # Remat granularity when remat=True, every layer alike. The rule of
-    # "full": a forward kernel whose cost is quadratic in the sequence for an
-    # output linear in it is kept; the linear-time cores (KDA, SSD) and every
-    # product are recomputed.
-    # - "full": save the layer's input and the flash kernel's two outputs (o
-    #   and lse, RESIDUAL_NAMES of ops/flash_attention.py) and recompute the
-    #   rest of the layer body in the backward: projections, rotations,
-    #   norms, the KDA / SSD cores, the experts. What a sizing sweep falls
-    #   back to when "dots" does not fit (kanana_2_30b_a3b.train_rank8_16k,
-    #   one sequence of 16,384, one v5e chip: step 977.8 -> 857.9 ms, step
-    #   memory 14.34 -> 15.13 GB; chip runs of PR 40, PERF.md section 6).
-    # - "dots": save matmul outputs and the kernels' own residuals (flash: o
-    #   [B,H,S,hd] and lse [B,H,S]; KDA: o, chunk states, inverses; SSD: y,
-    #   chunk states; a held range of experts: the first window's two grouped
-    #   products; named in their forward rules, RESIDUAL_NAMES of
-    #   ops/flash_attention.py, ops/kda.py, ops/ssd.py and ops/moe.py);
-    #   recompute the rest. A forward kernel runs once a layer.
-    # Default "dots": keeping o and lse takes the second forward-kernel call
-    # out of every layer's backward (gpt2_124m, batch 16 x 1024, one v5e
-    # chip: step 184.2 -> 179.5 ms, step memory 13.96 -> 15.19 GB; chip
-    # runs of PR 26, PERF.md section 6).
+    # "full": what XLA makes is recomputed, what a hand-written kernel's
+    # forward rule names is kept, so the backward re-runs no such kernel.
+    # - "full": save the layer's input and the kernels' own residuals (flash:
+    #   o [B,H,S,hd] and lse [B,H,S]; KDA / DeltaNet: o, chunk states,
+    #   inverses; SSD: y, chunk states; a held range of experts: the first
+    #   window's two grouped products; named in their forward rules,
+    #   RESIDUAL_NAMES of ops/flash_attention.py, ops/kda.py, ops/ssd.py and
+    #   ops/moe.py) and recompute the rest of the layer body in the backward:
+    #   projections, rotations, norms, convolutions, routing, every product.
+    #   What a sizing sweep falls back to when "dots" does not fit. What the
+    #   kept residuals cost a layer at one sequence of 16,384 on one v5e chip
+    #   (compiled for a v5e, PR 46): +0.14 GB a DeltaNet layer and +0.24 GB an
+    #   expert layer (qwen3_next_80b_a3b.train_rank16_16k), +0.46 GB an expert
+    #   layer (kanana_2_30b_a3b.train_rank8_16k); a program that fitted under
+    #   "full" by less no longer fits, and there is no smaller policy. What
+    #   they buy is a kernel's second forward call less a copy of each
+    #   residual into the layer scan's stack and one out (chip runs, PERF.md
+    #   section 6): the flash outputs 120 ms a step in kanana (PR 40), the
+    #   delta-rule core's 4 ms and the experts' 1 ms a step in qwen3_next
+    #   (PR 46; the experts' nothing in kanana).
+    # - "dots": also save matmul outputs and, under a `tensor` axis, the
+    #   products inside a ring (RESIDUAL_NAMES of parallel/tensor_overlap.py);
+    #   recompute the rest.
+    # Under either a forward kernel runs once a layer. Default "dots": keeping
+    # o and lse takes the second forward-kernel call out of every layer's
+    # backward (gpt2_124m, batch 16 x 1024, one v5e chip: step 184.2 -> 179.5
+    # ms, step memory 13.96 -> 15.19 GB; chip runs of PR 26, PERF.md section
+    # 6).
     remat_policy: str = "dots"
     # RMSNorm / LayerNorm epsilon; None = 1e-6 / 1e-5 (what was hard-coded).
     norm_eps: Optional[float] = None
@@ -1240,19 +1248,17 @@ def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
         return body
     from ray_tpu.ops import flash_attention as fa, kda, moe, ssd
 
-    # "full" keeps the one forward kernel that is quadratic in S for outputs
-    # linear in it. "dots" also keeps what no dot makes: the other kernels'
-    # residuals, the held experts' grouped products (`ragged_dot` is no
-    # `dot_general`) and the products inside a ring over `tensor` (a
-    # `custom_vjp` hides its dots).
-    names = fa.RESIDUAL_NAMES
+    # "full" recomputes what XLA makes and keeps what a hand-written kernel's
+    # forward rule names, so the backward re-runs no such kernel. "dots" also
+    # keeps every dot and the products inside a ring over `tensor` (a
+    # `custom_vjp` hides its dots; they are products, not kernels).
+    dots = cfg.remat_policy == "dots"
+    names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
+             + moe.RESIDUAL_NAMES + (tp.RESIDUAL_NAMES if dots else ()))
     policy = jax.checkpoint_policies.save_only_these_names(*names)
-    if cfg.remat_policy == "dots":
-        names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
-                 + moe.RESIDUAL_NAMES + tp.RESIDUAL_NAMES)
+    if dots:
         policy = jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names(*names))
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable, policy)
     tracing.observe("train.remat", 0, slow=False, policy=cfg.remat_policy,
                     kept=",".join(names))
     return jax.checkpoint(body, policy=policy)

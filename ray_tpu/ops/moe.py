@@ -241,8 +241,9 @@ FURTHER_WINDOW_SHARE = 0.5
 
 # The first window's grouped products' outputs (gate/up [W, 2F], down [W, d])
 # carry these names in the forward rule, so that a remat policy can keep them
-# (`save_only_these_names(*RESIDUAL_NAMES)`, models/transformer.py "dots"):
-# the backward then gathers no rows and runs no product a second time.
+# (`save_only_these_names(*RESIDUAL_NAMES)`, models/transformer.py
+# `layer_scan_body`, under either policy): the backward then gathers no rows
+# and runs no product a second time.
 RESIDUAL_NAMES = ("moe_gate_up", "moe_down")
 
 

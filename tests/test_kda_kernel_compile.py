@@ -197,3 +197,43 @@ def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
     assert text.count(" custom-call(") >= 6
     assert len(re.findall(r"%t?gmm[.\d]* = ", text)) == 6, text.count("gmm")
     assert "ragged-dot" in text
+
+
+@pytest.mark.parametrize("mixer", ["gdn", "kda"])
+def test_full_remat_reruns_no_core_kernel_for_v5e(
+        one_chip, compiled_not_interpreted, monkeypatch, mixer):
+    """The mechanism of remat "full" itself: the gradient of one
+    checkpointed delta-rule layer with a dense feed-forward (`layer_scan_body`,
+    what the stack scans), 1 x 512 tokens, heads of 128, chunks of 128,
+    bfloat16, holds two Mosaic calls, the core's forward and its backward:
+    "full" keeps `kda.RESIDUAL_NAMES`, so the backward's recomputation of
+    the layer body does not run the forward kernel again (a third call)."""
+    from ray_tpu.models import configs, transformer as tfm
+
+    rule = kda.use_kernels  # the platform here is cpu
+    monkeypatch.setattr(kda, "use_kernels", lambda _, *a: rule("tpu", *a))
+    kw = dict(n_layers=1, d_model=256, d_ff=512, max_seq_len=512,
+              moe_num_experts=0, moe_held=None, moe_shared_experts=0,
+              remat=True, remat_policy="full")
+    if mixer == "gdn":
+        cfg = configs.qwen3_next_tiny(gdn_head_dim=128, gdn_chunk=128,
+                                      moe_shared_gate=False, **kw)
+    else:
+        cfg = configs.kimi_linear_tiny(kda_head_dim=128, kda_chunk=128, **kw)
+    kind = cfg.layer_kinds()[0]
+    assert kind == (mixer, "dense")
+    B, S = 1, 512
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    layer = jax.tree.map(sd, jax.eval_shape(
+        lambda key: tfm.layer_params(tfm.init_params(key, cfg), cfg, 0),
+        jax.random.key(0)))
+    x = sd(jax.ShapeDtypeStruct((B, S, cfg.d_model), cfg.dtype))
+
+    def loss(x, layer):
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        body = tfm.layer_scan_body(cfg, kind, positions)
+        return jnp.sum(body(x, layer)[0].astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, layer).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

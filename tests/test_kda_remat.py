@@ -1,8 +1,9 @@
 """What a remat policy keeps of the delta-rule kernels (ops/kda.py) in a
 traced stack, on the CPU through the kernels in interpret mode: a KDA stack
 under both policies, and a Gated DeltaNet stack (the scalar-decay kernels)
-under the policy its cell runs. The cores themselves are tests/test_kda.py
-and tests/test_kda_scalar.py."""
+under the policy its cell runs. Either policy keeps the kernels' named
+residuals, so the forward kernel runs once a layer. The cores themselves are
+tests/test_kda.py and tests/test_kda_scalar.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,14 +32,12 @@ def _kernel_calls(jaxpr, times=1, out=None):
     return out
 
 
-@pytest.mark.parametrize("policy,fwd_calls_per_layer",
-                         [("dots", 1), ("full", 2)])
-def test_remat_dots_keeps_the_kda_kernel_residuals(monkeypatch, policy,
-                                                   fwd_calls_per_layer):
-    """The traced gradient of a KDA stack through the kernels: under "dots"
-    the forward kernel (6 in / 4 out) runs once a layer, its o, states and
-    inverses being named residuals; under "full" twice. The backward kernel
-    (9 in / 6 out) once. Neither has a flash kernel's signature
+@pytest.mark.parametrize("policy", ["dots", "full"])
+def test_remat_keeps_the_kda_kernel_residuals(monkeypatch, policy):
+    """The traced gradient of a KDA stack through the kernels: under either
+    policy the forward kernel (6 in / 4 out) runs once a layer, its o,
+    states and inverses being named residuals. The backward kernel (9 in /
+    6 out) once. Neither has a flash kernel's signature
     (chipbench/reduce/xplane.py names kernels by it). Gradients are those
     of the XLA body. Two layers, a dense one and an expert one: as many as
     "a layer" needs."""
@@ -53,7 +52,7 @@ def test_remat_dots_keeps_the_kda_kernel_residuals(monkeypatch, policy,
     g_xla = jax.jit(grad())(params)
     monkeypatch.setattr(kda, "use_kernels", lambda *a, **kw: True)
     calls = _kernel_calls(jax.make_jaxpr(grad())(params).jaxpr)
-    assert calls == {"6in_4out": 2 * fwd_calls_per_layer, "9in_6out": 2}
+    assert calls == {"6in_4out": 2, "9in_6out": 2}
     if policy == "dots":
         for a, b in zip(jax.tree.leaves(jax.jit(grad())(params)),
                         jax.tree.leaves(g_xla)):
@@ -64,9 +63,9 @@ def test_remat_dots_keeps_the_kda_kernel_residuals(monkeypatch, policy,
 def test_the_stack_takes_the_scalar_kernels_under_full_remat(monkeypatch):
     """A scanned stack's gradient through the kernels (interpret mode) under
     the cell's remat policy: two DeltaNet layers, one scan, run the scalar
-    forward kernel twice each (6 in / 4 out; `full` keeps no residual of
-    the core: ROADMAP D3) and the backward once (9 in / 6 out), every call
-    reading g as [B, S, H_v]; the gradients are the XLA body's."""
+    forward kernel once each (6 in / 4 out; `full` keeps the core's named
+    residuals) and the backward once (9 in / 6 out), every call reading g
+    as [B, S, H_v]; the gradients are the XLA body's."""
     cfg = qwen3_next_tiny(n_layers=2, remat=True, remat_policy="full",
                           dtype=jnp.float32)
     assert cfg.stack_plan() == (((("gdn", "moe"),), 2),)
@@ -79,7 +78,7 @@ def test_the_stack_takes_the_scalar_kernels_under_full_remat(monkeypatch):
     monkeypatch.setattr(kda, "use_kernels", lambda *a, **kw: True)
     jaxpr = jax.make_jaxpr(grad())(params).jaxpr
     calls = _kernel_calls(jaxpr)
-    assert calls["6in_4out"] == 4 and calls["9in_6out"] == 2, calls
+    assert calls["6in_4out"] == 2 and calls["9in_6out"] == 2, calls
     for call in _outer_avals(jaxpr)[1]:
         if len(call.invars) in (6, 9):  # the core's, not flash's
             assert call.invars[3].aval.shape == (2, 32, cfg.gdn_v_heads)

@@ -235,35 +235,61 @@ def test_remat_keeps_flash_residuals_through_shard_map(monkeypatch, policy):
         "6in_2out": cfg.n_layers}
 
 
+# The module whose forward rule names a mixer kind's kernel residuals.
+_MIXER_KERNEL = {"attn": "flash_attention", "swa": "flash_attention",
+                 "mla": "flash_attention", "kda": "kda", "gdn": "kda",
+                 "mamba2": "ssd"}
+
+
 @pytest.mark.parametrize("preset", [
     "llama_tiny", "moe_tiny", "kimi_linear_tiny", "kanana2_tiny",
-    "granite_hybrid_tiny", "mellum2_tiny"])
-def test_remat_full_saves_only_the_flash_outputs(monkeypatch, preset):
-    """What keeps "full" the small-memory policy: a layer of any kind keeps
-    its input and, where its mixer runs the flash kernel, o [B,H,S,hd] and
-    lse [B,H,S]; no dot output, and nothing the KDA and SSD cores or the held
-    experts name ("dots" keeps those)."""
-    from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+    "granite_hybrid_tiny", "mellum2_tiny", "qwen3_next_tiny"])
+def test_remat_full_keeps_what_a_layers_kernels_name(monkeypatch, preset):
+    """What "full" keeps a layer kind: its input and what a hand-written
+    kernel's forward rule names, so the backward re-runs no such kernel. An
+    `attn` / `swa` / `mla` layer keeps o [B,H,S,hd] and lse [B,H,S]; a `kda`
+    / `gdn` layer the three `kda_*` residuals, a `mamba2` layer the two
+    `ssd_*`, a layer with held experts the two `moe_*` beside its mixer's;
+    no layer keeps a dot's output (what still makes "full" the small
+    policy), and "dots" keeps strictly more."""
+    from ray_tpu.ops import flash_attention, kda, moe, ssd
 
     monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
+    ops = dict(flash_attention=flash_attention, kda=kda, ssd=ssd, moe=moe)
+    for core in (kda, ssd):  # the cores' kernels, where the names are
+        monkeypatch.setattr(core, "use_kernels", lambda *a, **kw: True)
+    # jax puts a reduce_precision behind a residual that the forward pass
+    # also uses, which hides the first name (o, y): a save is placed by
+    # where it was made, as in test_remat_dots_saved_residuals.
+    made_in = lambda why: {n for n in ops if f"/ops/{n}.py:" in why}
     cfg = getattr(configs, preset)(remat=True, remat_policy="full")
-    kinds = cfg.layer_kinds()
-    B, S, H = 2, 32, cfg.n_heads
-    for index in sorted({kinds.index(k) for k in kinds}):
-        saved = _layer_saves(cfg, index, B, S)
-        if kinds[index][0] not in ("attn", "swa", "mla"):
-            assert saved == [], kinds[index]
-            continue
-        # jax puts a reduce_precision behind a residual that the forward
-        # pass also uses, which hides o's name: o is found by where it was
-        # made, as in test_remat_dots_saved_residuals.
-        assert all("flash_attention" in why for _, _, why in saved), saved
-        lse, o = sorted(saved, key=lambda r: len(r[0]))
-        assert f"'{RESIDUAL_NAMES[1]}'" in lse[2]
-        assert lse[:2] == ((B, H, S), "float32")
-        assert o[0][:3] == (B, H, S) and o[1] == "bfloat16"
     dots = getattr(configs, preset)(remat=True, remat_policy="dots")
-    assert len(_layer_saves(dots, 0, B, S)) > 2  # the check can see a save
+    kinds = cfg.layer_kinds()
+    B, S = 2, 32
+    for index in sorted({kinds.index(k) for k in kinds}):
+        mixer, ffn = kinds[index]
+        want = {_MIXER_KERNEL[mixer]}
+        if ffn == "moe" and cfg.moe_holds_range:
+            want.add("moe")
+        saved = _layer_saves(cfg, index, B, S)
+        assert all(made_in(why) for _, _, why in saved), (kinds[index], saved)
+        assert not [why for _, _, why in saved if "dot_general" in why]
+        for n in ops:
+            rows = [r for r in saved if made_in(r[2]) == {n}]
+            names = ops[n].RESIDUAL_NAMES
+            assert len(rows) == (len(names) if n in want else 0), (
+                kinds[index], n, rows)
+            if n in want:  # the first (o, y) may be the hidden one
+                assert all(any(f"'{name}'" in r[2] for r in rows)
+                           for name in names[1:]), rows
+        if "flash_attention" in want:
+            lse, o = sorted((r for r in saved if "flash_attention" in r[2]),
+                            key=lambda r: len(r[0]))
+            H = cfg.attn_heads(mixer)
+            assert f"'{flash_attention.RESIDUAL_NAMES[1]}'" in lse[2]
+            assert lse[:2] == ((B, H, S), "float32")
+            assert o[0][:3] == (B, H, S) and o[1] == "bfloat16"
+        assert len(_layer_saves(dots, index, B, S)) > len(saved)
 
 
 def test_remat_full_saves_no_ring_product(monkeypatch):
@@ -287,9 +313,8 @@ def test_remat_full_saves_no_ring_product(monkeypatch):
 
 def test_remat_counter_is_one_a_checkpointed_body(monkeypatch):
     """`train.remat`: one observation a checkpointed layer body built, with
-    the policy and the names it keeps; none without remat."""
-    from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
-
+    the policy and the names it keeps ("full": every kernel's; "dots": those
+    and a ring's products, beside every dot); none without remat."""
     seen = []
     real = tracing.observe
     monkeypatch.setattr(tracing, "observe", lambda name, ns, **kw: (
@@ -304,10 +329,11 @@ def test_remat_counter_is_one_a_checkpointed_body(monkeypatch):
         tfm.layer_scan_body(cfg, cfg.layer_kinds()[0], positions)
     rows = [kw for name, kw in seen if name == "train.remat"]
     assert count() == before + 2
-    assert rows[0] == dict(slow=False, policy="full",
-                           kept=",".join(RESIDUAL_NAMES))
-    assert rows[1]["policy"] == "dots"
-    assert rows[1]["kept"].startswith(",".join(RESIDUAL_NAMES) + ",kda_o")
+    kernels = ("flash_o,flash_lse,kda_o,kda_states,kda_tinv,ssd_y,ssd_states,"
+               "moe_gate_up,moe_down")
+    assert rows[0] == dict(slow=False, policy="full", kept=kernels)
+    assert rows[1] == dict(slow=False, policy="dots",
+                           kept=kernels + ",tp.gathered,tp.scattered")
 
 
 def test_remat_dots_saved_residuals(monkeypatch):
