@@ -5,8 +5,8 @@ how long does each kernel take at each tile size.
 Shapes: the serving prefill buckets ([1, S, 16, 128] for S = 8 .. 2048,
 forward only, the table's tiles) and the three training cells' shapes
 (`CELLS`: forward and gradient against the reference at the table's tiles,
-then forward, dQ and dK/dV timed one by one for every (block, strip) of
-`SWEEP`). The table `_TILES` of ops/flash_attention.py is filled from the
+then forward, the fused backward, dQ and dK/dV timed one by one for every
+(block, strip) of `SWEEP`). The table `_TILES` of ops/flash_attention.py is filled from the
 sweep's lines. One JSON line per case; a case that fails to compile or
 disagrees is reported with its error and makes the exit status 1. Off the
 chip the script fails at once.
@@ -21,6 +21,10 @@ chip the script fails at once.
     python3 benchmarks/probe_flash.py band512    # the same for `BAND512`
         # (a window a quarter of the table's block, groups of 9), and the
         # full layers' causal call beside it at every (block, strip)
+    python3 benchmarks/probe_flash.py fused [cell ...]   # `FUSED`: each
+        # cell's attention kinds checked against the reference with the
+        # fused backward, then forward / fused backward / dQ / dK/dV timed
+        # at the table's row and its neighbours
     python3 benchmarks/probe_flash.py gated      # `GATED`, heads of 256 at
         # 8:1: checked at GATED_CHECK_S positions at the table's tiles and
         # at every (block, strip) of `GATED_SWEEP`, then timed at 16,384
@@ -67,6 +71,30 @@ GATED = ("qwen3_next_gattn", 1, 16384, 16, 2, 256, 256)
 GATED_CHECK_S = 4096
 GATED_SWEEP = [(512, 128), (512, 256), (512, 512), (1024, 128), (1024, 256),
                (1024, 512), (2048, 128), (2048, 256), (2048, 512)]
+# (name, B, S, H, KVH, D, Dv, window, check S, [(block, strip)]): every
+# cell's attention kinds, the fused backward beside the pair at the table's
+# row (first) and at its neighbours.
+FUSED = [
+    ("kanana_mla", 1, 16384, 32, 32, 192, 128, None, 4096,
+     [(1024, 256), (1024, 128), (1024, 512), (512, 256), (2048, 256)]),
+    ("mellum2_full", 1, 16384, 32, 4, 128, 128, None, 4096,
+     [(2048, 256), (1024, 256), (2048, 512), (2048, 128)]),
+    ("mellum2_swa", 1, 16384, 32, 4, 128, 128, 1024, 4096,
+     [(2048, 256), (1024, 256), (2048, 128), (1024, 128)]),
+    ("qwen3_next_gattn", 1, 16384, 16, 2, 256, 256, None, 4096,
+     [(1024, 512), (1024, 256), (512, 256), (512, 512)]),
+    ("laguna_swa", 1, 8192, 36, 4, 128, 128, 512, 2048,
+     [(2048, 256), (2048, 128), (1024, 128), (1024, 256)]),
+    ("laguna_full", 1, 8192, 24, 4, 128, 128, None, 2048,
+     [(2048, 256), (1024, 256)]),
+    ("gpt2", 16, 1024, 12, 12, 64, 64, None, 1024,
+     [(1024, 256), (1024, 128), (512, 256), (1024, 512)]),
+    ("granite_gqa64", 1, 4096, 32, 8, 64, 64, None, 4096,
+     [(1024, 256), (1024, 128)]),
+    ("internlm2_shard", 4, 2048, 8, 4, 128, 128, None, 2048,
+     [(2048, 256), (1024, 256), (2048, 512)]),
+    ("kimi_mla", 1, 8192, 32, 32, 192, 128, None, 4096, [(1024, 256)]),
+]
 SWEEP = [(b, s) for b in (512, 1024, 2048, 4096) for s in (128, 256, 512)]
 SWEEP += [(b, b) for b in (512, 1024)]  # no strips: the split of tiles alone
 # Flash and reference see the same bf16 inputs; flash rounds P to bf16 before
@@ -145,9 +173,11 @@ def check(B, S, H, KVH, D, Dv, grad, window=None, block=None, sub=None):
     return row, ok
 
 
-def kernel_ms(B, S, H, KVH, D, Dv, block, sub, window=None):
-    """Forward, dQ and dK/dV alone (XLA drops the kernel whose results a
-    program does not return), in model layout's transposed form."""
+def kernel_ms(B, S, H, KVH, D, Dv, block, sub, window=None, steps=30):
+    """Forward, the fused backward, and the pair it replaces, dQ and dK/dV
+    alone (XLA drops the kernel whose results a program does not return;
+    a budget of 0 sends any shape to the pair), in model layout's
+    transposed form."""
     q, k, v = (jnp.swapaxes(x, 1, 2) for x in _qkv(B, S, H, KVH, D, Dv))
     scale = D ** -0.5
     tiles = dict(block_q=block, block_k=block, sub=sub, window=window)
@@ -155,12 +185,25 @@ def kernel_ms(B, S, H, KVH, D, Dv, block, sub, window=None):
     o, lse = fwd(q, k, v)
     do = jnp.ones_like(o)
     delta = jnp.sum(o.astype(jnp.float32), axis=-1)
-    bwd = lambda pick: jax.jit(lambda *a: pick(fa.flash_bwd_core(
-        *a, scale=scale, causal=True, **tiles)))
     args = (q, k, v, do, lse, delta)
-    return {"fwd_ms": round(_time(fwd, (q, k, v)), 4),
-            "dq_ms": round(_time(bwd(lambda g: g[0]), args), 4),
-            "dkv_ms": round(_time(bwd(lambda g: g[1:]), args), 4)}
+
+    def bwd(pick, budget):
+        was, fa.FUSED_DQ_VMEM_BUDGET = fa.FUSED_DQ_VMEM_BUDGET, budget
+        try:  # the rule is read where the call is traced
+            return jax.jit(lambda *a: pick(fa.flash_bwd_core(
+                *a, scale=scale, causal=True, **tiles))).lower(
+                    *args).compile()
+        finally:
+            fa.FUSED_DQ_VMEM_BUDGET = was
+
+    row = {"bwd": fa.bwd_kind(S, D, Dv, q.dtype, block, block, sub),
+           "fwd_ms": round(_time(fwd, (q, k, v), steps), 4)}
+    if row["bwd"] == "fused":
+        row["bwd_fused_ms"] = round(_time(
+            bwd(lambda g: g, fa.FUSED_DQ_VMEM_BUDGET), args, steps), 4)
+    row["dq_ms"] = round(_time(bwd(lambda g: g[0], 0), args, steps), 4)
+    row["dkv_ms"] = round(_time(bwd(lambda g: g[1:], 0), args, steps), 4)
+    return row
 
 
 def main(argv) -> int:
@@ -203,6 +246,20 @@ def main(argv) -> int:
             report({"cell": name, "block": block, "sub": sub, "window": None},
                    lambda *a: (kernel_ms(*a), True),
                    B, S, full_h, KVH, D, Dv, block, sub)
+        return 1 if failed else 0
+    if argv[1:2] == ["fused"]:
+        for name, B, S, H, KVH, D, Dv, window, check_s, sweep in FUSED:
+            if argv[2:] and name not in argv[2:]:
+                continue
+            h = 2 * H // KVH if H > KVH else CHECK_HEADS
+            report({"cell": name, "bwd": fa.bwd_kind(check_s, D, Dv,
+                                                     jnp.bfloat16)},
+                   check, 1, check_s, h, h * KVH // H, D, Dv, True, window)
+            for block, sub in sweep:
+                report({"cell": name, "block": block, "sub": sub,
+                        "window": window},
+                       lambda *a: (kernel_ms(*a, steps=10), True),
+                       B, S, H, KVH, D, Dv, block, sub, window)
         return 1 if failed else 0
     if argv[1:] == ["gated"]:
         name, B, S, H, KVH, D, Dv = GATED

@@ -13,11 +13,21 @@ statistics (`lse`, `delta`) are [B, H, S] float32 and cross the kernels as
 (1, block) blocks, the sequence on the lanes.
 
 Grid convention: the innermost grid dimension is the contraction over KV (or
-Q, in the dk/dv kernel) blocks; TPU grids execute sequentially so VMEM
+Q, in the backward kernel) blocks; TPU grids execute sequentially so VMEM
 scratch accumulators carry across it ("arbitrary" dimension semantics), and
 outputs are flushed on the last inner step.
 
-A tile's work, in all three kernels alike (`_tile_class`, `tile_plan`): a grid
+The backward is one kernel (`_dkv_kernel` with dQ's output and accumulator;
+`bwd_kind` says "fused"): for a (batch, head) it walks every key block and
+the query blocks that see it, computes S^T, dP^T and the exponential once a
+cell and takes dV, dK and dQ from them, five products. dQ = dS^T (transposed
+on the left) K is added into a float32 accumulator that holds the whole head
+in VMEM across the key blocks, zeroed at the head's first grid step and
+written out once at its last. A head whose accumulator and output block pass
+`FUSED_DQ_VMEM_BUDGET` takes the two kernels the fused one replaced (`_dq_kernel`
+beside `_dkv_kernel` without dQ: "split"; S and dP are then computed twice).
+
+A tile's work, in all the kernels alike (`_tile_class`, `tile_plan`): a grid
 tile wholly above the diagonal is *skipped* (no compute, and its index maps
 stay on the last needed block, so nothing is fetched for it); one wholly
 below it and inside the sequence is *interior* and runs with no mask at all;
@@ -101,6 +111,31 @@ DEFAULT_BLOCK = 512
 # still gains (13.5 ms) and both backward kernels lose a factor of 1.9 (VMEM
 # pushes back), so the block is 1,024; the strip of 512 is 0.2 ms ahead of
 # 256 and unrolls half as many strips in the trace.
+# The one backward kernel (`probe_flash.py fused` on a v5e, chip runs PR 47,
+# `p47a` / `p47d`; ms a call: forward / fused backward | dQ + dK/dV, the pair
+# it replaced, at the same tiles; the table's row first):
+#   [1,16384,32,192|128]  1024/256 26.48 / 51.59 | 34.48 + 38.56
+#       1024/128 31.25 / 52.50 | 1024/512 24.67 / 52.08 | 512/256 36.17 /
+#       58.52 | 2048/256 23.72 / 94.95
+#   [1,16384,32|4,128]    2048/256 16.33 / 30.08 | 18.70 + 24.04
+#       1024/256 19.12 / 31.95 | 2048/512 15.91 / 30.44 | 2048/128 18.34 / 30.60
+#   the same, window 1,024: 2048/256 3.64 / 5.68 | 3.06 + 4.38
+#       1024/256 4.34 / 6.07 | 2048/128 3.88 / 5.84 | 1024/128 4.48 / 6.15
+#   [1,16384,16|2,256]    1024/512 14.68 / 30.91 | 19.37 + 24.83
+#       1024/256 15.50 / 30.57 | 512/256 20.27 / 34.21 | 512/512 18.53 / 34.54
+#   [16,1024,12,64]       1024/256 1.376 / 1.883 | 1.137 + 1.393
+#       1024/128 1.159 / 1.850 | 512/256 1.577 / 2.009 | 1024/512 1.304 / 1.985
+#   [1,8192,36|4,128], window 512: 2048/256 1.94 / 2.36 | 1.32 + 1.74
+#       2048/128 1.71 / 2.39 | 1024/128 1.85 / 2.52 | 1024/256 2.07 / 2.39
+#   [1,8192,24|4,128]     2048/256 3.37 / 5.91 | 3.74 + 4.71 | 1024/256 3.95 / 6.20
+#   [1,4096,32|8,64]      1024/256 1.51 / 2.46 | 1.48 + 1.88 | 1024/128 1.77 / 2.51
+#   [4,2048,8|4,128]      2048/256 0.432 / 0.647 | 0.372 + 0.511
+#       1024/256 0.495 / 0.712 | 2048/512 0.450 / 0.687
+#   [1,8192,32,192|128]   1024/256 7.46 / 14.01 | 9.68 + 10.45
+# The fused kernel takes 0.70-0.77 of the pair at every shape and its best
+# tiles are the pair's: no row of its own. (Heads of 256 at strip 256 are 0.3
+# ms ahead in the backward and 0.8 behind in the forward; head 64 at strip 128
+# is 0.25 ms a layer ahead and costs set-up, as above.)
 _TILES = {
     (64, 64): (1024, 256),
     (128, 128): (2048, 256),
@@ -148,12 +183,17 @@ def _tile_class(iq, ik, *, bq, bk, seq_len, causal, ragged, window=None):
     traced program ids. `ragged` names the side whose padded last block
     needs a mask: "k" in the forward and dQ kernels (padded keys would enter
     every row's softmax), "q" in the dK/dV kernel (padded queries would add
-    to every key's gradient). With a `window`, a tile wholly below the band
+    to every key's gradient), "qk" in the fused backward (both). With a
+    `window`, a tile wholly below the band
     (or, on a banded grid, past the last block) is skipped too, and one the
     band's lower boundary cuts is an edge."""
     row0, col0 = iq * bq, ik * bk
-    end = col0 + bk if ragged == "k" else row0 + bq
-    inside, outside = end <= seq_len, end > seq_len
+    if ragged == "qk":
+        inside = (col0 + bk <= seq_len) & (row0 + bq <= seq_len)
+        outside = (col0 + bk > seq_len) | (row0 + bq > seq_len)
+    else:
+        end = col0 + bk if ragged == "k" else row0 + bq
+        inside, outside = end <= seq_len, end > seq_len
     if not causal:
         return False, inside, outside
     last_row = row0 + (bq - 1)
@@ -298,16 +338,46 @@ def tile_plan(seq_len, bq, bk, sub, causal, window=None) -> TilePlan:
                     skipped)
 
 
-def _compiler_params(bq, bk, sub, D, Dv, itemsize):
+# What the fused backward may hold in VMEM for dQ beside the kernel's
+# present need: the head's float32 accumulator and dQ's double-buffered
+# whole-head output block. 64 MiB is S x D (D in whole lanes) of 8 M
+# elements in a 2-byte dtype; the widest cell (kanana: 16,384 x 192, held
+# in 256 lanes) takes 16 + 16 MiB. A v5e core has 128 MiB.
+FUSED_DQ_VMEM_BUDGET = 64 << 20
+
+
+def _dq_head_bytes(S, bq, D, itemsize):
+    """VMEM the fused backward holds for one head's dQ."""
+    lanes = -(-D // 128) * 128
+    return 4 * pl.cdiv(S, bq) * bq * lanes + 2 * itemsize * S * lanes
+
+
+def bwd_kind(S, D, Dv, dtype, block_q=None, block_k=None, sub=None) -> str:
+    """Which backward a call's shapes take: "fused" (one kernel: dK, dV and
+    dQ from one S, dP and exponential) where a head's dQ fits
+    `FUSED_DQ_VMEM_BUDGET`, "split" (the dQ kernel and the dK/dV kernel)
+    beyond it."""
+    itemsize = jnp.dtype(dtype).itemsize
+    bq = tile_sizes(S, D, Dv, dtype, block_q, block_k, sub)[0]
+    fits = _dq_head_bytes(S, bq, D, itemsize) <= FUSED_DQ_VMEM_BUDGET
+    return "fused" if fits else "split"
+
+
+def _compiler_params(bq, bk, sub, D, Dv, itemsize, dq_head_bytes=0):
     """Grid semantics, and room in VMEM for blocks past the compiler's 16 MiB
     of scoped stack: the double-buffered blocks of the widest kernel
     (dK/dV), its accumulators, and half a dozen [sub, block] float32
-    temporaries. A v5e core has 128 MiB."""
+    temporaries; in the fused backward a head's dQ besides
+    (`dq_head_bytes`), which crosses the key blocks: that grid dimension is
+    then sequential too. A v5e core has 128 MiB."""
     blocks = 2 * itemsize * (2 * bq * (D + Dv) + 2 * bk * (D + Dv))
     need = blocks + 4 * bk * (D + Dv) + 6 * 4 * sub * max(bq, bk)
-    limit = min(2 * need, 100 << 20) if need > (12 << 20) else None
+    limit = None
+    if need + dq_head_bytes > (12 << 20):
+        limit = min(2 * need + dq_head_bytes, 100 << 20)
+    outer = "arbitrary" if dq_head_bytes else "parallel"
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", outer, "arbitrary"),
         vmem_limit_bytes=limit)
 
 
@@ -332,6 +402,13 @@ def _dot_nn(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _dot_tn(a, b):
+    """[n, m] x [n, d] -> [m, d], f32 accumulation: the left operand is
+    contracted along its rows (dQ from dS^T as the dK/dV kernel holds it)."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _folds(scale) -> bool:
     """A power-of-two scale (D = 64: 0.125) multiplies `q` exactly in any
     float type, so it leaves the [bq, bk] score tile for the [bq, D] block:
@@ -344,15 +421,17 @@ def _scaled(x, scale):
 
 
 def _edge_mask(iq, ik, bq, bk, seq_len, causal, queries_on_rows,
-               window=None):
+               window=None, both=False):
     """The mask of a whole edge tile from its place in the sequence:
     [bq, bk] (forward, dQ: keys inside the sequence) or [bk, bq] (dK/dV:
-    queries inside it), under the diagonal if causal, inside the band if
-    windowed."""
+    queries inside it; `both` where that kernel also makes dQ), under the
+    diagonal if causal, inside the band if windowed."""
     shape, q_dim = ((bq, bk), 0) if queries_on_rows else ((bk, bq), 1)
     rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
     cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     valid = (cols if queries_on_rows else rows) < seq_len
+    if both:
+        valid = valid & ((rows if queries_on_rows else cols) < seq_len)
     if window is not None:
         valid = valid & (rows - cols < window)
     return valid & (rows >= cols) if causal else valid
@@ -528,6 +607,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None,
     nq = pl.cdiv(S, bq)
     nk = pl.cdiv(S, bk)
     tracing.observe("flash.plan", 0, slow=False, window=window or 0,
+                    bwd=bwd_kind(S, D, Dv, q.dtype, block_q, block_k, sub),
                     **tile_plan(S, bq, bk, sub, causal, window)._asdict())
     kv = _kv_block(causal, bq, bk, window, nk)
     params = _compiler_params(bq, bk, sub, D, Dv, q.dtype.itemsize)
@@ -637,27 +717,50 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, bq, bk, seq_len, sub, window):
+                dk_ref, dv_ref, *rest,
+                scale, causal, bq, bk, seq_len, sub, window):
+    """dK and dV of one key block over the query blocks that see it and,
+    where the call is the fused backward (`rest` then starts with dQ's
+    whole-head output block and ends with its float32 accumulator), dQ
+    too: dS^T is in registers here, so dQ costs one more product and no
+    second S, dP or exponential. The accumulator holds every query row of
+    the head across the head's key blocks (grid dimension 2 is then
+    "arbitrary"), is zeroed at the head's first step and written out once,
+    scaled and cast, at its last."""
+    fused = len(rest) == 4
+    dq_ref, dk_scr, dv_scr, dq_scr = rest if fused else (None, *rest, None)
     iq, ik, step_i, steps = _grid_place(bq, bk, seq_len, window, False)
     fold = _folds(scale)
     when_interior, when_edge = _tile_bodies(
-        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="q",
-        window=window)
+        iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal,
+        ragged="qk" if fused else "q", window=window)
+    nq, nk = pl.cdiv(seq_len, bq), pl.cdiv(seq_len, bk)
 
     @pl.when(step_i == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def add(keys, pieces):
-        """dk, dv of `keys` from (q, do, lse, delta, mask) pieces of one
-        tile, transposed ([keys, queries]): the statistics lie along the
-        lanes as they arrive. With a folded scale `q` is `q * scale`, which
-        is also what dk's product wants."""
+    if fused:
+        @pl.when((step_i == 0) & (ik == 0))
+        def _init_head():
+            @pl.loop(0, nq)
+            def _(c):
+                dq_scr[pl.ds(pl.multiple_of(c * bq, bq), bq)] = jnp.zeros(
+                    (bq, dq_scr.shape[1]), jnp.float32)
+
+    def add(keys, pieces, padded_keys=False):
+        """dk, dv of `keys` from (q, do, lse, delta, mask, rows) pieces of
+        one tile, transposed ([keys, queries]): the statistics lie along
+        the lanes as they arrive. With a folded scale `q` is `q * scale`,
+        which is also what dk's product wants. `rows` are the piece's
+        queries in the head, where the fused call adds their dq."""
         k, v = k_ref[keys], v_ref[keys]
+        if padded_keys:
+            # A padded key's dS is 0, and 0 * NaN is NaN in dQ's sum.
+            k, v = _zero_padded(ik * bk, bk, seq_len, k, v)
         dk = dv = None
-        for q, do, lse, delta, mask in pieces:
+        for q, do, lse, delta, mask, rows in pieces:
             st = _dot_nt(k, q)                # [keys, queries] f32
             if not fold:
                 st = st * scale
@@ -671,17 +774,23 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_p = _dot_nn(dst.astype(q.dtype), q)
             dv = dv_p if dv is None else dv + dv_p
             dk = dk_p if dk is None else dk + dk_p
+            if fused:
+                dq_scr[rows] += _dot_tn(dst.astype(k.dtype), k)
         dk_scr[keys] += dk
         dv_scr[keys] += dv
 
-    def queries(qs, mask=None):
+    def queries(first, n, mask=None):
+        """The piece of `n` queries from `first` of this q block."""
+        qs = slice(None) if n == bq else pl.ds(first, n)
         q = q_ref[qs]
+        rows = fused and pl.ds(
+            pl.multiple_of(iq * bq + first, math.gcd(bq, first)), n)
         return (_scaled(q, scale) if fold else q, do_ref[qs],
-                lse_ref[:, qs], delta_ref[:, qs], mask)
+                lse_ref[:, qs], delta_ref[:, qs], mask, rows)
 
     @when_interior
     def _interior():
-        piece = queries(slice(None))
+        piece = queries(0, bq)
         for keys in _strips(bk, sub):
             add(keys, [piece])
 
@@ -690,10 +799,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  for b in range(bk // sub)]
         masks = _cell_masks(cells, sub, window, lower_rows=False)
         for keys, (masked, runs) in zip(_strips(bk, sub), cells):
-            qs = [(pl.ds(a * sub, sub), masks[off]) for a, off in masked]
-            qs += [(pl.ds(a * sub, n * sub), None) for a, n in runs]
+            qs = [(a * sub, sub, masks[off]) for a, off in masked]
+            qs += [(a * sub, n * sub, None) for a, n in runs]
             if qs:
-                add(keys, [queries(rows, m) for rows, m in qs])
+                add(keys, [queries(*piece) for piece in qs])
 
     if _decomposed(causal, bq, bk, sub, seq_len):
         for d, when in _edge_tiles(iq, ik, when_edge, bk, window,
@@ -702,17 +811,33 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         @when_edge
         def _edge():
-            q, do, lse, delta, _ = queries(slice(None))
+            q, do, lse, delta, _, rows = queries(0, bq)
             if seq_len % bq:
                 # The statistics' padding is zeros (`flash_bwd_core`).
                 q, do = _zero_padded(iq * bq, bq, seq_len, q, do)
-            add(slice(None), [(q, do, lse, delta, _edge_mask(
-                iq, ik, bq, bk, seq_len, causal, False, window))])
+            mask = _edge_mask(iq, ik, bq, bk, seq_len, causal, False, window,
+                              both=fused)
+            add(slice(None), [(q, do, lse, delta, mask, rows)],
+                padded_keys=bool(fused and seq_len % bk))
 
     @pl.when(step_i == steps - 1)
     def _flush():
         dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
+
+    if fused:
+        def dq_out(first, n):
+            dq = dq_scr[pl.ds(first, n)]
+            dq_ref[pl.ds(first, n)] = (dq * scale if fold else dq).astype(
+                dq_ref.dtype)
+
+        @pl.when((step_i == steps - 1) & (ik == nk - 1))
+        def _flush_head():
+            @pl.loop(0, seq_len // bq)
+            def _(c):
+                dq_out(pl.multiple_of(c * bq, bq), bq)
+            if seq_len % bq:
+                dq_out(seq_len // bq * bq, seq_len % bq)
 
 
 def _flash_bwd(res, g, scale, causal, block_q, block_k, sub, window):
@@ -733,6 +858,8 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     recomputed as exp(s - lse), so partial-chunk gradients compose by
     simple accumulation.
     """
+    from ray_tpu.util import tracing
+
     B, H, S, D = q.shape
     KVH, Dv = k.shape[1], v.shape[-1]
     group = H // KVH
@@ -748,32 +875,46 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     pad = [(0, 0), (0, 0), (0, 0), (0, nq * bq - S)]
     lse = jnp.pad(lse[:, :, None, :], pad)
     delta = jnp.pad(delta[:, :, None, :], pad)
-    kv = _kv_block(causal, bq, bk, window, nk)
-    params = _compiler_params(bq, bk, sub, D, Dv, q.dtype.itemsize)
+    itemsize = q.dtype.itemsize
+    kind = bwd_kind(S, D, Dv, q.dtype, bq, bk, sub)
+    tracing.observe(f"flash.plan.bwd_{kind}", 0, slow=False)
+    fused = kind == "fused"
+    dq_bytes = _dq_head_bytes(S, bq, D, itemsize) if fused else 0
+    args = (q, k, v, do, lse, delta)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **tiles),
-        grid=(B, H, nq, k_steps),
-        in_specs=[
-            pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((None, None, bk, D), lambda b, h, i, j, g_=group:
-                         (b, h // g_, kv(i, j), 0)),
-            pl.BlockSpec((None, None, bk, Dv), lambda b, h, i, j, g_=group:
-                         (b, h // g_, kv(i, j), 0)),
-            pl.BlockSpec((None, None, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
-            _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
-            _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=params,
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    if not fused:
+        kv = _kv_block(causal, bq, bk, window, nk)
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, **tiles),
+            grid=(B, H, nq, k_steps),
+            in_specs=[
+                pl.BlockSpec((None, None, bq, D),
+                             lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((None, None, bk, D), lambda b, h, i, j, g_=group:
+                             (b, h // g_, kv(i, j), 0)),
+                pl.BlockSpec((None, None, bk, Dv), lambda b, h, i, j, g_=group:
+                             (b, h // g_, kv(i, j), 0)),
+                pl.BlockSpec((None, None, bq, Dv),
+                             lambda b, h, i, j: (b, h, i, 0)),
+                _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
+                _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
+            ],
+            out_specs=pl.BlockSpec((None, None, bq, D),
+                                   lambda b, h, i, j: (b, h, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            compiler_params=_compiler_params(bq, bk, sub, D, Dv, itemsize),
+            interpret=_interpret(),
+        )(*args)
 
-    # dk/dv per *query* head, then segment-sum over the GQA group in XLA.
+    # dk/dv per *query* head, then segment-sum over the GQA group in XLA;
+    # the fused call's third result is dq, a whole head a block.
     qb = _q_block(causal, bq, bk, window, nq)
-    dk_h, dv_h = pl.pallas_call(
+    dq_spec, dq_shape, dq_scr = ([], [], []) if not fused else (
+        [pl.BlockSpec((None, None, S, D), lambda b, h, j, i: (b, h, 0, 0))],
+        [jax.ShapeDtypeStruct((B, H, S, D), q.dtype)],
+        [pltpu.VMEM((nq * bq, D), jnp.float32)])
+    grads = pl.pallas_call(
         functools.partial(_dkv_kernel, **tiles),
         grid=(B, H, nk, q_steps),
         in_specs=[
@@ -791,18 +932,22 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
         out_specs=[
             pl.BlockSpec((None, None, bk, D), lambda b, h, j, i: (b, h, j, 0)),
             pl.BlockSpec((None, None, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
-        ],
+        ] + dq_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
-        ],
+        ] + dq_shape,
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, Dv), jnp.float32),
-        ],
-        compiler_params=params,
+        ] + dq_scr,
+        compiler_params=_compiler_params(bq, bk, sub, D, Dv, itemsize,
+                                         dq_bytes),
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    )(*args)
+    dk_h, dv_h = grads[:2]
+    if fused:
+        dq = grads[2]
 
     if group > 1:
         dk = dk_h.reshape(B, KVH, group, S, D).sum(axis=2).astype(k.dtype)

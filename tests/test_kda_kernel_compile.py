@@ -134,9 +134,12 @@ def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
         "laguna_full", "laguna_swa"])
 def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
                                        B, S, H, KVH, D, Dv, window):
-    """Forward, dQ and dK/dV at the tiles `_TILES` gives each cell's shape, bfloat16, causal: three Mosaic calls in the gradient's
-    program, and the statistics cross them with the sequence on the lanes
-    ([B,H,1,S])."""
+    """The forward and the one backward kernel (dK, dV and dQ; a head's
+    float32 dQ accumulator and dQ's whole-head output block in VMEM, 16 +
+    16 MiB at kanana's shape) at the tiles `_TILES` gives each cell's shape,
+    bfloat16, causal: two Mosaic calls in the gradient's program, every
+    cell's shape taking the fused backward, and the statistics cross them
+    with the sequence on the lanes ([B,H,1,S])."""
     sd = lambda sh: jax.ShapeDtypeStruct(sh, jnp.bfloat16, sharding=one_chip)
     q, k, v = sd((B, S, H, D)), sd((B, S, KVH, D)), sd((B, S, KVH, Dv))
 
@@ -146,8 +149,27 @@ def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert fa.bwd_kind(S, D, Dv, jnp.bfloat16) == "fused"
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert f"f32[{B},{H},1,{S}]" in text and f"f32[{B},{H},{S},1]" not in text
+
+
+def test_flash_pair_compiles_past_the_budget_for_v5e(
+        one_chip, compiled_not_interpreted):
+    """A head whose dQ does not fit the fused backward's VMEM budget (65,536
+    positions at keys 192 wide: 64 + 64 MiB; no cell has one) takes the dQ
+    and dK/dV kernels by the same rule: three Mosaic calls."""
+    B, S, H, D, Dv = 1, 65536, 2, 192, 128
+    sd = lambda sh: jax.ShapeDtypeStruct(sh, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v).astype(jnp.float32) ** 2)
+
+    assert fa.bwd_kind(S, D, Dv, jnp.bfloat16) == "split"
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        sd((B, S, H, D)), sd((B, S, H, D)), sd((B, S, H, Dv))).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
 @pytest.mark.parametrize("T,E,first,Eh,F,kind,d,k", [

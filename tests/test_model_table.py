@@ -75,10 +75,12 @@ PARENT = {
     "laguna_tiny": ("f5f7f7b0405c9cb0:99e87d210fd8ce25",
                     "a6b430c735907222:99e87d210fd8ce25",
                     "c2fd39ee880340f2:99e87d210fd8ce25"),
-    # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text
-    "llama_tiny-flash": ("b8a5e1398a317f9f:c7a534cb53ae9dba",
-                         "dc0235890402e707:c7a534cb53ae9dba",
-                         "c058535c5302c1aa:c7a534cb53ae9dba"),
+    # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text;
+    # re-recorded in PR 47 (its own tree's: one backward kernel where the
+    # parent's text held dQ's and dK/dV's; the operations' scopes unmoved)
+    "llama_tiny-flash": ("c818439799ab19f7:c7a534cb53ae9dba",
+                         "910183df523627d5:c7a534cb53ae9dba",
+                         "10557681da416ccb:c7a534cb53ae9dba"),
 }
 # (num_params, num_active_params, flops_per_token(), flops_per_token(512)) of
 # every preset of models/configs.py and of every chipbench/configs/*.json's

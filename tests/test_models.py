@@ -109,8 +109,9 @@ def test_sharded_matches_single_device():
 
 def _flash_kernel_calls(jaxpr, times=1, out=None):
     """Flash pallas_calls of a jaxpr by operand signature (the forward
-    kernel is 3 in / 2 out, dq 6 / 1, dkv 6 / 2), a call inside a scan
-    counted once per iteration."""
+    kernel is 3 in / 2 out, the one backward kernel 6 / 3; past its VMEM
+    budget dq 6 / 1 and dkv 6 / 2), a call inside a scan counted once per
+    iteration."""
     out = {} if out is None else out
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
@@ -184,8 +185,8 @@ def test_remat_matches_no_remat(monkeypatch, impl, preset, remat_kw,
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=atol)
     calls = _flash_kernel_calls(jax.make_jaxpr(grad_r)(params).jaxpr)
     per_layer = {k: v / cfg.n_layers for k, v in calls.items()}
-    assert per_layer == ({"3in_2out": fwd_calls_per_layer, "6in_1out": 1,
-                          "6in_2out": 1} if fwd_calls_per_layer else {})
+    assert per_layer == ({"3in_2out": fwd_calls_per_layer, "6in_3out": 1}
+                         if fwd_calls_per_layer else {})
 
 
 def test_remat_full_is_the_work_of_saving_nothing(monkeypatch):
@@ -231,8 +232,7 @@ def test_remat_keeps_flash_residuals_through_shard_map(monkeypatch, policy):
     jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
     assert "shard_map" in str(jaxpr)
     assert _flash_kernel_calls(jaxpr.jaxpr) == {
-        "3in_2out": cfg.n_layers, "6in_1out": cfg.n_layers,
-        "6in_2out": cfg.n_layers}
+        "3in_2out": cfg.n_layers, "6in_3out": cfg.n_layers}
 
 
 # The module whose forward rule names a mixer kind's kernel residuals.
