@@ -362,3 +362,36 @@ def laguna_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def ouro_tiny(**overrides) -> TransformerConfig:
+    """A looped stack in the Ouro (LoopLM) pattern at widths small enough for
+    CPU tests (docs/model_layers.md, "The loop"): two layers applied four
+    times a step over one set of leaves, 4 query and 4 key heads of 16 under
+    plain RoPE (theta 1e6), a dense SwiGLU, two norms round every sublayer,
+    the final norm closing every pass, an untied head and a scalar exit gate
+    reading every pass, the loss the expectation over the exit distribution
+    less 0.05 of its entropy. Published sizes live in chipbench/configs/
+    only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=2,
+        n_heads=4,
+        n_kv_heads=4,
+        attn_head_dim=16,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-6,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=1000000.0,
+        tie_embeddings=False,
+        loop_steps=4,
+        post_norm=True,
+        exit_gate=True,
+        exit_entropy_coef=0.05,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

@@ -68,6 +68,12 @@ def _kv_stack(params: Params, cfg: TransformerConfig):
     if cfg.moe_held is not None:
         raise NotImplementedError(
             f"decode needs every expert held, not moe_held={cfg.moe_held}")
+    if cfg.loop_steps > 1 or cfg.post_norm:
+        raise NotImplementedError(
+            "decode holds one slab of keys and values a layer and its layer "
+            "body has one norm a sublayer: a looped stack (loop_steps > 1) "
+            "needs a cache slab a pass a layer, and post_norm is not applied "
+            "yet; the stack trains but does not serve")
     return one_kind_stack(params, cfg, "decoding")
 
 

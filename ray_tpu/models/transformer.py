@@ -241,6 +241,25 @@ class TransformerConfig:
     # [B*S, V] logits/dlogits tensors (~1GB each way at bench shapes) —
     # vocab chunks stream through online logsumexp fwd / recompute bwd.
     fused_ce: bool = False
+    # A looped stack (docs/model_layers.md, "The loop"); at these defaults
+    # nothing is traced for it and no leaf is made.
+    # - loop_steps: the whole stack is applied this many times a step over
+    #   ONE set of leaves (every weight's gradient the sum of its uses), the
+    #   final norm closing every pass: its output is the next pass's input
+    #   and that pass's head input. Positions are the same in every pass.
+    # - post_norm: a second norm round each sublayer, x + N2(f(N1(x)))
+    #   (leaves `attn_post_norm`, `mlp_post_norm` [d]; rmsnorm).
+    # - exit_gate: a scalar gate reads every pass's normed output, lam =
+    #   sigmoid(h . `exit_gate_w` [d] + `exit_gate_b` [1]) in float32; pass
+    #   t < T is left with probability p(t) = lam_t prod_{u<t} (1 - lam_u),
+    #   the last with what remains, and training minimises the expected
+    #   next-token loss under p less `exit_entropy_coef` times p's entropy
+    #   (`loss_fn`). False: the last pass's loss alone. All passes always
+    #   run: there is no early exit here.
+    loop_steps: int = 1
+    post_norm: bool = False
+    exit_gate: bool = False
+    exit_entropy_coef: float = 0.0
 
     def __post_init__(self):
         fields = [m.layers_field for m in MIXERS.values() if m.layers_field]
@@ -290,6 +309,11 @@ class TransformerConfig:
                 "or 'softmax' only")
         if self.moe_router == "softmax" and self.moe_routed_scale != 1.0:
             raise ValueError("moe_router='softmax' takes no moe_routed_scale")
+        if self.loop_steps < 1 or (self.exit_gate and self.loop_steps < 2):
+            raise ValueError("loop_steps is at least 1, and at least 2 where "
+                             "an exit_gate chooses among the passes")
+        if self.post_norm and self.norm != "rmsnorm":
+            raise ValueError("post_norm is an RMSNorm: norm='rmsnorm'")
 
     @property
     def kv_heads(self) -> int:
@@ -419,7 +443,7 @@ class TransformerConfig:
         final_norm = d * (2 if self.norm == "layernorm" else 1)
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         pos = self.max_seq_len * d if self.positional == "learned" else 0
-        return layers + final_norm + emb + pos
+        return layers + final_norm + emb + pos + _size(_exit_shapes(self))
 
     def num_active_params(self) -> int:
         """Params touched per token: for MoE, only experts_per_token of the
@@ -445,7 +469,9 @@ class TransformerConfig:
         touches (no embedding lookup) and each layer's `Mixer.core_flops`:
         causal attention 3*S*H*(d_qk + d_v) a softmax layer, the chunked
         algorithm's operations a KDA, Gated DeltaNet or Mamba-2 layer
-        (chipbench/reduce/kda_counts.py, ssd_counts.py, qwen3_next_counts.py)."""
+        (chipbench/reduce/kda_counts.py, ssd_counts.py, qwen3_next_counts.py).
+        A looped stack's leaves are used `loop_steps` times a token, the
+        head as often where an `exit_gate` reads every pass, else once."""
         S = seq_len or self.max_seq_len
         n = self.num_active_params()
         if not self.tie_embeddings:  # the lookup; a tied table is the head
@@ -455,6 +481,10 @@ class TransformerConfig:
         total = 6.0 * n
         for mixer, _ in self.layer_kinds():
             total += MIXERS[mixer].core_flops(self, S)
+        if self.loop_steps > 1:
+            skipped = 0 if self.exit_gate else self.loop_steps - 1
+            total = (total * self.loop_steps
+                     - 6.0 * self.vocab_size * self.d_model * skipped)
         return total
 
 
@@ -659,7 +689,19 @@ def _layer_shapes(cfg: TransformerConfig, kind: Tuple[str, str]):
     if cfg.norm == "layernorm":
         sh["attn_norm_b"] = ((d,), (None,), "zeros")
         sh["mlp_norm_b"] = ((d,), (None,), "zeros")
+    if cfg.post_norm:
+        sh["attn_post_norm"] = ((d,), (None,), unit)
+        sh["mlp_post_norm"] = ((d,), (None,), unit)
     return sh
+
+
+def _exit_shapes(cfg: TransformerConfig):
+    """The exit gate's two leaves, beside the final norm at the top of the
+    tree (`cfg.exit_gate`; else none)."""
+    if not cfg.exit_gate:
+        return {}
+    return {"exit_gate_w": ((cfg.d_model,), ("embed",), _fan(cfg.d_model)),
+            "exit_gate_b": ((1,), (None,), "zeros")}
 
 
 # params["layers"] is stored in one of two formats, both part of what callers
@@ -747,6 +789,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
         params["pos_embed"] = (
             jax.random.normal(keys[9], (cfg.max_seq_len, d)) * 0.02
         ).astype(cfg.param_dtype)
+    for i, (n, (shape, _, init)) in enumerate(_exit_shapes(cfg).items()):
+        params[n] = leaf(jax.random.fold_in(keys[10], i), 1, shape, init)[0]
     return params
 
 
@@ -770,6 +814,7 @@ def param_logical_specs(cfg: TransformerConfig) -> Params:
         specs["lm_head"] = ("embed", "vocab")
     if cfg.positional == "learned":
         specs["pos_embed"] = (None, "embed")
+    specs.update({n: axes for n, (_, axes, _) in _exit_shapes(cfg).items()})
     return specs
 
 
@@ -1201,11 +1246,18 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     with jax.named_scope(scope) if scope else contextlib.nullcontext():
         delta, k, v = row.apply(cfg, kind, h if row.cut_rows else whole(h),
                                 layer, positions, overlap)
+    # `cfg.post_norm`: x + N2(f(N1(x))), the sublayer's output normed too.
+    post = lambda delta, n: _norm(delta, layer[n], None, cfg.norm,
+                                  cfg.norm_eps, cfg.norm_offset)
+    if cfg.post_norm:
+        delta = post(delta, "attn_post_norm")
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
     h = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm,
               cfg.norm_eps, cfg.norm_offset)
     delta, extras = _mlp_block(cfg, ffn, h if ffn == "dense" else whole(h),
                                layer, overlap)
+    if cfg.post_norm:
+        delta = post(delta, "mlp_post_norm")
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
     if overlap is not None:
         overlap.observe()
@@ -1277,18 +1329,11 @@ def forward_with_aux(
     return lm_head(params, x, cfg), extras.get("aux", jnp.zeros((), jnp.float32))
 
 
-def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
-    """Everything before the lm head: the segments in turn, each a scan over
-    its repeats, every layer under the remat policy. -> (hidden [B,S,d],
-    the stack's extras from its layers' (`_mlp_block`): {} for dense
-    feed-forwards, {"aux": sum} for GShard, and for a held range of experts
-    moe_assigned, moe_dropped, moe_past_buffer, moe_trips, moe_rows_worked
-    (sums), moe_load_max, moe_window_rows (max), moe_load_mean (mean) over
-    the expert layers)."""
-    B, S = tokens.shape
-    x = embed_tokens(params, tokens, cfg)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    per_layer = []  # each leaf [repeats]
+def _stack_once(params: Params, x: jax.Array, positions: jax.Array,
+                cfg: TransformerConfig):
+    """The segments in turn, each a scan over its repeats, every layer under
+    the remat policy -> (x, the layers' extras, each leaf [repeats])."""
+    per_layer = []
     for (pattern, _), seg in zip(cfg.stack_plan(), stack_segments(params, cfg)):
         bodies = [layer_scan_body(cfg, kind, positions) for kind in pattern]
 
@@ -1301,19 +1346,67 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig):
 
         x, outs = jax.lax.scan(period, x, seg)
         per_layer.extend(e for e in outs if e)
+    return x, per_layer
+
+
+def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig,
+              read: Optional[Callable] = None):
+    """Everything before the lm head: the stack (`_stack_once`), under
+    `cfg.loop_steps` > 1 as many times over the same leaves, the final norm
+    closing every pass (its output h the next pass's input). The passes are
+    written out, each its own scans over the layers: a scan over the passes
+    round them took 15% longer a step on the chip (PERF.md section 6, PR 49)
+    and, with the plain head inside it, did not fit. -> (hidden [B,S,d]
+    before the final norm, of the last pass; the stack's extras from its
+    layers' (`_mlp_block`): {} for dense feed-forwards, {"aux": sum} for
+    GShard, and for a held range of experts moe_assigned, moe_dropped,
+    moe_past_buffer, moe_trips, moe_rows_worked (sums), moe_load_max,
+    moe_window_rows (max), moe_load_mean (mean) over the expert layers'
+    applications). `read` (a looped stack's head, `loss_fn`): called on every
+    pass's h under the device scope `loop.head`, what it returns stacked
+    over the passes is returned third."""
+    B, S = tokens.shape
+    x = embed_tokens(params, tokens, cfg)
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    if cfg.loop_steps == 1:
+        x, per_layer = _stack_once(params, x, positions, cfg)
+        return x, _stack_extras(per_layer)
+    tracing.observe(
+        "train.loop", 0, slow=False, steps=cfg.loop_steps,
+        layers=cfg.n_layers, post_norm=cfg.post_norm, gate=cfg.exit_gate,
+        head=("none" if read is None else
+              "chunked" if cfg.fused_ce else "plain"))
+    h, per_layer, reads = x, [], []
+    for t in range(cfg.loop_steps):
+        x, extras = _stack_once(params, h, positions, cfg)
+        per_layer.extend(extras)
+        if read is None and t == cfg.loop_steps - 1:
+            break  # the caller's head norms the last pass's output itself
+        with jax.named_scope("loop.head"):
+            h = _norm(x, params["final_norm"], params.get("final_norm_b"),
+                      cfg.norm, cfg.norm_eps, cfg.norm_offset)
+            if read is not None:
+                reads.append(read(h))
+    out = (x, _stack_extras(per_layer))
+    if read is None:
+        return out
+    return out + (jax.tree.map(lambda *a: jnp.stack(a), *reads),)
+
+
+def _stack_extras(per_layer) -> Dict[str, jax.Array]:
     if not per_layer:
-        return x, {}
+        return {}
     cat = {n: jnp.concatenate([e[n] for e in per_layer]) for n in per_layer[0]}
     if "aux" in cat:
-        return x, {"aux": cat["aux"].sum()}
-    return x, {"moe_assigned": cat["assigned"].sum(),
-               "moe_dropped": cat["dropped"].sum(),
-               "moe_past_buffer": cat["past_buffer"].sum(),
-               "moe_load_max": cat["load_max"].max(),
-               "moe_load_mean": cat["load_mean"].mean(),
-               "moe_trips": cat["trips"].sum(),
-               "moe_window_rows": cat["window_rows"].max(),
-               "moe_rows_worked": cat["rows_worked"].sum()}
+        return {"aux": cat["aux"].sum()}
+    return {"moe_assigned": cat["assigned"].sum(),
+            "moe_dropped": cat["dropped"].sum(),
+            "moe_past_buffer": cat["past_buffer"].sum(),
+            "moe_load_max": cat["load_max"].max(),
+            "moe_load_mean": cat["load_mean"].mean(),
+            "moe_trips": cat["trips"].sum(),
+            "moe_window_rows": cat["window_rows"].max(),
+            "moe_rows_worked": cat["rows_worked"].sum()}
 
 
 def final_hidden_and_head(
@@ -1326,10 +1419,15 @@ def final_hidden_and_head(
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm,
               cfg.norm_eps, cfg.norm_offset)
     x = _whole(x, tp.plan(*x.shape[:2]))
+    return x, _head_weight(params).astype(cfg.dtype)
+
+
+def _head_weight(params: Params) -> jax.Array:
+    """[d, V] in the parameters' type: `lm_head`, or the tied table."""
     head = params.get("lm_head", None)
     if head is None:
         head = params["embed"].T
-    return x, head.astype(cfg.dtype)
+    return head
 
 
 def lm_head(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
@@ -1347,10 +1445,15 @@ def token_cross_entropy(logits: jax.Array, targets: jax.Array,
     second [B, S, V] f32 log-softmax tensor (at V=32k that tensor dominates
     HBM traffic for the loss epilogue).
     """
+    ll = _token_ll(logits, targets)
+    return -(ll * valid).sum() / jnp.maximum(valid.sum(), 1.0)
+
+
+def _token_ll(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """log softmax(logits)[target] of every position, [B,S]."""
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    ll = at_target - lse
-    return -(ll * valid).sum() / jnp.maximum(valid.sum(), 1.0)
+    return at_target - lse
 
 
 def shift_targets_valid(tokens: jax.Array, mask: Optional[jax.Array] = None):
@@ -1390,11 +1493,13 @@ def next_token_loss(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
             *, shift_inputs: bool = False, with_counters: bool = False):
-    """Next-token cross-entropy. `with_counters`: return (loss, routing
-    counters) for `ShardedTrainStep(has_aux=True)`: device scalars
-    moe_assigned, moe_dropped, moe_past_buffer, moe_load_max, moe_load_mean,
-    moe_trips, moe_window_rows, moe_rows_worked of a stack with a held range
-    of experts (`_backbone`), {} for any other.
+    """Next-token cross-entropy; of a stack with an exit gate the expected
+    one under its exit distribution (`_expected_exit_loss`). `with_counters`:
+    return (loss, counters) for `ShardedTrainStep(has_aux=True)`: device
+    scalars moe_assigned, moe_dropped, moe_past_buffer, moe_load_max,
+    moe_load_mean, moe_trips, moe_window_rows, moe_rows_worked of a stack
+    with a held range of experts (`_backbone`), exit_mass_pm_1..T and
+    exit_entropy_pm of one with an exit gate, {} for any other.
 
     Two token conventions:
     - in-place (default): batch tokens [B,S]; the forward runs on the FULL
@@ -1412,12 +1517,16 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
       parallelism composes too.
     """
     tokens = batch["tokens"]
-    x, extras = _backbone(params, tokens[:, :-1] if shift_inputs else tokens,
-                          cfg)
-    if shift_inputs:
-        targets, valid = shift_targets_valid(tokens, batch.get("mask"))
-    else:
-        targets, valid = inplace_targets_valid(batch)
+    inputs = tokens[:, :-1] if shift_inputs else tokens
+    targets_valid = lambda: (
+        shift_targets_valid(tokens, batch.get("mask")) if shift_inputs
+        else inplace_targets_valid(batch))
+    if cfg.exit_gate:
+        loss, extras = _expected_exit_loss(params, inputs, *targets_valid(),
+                                           cfg)
+        return (loss, extras) if with_counters else loss
+    x, extras = _backbone(params, inputs, cfg)
+    targets, valid = targets_valid()
     if cfg.fused_ce:
         from ..ops.fused_ce import fused_next_token_loss
 
@@ -1430,3 +1539,67 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
     if "aux" in extras:  # GShard's balancing loss; what is left are counters
         loss = loss + cfg.moe_aux_coef * extras.pop("aux")
     return (loss, extras) if with_counters else loss
+
+
+def exit_log_probs(gate_logits: jax.Array) -> jax.Array:
+    """The exit distribution of a looped stack, in logarithms and float32:
+    every pass's gate logit [T, ...] (the last pass's is not read) -> log p
+    [T, ...], p(t) = lam_t prod_{u<t} (1 - lam_u) for t < T and p(T) =
+    prod_{u<T} (1 - lam_u), what no gate took: the T masses sum to 1."""
+    g = gate_logits[:-1].astype(jnp.float32)
+    stay = jax.nn.log_sigmoid(-g)                 # log (1 - lam_t)
+    reached = jnp.cumsum(stay, axis=0) - stay     # log S^(t-1)
+    return jnp.concatenate([jax.nn.log_sigmoid(g) + reached,
+                            stay.sum(0, keepdims=True)])
+
+
+def _expected_exit_loss(params: Params, inputs: jax.Array,
+                        targets: jax.Array, valid: jax.Array,
+                        cfg: TransformerConfig):
+    """`loss_fn` of a stack with an exit gate -> (loss, counters): the mean
+    over valid tokens of sum_t p(t) CE^t - `exit_entropy_coef` H(p), CE^t a
+    token's cross-entropy under pass t's head (the one `lm_head`, the final
+    norm's output of that pass) and p its exit distribution
+    (`exit_log_probs`). A pass's float32 logits [B,S,V] are never kept:
+    the plain head is recomputed in the backward (a checkpoint round the
+    product and the cross-entropy), the chunked one (`cfg.fused_ce`,
+    ops/fused_ce.py `fused_token_ce`) never forms them. Counters, device
+    scalars in parts per thousand: `exit_mass_pm_<t>` the mean mass of pass
+    t, `exit_entropy_pm` the mean entropy in nats."""
+    # Cast inside a pass: the passes' head gradients then add up in the
+    # parameter's own type, not in cfg.dtype.
+    head = _head_weight(params)
+    w_g = params["exit_gate_w"].astype(jnp.float32)
+    b_g = params["exit_gate_b"].astype(jnp.float32)
+
+    @jax.checkpoint
+    def plain(h, head):
+        logits = _scaled((h @ head).astype(jnp.float32), 1.0 / cfg.logit_scale)
+        return -_token_ll(logits, targets)
+
+    def chunked(h, head):
+        from ..ops.fused_ce import fused_token_ce
+
+        B, S, d = h.shape
+        return fused_token_ce(
+            _scaled(h, 1.0 / cfg.logit_scale).reshape(B * S, d), head,
+            targets.reshape(B * S).astype(jnp.int32)).reshape(B, S)
+
+    def read(h):
+        h = _whole(h, tp.plan(*h.shape[:2]))
+        ce = (chunked if cfg.fused_ce else plain)(h, head.astype(cfg.dtype))
+        return ce, jnp.sum(h.astype(jnp.float32) * w_g, -1) + b_g
+
+    _, extras, (ce, gate) = _backbone(params, inputs, cfg, read)
+    logp = exit_log_probs(gate)
+    p = jnp.exp(logp)
+    entropy = -(p * logp).sum(0)
+    per_token = (p * ce).sum(0) - cfg.exit_entropy_coef * entropy
+    mean = lambda a: (a * valid).sum() / jnp.maximum(valid.sum(), 1.0)
+    loss = mean(per_token)
+    if "aux" in extras:
+        loss = loss + cfg.moe_aux_coef * extras.pop("aux")
+    for t in range(cfg.loop_steps):
+        extras[f"exit_mass_pm_{t + 1}"] = 1000.0 * mean(p[t])
+    extras["exit_entropy_pm"] = 1000.0 * mean(entropy)
+    return loss, extras

@@ -54,6 +54,15 @@ def fused_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
 
 
 def _fwd_stats(x, head, targets, valid, chunk):
+    tgt_logit, lse = _token_stats(x, head, targets, chunk)
+    denom = jnp.maximum(valid.sum(), 1.0)
+    loss = -(((tgt_logit - lse) * valid).sum() / denom)
+    return loss, (lse,)
+
+
+def _token_stats(x, head, targets, chunk):
+    """(the target's logit, logsumexp of the row) of every row of x @ head,
+    [M] float32 each."""
     M, d = x.shape
     V = head.shape[1]
     C = chunk or _pick_chunk(V)
@@ -82,10 +91,7 @@ def _fwd_stats(x, head, targets, valid, chunk):
             jnp.zeros((M,), jnp.float32))
     (m, s, tgt_logit), _ = jax.lax.scan(
         body, init, (head_c, jnp.arange(n)))
-    lse = m + jnp.log(s)
-    denom = jnp.maximum(valid.sum(), 1.0)
-    loss = -(((tgt_logit - lse) * valid).sum() / denom)
-    return loss, (lse,)
+    return tgt_logit, m + jnp.log(s)
 
 
 def _fused_ce_fwd(x, head, targets, valid, chunk):
@@ -95,13 +101,19 @@ def _fused_ce_fwd(x, head, targets, valid, chunk):
 
 def _fused_ce_bwd(chunk, res, g):
     x, head, targets, valid, lse = res
+    denom = jnp.maximum(valid.sum(), 1.0)
+    w = (g * valid / denom).astype(jnp.float32)  # [M] dloss/dll * -1 later
+    return _token_bwd(x, head, targets, lse, w, chunk) + (None, None)
+
+
+def _token_bwd(x, head, targets, lse, w, chunk):
+    """(dx, dhead) of sum_i w_i CE_i: `w` [M] float32 the cotangent of every
+    row's cross-entropy."""
     M, d = x.shape
     V = head.shape[1]
     C = chunk or _pick_chunk(V)
     n = V // C
     head_c = head.reshape(d, n, C).transpose(1, 0, 2)  # [n, d, C]
-    denom = jnp.maximum(valid.sum(), 1.0)
-    w = (g * valid / denom).astype(jnp.float32)  # [M] dloss/dll * -1 later
 
     def body(dx, inp):
         hc, ci = inp
@@ -122,10 +134,36 @@ def _fused_ce_bwd(chunk, res, g):
     dx, dhead_chunks = jax.lax.scan(
         body, jnp.zeros((M, d), jnp.float32), (head_c, jnp.arange(n)))
     dhead = dhead_chunks.transpose(1, 0, 2).reshape(d, V)
-    return (dx.astype(x.dtype), dhead.astype(head.dtype), None, None)
+    return dx.astype(x.dtype), dhead.astype(head.dtype)
 
 
 fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def fused_token_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
+                   chunk: int = 0) -> jax.Array:
+    """The cross-entropy of EVERY row of ``(x @ head)`` vs ``targets``, [M]
+    float32, where `fused_ce` returns their weighted mean: for a loss that
+    weighs a token's cross-entropy by something of the token's own (a looped
+    stack's exit distribution, models/transformer.py). The same vocab-chunk
+    scans; the backward takes a cotangent a row."""
+    tgt_logit, lse = _token_stats(x, head, targets, chunk)
+    return lse - tgt_logit
+
+
+def _fused_token_ce_fwd(x, head, targets, chunk):
+    tgt_logit, lse = _token_stats(x, head, targets, chunk)
+    return lse - tgt_logit, (x, head, targets, lse)
+
+
+def _fused_token_ce_bwd(chunk, res, g):
+    x, head, targets, lse = res
+    return _token_bwd(x, head, targets, lse, g.astype(jnp.float32),
+                      chunk) + (None,)
+
+
+fused_token_ce.defvjp(_fused_token_ce_fwd, _fused_token_ce_bwd)
 
 
 def fused_next_token_loss(x: jax.Array, head: jax.Array,

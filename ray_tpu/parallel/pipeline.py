@@ -171,6 +171,12 @@ def pipeline_loss_fn(cfg, mesh: Mesh, *, rules=None, num_microbatches: int = 4,
     # Refuse another stack here, not at trace time.
     tfm.one_kind_stack(tfm.param_logical_specs(cfg), cfg,
                        "pipeline parallelism")
+    if cfg.loop_steps > 1:
+        raise NotImplementedError(
+            "the pipeline's schedule visits a stage once a microbatch: a "
+            "looped stack (loop_steps > 1) needs a stage visited once a pass, "
+            "the last stage's output sent back to the first, and a head a "
+            "pass; it trains unpipelined")
 
     def loss_fn(params, batch):
         with shd.sharding_ctx(mesh, rules):

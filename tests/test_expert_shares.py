@@ -195,9 +195,17 @@ FAMILIES = {
 }
 
 
+# Families with no experts: no share to add up here, the same three pieces
+# for tests/test_preset_programs.py.
+DENSE_FAMILIES = {
+    "ouro_tiny": dict(weights="weights_ouro", sizes="OuroSizes",
+                      reference="ouro"),
+}
+
+
 def family(preset):
     """(weights module, reference module, cfg -> sizes) of a family."""
-    f = FAMILIES[preset]
+    f = {**DENSE_FAMILIES, **FAMILIES}[preset]
     W = importlib.import_module("chipbench." + f["weights"])
     ref = importlib.import_module("chipbench.reference." + f["reference"])
 

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.fused_ce import _pick_chunk, fused_ce
+from ray_tpu.ops.fused_ce import _pick_chunk, fused_ce, fused_token_ce
 
 
 def _reference(x, head, targets, valid):
@@ -31,6 +31,41 @@ def test_value_and_grads_match_reference(chunk):
     np.testing.assert_allclose(fused_loss, ref_loss, rtol=1e-5)
     np.testing.assert_allclose(dx, ref_dx, rtol=1e-4, atol=1e-6)
     np.testing.assert_allclose(dh, ref_dh, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("chunk", [0, 16])
+def test_per_token_values_and_grads_match_token_cross_entropy(chunk):
+    """`fused_token_ce`: every row's cross-entropy is `token_cross_entropy`'s
+    of that row alone, and a loss that weighs each row by a weight of its own
+    has the unfused pipeline's gradients with respect to x, the head and the
+    weights."""
+    from ray_tpu.models.transformer import token_cross_entropy
+
+    rng = np.random.default_rng(2)
+    M, d, V = 48, 32, 256
+    x = jnp.asarray(rng.standard_normal((M, d)), jnp.float32)
+    head = jnp.asarray(rng.standard_normal((d, V)) * 0.1, jnp.float32)
+    targets = jnp.asarray(rng.integers(0, V, M), jnp.int32)
+    w = jnp.asarray(rng.random(M), jnp.float32)
+
+    def per_token(x, head):
+        logits = (x @ head).astype(jnp.float32)[:, None]   # [M,1,V]
+        one = jnp.ones((1, 1), jnp.float32)
+        return jax.vmap(lambda l, t: token_cross_entropy(
+            l[None], t[None, None], one))(logits, targets)
+
+    ce = fused_token_ce(x, head, targets, chunk)
+    assert ce.shape == (M,) and ce.dtype == jnp.float32
+    np.testing.assert_allclose(ce, per_token(x, head), rtol=1e-5, atol=1e-6)
+    want = jax.grad(lambda x, h, w: (w * per_token(x, h)).sum(),
+                    argnums=(0, 1, 2))(x, head, w)
+    got = jax.grad(lambda x, h, w: (w * fused_token_ce(
+        x, h, targets, chunk)).sum(), argnums=(0, 1, 2))(x, head, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-6)
+    # the mean is the weighted sum of the rows over the weights' sum
+    np.testing.assert_allclose(fused_ce(x, head, targets, w, chunk),
+                               (w * ce).sum() / w.sum(), rtol=1e-5)
 
 
 def test_bf16_inputs_accumulate_f32():

@@ -118,39 +118,65 @@ def test_ssd_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     assert f",{chunk},{chunk}]" not in text.replace(f"f32[{chunk},{chunk}]", "")
 
 
-@pytest.mark.parametrize("B,S,H,KVH,D,Dv,window", [
-    (16, 1024, 12, 12, 64, 64, None),    # gpt2_124m.train_1chip
-    (4, 2048, 8, 4, 128, 128, None),     # internlm2_1_8b.train_mesh4, a device
-    (1, 8192, 32, 32, 192, 128, None),   # kimi_linear_48b_a3b.train_share_8k
-    (1, 4096, 32, 8, 64, 64, None),      # granite_4_0_h_micro.train_stage_4k
-    (1, 16384, 32, 4, 128, 128, None),   # mellum2_12b_a2_5b.train_share_16k,
-    (1, 16384, 32, 4, 128, 128, 1024),   # its full layer and a windowed one
-    (1, 16384, 32, 32, 192, 128, None),  # kanana_2_30b_a3b.train_rank8_16k
-    (1, 16384, 16, 2, 256, 256, None),   # qwen3_next_80b_a3b.train_rank16_16k
-    (1, 8192, 24, 4, 128, 128, None),    # laguna_s_2_1.train_rank32_8k: a full
-    (1, 8192, 36, 4, 128, 128, 512),     # layer (groups of 6), a windowed (9)
-], ids=["gpt2", "internlm2_shard", "kimi_mla", "granite_gqa64",
-        "mellum2_full", "mellum2_swa", "kanana_mla", "qwen3_next_gattn",
-        "laguna_full", "laguna_swa"])
+FLASH_SHAPES = {  # (B, S, H, KVH, D, Dv, window)
+    "gpt2": (16, 1024, 12, 12, 64, 64, None),    # gpt2_124m.train_1chip
+    "internlm2_shard": (4, 2048, 8, 4, 128, 128, None),  # train_mesh4, a device
+    "kimi_mla": (1, 8192, 32, 32, 192, 128, None),  # kimi_linear train_share_8k
+    "granite_gqa64": (1, 4096, 32, 8, 64, 64, None),  # granite train_stage_4k
+    "mellum2_full": (1, 16384, 32, 4, 128, 128, None),  # mellum2 train_share_16k,
+    "mellum2_swa": (1, 16384, 32, 4, 128, 128, 1024),  # a full and a windowed
+    "kanana_mla": (1, 16384, 32, 32, 192, 128, None),  # kanana train_rank8_16k
+    "qwen3_next_gattn": (1, 16384, 16, 2, 256, 256, None),  # train_rank16_16k
+    "laguna_full": (1, 8192, 24, 4, 128, 128, None),  # laguna train_rank32_8k: a
+    "laguna_swa": (1, 8192, 36, 4, 128, 128, 512),  # full (groups of 6), a windowed (9)
+}
+# Shapes compiled in ONE program (ROADMAP D11: the file's case-seconds): a
+# configuration's two attention kinds. (ouro_2_6b's 16 | 16 heads of 128 at
+# 8,192 are in its whole step's compile, the last test of the file.)
+FLASH_GROUPS = (("mellum2_full", "mellum2_swa"), ("laguna_full", "laguna_swa"))
+
+
+@pytest.fixture(scope="module")
+def flash_texts():
+    """{group: compiled text}: a group is compiled by its first case."""
+    return {}
+
+
+def _flash_text(one_chip, texts, name):
+    """The compiled gradient program that holds `name`'s two kernels (and
+    its group's), compiled once a group."""
+    group = next((g for g in FLASH_GROUPS if name in g), (name,))
+    if group not in texts:
+        sd = lambda sh: jax.ShapeDtypeStruct(sh, jnp.bfloat16,
+                                             sharding=one_chip)
+        args, windows = [], []
+        for B, S, H, KVH, D, Dv, window in map(FLASH_SHAPES.get, group):
+            args.append((sd((B, S, H, D)), sd((B, S, KVH, D)),
+                         sd((B, S, KVH, Dv))))
+            windows.append(window)
+
+        def loss(qkvs):
+            return sum(jnp.sum(fa.flash_attention(*qkv, window=w).astype(
+                jnp.float32) ** 2) for qkv, w in zip(qkvs, windows))
+
+        texts[group] = jax.jit(jax.grad(loss)).lower(
+            args).compile().as_text()
+    return texts[group], len(group)
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_SHAPES))
 def test_flash_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
-                                       B, S, H, KVH, D, Dv, window):
+                                       flash_texts, name):
     """The forward and the one backward kernel (dK, dV and dQ; a head's
     float32 dQ accumulator and dQ's whole-head output block in VMEM, 16 +
     16 MiB at kanana's shape) at the tiles `_TILES` gives each cell's shape,
-    bfloat16, causal: two Mosaic calls in the gradient's program, every
-    cell's shape taking the fused backward, and the statistics cross them
-    with the sequence on the lanes ([B,H,1,S])."""
-    sd = lambda sh: jax.ShapeDtypeStruct(sh, jnp.bfloat16, sharding=one_chip)
-    q, k, v = sd((B, S, H, D)), sd((B, S, KVH, D)), sd((B, S, KVH, Dv))
-
-    def loss(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, window=window).astype(
-            jnp.float32) ** 2)
-
-    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile(
-        ).as_text()
+    bfloat16, causal: two Mosaic calls a shape in the gradient's program,
+    every cell's shape taking the fused backward, and the statistics cross
+    them with the sequence on the lanes ([B,H,1,S])."""
+    B, S, H, KVH, D, Dv, window = FLASH_SHAPES[name]
+    text, shapes = _flash_text(one_chip, flash_texts, name)
     assert fa.bwd_kind(S, D, Dv, jnp.bfloat16) == "fused"
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * shapes
     assert f"f32[{B},{H},1,{S}]" in text and f"f32[{B},{H},{S},1]" not in text
 
 
@@ -259,3 +285,56 @@ def test_full_remat_reruns_no_core_kernel_for_v5e(
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         x, layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_looped_cells_step_fits_a_v5e(one_chip, compiled_not_interpreted,
+                                          monkeypatch):
+    """ouro_2_6b.train_loop4_8k's whole step as its files give it (published
+    widths, 8 layers run 4 times, one sequence of 8,192, the remat policy
+    and the head the traffic's sweep chose, AdamW's update inside, weights
+    and moments donated): the v5e's compiler takes it, so 9.8 GB of state,
+    the kept inputs and flash residuals of 32 layer applications and a
+    pass's head fit the 15.75 GiB a program runs in; the passes are written
+    out, each with its own scans: a forward and a backward flash call a
+    pass."""
+    import json
+    import os
+
+    import optax
+
+    from ray_tpu.models import transformer as tfm
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+    with open(os.path.join(root, "configs", "ouro_2_6b.json")) as f:
+        tc = dict(json.load(f)["transformer_config"])
+    with open(os.path.join(root, "traffic", "train_loop4_8k.json")) as f:
+        mix = json.load(f)
+    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")  # the platform here is cpu
+    tc.update(dtype=jnp.bfloat16, param_dtype=jnp.float32, remat=mix["remat"],
+              remat_policy=mix["remat_policy"])
+    cfg = tfm.TransformerConfig(**tc)
+    assert (cfg.loop_steps, cfg.n_layers, mix["seq"]) == (4, 8, 8192)
+    opt = optax.adamw(mix["lr"], weight_decay=0.0)
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.key(0))
+    state = jax.tree.map(sd, jax.eval_shape(opt.init, params))
+    params = jax.tree.map(sd, params)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (mix["batch"], mix["seq"] + 1), jnp.int32, sharding=one_chip)}
+
+    def step(p, o, b):
+        loss, g = jax.value_and_grad(lambda p: tfm.loss_fn(
+            p, b, cfg, shift_inputs=True))(p)
+        updates, o = opt.update(g, o, p)
+        return optax.apply_updates(p, updates), o, loss
+
+    exe = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, state, batch).compile()
+    ma = exe.memory_analysis()
+    # weights and both moments are donated: the new ones take their place
+    assert ma.alias_size_in_bytes >= 12 * cfg.num_params()
+    assert ma.argument_size_in_bytes - ma.alias_size_in_bytes < 1 << 16
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == (
+        2 * cfg.loop_steps)

@@ -27,7 +27,7 @@ pytestmark = pytest.mark.usefixtures("exact_matmuls")
 
 PRESETS = ("llama_tiny", "gpt2_tiny", "moe_tiny", "kimi_linear_tiny",
            "granite_hybrid_tiny", "mellum2_tiny", "kanana2_tiny",
-           "qwen3_next_tiny", "laguna_tiny")
+           "qwen3_next_tiny", "laguna_tiny", "ouro_tiny")
 REMAT = ("off", "dots", "full")
 # "<sha256[:16] of the StableHLO>:<sha256[:16] of its operations' name
 # stacks>" of each preset's gradient program, remat off and under either
@@ -75,6 +75,12 @@ PARENT = {
     "laguna_tiny": ("f5f7f7b0405c9cb0:99e87d210fd8ce25",
                     "a6b430c735907222:99e87d210fd8ce25",
                     "c2fd39ee880340f2:99e87d210fd8ce25"),
+    # new in PR 49 (its own tree's: four passes over two layers, the
+    # post-norms, a head and an exit gate a pass under `loop.head`); every
+    # row above is the parent's
+    "ouro_tiny": ("3282a46b53f1b59b:b9265fa8a1143fdc",
+                  "2f196b66ea41495d:b9265fa8a1143fdc",
+                  "cc6e52c1bdf75295:b9265fa8a1143fdc"),
     # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text;
     # re-recorded in PR 47 (its own tree's: one backward kernel where the
     # parent's text held dQ's and dK/dV's; the operations' scopes unmoved)
@@ -99,6 +105,7 @@ PARENT_COUNTS = {
     "mellum2_tiny": (215616, 141888, 837024.0, 1182852.0),
     "qwen3_next_tiny": (344008, 171976, 1077936.0, 1422000.0),
     "laguna_tiny": (260480, 180544, 1060248.0, 1405635.0),  # PR 45's own
+    "ouro_tiny": (115329, 115329, 2571288.0, 3947544.0),  # PR 49's own
     "gpt2_124m.json": (124356864, 124356864, 798045696.0, 769734144.0),
     "granite_4_0_h_micro.json": (772160448, 772160448, 4769113728.0,
                                  4725073536.0),
@@ -114,6 +121,8 @@ PARENT_COUNTS = {
                                 1213237632.0),
     "laguna_s_2_1.json": (672126976, 381932544, 2444659776.0,  # PR 45's own
                           2121808896.0),
+    "ouro_2_6b.json": (612438017, 612438017, 15503818776.0,  # PR 49's own
+                       12483919896.0),
 }
 
 
@@ -276,6 +285,11 @@ PLANS = {
         slot=(4, (2, 0, 0)), segments=True,
         refused=[dict(swa_heads=5), dict(attn_out_gate=True),
                  dict(swa_rope_fraction=0.2), dict(sliding_window=None)]),
+    # the loop is no part of the plan: one segment, run `loop_steps` times
+    "ouro_tiny": dict(plan=(((_a,), 2),), deep=(48, (((_a,), 48),)),
+                      slot=(1, (0, 0, 1)), segments=False,
+                      refused=[dict(loop_steps=1), dict(loop_steps=0),
+                               dict(norm="layernorm")]),
 }
 
 
@@ -327,6 +341,11 @@ def test_decoding_serves_a_row_or_says_its_sentence(preset):
     toks = jnp.zeros((2, 8), jnp.int32)
     why = [tfm.MIXERS[m].no_decode for m, _ in cfg.layer_kinds()
            if tfm.MIXERS[m].no_decode]
+    if cfg.loop_steps > 1:  # every row holds keys and values, ONE slab each
+        with pytest.raises(NotImplementedError,
+                           match="a cache slab a pass a layer"):
+            prefill(params, toks, cfg, 16)
+        return
     if not why:
         kind, layers = _kv_stack(params, cfg)
         assert kind == cfg.layer_kinds()[0]
@@ -340,7 +359,22 @@ def test_decoding_serves_a_row_or_says_its_sentence(preset):
     assert word in why[0]
 
 
+def test_the_pipeline_refuses_a_looped_stack():
+    """`pipeline_loss_fn` visits a stage once a microbatch: a looped stack
+    is refused where the loss is built, with what is missing; the post-norms
+    alone are the shared layer body's and pass."""
+    from ray_tpu.parallel import MeshSpec, make_mesh
+    from ray_tpu.parallel.pipeline import pipeline_loss_fn
+
+    mesh = make_mesh(MeshSpec(pipe=2), devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError,
+                       match="a stage visited once a pass"):
+        pipeline_loss_fn(configs.ouro_tiny(), mesh)
+    pipeline_loss_fn(configs.ouro_tiny(loop_steps=1, exit_gate=False), mesh)
+
+
 UNAPPLIED = {
+    "post_norm": dict(post_norm=True),
     "attn_out_gate": dict(attn_out_gate=True),
     "attn_head_gate": dict(attn_head_gate=True),
     "norm_offset": dict(norm_offset=1.0),

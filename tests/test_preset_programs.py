@@ -12,7 +12,7 @@ import pytest
 
 from ray_tpu.models import configs, transformer as tfm
 from ray_tpu.ops import moe
-from test_expert_shares import family
+from test_expert_shares import DENSE_FAMILIES, family
 
 pytestmark = pytest.mark.usefixtures("exact_matmuls")
 
@@ -98,9 +98,8 @@ def test_train_step_returns_the_counters_and_folds_them(preset, policy):
 # windowed layer's and a full one's; the latent layer's two projections.
 FLASH_LEAVES = {"mellum2_tiny": ("swa_wkv", "full_wq"),
                 "kanana2_tiny": ("mla_wq", "mla_wkva"),
-                "laguna_tiny": ("swa_wkv", "swa_gate", "full_wq")}
-
-
+                "laguna_tiny": ("swa_wkv", "swa_gate", "full_wq"),
+                "ouro_tiny": ("wq_first", "wo_last")}
 def _rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
@@ -110,7 +109,9 @@ def test_the_flash_path_is_the_xla_path(preset, monkeypatch):
     """The model through the flash kernels (interpret mode here: windowed
     and full layers at heads of 16; keys 24 wide and values 16), under both
     remat policies, on the family's seeded weights: the loss and the
-    gradients of the family's attention leaves are the XLA path's."""
+    gradients of the family's attention leaves are the XLA path's. The
+    looped stack's 4 | 4 heads of 16 make eight calls a step, every pass's
+    residuals kept under "full"."""
     W, _, sizes = family(preset)
     cfg = getattr(configs, preset)(dtype=jnp.float32)
     sz = sizes(cfg)
@@ -122,7 +123,9 @@ def test_the_flash_path_is_the_xla_path(preset, monkeypatch):
     want_loss, g = jax.jit(grad(cfg))(params)
     want = W.program_leaves(cfg, sz, g)
     monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
-    for policy in ("dots", "full"):
+    # ROADMAP D11: the looped stack's eight interpreted calls a policy are
+    # 15 s each; its cell runs "full", and "dots" differs in no kernel call
+    for policy in ("full",) if preset in DENSE_FAMILIES else ("dots", "full"):
         remat = dataclasses.replace(cfg, remat=True, remat_policy=policy)
         loss, g = grad(remat)(params)
         assert abs(float(loss) - float(want_loss)) < 1e-5, policy
