@@ -29,12 +29,23 @@ passes worked, and a checksum of the loss's and every gradient's bits. Run
 in a tree without `moe.block_rows` (a parent's, with this file copied into
 it) it gives that tree's body at the same inputs: the line to set beside.
 
+`combine` times a first window's sum back to the tokens (the combine, and
+`dx` in the backward) at each routed cell's (tokens, d, window rows, held
+rows), both ways: the scatter-add (`moe.scatter_rows`) against the token order
+(`moe.token_order`; and the same order from a prefix sum and two integer
+scatters), the row pass through it (the forward's scaling pass, which cuts
+its blocks today; the backward's, a pass more) and the one-hot + grouped
+product (`moe.block_sums`) at each of `TOKEN_BLOCKS` and `SUM_TILES`, each
+part alone and the whole, with the largest difference between the two sums
+(NaN is written past the held rows: none may reach a sum).
+
 One JSON line a case. Off the chip the script fails at once.
 
     python3 benchmarks/probe_moe.py            # the sweep, both cells
     python3 benchmarks/probe_moe.py skew       # the module's window, skewed
     python3 benchmarks/probe_moe.py parts      # the passes alone
     python3 benchmarks/probe_moe.py rows       # ms against the held total
+    python3 benchmarks/probe_moe.py combine    # the window's sum, both ways
 """
 from __future__ import annotations
 
@@ -70,6 +81,20 @@ SKEW = (0.0, 0.03, 0.06, 0.12)  # added to the held experts' scores in the selec
 # (rows, contracted, columns) tiles of the Pallas grouped matmul, for `parts`.
 GMM_TILES = ((512, 768, 896), (512, 1152, 896), (1024, 768, 896),
              (256, 1152, 896), (512, 1152, 1792), (512, 2304, 896))
+# `combine`: (cell, tokens, d, first window's rows, held rows in it).
+SUMS = (("mellum2", 16384, 2304, 81920, 37000),
+        ("mellum2", 16384, 2304, 81920, 60000),
+        ("kanana", 16384, 2048, 30720, 12000),
+        ("qwen3_next", 16384, 2048, 25600, 10000),
+        ("laguna", 8192, 3072, 8192, 3000),
+        ("kimi_linear", 8192, 2304, 8192, 4500))
+K = 8  # assignments a token (what the held rows' tokens are drawn from)
+TOKEN_BLOCKS = (128, 256, 512)
+# (rows, columns) tiles of the sum's product beside `moe._tiles`' own (None:
+# the whole of the case, the others the product alone); a block of tokens is
+# one tile.
+SUM_TILES = (None, (256, 1152), (1024, 1152), (512, 768), (512, 2304),
+             (256, 1024), (1024, 1024), (512, 2048), (512, 1536))
 FACTOR = moe.HELD_WINDOW_FACTOR  # the module's own, which the rules replace
 MIN_TOKENS = getattr(moe, "HELD_WINDOW_MIN_TOKENS", 0.0)  # (a rule: factor alone)
 SHARE = getattr(moe, "FURTHER_WINDOW_SHARE", 1.0)
@@ -275,6 +300,112 @@ def parts_ms(cell, factor):
     return row, True
 
 
+def combine_ms(case):
+    """One window's sum back to the tokens, by the scatter-add and by the
+    token-ordered product, part by part."""
+    name, T, d, W, held = case
+    bf = jnp.bfloat16
+    rng = np.random.default_rng(W + held)
+    # A token's k assignments fall on k experts: in the window's order (by
+    # expert) the tokens of the held rows come as drawn.
+    at_w = np.full(W, T * K, np.int32)
+    at_w[:held] = rng.choice(T * K, held, replace=False)
+    at_w = jnp.asarray(at_w)
+    tok = at_w // K
+    vals = jnp.where((jnp.arange(W) < held)[:, None], jax.random.normal(
+        jax.random.key(held), (W, d), bf), jnp.nan)
+    wrow = jax.random.uniform(jax.random.key(1), (W,), jnp.float32)
+    n = jnp.int32(held)
+    block = moe.block_rows(W)
+    row = {"cell": name, "tokens": T, "d": d, "window_rows": W, "held": held,
+           "block_rows": block}
+
+    def passes(body, init, n):
+        return jax.lax.fori_loop(0, (n + block - 1) // block, lambda b, c: (
+            jax.lax.dynamic_update_slice_in_dim(c, body(b * block), b * block,
+                                                0)), init)
+
+    cut = lambda a, at: jax.lax.dynamic_slice_in_dim(a, at, block)
+    f32 = lambda a: a.astype(jnp.float32)
+    scale_cut = lambda vals, wrow, n: passes(lambda at: (
+        f32(cut(vals, at)) * cut(wrow, at)[:, None]).astype(bf),
+        jax.lax.empty((W, d), bf), n)
+    scale_by = lambda vals, wrow, by, n: passes(lambda at: (
+        f32(vals[cut(by, at)]) * wrow[cut(by, at)][:, None]).astype(bf),
+        jax.lax.empty((W, d), bf), n)
+    through = lambda vals, by, n: passes(
+        lambda at: vals[cut(by, at)], jax.lax.empty((W, d), bf), n)
+
+    def order_scans(at_w):
+        """`moe.token_order`'s permutation with no sort, from the rows'
+        assignments `at_w` (token x k + choice; T k where a row holds none):
+        a row's place is the number of the window's assignments below its
+        own, a prefix sum over a mask of them (an integer scatter), and the
+        permutation is the places' inverse (a second)."""
+        here = jnp.zeros((T * K + 1,), jnp.int32).at[at_w].set(1)
+        place = jnp.where(at_w < T * K, (jnp.cumsum(here) - here)[at_w],
+                          jnp.arange(W))
+        return jnp.zeros((W,), jnp.int32).at[place].set(
+            jnp.arange(W, dtype=jnp.int32))
+
+    order = jax.jit(lambda tok: moe.token_order(tok, T, 256))
+    by, tok_t, _ = order(tok)
+    # (Inside a token the sort keeps the window's order, by expert, and the
+    # prefix sum the choices': the same runs of rows.)
+    row["orders_agree"] = bool(jnp.all(
+        tok[jax.jit(order_scans)(at_w)] == tok_t))
+    parts = {
+        "scatter_ms": (lambda tok, vals, n: moe.scatter_rows(
+            tok, vals, n, T), (tok, vals, n)),
+        "order_sort_ms": (order, (tok,)),
+        "order_scans_ms": (order_scans, (at_w,)),
+        "scale_cut_ms": (scale_cut, (vals, wrow, n)),
+        "scale_through_ms": (scale_by, (vals, wrow, by, n)),
+        "pass_through_ms": (through, (vals, by, n)),
+        "today_fwd_ms": (lambda tok, vals, wrow, n: moe.scatter_rows(
+            tok, scale_cut(vals, wrow, n), n, T), (tok, vals, wrow, n)),
+        "today_bwd_ms": (lambda tok, vals, n: moe.scatter_rows(
+            tok, vals, n, T), (tok, vals, n)),
+    }
+    for key, (fn, args) in parts.items():
+        row[key] = _ms(jax.jit(fn), args, steps=20)
+    want = f32(jax.jit(parts["today_fwd_ms"][0])(tok, vals, wrow, n))
+    ok = True
+    for tb in TOKEN_BLOCKS:
+        sizes = jax.jit(lambda tok: moe.token_order(tok, T, tb)[2])(tok)
+        for tiles in SUM_TILES:
+            if tiles and d % tiles[1]:
+                continue
+            label = "%d_%s" % (tb, "%dx%d" % tiles if tiles else "own")
+            tiling = tiles and (lambda m, k, n, tiles=tiles: (
+                tiles[0], tb, tiles[1]))
+            try:
+                row[f"sum_{label}_ms"] = _ms(jax.jit(
+                    lambda tok_t, rows_t, sizes: moe.block_sums(
+                        tok_t, rows_t, sizes, T, tb, tiling)),
+                    (tok_t, vals[by], sizes), steps=20)
+            except Exception as e:  # a tiling the kernel refuses
+                row[f"error_{label}"] = f"{type(e).__name__}: {e}"[:160]
+
+        def fwd(tok, vals, wrow, n):
+            by, tok_t, sizes = moe.token_order(tok, T, tb)
+            return moe.block_sums(tok_t, scale_by(vals, wrow, by, n), sizes,
+                                  T, tb)
+
+        def bwd(tok, vals, n):
+            by, tok_t, sizes = moe.token_order(tok, T, tb)
+            return moe.block_sums(tok_t, through(vals, by, n), sizes, T, tb)
+
+        row[f"fwd_{tb}_ms"] = _ms(jax.jit(fwd), (tok, vals, wrow, n),
+                                  steps=20)
+        row[f"bwd_{tb}_ms"] = _ms(jax.jit(bwd), (tok, vals, n), steps=20)
+        got = f32(jax.jit(fwd)(tok, vals, wrow, n))
+        row[f"diff_{tb}"] = float(jnp.max(jnp.abs(got - want)))
+        ok = ok and row[f"diff_{tb}"] <= 0.0625 * float(
+            jnp.max(jnp.abs(want)))
+    return row, ok
+
+
 def main(argv) -> int:
     dev = require_tpu()
     enable_compile_cache()
@@ -293,6 +424,10 @@ def main(argv) -> int:
         failed += not ok
         print(json.dumps(row), flush=True)
 
+    if argv[1:] == ["combine"]:
+        for case in SUMS:
+            report(combine_ms, case)
+        return 1 if failed else 0
     for cell in CELLS + [KANANA] if argv[1:] == ["rows"] else CELLS:
         if argv[1:] == ["rows"]:
             seen = set()

@@ -204,11 +204,13 @@ def softmax_route(x: jax.Array, router_w: jax.Array, *,
 # (In the traced step the held experts take 38.0 ms a layer at 2.5 and 24.2
 # at 1.25.) With a router that stays even (a balancing term in the cell's job
 # file, PERF.md section 7 (d)) 1.25 is the value.
-# What a margin costs since PR 43: memory (the [W, .] buffers), and the sort
-# and permutation of the unfilled rows a scatter-add is given (half the
-# window where the held rows end there). Every other pass works the blocks
-# the held rows reach into (`block_rows`), so the layer's time follows the
-# held total: same probe, `rows`, mellum2's shape at a held total of 0.5 /
+# What a margin costs since PR 43: memory (the [W, .] buffers), and where a
+# sum back to the tokens is a scatter-add (`scatter_rows`), the sort and
+# permutation of the unfilled rows it is given (half the window where the
+# held rows end there); the grouped product that sums a first window on the
+# chip since PR 50 (`block_sums`) visits no tile past the held rows. Every
+# other pass works the blocks the held rows reach into (`block_rows`), so the
+# layer's time follows the held total: same probe, `rows`, mellum2's shape at a held total of 0.5 /
 # 1.0 / 1.5 / 2.0 / 2.5 of the even share, ms: 27.71 / 33.40 / 45.05 / 50.72
 # / 56.41 where the whole-window passes took 47.89 / 51.24 / 54.58 / 57.91 /
 # 61.29; the cell at 2.5: 35,816-35,839 -> 39,670-39,945 tokens/s/chip over
@@ -240,11 +242,14 @@ HELD_WINDOW_MIN_TOKENS = 1.0
 FURTHER_WINDOW_SHARE = 0.5
 
 # The first window's grouped products' outputs (gate/up [W, 2F], down [W, d])
-# carry these names in the forward rule, so that a remat policy can keep them
-# (`save_only_these_names(*RESIDUAL_NAMES)`, models/transformer.py
+# carry the first two names in the forward rule, so that a remat policy can
+# keep them (`save_only_these_names(*RESIDUAL_NAMES)`, models/transformer.py
 # `layer_scan_body`, under either policy): the backward then gathers no rows
-# and runs no product a second time.
-RESIDUAL_NAMES = ("moe_gate_up", "moe_down")
+# and runs no product a second time. The third is the window's rows in token
+# order and their tokens (two [W] int32, `token_order`), made where the
+# window's sums are the grouped product: the backward's dx takes its rows
+# through the same order.
+RESIDUAL_NAMES = ("moe_gate_up", "moe_down", "moe_token_order")
 
 
 def use_kernels(platform: str, dtype, widths: Tuple[int, ...],
@@ -349,6 +354,102 @@ def _trips(held, rows: int, further_rows: int, further: int):
                         0, further)
 
 
+# A first window's two sums back to the tokens (the combine, and dx in the
+# backward) are `block_sums`' grouped product over token-ordered rows where
+# the grouped products are kernels and the tokens are whole blocks of this
+# many, else `scatter_rows`' scatter-add (the CPU, a mesh, float32, odd
+# widths; the further windows always: a Pallas call more costs set-up, as
+# their `ragged_dot`). Probe on the chip (`benchmarks/probe_moe.py combine`,
+# PR 50), ms a call at each routed cell's tokens / d / window rows / held
+# rows. Columns: the scatter-add as dx calls it | with the combine's scaling
+# pass before it | the token order, a sort | the same from a prefix sum and
+# two integer scatters | the scaling pass over cut blocks | through the order
+# | a plain pass through the order | one-hot + product at blocks of 128 /
+# 256 / 512 tokens and `_tiles` | at 256 and `_sum_tiles` | the combine whole
+# (order + scaling pass through it + product) at 128 / 256 / 512 | dx whole:
+#   mellum2 16384/2304/81920/37,000  4.95 | 5.54 | 0.38 | 1.38 | 0.62 | 1.85
+#     | 1.59 | 0.79 / 0.78 / 0.89 | 0.72 | 3.37 / 3.32 / 3.42 | 3.06 / 3.01 / 3.12
+#   mellum2 .../60,000               7.97 | 8.85 | 0.36 | 1.38 | 0.91 | 2.75
+#     | 2.37 | 1.02 / 0.96 / 1.20 | 0.88 | 4.53 / 4.41 / 4.65 | 4.08 / 3.97 / 4.20
+#   kanana 16384/2048/30720/12,000   1.87 | 2.04 | 0.39 | 0.54 | 0.22 | 0.45
+#     | 0.37 | 0.47 / 0.44 / 0.45 | 0.41 | 1.15 / 1.11 / 1.12 | 1.07 / 1.02 / 1.03
+#   qwen3_next 16384/2048/25600/10,000  1.71 | 1.84 | 0.40 | 0.45 | 0.21 | 0.46
+#     | 0.39 | 0.45 / 0.42 / 0.42 | 0.39 | 1.00 / 0.98 / 1.03 | 0.93 / 0.91 / 0.96
+#   laguna 8192/3072/8192/3,000      1.03 | 1.03 | 0.38 | 0.21 | 0.23 | 0.21
+#     | 0.22 | 0.31 / 0.28 / 0.28 | 0.27 | 0.48 / 0.46 / 0.47 | 0.46 / 0.44 / 0.45
+#   the hybrid 8192/2304/8192/4,500  1.43 | 1.45 | 0.38 | 0.20 | 0.20 | 0.22
+#     | 0.20 | 0.26 / 0.24 / 0.24 | 0.23 | 0.45 / 0.44 / 0.46 | 0.42 / 0.41 / 0.42
+# (both ways' sums agree to the last bit at every shape but one, 2e-9 apart).
+# A pass through the order is a row gather (0.9 ms for 37 k rows of 2,304)
+# and the block's `dynamic_update_slice` (0.7), two and a half times the cut
+# pass: the larger part of what the product's way costs. The order is made
+# once a layer and kept for the backward (`RESIDUAL_NAMES`), so a layer pays
+# 0.38 + 2.94 + 2.63 ms at mellum2's shape where the scatter-adds took 10.49.
+TOKEN_BLOCK = 256
+
+
+def scatter_rows(tok, vals, held, tokens: int):
+    """Zeros [tokens, d] with `vals` [W, d] added at the tokens `tok`
+    (`tokens`: skipped), as ONE scatter-add (float32 sums, rounded once),
+    over the window's first half where its `held` filled rows end there: the
+    rows past them are skipped either way, so the sums are the whole
+    window's, but XLA sorts and permutes every row it is given (3.0 ms of a
+    call's 7.8 at mellum2's shape), and a call costs 1.4-1.6 ms whatever its
+    rows, 0.08 ms per 1,000 rows it adds and 0.01 per 1,000 it skips, so it
+    is never cut into blocks (PERF.md section 6, PR 43). (A quarter as a
+    third choice made the step's program large enough for XLA to recompute a
+    product of 10 ms to fit it: same section.)"""
+    half = -(-len(tok) // 2)
+    add = lambda tok, vals: jnp.zeros(
+        (tokens, vals.shape[1]), vals.dtype).at[tok].add(vals, mode="drop")
+    return jax.lax.cond(
+        held > half, add,
+        lambda tok, vals: add(tok[:half], vals[:half]), tok, vals)
+
+
+def token_order(tok, tokens: int, block: int):
+    """The token of each of a window's rows (`tokens` where a row holds no
+    held assignment) -> (the rows in a stable order by token, those that hold
+    nothing last; their tokens in that order; how many held rows each block
+    of `block` tokens has). Integers only. One sort that carries the rows
+    along: on the chip it is 0.1 ms at 81,920 rows, where taking the tokens
+    through the order afterwards (a gather of scalars) is 1.2 (the traced
+    step, PERF.md section 6, PR 50)."""
+    tok_t, by_token = jax.lax.sort(
+        (tok, jnp.arange(len(tok), dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    sizes = jnp.sum((tok // block)[:, None] == jnp.arange(tokens // block),
+                    axis=0, dtype=jnp.int32)
+    return by_token, tok_t, sizes
+
+
+def _sum_tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """Tiles (rows, tokens, columns) of `block_sums`' product: a block of
+    tokens whole, the widest columns that divide the width."""
+    return (_tiles(m, k, n)[0], k, next(
+        (t for t in (2304, 2048, 1536, 1152, 1024, 768, 512, 256)
+         if n % t == 0), 128))
+
+
+def block_sums(tok_t, rows_t, sizes, tokens: int, block: int, tiling=None):
+    """Zeros [tokens, d] with the rows `rows_t` [W, d] added at the tokens
+    `tok_t`, both in `token_order`: the rows of a block of `block` tokens
+    are one contiguous run, so the block's sums are a product one-hot^T
+    [block, run] x rows [run, d], a grouped product whose groups are the
+    token blocks (`megablox.tgmm`): float32 sums rounded once to the rows'
+    dtype, zeros where a block has no row, no row past the groups' end
+    read into a sum."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    onehot = ((tok_t % block)[:, None] == jnp.arange(block)).astype(
+        rows_t.dtype)                                          # [W, block]
+    return tgmm(onehot.T, rows_t, sizes,
+                preferred_element_type=rows_t.dtype,
+                tiling=tiling or _sum_tiles,
+                interpret=flash_attention._interpret()).reshape(
+                    tokens, rows_t.shape[1])
+
+
 def moe_ffn_held(
     x: jax.Array,          # [B, S, d] (cfg.dtype)
     router_w: jax.Array,   # [d, E]      E = every expert of the layer
@@ -371,7 +472,9 @@ def moe_ffn_held(
     where all are held), always, then a loop of as many smaller ones
     (`further_window_rows`) as the held assignments reach into. Each is a
     gather of the window's token rows, two grouped products over the rows the
-    routing put there and a scatter-add back; the products' grids stop at the
+    routing put there and a sum back to the tokens (a third grouped product,
+    over the rows in token order, where the first window's are kernels, else
+    a scatter-add: `TOKEN_BLOCK`); the products' grids stop at the
     groups' end and every other pass runs over row blocks (`block_rows`), as
     many as the window's held rows reach into, so the device work follows
     the rows the routing filled, a block at a time, and the window's margin
@@ -386,6 +489,7 @@ def moe_ffn_held(
     `rows_worked` (rows of the blocks the row passes touched, over every
     window worked: whole blocks, the held rows at least)."""
     from ray_tpu.parallel.sharding import current_sharding_ctx
+    from ray_tpu.util import tracing
 
     B, S, d = x.shape
     T = B * S
@@ -419,17 +523,19 @@ def moe_ffn_held(
     # T, one past the tokens: gathered from there they are zeros, scattered
     # to there they are skipped. So a window's passes follow the rows the
     # routing filled, each in the way that costs least on the chip
-    # (`probe_moe.py rows`, PERF.md section 6, PR 43): the three scatter-adds
-    # stay one call over the window each (a call costs 1.4-1.6 ms whatever
-    # its rows and 0.08 ms per 1,000 rows it adds, 0.01 per 1,000 it skips;
-    # its sums are the whole window's in one order and one precision), and
-    # every other pass over a [W, .] buffer (gather, mask, SiLU, scale, row
-    # sums, and their transposes) runs over row blocks (`block_rows`), as
+    # (`probe_moe.py rows` / `combine`, PERF.md section 6, PRs 43 and 50):
+    # the two sums back to the tokens are one call over the window each
+    # (`add_rows`: a grouped product over token-ordered rows or a
+    # scatter-add; float32 sums of the whole window, rounded once), the
+    # weights' cotangent one small scatter-add, and every other pass over a
+    # [W, .] buffer (gather, mask, SiLU, scale, row sums, the rows into token
+    # order, and their transposes) runs over row blocks (`block_rows`), as
     # many as the window's held rows reach into: a loop of dynamic length, a
     # block's rows cut out of the buffer and put back. Rows past the last
     # worked block hold whatever the buffer held (`lax.empty`, or the
     # product's output that the pass overwrites in place): no pass reads
-    # them and the scatter-adds skip them. Inside the last worked block,
+    # them, the scatter-adds skip them and the sums' product masks them as
+    # every grouped product does. Inside the last worked block,
     # what a product wrote past the held rows is zeroed once, in the pass
     # that applies the SiLU (and its transpose), since a NaN times a zero
     # weight is a NaN.
@@ -471,21 +577,25 @@ def moe_ffn_held(
         make = lambda: jax.lax.empty(shape, dt)
         return jax.lax.cond(held >= 0, make, make)
 
-    def add_rows(tok, vals, held):
-        """Zeros [T, d] with `vals` [W, d] added at the tokens `tok` (T:
-        skipped), as ONE scatter-add, over the window's first half where its
-        `held` filled rows end there: the rows past them are skipped either
-        way, so the sums are the whole window's, but XLA sorts and permutes
-        every row it is given (3.0 ms of a call's 7.8 at mellum2's shape).
-        (A quarter as a third choice made the step's program large enough
-        for XLA to recompute a product of 10 ms to fit it: PERF.md section
-        6, PR 43.)"""
-        half = -(-len(tok) // 2)
-        add = lambda tok, vals: jnp.zeros((T, d), vals.dtype).at[tok].add(
-            vals, mode="drop")
-        return jax.lax.cond(
-            held > half, add,
-            lambda tok, vals: add(tok[:half], vals[:half]), tok, vals)
+    def add_rows(tok, held, cut, order_t):
+        """How a window's rows [W, d] are summed back to the tokens, from
+        the rows' tokens `tok` -> (`rows_at(a, at)`: the block at `at` of
+        `a` [W, ...] in the order the sum takes its rows, `add(rows)`: the
+        sums [T, d]). `order_t` is a first window's `token_order` (the rows
+        in token order, summed by `block_sums`), () (a first window's rows
+        as they lie, `scatter_rows`) or None, a further window's
+        scatter-add; a first window's sum is counted where it is traced
+        (`train.moe_combine.product` / `.scatter`)."""
+        if order_t is not None:
+            tracing.observe("train.moe_combine." + (
+                "product" if order_t else "scatter"), 0, slow=False)
+        if not order_t:
+            return cut, lambda rows: scatter_rows(tok, rows, held, T)
+        by, tok_t, sizes_t = order_t
+        return (lambda a, at: a.at[cut(by, at)].get(
+            mode="promise_in_bounds"),
+                lambda rows: block_sums(tok_t, rows, sizes_t, T,
+                                        TOKEN_BLOCK))
 
     # The first window's products by the kernels where `use_kernels` says so;
     # the further ones, which an even routing never reaches, by `ragged_dot`
@@ -493,22 +603,24 @@ def moe_ffn_held(
     # program is traced (0.4 s each on the chip's worker), and the loop's
     # eight would put `setup_s` past its bound (PERF.md section 6, PR 34).
     ctx = current_sharding_ctx()
-    first = (0, W, grouped_products(use_kernels(
-        jax.devices()[0].platform, dtype, (d, F),
-        ctx is not None and ctx[0].size > 1), dtype))
-    further = lambda i: (W + (i - 1) * W2, W2, grouped_products(False, dtype))
+    kernels = use_kernels(jax.devices()[0].platform, dtype, (d, F),
+                          ctx is not None and ctx[0].size > 1)
+    first = lambda order_t: (0, W, grouped_products(kernels, dtype), order_t)
+    further = lambda i: (W + (i - 1) * W2, W2,
+                         grouped_products(False, dtype), None)
 
     def swiglu(gu, valid):
         gu = jnp.where(valid, gu, 0)  # on the way in: transposed, it zeroes
         return jax.nn.silu(gu[:, :F]) * gu[:, F:]  # the cotangent going out
 
-    def window(xf, w1, w2, wflat, order, ends, lo, rows, products):
+    def window(xf, w1, w2, wflat, order, ends, lo, rows, products, order_t):
         """-> (the window's part of the output [T, d], how many of its rows
         were held assignments, the rows its passes worked, the two products'
         outputs)."""
         grouped = products[0]
         tok, _, wrow, sizes, valid, n = rows_of(wflat, order, ends, lo, rows)
         each, cut, put, touched = row_passes(rows, n)
+        rows_at, add = add_rows(tok, n, cut, order_t)
         xb = each(lambda at, xb: put(
             xb, take(xf, cut(tok, at)).astype(dtype), at),
             fresh((rows, d), dtype, n))
@@ -519,12 +631,12 @@ def moe_ffn_held(
         yb = grouped(act, w2, sizes)                           # [W, d]
         # (Written over the gathered rows, which the first product has read.)
         scaled = each(lambda at, xb: put(
-            xb, (f32(cut(yb, at)) * cut(wrow, at)[:, None]).astype(dtype),
-            at), xb)
-        return add_rows(tok, scaled, n), n, touched, (gu, yb)
+            xb, (f32(rows_at(yb, at)) * rows_at(wrow, at)[:, None]).astype(
+                dtype), at), xb)
+        return add(scaled), n, touched, (gu, yb)
 
-    def window_t(xf, w1, w2, wflat, order, ends, lo, rows, products, gu, yb,
-                 dy):
+    def window_t(xf, w1, w2, wflat, order, ends, lo, rows, products, order_t,
+                 gu, yb, dy):
         """The window's transpose: the output's cotangent dy [T, d] -> those
         of xf, w1, w2, wflat, given the products' outputs. A pass writes
         over the buffer it has read where the shapes allow: `yb`'s blocks
@@ -534,6 +646,7 @@ def moe_ffn_held(
         tok, at_w, wrow, sizes, valid, n = rows_of(wflat, order, ends, lo,
                                                    rows)
         each, cut, put, _ = row_passes(rows, n)
+        rows_at, add = add_rows(tok, n, cut, order_t)
 
         def gathers(at, carry):
             xb, yb, dwrow = carry
@@ -554,8 +667,11 @@ def moe_ffn_held(
             return put(dact, act, at), put(gu, t(cut(dact, at))[0], at)
 
         act, dgu = each(swiglu_t, (grouped_t(dyb, w2, sizes), gu))
-        dxb = grouped_t(dgu, w1, sizes)                        # [W, d]
-        return (add_rows(tok, dxb.astype(xf.dtype), n),
+        dxb = grouped_t(dgu, w1, sizes).astype(xf.dtype)       # [W, d]
+        if order_t:  # a pass more: the rows into the sum's order
+            dxb = each(lambda at, rows_t: put(rows_t, rows_at(dxb, at), at),
+                       fresh((rows, d), xf.dtype, n))
+        return (add(dxb),
                 grouped_tw(xb, dgu, sizes), grouped_tw(act, dyb, sizes),
                 jnp.zeros_like(wflat).at[at_w].add(dwrow, mode="drop"))
 
@@ -571,7 +687,7 @@ def moe_ffn_held(
     # would be added to zeros.
     def worked(*args):
         at, trips = args[:6], args[6]
-        *out, res = window(*at, *first)
+        *out, res = window(*at, *first(args[7:]))
 
         def body(i, carry):
             return tuple(a + b for a, b in zip(
@@ -580,8 +696,8 @@ def moe_ffn_held(
         return jax.lax.fori_loop(1, trips, body, tuple(out)), res
 
     @jax.custom_vjp
-    def worked_windows(*args):  # xf, w1, w2, wflat, order, ends, trips
-        return worked(*args)[0]
+    def worked_windows(*args):  # xf, w1, w2, wflat, order, ends, trips and
+        return worked(*args)[0]  # the first window's token order, if made
 
     def fwd(*args):
         out, res = worked(*args)
@@ -597,15 +713,23 @@ def moe_ffn_held(
             return tuple(a + b for a, b in zip(acc, g))
 
         acc = jax.lax.fori_loop(1, trips, body,
-                                window_t(*at, *first, *first_res, ct[0]))
+                                window_t(*at, *first(args[7:]), *first_res,
+                                         ct[0]))
         return acc + tuple(np.zeros(a.shape, jax.dtypes.float0)
                            for a in args[4:])
 
     worked_windows.defvjp(fwd, bwd)
 
     with jax.named_scope("moe.experts"):
+        order_t = ()
+        if kernels and T % TOKEN_BLOCK == 0:
+            # Once a layer, kept for the backward as the products' outputs.
+            by, tok_t, sizes_t = token_order(
+                rows_of(wflat, order, ends, 0, W)[0], T, TOKEN_BLOCK)
+            order_t = (checkpoint_name(by, RESIDUAL_NAMES[2]),
+                       checkpoint_name(tok_t, RESIDUAL_NAMES[2]), sizes_t)
         y, n_worked, touched = worked_windows(xf, w1, w2, wflat, order, ends,
-                                              trips)
+                                              trips, *order_t)
     counters = {
         "assigned": f32(held),
         "load_max": f32(jnp.max(counts)),

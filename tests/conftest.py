@@ -12,7 +12,38 @@ cpu_mesh_env(8)
 import pytest  # noqa: E402
 
 
+# ROADMAP D11: `--dist loadfile` hands a worker a whole file, and pytest-xdist
+# (3.8) sends the files out by their number of cases, most first, so a file
+# of five cases and 200 s starts last and is the run's tail (90 s of 1,300).
+# The files of 60 case-seconds or more in the last whole run (`PERF.md`
+# section 7) go out first instead, longest first; the rest follow as xdist
+# orders them. A PR that moves a file across that line moves its name here.
+LONGEST_FILES = (
+    "test_kda_kernel_compile", "test_kimi_linear_reference", "test_laguna",
+    "test_preset_programs", "test_qwen3_next", "test_kda", "test_moe",
+    "test_models", "test_expert_shares", "test_model_table",
+    "test_flash_backward", "test_kimi_linear", "test_tensor_overlap",
+    "test_kda_scalar", "test_granite_hybrid", "test_ssd_kernels",
+    "test_kda_remat", "test_flash_attention", "test_flash_attention_shapes",
+    "test_rollout", "test_serve", "test_llm_engine", "test_generate",
+    "test_ouro", "test_tensor_overlap_rows", "test_moe_held_loop",
+    "test_fused_ce", "test_kanana2", "test_replay_buffers",
+    "test_serve_disagg", "test_rllib", "test_transfer_fastpath",
+    "test_actors")
+
+
+def pytest_collection_modifyitems(config, items):
+    cases = {}
+    for item in items:
+        cases[item.path] = cases.get(item.path, 0) + 1
+    rank = {name: i for i, name in enumerate(LONGEST_FILES)}
+    items.sort(key=lambda item: (rank.get(item.path.stem, len(rank)),
+                                 -cases[item.path]))
+
+
 def pytest_configure(config):
+    # (the scheduler keeps the order above: `--no-loadscope-reorder`)
+    config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers",
         "slow: long-running stress/chaos variants excluded from tier-1 "

@@ -95,12 +95,14 @@ def test_expert_shares_add_up_to_the_uncut_layer(shares, factor, monkeypatch):
     rows = moe.held_window_rows(every // c["k"], c["k"], c["E"], per)
     assert rows == {(1, None): every, (4, None): 640, (16, None): 256,
                     (4, 0.5): 128, (4, 4.0): every}[shares, factor]
+    # (one program for every share: `held_first` is an operand)
+    share = jax.jit(lambda first, wgu, wd: moe.moe_ffn_held(
+        c["x"], c["rw"], wgu, wd, route=_sigmoid(c), held_first=first,
+        dtype=jnp.float32))
     total, assigned = 0.0, 0.0
     for r in range(shares):
-        y, cnt = moe.moe_ffn_held(
-            c["x"], c["rw"], c["wgu"][r * per:(r + 1) * per],
-            c["wd"][r * per:(r + 1) * per], route=_sigmoid(c),
-            held_first=r * per, dtype=jnp.float32)
+        y, cnt = share(r * per, c["wgu"][r * per:(r + 1) * per],
+                       c["wd"][r * per:(r + 1) * per])
         assert float(cnt["dropped"]) == 0.0
         held = float(cnt["assigned"])
         assert float(cnt["window_rows"]) == rows
@@ -249,6 +251,12 @@ def test_the_shares_add_up(preset):
     else:
         route = functools.partial(
             moe.softmax_route, experts_per_token=cfg.moe_experts_per_token)
+    # One program for every rank (`held_first` is an operand, the router's
+    # bias one where the family has it): op by op each rank's scans and
+    # conds compile again, ROADMAP D11.
+    held = jax.jit(lambda first, router, wgu, wd, **bias: moe.moe_ffn_held(
+        x, router, wgu, wd, route=functools.partial(route, **bias),
+        held_first=first, dtype=jnp.float32))
     total, assigned = shared, 0.0
     for first in range(0, f["experts"], 2):
         sz = sizes(cfg, moe_held=(first, 2))
@@ -257,10 +265,8 @@ def test_the_shares_add_up(preset):
         np.testing.assert_array_equal(  # a rank's experts are the model's
             w["moe_w_down"], w_all["e_down"][first:first + 2])
         bias = {"bias": w["router_bias"]} if "router_bias" in w else {}
-        y, cnt = moe.moe_ffn_held(
-            x, w["router"], w["moe_w_gate_up"], w["moe_w_down"],
-            route=functools.partial(route, **bias), held_first=first,
-            dtype=jnp.float32)
+        y, cnt = held(first, w["router"], w["moe_w_gate_up"],
+                      w["moe_w_down"], **bias)
         assert float(cnt["dropped"]) == 0.0
         total, assigned = total + y, assigned + float(cnt["assigned"])
         part = ref._experts(x, made, sz, ref.mm_f32)

@@ -290,8 +290,10 @@ def test_windowed_forward_and_gradients_match_reference(S, window, tiles):
 
     flash = lambda q, k, v: flash_attention(q, k, v, window=window, **tiles)
     ref = lambda q, k, v: reference_attention(q, k, v, window=window)
-    got = [flash(q, k, v), *jax.grad(loss(flash), (0, 1, 2))(q, k, v)]
-    want = [ref(q, k, v), *jax.grad(loss(ref), (0, 1, 2))(q, k, v)]
+    # (each a jitted program: op by op the small ops compile one at a time)
+    both = lambda fn: [jax.jit(fn)(q, k, v), *jax.jit(jax.grad(
+        loss(fn), (0, 1, 2)))(q, k, v)]
+    got, want = both(flash), both(ref)
     for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
         err = float(jnp.max(jnp.abs(a - b)))  # dq, dk are 0 at one key
         assert err <= 1e-4 * max(float(jnp.max(jnp.abs(b))), 1.0), (name, err)

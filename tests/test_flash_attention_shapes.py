@@ -56,9 +56,12 @@ def test_forward_and_gradients_match_reference(S, D, Dv, H, KVH, causal,
 
     flash = lambda q, k, v: flash_attention(q, k, v, causal=causal, **tiles)
     ref = lambda q, k, v: reference_attention(q, k, v, causal=causal)
-    out, grads = flash(q, k, v), jax.grad(loss(flash), (0, 1, 2))(q, k, v)
-    want = ref(*f32((q, k, v)))
-    wgrads = jax.grad(loss(ref), (0, 1, 2))(*f32((q, k, v)))
+    # (each a program of its own, jitted: op by op the wrappers' small ops
+    # compile one at a time, a quarter of the case, ROADMAP D11)
+    grad = lambda fn: jax.jit(jax.grad(loss(fn), (0, 1, 2)))
+    out, grads = jax.jit(flash)(q, k, v), grad(flash)(q, k, v)
+    want = jax.jit(ref)(*f32((q, k, v)))
+    wgrads = grad(ref)(*f32((q, k, v)))
     assert out.shape == (1, S, H, Dv) and out.dtype == dtype
     # bfloat16: P and the outputs are rounded to 2^-8, so a few 2^-8 of the
     # largest value (benchmarks/probe_flash.py TOL); a wrong mask is O(1).

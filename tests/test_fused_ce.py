@@ -108,15 +108,13 @@ def test_model_loss_path_matches_unfused():
             rngs.integers(0, cfg.vocab_size,
                           (2, S + 1 if shift else S)), jnp.int32)
         batch = {"tokens": tokens}
-        base = loss_fn(params, batch, cfg, shift_inputs=shift)
         fused_cfg = dataclasses.replace(cfg, fused_ce=True)
-        fused = loss_fn(params, batch, fused_cfg, shift_inputs=shift)
+        # (a jitted program a path: op by op the small ops compile alone)
+        both = lambda c: jax.jit(jax.value_and_grad(lambda p: loss_fn(
+            p, batch, c, shift_inputs=shift)))(params)
+        (base, g_base), (fused, g_fused) = both(cfg), both(fused_cfg)
         np.testing.assert_allclose(float(fused), float(base), rtol=2e-4)
 
-        g_base = jax.grad(lambda p: loss_fn(p, batch, cfg,
-                                            shift_inputs=shift))(params)
-        g_fused = jax.grad(lambda p: loss_fn(p, batch, fused_cfg,
-                                             shift_inputs=shift))(params)
         flat_b = jax.tree.leaves(g_base)
         flat_f = jax.tree.leaves(g_fused)
         for a, b in zip(flat_b, flat_f):

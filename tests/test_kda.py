@@ -4,6 +4,7 @@ both, and the dispatch rule. The rule at a decay a head is
 tests/test_kda_scalar.py, what a remat policy keeps of the kernels in a
 traced stack tests/test_kda_remat.py; tests/test_kda_kernel_compile.py
 compiles the kernels for the chip."""
+import functools
 import hashlib
 import re
 
@@ -53,14 +54,15 @@ def test_kda_chunked_carries_state_and_strong_decay():
     stays exact thanks to the sub-block references."""
     q, k, v, g, beta = _qkvgb(1, 128, 2, 16, 16, seed=3, decay=0.5)
     g = g.at[:, 40:60].set(-3.0)  # 20 tokens of e^-3 each in a 128-chunk
-    o, s = kda.kda_recurrent(q, k, v, g, beta)
+    o, s = jax.jit(kda.kda_recurrent)(q, k, v, g, beta)
     a = [x[:, :64] for x in (q, k, v, g, beta)]
     b = [x[:, 64:] for x in (q, k, v, g, beta)]
-    o_a, s_a = kda.kda_chunked(*a, chunk=32)
-    o_b, s_b = kda.kda_chunked(*b, chunk=32, initial_state=s_a)
+    chunked = lambda **kw: jax.jit(functools.partial(kda.kda_chunked, **kw))
+    o_a, s_a = chunked(chunk=32)(*a)
+    o_b, s_b = chunked(chunk=32)(*b, initial_state=s_a)
     np.testing.assert_allclose(jnp.concatenate([o_a, o_b], 1), o, atol=1e-5)
     np.testing.assert_allclose(s_b, s, atol=1e-5)
-    o128, _ = kda.kda_chunked(q, k, v, g, beta, chunk=128, sub=32)
+    o128, _ = chunked(chunk=128, sub=32)(q, k, v, g, beta)
     np.testing.assert_allclose(o128, o, atol=1e-5)
 
 

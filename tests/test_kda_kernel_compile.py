@@ -218,8 +218,10 @@ def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
     a token: the row-a-token window of 8,192), bfloat16, with the grouped
     products dispatched as on the chip: the
     first window's two products and their four transposes are the Pallas
-    grouped matmul at `_tiles` (`gmm` / `tgmm` in the program's text); the
-    loop of further windows keeps `ragged-dot`."""
+    grouped matmul at `_tiles` (`gmm` / `tgmm` in the program's text), and
+    its two sums back to the tokens (the combine and dx) two more `tgmm`s
+    over token blocks at `_sum_tiles` (`moe.block_sums`); the loop of
+    further windows keeps `ragged-dot` and the scatter-add."""
     import functools
 
     from ray_tpu.ops import moe
@@ -242,9 +244,9 @@ def test_held_experts_compile_for_v5e(one_chip, compiled_not_interpreted,
         sd((1, T, d), jnp.bfloat16), sd((d, E), jnp.float32),
         sd((Eh, d, 2, F), jnp.float32), sd((Eh, F, d), jnp.float32)
     ).compile().as_text()
-    assert text.count(" custom-call(") >= 6
-    assert len(re.findall(r"%t?gmm[.\d]* = ", text)) == 6, text.count("gmm")
-    assert "ragged-dot" in text
+    assert text.count(" custom-call(") >= 8
+    assert len(re.findall(r"%t?gmm[.\d]* = ", text)) == 8, text.count("gmm")
+    assert "ragged-dot" in text and " scatter(" in text
 
 
 @pytest.mark.parametrize("mixer", ["gdn", "kda"])

@@ -150,11 +150,11 @@ def test_flash_value_width_of_its_own(D, Dv, kvh):
     k = jax.random.normal(ks[1], (2, 64, kvh, D))
     v = jax.random.normal(ks[2], (2, 64, kvh, Dv))
     f = lambda q, k, v: flash_attention(q, k, v, block_q=32, block_k=32)
-    assert f(q, k, v).shape == (2, 64, 4, Dv)
-    np.testing.assert_allclose(f(q, k, v), reference_attention(q, k, v),
+    out = jax.jit(f)(q, k, v)  # (jitted programs: ROADMAP D11)
+    assert out.shape == (2, 64, 4, Dv)
+    np.testing.assert_allclose(out, jax.jit(reference_attention)(q, k, v),
                                atol=2e-5)
     loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
-    for a, b in zip(jax.grad(loss(f), argnums=(0, 1, 2))(q, k, v),
-                    jax.grad(loss(reference_attention),
-                             argnums=(0, 1, 2))(q, k, v)):
+    grads = lambda fn: jax.jit(jax.grad(loss(fn), argnums=(0, 1, 2)))(q, k, v)
+    for a, b in zip(grads(f), grads(reference_attention)):
         np.testing.assert_allclose(a, b, atol=5e-5)

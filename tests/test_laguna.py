@@ -294,8 +294,9 @@ def test_defaults_spelled_out_trace_nothing_and_each_property_counts(case):
     for change in (dict(swa_rope_theta=None), dict(swa_rope_fraction=None),
                    dict(rope_fraction=1.0), dict(attn_head_gate=False),
                    dict(yarn_factor=None), dict(moe_routed_scale=1.0)):
-        got = tfm.forward(case["params"], case["toks"][:, :-1],
-                          dataclasses.replace(cfg, **change))
+        changed = dataclasses.replace(cfg, **change)  # (a program each)
+        got = jax.jit(lambda p, t: tfm.forward(p, t, changed))(
+            case["params"], case["toks"][:, :-1])
         assert float(jnp.max(jnp.abs(got - want))) > 1e-3, change
     with pytest.raises(ValueError, match="swa_heads"):
         laguna_tiny(swa_heads=5)

@@ -21,9 +21,9 @@ def test_moe_forward_and_grads():
     cfg = moe_tiny()
     params = tfm.init_params(jax.random.key(0), cfg)
     batch = {"tokens": _tokens(cfg)}
-    loss = float(tfm.loss_fn(params, batch, cfg))
-    assert np.isfinite(loss)
-    grads = jax.grad(lambda p: tfm.loss_fn(p, batch, cfg))(params)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: tfm.loss_fn(p, batch, cfg)))(params)
+    assert np.isfinite(float(loss))
     # Routed experts receive gradient (capacity>0 ensures some dispatch).
     g = np.asarray(grads["layers"]["moe_w_gate_up"])
     assert np.abs(g).sum() > 0
@@ -386,3 +386,86 @@ def test_grouped_product_kernels_match_ragged_dot(routing, monkeypatch):
         got, w = np.asarray(got, np.float32), np.asarray(w, np.float32)
         assert np.all(np.isfinite(got))
         np.testing.assert_allclose(got, w, atol=2e-2 * np.max(np.abs(w)))
+
+
+# (tokens, window rows, held rows, the held rows' tokens): 512 tokens in
+# blocks of 128, rows of 128 bfloat16 in product tiles of 256.
+WINDOW_SUMS = {
+    "duplicates": (512, 1024, 600, lambda rng: rng.randint(0, 64, 600)),
+    "a_block_with_no_row": (512, 1024, 300, lambda rng: np.where(
+        rng.rand(300) < 0.5, rng.randint(0, 128, 300),
+        rng.randint(384, 512, 300))),
+    "under_a_tile": (512, 1024, 5, lambda rng: rng.randint(0, 512, 5)),
+    "a_tile_and_a_row": (512, 1024, 257, lambda rng: rng.randint(0, 512, 257)),
+    "whole_window": (512, 1024, 1024, lambda rng: rng.randint(0, 512, 1024)),
+    "nothing_held": (512, 1024, 0, lambda rng: np.zeros(0, np.int64)),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOW_SUMS) + ["layer"])
+def test_window_sum_as_a_grouped_product_is_the_scatter_add(case, monkeypatch):
+    """A first window's sum back to the tokens as `token_order` +
+    `block_sums` (the chip's path, the Pallas grouped product here in
+    interpret mode) against `scatter_rows` on the same (tok, rows, held): a
+    token with many rows, token blocks with none (zeros, not what a buffer
+    held), fewer held rows than a tile, a tile and a row, every row held,
+    none; NaN stands in every row past the held ones and none reaches a sum;
+    the sums agree to one rounding of bfloat16. `layer`: the layer's output
+    and `jax.grad` with the product against the same kernels with the
+    scatter-add (a block the tokens are no multiple of), and what each
+    counts where it is traced."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+    from ray_tpu.util import tracing
+
+    if case != "layer":
+        T, W, held, tokens = WINDOW_SUMS[case]
+        rng = np.random.RandomState(held)
+        tok = np.full(W, T, np.int32)
+        tok[:held] = tokens(rng)
+        rows = rng.standard_normal((W, 128)).astype(np.float32)
+        rows[held:] = np.nan
+        tok, rows = jnp.asarray(tok), jnp.asarray(rows, jnp.bfloat16)
+        want = np.asarray(jnp.zeros((T, 128), jnp.float32).at[tok].add(
+            jnp.nan_to_num(rows.astype(jnp.float32)), mode="drop"))
+        by, tok_t, sizes = moe.token_order(tok, T, 128)
+        assert int(sizes.sum()) == held and np.all(np.diff(tok_t) >= 0)
+        assert np.array_equal(tok_t, tok[by])
+        assert np.array_equal(by, np.argsort(tok, kind="stable"))
+        got = np.asarray(moe.block_sums(
+            tok_t, rows[by], sizes, T, 128,
+            tiling=lambda m, k, n: (256, 128, 128)), np.float32)
+        was = np.asarray(moe.scatter_rows(tok, rows, held, T), np.float32)
+        assert np.all(np.isfinite(got))
+        assert not np.any(got[np.setdiff1d(np.arange(T), tok[:held])])
+        # float32 sums rounded once: half a unit in bfloat16's last place
+        np.testing.assert_allclose(got, want, rtol=2 ** -8, atol=1e-6)
+        # (the CPU's scatter-add rounds every addition)
+        np.testing.assert_allclose(was, want,
+                                   atol=2 ** -6 * (1 + np.max(np.abs(want))))
+        return
+    monkeypatch.setattr(moe, "HELD_WINDOW_FACTOR", 1.25)
+    args, wy, route, first = _held_case(4, "even", "softmax", d=128, E=16,
+                                        F=128)
+    args = (args[0].astype(jnp.bfloat16),) + args[1:]
+    fn = functools.partial(moe.moe_ffn_held, route=route, held_first=first)
+    monkeypatch.setattr(moe, "use_kernels", lambda *a: True)
+    count = lambda how: tracing.phase_table().get(
+        "train.moe_combine." + how, {}).get("count", 0)
+    outs = {}
+    for how, block in (("scatter", 96), ("product", 128)):
+        monkeypatch.setattr(moe, "TOKEN_BLOCK", block)  # 256 tokens
+        other = "scatter" if how == "product" else "product"
+        before = count(how), count(other)
+        outs[how] = _loss_and_grads(fn, args, wy)
+        assert count(how) >= before[0] + 2  # the combine and dx
+        assert count(other) == before[1]
+    (y, cnt, grads), (y0, cnt0, grads0) = outs["product"], outs["scatter"]
+    assert float(cnt["trips"]) == 1.0 and float(cnt["dropped"]) == 0.0
+    for got, w in zip((y,) + grads, (y0,) + grads0):
+        got, w = np.asarray(got, np.float32), np.asarray(w, np.float32)
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, w, atol=2 ** -7 * np.max(np.abs(w)))

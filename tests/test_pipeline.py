@@ -23,15 +23,17 @@ def test_pipeline_matches_single_device(cfgname):
     params = tfm.init_params(jax.random.key(0), cfg)
     batch = {"tokens": _tokens(cfg, batch=8)}
 
-    ref_loss = float(tfm.loss_fn(params, batch, cfg))
-    ref_grads = jax.grad(lambda p: tfm.loss_fn(p, batch, cfg))(params)
+    # (jitted: op by op every scan and small op compiles on its own)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: tfm.loss_fn(p, batch, cfg)))(params)
 
     mesh = make_mesh(MeshSpec(pipe=2, data=2), devices=jax.devices()[:4])
     loss_fn = pipeline_loss_fn(cfg, mesh, rules=RULES_TP, num_microbatches=4)
-    pl = float(loss_fn(params, batch))
+    pl, pl_grads = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, batch)))(params)
+    ref_loss, pl = float(ref_loss), float(pl)
     assert abs(pl - ref_loss) < 2e-3, (pl, ref_loss)
 
-    pl_grads = jax.grad(lambda p: loss_fn(p, batch))(params)
     for a, b in zip(jax.tree.leaves(ref_grads), jax.tree.leaves(pl_grads)):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-3, rtol=2e-2)

@@ -127,7 +127,7 @@ def test_the_flash_path_is_the_xla_path(preset, monkeypatch):
     # 15 s each; its cell runs "full", and "dots" differs in no kernel call
     for policy in ("full",) if preset in DENSE_FAMILIES else ("dots", "full"):
         remat = dataclasses.replace(cfg, remat=True, remat_policy=policy)
-        loss, g = grad(remat)(params)
+        loss, g = jax.jit(grad(remat))(params)
         assert abs(float(loss) - float(want_loss)) < 1e-5, policy
         got = W.program_leaves(remat, sz, g)
         for leaf in FLASH_LEAVES[preset]:

@@ -233,8 +233,9 @@ def test_defaults_spelled_out_trace_nothing_and_each_property_counts(case):
     for change in (dict(attn_qk_norm=False), dict(rope_fraction=1.0),
                    dict(attn_out_gate=False), dict(norm_offset=0.0),
                    dict(moe_shared_gate=False)):
-        got = tfm.forward(case["params"], case["toks"][:, :-1],
-                          dataclasses.replace(cfg, **change))
+        changed = dataclasses.replace(cfg, **change)  # (a program each)
+        got = jax.jit(lambda p, t: tfm.forward(p, t, changed))(
+            case["params"], case["toks"][:, :-1])
         assert float(jnp.max(jnp.abs(got - want))) > 1e-3, change
 
 

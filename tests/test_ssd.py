@@ -35,8 +35,10 @@ def _case(seed, B=2, S=37, H=4, P=8, G=2, N=16, dt_scale=1.0, state=True):
 
 
 def _run(fn, c):
-    return fn(c["x"], c["dt"], c["A"], c["B"], c["C"], c["D"],
-              initial_state=c["s0"])
+    """One jitted program a call: op by op the chunked form's small ops
+    compile one at a time (ROADMAP D11)."""
+    return jax.jit(fn)(c["x"], c["dt"], c["A"], c["B"], c["C"], c["D"],
+                       initial_state=c["s0"])
 
 
 @pytest.mark.parametrize("name,kw,chunk", [

@@ -261,11 +261,11 @@ def test_other_mixers_keep_one_devices_answer_under_tensor(preset, over):
 
     cfg = getattr(configs, preset)(remat=True, **over)
     tokens = _tokens(cfg, (4, 65))
+    made = tfm.init_params(jax.random.key(0), cfg)  # once for both meshes
 
     def loss(spec):
         ts = transformer_train_step(cfg, _mesh(spec), shift_inputs=True)
-        params = jax.device_put(tfm.init_params(jax.random.key(0), cfg),
-                                ts.param_shardings)
+        params = jax.device_put(made, ts.param_shardings)
         out = ts.eval_loss(params, ts.shard_batch({"tokens": tokens}))
         return float(out[0] if isinstance(out, tuple) else out)
 
