@@ -1210,14 +1210,8 @@ class WorkerRuntime:
             return {"error": "checkpoint timed out behind queued calls"}
 
     def _format_stacks(self) -> str:
-        import sys
-
-        names = {t.ident: t.name for t in threading.enumerate()}
-        parts = [f"pid={os.getpid()} worker={self.worker_id}"]
-        for tid, frame in sys._current_frames().items():
-            parts.append(f"--- thread {names.get(tid, '?')} ({tid}) ---")
-            parts.append("".join(traceback.format_stack(frame)))
-        return "\n".join(parts)
+        return (f"pid={os.getpid()} worker={self.worker_id}\n"
+                + tracing.format_stacks())
 
     # -------------------------------------------------------------- execution
 

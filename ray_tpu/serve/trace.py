@@ -407,16 +407,8 @@ def start_request(*, request_id: str = "", deployment: str = "",
 
 def capture_stacks(max_chars: int = 16384) -> str:
     """All-thread stack capture for STREAM_STALLED events (the hang
-    watchdog's attachment shape — core/worker.py _format_stacks)."""
-    import sys
-    import traceback
-
-    out = []
+    watchdog's attachment shape: util/tracing.py format_stacks)."""
     try:
-        frames = sys._current_frames()
-        for tid, frame in list(frames.items()):
-            out.append(f"--- thread {tid} ---")
-            out.append("".join(traceback.format_stack(frame)))
+        return tracing.format_stacks()[:max_chars]
     except Exception as e:  # capture must never raise into the hot path
-        out.append(f"<stack capture failed: {e}>")
-    return "\n".join(out)[:max_chars]
+        return f"<stack capture failed: {e}>"[:max_chars]

@@ -22,6 +22,12 @@ from ray_tpu.parallel import sharding as shd
 from ray_tpu.util import tracing
 
 
+# Where the loop's thread spends a step's period by the program's own phases,
+# as a `train.stall` record's attributes; the rest is the caller's.
+_BEAT_PARTS = {"shard_ms": "train.shard_batch", "enqueue_ms": "train.step",
+               "report_ms": "train.report"}
+
+
 class ShardedTrainStep:
     """Holds the jitted init/step pair and the shardings they pin.
 
@@ -102,6 +108,7 @@ class ShardedTrainStep:
         return shd.shard_batch(self.mesh, batch)
 
     def step(self, params, opt_state, batch) -> Tuple[Any, ...]:
+        tracing.beat("train", _BEAT_PARTS)  # a late step: `train.stall`
         with tracing.phase("train.step"):  # the host side: the enqueue
             return (self._compiled_step or self._jit_step)(
                 params, opt_state, batch)

@@ -25,15 +25,46 @@ half: named stretches of host work stamped on CLOCK_MONOTONIC, folded into a
 per-process table and, in a process that has imported jax, emitted as
 ``jax.profiler.TraceAnnotation`` so a profiler session shows them beside the
 device ops. See the section at the end of this module.
+
+The slow ring says why. Every stretch of 50 ms or more that ``phase`` or
+``steps`` timed carries what its thread did with the time, as deltas of
+``getrusage(RUSAGE_THREAD)``: ``cpu_ns`` (user + system), ``majflt``,
+``inblock``, ``nvcsw``, ``nivcsw``, over ``over_ns`` (the stretch and at most
+10 ms before it). ``cpu_ns`` near ``dur_ns``: it computed; far under it with
+``nvcsw``: it waited (a lock, the GIL, a device, a sleep); with ``nivcsw``:
+it was descheduled; ``majflt`` / ``inblock``: it read the disk. ``dur_ns - cpu_ns``
+is time off the CPU whatever the reason: the stretch's own blocking calls (a
+synchronous RPC, a child it waits for, a read) count as much as a thread
+that was starved, and only the two switch counts tell them apart. The TPU
+machines' kernel (4.4.0, a sandbox's) reports those and ``majflt``,
+``inblock`` as 0 for every thread: there ``cpu_ns`` alone reads, in 10 ms
+steps. A collection of generation 1 or 2 is the phase ``host.gc``.
+
+Reading a stall record (``<name>.stall``, made by ``beat``: a loop's period
+that exceeded the median of its last 16 by a quarter and 250 ms; ``dur_ns``
+is the excess). ``shard_ms`` / ``enqueue_ms`` / ``report_ms`` near the excess:
+the program's own phase of that name was long, and its own slow record says
+why. ``gc_ms``: the collector ran (any thread: it holds the GIL).
+``outside_ms``: the caller's part of the loop, its wait for the device or its
+data; ``stack`` (taken by the watchdog thread while the period was open,
+the beating thread first, innermost frame first) names the line. ``cpu_ns``
+near the period: the thread computed; near none with ``majflt`` or
+``inblock``: the disk. No ``stack`` and ``watchdog_late_ms`` near the excess:
+the watchdog could not run either, so the GIL was held, or the process was
+stopped or starved by its machine. ``profiler`` 1: a profiler session
+started or stopped inside the period, which stalls any loop.
 """
 from __future__ import annotations
 
 import collections
+import gc
 import os
+import resource
 import secrets
 import sys
 import threading
 import time
+import traceback
 import weakref
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -298,11 +329,20 @@ class task_span:
 
 SLOW_NS = 50_000_000      # phases at least this long go to the slow ring
 SLOW_RING = 1024          # entries kept
+STAMP_NS = 10_000_000     # a thread's rusage stamp is good for this long
+BEAT_KEPT = 16            # periods a beat keeps; the rule's median is theirs
+BEAT_MIN = 8              # periods known before a long one is a stall
+STALL_MIN_NS = 250_000_000  # a stall: an excess over the median of this ...
+STALL_SHARE = 4           # ... and of the median over this, at least
+WATCHDOG_S = 0.25         # the watchdog's wake-ups
+STACK_CHARS = 2048        # of the stacks the watchdog took, kept on a record
 _N_BUCKETS = 40           # log2(ns) buckets: bucket b holds [2^(b-1), 2^b)
 _SLOW_EVENTS_PER_10S = 32  # cluster events a process may emit for slow phases
 
-_phase_lock = threading.Lock()   # guards the registry below, not the folding
-_phase_local = threading.local()  # .table: this thread's name -> row
+# Guards the registry below, not the folding. Re-entrant: a collection can
+# start under it, and its callback folds `host.gc` on the same thread.
+_phase_lock = threading.RLock()
+_phase_local = threading.local()  # .table: this thread's _Table
 _tables: List[tuple] = []         # (weakref to thread, its table), live threads
 _retired: Dict[str, list] = {}    # rows of threads that have ended
 _slow: "collections.deque" = collections.deque(maxlen=SLOW_RING)
@@ -313,6 +353,9 @@ _UNSENT_MAX = 64
 # Slow phases from before this process had a worker context (a worker's
 # boot): held until send_unsent(), which sets this to None.
 _unsent: Optional[List[tuple]] = []
+# Slow `host.gc` entries whose cluster event waits for a caller that holds no
+# lock of its own: a collector's callback must not take the event buffer's.
+_deferred: "collections.deque" = collections.deque(maxlen=_UNSENT_MAX)
 
 
 def _annotation():
@@ -356,11 +399,25 @@ def _merge(into: Dict[str, list], table: Dict[str, list]) -> None:
         acc[3] = [a + b for a, b in zip(acc[3], row[3])]
 
 
-def _thread_table() -> Dict[str, list]:
+class _Table(dict):
+    """A thread's name -> row; its ``stamp``, what a slow stretch takes its
+    rusage deltas from: (good until, taken at: monotonic ns, the rusage then,
+    this table); and its loops that beat, by name."""
+
+    __slots__ = ("stamp", "beats")
+
+    def restamp(self, now_ns: int, usage: tuple) -> tuple:
+        st = self.stamp = (now_ns + STAMP_NS, now_ns, usage, self)
+        return st
+
+
+def _thread_table() -> _Table:
     """This thread's own table, so folding takes no lock (seventeen threads
     of a replica fold a few thousand phases a second). A new thread's first
     phase registers it and retires the rows of threads that have ended."""
-    table: Dict[str, list] = {}
+    table = _Table()
+    table.beats = {}
+    table.restamp(time.monotonic_ns(), _usage())
     _phase_local.table = table
     with _phase_lock:
         live = []
@@ -376,12 +433,14 @@ def _thread_table() -> Dict[str, list]:
 
 
 def _fold(name: str, start_ns: int, dur_ns: int,
-          attrs: Optional[Dict[str, Any]], slow: bool = True) -> None:
+          attrs: Optional[Dict[str, Any]], slow: bool = True,
+          stamp: Optional[tuple] = None) -> None:
+    """One stretch into the calling thread's table and, where it is slow,
+    into the ring and out as a cluster event; with the ``stamp`` the thread
+    held when the stretch began (it names the table too), a slow one carries
+    what the thread did with the time."""
     dur_ns = max(0, int(dur_ns))
-    try:
-        table = _phase_local.table
-    except AttributeError:
-        table = _thread_table()
+    table = _table() if stamp is None else stamp[3]
     row = table.get(name)
     if row is None:
         row = table[name] = [0, 0, 0, [0] * _N_BUCKETS]
@@ -391,17 +450,65 @@ def _fold(name: str, start_ns: int, dur_ns: int,
         row[2] = dur_ns
     row[3][min(dur_ns.bit_length(), _N_BUCKETS - 1)] += 1
     if dur_ns >= SLOW_NS and slow:
-        _slow.append((name, int(start_ns), dur_ns, dict(attrs or {})))
+        attrs = dict(attrs or {})
+        if stamp is not None:
+            attrs.update(_spent(stamp, start_ns + dur_ns))
+        _slow.append((name, int(start_ns), dur_ns, attrs))
         _slow_event(name, int(start_ns), dur_ns, attrs)
+        _send_deferred()
 
 
-def _slow_event(name: str, start_ns: int, dur_ns: int, attrs) -> None:
+def _send_deferred() -> None:
+    while _deferred:
+        _slow_event(*_deferred.popleft())
+
+
+def _usage() -> tuple:
+    """The calling thread's rusage, in the order of ``_SPENT``."""
+    r = resource.getrusage(resource.RUSAGE_THREAD)
+    return (int((r.ru_utime + r.ru_stime) * 1e9), r.ru_majflt, r.ru_inblock,
+            r.ru_nvcsw, r.ru_nivcsw)
+
+
+_SPENT = ("cpu_ns", "majflt", "inblock", "nvcsw", "nivcsw")
+
+
+def _table() -> _Table:
+    try:
+        return _phase_local.table
+    except AttributeError:
+        return _thread_table()
+
+
+def _stamp(table: _Table, now_ns: int) -> tuple:
+    """The thread's stamp, taken anew when it is older than ``STAMP_NS``: a
+    stretch that ends short of ``SLOW_NS`` pays one read of it, and a thread
+    one ``getrusage`` in 10 ms."""
+    st = table.stamp
+    return table.restamp(now_ns, _usage()) if now_ns > st[0] else st
+
+
+def _spent(st: tuple, now_ns: int) -> Dict[str, int]:
+    """What the thread did since its stamp ``st``, for a slow record: the
+    five deltas and ``over_ns``, the interval they cover (a stretch and at
+    most ``STAMP_NS`` before it)."""
+    use = _usage()
+    st[3].restamp(now_ns, use)
+    out = {k: b - a for k, a, b in zip(_SPENT, st[2], use)}
+    out["over_ns"] = now_ns - st[1]
+    return out
+
+
+def _slow_event(name: str, start_ns: int, dur_ns: int, attrs,
+                limited: bool = True) -> None:
     """A slow phase of a worker process also becomes a cluster event (rare by
     construction, and rate-limited here), which is how `rtpu events` and the
     controller's process see a worker's stalls. What the limit drops is
     counted, and the next event that passes carries the count
-    (``dropped_before``), so a reader knows whether a sum is whole. The
-    controller's own process already holds its phases in this table."""
+    (``dropped_before``), so a reader knows whether a sum is whole. A stall
+    record (``limited=False``: four a second at most by its rule) is never
+    dropped. The controller's own process already holds its phases in this
+    table."""
     if name.startswith("ctrl."):
         return
     try:
@@ -411,7 +518,7 @@ def _slow_event(name: str, start_ns: int, dur_ns: int, attrs) -> None:
         if not ctx.is_initialized():
             # a worker that has not registered yet: kept for send_unsent()
             if _unsent is not None and len(_unsent) < _UNSENT_MAX:
-                _unsent.append((name, start_ns, dur_ns, attrs))
+                _unsent.append((name, start_ns, dur_ns, attrs, limited))
             return
         if not (events.enabled()
                 and ctx.get_worker_context().role == "worker"):
@@ -421,10 +528,10 @@ def _slow_event(name: str, start_ns: int, dur_ns: int, attrs) -> None:
             tokens, at, dropped = _slow_event_budget
             tokens = min(float(_SLOW_EVENTS_PER_10S), tokens + (now - at)
                          * _SLOW_EVENTS_PER_10S / 10.0)
-            if tokens < 1.0:
+            if tokens < 1.0 and limited:
                 _slow_event_budget[:] = [tokens, now, dropped + 1]
                 return
-            _slow_event_budget[:] = [tokens - 1.0, now, 0]
+            _slow_event_budget[:] = [max(0.0, tokens - 1.0), now, 0]
         data = {"name": name, "start_monotonic_ns": start_ns,
                 "dur_ns": dur_ns, "pid": os.getpid(),
                 "attrs": {k: v for k, v in (attrs or {}).items()
@@ -472,7 +579,7 @@ class phase:
     (a profiler annotation nests per thread); for time measured across
     threads or awaits use ``observe``."""
 
-    __slots__ = ("name", "attrs", "slow", "_t0", "_ann")
+    __slots__ = ("name", "attrs", "slow", "_t0", "_ann", "_st")
 
     def __init__(self, name: str, slow: bool = True, **attrs: Any):
         self.name = name
@@ -487,14 +594,22 @@ class phase:
             self._ann.__enter__()
         else:
             self._ann = None
-        self._t0 = time.monotonic_ns()
+        try:  # here, so that the exit's fold need not look it up
+            table = _phase_local.table
+        except AttributeError:
+            table = _thread_table()
+        self._t0 = t0 = time.monotonic_ns()
+        st = table.stamp
+        if t0 > st[0]:  # _stamp()'s common case, without the call
+            st = _stamp(table, t0)
+        self._st = st
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
         dur = time.monotonic_ns() - self._t0
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
-        _fold(self.name, self._t0, dur, self.attrs, self.slow)
+        _fold(self.name, self._t0, dur, self.attrs, self.slow, self._st)
         return False
 
 
@@ -531,16 +646,18 @@ class steps:
 
     def __await__(self):
         send, throw, name = self.coro.send, self.coro.throw, self.name
+        table = _table()  # the loop's thread: a coroutine does not migrate
         val: Any = None
         exc: Optional[BaseException] = None
         while True:
             t0 = time.monotonic_ns()
+            st = _stamp(table, t0)
             try:
                 out = send(val) if exc is None else throw(exc)
             except StopIteration as stop:
                 return stop.value
             finally:  # a step that returns, raises or suspends: all counted
-                _fold(name, t0, time.monotonic_ns() - t0, None)
+                _fold(name, t0, time.monotonic_ns() - t0, None, True, st)
             try:
                 val, exc = (yield out), None
             except GeneratorExit:
@@ -550,6 +667,241 @@ class steps:
                 val, exc = None, e
 
 
+# ------------------------------------------------------------- the collector
+
+_gc_open: list = [0, None]   # the collection under way: its start, its stamp
+_gc_total_ns = [0]           # generations 1 and 2, this process, all threads
+
+
+def _on_gc(when: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` entry: a collection of generation 1 or 2 is the phase
+    ``host.gc`` (attrs ``generation``, ``collected``) on the thread it ran
+    on; a young pass (hundreds a second, microseconds each) returns at once.
+    It runs wherever an allocation tripped the collector, under whatever lock
+    that thread holds: it folds, and leaves a slow entry's cluster event to
+    the next slow phase or the watchdog (``_deferred``)."""
+    try:
+        gen = info["generation"]
+        if not gen:
+            return
+        now = time.monotonic_ns()
+        if when == "start":
+            _gc_open[:] = now, _stamp(_table(), now)
+            return
+        t0, st = _gc_open
+        if st is None:
+            return  # installed while this collection ran
+        _gc_open[1] = None
+        dur = now - t0
+        _gc_total_ns[0] += dur
+        attrs = {"generation": gen, "collected": info["collected"]}
+        _fold("host.gc", t0, dur, None, slow=False)
+        if dur >= SLOW_NS:
+            attrs.update(_spent(st, now))
+            _slow.append(("host.gc", t0, dur, attrs))
+            _deferred.append(("host.gc", t0, dur, attrs))
+    except Exception:
+        pass  # never into the collector (the interpreter may be going down)
+
+
+gc.callbacks.append(_on_gc)
+
+
+# ------------------------------------------------------------ a loop that beats
+
+def format_stacks(first: Optional[int] = None,
+                  innermost_first: bool = False) -> str:
+    """Every thread's current stack, the thread ``first`` (an ident) leading:
+    what `stack_dump`, the serve plane's stall events and a stall record
+    attach. ``innermost_first`` turns each stack over, so that a cut keeps
+    the frame a thread stands in."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    frames = sys._current_frames()
+    parts = []
+    for tid in sorted(frames, key=lambda t: t != first):
+        lines = traceback.format_stack(frames[tid])
+        if innermost_first:
+            lines.reverse()
+        parts.append(f"--- thread {names.get(tid, '?')} ({tid}) ---")
+        parts.append("".join(lines))
+    return "\n".join(parts)
+
+
+_beats: List["_Beat"] = []    # every live thread's, for the watchdog
+_watchdog_woke = [0]          # its last wake-up, 0 while it does not run
+_stall_said = [0.0]           # the last stderr line, on time.monotonic()
+
+
+def _stall_floor(median_ns: int) -> int:
+    return max(STALL_MIN_NS, median_ns // STALL_SHARE)
+
+
+class _Beat:
+    """One loop on one thread: its periods, and the stall rule over them."""
+
+    __slots__ = ("name", "parts", "thread", "index", "last_ns", "periods",
+                 "median_ns", "snap", "sample", "late_ns")
+
+    def __init__(self, name: str, parts: Optional[Dict[str, str]] = None):
+        self.name = name
+        self.parts = dict(parts or {})   # attribute -> phase of this thread
+        self.thread = weakref.ref(threading.current_thread())
+        self.index = 0
+        self.last_ns: Optional[int] = None
+        self.periods: "collections.deque" = collections.deque(
+            maxlen=BEAT_KEPT)
+        self.median_ns = 0               # 0 until BEAT_MIN periods are known
+        # at the last beat: the thread's rusage, its totals under `parts`,
+        # the process's collector time, whether a profiler session was on
+        self.snap: Optional[tuple] = None
+        # the watchdog's, for the open period: (its start, the stacks), and
+        # the latest of its wake-ups since the last beat
+        self.sample: Optional[tuple] = None
+        self.late_ns = 0
+
+    def tick(self, now_ns: int, profiling: bool = False
+             ) -> Optional[Dict[str, Any]]:
+        """The beat at ``now_ns``: keeps the period since the last one for
+        the rule's median (no row in the table: nothing would read it), and
+        returns the attributes of the ``<name>.stall`` record it made, if the
+        rule made one."""
+        prev, self.last_ns = self.last_ns, now_ns
+        self.index += 1
+        was, sample, late_ns = self.snap, self.sample, self.late_ns
+        table, usage = _table(), _usage()
+        self.snap = (usage, [row[1] if row else 0 for row in map(
+            table.get, self.parts.values())], _gc_total_ns[0], profiling)
+        self.sample, self.late_ns = None, 0
+        table.restamp(now_ns, usage)
+        if prev is None:
+            return None
+        period = now_ns - prev
+        median = self.median_ns          # of the periods before this one
+        self.periods.append(period)
+        n = len(self.periods)
+        if n >= BEAT_MIN:
+            s = sorted(self.periods)
+            self.median_ns = (s[(n - 1) // 2] + s[n // 2]) // 2
+        if not median or period - median < _stall_floor(median):
+            return None
+        return self._stall(prev, period, median, was, sample, late_ns)
+
+    def _stall(self, prev, period, median, was, sample, late_ns
+               ) -> Dict[str, Any]:
+        ms = lambda ns: round(ns / 1e6, 3)  # noqa: E731
+        now_ns, excess = prev + period, period - median
+        attrs: Dict[str, Any] = {"index": self.index, "period_ms": ms(period),
+                                 "median_ms": ms(median)}
+        (usage0, totals0, gc0, prof0), (usage, totals, gc, prof) = (
+            was, self.snap)
+        named = gc - gc0
+        for key, a, b in zip(self.parts, totals0, totals):
+            attrs[key] = ms(b - a)
+            named += b - a
+        attrs["gc_ms"] = ms(gc - gc0)
+        attrs["outside_ms"] = ms(max(0, period - named))
+        attrs.update(zip(_SPENT, (b - a for a, b in zip(usage0, usage))))
+        attrs["profiler"] = int(prof0 != prof)
+        woke = _watchdog_woke[0]
+        if woke:  # a wake-up overdue now counts: it could not run either
+            late_ns = max(late_ns, now_ns - woke - int(WATCHDOG_S * 1e9))
+            attrs["watchdog_late_ms"] = ms(late_ns)
+        if sample and sample[0] == prev:
+            attrs["stack"] = sample[1]
+        name, start = self.name + ".stall", prev + median
+        if attrs["profiler"]:  # the session's own doing: not laid on a trace
+            _fold(name, start, excess, None, slow=False)
+        else:
+            observe(name, excess, start, slow=False, **{
+                k: v for k, v in attrs.items() if k != "stack"})
+        _slow.append((name, start, excess, dict(attrs)))
+        _slow_event(name, start, excess, attrs, limited=False)
+        _say_stall(name, excess, self.parts, attrs)
+        return attrs
+
+
+def _say_stall(name: str, excess_ns: int, parts, attrs) -> None:
+    """One line on stderr, one a second at most: an untraced run's log then
+    tells a stalled run from a slow program."""
+    now = time.monotonic()
+    if now - _stall_said[0] < 1.0:
+        return
+    _stall_said[0] = now
+    said = [f"outside {attrs['outside_ms']:.0f}"]
+    said += [f"{k[:-3]} {attrs[k]:.0f}" for k in parts]
+    said += [f"gc {attrs['gc_ms']:.0f}", f"cpu {attrs['cpu_ns'] / 1e6:.0f} ms",
+             f"majflt {attrs['majflt']}"]
+    if attrs["profiler"]:
+        said.append("profiler 1")
+    try:
+        sys.stderr.write(
+            f"[tracing] {name} {excess_ns / 1e6:.0f} ms at beat "
+            f"{attrs['index']}: {', '.join(said)}\n")
+        sys.stderr.flush()
+    except (OSError, ValueError):
+        pass  # a closed stderr
+
+
+def _watchdog() -> None:
+    """The one daemon thread of a process that beats. Every ``WATCHDOG_S``:
+    notes how late it woke (where the GIL is held through a stall it cannot
+    run either, and its lateness is the evidence), takes every thread's
+    stack once for a beat whose open period already exceeds the rule, and
+    sends what the collector left."""
+    woke = time.monotonic_ns()
+    while True:
+        time.sleep(WATCHDOG_S)
+        now = time.monotonic_ns()
+        late, woke = max(0, now - woke - int(WATCHDOG_S * 1e9)), now
+        _watchdog_woke[0] = now
+        try:
+            for b in list(_beats):
+                if late > b.late_ns:
+                    b.late_ns = late
+                last, median = b.last_ns, b.median_ns
+                if (median and b.sample is None and last is not None
+                        and now - last - median >= _stall_floor(median)):
+                    th = b.thread()
+                    b.sample = (last, format_stacks(
+                        th.ident if th else None, True)[:STACK_CHARS])
+            _send_deferred()
+        except Exception:
+            pass  # the watchdog outlives what it watches
+
+
+def beat(name: str, parts: Optional[Dict[str, str]] = None) -> None:
+    """``tracing.beat("train", {"enqueue_ms": "train.step"})`` at the top of a
+    loop's body: the time since this thread's last beat of that name is the
+    loop's period (kept for the rule, the last ``BEAT_KEPT``; no phase of its
+    own), and a period that exceeds their median (``BEAT_MIN`` known at
+    least) by ``STALL_MIN_NS`` and by a quarter of that median makes one slow
+    record ``<name>.stall``: it starts a median after the last beat and lasts
+    the excess. Its attributes (the module's docstring says how to read
+    them): ``index``, ``period_ms``, ``median_ms``; the period's time under
+    each phase of ``parts`` on this thread, ``gc_ms`` and the rest as
+    ``outside_ms``; the five rusage deltas over the period; ``profiler``;
+    ``stack`` and ``watchdog_late_ms`` from the watchdog thread, which the
+    process's first beat starts. The record passes the cluster events' rate
+    limit and writes one line to stderr."""
+    now = time.monotonic_ns()
+    b = _table().beats.get(name) or _new_beat(name, parts)
+    ann = _annotation()
+    b.tick(now, ann is not None and ann.is_enabled())
+
+
+def _new_beat(name: str, parts: Optional[Dict[str, str]]) -> _Beat:
+    b = _table().beats[name] = _Beat(name, parts)
+    with _phase_lock:
+        _beats[:] = [o for o in _beats
+                     if (th := o.thread()) is not None and th.is_alive()]
+        _beats.append(b)
+        if not _watchdog_woke[0]:
+            _watchdog_woke[0] = time.monotonic_ns()
+            threading.Thread(target=_watchdog, name="rtpu-tracing-watchdog",
+                             daemon=True).start()
+    return b
+
+
 def phase_table() -> Dict[str, Dict[str, Any]]:
     """This process's phases since it started (``ray_tpu.shutdown()`` does
     not clear them): name -> count, total_ns, max_ns and ``buckets``, where
@@ -557,7 +909,7 @@ def phase_table() -> Dict[str, Dict[str, Any]]:
     merged: Dict[str, list] = {}
     with _phase_lock:
         _merge(merged, _retired)
-        for _, table in _tables:
+        for _, table in list(_tables):
             _merge(merged, table)
     return {n: {"count": r[0], "total_ns": r[1], "max_ns": r[2],
                 "buckets": r[3]} for n, r in merged.items()}

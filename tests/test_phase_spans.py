@@ -3,6 +3,7 @@ table and slow ring, the profiler annotations and their clock anchor, and
 the sites that emit them: serve hops, the engine loop, the token relay, the
 controller's handlers and its loop-lag probe."""
 import asyncio
+import collections
 import glob
 import subprocess
 import sys
@@ -87,7 +88,8 @@ def test_phase_times_on_the_monotonic_clock_and_keeps_attrs():
     (p,) = _slow("t.phase")
     assert before <= p["start_monotonic_ns"] <= after
     assert 55_000_000 <= p["dur_ns"] <= after - before
-    assert p["attrs"] == {"live": 3}
+    assert p["attrs"]["live"] == 3
+    assert set(p["attrs"]) == {"live", "over_ns", *tracing._SPENT}
     with pytest.raises(KeyError):  # an exception passes through, and counts
         with tracing.phase("t.phase"):
             raise KeyError("x")
@@ -101,7 +103,7 @@ def test_phase_in_a_process_without_jax_never_imports_it():
         "from ray_tpu.util import tracing\n"
         "with tracing.phase('a', k=1):\n"
         "    tracing.observe('b', 5)\n"
-        "assert set(tracing.phase_table()) == {'a', 'b'}\n"
+        "assert set(tracing.phase_table()) - {'host.gc'} == {'a', 'b'}\n"
         "assert 'jax' not in sys.modules, 'phase() imported jax'\n")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=120)
@@ -356,18 +358,34 @@ def test_worker_slow_phase_reaches_the_controllers_ring(ray_start_regular):
 
         with tr.phase("t.worker.slow", slot=4):
             time.sleep(0.06)
+        for _ in range(2 * tr._SLOW_EVENTS_PER_10S):   # the limit, run dry
+            tr.observe("t.worker.flood", tr.SLOW_NS)
+        b, t = tr._Beat("t.worker"), time.monotonic_ns()
+        for i in range(10):
+            b.tick(t + i * 20_000_000)
+        b.tick(t + 800_000_000)    # 9 periods of 20 ms, then one of 620
         events.flush_events()
         return os.getpid()
 
     pid = ray_tpu.get(slow_one.remote())
     deadline = time.monotonic() + 10
-    while time.monotonic() < deadline and not _slow("t.worker.slow"):
+    while time.monotonic() < deadline and not _slow("t.worker.stall"):
         time.sleep(0.05)
     (p,) = _slow("t.worker.slow")
     assert p["attrs"]["pid"] == pid != os.getpid()
     assert p["attrs"]["slot"] == 4 and p["dur_ns"] >= 55_000_000
+    # what the worker's thread did with the time reaches this ring too
+    assert all(isinstance(p["attrs"][k], int) for k in tracing._SPENT)
+    assert p["attrs"]["cpu_ns"] < p["dur_ns"] / 2
+    assert p["attrs"]["nvcsw"] >= _counts_switches()
     evs = state.list_events(kind="SLOW_PHASE")
     assert any(e["data"]["name"] == "t.worker.slow" for e in evs)
+    # a stall record is not the rate limit's to drop
+    assert len(_slow("t.worker.flood")) < 2 * tracing._SLOW_EVENTS_PER_10S
+    (st,) = _slow("t.worker.stall")
+    assert st["dur_ns"] == 600_000_000 and st["attrs"]["pid"] == pid
+    assert st["attrs"]["index"] == 11 and st["attrs"]["median_ms"] == 20.0
+    assert st["attrs"]["dropped_before"] >= 1
 
 
 # ------------------------------------- set-up: xla.*, runtime.*, boot.* (PR 35)
@@ -500,6 +518,9 @@ def test_nested_spans_are_counted_once_through_self_ns(monkeypatch):
     from ray_tpu.util import jaxenv
 
     jaxenv.watch_compiles()
+    # the long spans this thread remembers from the tests before (one of 500
+    # ms, 0.6-1.5 s ago by the load) are not inside the ones made here
+    getattr(jaxenv._compiling, "long", collections.deque()).clear()
     t = time.time()
     span = lambda a, b, fun, ev=_TRACE: mon.record_event_time_span(  # noqa
         ev, t - a, t - b, fun_name=fun)
@@ -584,7 +605,10 @@ def test_backend_init_carries_platform_and_count(monkeypatch):
     devs = jaxenv.devices()
     assert devs == jax.devices()
     p = _slow("runtime.backend_init")[-1]
-    assert p["attrs"] == {"platform": devs[0].platform, "count": len(devs)}
+    assert {k: v for k, v in p["attrs"].items()
+            if k not in tracing._SPENT + ("over_ns",)} == {
+        "platform": devs[0].platform, "count": len(devs)}
+    assert p["attrs"]["cpu_ns"] >= 0 and p["attrs"]["inblock"] >= 0
 
 
 class _Worker:
@@ -605,6 +629,9 @@ def _as_worker(monkeypatch, initialized=True):
     monkeypatch.setattr(events, "emit", lambda *a, **kw: sent.append(kw))
     monkeypatch.setattr(tracing, "_slow_event_budget", [
         float(tracing._SLOW_EVENTS_PER_10S), time.monotonic(), 0])
+    # a slow collection's event, from an earlier test or during this one,
+    # would be one more through the limit
+    monkeypatch.setattr(tracing, "_deferred", collections.deque(maxlen=0))
     return sent
 
 
@@ -698,3 +725,353 @@ def test_a_spawned_worker_tells_its_boot_in_order(ray_start_regular):
     assert spawn["start_monotonic_ns"] - 20e6 \
         <= starts["boot.interpreter"] \
         <= spawn["start_monotonic_ns"] + spawn["dur_ns"]
+
+
+# ------------- why a stretch was slow: rusage deltas, host.gc, beat (PR 51)
+
+_DELTAS = tracing._SPENT + ("over_ns",)
+
+
+def _counts_switches():
+    """1 where this kernel fills a thread's switch counts, 0 where it reads
+    them as 0 (a sandbox's 4.4.0, as the chip machines run)."""
+    import resource
+
+    n0 = resource.getrusage(resource.RUSAGE_THREAD).ru_nvcsw
+    time.sleep(0.002)
+    return int(resource.getrusage(resource.RUSAGE_THREAD).ru_nvcsw > n0)
+
+
+def _burn(cpu_ns):
+    """Compute until this thread has had `cpu_ns` of the CPU."""
+    c0 = time.thread_time_ns()
+    while time.thread_time_ns() - c0 < cpu_ns:
+        pass
+
+
+@pytest.mark.parametrize("how", ["busy", "sleep"])
+@pytest.mark.parametrize("kind", ["phase", "steps"])
+def test_a_slow_stretch_carries_what_its_thread_did(kind, how):
+    """The five rusage deltas and the interval they cover, on a slow `phase`
+    and a slow `steps` stretch: a loop that computes reads `cpu_ns` near its
+    wall, a sleep near none of it and, where the kernel counts them, at
+    least one voluntary switch."""
+    name = f"t.spent.{kind}.{how}"
+    work = (lambda: _burn(80_000_000)) if how == "busy" else (
+        lambda: time.sleep(0.12))
+    if kind == "phase":
+        with tracing.phase(name, k=1):
+            work()
+    else:
+        async def handler():
+            work()
+
+        async def main():
+            await tracing.steps(name, handler())
+
+        asyncio.run(main())
+    (p,) = _slow(name)
+    a = p["attrs"]
+    assert set(a) == set(_DELTAS) | ({"k"} if kind == "phase" else set())
+    assert all(isinstance(a[k], int) and a[k] >= 0 for k in _DELTAS)
+    # the stamp was at most STAMP_NS old when the stretch began
+    assert p["dur_ns"] <= a["over_ns"] <= p["dur_ns"] + tracing.STAMP_NS
+    if how == "busy":
+        assert a["cpu_ns"] >= 75_000_000
+    else:
+        assert a["cpu_ns"] < p["dur_ns"] / 2
+        assert a["nvcsw"] >= _counts_switches()
+
+
+def test_a_stretch_under_50_ms_makes_no_getrusage_call(monkeypatch):
+    """Counted on this thread (the cluster's threads of this file fold
+    phases of their own), with the collector off (a slow collection is a
+    slow stretch, and reads it)."""
+    import gc
+    import resource
+
+    calls, me = [], threading.get_ident()
+    real = resource.getrusage
+
+    def counting(who):
+        if threading.get_ident() == me:
+            calls.append(who)
+        return real(who)
+
+    async def short():
+        for _ in range(20):
+            await tracing.steps("t.cheap.steps", asyncio.sleep(0))
+
+    def stretches(n):
+        for _ in range(n):
+            with tracing.phase("t.cheap"):
+                pass
+        asyncio.run(short())
+
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        monkeypatch.setattr(resource, "getrusage", counting)
+        monkeypatch.setattr(tracing, "STAMP_NS", 2 ** 62)  # no stamp ages
+        stretches(1)        # this thread's table exists from here
+        tracing._table().restamp(time.monotonic_ns(), tracing._usage())
+        del calls[:]
+        stretches(2000)
+        # (a stretch that the machine held for 50 ms is a slow one, and reads)
+        assert len(calls) == len(_slow("t.cheap") + _slow("t.cheap.steps"))
+        # with the real interval: one refresh in STAMP_NS a thread, no more
+        monkeypatch.setattr(tracing, "STAMP_NS", 10_000_000)
+        tracing._table().restamp(time.monotonic_ns(), tracing._usage())
+        del calls[:]
+        t0 = time.monotonic_ns()
+        while time.monotonic_ns() - t0 < 5 * tracing.STAMP_NS:
+            stretches(50)
+        spent = time.monotonic_ns() - t0
+        slow = len(_slow("t.cheap") + _slow("t.cheap.steps"))
+        assert 1 <= len(calls) <= 1 + spent // tracing.STAMP_NS + slow
+        assert set(calls) == {resource.RUSAGE_THREAD}
+        # and a slow one reads it once, where it ends
+        del calls[:]
+        with tracing.phase("t.cheap.slow"):
+            time.sleep(0.06)
+        assert 1 <= len(calls) <= 2  # its exit; its entry if the stamp was old
+        assert "cpu_ns" in _slow("t.cheap.slow")[-1]["attrs"]
+    finally:
+        if was:
+            gc.enable()
+
+
+def test_host_gc_folds_a_full_collection_and_not_a_young_pass(monkeypatch):
+    import gc
+
+    monkeypatch.setattr(tracing, "SLOW_NS", 0)
+    was = gc.isenabled()
+    gc.disable()      # no collection but the ones asked for below
+    try:
+        n0, total0 = _row("host.gc")["count"], tracing._gc_total_ns[0]
+        gc.collect(0)
+        assert _row("host.gc")["count"] == n0
+        junk = [[] for _ in range(1000)]
+        for j in junk:
+            j.append(j)      # cycles: only the collector frees them
+        del junk, j
+        gc.collect(2)
+        assert _row("host.gc")["count"] == n0 + 1
+    finally:
+        if was:
+            gc.enable()
+    p = _slow("host.gc")[-1]
+    assert p["attrs"]["generation"] == 2 and p["attrs"]["collected"] >= 1000
+    assert set(_DELTAS) <= set(p["attrs"])
+    assert tracing._gc_total_ns[0] - total0 == p["dur_ns"]
+    # its cluster event waits for a caller that holds no lock
+    assert ("host.gc", p["start_monotonic_ns"], p["dur_ns"],
+            p["attrs"]) in tracing._deferred
+    tracing.observe("t.after_gc", 1)    # SLOW_NS is 0: a slow fold drains it
+    assert not tracing._deferred
+
+
+def _beat_through(name, periods_ms, profiling=()):
+    """A fresh beat driven with injected stamps: one tick, then one a
+    period. Returns the last tick's record (or None) and the stamps."""
+    b = tracing._Beat(name, {"work_ms": name + ".work"})
+    t = time.monotonic_ns()
+    stamps, out = [t], b.tick(t, bool(profiling and profiling[0]))
+    for i, ms in enumerate(periods_ms):
+        t += ms * 1_000_000
+        stamps.append(t)
+        out = b.tick(t, bool(profiling and profiling[i + 1]))
+    return out, stamps
+
+
+@pytest.mark.parametrize("case,periods,excess_ms", [
+    ("seven_known", [100] * 7 + [1000], None),
+    ("eight_known", [100] * 8 + [1000], 900),
+    ("m300_500", [300] * 8 + [500], None),
+    ("m300_560", [300] * 8 + [560], 260),
+    ("m1200_1450", [1200] * 8 + [1450], None),
+    ("m1200_1510", [1200] * 8 + [1510], 310),
+    ("median_of_16", [100] * 4 + [2000] * 3 + [100] * 14 + [400], 300),
+])
+def test_the_stall_rule(case, periods, excess_ms):
+    """With eight periods known at least, a period whose excess over the
+    median of the last sixteen is 250 ms and a quarter of that median makes
+    one record: it starts a median after the last beat, lasts the excess."""
+    name = "t.rule." + case
+    out, stamps = _beat_through(name, periods)
+    got = _slow(name + ".stall")
+    assert _row(name + ".stall")["count"] == len(got)
+    if excess_ms is None:
+        assert out is None and got == []
+        return
+    (p,) = got
+    median = sorted(periods[-17:-1])[7] if len(periods) > 16 else periods[0]
+    assert p["dur_ns"] == excess_ms * 1_000_000
+    assert p["start_monotonic_ns"] == stamps[-2] + median * 1_000_000
+    a = p["attrs"]
+    assert a == out
+    assert (a["index"], a["period_ms"], a["median_ms"]) == (
+        len(periods) + 1, float(periods[-1]), float(median))
+    assert a["profiler"] == 0 and a["work_ms"] == 0.0 and a["gc_ms"] >= 0.0
+    assert a["outside_ms"] <= a["period_ms"]
+    assert all(isinstance(a[k], int) for k in tracing._SPENT)
+    assert "over_ns" not in a      # the deltas are the whole period's
+
+
+@pytest.mark.parametrize("flags,profiler", [
+    ((False,) * 9 + (True,), 1),     # the session started inside the period
+    ((False,) * 8 + (True, False), 1),    # it stopped inside it
+    ((False,) * 8 + (True, True), 0),     # wholly inside a session
+    ((False,) * 10, 0)])
+def test_a_stall_says_whether_a_profiler_session_began_or_ended_in_it(
+        flags, profiler):
+    name = f"t.rule.prof.{'.'.join(str(int(f)) for f in flags[-2:])}"
+    out, _ = _beat_through(name, [300] * 8 + [900], flags)
+    assert out["profiler"] == profiler
+    assert _row(name + ".stall")["count"] == 1
+
+
+def test_a_stall_record_passes_an_exhausted_rate_limit(monkeypatch, capsys):
+    sent = _as_worker(monkeypatch)
+    for i in range(tracing._SLOW_EVENTS_PER_10S + 5):
+        tracing.observe("t.flood2", tracing.SLOW_NS)
+    assert len(sent) == tracing._SLOW_EVENTS_PER_10S
+    monkeypatch.setattr(tracing, "_stall_said", [0.0])
+    _beat_through("t.unlimited", [20] * 9 + [620])
+    _beat_through("t.unlimited", [20] * 9 + [720])
+    assert [e["data"]["name"] for e in sent[-2:]] == ["t.unlimited.stall"] * 2
+    assert sent[-2]["data"]["dropped_before"] == 5
+    assert sent[-2]["data"]["attrs"]["median_ms"] == 20.0
+    # one line on stderr, and one a second at most
+    err = [line for line in capsys.readouterr().err.splitlines()
+           if line.startswith("[tracing] t.unlimited.stall")]
+    assert len(err) == 1
+    assert err[0].startswith(
+        "[tracing] t.unlimited.stall 600 ms at beat 11: outside 620, work 0, "
+        "gc 0, cpu ")
+    assert ", majflt " in err[0]
+
+
+def _nap(seconds, until):
+    """Sleeps `seconds`, then on until `until()` (bounded): the frame a
+    stalled loop's stack should name."""
+    time.sleep(seconds)
+    deadline = time.monotonic() + 10
+    while not until() and time.monotonic() < deadline:
+        time.sleep(0.02)
+
+
+def test_a_real_stall_names_the_sleeping_frame(monkeypatch, capsys):
+    """Sixteen beats 20 ms apart with 5 ms of the loop's own phase in each,
+    then 0.6 s asleep: one record, whose `outside_ms` holds the sleep and
+    whose `stack` (the watchdog's, taken while the period was open) names
+    the frame that slept."""
+    monkeypatch.setattr(tracing, "WATCHDOG_S", 0.05)
+    monkeypatch.setattr(tracing, "_stall_said", [0.0])
+    parts = {"work_ms": "t.real.work"}
+    for _ in range(17):
+        tracing.beat("t.real", parts)
+        with tracing.phase("t.real.work"):
+            time.sleep(0.005)
+        time.sleep(0.015)
+    assert _slow("t.real.stall") == []
+    b = tracing._table().beats["t.real"]
+    _nap(0.6, lambda: b.sample is not None)
+    tracing.beat("t.real", parts)
+    (p,) = _slow("t.real.stall")
+    a = p["attrs"]
+    assert a["index"] == 18 and a["profiler"] == 0
+    assert a["period_ms"] >= 600 and a["median_ms"] >= 20
+    assert abs(p["dur_ns"] - (a["period_ms"] - a["median_ms"]) * 1e6) < 2000
+    assert a["outside_ms"] >= 600 - a["median_ms"]
+    # the parts are the period's: they sum to no more than it
+    assert a["outside_ms"] + a["work_ms"] + a["gc_ms"] <= a["period_ms"] + 0.002
+    assert "_nap" in a["stack"] and len(a["stack"]) <= tracing.STACK_CHARS
+    assert a["stack"].startswith(
+        f"--- thread {threading.current_thread().name} ")
+    assert a["watchdog_late_ms"] >= 0.0
+    assert a["cpu_ns"] < p["dur_ns"] / 2
+    err = [line for line in capsys.readouterr().err.splitlines()
+           if line.startswith("[tracing] t.real.stall")]
+    assert len(err) == 1 and " at beat 18: outside " in err[0]
+
+
+def test_format_stacks_leads_with_the_thread_asked_for():
+    from ray_tpu.serve import trace as serve_trace
+
+    done = threading.Event()
+    t = threading.Thread(target=done.wait, name="t-stacks", daemon=True)
+    t.start()
+    try:
+        text = tracing.format_stacks(t.ident, innermost_first=True)
+        assert text.startswith(f"--- thread t-stacks ({t.ident}) ---")
+        first = text.split("--- thread ")[1]
+        assert first.index("in wait") < first.index("in run")  # innermost first
+        plain = tracing.format_stacks()
+        assert f"--- thread MainThread ({threading.get_ident()}) ---" in plain
+        assert "test_format_stacks_leads_with_the_thread_asked_for" in plain
+        assert serve_trace.capture_stacks(64) == plain[:64]
+    finally:
+        done.set()
+        t.join(5)
+
+
+def test_an_induced_stall_of_the_train_step_records_itself(tmp_path, capsys,
+                                                           monkeypatch):
+    """ShardedTrainStep.step beats as `train`: nothing for the first eight
+    beats whatever they took (the first call compiles), one `train.stall`
+    for a sleep between two steps, with where the thread spent the period
+    by the loop's own phases; across `jax.profiler.start_trace` the record
+    says `profiler` 1."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.parallel.mesh import single_device_mesh
+    from ray_tpu.train.step import ShardedTrainStep
+
+    monkeypatch.setattr(tracing, "WATCHDOG_S", 0.05)
+    monkeypatch.setattr(tracing, "_stall_said", [0.0])
+    tracing._table().beats.pop("train", None)
+    n0 = len(_slow("train.stall"))
+    ts = ShardedTrainStep(
+        init_params_fn=lambda key: {"w": jnp.ones((4,))},
+        loss_fn=lambda p, b: jnp.mean((b["x"] @ p["w"]) ** 2),
+        logical_specs={"w": (None,)}, mesh=single_device_mesh())
+    params, opt = ts.init(jax.random.key(0))
+    x = np.ones((2, 4), np.float32)
+
+    def step():
+        nonlocal params, opt
+        params, opt, loss = ts.step(params, opt, ts.shard_batch({"x": x}))
+        return float(loss)
+
+    for _ in range(8):
+        step()
+        time.sleep(0.3 if _ == 3 else 0.0)   # long, but among the first eight
+    assert len(_slow("train.stall")) == n0
+    for _ in range(9):
+        step()
+    b = tracing._table().beats["train"]
+    _nap(0.6, lambda: b.sample is not None)
+    step()
+    (p,) = _slow("train.stall")[n0:]
+    a = p["attrs"]
+    assert a["index"] == 18 and a["profiler"] == 0
+    assert a["outside_ms"] >= 600 - a["median_ms"]
+    assert sum(a[k] for k in ("outside_ms", "enqueue_ms", "shard_ms",
+                              "report_ms", "gc_ms")) <= a["period_ms"] + 0.003
+    assert a["report_ms"] == 0.0 and "_nap" in a["stack"]
+    err = [line for line in capsys.readouterr().err.splitlines()
+           if line.startswith("[tracing] train.stall")]
+    assert len(err) == 1 and "outside" in err[0] and "enqueue" in err[0]
+    assert len(b.periods) == tracing.BEAT_KEPT    # the rule's, and no row
+    # the same across the start of a profiler session
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        time.sleep(0.4)
+        step()
+    finally:
+        jax.profiler.stop_trace()
+    (q,) = _slow("train.stall")[n0 + 1:]
+    assert q["attrs"]["profiler"] == 1 and q["attrs"]["index"] == 19
