@@ -17,10 +17,22 @@ body take.
    this short measures the host), and the whole `value_and_grad` of both
    ways from the host: the difference is the traffic round the kernels.
 
+3. `conv` (PR 52): the way from a projection to the core,
+   [l2norm](SiLU(short_conv(x, w) [+ b])), alone: XLA's formulation
+   (`kda.mixer_conv_xla`, what the mixers wrote out) and the Pallas pair
+   (`kda.mixer_conv_pallas`), the forward and the forward + backward, eight
+   independent calls in one program, at [1,16384,32,128] K 4 with
+   and without the norm, at [1,8192,32,128], and at `_mamba_mixer`'s two
+   ([1,4096,4096] and [1,4096,256] with a bias); `rel_*` is each result
+   against the XLA body on float32 operands. `conv sweep` times the pair at
+   other blocks (rows, lanes, chunk, lanes worked, chunks a loop turn) as
+   well.
+
 One JSON line a case. Off the chip the script fails at once.
 
     python3 benchmarks/probe_kda.py          # check + time
     python3 benchmarks/probe_kda.py time     # no check
+    python3 benchmarks/probe_kda.py conv [sweep]
 """
 from __future__ import annotations
 
@@ -29,7 +41,9 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import functools
 import json
+import math
 import time
 
 import jax
@@ -139,9 +153,100 @@ def kernels_ms(args, wo):
                           / REPEAT}), flush=True)
 
 
+CONV_CASES = (  # rows, channel shape, taps, norm, bias
+    (16384, (32, 128), 4, True, False), (16384, (32, 128), 4, False, False),
+    (8192, (32, 128), 4, True, False), (4096, (4096,), 4, False, True),
+    (4096, (256,), 4, False, True))
+# (forward rows, backward rows), lanes, chunk rows, lanes worked at a time,
+# chunks a turn of the row loop
+CONV_BLOCKS = (((1024, 512), 512, 16, 512, 1), ((1024, 512), 512, 16, 512, 2),
+               ((1024, 512), 512, 16, 512, 8), ((512, 512), 512, 16, 512, 4),
+               ((1024, 1024), 512, 16, 512, 4), ((1024, 512), 1024, 16, 512, 4),
+               ((1024, 512), 512, 32, 256, 4), ((1024, 256), 512, 16, 512, 4))
+CALLS = 8
+
+
+def _conv_case(S, ch, K, l2, bias, check=True):
+    """One JSON line: both bodies' milliseconds a call (`CALLS` independent
+    calls in one program, each on its own operands: a loop that carries x
+    pays a copy of it a turn), and the kernels' results against the XLA body
+    on float32 operands."""
+    ks = jax.random.split(jax.random.key(S + K), 4)
+    flat = (1, S, math.prod(ch))  # as a projection's product lies: a program
+    xs = [jax.random.normal(k, flat, jnp.bfloat16)  # whose ARGUMENT is
+          for k in jax.random.split(ks[0], CALLS)]  # [1,S,32,128] tiles the
+    dys = [jax.random.normal(k, flat, jnp.bfloat16)  # heads and pays a copy
+           for k in jax.random.split(ks[3], CALLS)]
+    w = jax.random.normal(ks[1], (K,) + ch) * 0.5
+    b = jax.random.normal(ks[2], ch) * 0.1 if bias else None
+    out = {"case": "conv", "rows": S, "channels": list(ch), "taps": K,
+           "l2": l2, "bias": bias,
+           "block": [kda._CONV_ROWS, kda._CONV_COLS[0], kda._CONV_CHUNK,
+                     kda._CONV_WORK, kda._CONV_UNROLL]}
+
+    def pair(body, x, w, b, dy):
+        y, vjp = jax.vjp(lambda x, w, b: body(
+            x.reshape((1, S) + ch), w, b, l2=l2).reshape(flat), x, w, b)
+        return (y,) + vjp(dy)
+
+    if check:  # operands as the mixers hold them, [1,S,...ch] (this XLA's
+        # body on float32 [1,S,C] reshaped INSIDE the program read 0.21 off
+        # both kernels and itself on [1,S,32,128]: chiprun_out/p52g)
+        f32 = lambda a: a.astype(jnp.float32).reshape((1, S) + ch)
+        as_is = lambda a: a.reshape((1, S) + ch)
+
+        def pair4(body, x, w, b, dy):
+            y, vjp = jax.vjp(lambda x, w, b: body(x, w, b, l2=l2), x, w, b)
+            return (y,) + vjp(dy)
+
+        ref, got, was = (
+            jax.jit(functools.partial(pair4, body))(cast(xs[0]), w, b,
+                                                    cast(dys[0]))
+            for body, cast in ((kda.mixer_conv_xla, f32),
+                               (kda.mixer_conv_pallas, as_is),
+                               (kda.mixer_conv_xla, as_is)))
+        for n, r, g, o in zip(("y", "dx", "dw", "db"), ref, got, was):
+            if r is not None:
+                out["rel_" + n] = _rel(g, r)
+                out["rel_" + n + "_xla"] = _rel(o, r)
+    for name, body in (("xla", kda.mixer_conv_xla),
+                       ("pallas", kda.mixer_conv_pallas)):
+        fwd = jax.jit(lambda xs, w, b: [body(
+            x.reshape((1, S) + ch), w, b, l2=l2).reshape(flat) for x in xs])
+        both = jax.jit(lambda xs, w, b, dys: [
+            pair(body, x, w, b, dy)[1:] for x, dy in zip(xs, dys)])
+        out[name + "_fwd_ms"] = _ms(fwd, (xs, w, b)) / CALLS
+        out[name + "_fwd_bwd_ms"] = _ms(both, (xs, w, b, dys)) / CALLS
+    print(json.dumps(out), flush=True)
+    return out
+
+
+def conv(sweep):
+    """The stop rule of PR 52: the kernels' forward + backward under half of
+    XLA's at 16,384 rows."""
+    first = [_conv_case(*c) for c in CONV_CASES][0]
+    if sweep:
+        for rows, cols, chunk, work, unroll in CONV_BLOCKS:
+            kda._CONV_ROWS, kda._CONV_CHUNK, kda._CONV_WORK = rows, chunk, work
+            kda._CONV_UNROLL = unroll
+            kda._CONV_COLS = (cols, 256, 128)
+            kda._conv_fwd_call.clear_cache()
+            kda._conv_bwd_call.clear_cache()
+            try:
+                _conv_case(*CONV_CASES[0], check=False)
+            except Exception as e:  # a block the compiler refuses
+                print(json.dumps({"case": "conv", "error": repr(e)[:300],
+                                  "block": [rows, cols, chunk, work,
+                                            unroll]}),
+                      flush=True)
+    return first["pallas_fwd_bwd_ms"] < 0.5 * first["xla_fwd_bwd_ms"]
+
+
 def main(argv):
     require_tpu()
     enable_compile_cache()
+    if argv and argv[0] == "conv":
+        return 0 if conv(argv[1:] == ["sweep"]) else 1
     args, wo = _inputs()
     ok = True
     if not argv or argv[0] == "check":

@@ -1005,15 +1005,15 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
 
 
 def _kda_mixer(cfg, kind, h, layer, positions, overlap):
-    from ray_tpu.ops.kda import kda_chunked, l2_normalize, short_conv
+    from ray_tpu.ops.kda import kda_chunked, mixer_conv
 
     f32 = jnp.float32
 
-    def proj(n):
+    def proj(n, l2):  # convolution, SiLU and the norm: one call a tensor
         y = jnp.einsum("bsd,dnh->bsnh", h, _w(layer, "kda_w" + n, cfg))
-        return jax.nn.silu(short_conv(y, layer["kda_conv_" + n]))
+        return mixer_conv(y, layer["kda_conv_" + n], l2=l2, scope="kda")
 
-    q, k, v = l2_normalize(proj("q")), l2_normalize(proj("k")), proj("v")
+    q, k, v = proj("q", True), proj("k", True), proj("v", False)
 
     def low_rank(n):
         return jnp.einsum("bsr,rnh->bsnh", h @ _w(layer, f"kda_w{n}1", cfg),
@@ -1035,15 +1035,22 @@ def _gdn_mixer(cfg, kind, h, layer, positions, overlap):
     head, `gdn_k_heads` key heads serving `gdn_v_heads` value heads. The
     output's norm is over a head's columns with a plain weight (never
     zero-centred), then times SiLU(z)."""
-    from ray_tpu.ops.kda import kda_chunked, l2_normalize, short_conv
+    from ray_tpu.ops.kda import kda_chunked, mixer_conv
 
     f32 = jnp.float32
-    qk = jnp.einsum("bsd,dcnh->bscnh", h, _w(layer, "gdn_wqk", cfg))
-    vz = jnp.einsum("bsd,dcnh->bscnh", h, _w(layer, "gdn_wvz", cfg))
+
+    # A joint leaf's halves come out of its product one after the other
+    # ([2,B,S,n,h]), so each lies whole as its reader takes it: a column
+    # slice of [B,S,2,n,h] is a copy. (The leaf itself is not sliced: weights
+    # sliced inside the scanned layer gave the first layer's experts wrong
+    # gradients on the chip, PERF.md section 6, PR 52.)
+    q, k = jnp.einsum("bsd,dcnh->cbsnh", h, _w(layer, "gdn_wqk", cfg))
+    v, z = jnp.einsum("bsd,dcnh->cbsnh", h, _w(layer, "gdn_wvz", cfg))
     ba = jnp.einsum("bsd,dcn->bscn", h, _w(layer, "gdn_wba", cfg))
-    qk = jax.nn.silu(short_conv(qk, layer["gdn_conv_qk"]))
-    v = jax.nn.silu(short_conv(vz[:, :, 0], layer["gdn_conv_v"]))
-    q, k = l2_normalize(qk[:, :, 0]), l2_normalize(qk[:, :, 1])
+    conv_qk = layer["gdn_conv_qk"]
+    q = mixer_conv(q, conv_qk[:, 0], l2=True, scope="gdn")
+    k = mixer_conv(k, conv_qk[:, 1], l2=True, scope="gdn")
+    v = mixer_conv(v, layer["gdn_conv_v"], scope="gdn")
     beta = jax.nn.sigmoid(ba[:, :, 0].astype(f32))
     g = -jnp.exp(layer["gdn_A_log"].astype(f32)) * jax.nn.softplus(
         ba[:, :, 1].astype(f32) + layer["gdn_dt_bias"].astype(f32))
@@ -1051,25 +1058,26 @@ def _gdn_mixer(cfg, kind, h, layer, positions, overlap):
         o, _ = kda_chunked(q, k, v, g, beta, chunk=cfg.gdn_chunk)
     o = _norm(o.astype(f32), layer["gdn_o_norm"], None, "rmsnorm",
               cfg.norm_eps)
-    o = (o * jax.nn.silu(vz[:, :, 1].astype(f32))).astype(h.dtype)
+    o = (o * jax.nn.silu(z.astype(f32))).astype(h.dtype)
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "gdn_wo", cfg)), None, None
 
 
 def _mamba_mixer(cfg, kind, h, layer, positions, overlap):
-    from ray_tpu.ops.kda import short_conv
+    from ray_tpu.ops.kda import mixer_conv
     from ray_tpu.ops.ssd import ssd_chunked
 
     f32 = jnp.float32
     B, S, _ = h.shape
-    zx = jnp.einsum("bsd,dcnp->bscnp", h, _w(layer, "mamba_wzx", cfg))
+    # z, then x, each whole (`_gdn_mixer`'s note)
+    z, x = jnp.einsum("bsd,dcnp->cbsnp", h, _w(layer, "mamba_wzx", cfg))
     bc = jnp.einsum("bsd,dcgn->bscgn", h, _w(layer, "mamba_wbc", cfg))
     dt = jnp.einsum("bsd,dn->bsn", h, _w(layer, "mamba_wdt", cfg))
 
     def conv(y, n):
-        return jax.nn.silu(short_conv(y, layer["mamba_conv_" + n])
-                           + layer[f"mamba_conv_{n}_b"].astype(y.dtype))
+        return mixer_conv(y, layer["mamba_conv_" + n],
+                          layer[f"mamba_conv_{n}_b"], scope="mamba")
 
-    z, x, bc = zx[:, :, 0], conv(zx[:, :, 1], "x"), conv(bc, "bc")
+    x, bc = conv(x, "x"), conv(bc, "bc")
     dt = jax.nn.softplus(dt.astype(f32) + layer["mamba_dt_bias"].astype(f32))
     with jax.named_scope("ssd.core"):
         y, _ = ssd_chunked(x, dt, -jnp.exp(layer["mamba_A_log"].astype(f32)),

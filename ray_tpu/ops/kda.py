@@ -93,6 +93,7 @@ goes into the state update in float32.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -907,6 +908,328 @@ def kda_chunked_pallas(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
                         flat(v.astype(q.dtype)), flat(g.astype(_F32)),
                         beta.astype(_F32), s0, scale, C, sub, scope)
     return o.reshape(B, -1, H, dv)[:, :S].astype(v.dtype), s
+
+
+# ------------------------------------------------- the mixers' convolution
+#
+# y = [l2norm_128](SiLU(short_conv(x, w) [+ b])) as one forward and one
+# backward Pallas kernel under a `jax.custom_vjp`, over x [B, S, C] row-major:
+# the layout a projection's product writes and the cores' kernels read, so
+# between a projection and a core there are two device ops a tensor. Both
+# kernels take a block of rows by `_CONV_COLS` lanes and, by a second
+# BlockSpec on the same array, the `_HALO` rows before it (zeros at a
+# sequence's start); the block is widened once into a float32 scratch, and
+# worked `_CONV_CHUNK` rows by `_CONV_WORK` lanes at a time in a loop, so
+# that a piece's values stay in registers from the taps to the store and the
+# kernel's trace is one piece long: a tap is the piece's
+# aligned window of rows, rotated along the sublanes (`pltpu.roll`; a load at
+# a row that is no multiple of 8 costs more). The arithmetic is float32 (taps
+# in w's own dtype, the norm's sum and rsqrt, SiLU(c) = c/2 + c/2 tanh(c/2):
+# one transcendental and no division) with ONE rounding to x's dtype at the
+# store: no step is less precise than the XLA body's bfloat16 multiplies and
+# float32 norm. The backward walks a sequence's blocks last to first,
+# recomputes the convolution, the logistic and the norm's statistic from x,
+# w, b (the only residuals: nothing is named in `RESIDUAL_NAMES`, so a remat
+# policy keeps what it kept), carries the first `_HALO` rows of dc in scratch
+# for the rows before them (dx_t takes the next K - 1 rows' terms), and
+# accumulates dw and db in float32 scratch across a column block's batches
+# and row blocks, written once as one [8, C] array (dw's K rows, then db's).
+# At [1,16384,4096] bfloat16, K 4, the forward takes 0.69 ms and the pair
+# 1.17 with the norm, 0.56 and 0.91 without (268 MB at 819 GB/s is 0.33;
+# XLA's formulation 2.32 and 7.39: benchmarks/probe_kda.py conv; PERF.md
+# section 6, PR 52).
+
+_HALO = 16          # rows: a bfloat16 tile; the last K - 1 <= 6 are read
+_CONV_ROWS = (1024, 512)  # a block's rows, forward and backward
+_CONV_COLS = (512, 256, 128)  # a block's lanes: the first that divides C
+_CONV_CHUNK = 16    # rows worked at a time inside a block
+_CONV_WORK = 512    # and lanes
+_CONV_UNROLL = 4    # chunks a turn of the loop over a block's rows
+_L2 = 128           # the norm's group: a head's columns, one vreg row
+_L2_EPS = 1e-6      # `l2_normalize`'s
+
+
+def _each_piece(bs, bc, body, last_first=False):
+    """`body(first row, lane slice)` for a block's pieces of `_CONV_CHUNK`
+    rows by `_CONV_WORK` lanes: the rows in a loop on the device (a traced
+    body a lane slice, not one a piece: a kernel's trace is set-up time),
+    first to last or last to first."""
+    n = bs // _CONV_CHUNK
+    u = _CONV_UNROLL if n % _CONV_UNROLL == 0 else 1
+
+    def rows(i, _):
+        for j in range(u):
+            at = i * u + j
+            base = pl.multiple_of(
+                ((n - 1 - at) if last_first else at) * _CONV_CHUNK,
+                _CONV_CHUNK)
+            for c in range(0, bc, _CONV_WORK):
+                body(base, pl.ds(c, min(_CONV_WORK, bc)))
+        return _
+
+    jax.lax.fori_loop(0, n // u, rows, None)
+
+
+def _rows_at(ref, row, cs, shift):
+    """ref[row + shift : row + shift + chunk, cs] for -8 <= shift <= 8: the
+    aligned window that holds them, rotated."""
+    if shift == 0:
+        return ref[pl.ds(row, _CONV_CHUNK), cs]
+    n = _CONV_CHUNK + 8
+    if shift < 0:
+        return pltpu.roll(ref[pl.ds(row - 8, n), cs], -shift, 0)[8:]
+    return pltpu.roll(ref[pl.ds(row, n), cs], n - shift, 0)[:_CONV_CHUNK]
+
+
+def _conv_taps(xe_ref, w_ref, b_ref, base, cs, K):
+    """(the K taps x_{t-K+1+j}, c = sum_j w[j] tap_j (+ b)) [chunk, lanes]
+    float32 for the block's rows base .. base + chunk, from the widened
+    block, whose row r lies at `_HALO + r`."""
+    taps = [_rows_at(xe_ref, _HALO + base, cs, j - (K - 1)) for j in range(K)]
+    c = _tree_sum([t * w_ref[j:j + 1, cs].astype(_F32)
+                   for j, t in enumerate(taps)])
+    return taps, c if b_ref is None else c + b_ref[:, cs].astype(_F32)
+
+
+def _silu(c):
+    """(SiLU(c), the logistic of c): sigma(c) = 1/2 + 1/2 tanh(c/2)."""
+    h = 0.5 * c
+    th = jnp.tanh(h)
+    return h + h * th, 0.5 + 0.5 * th
+
+
+def _by_group(f, *arrays):
+    """f over every `_L2`-lane group of [rows, lanes] arrays: what a group's
+    row sum [rows, 1] takes part in."""
+    return jnp.concatenate(
+        [f(*(a[:, g:g + _L2] for a in arrays))
+         for g in range(0, arrays[0].shape[1], _L2)], axis=-1)
+
+
+def _row_sum(a):
+    return jnp.sum(a, -1, keepdims=True)
+
+
+def _widen(x_ref, halo_ref, xe_ref, n, rows_left):
+    """The block and the `_HALO` rows before it as float32 in `xe_ref`; a
+    sequence's first block has zeros before it, and rows past the sequence's
+    end (`rows_left`, None where the blocks are whole) read as zeros."""
+    xe_ref[:_HALO] = jnp.where(n > 0, halo_ref[0].astype(_F32), 0.0)
+    xf = x_ref[0].astype(_F32)
+    if rows_left is not None:
+        xf = jnp.where(_iota(xf.shape, 0) < rows_left, xf, 0.0)
+    xe_ref[_HALO:] = xf
+
+
+def _conv_fwd_kernel(x_ref, halo_ref, w_ref, *rest, K, l2, bias, S):
+    b_ref = rest[0] if bias else None
+    y_ref, xe_ref = rest[-2:]
+    _widen(x_ref, halo_ref, xe_ref, pl.program_id(2), None)
+
+    def piece(base, cs):
+        s = _silu(_conv_taps(xe_ref, w_ref, b_ref, base, cs, K)[1])[0]
+        if l2:
+            s = _by_group(lambda g: g * jax.lax.rsqrt(
+                _row_sum(g * g) + _L2_EPS), s)
+        y_ref[0, pl.ds(base, _CONV_CHUNK), cs] = s.astype(y_ref.dtype)
+
+    _each_piece(*x_ref.shape[1:], piece)
+
+
+def _conv_bwd_kernel(x_ref, halo_ref, w_ref, *rest, K, l2, bias, S):
+    b_ref = rest[0] if bias else None
+    dy_ref, dx_ref, dwb_ref, xe_ref, dc_ref, acc_ref = rest[-6:]
+    bs, bc = x_ref.shape[1:]
+    b, n = pl.program_id(1), pl.program_id(2)
+    nb = pl.num_programs(2) - 1 - n             # the block, last first
+    ragged = S % bs != 0
+    _widen(x_ref, halo_ref, xe_ref, nb, S - nb * bs if ragged else None)
+
+    @pl.when(n == 0)
+    def _():  # nothing follows a sequence's last block
+        dc_ref[bs:] = jnp.zeros((_HALO, bc), _F32)
+
+    @pl.when((b == 0) & (n == 0))
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+
+    fold = lambda a: _tree_sum([a[r:r + 8]
+                                for r in range(0, _CONV_CHUNK, 8)])
+
+    def norm_bwd(s, dy):  # y = s r, r = (sum s^2 + eps)^-1/2
+        r = jax.lax.rsqrt(_row_sum(s * s) + _L2_EPS)
+        y = s * r
+        return r * (dy - y * _row_sum(dy * y))
+
+    def piece(base, cs):
+        taps, c = _conv_taps(xe_ref, w_ref, b_ref, base, cs, K)
+        s, sig = _silu(c)
+        ds = dy_ref[0, pl.ds(base, _CONV_CHUNK), cs].astype(_F32)
+        if l2:
+            ds = _by_group(norm_bwd, s, ds)
+        dc = ds * (sig * (1.0 + c * (1.0 - sig)))
+        if ragged:  # rows past the sequence's end: nothing, whatever dy holds
+            row = _iota(dc.shape, 0) + (nb * bs + base)
+            dc = jnp.where(row < S, dc, 0.0)
+        dc_ref[pl.ds(base, _CONV_CHUNK), cs] = dc
+        for j in range(K):
+            acc_ref[j, :, cs] += fold(dc * taps[j])
+        acc_ref[K, :, cs] += fold(dc)
+        dx = _tree_sum([  # dx_t = sum_j w[j] dc_{t+K-1-j}
+            _rows_at(dc_ref, base, cs, K - 1 - j)
+            * w_ref[j:j + 1, cs].astype(_F32) for j in range(K)])
+        dx_ref[0, pl.ds(base, _CONV_CHUNK), cs] = dx.astype(dx_ref.dtype)
+
+    _each_piece(bs, bc, piece, last_first=True)
+    dc_ref[bs:] = dc_ref[:_HALO]    # the block before this one reads them
+
+    @pl.when((b == pl.num_programs(1) - 1) & (n == pl.num_programs(2) - 1))
+    def _():
+        dwb_ref[...] = jnp.zeros(dwb_ref.shape, _F32)
+        for j in range(K + 1):  # dw's K rows, then db's
+            dwb_ref[j:j + 1, :] = jnp.sum(acc_ref[j], 0, keepdims=True)
+
+
+def _conv_blocks(x, w, rows):
+    (B, S, C), K = x.shape, w.shape[0]
+    assert C % _L2 == 0 and K <= 7, (C, K)
+    bs = min(rows, -(-S // _HALO) * _HALO)
+    bc = next(c for c in _CONV_COLS if C % c == 0)
+    return B, S, C, K, bs, bc, -(-S // bs)
+
+
+# Jitted on their own, as the SSD calls are (ops/ssd.py): a stack's mixers
+# call these at two or three tensors a layer kind, and a program traces a
+# kernel's body once a shape.
+@functools.partial(jax.jit, static_argnames=("l2", "scope"))
+def _conv_fwd_call(x, w, b, l2, scope):
+    """x [B, S, C], w [K, C], b [1, C] or None -> y [B, S, C] in x's dtype."""
+    B, S, C, K, bs, bc, N = _conv_blocks(x, w, _CONV_ROWS[0])
+    row = lambda r, at: pl.BlockSpec((1, r, bc), lambda i, c, n: (i, at(n), c))
+    col = lambda r: pl.BlockSpec((r, bc), lambda i, c, n: (0, c))
+    hb = bs // _HALO
+    ins = [row(bs, lambda n: n),
+           row(_HALO, lambda n: jnp.maximum(n * hb - 1, 0)), col(K)]
+    with jax.named_scope(scope):
+        return pl.pallas_call(
+            functools.partial(_conv_fwd_kernel, K=K, l2=l2,
+                              bias=b is not None, S=S),
+            grid=(B, C // bc, N),
+            in_specs=ins + ([col(1)] if b is not None else []),
+            out_specs=row(bs, lambda n: n),
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            scratch_shapes=[pltpu.VMEM((_HALO + bs, bc), _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            interpret=_interpret(),
+        )(x, x, w, *(() if b is None else (b,)))
+
+
+@functools.partial(jax.jit, static_argnames=("l2", "scope"))
+def _conv_bwd_call(x, w, b, dy, l2, scope):
+    """-> dx [B, S, C] in x's dtype and one float32 [8, C]: dw's K rows, then
+    db's (a whole tile a column block, whatever K)."""
+    B, S, C, K, bs, bc, N = _conv_blocks(x, w, _CONV_ROWS[1])
+    row = lambda r, at: pl.BlockSpec((1, r, bc),
+                                     lambda c, i, n: (i, at(N - 1 - n), c))
+    col = lambda r: pl.BlockSpec((r, bc), lambda c, i, n: (0, c))
+    hb = bs // _HALO
+    blk = row(bs, lambda n: n)
+    ins = [blk, row(_HALO, lambda n: jnp.maximum(n * hb - 1, 0)), col(K)]
+    with jax.named_scope(scope):
+        return pl.pallas_call(
+            functools.partial(_conv_bwd_kernel, K=K, l2=l2,
+                              bias=b is not None, S=S),
+            grid=(C // bc, B, N),
+            in_specs=ins + ([col(1)] if b is not None else []) + [blk],
+            out_specs=[blk, col(8)],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct((8, C), _F32)],
+            scratch_shapes=[pltpu.VMEM((_HALO + bs, bc), _F32),
+                            pltpu.VMEM((bs + _HALO, bc), _F32),
+                            pltpu.VMEM((K + 1, 8, bc), _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            interpret=_interpret(),
+        )(x, x, w, *(() if b is None else (b,)), dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_kernels(x, w, b, l2, scope):
+    return _conv_fwd_call(x, w, b, l2, scope)
+
+
+def _conv_vjp_fwd(x, w, b, l2, scope):
+    return _conv_fwd_call(x, w, b, l2, scope), (x, w, b)
+
+
+def _conv_vjp_bwd(l2, scope, res, dy):
+    x, w, b = res
+    dx, dwb = _conv_bwd_call(x, w, b, dy, l2, scope)
+    K = w.shape[0]
+    return (dx, dwb[:K].astype(w.dtype),
+            None if b is None else dwb[K:K + 1].astype(b.dtype))
+
+
+_conv_kernels.defvjp(_conv_vjp_fwd, _conv_vjp_bwd)
+
+
+def mixer_conv_pallas(x, w, b=None, *, l2: bool = False,
+                      scope: str = "mixer.conv") -> jax.Array:
+    """`mixer_conv`'s kernel pair (the comment above): x [B, S, ...ch],
+    w [K, ...ch], b [...ch] or None; with `l2` the norm is over x's last
+    axis, which has to be `_L2` wide. The dispatcher comes here on the TPU;
+    tests come here directly and run the kernels in interpret mode."""
+    B, S = x.shape[:2]
+    assert not l2 or x.shape[-1] == _L2, x.shape
+    y = _conv_kernels(x.reshape(B, S, -1), w.reshape(w.shape[0], -1),
+                      None if b is None else b.reshape(1, -1), l2, scope)
+    return y.reshape(x.shape)
+
+
+def mixer_conv_xla(x, w, b=None, *, l2: bool = False) -> jax.Array:
+    """The same function in plain XLA, as the mixers wrote it out before the
+    kernels: the fallback, and the kernels' reference in the tests."""
+    y = short_conv(x, w)
+    if b is not None:
+        y = y + b.astype(y.dtype)
+    y = jax.nn.silu(y)
+    return l2_normalize(y) if l2 else y
+
+
+def use_conv_kernels(platform: str, channels: int, l2_group: Optional[int],
+                     on_mesh: bool) -> bool:
+    """The convolution path's dispatch rule, a pure function of what the
+    code observes: the kernels on a TPU, with the channels whole 128-lane
+    tiles, the norm (where there is one) over groups of 128, and no
+    multi-device mesh (`use_kernels`' reason)."""
+    return (platform == "tpu" and not on_mesh and channels % _L2 == 0
+            and l2_group in (None, _L2))
+
+
+def mixer_conv(x, w, b=None, *, l2: bool = False,
+               scope: str = "mixer.conv") -> jax.Array:
+    """A recurrent mixer's way from a projection to its core:
+    [l2norm](SiLU(short_conv(x, w) [+ b])) over x [B, S, ...ch], the norm
+    over the last axis. The Pallas pair where `use_conv_kernels` says so,
+    under the device scope `scope` (the caller's mixer: the backward rule is
+    traced outside it), else `mixer_conv_xla`. Each traced call counts once
+    in the phase table as `mixer.conv.pallas` or `mixer.conv.xla` with what
+    it observed."""
+    from ray_tpu.parallel.sharding import current_sharding_ctx
+    from ray_tpu.util import tracing
+
+    ctx = current_sharding_ctx()
+    channels = math.prod(x.shape[2:])
+    kernels = use_conv_kernels(
+        jax.devices()[0].platform, channels, x.shape[-1] if l2 else None,
+        ctx is not None and ctx[0].size > 1)
+    tracing.observe("mixer.conv." + ("pallas" if kernels else "xla"), 0,
+                    slow=False, rows=x.shape[1], channels=channels,
+                    taps=w.shape[0], l2=l2, bias=b is not None)
+    if kernels:
+        return mixer_conv_pallas(x, w, b, l2=l2, scope=scope)
+    return mixer_conv_xla(x, w, b, l2=l2)
 
 
 # ---------------------------------------------------------------- dispatch

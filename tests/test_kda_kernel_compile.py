@@ -34,9 +34,11 @@ def compiled_not_interpreted(monkeypatch):
     monkeypatch.setattr(kda, "_interpret", lambda: False)
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(ssd, "_interpret", lambda: False)
-    # The SSD calls are jitted on their own: no trace made in the other mode.
-    forget = lambda: [f.clear_cache() for f in (ssd._ssd_fwd_call,
-                                                ssd._ssd_bwd_call)]
+    # The SSD and convolution calls are jitted on their own: no trace made in
+    # the other mode.
+    forget = lambda: [f.clear_cache() for f in (
+        ssd._ssd_fwd_call, ssd._ssd_bwd_call, kda._conv_fwd_call,
+        kda._conv_bwd_call)]
     forget()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -92,6 +94,34 @@ def test_gdn_core_compiles_for_v5e(one_chip, compiled_not_interpreted, what):
         1 if what == "forward" else 2)
     assert f"[{B},{Hv},{S},{d}]" not in text
     assert f"f32[{B},{S},{Hv},{d}]" not in text
+
+
+def test_mixer_conv_kernels_compile_for_v5e(one_chip,
+                                            compiled_not_interpreted):
+    """The convolution path's pair (ops/kda.py `mixer_conv_pallas`) in one
+    gradient program at two of the cells' calls, bfloat16, four taps: q | k
+    of `kimi_linear_48b_a3b.train_share_8k` with the norm ([1,8192,32,128];
+    qwen3_next's v at 16,384 rows is the same blocks) and x of
+    `granite_4_0_h_micro.train_stage_4k` with its bias ([1,4096,64,64]:
+    4,096 channels, no norm). A forward and a backward Mosaic call each,
+    which read x [B,S,C] where a projection's product writes it (the
+    arguments lie so here: one that lies [B,S,H,d] tiles its heads and is
+    copied first) and write y and dx the same way: no copy of x's size."""
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    q, x = sd((1, 8192, 4096), jnp.bfloat16), sd((1, 4096, 4096),
+                                                 jnp.bfloat16)
+    wq, wx = sd((4, 32, 128), jnp.float32), sd((4, 64, 64), jnp.float32)
+
+    def loss(q, wq, x, wx, bx):
+        y = kda.mixer_conv_pallas(q.reshape(1, 8192, 32, 128), wq, l2=True)
+        z = kda.mixer_conv_pallas(x.reshape(1, 4096, 64, 64), wx, bx)
+        return (jnp.sum(y.astype(jnp.float32) ** 2)
+                + jnp.sum(z.astype(jnp.float32) ** 2))
+
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        q, wq, x, wx, sd((64, 64), jnp.float32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert not re.search(r"%copy[.\d]* = bf16\[1,(8192|4096),", text)
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])

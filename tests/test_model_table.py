@@ -44,7 +44,13 @@ REMAT = ("off", "dots", "full")
 # kanana2, qwen3_next, laguna): "full" keeps `moe.RESIDUAL_NAMES` there (and
 # the KDA / SSD kernels' names, which the CPU's XLA bodies do not carry);
 # every "off" and "dots" digest and the other presets' "full" are the
-# parent's.
+# parent's. PR 52 re-recorded the text digests of granite_hybrid and
+# qwen3_next: the halves of `mamba_wzx`, `gdn_wqk` and `gdn_wvz` come out of
+# their product one after the other (`cbsnh`), so that each tensor lies whole
+# as `mixer_conv`'s kernels and the cores read it (on the CPU the convolution
+# path is the XLA body, op for op the parent's: kimi_linear's digests, whose
+# projections were apart already, did not move); their scope digests and
+# every other preset's row are the parent's.
 PARENT = {
     "llama_tiny": ("477b60d37afe307a:1204d8d39a7b453e",
                    "fb0a0ec778730463:1204d8d39a7b453e",
@@ -58,18 +64,18 @@ PARENT = {
     "kimi_linear_tiny": ("1351b6f8a51ed658:0dec5428f5393512",
                          "595571e2024d4fe8:690c4963985676f7",
                          "0a701feee3e50793:690c4963985676f7"),
-    "granite_hybrid_tiny": ("ff3c8acbca76f994:1aa0ff837d278860",
-                            "51b8226e0760232d:9ce01d30262a7b2d",
-                            "30f4d092b8abcfbd:9ce01d30262a7b2d"),
+    "granite_hybrid_tiny": ("9ed9d48b47d5f78c:1aa0ff837d278860",
+                            "67c1cff2033e2b05:9ce01d30262a7b2d",
+                            "7276f6af5ccbf46a:9ce01d30262a7b2d"),
     "mellum2_tiny": ("7c8f75ed566a2912:5277c5b9e65d3b63",
                      "80fc62d0b1aaf755:5277c5b9e65d3b63",
                      "0738981824137919:5277c5b9e65d3b63"),
     "kanana2_tiny": ("ad659bdff892a231:593d1eba54411321",
                      "4ee529c508a5e1e7:593d1eba54411321",
                      "22aac6f201ad571f:593d1eba54411321"),
-    "qwen3_next_tiny": ("6a18e99b94bd3ae4:4d7d9eca8c599952",
-                        "befaff51114e9531:79df8da7a94dd775",
-                        "4639e692055caaa1:79df8da7a94dd775"),
+    "qwen3_next_tiny": ("28208d1e66f6925c:4d7d9eca8c599952",
+                        "1dc2fad0f258ad23:79df8da7a94dd775",
+                        "f73571e7b7631809:79df8da7a94dd775"),
     # new in PR 45 (its own tree's: the `swa` kind with its own heads, theta
     # and rotated share, the gate a head); the rows above are the parent's
     "laguna_tiny": ("f5f7f7b0405c9cb0:99e87d210fd8ce25",
