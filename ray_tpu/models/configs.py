@@ -395,3 +395,43 @@ def ouro_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def keye_vl2_tiny(**overrides) -> TransformerConfig:
+    """A learned-sparse-attention stack in the Keye-VL-2.0 pattern at widths
+    small enough for CPU tests (docs/model_layers.md, "dsa"): every layer 4
+    query heads over 2 key heads of 16 with q / k norms and M-RoPE in three
+    sections (2, 3, 3 of the 8 pairs; theta 1e4), an indexer of 4 heads of 8
+    over one key head, each query attending to the 16 earlier keys it ranks
+    highest, the indexer's loss added at coefficient 1; 16 softmax-routed
+    experts, 3 a token, none shared, experts 4-7 held here; an untied head.
+    Published sizes live in chipbench/configs/ only."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=2,
+        n_heads=4,
+        n_kv_heads=2,
+        attn_head_dim=16,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-6,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=10000.0,
+        rope_sections=(2, 3, 3),
+        attn_qk_norm=True,
+        tie_embeddings=False,
+        dsa_layers=tuple(range(1, 49)),
+        dsa_topk=16,
+        dsa_index_heads=4,
+        dsa_index_head_dim=8,
+        moe_num_experts=16,
+        moe_experts_per_token=3,
+        moe_router="softmax",
+        moe_held=(4, 4),
+        moe_d_ff=32,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

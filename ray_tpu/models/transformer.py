@@ -32,7 +32,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel import tensor_overlap as tp
-from ray_tpu.parallel.sharding import maybe_constrain
+from ray_tpu.parallel.sharding import current_sharding_ctx, maybe_constrain
 from ray_tpu.util import tracing
 
 Params = Dict[str, Any]
@@ -70,6 +70,13 @@ class TransformerConfig:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     yarn_attn_factor: float = 1.0
+    # M-RoPE (None: one position stream, and nothing is traced for it): the
+    # rotation's pairs in three contiguous sections (temporal, height, width;
+    # they add up to the rotated pairs), pair i turning by the position of
+    # its section's stream. `loss_fn` then reads `batch["positions"]`
+    # [3, B, S]; without it the three streams are `arange` and the rotation
+    # is plain RoPE. The rotation of the "attn", "swa" and "dsa" kinds.
+    rope_sections: Optional[Tuple[int, int, int]] = None
     # Width of an attention head; None => d_model // n_heads.
     attn_head_dim: Optional[int] = None
     # Three properties of the "attn" / "swa" layers and one of the stack's
@@ -114,7 +121,9 @@ class TransformerConfig:
     #   inverses; SSD: y, chunk states; a held range of experts: the first
     #   window's two grouped products; named in their forward rules,
     #   RESIDUAL_NAMES of ops/flash_attention.py, ops/kda.py, ops/ssd.py and
-    #   ops/moe.py) and recompute the rest of the layer body in the backward:
+    #   ops/moe.py; a "dsa" layer: the selection's bits, both logsumexps,
+    #   o and the index loss's pass, ops/sparse_attention.py) and recompute
+    #   the rest of the layer body in the backward:
     #   projections, rotations, norms, convolutions, routing, every product.
     #   What a sizing sweep falls back to when "dots" does not fit. What the
     #   kept residuals cost a layer at one sequence of 16,384 on one v5e chip
@@ -207,6 +216,7 @@ class TransformerConfig:
     mamba_layers: Tuple[int, ...] = ()
     swa_layers: Tuple[int, ...] = ()
     gdn_layers: Tuple[int, ...] = ()
+    dsa_layers: Tuple[int, ...] = ()
     sliding_window: Optional[int] = None
     kda_heads: Optional[int] = None       # None => n_heads
     kda_head_dim: int = 128
@@ -228,6 +238,21 @@ class TransformerConfig:
     gdn_head_dim: int = 128
     gdn_conv: int = 4
     gdn_chunk: int = 128
+    # - dsa_layers: learned sparse attention (ops/sparse_attention.py): the
+    #   "attn" layer's projections, q / k norms and rotation, and beside them
+    #   an indexer on the layer's DETACHED input: `dsa_index_heads` query
+    #   heads of `dsa_index_head_dim` over one key head (a LayerNorm on the
+    #   key, plain RoPE at the first position stream on the first half of
+    #   both), a weight a head; I[t, s] = sum_j w[t, j] relu(qi[t, j] .
+    #   ki[s]) / sqrt(heads x dim). A query attends to the `dsa_topk` earlier
+    #   keys of largest I (all of them while there are no more; ties to the
+    #   lower index). The layer adds `DSA_LOSS_COEF` x mean_t KL(mean over
+    #   heads of the attention's probabilities, detached || softmax of I
+    #   over the kept keys) to the loss: the indexer's leaves learn from that
+    #   term alone and no other leaf from it.
+    dsa_topk: int = 2048
+    dsa_index_heads: int = 16
+    dsa_index_head_dim: int = 64
     # Four scalars some families multiply by (docs/model_layers.md); at
     # these defaults no operation is traced for them. Embeddings times
     # `embed_scale`; both residual branches of every layer times
@@ -263,7 +288,7 @@ class TransformerConfig:
 
     def __post_init__(self):
         fields = [m.layers_field for m in MIXERS.values() if m.layers_field]
-        for name in fields + ["moe_held"]:
+        for name in fields + ["moe_held", "rope_sections"]:
             v = getattr(self, name)
             if isinstance(v, list):  # from a JSON file
                 object.__setattr__(self, name, tuple(v))
@@ -286,6 +311,13 @@ class TransformerConfig:
                 raise ValueError(
                     "rope_fraction / swa_rope_fraction rotate an even number "
                     "of a head's columns, at most all of them")
+            if self.rope_sections and 2 * sum(self.rope_sections) != rot:
+                raise ValueError("rope_sections add up to the rotated pairs "
+                                 f"of a head, {rot // 2}")
+        if self.rope_sections and self.mla_rotates and any(
+                l <= self.n_layers for l in self.mla_layers):
+            raise ValueError("an mla layer's rotation takes one position "
+                             "stream: no rope_sections")
         if self.swa_heads and self.swa_heads % self.kv_heads:
             raise ValueError("swa_heads is a multiple of the key / value "
                              "heads")
@@ -569,6 +601,19 @@ def _attn_shapes(cfg: TransformerConfig, mixer: str = "attn"):
         sh["q_norm"] = ((hd,), (None,), unit)
         sh["k_norm"] = ((hd,), (None,), unit)
     return sh
+
+
+def _dsa_shapes(cfg: TransformerConfig):
+    # The "attn" layer's leaves and the indexer's: queries a head, one key
+    # head with its LayerNorm, a weight a head.
+    d, HI, dI = cfg.d_model, cfg.dsa_index_heads, cfg.dsa_index_head_dim
+    fan = _fan(d)
+    return {**_attn_shapes(cfg),
+            "dsa_wq": ((d, HI, dI), ("embed", None, None), fan),
+            "dsa_wk": ((d, dI), ("embed", None), fan),
+            "dsa_k_norm": ((dI,), (None,), "ones"),
+            "dsa_k_norm_b": ((dI,), (None,), "zeros"),
+            "dsa_ww": ((d, HI), ("embed", None), fan)}
 
 
 def _gdn_shapes(cfg: TransformerConfig):
@@ -856,11 +901,14 @@ def yarn_ramp(half: int, theta: float, original_len: int, beta_fast: float,
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float,
-          yarn=None) -> jax.Array:
+          yarn=None, sections=None) -> jax.Array:
     """Rotary embedding over the last dim of [B, S, H, D]. `yarn`
     (`TransformerConfig.rope_yarn`): blended inverse frequencies, and cos
     and sin times the attention factor. Frequencies, angles and the factor
-    are float32: at position 16,383 an angle in bfloat16 is radians off."""
+    are float32: at position 16,383 an angle in bfloat16 is radians off.
+    `sections` (`TransformerConfig.rope_sections`) with `positions`
+    [3, B, S]: pair i turns by the stream of its section (M-RoPE), the
+    sections contiguous; positions [B, S] are the one stream of all."""
     D = x.shape[-1]
     half = D // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
@@ -868,7 +916,15 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
         factor, original_len, beta_fast, beta_slow, attn_factor = yarn
         r = yarn_ramp(half, theta, original_len, beta_fast, beta_slow)
         freqs = freqs * (1.0 - r) + freqs / factor * r
-    angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,S,half]
+    if positions.ndim == 3:
+        if sections is None:
+            raise ValueError("positions [3, B, S] need rope_sections")
+        ends = np.cumsum((0,) + tuple(sections))
+        angles = jnp.concatenate(
+            [positions[i, :, :, None].astype(jnp.float32) * freqs[a:b]
+             for i, (a, b) in enumerate(zip(ends[:-1], ends[1:]))], axis=-1)
+    else:
+        angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,S,half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     if yarn is not None:
@@ -879,13 +935,14 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
 
 
 def _rope_first(x: jax.Array, rot: int, positions: jax.Array, theta: float,
-                yarn=None) -> jax.Array:
+                yarn=None, sections=None) -> jax.Array:
     """`_rope` on the first `rot` columns of every head, the rest passed
     (`TransformerConfig.rope_fraction`); all of them: `_rope` itself."""
     if rot == x.shape[-1]:
-        return _rope(x, positions, theta, yarn)
+        return _rope(x, positions, theta, yarn, sections)
     return jnp.concatenate(
-        [_rope(x[..., :rot], positions, theta, yarn), x[..., rot:]], axis=-1)
+        [_rope(x[..., :rot], positions, theta, yarn, sections), x[..., rot:]],
+        axis=-1)
 
 
 def _w(layer: Params, name: str, cfg: TransformerConfig) -> jax.Array:
@@ -930,8 +987,8 @@ def _qkv_proj(cfg: TransformerConfig, h: jax.Array, layer: Params,
                 for x, n in ((q, "q_norm"), (k, "k_norm")))
     if cfg.positional == "rope":
         theta, rot, yarn = cfg.attn_rope(mixer)
-        q = _rope_first(q, rot, positions, theta, yarn)
-        k = _rope_first(k, rot, positions, theta, yarn)
+        q = _rope_first(q, rot, positions, theta, yarn, cfg.rope_sections)
+        k = _rope_first(k, rot, positions, theta, yarn, cfg.rope_sections)
     return q, k, v
 
 
@@ -1170,6 +1227,59 @@ def _attn_mixer(cfg, kind, h, layer, positions, overlap):
     return delta, k, v
 
 
+def _dsa_mixer(cfg, kind, h, layer, positions, overlap):
+    """Learned sparse attention -> (delta, k, v, extras): the "attn" layer's
+    projections (`_qkv_proj`), the indexer on the detached input, the
+    selection and attention over it (ops/sparse_attention.py). extras:
+    `dsa_kl` the layer's mean KL (the indexer's loss, `loss_fn` adds it),
+    `dsa_selected` the kept (query, key) pairs, `dsa_bits` the selection
+    (the kernels' kept residual; `loss_fn(with_selection=True)` returns it,
+    any other program drops it)."""
+    from ray_tpu.ops import sparse_attention as sa
+
+    _one_chip("a learned-sparse-attention (dsa) layer's kernels")
+    B, S, _ = h.shape
+    HI, dI = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+    pn = sa.plan(S)
+    tracing.observe(
+        "dsa.plan", 0, slow=False, seq=S, topk=cfg.dsa_topk, impl="threshold",
+        index_heads=HI, index_dim=dI, **pn._asdict())
+    q, k, v = _qkv_proj(cfg, h, layer, positions, "dsa")
+    q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
+    with jax.named_scope("dsa.index"):
+        hs = jax.lax.stop_gradient(h)
+        first = positions[0] if positions.ndim == 3 else positions
+        qi = jnp.einsum("bsd,dnh->bsnh", hs, _w(layer, "dsa_wq", cfg))
+        ki = _norm(hs @ _w(layer, "dsa_wk", cfg), layer["dsa_k_norm"],
+                   layer["dsa_k_norm_b"], "layernorm", cfg.norm_eps)
+        qi = _rope_first(qi, dI // 2, first, cfg.rope_theta)
+        ki = _rope_first(ki[:, :, None], dI // 2, first, cfg.rope_theta)[:, :, 0]
+        w = jnp.einsum("bsd,dn->bsn", hs, _w(layer, "dsa_ww", cfg),
+                       preferred_element_type=jnp.float32) * (HI * dI) ** -0.5
+    o, kl, count, bits = sa.sparse_attention(
+        q, k, v, qi, ki, w, topk=cfg.dsa_topk,
+        scale=cfg.attn_scale or cfg.head_dim ** -0.5)
+    extras = {"dsa_kl": kl.mean(), "dsa_selected": count.sum(),
+              "dsa_bits": bits}
+    return o.reshape(B, S, -1) @ _w(layer, "wo", cfg), k, v, extras
+
+
+# The indexer's loss in the stack's: L = L_LM + DSA_LOSS_COEF x sum over the
+# "dsa" layers of their mean KL (one value in use, so no field).
+DSA_LOSS_COEF = 1.0
+
+
+def _one_chip(what: str) -> None:
+    """Refuse a mesh: the "dsa" kernels are under no `shard_map` (a top-k
+    across sequence shards is not written) and `shard_batch` would cut the
+    three position streams as if they were rows of the batch."""
+    ctx = current_sharding_ctx()
+    if ctx is not None and ctx[0].size > 1:
+        raise NotImplementedError(
+            f"{what} run on one chip, not under a mesh of {ctx[0].size} "
+            "(ROADMAP R22 (a): the selection under a mesh)")
+
+
 def _swa_flops(cfg: TransformerConfig, S: int) -> float:
     W = min(cfg.sliding_window, S)  # the band's pairs for the triangle's
     return (6.0 * (2 * W - W * (W - 1) / S) * cfg.attn_heads("swa")
@@ -1218,6 +1328,20 @@ MIXERS: Dict[str, Mixer] = {m.name: m for m in (
               "hold a delta-rule state and the convolution's last tokens in "
               "place of keys and values (ROADMAP R7 / R9); the stack trains "
               "but does not serve yet")),
+    # What the algorithm needs a token: attention over the kept sets and the
+    # indexer's scores of every earlier key (chipbench/reduce/
+    # keye_vl2_counts.py), not what the thresholded kernels spend.
+    Mixer("dsa", "dsa_layers", _dsa_shapes, _dsa_mixer,
+          lambda cfg, S: 3.0 * (
+              2 * cfg.head_dim * cfg.n_heads * 2 * sum(
+                  min(t + 1, cfg.dsa_topk) for t in range(S)) / S
+              + 2 * cfg.dsa_index_heads * cfg.dsa_index_head_dim * (S + 1) / 2),
+          scope=lambda cfg: "dsa", no_decode=(
+              "decode cannot serve a learned-sparse-attention (dsa) layer: a "
+              "tick would score the slab's keys with the indexer (a cache of "
+              "indexer keys beside the key / value slab) and attend to its "
+              "top-k (ROADMAP R22 (a)); the stack trains but does not serve "
+              "yet")),
 )}
 _PLAIN = next(n for n, m in MIXERS.items() if m.layers_field is None)
 
@@ -1252,8 +1376,9 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     row = MIXERS[mixer]
     scope = row.scope(cfg)
     with jax.named_scope(scope) if scope else contextlib.nullcontext():
-        delta, k, v = row.apply(cfg, kind, h if row.cut_rows else whole(h),
-                                layer, positions, overlap)
+        # a mixer with a loss or counters of its own returns them fourth
+        delta, k, v, *own = row.apply(cfg, kind, h if row.cut_rows else whole(h),
+                                      layer, positions, overlap)
     # `cfg.post_norm`: x + N2(f(N1(x))), the sublayer's output normed too.
     post = lambda delta, n: _norm(delta, layer[n], None, cfg.norm,
                                   cfg.norm_eps, cfg.norm_offset)
@@ -1269,6 +1394,8 @@ def _layer_body(cfg: TransformerConfig, kind: Tuple[str, str], x: jax.Array,
     x = maybe_constrain(x + _scaled(delta, cfg.residual_scale), residual)
     if overlap is not None:
         overlap.observe()
+    if own:
+        extras = {**extras, **own[0]}
     return x, extras, k, v
 
 
@@ -1315,6 +1442,13 @@ def layer_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
     dots = cfg.remat_policy == "dots"
     names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
              + moe.RESIDUAL_NAMES + (tp.RESIDUAL_NAMES if dots else ()))
+    if kind[0] == "dsa":
+        # (imported where a "dsa" layer is traced, as `_dsa_mixer` does; the
+        # experts' products are kept as under every other kind: at one
+        # sequence of 32,768 they are 0.59 GB an expert layer, PR 54)
+        from ray_tpu.ops.sparse_attention import RESIDUAL_NAMES
+
+        names += RESIDUAL_NAMES
     policy = jax.checkpoint_policies.save_only_these_names(*names)
     if dots:
         policy = jax.checkpoint_policies.save_from_both_policies(
@@ -1358,7 +1492,8 @@ def _stack_once(params: Params, x: jax.Array, positions: jax.Array,
 
 
 def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig,
-              read: Optional[Callable] = None):
+              read: Optional[Callable] = None,
+              positions: Optional[jax.Array] = None):
     """Everything before the lm head: the stack (`_stack_once`), under
     `cfg.loop_steps` > 1 as many times over the same leaves, the final norm
     closing every pass (its output h the next pass's input). The passes are
@@ -1372,10 +1507,14 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     moe_window_rows (max), moe_load_mean (mean) over the expert layers'
     applications). `read` (a looped stack's head, `loss_fn`): called on every
     pass's h under the device scope `loop.head`, what it returns stacked
-    over the passes is returned third."""
+    over the passes is returned third. `positions` [3, B, S] (a stack with
+    `rope_sections`): the three streams the rotations turn by; None: every
+    token's place in its row."""
     B, S = tokens.shape
     x = embed_tokens(params, tokens, cfg)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
+                                     (B, S))
     if cfg.loop_steps == 1:
         x, per_layer = _stack_once(params, x, positions, cfg)
         return x, _stack_extras(per_layer)
@@ -1404,10 +1543,18 @@ def _backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig,
 def _stack_extras(per_layer) -> Dict[str, jax.Array]:
     if not per_layer:
         return {}
-    cat = {n: jnp.concatenate([e[n] for e in per_layer]) for n in per_layer[0]}
+    cat = {n: jnp.concatenate([e[n] for e in per_layer if n in e])
+           for n in dict.fromkeys(n for e in per_layer for n in e)}
+    out = {}
+    if "dsa_kl" in cat:  # `_dsa_mixer`: the indexer's loss and its counters
+        out = {"dsa_index_loss": cat["dsa_kl"].sum(),
+               "dsa_selected": cat["dsa_selected"].sum(),
+               "dsa_selection": cat["dsa_bits"]}
     if "aux" in cat:
-        return {"aux": cat["aux"].sum()}
-    return {"moe_assigned": cat["assigned"].sum(),
+        return {**out, "aux": cat["aux"].sum()}
+    if "assigned" not in cat:
+        return out
+    return {**out, "moe_assigned": cat["assigned"].sum(),
             "moe_dropped": cat["dropped"].sum(),
             "moe_past_buffer": cat["past_buffer"].sum(),
             "moe_load_max": cat["load_max"].max(),
@@ -1500,14 +1647,23 @@ def next_token_loss(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
-            *, shift_inputs: bool = False, with_counters: bool = False):
+            *, shift_inputs: bool = False, with_counters: bool = False,
+            with_selection: bool = False):
     """Next-token cross-entropy; of a stack with an exit gate the expected
     one under its exit distribution (`_expected_exit_loss`). `with_counters`:
     return (loss, counters) for `ShardedTrainStep(has_aux=True)`: device
     scalars moe_assigned, moe_dropped, moe_past_buffer, moe_load_max,
     moe_load_mean, moe_trips, moe_window_rows, moe_rows_worked of a stack
     with a held range of experts (`_backbone`), exit_mass_pm_1..T and
-    exit_entropy_pm of one with an exit gate, {} for any other.
+    exit_entropy_pm of one with an exit gate, dsa_selected (kept pairs, summed
+    over layers and queries) and dsa_index_loss (the layers' KL terms, which
+    the loss holds `DSA_LOSS_COEF` times) of one with "dsa" layers, {} for
+    any other; `with_selection`: also `dsa_selection`, every "dsa" layer's
+    kept keys as bits ([L, B, S, S / 32] int32; a comparison's, not a
+    step's). `batch["positions"]` ([3, B, S], or [3, B, S + 1] under
+    `shift_inputs`, cut as the tokens are; a stack with `rope_sections`):
+    the rotations' three position streams; absent: `arange`, and the program
+    is the one a batch of tokens alone always traced.
 
     Two token conventions:
     - in-place (default): batch tokens [B,S]; the forward runs on the FULL
@@ -1526,14 +1682,24 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
     """
     tokens = batch["tokens"]
     inputs = tokens[:, :-1] if shift_inputs else tokens
+    positions = batch.get("positions")
+    if positions is not None:
+        _one_chip("a batch's three position streams")
+        if shift_inputs:
+            positions = positions[:, :, :-1]
     targets_valid = lambda: (
         shift_targets_valid(tokens, batch.get("mask")) if shift_inputs
         else inplace_targets_valid(batch))
-    if cfg.exit_gate:
-        loss, extras = _expected_exit_loss(params, inputs, *targets_valid(),
-                                           cfg)
+
+    def done(loss, extras):
+        if not with_selection:  # dropped here, so never lowered
+            extras.pop("dsa_selection", None)
         return (loss, extras) if with_counters else loss
-    x, extras = _backbone(params, inputs, cfg)
+
+    if cfg.exit_gate:
+        return done(*_expected_exit_loss(params, inputs, *targets_valid(),
+                                         cfg, positions))
+    x, extras = _backbone(params, inputs, cfg, positions=positions)
     targets, valid = targets_valid()
     if cfg.fused_ce:
         from ..ops.fused_ce import fused_next_token_loss
@@ -1544,9 +1710,18 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
             targets, valid)
     else:
         loss = token_cross_entropy(lm_head(params, x, cfg), targets, valid)
-    if "aux" in extras:  # GShard's balancing loss; what is left are counters
+    return done(_with_layer_losses(loss, extras, cfg), extras)
+
+
+def _with_layer_losses(loss, extras, cfg: TransformerConfig):
+    """The loss with the terms the layers returned through `extras`: GShard's
+    balancing loss (popped: what is left are counters) and the "dsa" layers'
+    indexer loss (kept, a counter too)."""
+    if "aux" in extras:
         loss = loss + cfg.moe_aux_coef * extras.pop("aux")
-    return (loss, extras) if with_counters else loss
+    if "dsa_index_loss" in extras:
+        loss = loss + DSA_LOSS_COEF * extras["dsa_index_loss"]
+    return loss
 
 
 def exit_log_probs(gate_logits: jax.Array) -> jax.Array:
@@ -1563,7 +1738,8 @@ def exit_log_probs(gate_logits: jax.Array) -> jax.Array:
 
 def _expected_exit_loss(params: Params, inputs: jax.Array,
                         targets: jax.Array, valid: jax.Array,
-                        cfg: TransformerConfig):
+                        cfg: TransformerConfig,
+                        positions: Optional[jax.Array] = None):
     """`loss_fn` of a stack with an exit gate -> (loss, counters): the mean
     over valid tokens of sum_t p(t) CE^t - `exit_entropy_coef` H(p), CE^t a
     token's cross-entropy under pass t's head (the one `lm_head`, the final
@@ -1598,15 +1774,13 @@ def _expected_exit_loss(params: Params, inputs: jax.Array,
         ce = (chunked if cfg.fused_ce else plain)(h, head.astype(cfg.dtype))
         return ce, jnp.sum(h.astype(jnp.float32) * w_g, -1) + b_g
 
-    _, extras, (ce, gate) = _backbone(params, inputs, cfg, read)
+    _, extras, (ce, gate) = _backbone(params, inputs, cfg, read, positions)
     logp = exit_log_probs(gate)
     p = jnp.exp(logp)
     entropy = -(p * logp).sum(0)
     per_token = (p * ce).sum(0) - cfg.exit_entropy_coef * entropy
     mean = lambda a: (a * valid).sum() / jnp.maximum(valid.sum(), 1.0)
-    loss = mean(per_token)
-    if "aux" in extras:
-        loss = loss + cfg.moe_aux_coef * extras.pop("aux")
+    loss = _with_layer_losses(mean(per_token), extras, cfg)
     for t in range(cfg.loop_steps):
         extras[f"exit_mass_pm_{t + 1}"] = 1000.0 * mean(p[t])
     extras["exit_entropy_pm"] = 1000.0 * mean(entropy)

@@ -23,7 +23,7 @@ LONGEST_FILES = (
     "test_preset_programs", "test_qwen3_next", "test_kda", "test_moe",
     "test_models", "test_expert_shares", "test_model_table",
     "test_flash_backward", "test_kimi_linear", "test_tensor_overlap",
-    "test_kda_scalar", "test_granite_hybrid", "test_ssd_kernels",
+    "test_kda_scalar", "test_dsa", "test_granite_hybrid", "test_ssd_kernels",
     "test_kda_remat", "test_flash_attention", "test_flash_attention_shapes",
     "test_rollout", "test_serve", "test_llm_engine", "test_generate",
     "test_ouro", "test_tensor_overlap_rows", "test_moe_held_loop",

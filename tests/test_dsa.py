@@ -1,0 +1,296 @@
+"""The learned-sparse-attention mixer ("dsa", models/transformer.py
+`_dsa_mixer` over ops/sparse_attention.py) at a small size on the CPU, the
+kernels interpreted and every call jitted: the exact top-k with its tie rule,
+the program against the plain reference (chipbench/reference/keye_vl2.py) on
+seeded weights (loss, every gradient group, the selection), what the layer is
+where nothing can be left out, M-RoPE, where the indexer's gradient comes
+from, and the positions a batch carries."""
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import configs, transformer as tfm
+from ray_tpu.ops import sparse_attention as sa
+from test_kda_remat import _kernel_calls
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from chipbench import weights_keye_vl2 as W  # noqa: E402
+from chipbench.reference import keye_vl2 as ref  # noqa: E402
+
+pytestmark = pytest.mark.usefixtures("exact_matmuls")
+
+S = 64  # the preset's length: two key planes of a word
+
+
+def _sizes(cfg):
+    tc = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+    return W.KeyeSizes(tc, cfg.norm_eps)
+
+
+def _batch(cfg, seed=5, B=2):
+    """Tokens, three position streams that differ over an "image" of 4 x 4
+    patches (Qwen2-VL's rule) and a mask that skips it."""
+    rng = np.random.default_rng(seed)
+    toks = rng.integers(0, cfg.vocab_size, (B, S + 1), dtype=np.int32)
+    t = np.arange(S + 1)
+    pos = np.stack([t, t, t])
+    at, side = 20, 4
+    img = np.arange(side * side)
+    pos[0, at:at + 16] = at
+    pos[1, at:at + 16] = at + img // side
+    pos[2, at:at + 16] = at + img % side
+    pos[:, at + 16:] = at + side + np.arange(S + 1 - at - 16)
+    mask = np.ones((B, S + 1), np.int32)
+    mask[:, at:at + 16] = 0
+    return {"tokens": jnp.asarray(toks),
+            "positions": jnp.asarray(np.broadcast_to(
+                pos[:, None], (3, B, S + 1)).astype(np.int32)),
+            "mask": jnp.asarray(mask)}
+
+
+@pytest.fixture(scope="module")
+def case():
+    with jax.default_matmul_precision("highest"):
+        cfg = configs.keye_vl2_tiny(dtype=jnp.float32)
+        sz = _sizes(cfg)
+        key = jax.random.key(54)
+        params = W.program_params(key, sz, cfg)
+        batch = _batch(cfg)
+
+        def prog(p):
+            return tfm.loss_fn(p, batch, cfg, shift_inputs=True,
+                               with_counters=True, with_selection=True)
+
+        (loss, counters), g = jax.jit(jax.value_and_grad(
+            prog, has_aux=True))(params)
+        own = jax.jit(lambda: ref.loss_and_grads(key, batch, sz))()
+        given = jax.jit(lambda s: ref.loss_and_grads(
+            key, batch, sz, selection=s))(counters["dsa_selection"])
+    return dict(cfg=cfg, sz=sz, key=key, params=params, batch=batch,
+                loss=loss, counters=counters, grads=g, own=own, given=given)
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize("topk", [1, 5, 24, 64])
+def test_the_selection_is_the_exact_top_k_ties_to_the_lower_index(topk):
+    """Scores rounded to a few values, so that most rows tie at their
+    threshold: the bits are the first `topk` of a stable descending sort of
+    the causal scores, a row's count is min(t + 1, topk), and the rows'
+    logsumexp is over the kept set."""
+    B, HI, dI = 2, 4, 8
+    ks = jax.random.split(jax.random.key(topk), 3)
+    qi = jnp.round(jax.random.normal(ks[0], (B, S, HI, dI)))
+    ki = jnp.round(jax.random.normal(ks[1], (B, S, dI)))
+    w = jnp.round(jax.random.normal(ks[2], (B, S, HI)) * 2) / 8
+    bits, lse, count = jax.jit(lambda a, b, c: sa.select(a, b, c, topk))(
+        jnp.swapaxes(qi, 1, 2), jnp.swapaxes(ki, 1, 2),
+        jnp.swapaxes(w, 1, 2)[:, :, None])
+    got = np.asarray(sa.mask_of(bits))
+    score = np.einsum("btj,btjs->bts", w, np.maximum(
+        np.einsum("btjd,bsd->btjs", qi, ki), 0))
+    tied = 0
+    for b in range(B):
+        for t in range(S):
+            sc = np.where(np.arange(S) <= t, score[b, t], -np.inf)
+            keep = np.lexsort((np.arange(S), -sc))[:min(t + 1, topk)]
+            want = np.zeros(S, bool)
+            want[keep] = True
+            assert (got[b, t] == want).all(), (b, t)
+            tied += (sc[keep].min() == sc[~want]).any()
+            np.testing.assert_allclose(
+                lse[b, 0, 0, t], np.log(np.exp(score[b, t][want]).sum()),
+                rtol=1e-5)
+    assert tied > S // 2 or topk == 64  # the tie rule was exercised
+    assert (np.asarray(count)[:, 0, 0]
+            == np.minimum(np.arange(S) + 1, topk)).all()
+
+
+def test_the_program_is_the_reference(case):
+    """Loss, both of its parts, the selection and the counter; then, GIVEN
+    the program's selection, every compared gradient leaf."""
+    cfg, c = case["cfg"], case["counters"]
+    loss_r, _, aux = case["own"]
+    assert abs(float(case["loss"]) - float(loss_r)) < 2e-5
+    assert abs(float(c["dsa_index_loss"]) - float(aux["index"])) < 2e-5
+    assert float(c["dsa_selected"]) == float(aux["kept"]) == (
+        cfg.n_layers * 2 * sum(min(t + 1, cfg.dsa_topk) for t in range(S)))
+    # float32 against float32: the two selections are the same sets
+    np.testing.assert_array_equal(c["dsa_selection"], aux["bits"])
+    loss_g, g_r, aux_g = case["given"]
+    assert float(aux_g["missed"]) == 0.0 and float(aux_g["margin"]) == 0.0
+    assert abs(float(case["loss"]) - float(loss_g)) < 2e-5
+    got = W.program_leaves(cfg, case["sz"], case["grads"])
+    assert set(got) == set(g_r)
+    for leaf, want in g_r.items():
+        assert float(jnp.linalg.norm(want)) > 0, leaf
+        assert _rel(got[leaf], want) < 3e-5, leaf
+
+
+def test_a_wrong_selection_is_seen(case):
+    """The comparison's own readings move when the selection is not the
+    reference's: keys 0..k-1 for every late query miss most of its top-k by
+    a margin of the scores' own spread."""
+    cfg, sz = case["cfg"], case["sz"]
+    t, s = np.arange(S)[:, None], np.arange(S)[None, :]
+    first = (s <= t) & (s < cfg.dsa_topk)
+    bits = jnp.broadcast_to(ref.pack(jnp.asarray(first)),
+                            case["counters"]["dsa_selection"].shape)
+    _, _, aux = jax.jit(lambda b: ref.loss_and_grads(
+        case["key"], case["batch"], sz, selection=b))(bits)
+    assert float(aux["kept"]) == float(case["counters"]["dsa_selected"])
+    assert float(aux["missed"]) / float(aux["kept"]) > 0.2
+    assert float(aux["margin"]) > 0.5
+
+
+def test_where_nothing_is_left_out_the_layer_is_the_attn_layer(case):
+    """`dsa_topk` >= S keeps every earlier key: the stack's loss less the
+    indexer's term is the loss of the same stack with "attn" layers (the
+    same leaves without the indexer's)."""
+    whole = dataclasses.replace(case["cfg"], dsa_topk=S)
+    plain = dataclasses.replace(whole, dsa_layers=())
+    strip = lambda seg: [{n: a for n, a in layer.items()
+                          if not n.startswith("dsa_")} for layer in seg]
+    p_plain = dict(case["params"], layers=[
+        strip(seg) for seg in case["params"]["layers"]])
+    loss, c = jax.jit(lambda p: tfm.loss_fn(
+        p, case["batch"], whole, shift_inputs=True, with_counters=True))(
+        case["params"])
+    want = jax.jit(lambda p: tfm.loss_fn(
+        p, case["batch"], plain, shift_inputs=True))(p_plain)
+    assert float(c["dsa_selected"]) == whole.n_layers * 2 * S * (S + 1) / 2
+    assert abs(float(loss) - float(c["dsa_index_loss"]) - float(want)) < 2e-5
+
+
+def test_mrope_with_three_equal_streams_is_rope():
+    x = jax.random.normal(jax.random.key(0), (2, S, 4, 16))
+    pos = jnp.broadcast_to(jnp.arange(S)[None] * 3 + 1, (2, S))
+    plain = jax.jit(lambda x: tfm._rope(x, pos, 1e4))(x)
+    three = jax.jit(lambda x: tfm._rope(
+        x, jnp.stack([pos] * 3), 1e4, None, (2, 3, 3)))(x)
+    np.testing.assert_array_equal(plain, three)
+    # and a stream of its own turns its section's pairs alone
+    moved = jnp.stack([pos, pos + 7, pos])
+    got = jax.jit(lambda x: tfm._rope(x, moved, 1e4, None, (2, 3, 3)))(x)
+    same = np.isclose(got, plain, atol=1e-6).all(axis=(0, 1, 2))
+    assert same.tolist() == [True] * 2 + [False] * 3 + [True] * 3 + [
+        True] * 2 + [False] * 3 + [True] * 3
+    with pytest.raises(ValueError, match="rope_sections"):
+        tfm._rope(x, moved, 1e4)
+    with pytest.raises(ValueError, match="add up"):
+        configs.keye_vl2_tiny(rope_sections=(2, 3, 4))
+
+
+def test_the_indexer_learns_from_its_loss_alone_and_nothing_else_does(case):
+    """L = L_LM + DSA_LOSS_COEF x the counter `dsa_index_loss`: each part
+    differentiated alone."""
+    cfg, params, batch = case["cfg"], case["params"], case["batch"]
+
+    def parts(p):
+        loss, c = tfm.loss_fn(p, batch, cfg, shift_inputs=True,
+                              with_counters=True)
+        index = c["dsa_index_loss"]
+        return loss - tfm.DSA_LOSS_COEF * index, index
+
+    g_lm = jax.jit(jax.grad(lambda p: parts(p)[0]))(params)
+    g_ix = jax.jit(jax.grad(lambda p: parts(p)[1]))(params)
+    flat = lambda g: {jax.tree_util.keystr(k): a for k, a in
+                      jax.tree_util.tree_leaves_with_path(g)}
+    for name, a in flat(g_lm).items():
+        assert (float(jnp.abs(a).max()) == 0.0) == ("dsa_" in name), name
+    for name, a in flat(g_ix).items():
+        assert (float(jnp.abs(a).max()) > 0.0) == ("dsa_" in name), name
+
+
+def test_a_batch_without_positions_counts_them_itself(case):
+    cfg = case["cfg"]
+    toks = case["batch"]["tokens"]
+    f = jax.jit(lambda b: tfm.loss_fn(case["params"], b, cfg,
+                                      shift_inputs=True))
+    arange = jnp.broadcast_to(jnp.arange(S + 1, dtype=jnp.int32),
+                              (3,) + toks.shape)
+    assert float(f({"tokens": toks})) == float(
+        f({"tokens": toks, "positions": arange}))
+    assert float(f({"tokens": toks})) != float(
+        f({"tokens": toks, "positions": case["batch"]["positions"]}))
+    # in place: the positions are not cut
+    g = jax.jit(lambda b: tfm.loss_fn(case["params"], b, cfg))
+    assert float(g({"tokens": toks[:, :S]})) == float(
+        g({"tokens": toks[:, :S], "positions": arange[:, :, :S]}))
+
+
+@pytest.mark.parametrize("policy", ["dots", "full"])
+def test_remat_keeps_the_selection_and_re_runs_no_kernel(case, policy):
+    """Under either policy the gradient is the plain program's and the
+    kernels' residuals are kept: the traced gradient has each kernel once a
+    layer."""
+    cfg = case["cfg"]
+    remat = dataclasses.replace(cfg, remat=True, remat_policy=policy)
+    grad = lambda cfg: jax.jit(jax.value_and_grad(lambda p: tfm.loss_fn(
+        p, case["batch"], cfg, shift_inputs=True)))
+    want_loss, want = grad(cfg)(case["params"])
+    loss, got = grad(remat)(case["params"])
+    assert abs(float(loss) - float(want_loss)) < 1e-6
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=1e-6)
+    # each kernel once a layer (select 3 in / 3 out, forward 4 / 2, backward
+    # 7 / 3, the indexer's loss 8 / 4): the backward's recomputation re-runs
+    # none
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(lambda p: tfm.loss_fn(
+        p, case["batch"], remat, shift_inputs=True)))(case["params"]).jaxpr)
+    assert calls == {"3in_3out": 2, "4in_2out": 2, "7in_3out": 2,
+                     "8in_4out": 2}, calls
+
+
+
+def test_a_mesh_is_refused_with_a_sentence(case):
+    """The kernels are under no `shard_map` and `shard_batch` would cut the
+    three position streams as rows of the batch: under a sharding context of
+    more than one device a "dsa" layer, and a batch's positions, say so."""
+    from jax.sharding import Mesh
+
+    from ray_tpu.parallel import sharding as shd
+
+    cfg, params, toks = case["cfg"], case["params"], case["batch"]["tokens"]
+    devices = np.array(jax.devices()[:2])
+    if devices.size < 2:
+        pytest.skip("one device")
+    with shd.sharding_ctx(Mesh(devices, ("data",)), shd.DEFAULT_RULES):
+        with pytest.raises(NotImplementedError, match="dsa.*R22"):
+            jax.eval_shape(lambda p: tfm.loss_fn(
+                p, {"tokens": toks}, cfg, shift_inputs=True), params)
+        with pytest.raises(NotImplementedError, match="position streams"):
+            jax.eval_shape(lambda p: tfm.loss_fn(
+                p, case["batch"], cfg, shift_inputs=True), params)
+    # a mesh of one device is no mesh
+    with shd.sharding_ctx(Mesh(devices[:1], ("data",)), shd.DEFAULT_RULES):
+        jax.eval_shape(lambda p: tfm.loss_fn(
+            p, case["batch"], cfg, shift_inputs=True), params)
+
+
+def test_the_control_below_the_indexers_float32_reads_apart(case):
+    """The reference with L_I formed in bfloat16 (the scores as it reads
+    them, their logsumexp, the target; chipbench/limits_sparse.py
+    `bf16_index`) is not the float32 reference: L_I moves by bfloat16's
+    rounding and nothing else does; with float32 named it is the reference
+    bit for bit."""
+    sz, key, batch = case["sz"], case["key"], case["batch"]
+    run = lambda dt: jax.jit(lambda: ref.loss(key, batch, sz,
+                                              index_dtype=dt))()
+    _, low = run(jnp.bfloat16)
+    loss, same = run(jnp.float32)
+    want = float(case["own"][2]["index"])
+    assert float(same["index"]) == want
+    assert float(loss) == float(case["own"][0])
+    assert abs(float(low["index"]) - want) / want > 1e-3
+    np.testing.assert_array_equal(low["bits"], same["bits"])
+    assert float(low["lm"]) == float(same["lm"])

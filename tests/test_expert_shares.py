@@ -194,6 +194,10 @@ FAMILIES = {
     "laguna_tiny": dict(weights="weights_laguna", sizes="LagunaSizes",
                         reference="laguna", kind=("swa", "moe"), layer=1,
                         experts=16, atol=3e-5),
+    # eight shares of two: the eight ranks of the cell's layer
+    "keye_vl2_tiny": dict(weights="weights_keye_vl2", sizes="KeyeSizes",
+                          reference="keye_vl2", kind=None, layer=0,
+                          experts=16, atol=3e-5),
 }
 
 

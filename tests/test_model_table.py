@@ -27,7 +27,7 @@ pytestmark = pytest.mark.usefixtures("exact_matmuls")
 
 PRESETS = ("llama_tiny", "gpt2_tiny", "moe_tiny", "kimi_linear_tiny",
            "granite_hybrid_tiny", "mellum2_tiny", "kanana2_tiny",
-           "qwen3_next_tiny", "laguna_tiny", "ouro_tiny")
+           "qwen3_next_tiny", "laguna_tiny", "ouro_tiny", "keye_vl2_tiny")
 REMAT = ("off", "dots", "full")
 # "<sha256[:16] of the StableHLO>:<sha256[:16] of its operations' name
 # stacks>" of each preset's gradient program, remat off and under either
@@ -87,6 +87,10 @@ PARENT = {
     "ouro_tiny": ("3282a46b53f1b59b:b9265fa8a1143fdc",
                   "2f196b66ea41495d:b9265fa8a1143fdc",
                   "cc6e52c1bdf75295:b9265fa8a1143fdc"),
+    # PR 54's own (the "dsa" kind's kernels interpreted)
+    "keye_vl2_tiny": ("fd77242856c36a7e:eff64fa6672f2d28",
+                      "af6d1355fbb3bb56:eff64fa6672f2d28",
+                      "28e26817ef30b114:eff64fa6672f2d28"),
     # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text;
     # re-recorded in PR 47 (its own tree's: one backward kernel where the
     # parent's text held dQ's and dK/dV's; the operations' scopes unmoved)
@@ -129,6 +133,9 @@ PARENT_COUNTS = {
                           2121808896.0),
     "ouro_2_6b.json": (612438017, 612438017, 15503818776.0,  # PR 49's own
                        12483919896.0),
+    "keye_vl2_tiny": (114592, 74656, 383808.0, 472344.0),  # PR 54's own
+    "keye_vl_2_0_30b_a3b.json": (465391104, 182275584, 1653021696.0,
+                                 917013504.0),  # PR 54's own
 }
 
 
@@ -218,16 +225,20 @@ def test_the_table_is_what_the_configuration_lists():
     row's leaves are its own, and a row that cannot be decoded says why."""
     rows = tfm.MIXERS
     assert list(rows) == [r.name for r in rows.values()] == [
-        "attn", "swa", "mla", "kda", "mamba2", "gdn"]
+        "attn", "swa", "mla", "kda", "mamba2", "gdn", "dsa"]
     fields = {f.name for f in dataclasses.fields(tfm.TransformerConfig)}
     listed = [r.layers_field for r in rows.values() if r.layers_field]
     assert sorted(listed) == sorted(f for f in fields if f.endswith("_layers")
                                     and f != "n_layers")
     assert [r.name for r in rows.values() if not r.layers_field] == ["attn"]
     cfg = configs.llama_tiny(sliding_window=8)
+    # ("swa" holds the "attn" layer's leaves; "dsa" holds them and its own)
+    own = lambda r: [n for n in r.shapes(cfg) if r.name != "dsa"
+                     or n.startswith("dsa_")]
     leaves = collections.Counter(
-        n for r in rows.values() if r.name != "swa" for n in r.shapes(cfg))
+        n for r in rows.values() if r.name != "swa" for n in own(r))
     assert max(leaves.values()) == 1
+    assert set(rows["attn"].shapes(cfg)) < set(rows["dsa"].shapes(cfg))
     # swa's leaves are attn's until it has a head count of its own
     assert rows["swa"].shapes(cfg) == rows["attn"].shapes(cfg)
     wider = configs.llama_tiny(sliding_window=8, swa_heads=8)
@@ -249,7 +260,7 @@ _a, _s, _m = ("attn", "dense"), ("swa", "moe"), ("mamba2", "dense")
 _g, _ga = ("gdn", "moe"), ("attn", "moe")
 _ld, _lm = ("mla", "dense"), ("mla", "moe")
 _kd, _km = ("kda", "dense"), ("kda", "moe")
-_ad = ("attn", "dense")
+_ad, _d = ("attn", "dense"), ("dsa", "moe")
 PLANS = {
     "llama_tiny": dict(plan=(((_a,), 2),), deep=(24, (((_a,), 24),)),
                        slot=(1, (0, 0, 1)), segments=False,
@@ -296,6 +307,11 @@ PLANS = {
                       slot=(1, (0, 0, 1)), segments=False,
                       refused=[dict(loop_steps=1), dict(loop_steps=0),
                                dict(norm="layernorm")]),
+    "keye_vl2_tiny": dict(plan=(((_d,), 2),), deep=(48, (((_d,), 48),)),
+                          slot=(1, (0, 0, 1)), segments=True,
+                          refused=[dict(rope_sections=(2, 3, 4)),
+                                   dict(mla_layers=(49,), n_layers=49),
+                                   dict(moe_router="softmax_capacity")]),
 }
 
 
@@ -361,7 +377,8 @@ def test_decoding_serves_a_row_or_says_its_sentence(preset):
         prefill(params, toks, cfg, 16)
     word = {"kimi_linear_tiny": "KDA / MLA", "granite_hybrid_tiny": "Mamba-2",
             "mellum2_tiny": "windowed", "kanana2_tiny": "MLA",
-            "qwen3_next_tiny": "R7 / R9", "laguna_tiny": "windowed"}[preset]
+            "qwen3_next_tiny": "R7 / R9", "laguna_tiny": "windowed",
+            "keye_vl2_tiny": "R22 (a)"}[preset]
     assert word in why[0]
 
 
@@ -402,7 +419,10 @@ def test_decoding_refuses_what_it_does_not_apply(field):
 # The device scopes a preset's program must carry, from its rows and from
 # where the mixers open their own (`docs/model_layers.md`).
 INNER = {"kda": ("kda.core",), "gdn": ("gdn.core",), "mamba2": ("ssd.core",),
-         "swa": ("swa",), "mla": (), "attn": ()}
+         "swa": ("swa",), "mla": (), "attn": (),
+         # (`dsa.core` too, but its kernels sit in `custom_vjp` functions
+         # that lower apart, their locations relative: tests/test_dsa.py)
+         "dsa": ("dsa.index",)}
 
 
 @pytest.mark.parametrize("preset", PRESETS)
@@ -419,7 +439,10 @@ def test_a_presets_program_carries_its_rows_scopes(preset):
                 tfm.MIXERS[m].scope(cfg) for m in mixers}:
             assert scope not in under, scope
     assert ("mla.rope" in under) == ("mla" in mixers and cfg.mla_rotates)
-    assert ("gattn.gate" in under) == cfg.attn_gated == ("gattn" in under)
+    # (the gate's scopes are the "attn" / "swa" kinds': a "dsa" layer's q / k
+    # norms run under `dsa`)
+    gated = cfg.attn_gated and bool(mixers & {"attn", "swa"})
+    assert ("gattn.gate" in under) == gated == ("gattn" in under)
 
 
 def test_the_docs_table_is_the_table():

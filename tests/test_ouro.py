@@ -38,7 +38,7 @@ WRONG = ("three_passes_for_four", "no_norm_between_passes", "no_post_norms",
          "entropy_sign_turned", "beta_0", "mean_mass_for_a_tokens_own")
 
 
-def _wrong_backbone(kind, params, tokens, cfg, read=None):
+def _wrong_backbone(kind, params, tokens, cfg, read=None, positions=None):
     """`tfm._backbone`'s passes written out with one thing got wrong: the
     next pass handed the state before the final norm, or the gate reading
     that state."""
