@@ -2,16 +2,21 @@
 """Chip probe of ops/sparse_attention.py at the Keye-VL-2.0 cell's shape
 ([1, S, 32|4, 128], an indexer of 16 x 64, top 2,048): each kernel's ms a
 call, the core's two kernels over a few tiles, and the selection's count to
-the unit; and, with `gathered` as a second argument, ISSUE 54's other way
+the unit, the passes its search ran and the ways its blocks went, held bit
+for bit to the parent's kernel (PR 54's fixed 33 + 15 passes a block, kept
+below as a copy) with that kernel's time at all, half and none of its trips
+(the price of a pass; `select` as a second argument stops there); and, with
+`gathered` as a second argument, ISSUE 54's other way
 alone: attention over kept keys brought by index through XLA's gather, on
 2,048 late query rows (a sixteenth of the layer). (What the kernels compute is held to the plain reference by the
 cell's own comparison, chipbench/drivers/train_stack_sparse.py, at the cell's
 size; tests/test_dsa.py at a small one.) Refuses to run off the chip.
 
-    chiprun -- python3 benchmarks/probe_dsa.py [S] [gathered]
+    chiprun -- python3 benchmarks/probe_dsa.py [S] [gathered | select]
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -21,6 +26,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import sparse_attention as sa
 
@@ -43,6 +50,125 @@ def timed(fn, *args, n=5):
     for _ in range(n):
         out = jax.block_until_ready(fn(*args))
     return (time.perf_counter() - t) / n * 1e3, out
+
+
+# ------------------------------------------------- the parent's selection
+#
+# ops/sparse_attention.py's `_select_kernel` as PR 54 shipped it, kept here
+# (not in ray_tpu/) as what the adaptive search is held to bit for bit, and
+# for the price of a counting pass: `trips` scales the three search loops'
+# trip counts (1.0 the parent's 32 + 1 + nbits passes; 0.5 half of them, 0.0
+# none: the answers are then wrong and only the time is read).
+
+
+def _parent_select_kernel(qi_ref, ki_ref, w_ref, bits_ref, lse_ref, cnt_ref,
+                          keys_scr, w_scr, *, S, topk, R, ck, planes, heads,
+                          trips):
+    _iota, _flip, _F32 = sa._iota, sa._flip, jnp.float32
+    ib = pl.program_id(1)
+    for j in range(heads):
+        w_scr[j] = w_ref[j].T
+    row = ib * R + _iota((R, 1), 0)
+    n_ck = ((ib + 1) * R + ck - 1) // ck
+    fold = ck // 128 if ck % 128 == 0 else 1
+
+    def cols(c):
+        return pl.ds(pl.multiple_of(c * ck, ck), ck)
+
+    def fill(c, _):
+        scores = sa._index_scores(qi_ref, w_scr, ki_ref[:, cols(c)], heads)
+        key = _flip(jax.lax.bitcast_convert_type(scores, jnp.int32))
+        col = c * ck + _iota((R, ck), 1)
+        keys_scr[:, cols(c)] = jnp.where(col <= row, key, sa._INT_MIN)
+        return 0
+
+    jax.lax.fori_loop(0, n_ck, fill, 0)
+
+    def count(pred):
+        def body(c, acc):
+            hit = pred(keys_scr[:, cols(c)], c * ck).astype(_F32)
+            if fold == 1:
+                return acc + jnp.sum(hit, axis=1, keepdims=True)
+            for f in range(fold):
+                acc = acc + hit[:, f * 128:(f + 1) * 128]
+            return acc
+        acc = jax.lax.fori_loop(
+            0, n_ck, body, jnp.zeros((R, 1 if fold == 1 else 128), _F32))
+        return acc if fold == 1 else jnp.sum(acc, axis=1, keepdims=True)
+
+    k_eff = jnp.minimum(row + 1, topk).astype(_F32)
+
+    def value_bit(i, u):
+        cand = u | jnp.left_shift(jnp.int32(1), 31 - i)
+        n = count(lambda key, _: key >= (cand ^ sa._INT_MIN))
+        return jnp.where(n >= k_eff, cand, u)
+
+    tau = jax.lax.fori_loop(0, int(32 * trips), value_bit,
+                            jnp.zeros((R, 1), jnp.int32)) ^ sa._INT_MIN
+    if trips > 0:
+        ties = k_eff - count(lambda key, _: key > tau)
+    else:
+        ties = k_eff
+    nbits = max(1, (S - 1).bit_length())
+
+    def index_bit(i, x):
+        cand = x | jnp.left_shift(jnp.int32(1), nbits - 1 - i)
+        n = count(lambda key, c0: (key == tau)
+                  & (c0 + _iota((R, ck), 1) < cand))
+        return jnp.where(n <= ties - 1.0, cand, x)
+
+    last = jax.lax.fori_loop(0, int(nbits * trips), index_bit,
+                             jnp.zeros((R, 1), jnp.int32))
+
+    word = jnp.zeros((R, planes), jnp.int32)
+    cnt = jnp.zeros((R, 1), _F32)
+    m = jnp.full((R, 1), sa._NEG_INF, _F32)
+    l = jnp.zeros((R, 1), _F32)
+    for p in range(32):
+        key = keys_scr[:, p * planes:(p + 1) * planes]
+        col = p * planes + _iota((R, planes), 1)
+        sel = ((key > tau) | ((key == tau) & (col <= last))) & (col <= row)
+        word = word | jnp.left_shift(sel.astype(jnp.int32), p)
+        cnt = cnt + jnp.sum(sel.astype(_F32), axis=1, keepdims=True)
+        scores = jnp.where(sel, jax.lax.bitcast_convert_type(
+            _flip(key), _F32), sa._NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+        l = l * jnp.exp(m - m_new) + jnp.sum(
+            jnp.where(sel, jnp.exp(scores - m_new), 0.0), axis=1,
+            keepdims=True)
+        m = m_new
+    bits_ref[...] = word
+    lse_ref[...] = (m + jnp.log(l)).T
+    cnt_ref[...] = cnt.T
+
+
+def parent_select(qi, ki_t, w, topk, trips=1.0):
+    """`sa.select` as the parent of PR 55 had it -> bits, lse_i, count."""
+    B, HI, S, dI = qi.shape
+    pn = sa.plan(S)
+    R, ck = pn.rows, pn.chunk
+    stat = jax.ShapeDtypeStruct((B, 1, 1, S), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_parent_select_kernel, S=S, topk=topk, R=R, ck=ck,
+                          planes=pn.planes, heads=HI, trips=trips),
+        grid=(B, S // R),
+        in_specs=[
+            pl.BlockSpec((None, HI, R, dI), lambda b, i: (b, 0, i, 0)),
+            pl.BlockSpec((None, dI, S), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, HI, 1, R), lambda b, i: (b, 0, 0, i)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, R, pn.planes), lambda b, i: (b, i, 0)),
+            sa._stat_spec(R, lambda b, i: (b, 0, 0, i)),
+            sa._stat_spec(R, lambda b, i: (b, 0, 0, i)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((B, S, pn.planes), jnp.int32),
+                   stat, stat],
+        scratch_shapes=[pltpu.VMEM((R, S), jnp.int32),
+                        pltpu.VMEM((HI, R, 1), jnp.float32)],
+        compiler_params=sa._params("parallel", "arbitrary"),
+        name="dsa_select_parent", interpret=sa._interpret(),
+    )(qi, ki_t, w)
 
 
 def gathered(S, H, KVH, D, topk, scale, rows=2048, block=64):
@@ -90,7 +216,7 @@ def main():
         out["gathered"] = gathered(S, H, KVH, D, topk, scale)
         print(json.dumps(out))
         os.makedirs("chiprun_out", exist_ok=True)
-        with open("chiprun_out/p54_probe_gathered.json", "w") as f:
+        with open("chiprun_out/probe_dsa_gathered.json", "w") as f:
             json.dump(out, f)
         return
 
@@ -99,11 +225,47 @@ def main():
     qt, kt, vt, cot = (jnp.swapaxes(x, 1, 2) for x in (q, k, v, co))
     qi_t, ki_t = jnp.swapaxes(qi, 1, 2), jnp.swapaxes(ki, 1, 2)
     w_t = jnp.swapaxes(w, 1, 2)[:, :, None]
-    ms, (bits, lse_i, cnt) = timed(jax.jit(
+    ms, (bits, lse_i, cnt, passes, way) = timed(jax.jit(
         lambda a, b, c: sa.select(a, b, c, topk)), qi_t, ki_t, w_t)
     out["select_ms"] = ms
+    # the search: counting passes a block of rows (the parent ran 33 + the
+    # index's bits in every block), and the blocks by the way they went
+    out["select_passes"] = float(passes.mean()) / sa._SAMPLE
+    out["select_passes_max"] = float(passes.max()) / sa._SAMPLE
+    out["select_ways"] = [int((way == i).sum()) for i in range(3)]
     want = jnp.minimum(jnp.arange(S) + 1, topk)
     out["count_exact"] = bool((cnt[0, 0, 0] == want).all())
+    # the parent's kernel on the same inputs: bit for bit, and the price of
+    # its passes from the same kernel at half and at none of its trips
+    for trips in (1.0, 0.5, 0.0):
+        ms, old = timed(jax.jit(lambda a, b, c, t=trips: parent_select(
+            a, b, c, topk, t)), qi_t, ki_t, w_t)
+        out["parent_select_ms_trips_%g" % trips] = ms
+        if trips == 1.0:
+            out["parent_bit_for_bit"] = {
+                name: bool((x == y).all()) for name, x, y in zip(
+                    ("bits", "lse_i", "count"), (bits, lse_i, cnt), old)}
+    # the tie search on the chip: every key met twice (most blocks hold a
+    # row that keeps one of two) and scores rounded to a few values (rows
+    # keep many of their ties), each against the parent's kernel
+    both = jax.jit(lambda a, b, c: (sa.select(a, b, c, topk),
+                                    parent_select(a, b, c, topk)))
+    for name, (a, b, c) in (
+            ("met_twice", (qi_t, jnp.repeat(ki_t[:, :, ::2], 2, axis=2), w_t)),
+            ("rounded", (jnp.round(qi_t), jnp.round(ki_t),
+                         jnp.round(w_t * 64) / 8))):
+        new, old = both(a, b, c)
+        out["ties_" + name] = {
+            "bit_for_bit": all(bool((x == y).all())
+                               for x, y in zip(new[:3], old)),
+            "passes": float(new[3].mean()) / sa._SAMPLE,
+            "ways": [int((new[4] == i).sum()) for i in range(3)]}
+    if sys.argv[2:] == ["select"]:
+        print(json.dumps(out))
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/probe_dsa_select.json", "w") as f:
+            json.dump(out, f)
+        return
     ms, (o, lse) = timed(jax.jit(
         lambda *a: sa._attend_fwd(*a, scale)), qt, kt, vt, bits)
     out["fwd_ms"] = ms
@@ -131,7 +293,7 @@ def main():
     out["selected_pct"] = 100.0 * pairs / (S * (S + 1) / 2)
     print(json.dumps(out))
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/p54_probe_dsa.json", "w") as f:
+    with open("chiprun_out/probe_dsa.json", "w") as f:
         json.dump(out, f)
 
 

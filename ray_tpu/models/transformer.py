@@ -1232,9 +1232,11 @@ def _dsa_mixer(cfg, kind, h, layer, positions, overlap):
     projections (`_qkv_proj`), the indexer on the detached input, the
     selection and attention over it (ops/sparse_attention.py). extras:
     `dsa_kl` the layer's mean KL (the indexer's loss, `loss_fn` adds it),
-    `dsa_selected` the kept (query, key) pairs, `dsa_bits` the selection
-    (the kernels' kept residual; `loss_fn(with_selection=True)` returns it,
-    any other program drops it)."""
+    `dsa_selected` the kept (query, key) pairs, `dsa_select_passes` and
+    `dsa_select_fallback` the search's counting passes (in eighths) and its
+    blocks off the short way, `dsa_bits` the selection (the kernels' kept
+    residual; `loss_fn(with_selection=True)` returns it, any other program
+    drops it)."""
     from ray_tpu.ops import sparse_attention as sa
 
     _one_chip("a learned-sparse-attention (dsa) layer's kernels")
@@ -1256,11 +1258,12 @@ def _dsa_mixer(cfg, kind, h, layer, positions, overlap):
         ki = _rope_first(ki[:, :, None], dI // 2, first, cfg.rope_theta)[:, :, 0]
         w = jnp.einsum("bsd,dn->bsn", hs, _w(layer, "dsa_ww", cfg),
                        preferred_element_type=jnp.float32) * (HI * dI) ** -0.5
-    o, kl, count, bits = sa.sparse_attention(
+    o, kl, count, bits, passes, way = sa.sparse_attention(
         q, k, v, qi, ki, w, topk=cfg.dsa_topk,
         scale=cfg.attn_scale or cfg.head_dim ** -0.5)
     extras = {"dsa_kl": kl.mean(), "dsa_selected": count.sum(),
-              "dsa_bits": bits}
+              "dsa_bits": bits, "dsa_select_passes": passes.sum(),
+              "dsa_select_fallback": (way != 0).sum()}
     return o.reshape(B, S, -1) @ _w(layer, "wo", cfg), k, v, extras
 
 
@@ -1549,6 +1552,8 @@ def _stack_extras(per_layer) -> Dict[str, jax.Array]:
     if "dsa_kl" in cat:  # `_dsa_mixer`: the indexer's loss and its counters
         out = {"dsa_index_loss": cat["dsa_kl"].sum(),
                "dsa_selected": cat["dsa_selected"].sum(),
+               "dsa_select_passes": cat["dsa_select_passes"].sum(),
+               "dsa_select_fallback": cat["dsa_select_fallback"].sum(),
                "dsa_selection": cat["dsa_bits"]}
     if "aux" in cat:
         return {**out, "aux": cat["aux"].sum()}
@@ -1656,10 +1661,14 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
     moe_load_mean, moe_trips, moe_window_rows, moe_rows_worked of a stack
     with a held range of experts (`_backbone`), exit_mass_pm_1..T and
     exit_entropy_pm of one with an exit gate, dsa_selected (kept pairs, summed
-    over layers and queries) and dsa_index_loss (the layers' KL terms, which
-    the loss holds `DSA_LOSS_COEF` times) of one with "dsa" layers, {} for
-    any other; `with_selection`: also `dsa_selection`, every "dsa" layer's
-    kept keys as bits ([L, B, S, S / 32] int32; a comparison's, not a
+    over layers and queries), dsa_index_loss (the layers' KL terms, which
+    the loss holds `DSA_LOSS_COEF` times), dsa_select_passes (the selection's
+    counting passes in eighths of a pass, summed over layers and blocks of
+    query rows) and dsa_select_fallback (the blocks whose search left the
+    short way, summed over layers; both whole numbers whose mean a block
+    follows from shapes: ops/sparse_attention.py `select`) of one with "dsa"
+    layers, {} for any other; `with_selection`: also `dsa_selection`, every
+    "dsa" layer's kept keys as bits ([L, B, S, S / 32] int32; a comparison's, not a
     step's). `batch["positions"]` ([3, B, S], or [3, B, S + 1] under
     `shift_inputs`, cut as the tokens are; a stack with `rope_sections`):
     the rotations' three position streams; absent: `arange`, and the program

@@ -14,14 +14,25 @@ are never held. Four Pallas kernels, each under the device scope its metric
 reads (chipbench/reduce/scopes.py):
 
 - `_select_kernel` (`dsa.index`): a block of query rows at a time it forms
-  the rows' scores against every earlier key in VMEM (never in HBM), finds each
-  row's `topk`-th largest EXACTLY by bisection over the scores' 32 bits (32
-  counting passes over the block; no sort, no approximate top-k), settles ties
-  at that value towards the lower index by a second bisection over the index,
-  and writes the selection as BITS: `bits[b, t, l]` bit `p` says whether key
-  `p * (S / 32) + l` is kept by query `t` (a key tile of any later kernel is a
-  `[bq, bk]` block of words and one shift, no movement along the lanes). Also
-  the rows' logsumexp of I over the kept set and the kept count.
+  the rows' scores against every earlier key in VMEM (never in HBM) and finds
+  each row's kept set EXACTLY by counting passes over the block (no sort, no
+  approximate top-k). A row's threshold is held between two candidates of
+  the scores' 32 bits, `count(key >= lo) >= k > count(key >= hi)`, and a pass
+  halves the gap; what the search costs depends on what it observes. A
+  sample (an eighth of the columns) gives every row a bracket, and two
+  counts over the WHOLE block check it: an end that fails sends its row back
+  to the half line the count proved, so exactness never rests on the sample.
+  The loop ends once every row's `lo` counts exactly `k` keys (the kept set
+  is `key >= lo`; no later kernel reads the threshold) or is the `k`-th
+  largest key itself with keys at it left out; only a block with such a row
+  settles ties towards the lower index, by a second bisection over the
+  index. On random scores at S = 32,768 that is 24 passes a block (2% of
+  the blocks tie) where the fixed search ran 33 + 15.
+  The selection is written as BITS: `bits[b, t, l]` bit `p` says whether
+  key `p * (S / 32) + l` is kept by query `t` (a key tile of any later
+  kernel is a `[bq, bk]` block of words and one shift, no movement along
+  the lanes). Also the rows' logsumexp of I over the kept set, the kept
+  count, and a block's passes and way (`select`).
 - `_fwd_kernel`, `_bwd_kernel` (`dsa.core`): flash attention, a head a grid
   step, with the mask of a tile read from the bits; the backward is one kernel
   (dK, dV and dQ from one S, dP and exponential, a head's dQ held in VMEM
@@ -54,7 +65,8 @@ from ray_tpu.ops.flash_attention import (_NEG_INF, _dot_nn, _dot_nt, _dot_tn,
 
 RESIDUAL_NAMES = ("dsa_bits", "dsa_lse_i", "dsa_o", "dsa_lse", "dsa_kl",
                   "dsa_dqi", "dsa_dki", "dsa_dw")
-_INT_MIN = -2 ** 31
+_INT_MIN, _INT_MAX = -2 ** 31, 2 ** 31 - 1
+_SAMPLE = 8  # the selection's bracket comes from one column in `_SAMPLE`
 _F32 = jnp.float32
 _VMEM_LIMIT = 100 << 20  # a v5e core has 128 MiB
 
@@ -119,7 +131,8 @@ def _selected(bits_ref, ik, per_plane):
 
 
 def _select_kernel(qi_ref, ki_ref, w_ref, bits_ref, lse_ref, cnt_ref,
-                   keys_scr, w_scr, *, S, topk, R, ck, planes, heads):
+                   passes_ref, way_ref, keys_scr, w_scr, *, S, topk, R, ck,
+                   planes, heads):
     ib = pl.program_id(1)
     for j in range(heads):
         w_scr[j] = w_ref[j].T
@@ -152,30 +165,118 @@ def _select_kernel(qi_ref, ki_ref, w_ref, bits_ref, lse_ref, cnt_ref,
             0, n_ck, body, jnp.zeros((R, 1 if fold == 1 else 128), _F32))
         return acc if fold == 1 else jnp.sum(acc, axis=1, keepdims=True)
 
-    # The k-th largest key of each row, bit by bit from the top, in the
-    # unsigned order u = key ^ INT_MIN: the largest u that k keys reach.
+    # The sample: `sw` lanes of every `stride`-th chunk, an eighth of the
+    # columns, the lanes' offset moving with the chunk (from c alone).
+    sw = 128 if fold > 1 else ck
+    stride = max(1, _SAMPLE // (ck // sw))
+    n_sm = (n_ck + stride - 1) // stride
+
+    def sample_count(cand):
+        """Rows' counts [R, 1] of the sample's keys >= cand."""
+        def body(j, acc):
+            c = j * stride
+            at = c * ck + (j % (ck // sw)) * sw
+            hit = (keys_scr[:, pl.ds(pl.multiple_of(at, sw), sw)]
+                   >= cand).astype(_F32)
+            return acc + (hit if fold > 1 else
+                          jnp.sum(hit, axis=1, keepdims=True))
+        acc = jax.lax.fori_loop(0, n_sm, body, jnp.zeros(
+            (R, sw if fold > 1 else 1), _F32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def middle(lo, hi):  # lo < middle < hi where hi - lo >= 2; wraps as uint32
+        return lo + jax.lax.shift_right_logical(hi - lo, 1)
+
+    # Each row's threshold between two candidates, in the keys' int32 order:
+    # count(key >= lo) = n_lo >= k_eff > n_hi = count(key >= hi). A row is
+    # settled where n_lo IS k_eff (its kept set is key >= lo: no later kernel
+    # reads the threshold itself) or where hi = lo + 1 (lo is the k_eff-th
+    # largest key, and keys at it are left out: a tie to settle by index).
     k_eff = jnp.minimum(row + 1, topk).astype(_F32)
+    n_row = (row + 1).astype(_F32)
+    whole = (jnp.full((R, 1), _INT_MIN, jnp.int32), n_row,
+             jnp.full((R, 1), _INT_MAX, jnp.int32), jnp.zeros((R, 1), _F32))
 
-    def value_bit(i, u):
-        cand = u | jnp.left_shift(jnp.int32(1), 31 - i)
-        n = count(lambda key, _: key >= (cand ^ _INT_MIN))
-        return jnp.where(n >= k_eff, cand, u)
+    def bracket():
+        """lo, n_lo, hi, n_hi from the sample, each end CHECKED by a count
+        over the whole block; the passes it took; whether any row's end
+        failed (that row then starts from the half line the count proved)."""
+        m = sample_count(_INT_MIN + 1)  # the row's columns in the sample
+        k_s = k_eff * m / n_row
+        # the sample's count of a row's k_eff largest keys is hypergeometric:
+        # variance under k_s (1 - m / n); four deviations either way
+        dev = 4.0 * jnp.sqrt(k_s * (1.0 - m / n_row)) + 1.0
 
-    tau = jax.lax.fori_loop(0, 32, value_bit,
-                            jnp.zeros((R, 1), jnp.int32)) ^ _INT_MIN
-    # Of the keys AT tau the first `ties` by index: the largest x below
-    # which fewer than `ties` of them lie is the last one's index.
-    ties = k_eff - count(lambda key, _: key > tau)
+        def search(st):
+            i, lo, hi, live = st
+            cand = middle(lo, hi)
+            x = sample_count(cand)
+            up = (live > 0) & (x >= k_s + dev)
+            down = (live > 0) & (x <= k_s - dev)
+            lo, hi = jnp.where(up, cand, lo), jnp.where(down, cand, hi)
+            live = ((up | down) & (hi - lo != 1)).astype(jnp.int32)
+            return i + 1, lo, hi, live
+
+        steps, lo, hi, _ = jax.lax.while_loop(
+            lambda st: jnp.max(st[3]) > 0, search,
+            (jnp.int32(0), whole[0], whole[2], jnp.ones((R, 1), jnp.int32)))
+        n_lo = jnp.where(lo == _INT_MIN, n_row,
+                         count(lambda key, _: key >= lo))
+        n_hi = count(lambda key, _: key >= hi)
+        low, high = n_lo < k_eff, n_hi >= k_eff  # an end on the wrong side
+        out = (jnp.where(low, _INT_MIN, jnp.where(high, hi, lo)),
+               jnp.where(low, n_row, jnp.where(high, n_hi, n_lo)),
+               jnp.where(high, _INT_MAX, jnp.where(low, lo, hi)),
+               jnp.where(high, 0.0, jnp.where(low, n_lo, n_hi)))
+        missed = jnp.max((low | high).astype(jnp.int32))
+        return out + (steps + 1 + 2 * _SAMPLE, missed)
+
+    # No bracket where every row keeps all its keys, nor where the block has
+    # too few chunks for a sample to be worth its two checks.
+    lo, n_lo, hi, n_hi, units, missed = jax.lax.cond(
+        ((ib + 1) * R > topk) & (n_ck >= 2 * stride), bracket,
+        lambda: whole + (jnp.int32(0), jnp.int32(0)))
+
+    def unsettled(lo, n_lo, hi):
+        return (n_lo != k_eff) & (hi - lo != 1)
+
+    def halve(st):
+        i, lo, n_lo, hi, n_hi = st
+        cand = middle(lo, hi)
+        n = count(lambda key, _: key >= cand)
+        up = unsettled(lo, n_lo, hi) & (n >= k_eff)
+        down = unsettled(lo, n_lo, hi) & (n < k_eff)
+        return (i + 1, jnp.where(up, cand, lo), jnp.where(up, n, n_lo),
+                jnp.where(down, cand, hi), jnp.where(down, n, n_hi))
+
+    # (a vector reduced to the scalar that steers the loop)
+    steps, tau, n_tau, hi, n_hi = jax.lax.while_loop(
+        lambda st: jnp.max(unsettled(st[1], st[2], st[3]).astype(
+            jnp.int32)) > 0,
+        halve, (jnp.int32(0), lo, n_lo, hi, n_hi))
+    units = units + _SAMPLE * steps
+
+    # Of the keys AT tau the first `ties` by index, where a row leaves some
+    # out (then hi = tau + 1 and n_hi counts the keys above tau): the largest
+    # x below which fewer than `ties` of them lie is the last one's index,
+    # bit by bit. Only in a block where some row leaves a tie out.
+    exact = n_tau == k_eff
+    ties = jnp.where(exact, 0.0, k_eff - n_hi)
+    tie = jnp.max(ties) > 0.0
     nbits = max(1, (S - 1).bit_length())
 
-    def index_bit(i, x):
-        cand = x | jnp.left_shift(jnp.int32(1), nbits - 1 - i)
-        n = count(lambda key, c0: (key == tau)
-                  & (c0 + _iota((R, ck), 1) < cand))
-        return jnp.where(n <= ties - 1.0, cand, x)
+    def halve_index():
+        def index_bit(i, x):
+            cand = x | jnp.left_shift(jnp.int32(1), nbits - 1 - i)
+            n = count(lambda key, c0: (key == tau)
+                      & (c0 + _iota((R, ck), 1) < cand))
+            return jnp.where(n <= ties - 1.0, cand, x)
+        return jax.lax.fori_loop(0, nbits, index_bit,
+                                 jnp.zeros((R, 1), jnp.int32))
 
-    last = jax.lax.fori_loop(0, nbits, index_bit,
-                             jnp.zeros((R, 1), jnp.int32))
+    last = jnp.where(exact, S, jax.lax.cond(
+        tie, halve_index, lambda: jnp.zeros((R, 1), jnp.int32)))
+    units = units + _SAMPLE * jnp.where(tie, nbits, 0)
 
     word = jnp.zeros((R, planes), jnp.int32)
     cnt = jnp.zeros((R, 1), _F32)
@@ -197,17 +298,27 @@ def _select_kernel(qi_ref, ki_ref, w_ref, bits_ref, lse_ref, cnt_ref,
     bits_ref[...] = word
     lse_ref[...] = (m + jnp.log(l)).T
     cnt_ref[...] = cnt.T
+    passes_ref[...] = jnp.full((1, R), units, jnp.int32)
+    way_ref[...] = jnp.full((1, R), jnp.where(
+        missed > 0, 2, jnp.where(tie, 1, 0)), jnp.int32)
 
 
 def select(qi, ki_t, w, topk: int):
     """qi [B,HI,S,dI], ki_t [B,dI,S], w [B,HI,1,S] float32 (the scale
-    folded in) -> bits [B,S,S/32] int32, lse_i [B,1,1,S], count [B,1,1,S]."""
+    folded in) -> bits [B,S,S/32] int32, lse_i [B,1,1,S], count [B,1,1,S];
+    and, a block of `plan(S).rows` query rows, passes [B,S/rows] int32 (the
+    counting passes it ran in units of one `_SAMPLE`-th of a pass: a pass
+    over the block's columns is `_SAMPLE`, one over the sample 1) and way
+    [B,S/rows] int32 (0 the sample's bracket held and no tie was left out, 1
+    the tie search ran, 2 the bracket missed)."""
     B, HI, S, dI = qi.shape
     pn = plan(S)
     R, ck = pn.rows, pn.chunk
     stat = jax.ShapeDtypeStruct((B, 1, 1, S), _F32)
+    block = jax.ShapeDtypeStruct((B, 1, 1, S), jnp.int32)
+    at = lambda b, i: (b, 0, 0, i)
     with jax.named_scope("dsa.index"):
-        return pl.pallas_call(
+        bits, lse_i, count, passes, way = pl.pallas_call(
             functools.partial(_select_kernel, S=S, topk=topk, R=R, ck=ck,
                               planes=pn.planes, heads=HI),
             grid=(B, S // R),
@@ -218,16 +329,17 @@ def select(qi, ki_t, w, topk: int):
             ],
             out_specs=[
                 pl.BlockSpec((None, R, pn.planes), lambda b, i: (b, i, 0)),
-                _stat_spec(R, lambda b, i: (b, 0, 0, i)),
-                _stat_spec(R, lambda b, i: (b, 0, 0, i)),
+                _stat_spec(R, at), _stat_spec(R, at), _stat_spec(R, at),
+                _stat_spec(R, at),
             ],
             out_shape=[jax.ShapeDtypeStruct((B, S, pn.planes), jnp.int32),
-                       stat, stat],
+                       stat, stat, block, block],
             scratch_shapes=[pltpu.VMEM((R, S), jnp.int32),
                             pltpu.VMEM((HI, R, 1), _F32)],
             compiler_params=_params("parallel", "arbitrary"),
             name="dsa_select", interpret=_interpret(),
         )(qi, ki_t, w)
+        return bits, lse_i, count, passes[:, 0, 0, ::R], way[:, 0, 0, ::R]
 
 
 # ------------------------------------------------------------- attention
@@ -571,8 +683,8 @@ def sparse_attention(q, k, v, qi, ki, w, *, topk: int, scale: float):
     softmax over the kept set of I), differentiable in qi, ki and w ONLY
     and with every row's cotangent the same (a mean over rows: the keys'
     gradient is summed over rows before it is weighed); count [B,S] float32
-    of kept keys; bits [B,S,S/32] int32, the selection). o is differentiable
-    in q, k and v."""
+    of kept keys; bits [B,S,S/32] int32, the selection; `select`'s passes and
+    way, a block of query rows). o is differentiable in q, k and v."""
     B, S, H, D = q.shape
     if not _interpret() and plan(S).bk % 128:
         raise ValueError(
@@ -583,14 +695,15 @@ def sparse_attention(q, k, v, qi, ki, w, *, topk: int, scale: float):
     qi_t = jnp.swapaxes(qi, 1, 2)                       # [B,HI,S,dI]
     ki_t = jnp.swapaxes(ki, 1, 2)                       # [B,dI,S]
     w_t = jnp.swapaxes(w.astype(_F32), 1, 2)[:, :, None]  # [B,HI,1,S]
-    bits, lse_i, count = _whole_call(select(sg(qi_t), sg(ki_t), sg(w_t),
-                                            topk))
+    bits, lse_i, count, passes, way = _whole_call(select(
+        sg(qi_t), sg(ki_t), sg(w_t), topk))
     bits = checkpoint_name(bits, "dsa_bits")
     lse_i = checkpoint_name(lse_i, "dsa_lse_i")
     o, lse = _attend(qt, kt, vt, bits, scale)
     kl = _index_loss(qi_t, ki_t, w_t, sg(qt), sg(kt), sg(lse), bits, lse_i,
                      scale)
-    return jnp.swapaxes(o, 1, 2), kl[:, 0, 0], count[:, 0, 0], bits
+    return (jnp.swapaxes(o, 1, 2), kl[:, 0, 0], count[:, 0, 0], bits, passes,
+            way)
 
 
 def mask_of(bits: jax.Array) -> jax.Array:

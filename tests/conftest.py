@@ -20,10 +20,10 @@ import pytest  # noqa: E402
 # orders them. A PR that moves a file across that line moves its name here.
 LONGEST_FILES = (
     "test_kda_kernel_compile", "test_kimi_linear_reference", "test_laguna",
-    "test_preset_programs", "test_qwen3_next", "test_kda", "test_moe",
-    "test_models", "test_expert_shares", "test_model_table",
+    "test_preset_programs", "test_dsa", "test_qwen3_next", "test_kda",
+    "test_moe", "test_models", "test_expert_shares", "test_model_table",
     "test_flash_backward", "test_kimi_linear", "test_tensor_overlap",
-    "test_kda_scalar", "test_dsa", "test_granite_hybrid", "test_ssd_kernels",
+    "test_kda_scalar", "test_granite_hybrid", "test_ssd_kernels",
     "test_kda_remat", "test_flash_attention", "test_flash_attention_shapes",
     "test_rollout", "test_serve", "test_llm_engine", "test_generate",
     "test_ouro", "test_tensor_overlap_rows", "test_moe_held_loop",

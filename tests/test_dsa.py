@@ -92,7 +92,8 @@ def test_the_selection_is_the_exact_top_k_ties_to_the_lower_index(topk):
     qi = jnp.round(jax.random.normal(ks[0], (B, S, HI, dI)))
     ki = jnp.round(jax.random.normal(ks[1], (B, S, dI)))
     w = jnp.round(jax.random.normal(ks[2], (B, S, HI)) * 2) / 8
-    bits, lse, count = jax.jit(lambda a, b, c: sa.select(a, b, c, topk))(
+    bits, lse, count, passes, way = jax.jit(
+        lambda a, b, c: sa.select(a, b, c, topk))(
         jnp.swapaxes(qi, 1, 2), jnp.swapaxes(ki, 1, 2),
         jnp.swapaxes(w, 1, 2)[:, :, None])
     got = np.asarray(sa.mask_of(bits))
@@ -113,6 +114,90 @@ def test_the_selection_is_the_exact_top_k_ties_to_the_lower_index(topk):
     assert tied > S // 2 or topk == 64  # the tie rule was exercised
     assert (np.asarray(count)[:, 0, 0]
             == np.minimum(np.arange(S) + 1, topk)).all()
+    # one block of rows a sequence: the tie search ran (its six passes after
+    # the value's at most 32), or every row kept all its keys and none did
+    nbits, passes = (S - 1).bit_length(), np.asarray(passes) / sa._SAMPLE
+    assert way.tolist() == [[1 if topk < S else 0]] * B
+    assert ((passes > nbits) & (passes <= 32 + nbits)).all() or topk == S
+    assert (passes == 0).all() or topk < S
+
+
+def _scores_by_hand(kind, S, rng):
+    """w [S], q [S], k [S] of a one-head indexer one wide, whose score
+    I[t, s] = w[t] relu(q[t] k[s]) numpy forms bit for bit (one product, one
+    rounding each), and the `topk` of the case."""
+    one = np.ones(S, np.float32)
+    if kind in ("continuous", "all_kept"):
+        # distinct positive scores, every column like every other
+        return (one, rng.uniform(0.5, 1.5, S).astype(np.float32),
+                rng.uniform(1.0, 2.0, S).astype(np.float32),
+                256 if kind == "continuous" else S)
+    if kind == "misled":
+        # columns 512..1023 hold every late row's largest scores, and the
+        # sample (128 lanes of every second chunk of 512) holds none of them
+        k = rng.uniform(1.0, 2.0, S).astype(np.float32)
+        k[512:1024] += 8.0
+        return one, rng.uniform(0.5, 1.5, S).astype(np.float32), k, 256
+    if kind == "met_twice":  # every key a second time, next to the first
+        k = np.repeat(rng.uniform(1.0, 2.0, S // 2).astype(np.float32), 2)
+        return one, rng.uniform(0.5, 1.5, S).astype(np.float32), k, 25
+    if kind == "equal":  # a row's scores all the same
+        return rng.uniform(0.5, 1.5, S).astype(np.float32), one, one, 24
+    if kind == "negative":  # every score below zero, all distinct
+        return (-one, rng.uniform(0.5, 1.5, S).astype(np.float32),
+                rng.uniform(1.0, 2.0, S).astype(np.float32), 24)
+    assert kind == "zeros"  # +0.0 and -0.0 among positive and negative
+    return (np.where(rng.random(S) < 0.5, -one, one),
+            rng.uniform(0.5, 1.5, S).astype(np.float32),
+            rng.normal(size=S).astype(np.float32), 24)
+
+
+@pytest.mark.parametrize("kind,S_,ways", [
+    ("continuous", 2048, {0}), ("misled", 2048, {0, 2}), ("equal", 512, {1}),
+    ("met_twice", 512, {1}),
+    ("negative", 512, {0}), ("zeros", 512, {1}), ("all_kept", 512, {0})])
+def test_the_search_goes_each_way_and_keeps_the_exact_top_k(kind, S_, ways):
+    """`select`'s adaptive search on scores made by hand: the bits are the
+    first `topk` of a stable descending sort whichever way a block of rows
+    went (0: the sample's bracket held, or none was taken, and no tie was
+    left out; 1: the tie search ran; 2: a count over the whole block refused
+    the sample's bracket), and the passes say so."""
+    w, q, k, topk = _scores_by_hand(kind, S_, np.random.default_rng(55))
+    bits, lse, count, passes, way = jax.jit(
+        lambda a, b, c: sa.select(a, b, c, topk))(
+        jnp.asarray(q)[None, None, :, None], jnp.asarray(k)[None, None],
+        jnp.asarray(w)[None, None, None])
+    score = w[:, None] * np.maximum(q[:, None] * k[None, :], np.float32(0))
+    score = np.where(np.tri(S_, dtype=bool), score, -np.inf)
+    order = np.argsort(-score, axis=1, kind="stable")
+    rank = np.empty_like(order)
+    np.put_along_axis(rank, order, np.arange(S_)[None, :], axis=1)
+    want = rank < np.minimum(np.arange(S_) + 1, topk)[:, None]
+    np.testing.assert_array_equal(np.asarray(sa.mask_of(bits))[0], want)
+    assert (np.asarray(count)[0, 0, 0] == want.sum(1)).all()
+    np.testing.assert_allclose(lse[0, 0, 0], np.log(np.where(
+        want, np.exp(score), 0).sum(1)), rtol=1e-5)
+    R = sa.plan(S_).rows
+    way, passes = np.asarray(way)[0], np.asarray(passes)[0] / sa._SAMPLE
+    nbits = (S_ - 1).bit_length()
+    assert set(way.tolist()) == ways, way
+    # a block whose rows keep every key counts nothing; no block runs more
+    # than the value's 32 passes, the bracket's and, on way 1, the index's
+    first = np.arange(S_ // R) * R
+    assert (passes[first + R <= topk] == 0).all()
+    assert (passes <= 32 + 4 + nbits * (way == 1)).all(), passes
+    if kind == "continuous":
+        # every block stops at an exact count, under the parent's 33 + nbits;
+        # the last four (four chunks: a sample is taken) from its bracket
+        assert (passes < 33).all(), passes
+        assert (passes[first >= 1536] % 1 != 0).all(), passes
+    if kind == "misled":
+        # rows from 1,536 on have a sample that holds none of their 256
+        # largest: the bracket's upper end fails its count
+        assert (way[first >= 1536] == 2).all() and (
+            way[first < 1536] == 0).all(), way
+    if kind in ("equal", "met_twice"):  # a tie left out: the index's passes
+        assert (passes[first >= topk] > nbits).all()
 
 
 def test_the_program_is_the_reference(case):
@@ -134,6 +219,33 @@ def test_the_program_is_the_reference(case):
     for leaf, want in g_r.items():
         assert float(jnp.linalg.norm(want)) > 0, leaf
         assert _rel(got[leaf], want) < 3e-5, leaf
+
+
+def test_the_searchs_counters_reach_the_phase_table(case):
+    """`dsa_select_passes` (the counting passes, in eighths, summed over
+    layers and blocks of rows) and `dsa_select_fallback` (the blocks off way
+    0, summed over layers) are whole-number counters of a "dsa" stack, and
+    `observe_counters` folds their VALUES into the phase table."""
+    from ray_tpu.train.step import ShardedTrainStep
+    from ray_tpu.util import tracing
+
+    c = {n: a for n, a in case["counters"].items() if n != "dsa_selection"}
+    blocks = 2 * case["batch"]["tokens"].shape[0]  # two layers' one block each
+    # the preset's 64 rows are one block with no sample: a whole number of
+    # passes, at most the value's 32 and the index's six
+    passes = int(c["dsa_select_passes"])
+    assert passes % sa._SAMPLE == 0
+    assert 0 < passes <= 38 * sa._SAMPLE * blocks
+    assert 0 <= int(c["dsa_select_fallback"]) <= blocks
+    names = ("dsa_select_passes", "dsa_select_fallback")
+    row = lambda n: tracing.phase_table().get(
+        "train." + n, {"count": 0, "total_ns": 0})
+    before = {n: row(n) for n in names}
+    seen = ShardedTrainStep.observe_counters(c)
+    for n in names:
+        assert seen[n] == int(c[n])
+        assert row(n)["count"] == before[n]["count"] + 1
+        assert row(n)["total_ns"] == before[n]["total_ns"] + int(c[n])
 
 
 def test_a_wrong_selection_is_seen(case):
@@ -242,12 +354,12 @@ def test_remat_keeps_the_selection_and_re_runs_no_kernel(case, policy):
     assert abs(float(loss) - float(want_loss)) < 1e-6
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, atol=1e-6)
-    # each kernel once a layer (select 3 in / 3 out, forward 4 / 2, backward
+    # each kernel once a layer (select 3 in / 5 out, forward 4 / 2, backward
     # 7 / 3, the indexer's loss 8 / 4): the backward's recomputation re-runs
     # none
     calls = _kernel_calls(jax.make_jaxpr(jax.grad(lambda p: tfm.loss_fn(
         p, case["batch"], remat, shift_inputs=True)))(case["params"]).jaxpr)
-    assert calls == {"3in_3out": 2, "4in_2out": 2, "7in_3out": 2,
+    assert calls == {"3in_5out": 2, "4in_2out": 2, "7in_3out": 2,
                      "8in_4out": 2}, calls
 
 

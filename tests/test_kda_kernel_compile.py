@@ -1,4 +1,4 @@
-"""The KDA, SSD and flash kernels compiled for a v5e at the cells' real shapes, with no chip:
+"""The KDA, SSD, flash and sparse-selection kernels compiled for a v5e at the cells' real shapes, with no chip:
 the TPU compiler is installed here and compiles for a described device.
 Interpret mode (tests/test_kda.py, tests/test_ssd.py) cannot see what Mosaic refuses
 (unaligned slices, VMEM over the limit, an op with no lowering). Nothing
@@ -226,6 +226,26 @@ def test_flash_pair_compiles_past_the_budget_for_v5e(
         sd((B, S, H, D)), sd((B, S, H, D)), sd((B, S, H, Dv))).compile(
         ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_sparse_selection_compiles_for_v5e(one_chip,
+                                               compiled_not_interpreted,
+                                               monkeypatch):
+    """`dsa_select` at the Keye-VL-2.0 cell's shape (1 x 32,768 positions,
+    an indexer of 16 heads of 64, the top 2,048): a block of 128 rows' scores
+    in 16 MiB of VMEM, and a search whose loops a vector reduced to a scalar
+    steers (`lax.while_loop` and `lax.cond` inside the Mosaic kernel), which
+    interpret mode cannot refuse. One Mosaic call, five outputs."""
+    from ray_tpu.ops import sparse_attention as sa
+
+    monkeypatch.setattr(sa, "_interpret", lambda: False)
+    B, S, HI, dI = 1, 32768, 16, 64
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    text = jax.jit(lambda a, b, c: sa.select(a, b, c, 2048)).lower(
+        sd((B, HI, S, dI), jnp.bfloat16), sd((B, dI, S), jnp.bfloat16),
+        sd((B, HI, 1, S), jnp.float32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"s32[{B},{S},{S // 32}]" in text
 
 
 @pytest.mark.parametrize("T,E,first,Eh,F,kind,d,k", [

@@ -87,10 +87,12 @@ PARENT = {
     "ouro_tiny": ("3282a46b53f1b59b:b9265fa8a1143fdc",
                   "2f196b66ea41495d:b9265fa8a1143fdc",
                   "cc6e52c1bdf75295:b9265fa8a1143fdc"),
-    # PR 54's own (the "dsa" kind's kernels interpreted)
-    "keye_vl2_tiny": ("fd77242856c36a7e:eff64fa6672f2d28",
-                      "af6d1355fbb3bb56:eff64fa6672f2d28",
-                      "28e26817ef30b114:eff64fa6672f2d28"),
+    # PR 54's own (the "dsa" kind's kernels interpreted); re-recorded in PR
+    # 55 (its own tree's: the selection's adaptive search in the text, its
+    # two counters under `dsa`; every other preset's program unmoved)
+    "keye_vl2_tiny": ("9f2bc330858adc68:d02a27497dfe0501",
+                      "7c1ef0dd5424e566:d02a27497dfe0501",
+                      "f9028fe15fc1874e:d02a27497dfe0501"),
     # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text;
     # re-recorded in PR 47 (its own tree's: one backward kernel where the
     # parent's text held dQ's and dK/dV's; the operations' scopes unmoved)
