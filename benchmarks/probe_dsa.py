@@ -12,11 +12,19 @@ alone: attention over kept keys brought by index through XLA's gather, on
 cell's own comparison, chipbench/drivers/train_stack_sparse.py, at the cell's
 size; tests/test_dsa.py at a small one.) Refuses to run off the chip.
 
-    chiprun -- python3 benchmarks/probe_dsa.py [S] [gathered | select]
+    chiprun -- python3 benchmarks/probe_dsa.py [S] [gathered | select | core]
+
+`core` as a second argument: the selection once (for its bits), then the
+core's two kernels alone at `plan`'s tiles, at 512 x 512 (four times the grid
+steps over the same triangle) and at 2,048 x 1,024 (not square: the
+rectangular grid), each beside a head's grid steps and the idle ones among
+them, and the first results' sha256: PR 57 ran it in its parent's tree and
+in its own for the price of an idle grid step and of a working one.
 """
 from __future__ import annotations
 
 import functools
+import hashlib
 import json
 import os
 import sys
@@ -26,9 +34,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops import sparse_attention as sa
 
 
@@ -228,6 +238,28 @@ def main():
     ms, (bits, lse_i, cnt, passes, way) = timed(jax.jit(
         lambda a, b, c: sa.select(a, b, c, topk)), qi_t, ki_t, w_t)
     out["select_ms"] = ms
+    if sys.argv[2:] == ["core"]:
+        pn = sa.plan(S)
+        out["core"] = {}
+        for tiles in ((pn.bq, pn.bk), (512, 512), (2048, 1024),
+                      (pn.bq, pn.bk)):
+            f, (o, lse) = timed(jax.jit(lambda *a, t=tiles: sa._attend_fwd(
+                *a, scale, t)), qt, kt, vt, bits, n=10)
+            b, grads = timed(jax.jit(lambda *a, t=tiles: sa._attend_bwd(
+                *a, scale, t)), qt, kt, vt, bits, o, lse, cot, n=10)
+            row = {"fwd_ms": f, "bwd_ms": b,
+                   "grid_steps": fa.grid_steps(S, *tiles, True)}
+            if not out["core"]:  # the results' bits, to hold against a tree's
+                row["sha256"] = {name: hashlib.sha256(np.asarray(
+                    x.astype(jnp.float32)).tobytes()).hexdigest()[:16]
+                    for name, x in zip(("o", "lse", "dq", "dk", "dv"),
+                                       (o, lse) + tuple(grads))}
+            out["core"].setdefault("%dx%d" % tiles, []).append(row)
+        print(json.dumps(out))
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/probe_dsa_core.json", "w") as f:
+            json.dump(out, f)
+        return
     # the search: counting passes a block of rows (the parent ran 33 + the
     # index's bits in every block), and the blocks by the way they went
     out["select_passes"] = float(passes.mean()) / sa._SAMPLE

@@ -1434,14 +1434,17 @@ def _dsa_mixer(cfg, kind, h, layer, positions, overlap):
     residual; `loss_fn(with_selection=True)` returns it, any other program
     drops it)."""
     from ray_tpu.ops import sparse_attention as sa
+    from ray_tpu.ops.flash_attention import grid_steps
 
     _one_chip("a learned-sparse-attention (dsa) layer's kernels")
     B, S, _ = h.shape
     HI, dI = cfg.dsa_index_heads, cfg.dsa_index_head_dim
     pn = sa.plan(S)
+    steps, idle = grid_steps(S, pn.bq, pn.bk, True)
     tracing.observe(
         "dsa.plan", 0, slow=False, seq=S, topk=cfg.dsa_topk, impl="threshold",
-        index_heads=HI, index_dim=dI, **pn._asdict())
+        index_heads=HI, index_dim=dI, grid_steps=steps, grid_steps_idle=idle,
+        **pn._asdict())
     q, k, v = _qkv_proj(cfg, h, layer, positions, "dsa")
     q = maybe_constrain(q, ("batch", "seq_act", "heads", None))
     with jax.named_scope("dsa.index"):

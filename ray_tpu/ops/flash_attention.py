@@ -15,7 +15,19 @@ statistics (`lse`, `delta`) are [B, H, S] float32 and cross the kernels as
 Grid convention: the innermost grid dimension is the contraction over KV (or
 Q, in the backward kernel) blocks; TPU grids execute sequentially so VMEM
 scratch accumulators carry across it ("arbitrary" dimension semantics), and
-outputs are flushed on the last inner step.
+outputs are flushed on the last step of a row (a q block's key blocks, or a
+key block's q blocks: `_grid_place` gives the step within the row and the
+row's steps). A rectangular grid has a row an outer index and every block of
+the other side, or those a window's band reaches, inside it. A full causal
+call whose blocks are square and divide the sequence into an even number n of
+them takes the FOLDED grid (`_folded`, `_fold`; from the static shapes, no
+setting): row p of the triangle and row n - 1 - p are one line of n + 1
+steps, n / 2 lines, every step a tile at or below the diagonal, where the
+rectangle entered n (n - 1) / 2 of its n x n steps to do nothing. The output
+block's index then moves once inside the inner dimension and is never
+revisited; within a row the blocks still arrive in ascending order, so o,
+lse, dK and dV are the rectangle's bit for bit (the fused backward's dQ sums
+a row's key blocks in the order the grid brings them: float32 rounding).
 
 The backward is one kernel (`_dkv_kernel` with dQ's output and accumulator;
 `bwd_kind` says "fused"): for a (batch, head) it walks every key block and
@@ -28,8 +40,10 @@ written out once at its last. A head whose accumulator and output block pass
 beside `_dkv_kernel` without dQ: "split"; S and dP are then computed twice).
 
 A tile's work, in all the kernels alike (`_tile_class`, `tile_plan`): a grid
-tile wholly above the diagonal is *skipped* (no compute, and its index maps
-stay on the last needed block, so nothing is fetched for it); one wholly
+tile wholly above the diagonal is *skipped* (the folded grid has no step for
+it; the rectangle enters the step, computes nothing, and its index maps stay
+on the last needed block, so nothing is fetched: `grid_steps` counts both,
+and `flash.plan` says them); one wholly
 below it and inside the sequence is *interior* and runs with no mask at all;
 only an *edge* tile (cut by the diagonal or by a ragged last block) builds
 one. Inside a grid step the accumulating side goes in strips of `sub`
@@ -136,6 +150,25 @@ DEFAULT_BLOCK = 512
 # tiles are the pair's: no row of its own. (Heads of 256 at strip 256 are 0.3
 # ms ahead in the backward and 0.8 behind in the forward; head 64 at strip 128
 # is 0.25 ms a layer ahead and costs set-up, as above.)
+# The same shapes on the folded grid (`_fold`; `probe_flash.py fused` on a
+# v5e, chip run PR 57, `p57a`: the parent's tree and this one in one call,
+# twice each; ms a call forward / fused backward, the rectangle -> folded):
+#   [1,16384,32,192|128]  1024/256 26.45 / 51.58 -> 25.07-25.12 / 50.61-50.62
+#       (dQ + dK/dV 34.44 + 38.53 -> 32.45 + 37.79-37.84)
+#       1024/128 31.25 / 52.46 -> 29.85-29.92 / 51.46-51.53
+#       1024/512 24.65 / 52.06 -> 23.28-23.47 / 51.10-51.43
+#       512/256 36.15 / 58.52 -> 32.94-33.17 / 54.67-54.89
+#       2048/256 23.73 / 94.95 -> 22.89-22.90 / 68.93-68.94
+#   [1,16384,16|2,256]    1024/512 14.67-14.69 / 30.88-30.91 -> 13.81-13.84 /
+#       30.37-30.39 | 1024/256 15.51-15.56 / 30.60-30.63 -> 14.62-14.64 /
+#       30.05-30.08 | 512/256 20.27-20.29 / 34.24 -> 18.39-18.42 / 32.21
+#       512/512 18.53-18.56 / 34.58-34.60 -> 16.66-16.69 / 32.57
+#   [1,8192,24|4,128]     2048/256 3.37-3.38 / 5.93-5.97 -> 3.24-3.25 /
+#       5.87-5.89 | 1024/256 3.95-3.99 / 6.19-6.23 -> 3.70-3.79 / 6.07-6.08
+# A step entered to do nothing costs 0.25-0.45 us (3,840 of them a call at
+# 32 heads of n = 16: 1.35 ms forward, 0.97 backward); every row gains and
+# the rows keep their order (2,048 at heads of 192 still loses the backward
+# to VMEM, by less): no tile changes.
 # Differential attention's maps (keys of 64, values of 128: a pair of heads'
 # values side by side; `models/transformer.py` `_diff_attend`). Sweep at
 # [1,16384,20|10,64|128] on a v5e (my chip run, PR 56; ms a call, forward and
@@ -457,12 +490,68 @@ def _zero_padded(block0, n, seq_len, *arrays):
 # ---------------------------------------------------------------- forward
 
 
-def _grid_place(bq, bk, seq_len, window, keys_inner):
-    """(iq, ik, inner step, inner steps) of this grid step: the inner
-    dimension runs over every block of the other side, or with a window
-    over those the band reaches, from its first one."""
+def _folded(causal, bq, bk, seq_len, window=None) -> bool:
+    """Whether a call's grid is the folded one (`_fold`): full causal, the
+    blocks square and dividing the sequence into an even number n >= 2.
+    From the static shapes alone; anything else takes the rectangle."""
+    n = seq_len // bq
+    return bool(causal and window is None and bq == bk
+                and seq_len % bq == 0 and n >= 2 and n % 2 == 0)
+
+
+def _fold(p, j, n, keys_inner, where=jnp.where):
+    """Step (p, j) of the folded grid n / 2 x (n + 1) -> (iq, ik, the step
+    within its row, the row's steps). The causal triangle of n x n blocks
+    has i + 1 tiles in row i: row p and row n - 1 - p together have n + 1,
+    so the pair is one line of the grid and every step is a tile at or
+    below the diagonal. Keys inner (a row is a q block, its key blocks
+    ascending): j <= p is (p, j), the rest (n - 1 - p, j - p - 1). Queries
+    inner (a row is a key block, the q blocks that see it ascending):
+    j < n - p is key block p at q block p + j, the rest key block n - 1 - p
+    at q block j - 1. On traced ids, or on ints with a `where` of ints."""
+    if keys_inner:
+        low = j <= p
+        iq, ik = where(low, p, n - 1 - p), where(low, j, j - p - 1)
+        return iq, ik, ik, iq + 1
+    low = j < n - p
+    iq, ik = where(low, p + j, j - 1), where(low, p, n - 1 - p)
+    return iq, ik, iq - ik, n - ik
+
+
+def _grid_dims(causal, bq, bk, seq_len, window, keys_inner):
+    """(outer, inner) grid dimensions of one (batch, head): every block of
+    the one side by every block of the other; with a window the inner one
+    is the blocks a band reaches (`_band_steps`); folded, n / 2 x (n + 1)."""
+    nq, nk = pl.cdiv(seq_len, bq), pl.cdiv(seq_len, bk)
+    if _folded(causal, bq, bk, seq_len, window):
+        return nq // 2, nq + 1
+    if window is not None:
+        k_steps, q_steps = _band_steps(seq_len, bq, bk, window)
+        return (nq, k_steps) if keys_inner else (nk, q_steps)
+    return (nq, nk) if keys_inner else (nk, nq)
+
+
+def grid_steps(seq_len, bq, bk, causal, window=None):
+    """(grid steps of one (batch, head) of the forward call, those of them
+    that are entered to do nothing: a tile above the diagonal or below the
+    band, which a rectangle's inner dimension still runs over)."""
+    outer, inner = _grid_dims(causal, bq, bk, seq_len, window, True)
+    work = sum(not _tile_class(i, j, bq=bq, bk=bk, seq_len=seq_len,
+                               causal=causal, ragged="k", window=window)[0]
+               for i in range(pl.cdiv(seq_len, bq))
+               for j in range(pl.cdiv(seq_len, bk)))
+    return outer * inner, outer * inner - work
+
+
+def _grid_place(causal, bq, bk, seq_len, window, keys_inner):
+    """(iq, ik, step within the row, the row's steps) of this grid step: the
+    inner dimension runs over every block of the other side, or with a
+    window over those the band reaches, from its first one; on the folded
+    grid (`_folded`) a row ends where its tiles do."""
     outer, inner = pl.program_id(2), pl.program_id(3)
     nq, nk = pl.cdiv(seq_len, bq), pl.cdiv(seq_len, bk)
+    if _folded(causal, bq, bk, seq_len, window):
+        return _fold(outer, inner, nq, keys_inner)
     if window is None:
         n = nk if keys_inner else nq
         pair = (outer, inner) if keys_inner else (inner, outer)
@@ -477,7 +566,8 @@ def _grid_place(bq, bk, seq_len, window, keys_inner):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale, causal, bq, bk, seq_len, sub, window):
-    iq, ik, step_i, steps = _grid_place(bq, bk, seq_len, window, True)
+    iq, ik, step_i, steps = _grid_place(causal, bq, bk, seq_len, window,
+                                            True)
     fold = _folds(scale)
     when_interior, when_edge = _tile_bodies(
         iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k",
@@ -601,6 +691,21 @@ def _q_block(causal, bq, bk, window=None, nq=None):
     return block
 
 
+def _block_at(causal, bq, bk, seq_len, window, keys_inner):
+    """Two functions of (outer, inner), the q block and the key block the
+    index maps of a call's grid fetch and write at a grid step. The
+    rectangle's outer id is its own side's block and the inner one is held
+    on the needed blocks (`_kv_block`, `_q_block`); the folded grid skips
+    nothing (`_fold`)."""
+    nq, nk = pl.cdiv(seq_len, bq), pl.cdiv(seq_len, bk)
+    if _folded(causal, bq, bk, seq_len, window):
+        return (lambda p, j: _fold(p, j, nq, keys_inner)[0],
+                lambda p, j: _fold(p, j, nq, keys_inner)[1])
+    if keys_inner:
+        return lambda i, j: i, _kv_block(causal, bq, bk, window, nk)
+    return _q_block(causal, bq, bk, window, nq), lambda j, i: j
+
+
 def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None,
                window=None):
     """q: [B,H,S,D], k: [B,KVH,S,D], v: [B,KVH,S,Dv] -> (o [B,H,S,Dv],
@@ -614,28 +719,28 @@ def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None,
     group = H // KVH
     bq, bk, sub = tile_sizes(S, D, Dv, q.dtype, block_q, block_k, sub)
     nq = pl.cdiv(S, bq)
-    nk = pl.cdiv(S, bk)
+    steps, idle = grid_steps(S, bq, bk, causal, window)
     tracing.observe("flash.plan", 0, slow=False, window=window or 0,
                     bwd=bwd_kind(S, D, Dv, q.dtype, block_q, block_k, sub),
+                    grid_steps=steps, grid_steps_idle=idle,
                     **tile_plan(S, bq, bk, sub, causal, window)._asdict())
-    kv = _kv_block(causal, bq, bk, window, nk)
+    iq, ik = _block_at(causal, bq, bk, S, window, True)
+    q_at = lambda b, h, i, j: (b, h, iq(i, j), 0)
+    kv_at = lambda b, h, i, j, g=group: (b, h // g, ik(i, j), 0)
     params = _compiler_params(bq, bk, sub, D, Dv, q.dtype.itemsize)
-    steps = nk if window is None else _band_steps(S, bq, bk, window)[0]
 
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
                           bk=bk, seq_len=S, sub=sub, window=window),
-        grid=(B, H, nq, steps),
+        grid=(B, H) + _grid_dims(causal, bq, bk, S, window, True),
         in_specs=[
-            pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((None, None, bk, D),
-                         lambda b, h, i, j, g=group: (b, h // g, kv(i, j), 0)),
-            pl.BlockSpec((None, None, bk, Dv),
-                         lambda b, h, i, j, g=group: (b, h // g, kv(i, j), 0)),
+            pl.BlockSpec((None, None, bq, D), q_at),
+            pl.BlockSpec((None, None, bk, D), kv_at),
+            pl.BlockSpec((None, None, bk, Dv), kv_at),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
-            _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
+            pl.BlockSpec((None, None, bq, Dv), q_at),
+            _stat_spec(bq, lambda b, h, i, j: (b, h, 0, iq(i, j))),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
@@ -658,7 +763,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_scr, *, scale, causal, bq, bk, seq_len, sub, window):
-    iq, ik, step_i, steps = _grid_place(bq, bk, seq_len, window, True)
+    iq, ik, step_i, steps = _grid_place(causal, bq, bk, seq_len, window,
+                                            True)
     fold = _folds(scale)
     when_interior, when_edge = _tile_bodies(
         iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal, ragged="k",
@@ -738,7 +844,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     scaled and cast, at its last."""
     fused = len(rest) == 4
     dq_ref, dk_scr, dv_scr, dq_scr = rest if fused else (None, *rest, None)
-    iq, ik, step_i, steps = _grid_place(bq, bk, seq_len, window, False)
+    iq, ik, step_i, steps = _grid_place(causal, bq, bk, seq_len, window,
+                                            False)
     fold = _folds(scale)
     when_interior, when_edge = _tile_bodies(
         iq, ik, bq=bq, bk=bk, seq_len=seq_len, causal=causal,
@@ -840,7 +947,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[pl.ds(first, n)] = (dq * scale if fold else dq).astype(
                 dq_ref.dtype)
 
-        @pl.when((step_i == steps - 1) & (ik == nk - 1))
+        # the key block a head's grid ends on: the last one, or on the
+        # folded grid the later of the pair its last line holds
+        end_k = nk // 2 if _folded(causal, bq, bk, seq_len, window) else nk - 1
+
+        @pl.when((step_i == steps - 1) & (ik == end_k))
         def _flush_head():
             @pl.loop(0, seq_len // bq)
             def _(c):
@@ -874,11 +985,8 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     group = H // KVH
     bq, bk, sub = tile_sizes(S, D, Dv, q.dtype, block_q, block_k, sub)
     nq = pl.cdiv(S, bq)
-    nk = pl.cdiv(S, bk)
     tiles = dict(scale=scale, causal=causal, bq=bq, bk=bk, seq_len=S, sub=sub,
                  window=window)
-    k_steps, q_steps = (nk, nq) if window is None else _band_steps(
-        S, bq, bk, window)
     # Whole (1, bq) blocks with zeros behind the sequence: nothing ragged
     # along the lanes, and a padded query's statistics are numbers.
     pad = [(0, 0), (0, 0), (0, 0), (0, nq * bq - S)]
@@ -892,24 +1000,22 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
     args = (q, k, v, do, lse, delta)
 
     if not fused:
-        kv = _kv_block(causal, bq, bk, window, nk)
+        iq, ik = _block_at(causal, bq, bk, S, window, True)
+        q_at = lambda b, h, i, j: (b, h, iq(i, j), 0)
+        kv_at = lambda b, h, i, j, g_=group: (b, h // g_, ik(i, j), 0)
+        stat_at = lambda b, h, i, j: (b, h, 0, iq(i, j))
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, **tiles),
-            grid=(B, H, nq, k_steps),
+            grid=(B, H) + _grid_dims(causal, bq, bk, S, window, True),
             in_specs=[
-                pl.BlockSpec((None, None, bq, D),
-                             lambda b, h, i, j: (b, h, i, 0)),
-                pl.BlockSpec((None, None, bk, D), lambda b, h, i, j, g_=group:
-                             (b, h // g_, kv(i, j), 0)),
-                pl.BlockSpec((None, None, bk, Dv), lambda b, h, i, j, g_=group:
-                             (b, h // g_, kv(i, j), 0)),
-                pl.BlockSpec((None, None, bq, Dv),
-                             lambda b, h, i, j: (b, h, i, 0)),
-                _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
-                _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
+                pl.BlockSpec((None, None, bq, D), q_at),
+                pl.BlockSpec((None, None, bk, D), kv_at),
+                pl.BlockSpec((None, None, bk, Dv), kv_at),
+                pl.BlockSpec((None, None, bq, Dv), q_at),
+                _stat_spec(bq, stat_at),
+                _stat_spec(bq, stat_at),
             ],
-            out_specs=pl.BlockSpec((None, None, bq, D),
-                                   lambda b, h, i, j: (b, h, i, 0)),
+            out_specs=pl.BlockSpec((None, None, bq, D), q_at),
             out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             compiler_params=_compiler_params(bq, bk, sub, D, Dv, itemsize),
@@ -918,29 +1024,29 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
 
     # dk/dv per *query* head, then segment-sum over the GQA group in XLA;
     # the fused call's third result is dq, a whole head a block.
-    qb = _q_block(causal, bq, bk, window, nq)
+    iq, ik = _block_at(causal, bq, bk, S, window, False)
+    q_at = lambda b, h, j, i: (b, h, iq(j, i), 0)
+    kv_at = lambda b, h, j, i, g_=group: (b, h // g_, ik(j, i), 0)
+    stat_at = lambda b, h, j, i: (b, h, 0, iq(j, i))
+    out_at = lambda b, h, j, i: (b, h, ik(j, i), 0)
     dq_spec, dq_shape, dq_scr = ([], [], []) if not fused else (
         [pl.BlockSpec((None, None, S, D), lambda b, h, j, i: (b, h, 0, 0))],
         [jax.ShapeDtypeStruct((B, H, S, D), q.dtype)],
         [pltpu.VMEM((nq * bq, D), jnp.float32)])
     grads = pl.pallas_call(
         functools.partial(_dkv_kernel, **tiles),
-        grid=(B, H, nk, q_steps),
+        grid=(B, H) + _grid_dims(causal, bq, bk, S, window, False),
         in_specs=[
-            pl.BlockSpec((None, None, bq, D),
-                         lambda b, h, j, i: (b, h, qb(j, i), 0)),
-            pl.BlockSpec((None, None, bk, D),
-                         lambda b, h, j, i, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((None, None, bk, Dv),
-                         lambda b, h, j, i, g_=group: (b, h // g_, j, 0)),
-            pl.BlockSpec((None, None, bq, Dv),
-                         lambda b, h, j, i: (b, h, qb(j, i), 0)),
-            _stat_spec(bq, lambda b, h, j, i: (b, h, 0, qb(j, i))),
-            _stat_spec(bq, lambda b, h, j, i: (b, h, 0, qb(j, i))),
+            pl.BlockSpec((None, None, bq, D), q_at),
+            pl.BlockSpec((None, None, bk, D), kv_at),
+            pl.BlockSpec((None, None, bk, Dv), kv_at),
+            pl.BlockSpec((None, None, bq, Dv), q_at),
+            _stat_spec(bq, stat_at),
+            _stat_spec(bq, stat_at),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((None, None, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((None, None, bk, D), out_at),
+            pl.BlockSpec((None, None, bk, Dv), out_at),
         ] + dq_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),

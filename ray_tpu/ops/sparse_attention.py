@@ -33,10 +33,14 @@ reads (chipbench/reduce/scopes.py):
   kernel is a `[bq, bk]` block of words and one shift, no movement along
   the lanes). Also the rows' logsumexp of I over the kept set, the kept
   count, and a block's passes and way (`select`).
-- `_fwd_kernel`, `_bwd_kernel` (`dsa.core`): flash attention, a head a grid
-  step, with the mask of a tile read from the bits; the backward is one kernel
-  (dK, dV and dQ from one S, dP and exponential, a head's dQ held in VMEM
-  across the key blocks, as ops/flash_attention.py's fused one).
+- `_fwd_kernel`, `_bwd_kernel` (`dsa.core`): flash attention, a (head, tile)
+  a grid step, with the mask of a tile read from the bits; the backward is one
+  kernel (dK, dV and dQ from one S, dP and exponential, a head's dQ held in
+  VMEM across the key blocks, as ops/flash_attention.py's fused one). Square
+  tiles, an even number n of them a side (the cell's 32), take that file's
+  folded grid (`_fold`, `_attend_grid`): n / 2 x (n + 1) steps a head, each
+  a tile at or below the diagonal (528 for the rectangle's 1,024); o, lse,
+  dK and dV are the rectangle's bit for bit, dQ to float32 rounding.
 - `_index_loss_kernel` (`dsa.index`): kl and its gradient to qi, ki and w in
   one pass over the tiles (pbar from the saved lse of every head, I again),
   run where the loss is made: the backward rule only scales what it kept.
@@ -60,8 +64,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.flash_attention import (_NEG_INF, _dot_nn, _dot_nt, _dot_tn,
-                                         _interpret, _stat_spec)
+from ray_tpu.ops.flash_attention import (_NEG_INF, _block_at, _dot_nn,
+                                         _dot_nt, _dot_tn, _fold, _folded,
+                                         _grid_dims, _interpret, _stat_spec)
 
 RESIDUAL_NAMES = ("dsa_bits", "dsa_lse_i", "dsa_o", "dsa_lse", "dsa_kl",
                   "dsa_dqi", "dsa_dki", "dsa_dw")
@@ -92,7 +97,14 @@ def plan(S: int) -> Plan:
     # [1,32768,32|4,128] (chip run, PR 54; ms a call forward / backward):
     #   512x512 147.4 / 181.6 | 1024x512 138.6 / 155.6 | 512x1024 86.7 / 150.4
     #   1024x1024 76.3 / 137.9 | 2048x512 115.4 / 152.2 | 2048x1024 76.6 / 137.3
-    # (a key tile cannot pass a plane: 1,024 keys at 32,768).
+    # (a key tile cannot pass a plane: 1,024 keys at 32,768). The same on
+    # the folded grid (chip run, PR 57, `p57a`: the parent's tree and this
+    # one in one call, twice each; the rectangle -> folded):
+    #   1024x1024 76.4-77.1 / 137.8-138.6 -> 70.2-70.8 / 133.6-133.7
+    #   512x512 147.6-153.3 / 181.6-182.2 -> 132.1-132.4 / 162.9-163.4
+    #   2048x1024 (not square: the rectangle in both trees) 76.5-76.9 /
+    #   137.3-137.5 -> 76.9-77.0 / 137.7-138.0
+    # A step entered to do nothing costs 0.29-0.39 us (15,872 of them a call).
     return Plan(planes, math.gcd(S, 1024), math.gcd(planes, 1024),
                 math.gcd(S, 128), math.gcd(S, 512))
 
@@ -345,9 +357,17 @@ def select(qi, ki_t, w, topk: int):
 # ------------------------------------------------------------- attention
 
 
+def _run(body):
+    """The decorator of a body every grid step enters (the folded grid's
+    tile, where the rectangle's is under a `pl.when`)."""
+    body()
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, m_scr, l_scr,
-                acc_scr, *, scale, bq, bk, per_plane):
+                acc_scr, *, scale, bq, bk, per_plane, n):
     iq, ik = pl.program_id(2), pl.program_id(3)
+    if n:  # the folded grid of n blocks a side (`_attend_grid`)
+        iq, ik = _fold(iq, ik, n, True)[:2]
 
     @pl.when(ik == 0)
     def _init():
@@ -355,7 +375,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(ik * bk <= (iq + 1) * bq - 1)
+    @(_run if n else pl.when(ik * bk <= (iq + 1) * bq - 1))
     def _tile():
         # A row whose kept keys all lie in later tiles carries exp(0) of its
         # masked scores until its first kept key arrives; alpha is then 0.
@@ -370,11 +390,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, m_scr, l_scr,
                                                   v_ref[...])
         m_scr[:] = m_new
 
-    @pl.when(ik == pl.num_programs(3) - 1)
+    @pl.when(ik == (iq if n else pl.num_programs(3) - 1))
     def _flush():
         l = l_scr[:]
         o_ref[...] = (acc_scr[:] / l).astype(o_ref.dtype)
         lse_ref[...] = (m_scr[:] + jnp.log(l)).T
+
+
+def _attend_grid(S, bq, bk, keys_inner):
+    """The core's grid of one (batch, head), as a full causal flash call's
+    (ops/flash_attention.py) -> (n, (outer, inner), iq, ik), the last two
+    functions of (outer, inner): the q block and the key block of a grid
+    step. Folded (square tiles, an even n of them a side): n / 2 x (n + 1)
+    steps, each a tile (`_fold`). Else n = 0 and the rectangle: every block
+    of the one side by every block of the other, the inner block held on
+    the first or last one a tile needs, so that a step above the diagonal
+    fetches nothing."""
+    n = S // bq if _folded(True, bq, bk, S) else 0
+    return (n, _grid_dims(True, bq, bk, S, None, keys_inner)) + _block_at(
+        True, bq, bk, S, None, keys_inner)
 
 
 def _attend_fwd(q, k, v, bits, scale, tiles=None):
@@ -385,26 +419,24 @@ def _attend_fwd(q, k, v, bits, scale, tiles=None):
     pn = plan(S)
     bq, bk = tiles or (pn.bq, pn.bk)
     per_plane = pn.planes // bk
-    kv = lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+    n, grid, iq, ik = _attend_grid(S, bq, bk, True)
+    q_at = lambda b, h, i, j: (b, h, iq(i, j), 0)
+    kv_at = lambda b, h, i, j: (b, h // g, ik(i, j), 0)
     with jax.named_scope("dsa.core"):
         return pl.pallas_call(
             functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
-                              per_plane=per_plane),
-            grid=(B, H, S // bq, S // bk),
+                              per_plane=per_plane, n=n),
+            grid=(B, H) + grid,
             in_specs=[
-                pl.BlockSpec((None, None, bq, D),
-                             lambda b, h, i, j: (b, h, i, 0)),
-                pl.BlockSpec((None, None, bk, D),
-                             lambda b, h, i, j: (b, h // g, kv(i, j), 0)),
-                pl.BlockSpec((None, None, bk, D),
-                             lambda b, h, i, j: (b, h // g, kv(i, j), 0)),
-                pl.BlockSpec((None, bq, bk), lambda b, h, i, j:
-                             (b, i, kv(i, j) % per_plane)),
+                pl.BlockSpec((None, None, bq, D), q_at),
+                pl.BlockSpec((None, None, bk, D), kv_at),
+                pl.BlockSpec((None, None, bk, D), kv_at),
+                pl.BlockSpec((None, bq, bk), lambda b, h, i, j: (
+                    b, iq(i, j), ik(i, j) % per_plane)),
             ],
             out_specs=[
-                pl.BlockSpec((None, None, bq, D),
-                             lambda b, h, i, j: (b, h, i, 0)),
-                _stat_spec(bq, lambda b, h, i, j: (b, h, 0, i)),
+                pl.BlockSpec((None, None, bq, D), q_at),
+                _stat_spec(bq, lambda b, h, i, j: (b, h, 0, iq(i, j))),
             ],
             out_shape=[jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
                        jax.ShapeDtypeStruct((B, H, 1, S), _F32)],
@@ -419,11 +451,14 @@ def _attend_fwd(q, k, v, bits, scale, tiles=None):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bits_ref,
                 dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, dq_scr, *, scale, bq,
-                bk, per_plane):
+                bk, per_plane, n):
     ik, step = pl.program_id(2), pl.program_id(3)
-    nk, nq = pl.num_programs(2), pl.num_programs(3)
-    first = (ik * bk) // bq  # the first query block that sees this key block
-    iq = jnp.maximum(step, first)
+    if n:  # the folded grid: a key block's steps are the q blocks from its own
+        iq, ik, step, nq = _fold(ik, step, n, False)
+    else:
+        nk, nq = pl.num_programs(2), pl.num_programs(3)
+        first = (ik * bk) // bq  # the first query block that sees this key block
+        iq = jnp.maximum(step, first)
 
     @pl.when(step == 0)
     def _init():
@@ -434,7 +469,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bits_ref,
     def _init_head():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(step >= first)
+    @(_run if n else pl.when(step >= first))
     def _tile():
         q, do, k = q_ref[...], do_ref[...], k_ref[...]
         s = jnp.where(_selected(bits_ref, ik, per_plane),
@@ -452,7 +487,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bits_ref,
         dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
 
-    @pl.when((step == nq - 1) & (ik == nk - 1))
+    # a head's grid ends on its last key block, or folded on the later one
+    # of the pair its last line holds
+    @pl.when((step == nq - 1) & (ik == (n // 2 if n else nk - 1)))
     def _flush_head():
         dq_ref[...] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -466,26 +503,26 @@ def _attend_bwd(q, k, v, bits, o, lse, do, scale, tiles=None):
     per_plane = pn.planes // bk
     with jax.named_scope("dsa.core"):
         delta = jnp.sum(do.astype(_F32) * o.astype(_F32), axis=-1)[:, :, None]
-    qb = lambda j, i: jnp.maximum(i, (j * bk) // bq)
-    block = lambda n, at: pl.BlockSpec((None, None, n, D), at)
-    q_at = lambda b, h, j, i: (b, h, qb(j, i), 0)
-    k_at = lambda b, h, j, i: (b, h // g, j, 0)
-    stat_at = lambda b, h, j, i: (b, h, 0, qb(j, i))
+    n, grid, iq, ik = _attend_grid(S, bq, bk, False)
+    block = lambda rows, at: pl.BlockSpec((None, None, rows, D), at)
+    q_at = lambda b, h, j, i: (b, h, iq(j, i), 0)
+    k_at = lambda b, h, j, i: (b, h // g, ik(j, i), 0)
+    stat_at = lambda b, h, j, i: (b, h, 0, iq(j, i))
+    out_at = lambda b, h, j, i: (b, h, ik(j, i), 0)
     with jax.named_scope("dsa.core"):
         dk, dv, dq = pl.pallas_call(
             functools.partial(_bwd_kernel, scale=scale, bq=bq, bk=bk,
-                              per_plane=per_plane),
-            grid=(B, H, S // bk, S // bq),
+                              per_plane=per_plane, n=n),
+            grid=(B, H) + grid,
             in_specs=[
                 block(bq, q_at), block(bk, k_at), block(bk, k_at),
                 block(bq, q_at), _stat_spec(bq, stat_at),
                 _stat_spec(bq, stat_at),
-                pl.BlockSpec((None, bq, bk), lambda b, h, j, i:
-                             (b, qb(j, i), j % per_plane)),
+                pl.BlockSpec((None, bq, bk), lambda b, h, j, i: (
+                    b, iq(j, i), ik(j, i) % per_plane)),
             ],
             out_specs=[
-                block(bk, lambda b, h, j, i: (b, h, j, 0)),
-                block(bk, lambda b, h, j, i: (b, h, j, 0)),
+                block(bk, out_at), block(bk, out_at),
                 block(S, lambda b, h, j, i: (b, h, 0, 0)),
             ],
             out_shape=[jax.ShapeDtypeStruct((B, H, S, D), q.dtype)] * 3,

@@ -340,8 +340,64 @@ def test_a_batch_without_positions_counts_them_itself(case):
         g({"tokens": toks[:, :S], "positions": arange[:, :, :S]}))
 
 
+def test_the_cores_folded_grid_is_the_rectangular_one_bit_for_bit(
+        monkeypatch):
+    """`_attend_fwd` / `_attend_bwd` at 256 positions with tiles of 8 x 8: a
+    key tile lies inside a plane of S / 32 keys, so a square grid has a
+    multiple of 32 tiles a side, here 32 as in the cell (16 x 33 grid steps
+    a head, all tiles, for the rectangle's 1,024). Against autodiff of the
+    plain masked softmax over the same bits, and against the SAME call on
+    the rectangular grid (the rule answering no, here in the test): o, lse,
+    dK and dV bit for bit, dQ within float32 rounding (the fused backward
+    sums a row's key blocks in the order the grid brings them)."""
+    from ray_tpu.ops import flash_attention as fa
+
+    S_, H, KVH, D, HI, dI, topk, scale, tiles = 256, 4, 2, 16, 2, 8, 40, .25, (
+        8, 8)
+    assert fa._folded(True, *tiles, S_) and fa.grid_steps(
+        S_, *tiles, True) == (528, 0)
+    ks = jax.random.split(jax.random.key(57), 7)
+    q, do = (jax.random.normal(x, (1, H, S_, D)) for x in ks[:2])
+    k, v = (jax.random.normal(x, (1, KVH, S_, D)) for x in ks[2:4])
+    bits = jax.jit(lambda a, b, c: sa.select(a, b, c, topk))(
+        jax.random.normal(ks[4], (1, HI, S_, dI)),
+        jax.random.normal(ks[5], (1, dI, S_)),
+        jax.random.normal(ks[6], (1, HI, 1, S_)))[0]
+
+    def core(q, k, v, bits, do):
+        o, lse = sa._attend_fwd(q, k, v, bits, scale, tiles)
+        return (o, lse) + tuple(sa._attend_bwd(q, k, v, bits, o, lse, do,
+                                               scale, tiles))
+
+    def plain(q, k, v, bits, do):
+        def attend(q, k, v):
+            kk, vv = (jnp.repeat(x, H // KVH, axis=1) for x in (k, v))
+            s = jnp.where(sa.mask_of(bits)[:, None], jnp.einsum(
+                "bhqd,bhkd->bhqk", q, kk) * scale, -jnp.inf)
+            return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vv)
+        o, vjp = jax.vjp(attend, q, k, v)
+        return (o,) + vjp(do)
+
+    got = jax.jit(core)(q, k, v, bits, do)
+    for module in (fa, sa):
+        monkeypatch.setattr(module, "_folded", lambda *a, **kw: False)
+    rect = jax.jit(lambda *a: core(*a))(q, k, v, bits, do)
+    want = jax.jit(plain)(q, k, v, bits, do)
+    want = (want[0], None) + want[1:]
+    for name, a, b, c in zip(("o", "lse", "dq", "dk", "dv"), got, rect, want):
+        if name == "dq":
+            assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * float(
+                jnp.max(jnp.abs(b)))
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        if c is not None:
+            assert float(jnp.max(jnp.abs(a - c))) <= 1e-5 * max(
+                float(jnp.max(jnp.abs(c))), 1.0), name
+
+
 @pytest.mark.parametrize("policy", ["dots", "full"])
-def test_remat_keeps_the_selection_and_re_runs_no_kernel(case, policy):
+def test_remat_keeps_the_selection_and_re_runs_no_kernel(case, policy,
+                                                         monkeypatch):
     """Under either policy the gradient is the plain program's and the
     kernels' residuals are kept: the traced gradient has each kernel once a
     layer."""
@@ -357,10 +413,23 @@ def test_remat_keeps_the_selection_and_re_runs_no_kernel(case, policy):
     # each kernel once a layer (select 3 in / 5 out, forward 4 / 2, backward
     # 7 / 3, the indexer's loss 8 / 4): the backward's recomputation re-runs
     # none
+    from ray_tpu.util import tracing
+
+    plans = []
+    observe = tracing.observe
+    monkeypatch.setattr(tracing, "observe", lambda name, *a, **kw: (
+        plans.append(kw) if name == "dsa.plan" else None,
+        observe(name, *a, **kw))[1])
     calls = _kernel_calls(jax.make_jaxpr(jax.grad(lambda p: tfm.loss_fn(
         p, case["batch"], remat, shift_inputs=True)))(case["params"]).jaxpr)
     assert calls == {"3in_5out": 2, "4in_2out": 2, "7in_3out": 2,
                      "8in_4out": 2}, calls
+    # a traced layer says its plan, with the core's grid: the preset's one
+    # block of 64 rows over 32 key tiles of a word's two planes, every step
+    # a tile (the cell's 1,024 x 1,024 at 32,768: 528 and 0, folded)
+    assert plans and all(
+        (p["bq"], p["bk"], p["grid_steps"], p["grid_steps_idle"])
+        == (64, 2, 32, 0) for p in plans), plans
 
 
 
