@@ -38,6 +38,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import dispatch
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops import sparse_attention as sa
 
@@ -177,7 +178,7 @@ def parent_select(qi, ki_t, w, topk, trips=1.0):
         scratch_shapes=[pltpu.VMEM((R, S), jnp.int32),
                         pltpu.VMEM((HI, R, 1), jnp.float32)],
         compiler_params=sa._params("parallel", "arbitrary"),
-        name="dsa_select_parent", interpret=sa._interpret(),
+        name="dsa_select_parent", interpret=dispatch.interpret(),
     )(qi, ki_t, w)
 
 

@@ -395,9 +395,6 @@ _define("RTPU_TPU_GENERATION", str, None,
 _define("RTPU_WORKFLOW_STORAGE", str, None,
         "Workflow durability root (default ~/.ray_tpu/workflows).")
 
-_define("RTPU_ATTN_IMPL", str, "auto",
-        "Attention implementation: auto (flash on TPU, else XLA) | flash | "
-        "xla. 'xla' keeps the whole program Pallas-free.")
 _define("RTPU_SP_MODE", str, "ring",
         "Context-parallel attention scheme over the seq mesh axis: "
         "ring | ulysses | auto (ulysses when head counts divide the axis).")

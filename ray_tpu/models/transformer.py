@@ -30,9 +30,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import dispatch
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel import tensor_overlap as tp
-from ray_tpu.parallel.sharding import current_sharding_ctx, maybe_constrain
+from ray_tpu.parallel.sharding import maybe_constrain
 from ray_tpu.util import tracing
 
 Params = Dict[str, Any]
@@ -1394,8 +1395,6 @@ def _mamba1_mixer(cfg, kind, h, layer, positions, overlap):
     from ray_tpu.ops.kda import mixer_conv
     from ray_tpu.ops.selective_scan import selective_scan
 
-    _one_chip("a Mamba-1 (mamba1) layer's selective scan",
-              "R22 (h): the selective scan under a mesh")
     f32 = jnp.float32
     N, R = cfg.mamba1_d_state, cfg.mamba1_rank
     # u, then z, each whole (`_gdn_mixer`'s note)
@@ -1436,7 +1435,7 @@ def _dsa_mixer(cfg, kind, h, layer, positions, overlap):
     from ray_tpu.ops import sparse_attention as sa
     from ray_tpu.ops.flash_attention import grid_steps
 
-    _one_chip("a learned-sparse-attention (dsa) layer's kernels")
+    dispatch.one_chip("a learned-sparse-attention (dsa) layer's kernels")
     B, S, _ = h.shape
     HI, dI = cfg.dsa_index_heads, cfg.dsa_index_head_dim
     pn = sa.plan(S)
@@ -1469,20 +1468,6 @@ def _dsa_mixer(cfg, kind, h, layer, positions, overlap):
 # The indexer's loss in the stack's: L = L_LM + DSA_LOSS_COEF x sum over the
 # "dsa" layers of their mean KL (one value in use, so no field).
 DSA_LOSS_COEF = 1.0
-
-
-def _one_chip(what: str,
-              roadmap: str = "R22 (a): the selection under a mesh") -> None:
-    """Refuse a mesh. The "dsa" kernels are under no `shard_map` (a top-k
-    across sequence shards is not written) and `shard_batch` would cut the
-    three position streams as if they were rows of the batch; the "mamba1"
-    scan is a Mosaic call on one device too, and what it would fall back
-    to under a mesh is a loop over the tokens."""
-    ctx = current_sharding_ctx()
-    if ctx is not None and ctx[0].size > 1:
-        raise NotImplementedError(
-            f"{what} run on one chip, not under a mesh of {ctx[0].size} "
-            f"(ROADMAP {roadmap})")
 
 
 def _swa_flops(cfg: TransformerConfig, S: int) -> float:
@@ -1706,26 +1691,12 @@ def carry_scan_body(cfg: TransformerConfig, kind: Tuple[str, str],
 
     if not cfg.remat:
         return body
-    from ray_tpu.ops import flash_attention as fa, kda, moe, ssd
-
     # "full" recomputes what XLA makes and keeps what a hand-written kernel's
     # forward rule names, so the backward re-runs no such kernel. "dots" also
     # keeps every dot and the products inside a ring over `tensor` (a
     # `custom_vjp` hides its dots; they are products, not kernels).
     dots = cfg.remat_policy == "dots"
-    names = (fa.RESIDUAL_NAMES + kda.RESIDUAL_NAMES + ssd.RESIDUAL_NAMES
-             + moe.RESIDUAL_NAMES + (tp.RESIDUAL_NAMES if dots else ()))
-    if kind[0] == "dsa":
-        # (imported where a "dsa" layer is traced, as `_dsa_mixer` does; the
-        # experts' products are kept as under every other kind: at one
-        # sequence of 32,768 they are 0.59 GB an expert layer, PR 54)
-        from ray_tpu.ops.sparse_attention import RESIDUAL_NAMES
-
-        names += RESIDUAL_NAMES
-    if kind[0] == "mamba1":  # y and the chunk-start states
-        from ray_tpu.ops.selective_scan import RESIDUAL_NAMES
-
-        names += RESIDUAL_NAMES
+    names = dispatch.residual_names() + (tp.RESIDUAL_NAMES if dots else ())
     policy = jax.checkpoint_policies.save_only_these_names(*names)
     if dots:
         policy = jax.checkpoint_policies.save_from_both_policies(
@@ -2013,7 +1984,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: TransformerConfig,
     inputs = tokens[:, :-1] if shift_inputs else tokens
     positions = batch.get("positions")
     if positions is not None:
-        _one_chip("a batch's three position streams")
+        dispatch.one_chip("a batch's three position streams")
         if shift_inputs:
             positions = positions[:, :, :-1]
     targets_valid = lambda: (

@@ -8,17 +8,10 @@ a fused-softmax XLA implementation that the compiler maps onto MXU+VPU well.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-
-def _on_tpu() -> bool:
-    """Decided from the live platform; a backend that fails to initialize
-    raises here instead of quietly selecting the XLA path."""
-    return jax.devices()[0].platform == "tpu"
 
 
 def reference_attention(
@@ -55,8 +48,7 @@ def reference_attention(
     return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
-def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash,
-                            window=None):
+def _shard_mapped_attention(q, k, v, causal, scale, kernels, window=None):
     """Run attention per shard of a multi-device mesh via shard_map: pjit
     keeps global array semantics outside; inside, each device works on its
     batch/head/sequence shard.
@@ -79,11 +71,13 @@ def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash,
     from jax import shard_map
 
     from ray_tpu import flags
-    from ray_tpu.parallel.sharding import logical_to_mesh_spec
+    from ray_tpu.parallel.sharding import (current_sharding_ctx,
+                                           logical_to_mesh_spec)
     from .flash_attention import flash_attention
     from .ring_attention import ring_attention
     from .ulysses_attention import ulysses_attention
 
+    mesh, rules = current_sharding_ctx()
     q_spec = logical_to_mesh_spec(("batch", "seq_act", "heads", None), rules, mesh)
     kv_spec = logical_to_mesh_spec(("batch", "seq_act", "kv_heads", None), rules, mesh)
 
@@ -97,7 +91,7 @@ def _shard_mapped_attention(q, k, v, mesh, rules, causal, scale, use_flash,
         # RULES_DP on a mesh that happens to have seq>1 — a ring over
         # replicated full-sequence "chunks" would silently double-count
         # keys).
-        if not use_flash:
+        if not kernels:
             return None
         return run(lambda q, k, v: flash_attention(
             q, k, v, causal=causal, scale=scale, window=window))
@@ -141,50 +135,23 @@ def attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    use_flash: Optional[bool] = None,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Dispatching attention entry point used by all models. `window`
-    (with `causal`): query i sees keys j with 0 <= i - j < window."""
-    from ray_tpu import flags
-    from ray_tpu.parallel.sharding import current_sharding_ctx
+    """Dispatching attention entry point used by all models: the flash
+    kernels where `flash_attention.use_kernels` says so, under a
+    multi-device mesh inside a `shard_map`, else `reference_attention`.
+    `window` (with `causal`): query i sees keys j with 0 <= i - j <
+    window."""
+    from . import dispatch, flash_attention as fa
 
-    impl = flags.get("RTPU_ATTN_IMPL")
-    if impl not in ("auto", "flash", "xla"):
-        global _warned_bad_impl
-        if not _warned_bad_impl:
-            import warnings
-
-            warnings.warn(
-                f"RTPU_ATTN_IMPL={impl!r} is not one of auto|flash|xla; "
-                "treating as 'auto'", stacklevel=2)
-            _warned_bad_impl = True
-        impl = "auto"
-    if use_flash is None:
-        if impl == "flash":
-            use_flash = True
-        elif impl == "xla":
-            use_flash = False
-        else:
-            use_flash = _on_tpu()
-    ctx = current_sharding_ctx()
-    # impl=xla promises a Pallas-free program; the seq-parallel schemes
-    # (ring/ulysses) run Mosaic flash kernels per-shard, so they are
-    # bypassed too — dense reference attention under pjit computes the
-    # same global result (XLA shards it by the operand shardings), just
-    # without the comm/compute overlap.
-    if ctx is not None and impl != "xla" and ctx[0].size > 1:
-        out = _shard_mapped_attention(q, k, v, *ctx, causal, scale, use_flash,
-                                      window)
+    s = dispatch.site()
+    kernels = fa.use_kernels(s.platform)
+    if s.on_mesh:
+        out = _shard_mapped_attention(q, k, v, causal, scale, kernels, window)
         if out is not None:
             return out
-    if use_flash:
-        from .flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               window=window)
+    if kernels:
+        return fa.flash_attention(q, k, v, causal=causal, scale=scale,
+                                  window=window)
     return reference_attention(q, k, v, causal=causal, scale=scale,
                                window=window)
-
-
-_warned_bad_impl = False

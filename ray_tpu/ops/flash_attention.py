@@ -72,6 +72,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import dispatch
+
 # The fallback for shapes `_TILES` does not know, and the grid block of a
 # sequence the table's block does not divide.
 DEFAULT_BLOCK = 512
@@ -185,12 +187,6 @@ _TILES = {
     (256, 256): (1024, 512),
 }
 _NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    """Interpret mode is chosen because the platform is cpu, never because
-    the backend failed: a backend error propagates to the caller."""
-    return jax.devices()[0].platform == "cpu"
 
 
 # ---------------------------------------------------------------- tiles
@@ -753,7 +749,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q=None, block_k=None, sub=None,
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         compiler_params=params,
-        interpret=_interpret(),
+        interpret=dispatch.interpret(),
     )(q, k, v)
     return o, lse[:, :, 0, :S]
 
@@ -1019,7 +1015,7 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
             out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             compiler_params=_compiler_params(bq, bk, sub, D, Dv, itemsize),
-            interpret=_interpret(),
+            interpret=dispatch.interpret(),
         )(*args)
 
     # dk/dv per *query* head, then segment-sum over the GQA group in XLA;
@@ -1058,7 +1054,7 @@ def flash_bwd_core(q, k, v, do, lse, delta, *, scale, causal,
         ] + dq_scr,
         compiler_params=_compiler_params(bq, bk, sub, D, Dv, itemsize,
                                          dq_bytes),
-        interpret=_interpret(),
+        interpret=dispatch.interpret(),
     )(*args)
     dk_h, dv_h = grads[:2]
     if fused:
@@ -1101,6 +1097,13 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, sub, window, res, g):
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def use_kernels(platform: str) -> bool:
+    """The dispatch rule of `ops/attention.py`, a pure function of what the
+    code observes: the kernels on a TPU, at any shape (`tile_sizes`). Under a
+    multi-device mesh they run inside a `shard_map` (`dispatch`'s table)."""
+    return platform == "tpu"
 
 
 def flash_attention(

@@ -102,7 +102,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret
+from . import dispatch
 
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -802,7 +802,7 @@ def _call(kernel, rev, operands, ins, outs, C, scope, scratch=(), **kw):
             scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32), *scratch],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
+            interpret=dispatch.interpret(),
         )(*operands)
 
 
@@ -1121,7 +1121,7 @@ def _conv_fwd_call(x, w, b, l2, scope):
             scratch_shapes=[pltpu.VMEM((_HALO + bs, bc), _F32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel")),
-            interpret=_interpret(),
+            interpret=dispatch.interpret(),
         )(x, x, w, *(() if b is None else (b,)))
 
 
@@ -1150,7 +1150,7 @@ def _conv_bwd_call(x, w, b, dy, l2, scope):
                             pltpu.VMEM((K + 1, 8, bc), _F32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=_interpret(),
+            interpret=dispatch.interpret(),
         )(x, x, w, *(() if b is None else (b,)), dy)
 
 
@@ -1200,11 +1200,11 @@ def mixer_conv_xla(x, w, b=None, *, l2: bool = False) -> jax.Array:
 def use_conv_kernels(platform: str, channels: int, l2_group: Optional[int],
                      on_mesh: bool) -> bool:
     """The convolution path's dispatch rule, a pure function of what the
-    code observes: the kernels on a TPU, with the channels whole 128-lane
-    tiles, the norm (where there is one) over groups of 128, and no
-    multi-device mesh (`use_kernels`' reason)."""
-    return (platform == "tpu" and not on_mesh and channels % _L2 == 0
-            and l2_group in (None, _L2))
+    code observes: the kernels where a Mosaic call can run
+    (`dispatch.mosaic`), with the channels whole 128-lane tiles and the norm
+    (where there is one) over groups of 128."""
+    return (dispatch.mosaic(platform, on_mesh)
+            and dispatch.whole(channels, of=_L2) and l2_group in (None, _L2))
 
 
 def mixer_conv(x, w, b=None, *, l2: bool = False,
@@ -1216,17 +1216,13 @@ def mixer_conv(x, w, b=None, *, l2: bool = False,
     traced outside it), else `mixer_conv_xla`. Each traced call counts once
     in the phase table as `mixer.conv.pallas` or `mixer.conv.xla` with what
     it observed."""
-    from ray_tpu.parallel.sharding import current_sharding_ctx
-    from ray_tpu.util import tracing
-
-    ctx = current_sharding_ctx()
+    s = dispatch.site()
     channels = math.prod(x.shape[2:])
-    kernels = use_conv_kernels(
-        jax.devices()[0].platform, channels, x.shape[-1] if l2 else None,
-        ctx is not None and ctx[0].size > 1)
-    tracing.observe("mixer.conv." + ("pallas" if kernels else "xla"), 0,
-                    slow=False, rows=x.shape[1], channels=channels,
-                    taps=w.shape[0], l2=l2, bias=b is not None)
+    kernels = use_conv_kernels(s.platform, channels,
+                               x.shape[-1] if l2 else None, s.on_mesh)
+    dispatch.observe("mixer.conv", kernels, rows=x.shape[1],
+                     channels=channels, taps=w.shape[0], l2=l2,
+                     bias=b is not None)
     if kernels:
         return mixer_conv_pallas(x, w, b, l2=l2, scope=scope)
     return mixer_conv_xla(x, w, b, l2=l2)
@@ -1238,11 +1234,12 @@ def mixer_conv(x, w, b=None, *, l2: bool = False,
 def use_kernels(platform: str, d_k: int, d_v: int, chunk: int,
                 on_mesh: bool) -> bool:
     """The dispatch rule, a pure function of what the code observes: the
-    kernels on a TPU, with keys, values and chunk whole 128-lane tiles and
-    no multi-device mesh (a Mosaic call cannot be partitioned by GSPMD; it
-    would need `shard_map`, as ops/attention.py does for flash)."""
-    return (platform == "tpu" and not on_mesh
-            and d_k % 128 == 0 and d_v % 128 == 0 and chunk % 128 == 0)
+    kernels where a Mosaic call can run (`dispatch.mosaic`), with keys and
+    values whole 128-lane tiles and a chunk of 128, the one the kernels
+    compile for: at 256 Mosaic refuses them (`tests/
+    test_kda_kernel_compile.py`, `test_a_chunk_of_256_is_refused_for_v5e`)."""
+    return (dispatch.mosaic(platform, on_mesh) and dispatch.whole(d_k, d_v)
+            and chunk == 128)
 
 
 def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
@@ -1258,20 +1255,16 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 128, sub: int = 32,
     `chunks`, `k_heads`, `v_heads`, `decay="head"`) and the body that runs
     it: `body="scalar"` (the kernels take the rule as it is) or
     `"per_channel"` (the XLA body's broadcast)."""
-    from ray_tpu.parallel.sharding import current_sharding_ctx
-    from ray_tpu.util import tracing
-
-    ctx = current_sharding_ctx()
-    kernels = use_kernels(jax.devices()[0].platform, q.shape[-1], v.shape[-1],
-                          chunk, ctx is not None and ctx[0].size > 1)
-    path = "pallas" if kernels else "xla"
+    s = dispatch.site()
+    kernels = use_kernels(s.platform, q.shape[-1], v.shape[-1], chunk,
+                          s.on_mesh)
     if g.ndim == 3:
-        tracing.observe("gdn.core." + path, 0, slow=False, chunk=chunk,
-                        chunks=-(-q.shape[1] // chunk), k_heads=q.shape[2],
-                        v_heads=v.shape[2], decay="head",
-                        body="scalar" if kernels else "per_channel")
+        dispatch.observe("gdn.core", kernels, chunk=chunk,
+                         chunks=-(-q.shape[1] // chunk), k_heads=q.shape[2],
+                         v_heads=v.shape[2], decay="head",
+                         body="scalar" if kernels else "per_channel")
     else:
-        tracing.observe("kda.core." + path, 0, slow=False)
+        dispatch.observe("kda.core", kernels)
     body = kda_chunked_pallas if kernels else kda_chunked_xla
     return body(q, k, v, g, beta, chunk=chunk, sub=sub, scale=scale,
                 initial_state=initial_state)

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from . import flash_attention
+from . import dispatch
 
 
 def moe_ffn(
@@ -121,11 +121,10 @@ def _moe_group(
             * cap_onehot[:, :, None, :]
             * keep[..., None, None])                         # [T, k, E, C]
     combine = (disp * gate_vals[..., None, None]).sum(1)     # [T, E, C]
-    dispatch = disp.sum(1)                                   # [T, E, C]
 
     # Route tokens to expert buffers: [E, C, d].
     expert_in = jnp.einsum(
-        "tec,td->ecd", dispatch.astype(dtype), xf.astype(dtype))
+        "tec,td->ecd", disp.sum(1).astype(dtype), xf.astype(dtype))
     # Batched expert FFN (swiglu), ONE einsum per projection over E.
     gu = jnp.einsum("ecd,edgf->ecgf", expert_in, w_gate_up.astype(dtype))
     act = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]             # [E, C, F]
@@ -256,16 +255,15 @@ def use_kernels(platform: str, dtype, widths: Tuple[int, ...],
                 on_mesh: bool) -> bool:
     """The grouped products' dispatch rule, a pure function of what the code
     observes: the Pallas grouped matmul (`jax.experimental.pallas.ops.tpu.
-    megablox`) on a TPU with 2-byte operands, widths of whole 128-lane tiles
-    and no multi-device mesh (a Mosaic call cannot be partitioned by GSPMD),
-    else `lax.ragged_dot`, which XLA lowers to a Mosaic call of its own
-    tiling: at a window of 40,960 rows x 2,304 -> 1,792 that one takes 4.12
-    ms, 4.59 against the weights transposed and 5.10 for the weights'
-    cotangent, the kernel at `_tiles` 1.98, 2.01 and 2.00 (`probe_moe.py
-    parts`, PERF.md section 6, PR 34)."""
-    return (platform == "tpu" and not on_mesh
-            and jnp.dtype(dtype).itemsize == 2
-            and all(w % 128 == 0 for w in widths))
+    megablox`) where a Mosaic call can run (`dispatch.mosaic`) with 2-byte
+    operands and widths of whole 128-lane tiles, else `lax.ragged_dot`,
+    which XLA lowers to a Mosaic call of its own tiling: at a window of
+    40,960 rows x 2,304 -> 1,792 that one takes 4.12 ms, 4.59 against the
+    weights transposed and 5.10 for the weights' cotangent, the kernel at
+    `_tiles` 1.98, 2.01 and 2.00 (`probe_moe.py parts`, PERF.md section 6,
+    PR 34)."""
+    return (dispatch.mosaic(platform, on_mesh)
+            and jnp.dtype(dtype).itemsize == 2 and dispatch.whole(*widths))
 
 
 def _tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
@@ -300,7 +298,7 @@ def grouped_products(kernels: bool, dtype):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
     kw = dict(preferred_element_type=dtype, tiling=_tiles,
-              interpret=flash_attention._interpret())
+              interpret=dispatch.interpret())
     return (lambda a, w, sizes: gmm(a, w, sizes, **kw),
             lambda g, w, sizes: gmm(g, w, sizes, transpose_rhs=True, **kw),
             lambda a, g, sizes: tgmm(a.T, g, sizes, **kw))
@@ -446,7 +444,7 @@ def block_sums(tok_t, rows_t, sizes, tokens: int, block: int, tiling=None):
     return tgmm(onehot.T, rows_t, sizes,
                 preferred_element_type=rows_t.dtype,
                 tiling=tiling or _sum_tiles,
-                interpret=flash_attention._interpret()).reshape(
+                interpret=dispatch.interpret()).reshape(
                     tokens, rows_t.shape[1])
 
 
@@ -488,7 +486,6 @@ def moe_ffn_held(
     `trips` (windows worked), `window_rows` (rows of the first window),
     `rows_worked` (rows of the blocks the row passes touched, over every
     window worked: whole blocks, the held rows at least)."""
-    from ray_tpu.parallel.sharding import current_sharding_ctx
     from ray_tpu.util import tracing
 
     B, S, d = x.shape
@@ -602,9 +599,8 @@ def moe_ffn_held(
     # always: every Pallas call in a program is lowered in Python when the
     # program is traced (0.4 s each on the chip's worker), and the loop's
     # eight would put `setup_s` past its bound (PERF.md section 6, PR 34).
-    ctx = current_sharding_ctx()
-    kernels = use_kernels(jax.devices()[0].platform, dtype, (d, F),
-                          ctx is not None and ctx[0].size > 1)
+    s = dispatch.site()
+    kernels = use_kernels(s.platform, dtype, (d, F), s.on_mesh)
     first = lambda order_t: (0, W, grouped_products(kernels, dtype), order_t)
     further = lambda i: (W + (i - 1) * W2, W2,
                          grouped_products(False, dtype), None)

@@ -171,41 +171,3 @@ def ring_attention(
     vt = jnp.swapaxes(v, 1, 2)
     ot = _ring(qt, kt, vt, axis_name, causal, scale, block)
     return jnp.swapaxes(ot, 1, 2)
-
-
-def ulysses_attention(
-    q: jax.Array,  # [B, S_local, H, D] shard
-    k: jax.Array,  # [B, S_local, KVH, D] shard
-    v: jax.Array,  # [B, S_local, KVH, D] shard
-    axis_name: str,
-    *,
-    causal: bool = True,
-    scale: Optional[float] = None,
-) -> jax.Array:
-    """Ulysses-style sequence parallelism: all-to-all trades the sequence
-    shard for a head shard (each device sees the FULL sequence for H/sp
-    heads), runs dense flash attention locally, and scatters back. One
-    all-to-all each way instead of sp-1 ring hops — better when
-    H >= axis size and ICI all-to-all bandwidth is plentiful; ring wins on
-    memory at extreme S. Differentiable through the collectives.
-    """
-    # NOT the dispatching ops.attention entry point: that would re-enter the
-    # seq-parallel branch from inside this shard_map body and nest manual
-    # regions over the same axis.
-    from .attention import reference_attention
-    from .flash_attention import flash_attention
-
-    sp = jax.lax.axis_size(axis_name)
-    # [B, S, H, D] -> heads scattered, sequence gathered: [B, S*sp, H//sp, D]
-    qh = jax.lax.all_to_all(q, axis_name, split_axis=2, concat_axis=1,
-                            tiled=True)
-    kh = jax.lax.all_to_all(k, axis_name, split_axis=2, concat_axis=1,
-                            tiled=True)
-    vh = jax.lax.all_to_all(v, axis_name, split_axis=2, concat_axis=1,
-                            tiled=True)
-    try:
-        oh = flash_attention(qh, kh, vh, causal=causal, scale=scale)
-    except Exception:
-        oh = reference_attention(qh, kh, vh, causal=causal, scale=scale)
-    return jax.lax.all_to_all(oh, axis_name, split_axis=1, concat_axis=2,
-                              tiled=True)

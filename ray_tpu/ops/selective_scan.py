@@ -57,7 +57,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret
+from . import dispatch
 
 _F32 = jnp.float32
 RESIDUAL_NAMES = ("sscan_y", "sscan_states")
@@ -299,7 +299,7 @@ def _fwd_call(u, dt, bm, cm, a, dw, T):
             scratch_shapes=[pltpu.VMEM((N, ROWS, LANES), _F32),
                             pltpu.VMEM((N, T, LANES), _F32),
                             pltpu.VMEM((N, T, LANES), _F32)],
-            compiler_params=_params(), interpret=_interpret(),
+            compiler_params=_params(), interpret=dispatch.interpret(),
         )(u, dt, bm, cm, a, dw, s0)
 
 
@@ -327,7 +327,7 @@ def _bwd_call(u, dt, bm, cm, a, dw, states, dy, T):
                             pltpu.VMEM((N, T, LANES), _F32),
                             pltpu.VMEM((N, T, LANES), _F32),
                             pltpu.VMEM((N, T, LANES), _F32)],
-            compiler_params=_params(), interpret=_interpret(),
+            compiler_params=_params(), interpret=dispatch.interpret(),
         )(u, dt, bm, cm, a, dw, states, dy)
         return (du, ddt, jnp.sum(da, 0), jnp.sum(db, 1)[..., :N],
                 jnp.sum(dc, 1)[..., :N], jnp.sum(dd, 0))
@@ -374,15 +374,14 @@ def selective_scan_pallas(u, dt, A, B, C, D, *, chunk: int = 128
 # ---------------------------------------------------------------- dispatch
 
 
-def use_kernels(platform: str, channels: int, state: int, chunk: int,
-                on_mesh: bool) -> bool:
+def use_kernels(platform: str, channels: int, state: int, chunk: int
+                ) -> bool:
     """The dispatch rule, a pure function of what the code observes: the
-    kernels on a TPU with no multi-device mesh (a Mosaic call cannot be
-    partitioned by GSPMD), channels in whole blocks of 1,024 (one [8, 128]
-    tile a state index), a state that fits a row's lanes and a chunk of
-    whole sublane tiles."""
-    return (platform == "tpu" and not on_mesh and channels % BLOCK == 0
-            and state <= LANES and chunk % ROWS == 0)
+    kernels on a TPU (a mesh `selective_scan` has refused by then), channels
+    in whole blocks of 1,024 (one [8, 128] tile a state index), a state that
+    fits a row's lanes and a chunk of whole sublane tiles."""
+    return (platform == "tpu" and dispatch.whole(channels, of=BLOCK)
+            and state <= LANES and dispatch.whole(chunk, of=ROWS))
 
 
 def chunk_plan(S: int, channels: int, state: int, chunk: int, kernels: bool
@@ -403,15 +402,13 @@ def selective_scan(u, dt, A, B, C, D, *, chunk: int = 128) -> jax.Array:
     `selective_scan_recurrent`, any sequence length: the Pallas kernels where
     `use_kernels` says so, else `selective_scan_xla`. Each traced call
     counts once in the phase table as `mamba1.core.pallas` or
-    `mamba1.core.xla`, its chunk plan the attributes."""
-    from ray_tpu.parallel.sharding import current_sharding_ctx
-    from ray_tpu.util import tracing
-
+    `mamba1.core.xla`, its chunk plan the attributes. One chip: a Mosaic
+    call cannot be partitioned and no `shard_map` is written for it."""
+    dispatch.one_chip("a Mamba-1 (mamba1) layer's selective scan",
+                      "R22 (h): the selective scan under a mesh")
     (_, S, Di), N = u.shape, A.shape[1]
-    ctx = current_sharding_ctx()
-    kernels = use_kernels(jax.devices()[0].platform, Di, N, chunk,
-                          ctx is not None and ctx[0].size > 1)
+    kernels = use_kernels(dispatch.site().platform, Di, N, chunk)
     plan = chunk_plan(S, Di, N, chunk, kernels)
-    tracing.observe("mamba1.core." + plan["body"], 0, slow=False, **plan)
+    dispatch.observe("mamba1.core", kernels, **plan)
     body = selective_scan_pallas if kernels else selective_scan_xla
     return body(u, dt, A, B, C, D, chunk=chunk)

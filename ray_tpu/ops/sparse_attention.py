@@ -64,9 +64,10 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import dispatch
 from ray_tpu.ops.flash_attention import (_NEG_INF, _block_at, _dot_nn,
                                          _dot_nt, _dot_tn, _fold, _folded,
-                                         _grid_dims, _interpret, _stat_spec)
+                                         _grid_dims, _stat_spec)
 
 RESIDUAL_NAMES = ("dsa_bits", "dsa_lse_i", "dsa_o", "dsa_lse", "dsa_kl",
                   "dsa_dqi", "dsa_dki", "dsa_dw")
@@ -349,7 +350,7 @@ def select(qi, ki_t, w, topk: int):
             scratch_shapes=[pltpu.VMEM((R, S), jnp.int32),
                             pltpu.VMEM((HI, R, 1), _F32)],
             compiler_params=_params("parallel", "arbitrary"),
-            name="dsa_select", interpret=_interpret(),
+            name="dsa_select", interpret=dispatch.interpret(),
         )(qi, ki_t, w)
         return bits, lse_i, count, passes[:, 0, 0, ::R], way[:, 0, 0, ::R]
 
@@ -445,7 +446,7 @@ def _attend_fwd(q, k, v, bits, scale, tiles=None):
                             pltpu.VMEM((bq, D), _F32)],
             compiler_params=_params("parallel", "parallel", "parallel",
                                     "arbitrary"),
-            name="dsa_fwd", interpret=_interpret(),
+            name="dsa_fwd", interpret=dispatch.interpret(),
         )(q, k, v, bits)
 
 
@@ -531,7 +532,7 @@ def _attend_bwd(q, k, v, bits, o, lse, do, scale, tiles=None):
                             pltpu.VMEM((S, D), _F32)],
             compiler_params=_params("parallel", "parallel", "arbitrary",
                                     "arbitrary"),
-            name="dsa_bwd", interpret=_interpret(),
+            name="dsa_bwd", interpret=dispatch.interpret(),
         )(q, k, v, do, lse, delta, bits)
         # dk / dv a *query* head, summed over the group in XLA (as the
         # flash backward's).
@@ -678,7 +679,7 @@ def _index_loss_call(q, k, lse, qi, ki_t, w, bits, lse_i, scale):
                             pltpu.VMEM((HI, bq, dI), _F32),
                             pltpu.VMEM((HI, bq, 1), _F32)],
             compiler_params=_params("arbitrary", "arbitrary", "arbitrary"),
-            name="dsa_index_loss", interpret=_interpret(),
+            name="dsa_index_loss", interpret=dispatch.interpret(),
         )(q, k, lse, qi, ki_t, w, bits, lse_i)
 
 
@@ -723,7 +724,7 @@ def sparse_attention(q, k, v, qi, ki, w, *, topk: int, scale: float):
     of kept keys; bits [B,S,S/32] int32, the selection; `select`'s passes and
     way, a block of query rows). o is differentiable in q, k and v."""
     B, S, H, D = q.shape
-    if not _interpret() and plan(S).bk % 128:
+    if not dispatch.interpret() and plan(S).bk % 128:
         raise ValueError(
             "on the TPU a key tile of the selection is whole 128-lane words: "
             f"S = {S} is not a multiple of 4,096")

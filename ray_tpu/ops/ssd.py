@@ -81,7 +81,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret
+from . import dispatch
 
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -438,7 +438,7 @@ def _call(kernel, rev, operands, ins, outs, C, scratch):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
                 vmem_limit_bytes=_VMEM_BYTES),
-            interpret=_interpret(),
+            interpret=dispatch.interpret(),
         )(*operands)
 
 
@@ -531,15 +531,13 @@ def ssd_chunked_pallas(x, dt, A, B, C, D, *, chunk: int = 256,
 def use_kernels(platform: str, P: int, N: int, chunk: int, heads: int,
                 groups: int, on_mesh: bool) -> bool:
     """The dispatch rule, a pure function of what the code observes: the
-    kernels on a TPU with no multi-device mesh (a Mosaic call cannot be
-    partitioned by GSPMD; it would need `shard_map`), the chunk and the
-    state whole 128-lane tiles, heads that fill 128-lane groups inside
+    kernels where a Mosaic call can run (`dispatch.mosaic`), the chunk and
+    the state whole 128-lane tiles, heads that fill 128-lane groups inside
     their group of B and C, and every head's state within the VMEM scratch
     the kernels carry it in."""
     hp = max(1, 128 // P)
-    return (platform == "tpu" and not on_mesh
-            and chunk % 128 == 0 and N % 128 == 0
-            and (hp * P) % 128 == 0 and heads % groups == 0
+    return (dispatch.mosaic(platform, on_mesh)
+            and dispatch.whole(chunk, N, hp * P) and heads % groups == 0
             and (heads // groups) % hp == 0
             and heads * P * N * 4 <= _STATE_BYTES)
 
@@ -552,17 +550,12 @@ def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 256,
     `ssd_chunked_xla`. Each traced call counts once in the phase table, as
     `ssd.core.pallas` or `ssd.core.xla`, with its chunk, chunks, heads and
     state as attributes (layers under one scan trace once)."""
-    from ray_tpu.parallel.sharding import current_sharding_ctx
-    from ray_tpu.util import tracing
-
     (_, S, H, P), (G, N) = x.shape, B.shape[-2:]
-    ctx = current_sharding_ctx()
-    kernels = use_kernels(jax.devices()[0].platform, P, N, chunk, H, G,
-                          ctx is not None and ctx[0].size > 1)
+    s = dispatch.site()
+    kernels = use_kernels(s.platform, P, N, chunk, H, G, s.on_mesh)
     attrs = dict(chunk=chunk, chunks=-(-S // chunk), heads=H, state=N)
     if kernels:
         attrs["heads_per_step"] = heads_per_step(H // G, P)
-    tracing.observe("ssd.core.pallas" if kernels else "ssd.core.xla", 0,
-                    slow=False, **attrs)
+    dispatch.observe("ssd.core", kernels, **attrs)
     body = ssd_chunked_pallas if kernels else ssd_chunked_xla
     return body(x, dt, A, B, C, D, chunk=chunk, initial_state=initial_state)

@@ -55,6 +55,16 @@ def pytest_configure(config):
         "variants are additionally marked slow.")
 
 
+@pytest.fixture()
+def flash_kernels(monkeypatch):
+    """Flash's rule says yes on the platform here, the CPU: `attention()`
+    takes the kernels, interpreted. A test steers the rule, as
+    tests/test_kda_remat.py does KDA's; the program has no option for it."""
+    from ray_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "use_kernels", lambda platform: True)
+
+
 @pytest.fixture(scope="module")
 def ray_start_regular():
     import ray_tpu

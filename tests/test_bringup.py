@@ -114,16 +114,16 @@ def test_compile_cache_dir_from_outside_wins(monkeypatch, tmp_path):
 
 
 def test_platform_probes_propagate_backend_errors(monkeypatch):
-    from ray_tpu.ops import attention, flash_attention
+    from ray_tpu.ops import dispatch
 
     def broken():
         raise RuntimeError("Unable to initialize backend 'tpu'")
 
     monkeypatch.setattr(jax, "devices", broken)
     with pytest.raises(RuntimeError, match="backend 'tpu'"):
-        flash_attention._interpret()
+        dispatch.interpret()
     with pytest.raises(RuntimeError, match="backend 'tpu'"):
-        attention._on_tpu()
+        dispatch.site()
 
 
 def test_peak_flops_has_no_default_for_an_unknown_device():

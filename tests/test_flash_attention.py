@@ -101,48 +101,7 @@ def test_uneven_blocks_grad():
                                    atol=5e-4, rtol=5e-4)
 
 
-def test_attn_impl_flag_forces_xla(monkeypatch):
-    """RTPU_ATTN_IMPL selects the implementation: 'xla' keeps the compiled
-    program free of Pallas custom calls, 'flash' forces the kernel. On the
-    CPU test platform 'auto' picks XLA anyway, so assert the dispatch
-    decision itself via use_flash resolution against a stub."""
-    import ray_tpu.ops.attention as att
-
-    called = {}
-
-    def fake_flash(q, k, v, **kw):
-        called["flash"] = True
-        return att.reference_attention(q, k, v, causal=kw.get("causal", True))
-
-    import ray_tpu.ops.flash_attention as fa
-    monkeypatch.setattr(fa, "flash_attention", fake_flash)
-    B, S, H, D = 1, 16, 2, 8
-    q = jnp.ones((B, S, H, D), jnp.float32)
-
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
-    att.attention(q, q, q, causal=True)
-    assert called.pop("flash", False)
-
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "xla")
-    att.attention(q, q, q, causal=True)
-    assert "flash" not in called
-
-
-def test_attn_impl_flag_bad_value_warns(monkeypatch):
-    import warnings
-
-    import ray_tpu.ops.attention as att
-
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "falsh")
-    monkeypatch.setattr(att, "_warned_bad_impl", False)
-    q = jnp.ones((1, 8, 2, 8), jnp.float32)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        att.attention(q, q, q, causal=True)
-    assert any("RTPU_ATTN_IMPL" in str(x.message) for x in w)
-
-
-def test_flash_runs_per_shard_on_a_multi_device_mesh():
+def test_flash_runs_per_shard_on_a_multi_device_mesh(flash_kernels):
     """GSPMD cannot partition a Mosaic kernel (a hard lowering error on a
     real 2x2 mesh): under a multi-device sharding context the flash kernel
     runs per batch/head shard through shard_map, and agrees with the dense
@@ -160,7 +119,7 @@ def test_flash_runs_per_shard_on_a_multi_device_mesh():
 
     def sharded(q, k, v):
         with sharding_ctx(mesh, DEFAULT_RULES):
-            return att.attention(q, k, v, causal=True, use_flash=True)
+            return att.attention(q, k, v, causal=True)
 
     out, grads = jax.jit(jax.value_and_grad(loss(sharded), (0, 1, 2)))(q, k, v)
     ref, rgrads = jax.value_and_grad(

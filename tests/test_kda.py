@@ -182,7 +182,8 @@ def test_kda_dispatch_rule():
     from ray_tpu.util import tracing
 
     assert kda.use_kernels("tpu", 128, 128, 128, on_mesh=False)
-    assert kda.use_kernels("tpu", 256, 128, 256, on_mesh=False)
+    assert kda.use_kernels("tpu", 256, 128, 128, on_mesh=False)
+    assert not kda.use_kernels("tpu", 128, 128, 256, on_mesh=False)
     assert not kda.use_kernels("cpu", 128, 128, 128, on_mesh=False)
     assert not kda.use_kernels("tpu", 16, 128, 128, on_mesh=False)
     assert not kda.use_kernels("tpu", 128, 16, 128, on_mesh=False)

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import flash_attention as fa, kda, ssd
+from ray_tpu.ops import dispatch, flash_attention as fa, kda, ssd
 
 
 @pytest.fixture(scope="module")
@@ -27,13 +27,12 @@ def one_chip():
 
 @pytest.fixture()
 def compiled_not_interpreted(monkeypatch):
-    """The platform here is cpu, which `_interpret()` reads; and a compile
-    for a device that is not attached must not be read back from the cache."""
+    """The platform here is cpu, which `dispatch.interpret()` reads; and a
+    compile for a device that is not attached must not be read back from the
+    cache."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(kda, "_interpret", lambda: False)
-    monkeypatch.setattr(fa, "_interpret", lambda: False)
-    monkeypatch.setattr(ssd, "_interpret", lambda: False)
+    monkeypatch.setattr(dispatch, "interpret", lambda: False)
     # The SSD and convolution calls are jitted on their own: no trace made in
     # the other mode.
     forget = lambda: [f.clear_cache() for f in (
@@ -68,6 +67,23 @@ def test_kda_kernels_compile_for_v5e(one_chip, compiled_not_interpreted,
     text = jax.jit(fn).lower(q, q, q, g, beta).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == (
         1 if what == "forward" else 2)
+
+
+@pytest.mark.parametrize("decay", ["channel", "head"])
+def test_a_chunk_of_256_is_refused_for_v5e(one_chip, compiled_not_interpreted,
+                                           decay):
+    """What `kda.use_kernels` goes by when it admits a chunk of 128 alone: at
+    256 Mosaic refuses the per-channel and the scalar-decay call alike (the
+    inverse's strided load wants a last dimension of 128), which interpret
+    mode does not see."""
+    B, S, H, d = 1, 1024, 2, 128
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    q, beta = sd((B, S, H, d), jnp.bfloat16), sd((B, S, H), jnp.float32)
+    g = sd((B, S, H, d), jnp.float32) if decay == "channel" else beta
+    lowered = jax.jit(lambda q, k, v, g, beta: kda.kda_chunked_pallas(
+        q, k, v, g, beta, chunk=256)[0]).lower(q, q, q, g, beta)
+    with pytest.raises(Exception, match="last dim size is not 128"):
+        lowered.compile()
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
@@ -229,8 +245,7 @@ def test_flash_pair_compiles_past_the_budget_for_v5e(
 
 
 def test_the_sparse_selection_compiles_for_v5e(one_chip,
-                                               compiled_not_interpreted,
-                                               monkeypatch):
+                                               compiled_not_interpreted):
     """`dsa_select` at the Keye-VL-2.0 cell's shape (1 x 32,768 positions,
     an indexer of 16 heads of 64, the top 2,048): a block of 128 rows' scores
     in 16 MiB of VMEM, and a search whose loops a vector reduced to a scalar
@@ -238,7 +253,6 @@ def test_the_sparse_selection_compiles_for_v5e(one_chip,
     interpret mode cannot refuse. One Mosaic call, five outputs."""
     from ray_tpu.ops import sparse_attention as sa
 
-    monkeypatch.setattr(sa, "_interpret", lambda: False)
     B, S, HI, dI = 1, 32768, 16, 64
     sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
     text = jax.jit(lambda a, b, c: sa.select(a, b, c, 2048)).lower(
@@ -340,7 +354,7 @@ def test_full_remat_reruns_no_core_kernel_for_v5e(
 
 
 def test_the_looped_cells_step_fits_a_v5e(one_chip, compiled_not_interpreted,
-                                          monkeypatch):
+                                          flash_kernels):
     """ouro_2_6b.train_loop4_8k's whole step as its files give it (published
     widths, 8 layers run 4 times, one sequence of 8,192, the remat policy
     and the head the traffic's sweep chose, AdamW's update inside, weights
@@ -362,7 +376,6 @@ def test_the_looped_cells_step_fits_a_v5e(one_chip, compiled_not_interpreted,
         tc = dict(json.load(f)["transformer_config"])
     with open(os.path.join(root, "traffic", "train_loop4_8k.json")) as f:
         mix = json.load(f)
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")  # the platform here is cpu
     tc.update(dtype=jnp.bfloat16, param_dtype=jnp.float32, remat=mix["remat"],
               remat_policy=mix["remat_policy"])
     cfg = tfm.TransformerConfig(**tc)

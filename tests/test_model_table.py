@@ -101,7 +101,7 @@ PARENT = {
     "phi4_flash_tiny": ("f777c0a4e2142200:bdeabde20eab01bb",
                         "724778c22dc2c088:f7ed437597c6e1d7",
                         "d439e5528bdd388a:f7ed437597c6e1d7"),
-    # RTPU_ATTN_IMPL=flash: the kernels' calls (interpret mode) in the text;
+    # flash's rule set true: the kernels' calls (interpret mode) in the text;
     # re-recorded in PR 47 (its own tree's: one backward kernel where the
     # parent's text held dQ's and dK/dV's; the operations' scopes unmoved)
     "llama_tiny-flash": ("c818439799ab19f7:c7a534cb53ae9dba",
@@ -158,7 +158,7 @@ PARENT_COUNTS = {
 def _lowered(preset, mode="off", impl=None):
     """(sha256[:16] of the text, `_scoped_ops` of the text with locations) of
     a preset's gradient program, remat off or under a policy; lowered once
-    a process (`impl`: what RTPU_ATTN_IMPL holds, the caller's to set)."""
+    a process (`impl`: "flash" where the caller set flash's rule true)."""
     cfg = getattr(configs, preset)(
         remat=mode != "off", remat_policy="dots" if mode == "off" else mode)
     p = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
@@ -201,11 +201,11 @@ def _digests(preset, mode, impl=None):
 
 @pytest.mark.parametrize("mode", REMAT)
 @pytest.mark.parametrize("preset", sorted(PARENT))
-def test_lowers_to_the_parents_program(preset, mode, monkeypatch):
+def test_lowers_to_the_parents_program(preset, mode, request):
     """Byte for byte, and every operation under the scope it was under."""
     name, _, impl = preset.partition("-")
     if impl:
-        monkeypatch.setenv("RTPU_ATTN_IMPL", impl)
+        request.getfixturevalue("flash_kernels")
     assert _digests(name, mode, impl or None) == PARENT[preset][
         REMAT.index(mode)]
 
@@ -501,11 +501,11 @@ def test_the_docs_table_is_the_table():
 
 
 if __name__ == "__main__":  # this tree's digests (JAX_PLATFORMS=cpu), for PARENT
+    from ray_tpu.ops import flash_attention as fa
+
     with jax.default_matmul_precision("highest"):
         for preset in PARENT:
             name, _, impl = preset.partition("-")
-            os.environ.pop("RTPU_ATTN_IMPL", None)
-            if impl:
-                os.environ["RTPU_ATTN_IMPL"] = impl
+            fa.use_kernels = lambda platform: bool(impl)  # the CPU's, or true
             print(repr(preset) + ":",
                   tuple(_digests(name, m, impl or None) for m in REMAT), ",")

@@ -161,13 +161,14 @@ def _kanana2_two_layers(**kw):
     ("flash", llama_tiny, dict(remat=True, remat_policy="full"), 1),
     ("flash", _kanana2_two_layers, dict(remat=True, remat_policy="full"), 1),
 ])
-def test_remat_matches_no_remat(monkeypatch, impl, preset, remat_kw,
+def test_remat_matches_no_remat(request, impl, preset, remat_kw,
                                 fwd_calls_per_layer):
     """Both remat policies give the gradients of no remat, and neither runs
     the flash forward kernel a second time in the backward: both keep the
     kernel's own residuals (o, lse), which no dot produces. The latent
     layers' keys (24 wide) and values (16) go through the same rule."""
-    monkeypatch.setenv("RTPU_ATTN_IMPL", impl)
+    if impl == "flash":  # ("xla" is the rule's own answer on the CPU)
+        request.getfixturevalue("flash_kernels")
     cfg = preset()
     params = tfm.init_params(jax.random.key(0), cfg)
     batch = _tiny_batch(cfg)
@@ -190,13 +191,13 @@ def test_remat_matches_no_remat(monkeypatch, impl, preset, remat_kw,
                          if fwd_calls_per_layer else {})
 
 
-def test_remat_full_is_the_work_of_saving_nothing(monkeypatch):
+def test_remat_full_is_the_work_of_saving_nothing(monkeypatch,
+                                                  flash_kernels):
     """The saved o and lse are the arrays the second forward call would have
     written: the loss and every gradient element under "full" are bit-equal
     to a body checkpointed with nothing saved (here, where XLA compiles the
     two programs alike; on the chip they differ by roundings, PERF.md
     section 6, PR 40)."""
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     cfg = llama_tiny(remat=True, remat_policy="full")
     params = tfm.init_params(jax.random.key(0), cfg)
     batch = _tiny_batch(cfg)
@@ -214,13 +215,13 @@ def test_remat_full_is_the_work_of_saving_nothing(monkeypatch):
 
 
 @pytest.mark.parametrize("policy", ["dots", "full"])
-def test_remat_keeps_flash_residuals_through_shard_map(monkeypatch, policy):
+def test_remat_keeps_flash_residuals_through_shard_map(flash_kernels,
+                                                       policy):
     """The mesh path (ops/attention.py wraps the kernel in shard_map on a
     multi-device mesh): still one forward call a layer under either
     policy."""
     from ray_tpu.parallel.sharding import DEFAULT_RULES, sharding_ctx
 
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     mesh = make_mesh(MeshSpec(fsdp=2, tensor=2), devices=jax.devices()[:4])
     cfg = llama_tiny(remat=True, remat_policy=policy)
     params = tfm.init_params(jax.random.key(0), cfg)
@@ -245,7 +246,8 @@ _MIXER_KERNEL = {"attn": "flash_attention", "swa": "flash_attention",
 @pytest.mark.parametrize("preset", [
     "llama_tiny", "moe_tiny", "kimi_linear_tiny", "kanana2_tiny",
     "granite_hybrid_tiny", "mellum2_tiny", "qwen3_next_tiny"])
-def test_remat_full_keeps_what_a_layers_kernels_name(monkeypatch, preset):
+def test_remat_full_keeps_what_a_layers_kernels_name(
+        monkeypatch, flash_kernels, preset):
     """What "full" keeps a layer kind: its input and what a hand-written
     kernel's forward rule names, so the backward re-runs no such kernel. An
     `attn` / `swa` / `mla` layer keeps o [B,H,S,hd] and lse [B,H,S]; a `kda`
@@ -255,7 +257,6 @@ def test_remat_full_keeps_what_a_layers_kernels_name(monkeypatch, preset):
     policy), and "dots" keeps strictly more."""
     from ray_tpu.ops import flash_attention, kda, moe, ssd
 
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     ops = dict(flash_attention=flash_attention, kda=kda, ssd=ssd, moe=moe)
     for core in (kda, ssd):  # the cores' kernels, where the names are
         monkeypatch.setattr(core, "use_kernels", lambda *a, **kw: True)
@@ -295,14 +296,13 @@ def test_remat_full_keeps_what_a_layers_kernels_name(monkeypatch, preset):
         assert len(_layer_saves(dots, index, B, S)) > len(saved)
 
 
-def test_remat_full_saves_no_ring_product(monkeypatch):
+def test_remat_full_saves_no_ring_product(flash_kernels):
     """Under a `tensor` axis the decomposed products name their outputs
     (tp.RESIDUAL_NAMES) for "dots"; "full" keeps none of them. (A save that
     the forward pass also uses loses its name in the listing: the ring's are
     found by where they were made.)"""
     from ray_tpu.parallel.sharding import sharding_ctx
 
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     mesh = make_mesh(MeshSpec(fsdp=2, tensor=2), devices=jax.devices()[:4])
     saves = {}
     for policy in ("dots", "full"):
@@ -333,19 +333,19 @@ def test_remat_counter_is_one_a_checkpointed_body(monkeypatch):
     rows = [kw for name, kw in seen if name == "train.remat"]
     assert count() == before + 2
     kernels = ("flash_o,flash_lse,kda_o,kda_states,kda_tinv,ssd_y,ssd_states,"
-               "moe_gate_up,moe_down,moe_token_order")
+               "moe_gate_up,moe_down,moe_token_order,dsa_bits,dsa_lse_i,dsa_o,"
+               "dsa_lse,dsa_kl,dsa_dqi,dsa_dki,dsa_dw,sscan_y,sscan_states")
     assert rows[0] == dict(slow=False, policy="full", kept=kernels)
     assert rows[1] == dict(slow=False, policy="dots",
                            kept=kernels + ",tp.gathered,tp.scattered")
 
 
-def test_remat_dots_saved_residuals(monkeypatch):
+def test_remat_dots_saved_residuals(flash_kernels):
     """What one checkpointed layer keeps for the backward under "dots": the
     kernel's o once, as [B,H,S,hd], and lse as lane-dense [B,H,S] float32;
     no second attention output in the model's [B,S,H,hd] layout."""
     from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     cfg = llama_tiny(remat=True, remat_policy="dots")
     B, S, H, hd = 2, 32, cfg.n_heads, cfg.head_dim
     saved = _layer_saves(cfg, 0, B, S)

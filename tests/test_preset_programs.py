@@ -105,7 +105,7 @@ def _rel(a, b):
 
 
 @pytest.mark.parametrize("preset", sorted(FLASH_LEAVES))
-def test_the_flash_path_is_the_xla_path(preset, monkeypatch):
+def test_the_flash_path_is_the_xla_path(preset, request):
     """The model through the flash kernels (interpret mode here: windowed
     and full layers at heads of 16; keys 24 wide and values 16), under both
     remat policies, on the family's seeded weights: the loss and the
@@ -122,7 +122,7 @@ def test_the_flash_path_is_the_xla_path(preset, monkeypatch):
         p, batch, cfg, shift_inputs=True))
     want_loss, g = jax.jit(grad(cfg))(params)
     want = W.program_leaves(cfg, sz, g)
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
+    request.getfixturevalue("flash_kernels")  # from here on
     # ROADMAP D11: the looped stack's eight interpreted calls a policy are
     # 15 s each; its cell runs "full", and "dots" differs in no kernel call
     for policy in ("full",) if preset in DENSE_FAMILIES else ("dots", "full"):

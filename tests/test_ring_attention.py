@@ -11,7 +11,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ray_tpu.ops.attention import reference_attention
-from ray_tpu.ops.ring_attention import ring_attention, ulysses_attention
+from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.ops.ulysses_attention import ulysses_attention
 
 SP = 4
 

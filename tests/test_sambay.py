@@ -153,11 +153,10 @@ def test_the_chunked_scan_is_the_recurrence(body):
 
 
 def test_differential_attention_through_flash_at_the_windows_edge(
-        monkeypatch):
+        flash_kernels):
     """Window 512 through the flash kernels (interpreted) against the
     reference's dense maps, and the edge itself: query t sees key t - 511
     and does not see key t - 512."""
-    monkeypatch.setenv("RTPU_ATTN_IMPL", "flash")
     cfg = configs.phi4_flash_tiny(dtype=jnp.float32, sliding_window=512,
                                   max_seq_len=640)
     sz, T, t = _sizes(cfg), 640, 600
