@@ -28,11 +28,22 @@ body take.
    other blocks (rows, lanes, chunk, lanes worked, chunks a loop turn) as
    well.
 
+4. `norm` (PR 59): the way from the core to the output projection, an
+   RMSNorm over a channel group times a gate, alone: XLA's formulation
+   (`kda.gated_norm_xla`, the three lines each mixer wrote out) and the
+   Pallas pair (`kda.gated_norm_pallas`), the forward and the forward +
+   backward, eight independent calls in one program, at `_gdn_mixer`'s call
+   ([1,16384,32,128], SiLU(z) after the norm), `_kda_mixer`'s ([1,8192,32,128],
+   a sigmoid after) and `_mamba_mixer`'s ([1,4096,4096] as one group, SiLU(z)
+   before); `max_*` is each result's largest error over the reference's
+   largest value, against the XLA body on float32 operands.
+
 One JSON line a case. Off the chip the script fails at once.
 
     python3 benchmarks/probe_kda.py          # check + time
     python3 benchmarks/probe_kda.py time     # no check
     python3 benchmarks/probe_kda.py conv [sweep]
+    python3 benchmarks/probe_kda.py norm
 """
 from __future__ import annotations
 
@@ -242,11 +253,74 @@ def conv(sweep):
     return first["pallas_fwd_bwd_ms"] < 0.5 * first["xla_fwd_bwd_ms"]
 
 
+NORM_CASES = (  # rows, channel shape, group, the gate, gate first
+    (16384, (32, 128), 128, "silu", False),
+    (8192, (32, 128), 128, "sigmoid", False),
+    (4096, (64, 64), 4096, "silu", True))
+
+
+def _norm_case(S, ch, group, act, first):
+    """One JSON line, as `_conv_case`'s: both bodies' milliseconds a call
+    and each body's results on bfloat16 operands against the XLA body on
+    float32 ones."""
+    C = math.prod(ch)
+    flat, full = (1, S, C), (1, S) + ch
+    ks = jax.random.split(jax.random.key(S + group), 4)
+    many = lambda k, scale=1.0: [
+        (jax.random.normal(k, flat) * scale).astype(jnp.bfloat16)
+        for k in jax.random.split(k, CALLS)]
+    ys, gs, dos = many(ks[0]), many(ks[1], 2.0), many(ks[2])
+    w = 1.0 + 0.1 * jax.random.normal(ks[3], ch[-1:] if ch[-1] == group
+                                      else ch)
+    kw = dict(group=group, gate_act=act, gate_first=first, eps=1e-6)
+    out = {"case": "norm", "rows": S, "channels": list(ch), **kw,
+           "block": [kda._CONV_ROWS, kda._norm_blocks(
+               jax.ShapeDtypeStruct(flat, jnp.bfloat16), group,
+               kda._CONV_ROWS[0])[:2]]}
+
+    def pair(body, y, g, w, do):  # operands as the core and a product
+        o, vjp = jax.vjp(lambda y, g, w: body(  # write them: [1, S, C]
+            y.reshape(full), g.reshape(full), w, **kw).reshape(flat), y, g, w)
+        return (o,) + vjp(do)
+
+    f32 = lambda a: a.astype(jnp.float32)
+    ref, got, was = (
+        jax.jit(functools.partial(pair, body))(cast(ys[0]), cast(gs[0]), w,
+                                               cast(dos[0]))
+        for body, cast in ((kda.gated_norm_xla, f32),
+                           (kda.gated_norm_pallas, lambda a: a),
+                           (kda.gated_norm_xla, lambda a: a)))
+    worst = lambda a, r: float(jnp.abs(f32(a) - r).max() / jnp.abs(r).max())
+    for n, r, g, o in zip(("out", "dy", "dgate", "dw"), ref, got, was):
+        out["max_" + n], out["max_" + n + "_xla"] = worst(g, r), worst(o, r)
+    for name, body in (("xla", kda.gated_norm_xla),
+                       ("pallas", kda.gated_norm_pallas)):
+        fwd = jax.jit(lambda ys, gs, w: [
+            body(y.reshape(full), g.reshape(full), w, **kw).reshape(flat)
+            for y, g in zip(ys, gs)])
+        both = jax.jit(lambda ys, gs, w, dos: [  # the result too: a forward
+            pair(body, y, g, w, do)              # nobody reads is not run
+            for y, g, do in zip(ys, gs, dos)])
+        out[name + "_fwd_ms"] = _ms(fwd, (ys, gs, w)) / CALLS
+        out[name + "_fwd_bwd_ms"] = _ms(both, (ys, gs, w, dos)) / CALLS
+    print(json.dumps(out), flush=True)
+    return out
+
+
+def norm():
+    """The stop rule of PR 59: the pair's forward + backward under half of
+    XLA's at `_gdn_mixer`'s call."""
+    first = [_norm_case(*c) for c in NORM_CASES][0]
+    return first["pallas_fwd_bwd_ms"] < 0.5 * first["xla_fwd_bwd_ms"]
+
+
 def main(argv):
     require_tpu()
     enable_compile_cache()
     if argv and argv[0] == "conv":
         return 0 if conv(argv[1:] == ["sweep"]) else 1
+    if argv and argv[0] == "norm":
+        return 0 if norm() else 1
     args, wo = _inputs()
     ok = True
     if not argv or argv[0] == "check":

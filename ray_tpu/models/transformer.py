@@ -1191,7 +1191,7 @@ def _mlp_block(cfg: TransformerConfig, ffn: str, h: jax.Array,
 
 
 def _kda_mixer(cfg, kind, h, layer, positions, overlap):
-    from ray_tpu.ops.kda import kda_chunked, mixer_conv
+    from ray_tpu.ops.kda import gated_norm, kda_chunked, mixer_conv
 
     f32 = jnp.float32
 
@@ -1211,8 +1211,9 @@ def _kda_mixer(cfg, kind, h, layer, positions, overlap):
         "bsd,dn->bsn", h, _w(layer, "kda_wb", cfg)).astype(f32))
     with jax.named_scope("kda.core"):
         o, _ = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
-    o = _norm(o, layer["kda_o_norm"], None, "rmsnorm", cfg.norm_eps)
-    o = o * jax.nn.sigmoid(low_rank("g"))
+    o = gated_norm(o, low_rank("g"), layer["kda_o_norm"], group=o.shape[-1],
+                   gate_act="sigmoid", gate_first=False, eps=cfg.norm_eps,
+                   scope="kda")
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "kda_wo", cfg)), None, None
 
 
@@ -1221,7 +1222,7 @@ def _gdn_mixer(cfg, kind, h, layer, positions, overlap):
     head, `gdn_k_heads` key heads serving `gdn_v_heads` value heads. The
     output's norm is over a head's columns with a plain weight (never
     zero-centred), then times SiLU(z)."""
-    from ray_tpu.ops.kda import kda_chunked, mixer_conv
+    from ray_tpu.ops.kda import gated_norm, kda_chunked, mixer_conv
 
     f32 = jnp.float32
 
@@ -1242,18 +1243,17 @@ def _gdn_mixer(cfg, kind, h, layer, positions, overlap):
         ba[:, :, 1].astype(f32) + layer["gdn_dt_bias"].astype(f32))
     with jax.named_scope("gdn.core"):
         o, _ = kda_chunked(q, k, v, g, beta, chunk=cfg.gdn_chunk)
-    o = _norm(o.astype(f32), layer["gdn_o_norm"], None, "rmsnorm",
-              cfg.norm_eps)
-    o = (o * jax.nn.silu(z.astype(f32))).astype(h.dtype)
+    o = gated_norm(o, z, layer["gdn_o_norm"], group=o.shape[-1],
+                   gate_act="silu", gate_first=False, eps=cfg.norm_eps,
+                   scope="gdn")
     return jnp.einsum("bsnh,nhd->bsd", o, _w(layer, "gdn_wo", cfg)), None, None
 
 
 def _mamba_mixer(cfg, kind, h, layer, positions, overlap):
-    from ray_tpu.ops.kda import mixer_conv
+    from ray_tpu.ops.kda import gated_norm, mixer_conv
     from ray_tpu.ops.ssd import ssd_chunked
 
     f32 = jnp.float32
-    B, S, _ = h.shape
     # z, then x, each whole (`_gdn_mixer`'s note)
     z, x = jnp.einsum("bsd,dcnp->cbsnp", h, _w(layer, "mamba_wzx", cfg))
     bc = jnp.einsum("bsd,dcgn->bscgn", h, _w(layer, "mamba_wbc", cfg))
@@ -1271,10 +1271,10 @@ def _mamba_mixer(cfg, kind, h, layer, positions, overlap):
                            chunk=cfg.mamba_chunk)
     # The gated norm: times silu(z) first, then RMSNorm over a group's
     # channels (all of them where there is one group), float32.
-    G = cfg.mamba_groups
-    y = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(B, S, G, -1)
-    y = _norm(y, layer["mamba_norm"].reshape(G, -1), None, "rmsnorm",
-              cfg.norm_eps).reshape(z.shape).astype(h.dtype)
+    y = gated_norm(y, z, layer["mamba_norm"],
+                   group=layer["mamba_norm"].size // cfg.mamba_groups,
+                   gate_act="silu", gate_first=True, eps=cfg.norm_eps,
+                   scope="mamba")
     return (jnp.einsum("bsnp,npd->bsd", y, _w(layer, "mamba_wo", cfg)),
             None, None)
 

@@ -6,21 +6,23 @@ the only code in `ray_tpu/ops` and `ray_tpu/models` that reads the platform
 or the sharding context to choose, interpret or refuse a kernel. A dispatcher
 is `s = dispatch.site()`, its rule, `dispatch.observe(...)`, the body.
 
-family      its rule                     phase-table row  under a mesh
-----------  ---------------------------  ---------------  ------------------
-flash       flash_attention.use_kernels  flash.plan (1)   `shard_map` (2)
-kda         kda.use_kernels              kda.core.*       XLA body
-gdn         kda.use_kernels              gdn.core.*       XLA body
-mixer.conv  kda.use_conv_kernels         mixer.conv.*     XLA body
-ssd         ssd.use_kernels              ssd.core.*       XLA body
-moe         moe.use_kernels              none (3)         `lax.ragged_dot`
-dsa         none: Pallas everywhere      dsa.plan         refuses (4)
-mamba1      selective_scan.use_kernels   mamba1.core.*    refuses (4)
+family            its rule                     phase-table row     under a mesh
+----------------  ---------------------------  ------------------  ----------------
+flash             flash_attention.use_kernels  flash.plan (1)      `shard_map` (2)
+kda               kda.use_kernels              kda.core.*          XLA body
+gdn               kda.use_kernels              gdn.core.*          XLA body
+mixer.conv        kda.use_conv_kernels         mixer.conv.*        XLA body
+mixer.gated_norm  kda.use_norm_kernels         mixer.gated_norm.*  XLA body
+ssd               ssd.use_kernels              ssd.core.*          XLA body
+moe               moe.use_kernels              none (3)            `lax.ragged_dot`
+dsa               none: Pallas everywhere      dsa.plan            refuses (4)
+mamba1            selective_scan.use_kernels   mamba1.core.*       refuses (4)
 
 A rule's own terms, beside `mosaic`: flash none (`tile_sizes` fits any
 shape); kda and gdn (the scalar-decay call) keys and values of whole lane
-tiles and a chunk of 128; mixer.conv channels whole, a norm over 128; ssd
-chunk and state whole, heads that fill 128 lanes within a group, states
+tiles and a chunk of 128; mixer.conv channels whole, a norm over 128;
+mixer.gated_norm channels whole, a group of whole tiles that divides them;
+ssd chunk and state whole, heads that fill 128 lanes within a group, states
 within `_STATE_BYTES`; moe 2-byte operands and widths whole; dsa on a TPU an S
 of whole 4,096s (`sparse_attention`); mamba1 channels in blocks of 1,024, a
 state of 128 at most, a chunk of whole sublanes. (1) and `flash.plan.bwd_*`,

@@ -949,11 +949,12 @@ _L2 = 128           # the norm's group: a head's columns, one vreg row
 _L2_EPS = 1e-6      # `l2_normalize`'s
 
 
-def _each_piece(bs, bc, body, last_first=False):
+def _each_piece(bs, bc, body, last_first=False, work=None):
     """`body(first row, lane slice)` for a block's pieces of `_CONV_CHUNK`
-    rows by `_CONV_WORK` lanes: the rows in a loop on the device (a traced
-    body a lane slice, not one a piece: a kernel's trace is set-up time),
-    first to last or last to first."""
+    rows by `work` (`_CONV_WORK`) lanes: the rows in a loop on the device (a
+    traced body a lane slice, not one a piece: a kernel's trace is set-up
+    time), first to last or last to first."""
+    work = work or _CONV_WORK
     n = bs // _CONV_CHUNK
     u = _CONV_UNROLL if n % _CONV_UNROLL == 0 else 1
 
@@ -963,8 +964,8 @@ def _each_piece(bs, bc, body, last_first=False):
             base = pl.multiple_of(
                 ((n - 1 - at) if last_first else at) * _CONV_CHUNK,
                 _CONV_CHUNK)
-            for c in range(0, bc, _CONV_WORK):
-                body(base, pl.ds(c, min(_CONV_WORK, bc)))
+            for c in range(0, bc, work):
+                body(base, pl.ds(c, min(work, bc)))
         return _
 
     jax.lax.fori_loop(0, n // u, rows, None)
@@ -998,16 +999,22 @@ def _silu(c):
     return h + h * th, 0.5 + 0.5 * th
 
 
-def _by_group(f, *arrays):
-    """f over every `_L2`-lane group of [rows, lanes] arrays: what a group's
-    row sum [rows, 1] takes part in."""
+def _by_group(f, *arrays, group=_L2):
+    """f over every `group`-lane group of [rows, lanes] arrays: what a
+    group's row sum [rows, 1] takes part in."""
     return jnp.concatenate(
-        [f(*(a[:, g:g + _L2] for a in arrays))
-         for g in range(0, arrays[0].shape[1], _L2)], axis=-1)
+        [f(*(a[:, g:g + group] for a in arrays))
+         for g in range(0, arrays[0].shape[1], group)], axis=-1)
 
 
 def _row_sum(a):
     return jnp.sum(a, -1, keepdims=True)
+
+
+def _fold8(a):
+    """A row chunk's [`_CONV_CHUNK`, lanes] onto eight sublanes: what a
+    weight's gradient adds to its float32 scratch a piece."""
+    return _tree_sum([a[r:r + 8] for r in range(0, _CONV_CHUNK, 8)])
 
 
 def _widen(x_ref, halo_ref, xe_ref, n, rows_left):
@@ -1053,9 +1060,6 @@ def _conv_bwd_kernel(x_ref, halo_ref, w_ref, *rest, K, l2, bias, S):
     def _():
         acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
 
-    fold = lambda a: _tree_sum([a[r:r + 8]
-                                for r in range(0, _CONV_CHUNK, 8)])
-
     def norm_bwd(s, dy):  # y = s r, r = (sum s^2 + eps)^-1/2
         r = jax.lax.rsqrt(_row_sum(s * s) + _L2_EPS)
         y = s * r
@@ -1073,8 +1077,8 @@ def _conv_bwd_kernel(x_ref, halo_ref, w_ref, *rest, K, l2, bias, S):
             dc = jnp.where(row < S, dc, 0.0)
         dc_ref[pl.ds(base, _CONV_CHUNK), cs] = dc
         for j in range(K):
-            acc_ref[j, :, cs] += fold(dc * taps[j])
-        acc_ref[K, :, cs] += fold(dc)
+            acc_ref[j, :, cs] += _fold8(dc * taps[j])
+        acc_ref[K, :, cs] += _fold8(dc)
         dx = _tree_sum([  # dx_t = sum_j w[j] dc_{t+K-1-j}
             _rows_at(dc_ref, base, cs, K - 1 - j)
             * w_ref[j:j + 1, cs].astype(_F32) for j in range(K)])
@@ -1226,6 +1230,323 @@ def mixer_conv(x, w, b=None, *, l2: bool = False,
     if kernels:
         return mixer_conv_pallas(x, w, b, l2=l2, scope=scope)
     return mixer_conv_xla(x, w, b, l2=l2)
+
+
+# ------------------------------------------------- the mixers' gated norm
+#
+# out = RMSNorm_group(y) * w * act(gate)   (gate after: GDN, KDA)   or
+# out = RMSNorm_group(y * act(gate)) * w   (gate first: Mamba-2)
+#
+# as one forward and one backward Pallas kernel under a `jax.custom_vjp`, over
+# y, gate [B, S, C] row-major: the layout the cores' kernels write and the
+# output projection reads, so that between a core and its projection there is
+# one device op and no float32 [B, S, heads, 128] tensor, which XLA lays out
+# its own way and copies (PERF.md section 6, PR 59). A block is
+# `_norm_blocks`'s rows by whole groups of lanes (all C where the group is C);
+# it is worked `_CONV_CHUNK` rows at a time in a device loop, a row chunk's
+# lanes in parts of `_CONV_WORK` at most: a part holds whole groups (the
+# statistic by `_by_group`), or a group whole parts (two passes over the row
+# chunk: the sums, added part by part, then the results, each part read again
+# from VMEM). The arithmetic is float32 (the mean of squares and rsqrt, the
+# logistic as `_silu` has it) with ONE rounding to y's dtype at the store. The
+# backward recomputes the statistic from y, gate, w (the only residuals:
+# nothing is named in `RESIDUAL_NAMES`) and accumulates dw in float32 scratch
+# across a column block's batches and row blocks, written once as eight
+# sublanes' partial sums [8, C] (summed, and folded over the heads that share a
+# weight, outside).
+# At [1,16384,32,128] bfloat16 (SiLU after the norm) the forward takes 0.79 ms
+# and forward + backward 1.91 (402 and 671 MB: 509 and 520 GB/s of 819); XLA's
+# formulation of the same three lines 4.13 and 10.47; at [1,8192,32,128] (a
+# sigmoid) 0.48 and 1.12 against 2.15 and 5.10; at [1,4096,4096] as one group
+# (SiLU first) 0.30 and 0.69 against 0.38 and 0.79 (the two passes are the
+# vector unit's); largest error over the float32 reference's largest value
+# 0.0030 in the result and 0.0027 / 0.0020 in dy / dgate, XLA's body on the
+# same operands alike (benchmarks/probe_kda.py norm; PERF.md section 6, PR 59).
+
+_GATES = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}
+
+
+def _gate(act, c):
+    """(act(c), act'(c)) float32 for "silu" or "sigmoid"."""
+    s, sig = _silu(c)
+    if act == "silu":
+        return s, sig * (1.0 + c * (1.0 - sig))
+    return sig, sig * (1.0 - sig)
+
+
+def _norm_pieces(bs, bc, group, body):
+    """`body(rows, [lane slices of one span])` for a block's row chunks, in
+    `_each_piece`'s loop. A part is the widest of `_CONV_COLS` (at most
+    `_CONV_WORK`) that holds whole groups of the block's `bc` lanes or that
+    a group holds whole; a span is a part, or the group's parts."""
+    part = next(c for c in _CONV_COLS if c <= _CONV_WORK and (
+        (c % group == 0 and bc % c == 0) or group % c == 0))
+    span = max(part, group)
+
+    def piece(base, cs):
+        body(pl.ds(base, _CONV_CHUNK),
+             [pl.ds(cs.start + c, part) for c in range(0, span, part)])
+
+    _each_piece(bs, bc, piece, work=span)
+
+
+def _span_terms(parts, terms):
+    """`terms(part)` as the two passes over a span ask for it: worked once
+    where the span is one part (its values stay in registers), again at
+    every asking where it is several (they would not)."""
+    if len(parts) > 1:
+        return terms
+    held = terms(parts[0])
+    return lambda p: held
+
+
+def _group_sums(parts, products, group):
+    """Every group's row sums of `products(part)` (a tuple of [rows, part]
+    float32 arrays), a part: [rows, part] each where a part holds whole
+    groups; where the span is one group of several parts the products are
+    added part by part (a part's worth of registers a sum) and reduced along
+    the lanes once, [rows, 1] for every part."""
+    if group < parts[0].size:
+        return [tuple(_by_group(lambda g: jnp.broadcast_to(_row_sum(g),
+                                                           g.shape),
+                                v, group=group) for v in products(p))
+                for p in parts]
+    acc = None
+    for p in parts:
+        vs = products(p)
+        acc = vs if acc is None else tuple(a + v for a, v in zip(acc, vs))
+    return [tuple(_row_sum(a) for a in acc)] * len(parts)
+
+
+def _norm_fwd_kernel(y_ref, g_ref, w_ref, o_ref, *, group, act, first, eps):
+    def span(rows, parts):
+        def terms(p):  # what the norm takes, and the gate's value after it
+            y = y_ref[0, rows, p].astype(_F32)
+            a = _gate(act, g_ref[0, rows, p].astype(_F32))[0]
+            return (y * a, None) if first else (y, a)
+
+        terms = _span_terms(parts, terms)
+        sums = _group_sums(parts, lambda p: (terms(p)[0] ** 2,), group)
+        for p, (ss,) in zip(parts, sums):
+            x, a = terms(p)
+            out = x * jax.lax.rsqrt(ss * (1.0 / group) + eps) * w_ref[
+                :, p].astype(_F32)
+            o_ref[0, rows, p] = (out if first else out * a).astype(
+                o_ref.dtype)
+
+    _norm_pieces(*y_ref.shape[1:], group, span)
+
+
+def _norm_bwd_kernel(y_ref, g_ref, w_ref, do_ref, dy_ref, dg_ref, dw_ref,
+                     acc_ref, *, group, act, first, eps, S):
+    bs, bc = y_ref.shape[1:]
+    b, n = pl.program_id(1), pl.program_id(2)
+    ragged = S % bs != 0
+
+    @pl.when((b == 0) & (n == 0))
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+
+    def span(rows, parts):
+        def terms(p):  # x the norm took; t = d(x r); what the gate needs
+            y = y_ref[0, rows, p].astype(_F32)
+            a, da = _gate(act, g_ref[0, rows, p].astype(_F32))
+            do = do_ref[0, rows, p].astype(_F32)
+            w = w_ref[:, p].astype(_F32)
+            if first:
+                return y * a, do * w, (y, a, da, do)
+            return y, do * (w * a), (w, a, da, do)
+
+        terms = _span_terms(parts, terms)
+
+        def products(p):
+            x, t, _ = terms(p)
+            return x * x, x * t
+
+        for p, (ss, tx) in zip(parts, _group_sums(parts, products, group)):
+            x, t, (u, a, da, do) = terms(p)
+            r = jax.lax.rsqrt(ss * (1.0 / group) + eps)
+            # n = x r;  dx = r (t - n mean(t n))
+            dx = r * (t - x * (r * r * (1.0 / group)) * tx)
+            nrm = x * r
+            if first:   # x = y a;  out = n w
+                dy, dg, dw = dx * a, dx * u * da, do * nrm
+            else:       # x = y;    out = n w a
+                dy, dg, dw = dx, do * nrm * (u * da), do * nrm * a
+            dy_ref[0, rows, p] = dy.astype(dy_ref.dtype)
+            dg_ref[0, rows, p] = dg.astype(dg_ref.dtype)
+            if ragged:  # rows past the sequence's end reach no weight
+                row = _iota(dw.shape, 0) + (n * bs + rows.start)
+                dw = jnp.where(row < S, dw, 0.0)
+            acc_ref[:, p] += _fold8(dw)
+
+    _norm_pieces(bs, bc, group, span)
+
+    @pl.when((b == pl.num_programs(1) - 1) & (n == pl.num_programs(2) - 1))
+    def _():
+        dw_ref[...] = acc_ref[...]
+
+
+def _norm_blocks(y, group, rows):
+    """(bs, bc, row blocks): lanes the first of `_CONV_COLS` that holds whole
+    groups, or one group; rows so that a block is `rows` by `_CONV_COLS[0]`
+    elements."""
+    B, S, C = y.shape
+    assert group % _L2 == 0 and C % group == 0, (C, group)
+    bc = next((c for c in _CONV_COLS if C % c == 0 and c % group == 0), group)
+    bs = min(max(rows * _CONV_COLS[0] // bc, _CONV_CHUNK),
+             -(-S // _HALO) * _HALO)
+    return bs, bc, -(-S // bs)
+
+
+@functools.partial(jax.jit, static_argnames=("group", "act", "first", "eps",
+                                             "scope"))
+def _norm_fwd_call(y, gate, w, group, act, first, eps, scope):
+    """y, gate [B, S, C], w [1, C] -> out [B, S, C] in y's dtype."""
+    B, S, C = y.shape
+    bs, bc, N = _norm_blocks(y, group, _CONV_ROWS[0])
+    blk = pl.BlockSpec((1, bs, bc), lambda c, i, n: (i, n, c))
+    with jax.named_scope(scope):
+        return pl.pallas_call(
+            functools.partial(_norm_fwd_kernel, group=group, act=act,
+                              first=first, eps=eps),
+            grid=(C // bc, B, N),
+            in_specs=[blk, blk, pl.BlockSpec((1, bc), lambda c, i, n: (0, c))],
+            out_specs=blk,
+            out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            interpret=dispatch.interpret(),
+        )(y, gate, w)
+
+
+@functools.partial(jax.jit, static_argnames=("group", "act", "first", "eps",
+                                             "scope"))
+def _norm_bwd_call(y, gate, w, do, group, act, first, eps, scope):
+    """-> dy, dgate [B, S, C] in their operands' dtypes and dw's eight
+    partial sums, float32 [8, C]."""
+    B, S, C = y.shape
+    bs, bc, N = _norm_blocks(y, group, _CONV_ROWS[1])
+    blk = pl.BlockSpec((1, bs, bc), lambda c, i, n: (i, n, c))
+    col = lambda r: pl.BlockSpec((r, bc), lambda c, i, n: (0, c))
+    with jax.named_scope(scope):
+        return pl.pallas_call(
+            functools.partial(_norm_bwd_kernel, group=group, act=act,
+                              first=first, eps=eps, S=S),
+            grid=(C // bc, B, N),
+            in_specs=[blk, blk, col(1), blk],
+            out_specs=[blk, blk, col(8)],
+            out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
+                       jax.ShapeDtypeStruct(gate.shape, gate.dtype),
+                       jax.ShapeDtypeStruct((8, C), _F32)],
+            scratch_shapes=[pltpu.VMEM((8, bc), _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            interpret=dispatch.interpret(),
+        )(y, gate, w, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _norm_kernels(y, gate, w, group, act, first, eps, scope):
+    return _norm_fwd_call(y, gate, w, group, act, first, eps, scope)
+
+
+def _norm_vjp_fwd(y, gate, w, group, act, first, eps, scope):
+    return _norm_fwd_call(y, gate, w, group, act, first, eps, scope), (
+        y, gate, w)
+
+
+def _norm_vjp_bwd(group, act, first, eps, scope, res, do):
+    dy, dg, dw = _norm_bwd_call(*res, do, group, act, first, eps, scope)
+    return dy, dg, jnp.sum(dw, 0, keepdims=True).astype(res[2].dtype)
+
+
+_norm_kernels.defvjp(_norm_vjp_fwd, _norm_vjp_bwd)
+
+
+def _by_groups(w, group):
+    """A weight a group shares ([group]) as it is, a weight a channel (any
+    shape) as [groups, group]: either broadcasts against [..., groups,
+    group]."""
+    return w if w.shape == (group,) else w.reshape(-1, group)
+
+
+def gated_norm_pallas(y, gate, w, *, group: int, gate_act: str,
+                      gate_first: bool, eps: float,
+                      scope: str = "mixer.gated_norm") -> jax.Array:
+    """`gated_norm`'s kernel pair (the comment above): y, gate [B, S, ...ch],
+    w broadcastable to [..., group] against the channels cut into groups (a
+    weight a head is repeated over the heads here, so that its gradient is
+    summed over them by autodiff). The dispatcher comes here on the TPU;
+    tests come here directly and run the kernels in interpret mode."""
+    B, S = y.shape[:2]
+    C = math.prod(y.shape[2:])
+    wc = jnp.broadcast_to(_by_groups(w, group), (C // group, group))
+    out = _norm_kernels(y.reshape(B, S, C), gate.reshape(B, S, C),
+                        wc.reshape(1, C), group, gate_act, gate_first, eps,
+                        scope)
+    return out.reshape(y.shape)
+
+
+def gated_norm_xla(y, gate, w, *, group: int, gate_act: str,
+                   gate_first: bool, eps: float) -> jax.Array:
+    """The same function in plain XLA, as the three mixers wrote it out
+    before the kernels, op for op (a preset's CPU program is the one it was:
+    tests/test_model_table.py): the fallback, and the kernels' reference in
+    the tests. Float32 inside and one rounding to y's dtype, but for the
+    "sigmoid" gate after the norm (KDA's): its mixer rounded the norm to the
+    model's dtype and gated there, and so does this."""
+    dt, act = y.dtype, _GATES[gate_act]
+    grouped = lambda a: a.reshape(a.shape[:2] + (-1, group))
+
+    def norm(x):  # float32 over a group's channels
+        wg = _by_groups(w, group)
+        x2 = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(x2 + eps) * wg.astype(_F32)
+
+    if gate_first:
+        x = grouped(y.astype(_F32) * act(gate.astype(_F32)))
+        return norm(x).reshape(y.shape).astype(dt)
+    out = norm(grouped(y.astype(_F32)))
+    if gate_act == "sigmoid":
+        return (out.astype(dt) * grouped(act(gate))).reshape(y.shape)
+    return (out * grouped(act(gate.astype(_F32)))).astype(dt).reshape(y.shape)
+
+
+def use_norm_kernels(platform: str, channels: int, group: int,
+                     on_mesh: bool) -> bool:
+    """The gated norm's dispatch rule, a pure function of what the code
+    observes: the kernels where a Mosaic call can run (`dispatch.mosaic`),
+    with the channels whole 128-lane tiles and the group a multiple of 128
+    that divides them."""
+    return (dispatch.mosaic(platform, on_mesh)
+            and dispatch.whole(channels, group, of=_L2)
+            and channels % group == 0)
+
+
+def gated_norm(y, gate, w, *, group: int, gate_act: str, gate_first: bool,
+               eps: Optional[float],
+               scope: str = "mixer.gated_norm") -> jax.Array:
+    """A recurrent mixer's way from its core to its output projection: an
+    RMSNorm over every `group` channels of y [B, S, ...ch] times `w`, and
+    `gate_act` ("silu", "sigmoid") of `gate` times the result, or times y
+    before the norm (`gate_first`); what a caller passes is what its layer
+    kind IS (`eps` None: an RMSNorm's 1e-6). The Pallas pair where
+    `use_norm_kernels` says so, under the device scope `scope` (the caller's
+    mixer, never its core), else `gated_norm_xla`. Each traced call counts
+    once in the phase table as `mixer.gated_norm.pallas` or
+    `mixer.gated_norm.xla` with what it observed."""
+    s = dispatch.site()
+    channels = math.prod(y.shape[2:])
+    kernels = use_norm_kernels(s.platform, channels, group, s.on_mesh)
+    dispatch.observe("mixer.gated_norm", kernels, rows=y.shape[1],
+                     channels=channels, group=group, gate=gate_act,
+                     gate_first=gate_first)
+    kw = dict(group=group, gate_act=gate_act, gate_first=gate_first,
+              eps=1e-6 if eps is None else eps)
+    if kernels:
+        return gated_norm_pallas(y, gate, w, scope=scope, **kw)
+    return gated_norm_xla(y, gate, w, **kw)
 
 
 # ---------------------------------------------------------------- dispatch

@@ -247,7 +247,9 @@ FURTHER_WINDOW_SHARE = 0.5
 # and runs no product a second time. The third is the window's rows in token
 # order and their tokens (two [W] int32, `token_order`), made where the
 # window's sums are the grouped product: the backward's dx takes its rows
-# through the same order.
+# through the same order; since PR 59 also the routing the windows go by
+# (the sorted list, the runs' ends, the assignments' weights), so that the
+# backward works the rows the forward filled.
 RESIDUAL_NAMES = ("moe_gate_up", "moe_down", "moe_token_order")
 
 
@@ -508,7 +510,19 @@ def moe_ffn_held(
         order = jnp.pad(jnp.argsort(local, stable=True),
                         (0, W + n_further * W2 - T * k))
         wflat = wts.reshape(T * k)
-        trips = _trips(held, W, W2, n_further)
+        # The windows and their transposes go by ONE routing: the sorted
+        # list, the runs' ends and the weights carry the token order's name,
+        # so that a remat policy keeps them beside the products' outputs
+        # (`RESIDUAL_NAMES`). Formed again in a layer's recomputation they
+        # can differ from the forward's by a token at a tie (XLA fuses the
+        # recomputed layer its own way, and a rounding apart in x is another
+        # expert), and every row behind that token then meets another row's
+        # kept output: the layer's expert and router gradients came out
+        # uncorrelated with the reference's on the chip (relative error 1.41
+        # and 1.2, 3 seeds in 40; PERF.md section 6, PR 59).
+        order, ends, wflat = (checkpoint_name(a, RESIDUAL_NAMES[2])
+                              for a in (order, ends, wflat))
+        trips = _trips(ends[-1], W, W2, n_further)
     w1 = w_gate_up.reshape(Eh, d, 2 * F).astype(dtype)
     w2 = w_down.astype(dtype)
     f32 = lambda a: a.astype(jnp.float32)

@@ -56,6 +56,13 @@ def _conv(mp):  # the same cell's q | k: four taps, the norm over 128
                           sd((4, 32, 128), F32), l2=True)
 
 
+def _norm(mp):  # the same cell's way out of the core: SiLU(z) after the norm
+    _stub(mp, kda, gated_norm_pallas="pallas", gated_norm_xla="xla")
+    o = sd((1, 16384, 32, 128), BF16)
+    return kda.gated_norm(o, o, sd((128,), F32), group=128, gate_act="silu",
+                          gate_first=False, eps=1e-6)
+
+
 def _ssd(mp):  # granite_4_0_h_micro.train_stage_4k: 64 heads of 64, state 128
     _stub(mp, ssd, ssd_chunked_pallas="pallas", ssd_chunked_xla="xla")
     x, bc = sd((1, 4096, 64, 64), BF16), sd((1, 4096, 1, 128), BF16)
@@ -89,6 +96,8 @@ TABLE = {
     "kda": (_kda, "kda.core", "xla", "pallas", "xla", "xla"),
     "gdn": (_gdn, "gdn.core", "xla", "pallas", "xla", "xla"),
     "mixer.conv": (_conv, "mixer.conv", "xla", "pallas", "xla", "xla"),
+    "mixer.gated_norm": (_norm, "mixer.gated_norm", "xla", "pallas", "xla",
+                         "xla"),
     "ssd": (_ssd, "ssd.core", "xla", "pallas", "xla", "xla"),
     "moe": (_moe, None, "xla", "pallas", "xla", "xla"),
     "dsa": (_dsa, None, "interpreted", "pallas", REFUSES, REFUSES),

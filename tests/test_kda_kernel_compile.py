@@ -37,7 +37,7 @@ def compiled_not_interpreted(monkeypatch):
     # the other mode.
     forget = lambda: [f.clear_cache() for f in (
         ssd._ssd_fwd_call, ssd._ssd_bwd_call, kda._conv_fwd_call,
-        kda._conv_bwd_call)]
+        kda._conv_bwd_call, kda._norm_fwd_call, kda._norm_bwd_call)]
     forget()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -138,6 +138,38 @@ def test_mixer_conv_kernels_compile_for_v5e(one_chip,
         q, wq, x, wx, sd((64, 64), jnp.float32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert not re.search(r"%copy[.\d]* = bf16\[1,(8192|4096),", text)
+
+
+@pytest.mark.parametrize("S,ch,group,act,first", [
+    (16384, (32, 128), 128, "silu", False),    # qwen3_next train_rank16_16k
+    (8192, (32, 128), 128, "sigmoid", False),  # kimi_linear train_share_8k
+    (4096, (64, 64), 4096, "silu", True)])     # granite train_stage_4k
+def test_gated_norm_kernels_compile_for_v5e(one_chip,
+                                            compiled_not_interpreted, S, ch,
+                                            group, act, first):
+    """The gated norm's pair (ops/kda.py `gated_norm_pallas`) alone in one
+    gradient program at the three cells' calls, bfloat16: a norm a head of
+    128 times SiLU(z) or a sigmoid, and SiLU(z) times y under one norm over
+    4,096 channels (a block all of them, a row chunk in two passes). A
+    forward and a backward Mosaic call, which read y and the gate [B,S,C]
+    where the core and a projection's product write them: no copy of their
+    size and nothing float32 of it anywhere in the program."""
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    C = ch[0] * ch[1]
+    y = sd((1, S, C), jnp.bfloat16)
+    w = sd(ch[-1:] if ch[-1] == group else ch, jnp.float32)
+
+    def pair(y, gate, w, do):
+        out, vjp = jax.vjp(lambda y, gate, w: kda.gated_norm_pallas(
+            y.reshape((1, S) + ch), gate.reshape((1, S) + ch), w, group=group,
+            gate_act=act, gate_first=first, eps=1e-6).reshape(1, S, C),
+                           y, gate, w)
+        return (out,) + vjp(do)
+
+    text = jax.jit(pair).lower(y, y, w, y).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(rf"%copy[.\d]* = \w+\[1,{S},", text)
+    assert f"f32[1,{S},{C}]" not in text and f"f32[1,{S},{ch[0]}," not in text
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])

@@ -51,7 +51,17 @@ REMAT = ("off", "dots", "full")
 # as `mixer_conv`'s kernels and the cores read it (on the CPU the convolution
 # path is the XLA body, op for op the parent's: kimi_linear's digests, whose
 # projections were apart already, did not move); their scope digests and
-# every other preset's row are the parent's.
+# every other preset's row are the parent's. PR 59 (`gated_norm`: on the CPU
+# the XLA body, each mixer's former expression op for op) moved no digest of
+# granite_hybrid or qwen3_next and kimi_linear's text alone (its row). The same
+# PR re-recorded the TEXT digests of the six presets with held experts
+# (kimi_linear, mellum2, kanana2, qwen3_next, laguna, keye_vl2; scope digests
+# unmoved): `moe_ffn_held` names the routing its windows go by (the sorted
+# list, the runs' ends, the weights) with the token order, so that a remat
+# policy keeps it beside the products' outputs and the backward works the
+# rows the forward filled (ops/moe.py; on the chip a recomputed routing
+# differed from the forward's at a tie and a layer's expert gradients came
+# out wrong, PERF.md section 6, PR 59).
 PARENT = {
     "llama_tiny": ("477b60d37afe307a:1204d8d39a7b453e",
                    "fb0a0ec778730463:1204d8d39a7b453e",
@@ -62,26 +72,33 @@ PARENT = {
     "moe_tiny": ("e7db6dd180684ee6:846b7814e5778ffa",
                  "b1a0b14f2dbc5ccf:846b7814e5778ffa",
                  "1a06e30aefde286e:846b7814e5778ffa"),
-    "kimi_linear_tiny": ("1351b6f8a51ed658:0dec5428f5393512",
-                         "595571e2024d4fe8:690c4963985676f7",
-                         "0a701feee3e50793:690c4963985676f7"),
+    # text re-recorded in PR 59 (beside the routing's names, above: the same
+    # operations in another order: the
+    # output gate's two low-rank products are an ARGUMENT of `gated_norm`,
+    # so they are traced before the norm's lines where `_kda_mixer` wrote
+    # them after; the lines of the text, sorted, SSA names erased, are the
+    # parent's under "dots" and "full", and under "off" but for the order of
+    # one call's residuals; the scope digests unmoved)
+    "kimi_linear_tiny": ("9ca3d68216127708:0dec5428f5393512",
+                         "9d798a2c59dfb7c8:690c4963985676f7",
+                         "4bded50f75fe4ee1:690c4963985676f7"),
     "granite_hybrid_tiny": ("9ed9d48b47d5f78c:1aa0ff837d278860",
                             "67c1cff2033e2b05:9ce01d30262a7b2d",
                             "7276f6af5ccbf46a:9ce01d30262a7b2d"),
-    "mellum2_tiny": ("7c8f75ed566a2912:5277c5b9e65d3b63",
-                     "80fc62d0b1aaf755:5277c5b9e65d3b63",
-                     "0738981824137919:5277c5b9e65d3b63"),
-    "kanana2_tiny": ("ad659bdff892a231:593d1eba54411321",
-                     "4ee529c508a5e1e7:593d1eba54411321",
-                     "22aac6f201ad571f:593d1eba54411321"),
-    "qwen3_next_tiny": ("28208d1e66f6925c:4d7d9eca8c599952",
-                        "1dc2fad0f258ad23:79df8da7a94dd775",
-                        "f73571e7b7631809:79df8da7a94dd775"),
+    "mellum2_tiny": ("16cca482cb4d30a5:5277c5b9e65d3b63",
+                     "5d5695e03fafcb7d:5277c5b9e65d3b63",
+                     "856f8c11546a3402:5277c5b9e65d3b63"),
+    "kanana2_tiny": ("e18ccae8390ca03d:593d1eba54411321",
+                     "595b844a53fbbca8:593d1eba54411321",
+                     "de94b9f7f5ca2179:593d1eba54411321"),
+    "qwen3_next_tiny": ("7000882c6b79b320:4d7d9eca8c599952",
+                        "20afe83c15dcbde8:79df8da7a94dd775",
+                        "87dbccbb63dba44c:79df8da7a94dd775"),
     # new in PR 45 (its own tree's: the `swa` kind with its own heads, theta
     # and rotated share, the gate a head); the rows above are the parent's
-    "laguna_tiny": ("f5f7f7b0405c9cb0:99e87d210fd8ce25",
-                    "a6b430c735907222:99e87d210fd8ce25",
-                    "c2fd39ee880340f2:99e87d210fd8ce25"),
+    "laguna_tiny": ("b158847aa832b0e1:99e87d210fd8ce25",
+                    "fb286a5009c7c5fb:99e87d210fd8ce25",
+                    "d4f5700cb43107e8:99e87d210fd8ce25"),
     # new in PR 49 (its own tree's: four passes over two layers, the
     # post-norms, a head and an exit gate a pass under `loop.head`); every
     # row above is the parent's
@@ -91,9 +108,9 @@ PARENT = {
     # PR 54's own (the "dsa" kind's kernels interpreted); re-recorded in PR
     # 55 (its own tree's: the selection's adaptive search in the text, its
     # two counters under `dsa`; every other preset's program unmoved)
-    "keye_vl2_tiny": ("9f2bc330858adc68:d02a27497dfe0501",
-                      "7c1ef0dd5424e566:d02a27497dfe0501",
-                      "f9028fe15fc1874e:d02a27497dfe0501"),
+    "keye_vl2_tiny": ("ccc613e59ce2bca9:d02a27497dfe0501",
+                      "4e8b7153711fb0aa:d02a27497dfe0501",
+                      "0e6b36960d796f29:d02a27497dfe0501"),
     # new in PR 56 (its own tree's: the `mamba1`, `gmu` and `xattn` kinds,
     # differential attention under `diffattn`, what two layers hand on in
     # the stack's carry); every row above is the parent's: a stack without

@@ -252,7 +252,8 @@ def test_remat_full_keeps_what_a_layers_kernels_name(
     kernel's forward rule names, so the backward re-runs no such kernel. An
     `attn` / `swa` / `mla` layer keeps o [B,H,S,hd] and lse [B,H,S]; a `kda`
     / `gdn` layer the three `kda_*` residuals, a `mamba2` layer the two
-    `ssd_*`, a layer with held experts two `moe_*` beside its mixer's;
+    `ssd_*`, a layer with held experts two `moe_*` and its routing beside its
+    mixer's;
     no layer keeps a dot's output (what still makes "full" the small
     policy), and "dots" keeps strictly more."""
     from ray_tpu.ops import flash_attention, kda, moe, ssd
@@ -278,11 +279,18 @@ def test_remat_full_keeps_what_a_layers_kernels_name(
         assert not [why for _, _, why in saved if "dot_general" in why]
         for n in ops:
             rows = [r for r in saved if made_in(r[2]) == {n}]
-            # (the experts' third, the window's token order, is made where
-            # the grouped products are kernels: not here)
+            # (the experts' third names the window's token order, made
+            # where the grouped products are kernels, not here, and since
+            # PR 59 the routing the windows go by: the sorted list, the
+            # runs' ends, the weights, kept so that the backward works the
+            # rows the forward filled)
             names = ops[n].RESIDUAL_NAMES[:2 if n == "moe" else None]
-            assert len(rows) == (len(names) if n in want else 0), (
+            routing = 3 if n == "moe" and n in want else 0
+            assert len(rows) == (len(names) + routing if n in want else 0), (
                 kinds[index], n, rows)
+            # (the weights' name is hidden as o's is: the forward uses them)
+            assert len([r for r in rows if f"'{moe.RESIDUAL_NAMES[2]}'"
+                        in r[2] and r[1] == "int32"]) == (2 if routing else 0)
             if n in want:  # the first (o, y) may be the hidden one
                 assert all(any(f"'{name}'" in r[2] for r in rows)
                            for name in names[1:]), rows
