@@ -516,3 +516,80 @@ def phi4_flash_tiny(**overrides) -> TransformerConfig:
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+_LFM2_ATTN = (3, 7, 11, 15, 19, 22)  # published full_attention layers, 1-based
+
+
+def lfm2_8b_a1b(**overrides) -> TransformerConfig:
+    """LiquidAI/LFM2-8B-A1B (`lfm2_moe`) at its published sizes: 24 layers
+    of d 2,048, 18 of them a double-gated short convolution of 3 taps
+    (`shortconv`) and 6 GQA attention at 32 query / 8 key heads of 64 with
+    an RMSNorm over every query and key head before full RoPE at theta
+    1e6; the first 2 layers a SwiGLU of 7,168, the other 22 carry 32
+    sigmoid-routed experts of 1,792, 4 a token by score + selection bias,
+    renormalised, none shared; RMSNorm 1e-5, a tied table of 65,536.
+    chipbench/configs/lfm2_8b_a1b.json holds one expert rank's five-layer
+    stage (docs/model_layers.md)."""
+    kw = dict(
+        vocab_size=65536,
+        d_model=2048,
+        n_layers=24,
+        n_heads=32,
+        n_kv_heads=8,
+        d_ff=7168,
+        max_seq_len=128000,
+        norm="rmsnorm",
+        norm_eps=1e-5,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=1e6,
+        attn_qk_norm=True,
+        tie_embeddings=True,
+        shortconv_layers=tuple(l for l in range(1, 25)
+                               if l not in _LFM2_ATTN),
+        moe_num_experts=32,
+        moe_experts_per_token=4,
+        moe_router="sigmoid",
+        moe_d_ff=1792,
+        moe_first_dense=2,
+        moe_routed_scale=1.0,
+        moe_aux_coef=0.0,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
+def lfm2_moe_tiny(**overrides) -> TransformerConfig:
+    """The LFM2-MoE pattern at widths small enough for CPU tests
+    (docs/model_layers.md), every kind of layer once and the convolution
+    expert layer twice: a `shortconv` layer with the dense SwiGLU, an
+    attention layer (4 query / 2 key heads of 16, q / k norms, full RoPE)
+    with experts, two `shortconv` layers with experts; 8 sigmoid-routed
+    experts, 2 a token, experts 2-5 held here; a tied head."""
+    kw = dict(
+        vocab_size=256,
+        d_model=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=128,
+        max_seq_len=64,
+        norm="rmsnorm",
+        norm_eps=1e-5,
+        activation="swiglu",
+        positional="rope",
+        rope_theta=1e6,
+        attn_qk_norm=True,
+        tie_embeddings=True,
+        shortconv_layers=(1, 3, 4),
+        moe_num_experts=8,
+        moe_experts_per_token=2,
+        moe_router="sigmoid",
+        moe_held=(2, 4),
+        moe_d_ff=32,
+        moe_first_dense=1,
+        moe_aux_coef=0.0,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)

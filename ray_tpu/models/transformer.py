@@ -278,6 +278,12 @@ class TransformerConfig:
     mamba1_conv: int = 4
     mamba1_dt_rank: Optional[int] = None
     mamba1_chunk: int = 128
+    # - shortconv_layers: a double-gated short convolution as the layer's
+    #   whole mixer (ops/shortconv.py): [Bg ; Cg ; x] = h W_in, a causal
+    #   depthwise convolution of three taps (`_SHORTCONV_TAPS`, the family's
+    #   `conv_L_cache`) over Bg * x, times Cg, W_out; d_model channels, no
+    #   activation, no bias.
+    shortconv_layers: Tuple[int, ...] = ()
     # Two properties of the "attn" / "swa" / "xattn" kinds; at the defaults
     # nothing is traced for them and no leaf is made.
     # - diff_attn: differential attention. Query and key heads split even /
@@ -725,6 +731,20 @@ def _gmu_shapes(cfg: TransformerConfig):
     d, Di = cfg.d_model, cfg.mamba1_channels
     return {"gmu_w1": ((d, Di), ("embed", "mlp"), _fan(d)),
             "gmu_w2": ((Di, d), ("mlp", "embed"), _out_std(cfg, Di))}
+
+
+# The one tap count in use (LFM2's `conv_L_cache`): the mixer and the kernels
+# take it from the taps leaf's shape, so a second count is a field the day a
+# configuration needs it.
+_SHORTCONV_TAPS = 3
+
+
+def _shortconv_shapes(cfg: TransformerConfig):
+    # [Bg ; Cg ; x] fused over an array dim of its own, as `w_gate_up` is.
+    d, K = cfg.d_model, _SHORTCONV_TAPS
+    return {"shortconv_win": ((d, 3, d), ("embed", None, "mlp"), _fan(d)),
+            "shortconv_conv": ((K, d), (None, "mlp"), _fan(K)),
+            "shortconv_wout": ((d, d), ("mlp", "embed"), _out_std(cfg, d))}
 
 
 def _dsa_shapes(cfg: TransformerConfig):
@@ -1422,6 +1442,19 @@ def _gmu_mixer(cfg, kind, h, layer, positions, overlap, handed=None):
     return (handed["m"] * gate) @ _w(layer, "gmu_w2", cfg), None, None
 
 
+def _shortconv_mixer(cfg, kind, h, layer, positions, overlap):
+    """A double-gated short convolution: the joint projection's [B,S,3d]
+    output goes to the core as it lies ([Bg ; Cg ; x]: neither it nor the
+    leaf is sliced here, `_gdn_mixer`'s note), and the core's output to
+    W_out. No activation anywhere."""
+    from ray_tpu.ops.shortconv import gated_conv
+
+    w_in = _w(layer, "shortconv_win", cfg)
+    p = h @ w_in.reshape(w_in.shape[0], -1)
+    y = gated_conv(p, layer["shortconv_conv"])
+    return y @ _w(layer, "shortconv_wout", cfg), None, None
+
+
 def _dsa_mixer(cfg, kind, h, layer, positions, overlap):
     """Learned sparse attention -> (delta, k, v, extras): the "attn" layer's
     projections (`_qkv_proj`), the indexer on the detached input, the
@@ -1559,6 +1592,15 @@ MIXERS: Dict[str, Mixer] = {m.name: m for m in (
               "would read the memory an earlier layer made for the same "
               "token, which no cache hands from layer to layer (ROADMAP R22 "
               "(h)); the stack trains but does not serve yet")),
+    # The two projections are matmul parameters (6 a token each, counted
+    # with every other leaf); the taps and the gates are no matmuls.
+    Mixer("shortconv", "shortconv_layers", _shortconv_shapes,
+          _shortconv_mixer, lambda cfg, S: 0.0,
+          scope=lambda cfg: "shortconv", no_decode=(
+              "decode cannot serve a gated short convolution (shortconv) "
+              "layer: a tick would hold the last taps - 1 = 2 rows of "
+              "Bg * x a slot and layer in place of keys and values "
+              "(ROADMAP R7); the stack trains but does not serve yet")),
 )}
 _PLAIN = next(n for n, m in MIXERS.items() if m.layers_field is None)
 

@@ -17,6 +17,7 @@ ssd               ssd.use_kernels              ssd.core.*          XLA body
 moe               moe.use_kernels              none (3)            `lax.ragged_dot`
 dsa               none: Pallas everywhere      dsa.plan            refuses (4)
 mamba1            selective_scan.use_kernels   mamba1.core.*       refuses (4)
+shortconv         shortconv.use_kernels        shortconv.core.*    XLA body
 
 A rule's own terms, beside `mosaic`: flash none (`tile_sizes` fits any
 shape); kda and gdn (the scalar-decay call) keys and values of whole lane
@@ -25,8 +26,9 @@ mixer.gated_norm channels whole, a group of whole tiles that divides them;
 ssd chunk and state whole, heads that fill 128 lanes within a group, states
 within `_STATE_BYTES`; moe 2-byte operands and widths whole; dsa on a TPU an S
 of whole 4,096s (`sparse_attention`); mamba1 channels in blocks of 1,024, a
-state of 128 at most, a chunk of whole sublanes. (1) and `flash.plan.bwd_*`,
-by the call itself, no suffix. (2) a batch / head shard a device
+state of 128 at most, a chunk of whole sublanes; shortconv channels whole.
+(1) and `flash.plan.bwd_*`, by the call itself, no suffix. (2) a batch /
+head shard a device
 (`attention._shard_mapped_attention`; ring or ulysses under a live `seq`
 axis, on any platform). (3) `train.moe_*` are the step's counters. (4)
 `one_chip`: from `_dsa_mixer`, and from `selective_scan`, whose rule

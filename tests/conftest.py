@@ -29,7 +29,7 @@ LONGEST_FILES = (
     "test_ouro", "test_tensor_overlap_rows", "test_moe_held_loop",
     "test_fused_ce", "test_kanana2", "test_replay_buffers",
     "test_serve_disagg", "test_rllib", "test_transfer_fastpath",
-    "test_actors", "test_sambay")
+    "test_actors", "test_lfm2_moe", "test_sambay")
 
 
 def pytest_collection_modifyitems(config, items):

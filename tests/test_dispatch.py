@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.ops import (attention as att, dispatch, flash_attention as fa,
-                         kda, moe, selective_scan as ss, ssd)
+                         kda, moe, selective_scan as ss, shortconv as sc, ssd)
 from ray_tpu.util import tracing
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -88,6 +88,11 @@ def _mamba1(mp):  # phi4_mini_flash_reasoning.train_stage_16k: 5,120 x 16
                              sd((5120,), F32), chunk=256)
 
 
+def _shortconv(mp):  # lfm2_8b_a1b.train_rank4_8k: [Bg ; Cg ; x] of 2,048
+    _stub(mp, sc, gated_conv_pallas="pallas", gated_conv_xla="xla")
+    return sc.gated_conv(sd((4, 8192, 6144), BF16), sd((3, 2048), F32))
+
+
 REFUSES = NotImplementedError
 # family: (its dispatcher, the phase-table row, then what it takes on the
 # CPU, on a TPU, on a TPU under a mesh, on the CPU under a mesh)
@@ -102,6 +107,8 @@ TABLE = {
     "moe": (_moe, None, "xla", "pallas", "xla", "xla"),
     "dsa": (_dsa, None, "interpreted", "pallas", REFUSES, REFUSES),
     "mamba1": (_mamba1, "mamba1.core", "xla", "pallas", REFUSES, REFUSES),
+    "shortconv": (_shortconv, "shortconv.core", "xla", "pallas", "xla",
+                  "xla"),
 }
 SITES = [("cpu", False), ("tpu", False), ("tpu", True), ("cpu", True)]
 

@@ -198,6 +198,11 @@ FAMILIES = {
     "keye_vl2_tiny": dict(weights="weights_keye_vl2", sizes="KeyeSizes",
                           reference="keye_vl2", kind=None, layer=0,
                           experts=16, atol=3e-5),
+    # four shares of two: the four ranks of the cell's layer (the reference
+    # adds 1e-6 to the top-k's sum, the program does not: 5e-7 of a gate)
+    "lfm2_moe_tiny": dict(weights="weights_lfm2_moe", sizes="Lfm2Sizes",
+                          reference="lfm2_moe", kind=("shortconv", "moe"),
+                          layer=2, experts=8, atol=3e-5),
 }
 
 

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import dispatch, flash_attention as fa, kda, ssd
+from ray_tpu.ops import (dispatch, flash_attention as fa, kda,
+                         shortconv as sc, ssd)
 
 
 @pytest.fixture(scope="module")
@@ -37,7 +38,8 @@ def compiled_not_interpreted(monkeypatch):
     # the other mode.
     forget = lambda: [f.clear_cache() for f in (
         ssd._ssd_fwd_call, ssd._ssd_bwd_call, kda._conv_fwd_call,
-        kda._conv_bwd_call, kda._norm_fwd_call, kda._norm_bwd_call)]
+        kda._conv_bwd_call, kda._norm_fwd_call, kda._norm_bwd_call,
+        sc._fwd_call, sc._bwd_call)]
     forget()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -138,6 +140,27 @@ def test_mixer_conv_kernels_compile_for_v5e(one_chip,
         q, wq, x, wx, sd((64, 64), jnp.float32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert not re.search(r"%copy[.\d]* = bf16\[1,(8192|4096),", text)
+
+
+def test_shortconv_kernels_compile_for_v5e(one_chip,
+                                           compiled_not_interpreted):
+    """The gated short convolution's pair (ops/shortconv.py) in one gradient
+    program at `lfm2_8b_a1b.train_rank4_8k`'s call: the joint projection's
+    output [4,8192,6144] bfloat16, three taps over 2,048 channels. A forward
+    and a backward Mosaic call, which read [Bg ; Cg ; x] where the
+    projection writes them and write d[Bg ; Cg ; x] as one tensor: no slice
+    and no copy of a part's or the whole's size."""
+    sd = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    p, w = sd((4, 8192, 6144), jnp.bfloat16), sd((3, 2048), jnp.float32)
+
+    def loss(p, w):
+        return jnp.sum(sc.gated_conv_pallas(p, w).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        p, w).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(r"%(copy|slice)[.\d]* = bf16\[4,8192,(2048|6144)\]",
+                         text)
 
 
 @pytest.mark.parametrize("S,ch,group,act,first", [

@@ -28,7 +28,7 @@ pytestmark = pytest.mark.usefixtures("exact_matmuls")
 PRESETS = ("llama_tiny", "gpt2_tiny", "moe_tiny", "kimi_linear_tiny",
            "granite_hybrid_tiny", "mellum2_tiny", "kanana2_tiny",
            "qwen3_next_tiny", "laguna_tiny", "ouro_tiny", "keye_vl2_tiny",
-           "phi4_flash_tiny")
+           "phi4_flash_tiny", "lfm2_moe_tiny")
 REMAT = ("off", "dots", "full")
 # "<sha256[:16] of the StableHLO>:<sha256[:16] of its operations' name
 # stacks>" of each preset's gradient program, remat off and under either
@@ -118,6 +118,11 @@ PARENT = {
     "phi4_flash_tiny": ("f777c0a4e2142200:bdeabde20eab01bb",
                         "724778c22dc2c088:f7ed437597c6e1d7",
                         "d439e5528bdd388a:f7ed437597c6e1d7"),
+    # new in PR 60 (its own tree's: the `shortconv` kind, `shortconv.core`
+    # inside it, the XLA body on the CPU); every row above is the parent's
+    "lfm2_moe_tiny": ("029b0a8e5e439340:82547843ee104708",
+                      "42db50c48a324384:82547843ee104708",
+                      "6db2f6b2dfb28e9e:82547843ee104708"),
     # flash's rule set true: the kernels' calls (interpret mode) in the text;
     # re-recorded in PR 47 (its own tree's: one backward kernel where the
     # parent's text held dQ's and dK/dV's; the operations' scopes unmoved)
@@ -168,6 +173,9 @@ PARENT_COUNTS = {
                         23317576704.0),
     "phi4_mini_flash_reasoning.json": (697094272, 697094272, 4963714512.0,
                                        4220927232.0),
+    "lfm2_moe_tiny": (178872, 123552, 765888.0, 937920.0),  # PR 60's own
+    "lfm2_8b_a1b": (8339930560, 1557740288, 18783625728.0, 9384190464.0),
+    "lfm2_8b_a1b.json": (507820288, 199538816, 1297896192.0, 1203524352.0),
 }
 
 
@@ -246,6 +254,52 @@ def test_counts_are_the_parents(name):
     assert sum(a.size for a in jax.tree.leaves(made)) == cfg.num_params()
 
 
+def test_the_lfm2_file_holds_every_published_width():
+    """chipbench/configs/lfm2_8b_a1b.json: its `transformer_config` is the
+    published model's (`configs.lfm2_8b_a1b`) in every field but the four
+    cuts the file lists under `reduced` (depth, the leading dense layers,
+    the held experts, the vocabulary slice), the stage's own layer list and
+    the cell's length; the catalog's keys stand at the top level, every
+    width as published."""
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "lfm2_8b_a1b.json")) as f:
+        file = json.load(f)
+    cut = _file_config(os.path.join(ROOT, "chipbench", "configs",
+                                    "lfm2_8b_a1b.json"))
+    whole = configs.lfm2_8b_a1b(dtype=jnp.bfloat16)
+    differ = {f.name for f in dataclasses.fields(cut)
+              if getattr(cut, f.name) != getattr(whole, f.name)}
+    assert differ == {"n_layers", "moe_first_dense", "moe_held", "vocab_size",
+                      "shortconv_layers", "max_seq_len"}
+    assert file["reduced"] == ["num_hidden_layers", "num_dense_layers",
+                               "num_experts", "vocab_size"]
+    assert {k: file[k] for k in file["reduced"]} == {
+        "num_hidden_layers": cut.n_layers,
+        "num_dense_layers": cut.moe_first_dense,
+        "num_experts": cut.moe_held[1], "vocab_size": cut.vocab_size}
+    assert {k: file["published"][k] for k in file["reduced"]} == {
+        "num_hidden_layers": whole.n_layers,
+        "num_dense_layers": whole.moe_first_dense,
+        "num_experts": whole.moe_num_experts, "vocab_size": whole.vocab_size}
+    assert (file["hidden_size"], file["intermediate_size"],
+            file["moe_intermediate_size"], file["num_attention_heads"],
+            file["num_key_value_heads"], file["conv_L_cache"],
+            file["num_experts_per_tok"], file["norm_eps"],
+            file["rope_theta"]) == (
+        cut.d_model, cut.ff_dim, cut.moe_ff_dim, cut.n_heads, cut.kv_heads,
+        tfm.MIXERS["shortconv"].shapes(cut)["shortconv_conv"][0][0],
+        cut.moe_experts_per_token, cut.norm_eps,
+        cut.rope_theta) == (2048, 7168, 1792, 32, 8, 3, 4, 1e-5, 1e6)
+    assert cut.head_dim == 64 and cut.attn_qk_norm and cut.tie_embeddings
+    # the stage is published layers 1-5 (0-based) of the file's own list
+    kinds = {"conv": "shortconv", "full_attention": "attn"}
+    assert [kinds[t] for t in file["layer_types"]] == [
+        m for m, _ in whole.layer_kinds()]
+    assert [kinds[t] for t in file["layer_types"][1:6]] == [
+        m for m, _ in cut.layer_kinds()]
+    assert cut.num_params() == 507820288  # 8.13 GB at 16 bytes
+
+
 def test_every_configuration_file_and_preset_is_counted():
     files = {os.path.basename(p) for p in glob.glob(
         os.path.join(ROOT, "chipbench", "configs", "*.json"))}
@@ -263,7 +317,7 @@ def test_the_table_is_what_the_configuration_lists():
     rows = tfm.MIXERS
     assert list(rows) == [r.name for r in rows.values()] == [
         "attn", "swa", "mla", "kda", "mamba2", "gdn", "dsa", "xattn",
-        "mamba1", "gmu"]
+        "mamba1", "gmu", "shortconv"]
     fields = {f.name for f in dataclasses.fields(tfm.TransformerConfig)}
     listed = [r.layers_field for r in rows.values() if r.layers_field]
     assert sorted(listed) == sorted(f for f in fields if f.endswith("_layers")
@@ -309,6 +363,7 @@ _kd, _km = ("kda", "dense"), ("kda", "moe")
 _ad, _d = ("attn", "dense"), ("dsa", "moe")
 _m1, _sd = ("mamba1", "dense"), ("swa", "dense")
 _gm, _x = ("gmu", "dense"), ("xattn", "dense")
+_cd, _cm = ("shortconv", "dense"), ("shortconv", "moe")
 PLANS = {
     "llama_tiny": dict(plan=(((_a,), 2),), deep=(24, (((_a,), 24),)),
                        slot=(1, (0, 0, 1)), segments=False,
@@ -370,6 +425,14 @@ PLANS = {
         slot=(4, (4, 0, 0)), segments=True,
         refused=[dict(n_heads=3), dict(mamba1_layers=()),
                  dict(sliding_window=None), dict(attn_qk_norm=True)]),
+    # the cell's cut: published layers 1-5 at four (one convolution expert
+    # layer fewer); deeper, the stage's own lists only add plain layers
+    "lfm2_moe_tiny": dict(
+        plan=(((_cd,), 1), ((_ga,), 1), ((_cm,), 2)),
+        deep=(6, (((_cd,), 1), ((_ga,), 1), ((_cm,), 2), ((_ga,), 2))),
+        slot=(3, (2, 0, 1)), segments=True,
+        refused=[dict(shortconv_layers=(1,), kda_layers=(1,)),
+                 dict(moe_router="softmax_capacity")]),
 }
 
 
@@ -436,7 +499,8 @@ def test_decoding_serves_a_row_or_says_its_sentence(preset):
     word = {"kimi_linear_tiny": "KDA / MLA", "granite_hybrid_tiny": "Mamba-2",
             "mellum2_tiny": "windowed", "kanana2_tiny": "MLA",
             "qwen3_next_tiny": "R7 / R9", "laguna_tiny": "windowed",
-            "keye_vl2_tiny": "R22 (a)", "phi4_flash_tiny": "Mamba-1"}[preset]
+            "keye_vl2_tiny": "R22 (a)", "phi4_flash_tiny": "Mamba-1",
+            "lfm2_moe_tiny": "taps - 1 = 2 rows"}[preset]
     assert word in why[0]
 
 
@@ -481,7 +545,7 @@ INNER = {"kda": ("kda.core",), "gdn": ("gdn.core",), "mamba2": ("ssd.core",),
          # (`dsa.core` too, but its kernels sit in `custom_vjp` functions
          # that lower apart, their locations relative: tests/test_dsa.py)
          "dsa": ("dsa.index",), "mamba1": ("mamba1.core",), "gmu": (),
-         "xattn": ()}
+         "xattn": (), "shortconv": ("shortconv.core",)}
 
 
 @pytest.mark.parametrize("preset", PRESETS)
